@@ -695,8 +695,7 @@ async def run_http_frontend(args) -> None:
     print(f"frontend at {service.url} (hub {addr}); models appear on discovery")
     stop = asyncio.Event()
     # hub loss must terminate the frontend (fail loud), not freeze its view
-    if hasattr(runtime.hub, "on_connection_lost"):
-        runtime.hub.on_connection_lost = stop.set
+    lost = _stop_on_hub_loss(runtime.hub, stop)
     try:
         await _wait_forever(stop)
     finally:
@@ -706,6 +705,7 @@ async def run_http_frontend(args) -> None:
         await runtime.shutdown()
         if owned_hub:
             await owned_hub.stop()
+    _exit_if_lost(lost)
 
 
 async def run_worker(args) -> None:
@@ -824,8 +824,7 @@ async def run_worker(args) -> None:
     stop = asyncio.Event()
     # hub loss orphans this worker's registrations: exit so a supervisor
     # restarts it into a live cluster (fail loud)
-    if hasattr(runtime.hub, "on_connection_lost"):
-        runtime.hub.on_connection_lost = stop.set
+    lost = _stop_on_hub_loss(runtime.hub, stop)
     if args.model_path and args.disagg != "prefill":
         card = await register_llm(
             runtime, ep, args.model_path,
@@ -849,6 +848,7 @@ async def run_worker(args) -> None:
         await runtime.shutdown()
         if owned_hub:
             await owned_hub.stop()
+    _exit_if_lost(lost)
 
 
 async def run_text(args) -> None:
@@ -990,6 +990,30 @@ async def run_batch(args) -> None:
         await asyncio.to_thread(_write_results, results)
     finally:
         await engine.stop()
+
+
+def _stop_on_hub_loss(hub, stop: asyncio.Event) -> list:
+    """Losing the hub (its connection, or a lease it expired) ends this
+    process: it must not serve on from a frozen view of the cluster.  The
+    returned list is non-empty once that happened, and :func:`_exit_if_lost`
+    then turns the clean-up's end into a failing exit code -- a supervisor
+    must see a failure, not a clean stop.  A loss seen after ``stop`` was
+    already set is this process's own shutdown closing the connection."""
+    lost: list = []
+
+    def on_lost() -> None:
+        if not stop.is_set():
+            lost.append(True)
+        stop.set()
+
+    if hasattr(hub, "on_connection_lost"):
+        hub.on_connection_lost = on_lost
+    return lost
+
+
+def _exit_if_lost(lost: list) -> None:
+    if lost:
+        raise SystemExit("hub connection or lease lost; exiting")
 
 
 async def _wait_forever(
@@ -1242,13 +1266,13 @@ async def run_metrics(args) -> int:
     host, port = await svc.serve_http(args.host, args.port)
     print(f"cluster metrics at http://{host}:{port}/metrics (hub {args.hub})")
     stop = asyncio.Event()
-    if hasattr(runtime.hub, "on_connection_lost"):
-        runtime.hub.on_connection_lost = stop.set
+    lost = _stop_on_hub_loss(runtime.hub, stop)
     try:
         await _wait_forever(stop)
     finally:
         await svc.stop()
         await runtime.shutdown()
+    _exit_if_lost(lost)
     return 0
 
 

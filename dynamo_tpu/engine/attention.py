@@ -18,10 +18,12 @@ kernel in dynamo_tpu.ops.paged_attention instead (see
 
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..analysis.hotpath import hot_path
 from .kv_cache import (
@@ -84,12 +86,55 @@ def _env_flag(name: str):
     return env not in ("0", "false", "")
 
 
+@functools.lru_cache(maxsize=None)
 def _on_tpu() -> bool:
-    try:
-        return any("TPU" in d.device_kind for d in jax.devices())
-    # dynalint: disable=DT003 -- platform probe: "no backend" simply means not-TPU
-    except Exception:
-        return False
+    """Whether the process's default backend is a TPU, probed once.  A
+    backend that fails to initialise raises here: a server that cannot
+    reach its chip must not come up on the XLA composition instead."""
+    return jax.default_backend() == "tpu"
+
+
+# -- kernels on a mesh --------------------------------------------------------
+#
+# A Pallas (Mosaic) kernel cannot be partitioned by GSPMD: inside a jit
+# over more than one device the lowering refuses it outright ("Mosaic
+# kernels cannot be automatically partitioned"), whichever axis the
+# operands are sharded on.  Attention has no cross-head term and the pool
+# is sharded on its kv-head axis, so on a mesh every kernel below runs
+# through ``jax.shard_map``: per ``tp`` shard on the head axes, replicated
+# over every other axis (dp lanes are gathered around the kernel).  The
+# mesh is JAX's own context mesh (``jax.set_mesh``), which is part of
+# jit's cache key: the engine sets its mesh on its dispatch thread.
+
+
+def _context_mesh():
+    """The context mesh of the trace in progress, or None on one device."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty or mesh.size == 1 else mesh
+
+
+def _heads_shard(Hq: int, Hkv: int) -> bool:
+    """The explicit tp rule of every Pallas gate: under a tp mesh the
+    kernels run per shard, which needs both head counts to divide.  Where
+    they do not (the pool then sits replicated, sharding._compatible_spec),
+    the dispatch takes the XLA composition, which GSPMD can partition."""
+    mesh = _context_mesh()
+    tp = mesh.shape.get("tp", 1) if mesh is not None else 1
+    return Hq % tp == 0 and Hkv % tp == 0
+
+
+def _per_shard(kernel, args, specs, out_spec):
+    """Call ``kernel(*args)`` directly on one device, or through
+    ``jax.shard_map`` over the context mesh (``specs`` name the head axis
+    of each operand; everything else is replicated over the mesh)."""
+    if _context_mesh() is None:
+        return kernel(*args)
+    return jax.shard_map(
+        kernel, in_specs=tuple(specs), out_specs=out_spec, check_vma=False,
+    )(*args)
+
+
+_POOL_SPEC = P(None, None, None, None, "tp", None)  # [L, 2, P, page, Hkv, D]
 
 
 def _pallas_decode_enabled(page_size: int) -> bool:
@@ -127,6 +172,7 @@ def decode_attention_dispatch(
         # (explicit --kv-dtype float32 under a bf16 model) takes the XLA
         # gather, whose dequant/cast normalizes operands
         and kv_pages.dtype == q.dtype
+        and _heads_shard(q.shape[1], kv_pages.shape[4])
         and _pallas_decode_enabled(kv_pages.shape[3])
     ):
         from ..ops.paged_attention import paged_decode_attention_v2
@@ -134,8 +180,13 @@ def decode_attention_dispatch(
         # group-of-8 fetches: grid-step overhead dominates per-page v1 at
         # serving shapes (v2 internally falls back to v1 for table widths
         # the group doesn't divide)
-        return paged_decode_attention_v2(
-            q, kv_pages, page_table, kv_lens, layer, window, group=8
+        return _per_shard(
+            lambda q, kv, pt, lens, layer: paged_decode_attention_v2(
+                q, kv, pt, lens, layer, window, group=8
+            ),
+            (q, kv_pages, page_table, kv_lens, layer),
+            (P(None, "tp", None), _POOL_SPEC, P(), P(), P()),
+            P(None, "tp", None),
         )
     layer_kv = index_kv_layer(kv_pages, layer)
     return paged_decode_attention(q, layer_kv, page_table, kv_lens, window)
@@ -152,9 +203,15 @@ def _pallas_ragged_enabled(page_size: int, Hq: int, Hkv: int, D: int) -> bool:
     forced = _env_flag("DYN_PALLAS_RAGGED")
     if forced is not None:
         return forced
-    if page_size < 8 or Hq % Hkv or D % 8:
+    if page_size < 8 or Hq % Hkv or D % 8 or not _heads_shard(Hq, Hkv):
         return False
     return _on_tpu()
+
+
+def _scale_args(scales):
+    """The int8 pool's row scales as (operands, specs) of a per-shard
+    kernel call: they carry no head axis and ride replicated."""
+    return ((), ()) if scales is None else ((scales,), (P(),))
 
 
 @hot_path
@@ -186,9 +243,18 @@ def ragged_attention_dispatch(
     if _pallas_ragged_enabled(data.shape[3], Hq, Hkv, D):
         from ..ops.ragged_attention import ragged_paged_attention
 
-        return ragged_paged_attention(
-            q, k, v, data, page_table, base, q_lens, layer, window,
-            group=4, kv_scales=scales,
+        s_ops, s_specs = _scale_args(scales)
+        heads = P(None, None, "tp", None)
+        return _per_shard(
+            lambda q, k, v, data, pt, base, lens, layer, *sc: (
+                ragged_paged_attention(
+                    q, k, v, data, pt, base, lens, layer, window,
+                    group=4, kv_scales=sc[0] if sc else None,
+                )
+            ),
+            (q, k, v, data, page_table, base, q_lens, layer, *s_ops),
+            (heads, heads, heads, _POOL_SPEC, P(), P(), P(), P(), *s_specs),
+            heads,
         )
     from ..ops.ragged_attention import ragged_paged_attention_xla
 
@@ -228,9 +294,21 @@ def packed_ragged_attention_dispatch(
     if _pallas_ragged_enabled(data.shape[3], Hq, Hkv, D):
         from ..ops.ragged_attention import packed_ragged_attention
 
-        return packed_ragged_attention(
-            q, k, v, data, page_table, base, seg_off, q_lens, s_max,
-            layer, window, group=4, kv_scales=scales,
+        s_ops, s_specs = _scale_args(scales)
+        heads = P(None, "tp", None)
+        return _per_shard(
+            lambda q, k, v, data, pt, base, off, lens, layer, *sc: (
+                packed_ragged_attention(
+                    q, k, v, data, pt, base, off, lens, s_max, layer,
+                    window, group=4, kv_scales=sc[0] if sc else None,
+                )
+            ),
+            (q, k, v, data, page_table, base, seg_off, q_lens, layer, *s_ops),
+            (
+                heads, heads, heads, _POOL_SPEC, P(), P(), P(), P(), P(),
+                *s_specs,
+            ),
+            heads,
         )
     from ..ops.ragged_attention import packed_ragged_attention_xla
 
@@ -253,7 +331,7 @@ def _pallas_prefill_enabled(T: int, Hq: int, Hkv: int, D: int) -> bool:
     forced = _env_flag("DYN_PALLAS_PREFILL")
     if forced is not None:
         return forced
-    if T < 1024 or Hq % Hkv or D % 8:
+    if T < 1024 or Hq % Hkv or D % 8 or not _heads_shard(Hq, Hkv):
         return False
     return _on_tpu()
 
@@ -273,7 +351,15 @@ def prefill_attention_dispatch(
     if _pallas_prefill_enabled(T, Hq, k.shape[2], D):
         from ..ops.flash_prefill import flash_prefill_attention
 
-        return flash_prefill_attention(q, k, v, seq_lens, window)
+        heads = P(None, None, "tp", None)
+        return _per_shard(
+            lambda q, k, v, lens: flash_prefill_attention(
+                q, k, v, lens, window
+            ),
+            (q, k, v, seq_lens),
+            (heads, heads, heads, P()),
+            heads,
+        )
     return prefill_attention(q, k, v, seq_lens, window)
 
 
@@ -289,7 +375,7 @@ def _pallas_prefix_prefill_enabled(
     forced = _env_flag("DYN_PALLAS_PREFILL")
     if forced is not None:
         return forced
-    if Hq % Hkv or D % 8:
+    if Hq % Hkv or D % 8 or not _heads_shard(Hq, Hkv):
         return False
     if T < 1024 and (T < 512 or Kp < 512):
         return False
@@ -342,13 +428,20 @@ def prefill_prefix_attention_dispatch(
             widths[1] = (0, pad)
             kp = jnp.pad(kp, widths)
             vp = jnp.pad(vp, widths)
-        return flash_prefix_prefill_attention(
-            q,
-            jnp.concatenate([kp, k], axis=1),
-            jnp.concatenate([vp, v], axis=1),
-            offset,
-            suffix_lens,
-            window,
+        heads = P(None, None, "tp", None)
+        return _per_shard(
+            lambda q, k, v, off, lens: flash_prefix_prefill_attention(
+                q, k, v, off, lens, window
+            ),
+            (
+                q,
+                jnp.concatenate([kp, k], axis=1),
+                jnp.concatenate([vp, v], axis=1),
+                offset,
+                suffix_lens,
+            ),
+            (heads, heads, heads, P(), P()),
+            heads,
         )
     return prefill_prefix_attention(
         q, k, v, kv_pages, layer, prefix_table, offset, suffix_lens, window

@@ -23,10 +23,25 @@ from typing import Dict, Optional, Tuple
 
 from .config import ModelConfig
 
-# v5e: 16 GiB HBM per chip; leave headroom for XLA's runtime buffers,
-# compiled program constants, and fragmentation.
-HBM_V5E = 16 * 1024**3
+# HBM per chip, keyed by ``jax.devices()[0].device_kind`` (Google Cloud TPU
+# documentation, "TPU v5e": 16 GiB).  A plan is for a named device: one that
+# is not listed here is an error, never a silent default.
+HBM_BYTES = {"TPU v5 lite": 16 * 1024**3}
+# leave headroom for XLA's runtime buffers, compiled program constants, and
+# fragmentation
 DEFAULT_RESERVE_FRACTION = 0.06
+
+
+def hbm_bytes_for(device_kind: str) -> int:
+    """HBM of one chip of ``device_kind``; raises for a device the table
+    does not know."""
+    try:
+        return HBM_BYTES[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no HBM size recorded for device kind {device_kind!r}; known: "
+            f"{sorted(HBM_BYTES)}"
+        ) from None
 
 _DTYPE_BYTES = {
     "bfloat16": 2, "float16": 2, "float32": 4, "float64": 8, "int8": 1,
@@ -156,7 +171,7 @@ def plan_memory(
     num_pages: int = 512,
     max_batch_size: int = 8,
     prefill_bucket: int = 2048,
-    hbm_bytes: int = HBM_V5E,
+    hbm_bytes: int,
     reserve_fraction: float = DEFAULT_RESERVE_FRACTION,
 ) -> MemoryPlan:
     """Byte-exact params + KV and a bounded scratch estimate, per chip."""
@@ -229,7 +244,7 @@ def max_kv_pages(
     page_size: int = 16,
     max_batch_size: int = 8,
     prefill_bucket: int = 2048,
-    hbm_bytes: int = HBM_V5E,
+    hbm_bytes: int,
     reserve_fraction: float = DEFAULT_RESERVE_FRACTION,
 ) -> int:
     """Largest page budget that still fits: the KV-cache capacity question

@@ -55,20 +55,104 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
-def _dequant_block(blk, s_ref, kv_idx, out_dtype):
-    """In-kernel fused dequant of one fetched page block: ``blk`` is the
-    raw ``[page, Hkv, D]`` VMEM tile (int8 for a quantized pool), and
-    ``s_ref`` its ``[1, 2, 1, page]`` row-scale block (None for dense
-    pools).  The multiply runs on the VMEM-resident tile right after the
-    HBM fetch -- the pool's int8 bytes are the only thing that ever
-    streams.  Dense pools whose dtype differs from the compute dtype
-    (an explicit ``--kv-dtype float32`` under a bf16 model) convert here
-    too -- ``lax.dot_general`` rejects mixed operand dtypes."""
+# Physical VMEM the kernels may ask the compiler for: a v5e/v6e core holds
+# 128 MiB (jax.experimental.pallas.tpu.get_tpu_info), and the default scoped
+# limit (16 MiB) refuses the packed kernel from s_max 256 up.  Each
+# ``pallas_call`` below passes the footprint it computes from its own block
+# and scratch shapes as ``vmem_limit_bytes``; a shape whose footprint passes
+# this cap is refused by :func:`packed_shape_fits` before the engine can mint
+# it.
+VMEM_CAP_BYTES = 100 << 20
+
+# rows of the int8 pool's row-scale array one scale block carries: the TPU
+# lowering wants a block's second-minor dimension divisible by 8, so a page's
+# scales ride in the aligned 8-page group that holds them
+_SCALE_ROWS = 8
+
+
+def _vmem_limit(need: int) -> int:
+    """What a call asks the compiler for: half again over the footprint it
+    counts (the limit is a ceiling, not a reservation), at least the
+    default 16 MiB, never past the cap."""
+    return min(VMEM_CAP_BYTES, max(need + need // 2, 16 << 20))
+
+
+def _tile_bytes(shape, dtype) -> int:
+    """Bytes ``shape`` occupies in VMEM: the last dimension pads to 128
+    lanes, the one before it to the dtype's sublane pack (8 rows of 32
+    bits)."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 8 * max(4 // item, 1)
+    dims = list(shape)
+    dims[-1] = -(-dims[-1] // 128) * 128
+    if len(dims) > 1:
+        dims[-2] = -(-dims[-2] // sub) * sub
+    n = item
+    for d in dims:
+        n *= d
+    return n
+
+
+def _scale_column(s_ref, kv_idx, row, page):
+    """One page's row scales as a ``[page, 1]`` column.  ``s_ref`` is the
+    ``[1, 2, _SCALE_ROWS, page]`` block holding the page's aligned group;
+    ``row`` picks the page inside it.  The scales arrive along lanes and
+    the dequant multiplies along sublanes, so the row is turned with a
+    masked lane reduction (iota, select, sum) -- ops every TPU generation
+    lowers, on one vreg."""
+    srow = s_ref[0, kv_idx, pl.ds(row, 1), :]  # [1, page]
+    r = jax.lax.broadcasted_iota(jnp.int32, (page, page), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (page, page), 1)
+    return jnp.sum(
+        jnp.where(r == c, jnp.broadcast_to(srow, (page, page)), 0.0),
+        axis=1, keepdims=True,
+    )
+
+
+def _dequant_block(blk, s_ref, kv_idx, row, out_dtype):
+    """In-kernel fused dequant of one fetched page block, returned
+    heads-major: ``blk`` is the raw ``[page, Hkv, D]`` VMEM tile (int8 for
+    a quantized pool), ``s_ref`` its row-scale group block (None for dense
+    pools) and ``row`` the page's row inside that group.  The multiply
+    runs on the VMEM-resident tile right after the HBM fetch -- the pool's
+    int8 bytes are the only thing that ever streams.  Dense pools whose
+    dtype differs from the compute dtype (an explicit ``--kv-dtype
+    float32`` under a bf16 model) convert here too -- ``lax.dot_general``
+    rejects mixed operand dtypes.  Returns ``[Hkv, page, D]``."""
     if s_ref is None:
-        return blk if blk.dtype == out_dtype else blk.astype(out_dtype)
+        blk = blk if blk.dtype == out_dtype else blk.astype(out_dtype)
+        return blk.transpose(1, 0, 2)
+    col = _scale_column(s_ref, kv_idx, row, blk.shape[0])
     return (
-        blk.astype(jnp.float32) * s_ref[0, kv_idx, 0][:, None, None]
+        blk.astype(jnp.float32).transpose(1, 0, 2) * col[None]
     ).astype(out_dtype)
+
+
+def _group_kv(kv_refs, s_refs, rows, kv_idx, out_dtype):
+    """The fetched page group's K (``kv_idx`` 0) or V (1) as one
+    ``[Hkv, G*page, D]`` block."""
+    return jnp.concatenate(
+        [
+            _dequant_block(r[0, kv_idx, 0], sr, kv_idx, row, out_dtype)
+            for r, sr, row in zip(kv_refs, s_refs, rows)
+        ],
+        axis=1,
+    )
+
+
+def _for_blocks(n, body) -> None:
+    """Run ``body(i)`` for ``i`` in ``[0, n)``: inline for a static single
+    block (the shape every small dispatch has), else one ``fori_loop`` so
+    the body is emitted once whatever ``n`` is."""
+    if isinstance(n, int) and n == 1:
+        body(0)
+        return
+
+    def step(i, carry):
+        body(i)
+        return carry
+
+    jax.lax.fori_loop(0, n, step, 0)
 
 
 def _ragged_kernel(
@@ -77,9 +161,9 @@ def _ragged_kernel(
     pt_ref,  # [B, P] page table (SMEM)
     base_ref,  # [B] committed cache length = first fresh position (SMEM)
     len_ref,  # [B] fresh query rows per lane (SMEM)
-    *refs,  # G kv blocks [1, 2, 1, page, Hkv, D] (+ G row-scale blocks
-    # [1, 2, 1, page] when the pool is int8), q, fresh k, fresh v, then
-    # o_ref and m/l/acc scratch
+    *refs,  # G kv blocks [1, 2, 1, page, Hkv, D] (+ G row-scale group
+    # blocks [1, 2, _SCALE_ROWS, page] when the pool is int8), q, fresh k,
+    # fresh v, then o_ref and m/l/acc scratch
     G: int,
     quant: bool = False,
     window: int = 0,
@@ -144,24 +228,13 @@ def _ragged_kernel(
 
     @pl.when(live)
     def _prefix():
-        k = jnp.concatenate(
-            [
-                _dequant_block(r[0, 0, 0], sr, 0, q_ref.dtype).transpose(
-                    1, 0, 2
-                )
-                for r, sr in zip(kv_refs, s_refs)
-            ],
-            axis=1,
-        )  # [Hkv, G*page, D]
-        v = jnp.concatenate(
-            [
-                _dequant_block(r[0, 1, 0], sr, 1, q_ref.dtype).transpose(
-                    1, 0, 2
-                )
-                for r, sr in zip(kv_refs, s_refs)
-            ],
-            axis=1,
-        )
+        # each page's row inside its scale group (int8 pools only)
+        rows = [
+            pt_ref[b, p * G + g] % _SCALE_ROWS if quant else 0
+            for g in range(G)
+        ]
+        k = _group_kv(kv_refs, s_refs, rows, 0, q_ref.dtype)
+        v = _group_kv(kv_refs, s_refs, rows, 1, q_ref.dtype)  # [Hkv, G*page, D]
         s = jax.lax.dot_general(
             q4(), k,
             dimension_numbers=(((3,), (2,)), ((0,), (0,))),
@@ -234,12 +307,19 @@ def ragged_paged_attention(
     pt = jnp.clip(page_table.astype(jnp.int32), 0, num_pages - 1)
     lyr = jnp.clip(jnp.asarray(layer, jnp.int32), 0, L - 1).reshape(1)
 
-    def kv_map(g, ndim=6):
+    def kv_map(g):
         def m(b, p, layer_ref, pt_ref, base_ref, len_ref):
             # the fresh step (p == npg) re-targets the last group: the
             # fetch is dead weight there but keeps the operand spec static
             pp = jnp.minimum(p, npg - 1)
-            return (layer_ref[0], 0, pt_ref[b, pp * G + g], 0, 0, 0)[:ndim]
+            return (layer_ref[0], 0, pt_ref[b, pp * G + g], 0, 0, 0)
+
+        return m
+
+    def scale_map(g):
+        def m(b, p, layer_ref, pt_ref, base_ref, len_ref):
+            pp = jnp.minimum(p, npg - 1)
+            return (layer_ref[0], 0, pt_ref[b, pp * G + g] // _SCALE_ROWS, 0)
 
         return m
 
@@ -248,7 +328,7 @@ def ragged_paged_attention(
 
     scale_specs = (
         [
-            pl.BlockSpec((1, 2, 1, page), kv_map(g, ndim=4))
+            pl.BlockSpec((1, 2, _SCALE_ROWS, page), scale_map(g))
             for g in range(G)
         ]
         if quant
@@ -278,10 +358,59 @@ def ragged_paged_attention(
         functools.partial(_ragged_kernel, G=G, quant=quant, window=window),
         out_shape=jax.ShapeDtypeStruct((B, S, Hq, D), q.dtype),
         grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(
+                ragged_vmem_bytes(
+                    S, Hq, Hkv, D, page, G, q.dtype, kv_pages.dtype, quant
+                )
+            ),
+        ),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(
         lyr, pt, base.astype(jnp.int32), q_lens.astype(jnp.int32),
         *([kv_pages] * G), *scale_ops, q, k, v,
+    )
+
+
+def _kv_stream_bytes(page, Hkv, D, G, kv_dtype, quant) -> int:
+    """Double-buffered page-group operands shared by both kernels."""
+    n = 2 * G * 2 * _tile_bytes((page, Hkv, D), kv_dtype)
+    if quant:
+        n += 2 * G * 2 * _tile_bytes((_SCALE_ROWS, page), jnp.float32)
+    return n
+
+
+def _body_bytes(rows, keys, Hkv, D, dtype) -> int:
+    """Live values of one accumulate body over ``rows`` query rows (all
+    heads) against ``keys`` keys: the f32 score chain (scores, mask,
+    probabilities and their casts -- four live copies), the K/V group in
+    f32 and compute dtype, the query block and the f32 PV product."""
+    return (
+        4 * _tile_bytes((rows, keys), jnp.float32)
+        + 4 * Hkv * _tile_bytes((keys, D), jnp.float32)
+        + 2 * _tile_bytes((rows, D), jnp.float32)
+        + 2 * _tile_bytes((rows, D), dtype)
+    )
+
+
+def ragged_vmem_bytes(S, Hq, Hkv, D, page, G, dtype, kv_dtype, quant) -> int:
+    """VMEM the rectangle kernel asks for, from its own shapes."""
+    rows = Hq * S
+    blocks = 2 * (
+        2 * _tile_bytes((S, Hq, D), dtype)
+        + 2 * _tile_bytes((S, Hkv, D), dtype)
+    )
+    scratch = 2 * _tile_bytes((rows, 1), jnp.float32) + _tile_bytes(
+        (rows, D), jnp.float32
+    )
+    return (
+        blocks
+        + scratch
+        + _kv_stream_bytes(page, Hkv, D, G, kv_dtype, quant)
+        + _body_bytes(rows, max(G * page, S), Hkv, D, dtype)
+        + (4 << 20)
     )
 
 
@@ -373,6 +502,13 @@ def ragged_paged_attention_xla(
 # segment per lane, decode lanes contributing a single row.
 
 
+# query rows one accumulate body handles: a lane's ``s_max`` window is
+# walked in blocks of this many rows, so the score tile, the emitted code and
+# the compile time stop growing with ``s_max`` (a whole 512-row window in one
+# body asked for 32 MiB of scores and most of a minute of compile)
+_Q_BLOCK = 128
+
+
 def _packed_kernel(
     # scalar prefetch
     layer_ref,  # [1] layer index (SMEM)
@@ -380,8 +516,8 @@ def _packed_kernel(
     base_ref,  # [B] committed cache length = first fresh position (SMEM)
     off_ref,  # [B] lane's segment offset into the packed axis (SMEM)
     len_ref,  # [B] fresh rows per lane (SMEM)
-    *refs,  # G kv blocks (+ G row-scale blocks when the pool is int8),
-    # packed q, packed fresh k/v, o_ref, m/l/acc scratch
+    *refs,  # G kv blocks (+ G row-scale group blocks when the pool is
+    # int8), packed q, packed fresh k/v, o_ref, m/l/acc scratch
     G: int,
     s_max: int,
     quant: bool = False,
@@ -391,18 +527,20 @@ def _packed_kernel(
     :func:`_ragged_kernel`, over PACKED operands: the whole packed
     ``[Np, H, D]`` q / fresh-k / fresh-v arrays ride as single VMEM
     blocks (revisited every step, so they transfer once), and lane ``b``
-    reads its ``s_max``-row window at ``off_ref[b]`` with a dynamic
-    slice.  The caller guarantees ``off + s_max <= Np`` for every live
-    lane (packed-axis padding rule in the step assembly), so the slice
-    never clamps and rows stay aligned.
+    reads its ``s_max``-row window at ``off_ref[b]`` with dynamic
+    slices, ``_Q_BLOCK`` rows at a time.  The caller guarantees
+    ``off + s_max <= Np`` for every live lane (packed-axis padding rule
+    in the step assembly), so a slice never clamps and rows stay aligned.
+    Only the query blocks that hold live rows (``< q_len``) are walked,
+    and the fresh phase pairs query block ``i`` with key blocks ``<= i``
+    (later ones are wholly above the causal diagonal).
 
-    Output aliasing: lane ``b``'s final step writes its full
-    ``s_max``-row window, whose tail (rows past ``q_len``) overlaps the
-    NEXT lanes' segments -- safe because the grid walks lanes in
-    ascending order, so a later lane's write overwrites any garbage a
-    predecessor spilled into its rows.  Idle lanes (``q_len == 0``) skip
-    both compute and the write (their offset is 0 and would clobber the
-    first live lane)."""
+    Output aliasing: lane ``b``'s final step writes whole query blocks,
+    whose tail (rows past ``q_len``) overlaps the NEXT lanes' segments --
+    safe because the grid walks lanes in ascending order, so a later
+    lane's write overwrites any garbage a predecessor spilled into its
+    rows.  Idle lanes (``q_len == 0``) skip both compute and the write
+    (their offset is 0 and would clobber the first live lane)."""
     kv_refs = refs[:G]
     s_refs = refs[G : 2 * G] if quant else [None] * G
     q_ref, fk_ref, fv_ref, o_ref, m_scr, l_scr, acc_scr = refs[
@@ -417,17 +555,23 @@ def _packed_kernel(
     Hq = q_ref.shape[1]
     n_rep = Hq // Hkv
     scale = 1.0 / (D ** 0.5)
+    nq, qb = m_scr.shape[0], m_scr.shape[1] // Hq  # query blocks x rows
 
     base = base_ref[b]
     off = off_ref[b]
     q_len = len_ref[b]
     live_lane = q_len > 0
+    # query blocks holding live rows; static when the window is one block
+    n_live = 1 if nq == 1 else (q_len + qb - 1) // qb
 
     @pl.when((p == 0) & ((b == 0) | live_lane))
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        def init(i):
+            m_scr[i] = jnp.full(m_scr.shape[1:], _NEG_INF, m_scr.dtype)
+            l_scr[i] = jnp.zeros(l_scr.shape[1:], l_scr.dtype)
+            acc_scr[i] = jnp.zeros(acc_scr.shape[1:], acc_scr.dtype)
+
+        _for_blocks(nq, init)
 
     @pl.when((b == 0) & (p == 0))
     def _zero_out():
@@ -436,26 +580,26 @@ def _packed_kernel(
         # memory
         o_ref[:] = jnp.zeros_like(o_ref)
 
-    def q4():
-        # lane window [s_max, Hq, D] -> [Hkv, n_rep, s_max, D]
-        qw = q_ref[pl.ds(off, s_max)]
-        return qw.transpose(1, 0, 2).reshape(Hkv, n_rep, s_max, D)
+    def q4(i):
+        # query block i of the lane window -> [Hkv, n_rep, qb, D]
+        qw = q_ref[pl.ds(off + i * qb, qb)]
+        return qw.transpose(1, 0, 2).reshape(Hkv, n_rep, qb, D)
 
-    def accumulate(s, v):  # s [Hkv, n_rep, s_max, K], v [Hkv, K, D]
-        s2 = s.reshape(Hq * s_max, s.shape[-1])
-        m_prev = m_scr[:]
+    def accumulate(i, s, v):  # s [Hkv, n_rep, qb, K], v [Hkv, K, D]
+        s2 = s.reshape(Hq * qb, s.shape[-1])
+        m_prev = m_scr[i]
         m_cur = jnp.max(s2, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
         probs = jnp.exp(s2 - m_new)
         pv = jax.lax.dot_general(
-            probs.reshape(Hkv, n_rep * s_max, s.shape[-1]).astype(v.dtype), v,
+            probs.reshape(Hkv, n_rep * qb, s.shape[-1]).astype(v.dtype), v,
             dimension_numbers=(((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
-        m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(probs, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + pv.reshape(Hq * s_max, D)
+        m_scr[i] = m_new
+        l_scr[i] = l_scr[i] * alpha + jnp.sum(probs, axis=-1, keepdims=True)
+        acc_scr[i] = acc_scr[i] * alpha + pv.reshape(Hq * qb, D)
 
     grp_base = p * G * page
     live = live_lane & (p < npg) & (grp_base < base)
@@ -464,61 +608,110 @@ def _packed_kernel(
 
     @pl.when(live)
     def _prefix():
-        k = jnp.concatenate(
-            [
-                _dequant_block(r[0, 0, 0], sr, 0, q_ref.dtype).transpose(
-                    1, 0, 2
-                )
-                for r, sr in zip(kv_refs, s_refs)
-            ],
-            axis=1,
-        )  # [Hkv, G*page, D]
-        v = jnp.concatenate(
-            [
-                _dequant_block(r[0, 1, 0], sr, 1, q_ref.dtype).transpose(
-                    1, 0, 2
-                )
-                for r, sr in zip(kv_refs, s_refs)
-            ],
-            axis=1,
-        )
-        s = jax.lax.dot_general(
-            q4(), k,
-            dimension_numbers=(((3,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [Hkv, n_rep, s_max, G*page]
-        kpos = grp_base + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, dimension=3
-        )
-        keep = kpos < base
-        if window > 0:
-            qpos = base + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, dimension=2
+        # each page's row inside its scale group (int8 pools only)
+        rows = [
+            pt_ref[b, p * G + g] % _SCALE_ROWS if quant else 0
+            for g in range(G)
+        ]
+        k = _group_kv(kv_refs, s_refs, rows, 0, q_ref.dtype)
+        v = _group_kv(kv_refs, s_refs, rows, 1, q_ref.dtype)  # [Hkv, G*page, D]
+
+        def block(i):
+            s = jax.lax.dot_general(
+                q4(i), k,
+                dimension_numbers=(((3,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [Hkv, n_rep, qb, G*page]
+            kpos = grp_base + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, dimension=3
             )
-            keep = keep & (kpos > qpos - window)
-        accumulate(jnp.where(keep, s, _NEG_INF), v)
+            keep = kpos < base
+            if window > 0:
+                qpos = base + i * qb + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, dimension=2
+                )
+                keep = keep & (kpos > qpos - window)
+            accumulate(i, jnp.where(keep, s, _NEG_INF), v)
+
+        _for_blocks(n_live, block)
 
     @pl.when(live_lane & (p == npg))
     def _fresh():
-        fk = fk_ref[pl.ds(off, s_max)].transpose(1, 0, 2)  # [Hkv, s_max, D]
-        fv = fv_ref[pl.ds(off, s_max)].transpose(1, 0, 2)
-        s = jax.lax.dot_general(
-            q4(), fk,
-            dimension_numbers=(((3,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [Hkv, n_rep, s_max, s_max]
-        qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, dimension=2)
-        kj = jax.lax.broadcasted_iota(jnp.int32, s.shape, dimension=3)
-        keep = (kj <= qi) & (kj < q_len)
-        if window > 0:
-            keep = keep & (qi - kj < window)
-        accumulate(jnp.where(keep, s, _NEG_INF), fv)
-        l = l_scr[:]
-        safe = jnp.where(l > 0.0, l, 1.0)
-        out = (acc_scr[:] / safe).reshape(Hkv, n_rep, s_max, D)
-        o_ref[pl.ds(off, s_max)] = (
-            out.reshape(Hq, s_max, D).transpose(1, 0, 2).astype(o_ref.dtype)
+        def block(i):
+            q = q4(i)
+
+            def keys(j):
+                at = pl.ds(off + j * qb, qb)
+                fk = fk_ref[at].transpose(1, 0, 2)  # [Hkv, qb, D]
+                fv = fv_ref[at].transpose(1, 0, 2)
+                s = jax.lax.dot_general(
+                    q, fk,
+                    dimension_numbers=(((3,), (2,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32,
+                ) * scale  # [Hkv, n_rep, qb, qb]
+                qi = i * qb + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, dimension=2
+                )
+                kj = j * qb + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, dimension=3
+                )
+                keep = (kj <= qi) & (kj < q_len)
+                if window > 0:
+                    keep = keep & (qi - kj < window)
+                accumulate(i, jnp.where(keep, s, _NEG_INF), fv)
+
+            _for_blocks(1 if nq == 1 else i + 1, keys)
+            l = l_scr[i]
+            safe = jnp.where(l > 0.0, l, 1.0)
+            out = (acc_scr[i] / safe).reshape(Hkv, n_rep, qb, D)
+            o_ref[pl.ds(off + i * qb, qb)] = (
+                out.reshape(Hq, qb, D).transpose(1, 0, 2).astype(o_ref.dtype)
+            )
+
+        _for_blocks(n_live, block)
+
+
+def packed_vmem_bytes(
+    Np, s_max, Hq, Hkv, D, page, G, dtype, kv_dtype, quant
+) -> int:
+    """VMEM the packed kernel needs, from its own shapes: the packed
+    q/k/v/out blocks (one copy each: their block index never moves), the
+    m/l/acc scratch over the lane window (a ``[rows, 1]`` column pads to
+    128 lanes), the page-group stream and one accumulate body.  Checked
+    against the chip's compiler at the widest default shape (1024, 512):
+    it accepts the kernel from 56 MiB at Mixtral widths and from 40 MiB at
+    TinyLlama's, where this counts 69 and 68."""
+    qb = min(s_max, _Q_BLOCK)
+    nq = s_max // qb
+    blocks = 2 * _tile_bytes((Np, Hq, D), dtype) + 2 * _tile_bytes(
+        (Np, Hkv, D), dtype
+    )
+    scratch = nq * (
+        2 * _tile_bytes((Hq * qb, 1), jnp.float32)
+        + _tile_bytes((Hq * qb, D), jnp.float32)
+    )
+    return (
+        blocks
+        + scratch
+        + _kv_stream_bytes(page, Hkv, D, G, kv_dtype, quant)
+        + _body_bytes(Hq * qb, max(G * page, qb), Hkv, D, dtype)
+        + (4 << 20)
+    )
+
+
+def packed_shape_fits(
+    Np, s_max, Hq, Hkv, D, page, dtype, kv_dtype, quant, group: int = 4
+) -> bool:
+    """Whether :func:`packed_ragged_attention` can hold ``(Np, s_max)`` at
+    these widths -- the bound the engine checks before it lets a mixed
+    dispatch mint a shape (never discovered at a user's first long
+    prompt)."""
+    return (
+        packed_vmem_bytes(
+            Np, s_max, Hq, Hkv, D, page, group, dtype, kv_dtype, quant
         )
+        <= VMEM_CAP_BYTES
+    )
 
 
 @functools.partial(
@@ -544,10 +737,10 @@ def packed_ragged_attention(
     one flat ``[Np]`` token axis, per-lane segment offsets, the same
     page-group-streaming grid as :func:`ragged_paged_attention`.  The
     packed operands live in VMEM for the whole launch, so ``Np`` (the
-    mixed-dispatch token budget) bounds the resident footprint --
-    budgets into the low thousands of tokens fit comfortably.
-    ``kv_scales`` arms the fused int8 dequant, exactly as in the
-    rectangle kernel."""
+    mixed-dispatch token budget) bounds the resident footprint:
+    :func:`packed_vmem_bytes` is what the call asks the compiler for and
+    :func:`packed_shape_fits` the bound callers check.  ``kv_scales`` arms
+    the fused int8 dequant, exactly as in the rectangle kernel."""
     Np, Hq, D = q.shape
     L, _, num_pages, page, Hkv, _ = kv_pages.shape
     B, P = page_table.shape
@@ -556,14 +749,25 @@ def packed_ragged_attention(
         G -= 1
     npg = P // G
     quant = kv_scales is not None
+    qb = min(s_max, _Q_BLOCK)
+    if s_max % qb:
+        raise ValueError(f"s_max {s_max} is not a multiple of {qb}")
+    nq = s_max // qb
 
     pt = jnp.clip(page_table.astype(jnp.int32), 0, num_pages - 1)
     lyr = jnp.clip(jnp.asarray(layer, jnp.int32), 0, L - 1).reshape(1)
 
-    def kv_map(g, ndim=6):
+    def kv_map(g):
         def m(b, p, layer_ref, pt_ref, base_ref, off_ref, len_ref):
             pp = jnp.minimum(p, npg - 1)
-            return (layer_ref[0], 0, pt_ref[b, pp * G + g], 0, 0, 0)[:ndim]
+            return (layer_ref[0], 0, pt_ref[b, pp * G + g], 0, 0, 0)
+
+        return m
+
+    def scale_map(g):
+        def m(b, p, layer_ref, pt_ref, base_ref, off_ref, len_ref):
+            pp = jnp.minimum(p, npg - 1)
+            return (layer_ref[0], 0, pt_ref[b, pp * G + g] // _SCALE_ROWS, 0)
 
         return m
 
@@ -573,7 +777,7 @@ def packed_ragged_attention(
 
     scale_specs = (
         [
-            pl.BlockSpec((1, 2, 1, page), kv_map(g, ndim=4))
+            pl.BlockSpec((1, 2, _SCALE_ROWS, page), scale_map(g))
             for g in range(G)
         ]
         if quant
@@ -593,9 +797,9 @@ def packed_ragged_attention(
         ],
         out_specs=pl.BlockSpec((Np, Hq, D), packed_map),
         scratch_shapes=[
-            pltpu.VMEM((Hq * s_max, 1), jnp.float32),
-            pltpu.VMEM((Hq * s_max, 1), jnp.float32),
-            pltpu.VMEM((Hq * s_max, D), jnp.float32),
+            pltpu.VMEM((nq, Hq * qb, 1), jnp.float32),
+            pltpu.VMEM((nq, Hq * qb, 1), jnp.float32),
+            pltpu.VMEM((nq, Hq * qb, D), jnp.float32),
         ],
     )
     scale_ops = [kv_scales] * G if quant else []
@@ -605,7 +809,17 @@ def packed_ragged_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((Np, Hq, D), q.dtype),
         grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(
+                packed_vmem_bytes(
+                    Np, s_max, Hq, Hkv, D, page, G, q.dtype, kv_pages.dtype,
+                    quant,
+                )
+            ),
+        ),
         interpret=interpret,
+        name="packed_ragged_attention",
     )(
         lyr, pt, base.astype(jnp.int32), seg_off.astype(jnp.int32),
         q_lens.astype(jnp.int32), *([kv_pages] * G), *scale_ops, q, k, v,

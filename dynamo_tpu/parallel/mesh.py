@@ -60,27 +60,6 @@ def single_device_mesh() -> Mesh:
     return build_mesh(MeshConfig())
 
 
-def shard_map_compat(f, *, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` moved out of experimental AND renamed its
-    replication-check kwarg (``check_rep`` -> ``check_vma``) across the
-    jax versions this repo must serve on (TPU driver vs CI container).
-    Resolve whichever this runtime carries and disable the check under
-    its local name (the per-shard bodies here return intentionally
-    stage-local values that the checker would reject)."""
-    import inspect
-
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    params = inspect.signature(fn).parameters
-    if "check_vma" in params:
-        kwargs["check_vma"] = False
-    elif "check_rep" in params:
-        kwargs["check_rep"] = False
-    return fn(f, **kwargs)
-
-
 def serving_mesh(
     tp: int = 1, dp: int = 1, devices: Optional[Sequence[jax.Device]] = None
 ) -> Optional[Mesh]:

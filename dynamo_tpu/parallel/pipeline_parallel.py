@@ -38,7 +38,6 @@ from ..engine.model import (
     rope_cos_sin,
     scan_layers,
 )
-from .mesh import shard_map_compat
 
 
 @partial(
@@ -115,11 +114,12 @@ def pp_prefill_step(
         # only the last stage wrote non-zeros; psum replicates the result
         return jax.lax.psum(out, axis_name), kv
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         stage,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(), P(), P(), P(), P()),
         out_specs=(P(), P(axis_name)),
+        check_vma=False,  # the stage bodies return stage-local values
     )
     hidden_mb, kv_pages = fn(
         params["layers"], kv_pages, x_mb, cos_mb, sin_mb, pt_mb, lens_mb

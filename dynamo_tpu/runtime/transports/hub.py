@@ -44,6 +44,12 @@ from .codec import read_frame, write_frame
 
 logger = logging.getLogger("dynamo.hub")
 
+# the lease-expiry loop's watch on its own clock (HubServer._expiry_loop):
+# the longest single sleep while leases are held, and how late a wake must
+# come before the excess counts as time the hub did not run
+LEASE_WATCH_S = 1.0
+LEASE_STALL_S = 0.5
+
 # ---------------------------------------------------------------------------
 # Shared data model
 # ---------------------------------------------------------------------------
@@ -211,6 +217,13 @@ class HubState:
             self.journal({"op": "lease_revoke", "id": lease_id}, b"")
         for key in list(self.lease_keys.pop(lease_id, ())):
             self.kv_delete(key)
+
+    def extend_leases(self, seconds: float) -> None:
+        """Push every lease deadline out by ``seconds`` during which this
+        hub did not run: it could hear no keepalive then, so that time is
+        not the clients' to lose (a restore re-arms leases the same way)."""
+        for lease_id in self.leases:
+            self.leases[lease_id] += seconds
 
     def expire_leases(self) -> None:
         now = time.monotonic()
@@ -863,10 +876,19 @@ class HubServer:
 
     async def _expiry_loop(self) -> None:
         """Event-driven lease expiry: sleep until the EARLIEST lease
-        deadline (not a fixed 2 Hz poll -- an idle hub makes zero wakeups),
-        re-aimed whenever a grant introduces an earlier one.  Keepalives
-        only extend deadlines, so waking at a stale deadline just finds
-        nothing expired and recomputes."""
+        deadline (not a fixed 2 Hz poll -- a hub that holds no lease makes
+        zero wakeups), re-aimed whenever a grant introduces an earlier one.
+        Keepalives only extend deadlines, so waking at a stale deadline just
+        finds nothing expired and recomputes.
+
+        While leases are held one sleep lasts at most ``LEASE_WATCH_S``, so
+        that a wake which comes late measures time this process did not run
+        (a blocked loop, or the whole machine frozen: a TPU runtime starting
+        in another process stops every process of a v5e host for seconds).
+        Clients frozen with the hub send their keepalive the moment both
+        thaw; the hub must not expire their leases first, for seconds in
+        which it could not have heard them.  Such time is added to every
+        deadline before the next expiry pass."""
         wake = asyncio.Event()
         self.state.lease_wake = wake.set
         while True:
@@ -875,11 +897,21 @@ class HubServer:
             # the read and the wait sets the event and wakes us right back
             wake.clear()
             nxt = self.state.next_lease_expiry()
+            asked = time.monotonic()
             timeout = (
-                None if nxt is None else max(nxt - time.monotonic(), 0.0)
+                None if nxt is None
+                else min(max(nxt - asked, 0.0), LEASE_WATCH_S)
             )
             with contextlib.suppress(asyncio.TimeoutError):
                 await asyncio.wait_for(wake.wait(), timeout)
+            if timeout is not None:
+                stalled = time.monotonic() - asked - timeout
+                if stalled > LEASE_STALL_S:
+                    logger.warning(
+                        "hub did not run for %.1fs; extending %d leases by it",
+                        stalled, len(self.state.leases),
+                    )
+                    self.state.extend_leases(stalled)
 
     async def _handle_conn(
         self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter

@@ -356,3 +356,101 @@ def test_fleet_table_plan_column_and_quarantine_flag():
     assert "(no planner adjustments yet)" in format_fleet_table(
         empty, show_plan=True
     )
+
+
+def _hub_and_frontend(tmp_path):
+    """Start ``hub`` and an ``in=http out=dyn`` frontend as a user would;
+    returns (hub, front, health_url) once the frontend answers."""
+    import os
+    import socket
+    import subprocess
+    import sys
+    import time
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ports = []
+    for _ in range(2):
+        with socket.socket() as s:
+            s.bind(("127.0.0.1", 0))
+            ports.append(s.getsockname()[1])
+    hub_port, http_port = ports
+    env = dict(os.environ, PYTHONPATH=root, DYN_LOG="info")
+    cmd = [sys.executable, "-m", "dynamo_tpu"]
+    hub = subprocess.Popen(
+        cmd + ["hub", "--host", "127.0.0.1", "--port", str(hub_port)],
+        env=env, cwd=tmp_path, stderr=subprocess.PIPE, text=True,
+    )
+    front = None
+    try:
+        for _ in range(200):
+            try:
+                socket.create_connection(("127.0.0.1", hub_port), 1).close()
+                break
+            except OSError:
+                time.sleep(0.05)
+        front = subprocess.Popen(
+            cmd + ["run", "in=http", "out=dyn", "--hub",
+                   f"127.0.0.1:{hub_port}", "--port", str(http_port)],
+            env=env, cwd=tmp_path, stderr=subprocess.PIPE, text=True,
+        )
+        url = f"http://127.0.0.1:{http_port}/health"
+        for _ in range(400):
+            assert front.poll() is None, front.stderr.read()[-2000:]
+            try:
+                if urllib.request.urlopen(url, timeout=2).status == 200:
+                    return hub, front, url
+            except OSError:
+                time.sleep(0.05)
+        raise AssertionError("frontend never answered /health")
+    except BaseException:
+        _reap(hub, front)
+        raise
+
+
+def _reap(*procs):
+    for proc in procs:
+        if proc is not None and proc.poll() is None:
+            proc.kill()
+        if proc is not None:
+            proc.wait()
+            proc.stderr.close()
+
+
+def test_frontend_that_loses_its_hub_exits_nonzero(tmp_path):
+    """A frontend (or worker) that loses the hub's connection or its lease
+    stops, and a supervisor must see that as a failure: on the chip a
+    frontend whose lease the hub had expired exited with code 0 (PR 22)."""
+    import signal
+
+    hub, front, _url = _hub_and_frontend(tmp_path)
+    try:
+        hub.send_signal(signal.SIGKILL)
+        assert front.wait(timeout=30) != 0
+        assert "lost" in front.stderr.read()
+    finally:
+        _reap(hub, front)
+
+
+def test_frontend_keeps_its_lease_through_a_freeze_of_the_machine(tmp_path):
+    """A TPU runtime starting in a worker stops every process of a v5e
+    host for seconds (6.8 s measured, PR 22).  Stopped together for longer
+    than the lease's 10 s, hub and frontend thaw together: the frontend
+    keeps its lease and goes on serving."""
+    import signal
+    import time
+
+    hub, front, url = _hub_and_frontend(tmp_path)
+    try:
+        for proc in (hub, front):
+            proc.send_signal(signal.SIGSTOP)
+        time.sleep(11.0)
+        for proc in (front, hub):
+            proc.send_signal(signal.SIGCONT)
+        time.sleep(5.0)  # a keepalive interval (10 s / 3) and some
+        assert front.poll() is None, front.stderr.read()[-2000:]
+        assert urllib.request.urlopen(url, timeout=5).status == 200
+        hub.send_signal(signal.SIGINT)
+        hub.wait(timeout=30)
+        assert "hub did not run for" in hub.stderr.read()
+    finally:
+        _reap(hub, front)

@@ -154,6 +154,9 @@ def _mk_packed_case(B, page, Pp, Hq, Hkv, D, bases, qlens, seed=0, L=2):
         (4, 8, 4, 4, 2, 16, [16, 0, 11, 24], [1, 8, 5, 0]),
         # one big prefill + one decode row (the rectangle-waste shape)
         (2, 8, 8, 8, 2, 32, [0, 40], [16, 1]),
+        # windows past one query block: s_max 512 walks four 128-row
+        # blocks, the 130-row lane two, the decode row one
+        (3, 8, 8, 4, 2, 16, [24, 40, 0], [300, 1, 130]),
     ],
 )
 def test_packed_kernel_matches_rectangle(B, page, Pp, Hq, Hkv, D, bases, qlens):
@@ -187,6 +190,42 @@ def test_packed_kernel_matches_rectangle(B, page, Pp, Hq, Hkv, D, bases, qlens):
         np.testing.assert_allclose(
             packed_plas[n], rect[b, i], rtol=2e-5, atol=2e-5
         )
+
+
+def test_packed_kernel_per_tp_shard_matches_reference():
+    """Under a tp mesh the dispatch runs the kernel per shard over the head
+    axes (GSPMD cannot partition a Mosaic kernel): contiguous head shards
+    keep each query head with its own kv head, so the sharded call equals
+    the unsharded reference.  Interpret mode, two virtual devices."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from dynamo_tpu.engine import attention as att
+    from dynamo_tpu.ops.ragged_attention import (
+        packed_ragged_attention,
+        packed_ragged_attention_xla,
+    )
+    from dynamo_tpu.parallel.mesh import serving_mesh
+
+    (qp, kp, vp, _qr, _kr, _vr, kv_pages, pt, base, seg_off, qn, lane, rel,
+     s_max, total) = _mk_packed_case(
+        3, 8, 4, 8, 4, 16, [16, 0, 11], [1, 8, 5])
+    ref = np.asarray(packed_ragged_attention_xla(
+        qp, kp, vp, kv_pages, pt, base, seg_off, qn, lane, rel, s_max, 1))
+    heads = P(None, "tp", None)
+    with jax.set_mesh(serving_mesh(tp=2, devices=jax.devices()[:2])):
+        got = jax.jit(lambda *ops: att._per_shard(
+            lambda q, k, v, pool, pt, base, off, lens: packed_ragged_attention(
+                q, k, v, pool, pt, base, off, lens, s_max, 1, group=2,
+                interpret=True,
+            ),
+            ops,
+            (heads, heads, heads, att._POOL_SPEC, P(), P(), P(), P()),
+            heads,
+        ))(qp, kp, vp, kv_pages, pt, base, seg_off, qn)
+    np.testing.assert_allclose(
+        np.asarray(got)[:total], ref[:total], rtol=2e-5, atol=2e-5
+    )
 
 
 # -- KV-budget admission (scheduler level) -----------------------------------

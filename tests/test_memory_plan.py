@@ -6,17 +6,20 @@ multinode configs (examples/llm/configs/multinode-405b.yaml); here fit is
 computed analytically and must agree with what the engine allocates.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
 
+from dynamo_tpu.engine import memory_plan
 from dynamo_tpu.engine.config import ModelConfig
-from dynamo_tpu.engine.memory_plan import (
-    HBM_V5E,
-    llama3_70b_config,
-    max_kv_pages,
-    plan_memory,
-)
+from dynamo_tpu.engine.memory_plan import hbm_bytes_for, llama3_70b_config
+
+# every plan names its device: these are plans for one v5e chip
+V5E = hbm_bytes_for("TPU v5 lite")
+plan_memory = functools.partial(memory_plan.plan_memory, hbm_bytes=V5E)
+max_kv_pages = functools.partial(memory_plan.max_kv_pages, hbm_bytes=V5E)
 from dynamo_tpu.engine.model import init_params
 from dynamo_tpu.engine.quant import quantize_params
 from dynamo_tpu.engine.weights import param_bytes
@@ -93,8 +96,12 @@ def test_max_kv_pages_inverts_plan():
     assert at_cap.fits and not over.fits
 
 
-def test_default_hbm_is_v5e():
-    assert HBM_V5E == 16 * 1024**3
+def test_hbm_is_keyed_by_device_kind():
+    assert V5E == 16 * 1024**3
+    with pytest.raises(ValueError, match="no HBM size recorded"):
+        hbm_bytes_for("TPU v9 imaginary")
+    with pytest.raises(TypeError):  # no silent default device
+        memory_plan.plan_memory(ModelConfig.tiny(), num_pages=0)
 
 
 def test_int8_scale_replication_on_contracted_axis():

@@ -76,6 +76,39 @@ def test_hub_lease_expiry_removes_keys(run):
     run(body())
 
 
+def test_hub_lease_survives_a_freeze_of_hub_and_client(run, caplog):
+    """A TPU runtime starting in another process stops every process of a
+    v5e host for seconds (PR 22, on the chip: 6.8 s against a 10 s TTL with
+    the keepalive 3.3 s old).  Hub and client thaw together and the client
+    sends its keepalive at once: the hub must not expire the lease first,
+    for seconds in which it could not have heard it.  Here hub and client
+    share one loop, so blocking it freezes both."""
+    import time
+
+    async def body():
+        server, client = await _hub_pair()
+        try:
+            lease = await client.lease_grant(ttl=2.0, keepalive=True)
+            await client.kv_put("instances/z", b"v", lease=lease)
+            await asyncio.sleep(0.8)  # one keepalive (every ttl / 3) is in
+            time.sleep(2.6)  # the freeze: longer than the whole TTL
+            await asyncio.sleep(0.5)
+            assert await client.kv_get_prefix("instances/") == [("instances/z", b"v")]
+            assert lease in client._keepalives  # the client kept its lease
+            assert "hub did not run for" in caplog.text
+            # time the hub did run still counts: without keepalives the
+            # extended lease expires one TTL after the last one it heard
+            client._keepalives.pop(lease).cancel()
+            await asyncio.sleep(2.6)
+            assert await client.kv_get_prefix("instances/") == []
+        finally:
+            await client.close()
+            await server.stop()
+
+    with caplog.at_level("WARNING", logger="dynamo.hub"):
+        run(body())
+
+
 def test_hub_lease_keepalive_holds_key(run):
     async def body():
         server, client = await _hub_pair()
