@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analysis.hotpath import hot_path
-from ..runtime import compile_sentry, profiling, slo, thread_sentry
+from ..runtime import compile_sentry, profiling, slo, thread_sentry, tracing
 from ..runtime.engine import Annotated, Context, ResponseStream
 from ..runtime.utils import log_throttled
 from ..protocols.common import (
@@ -85,6 +85,10 @@ from .step import (
 )
 
 logger = logging.getLogger("dynamo.engine")
+
+# a watched loop's parked wait is annotated in slices of this length
+# (JaxEngine._park): what a device trace loses at its edges
+PARKED_SLICE_S = 0.05
 
 # The designated blocking/fanout sites of the tick-loop module (dynalint
 # DT013): blocking device fetches, detok, and stream-fanout queue puts may
@@ -355,8 +359,7 @@ class EngineConfig:
     # adapts per tick (engine._multistep_plan_k): prefill/mixed queue
     # pressure, speculating lanes, or pending admissions collapse it to 1
     # (admission/preemption granularity never hurts TTFT); an idle queue
-    # ramps it toward ``multistep_max_k``, jumping straight there when
-    # the tick profiler reports a host-bound loop.  Token-identical
+    # ramps it 1, 2, 4, ... toward ``multistep_max_k``.  Token-identical
     # (greedy, seeded, and unseeded-temperature) to K=1 -- the commit
     # replays stop rules over the [B, K] block exactly like decode_block.
     # ``--no-multistep-decode`` / DYN_MULTISTEP=0 pin the exact previous
@@ -1389,6 +1392,10 @@ class JaxEngine:
         else:
             req = data
         seq = SeqState.from_request(request.id, req, self.sched.block_size)
+        # ingress leg: the process received the request (the context's
+        # stamp) -> it stands in this engine's queue (arrival_s, just taken)
+        seq.created_s = request.created_s
+        self.obs.ingress.observe(max(seq.arrival_s - seq.created_s, 0.0))
         if _external:
             # disaggregated: the prompt KV arrives via deliver_external
             seq.awaiting_kv = True
@@ -2545,18 +2552,17 @@ class JaxEngine:
                     # mixed-mode chunked prefill still owes chunks (the
                     # serial loop always carried that tick's dispatch in
                     # ``pending``, masking the case)
+                    watched = tick is not None
                     if tick is not None:
                         tick.discard()
                         self._tick = tick = None
                     self._wake.clear()
-                    if self._external or self._swapped:
-                        # bounded wait so parked-lane timeouts still fire
-                        try:
-                            await asyncio.wait_for(self._wake.wait(), 1.0)
-                        except asyncio.TimeoutError:
-                            pass
-                    else:
-                        await self._wake.wait()
+                    # bounded wait with parked lanes, so their timeouts
+                    # still fire
+                    await self._park(
+                        1.0 if self._external or self._swapped else None,
+                        watched,
+                    )
                     continue
                 self._drive_prefetch()
                 if tick is not None:
@@ -2880,6 +2886,8 @@ class JaxEngine:
                 raise
             except Exception as e:  # engine must never die silently
                 logger.exception("engine tick failed")
+                if self._tick is not None:
+                    self._tick.discard()
                 self._tick = None
                 inflight.clear()
                 self._pending_injects.clear()
@@ -2889,6 +2897,34 @@ class JaxEngine:
                 self._dev = None  # full rebuild once work resumes
                 self.sched.dirty_slots.clear()
                 await asyncio.sleep(0.01)
+
+    async def _park(self, timeout: Optional[float], watched: bool) -> None:
+        """Wait for ``_wake`` (at most ``timeout`` seconds): nothing is
+        runnable.  ``watched`` (the tick profiler is on) only records: the
+        wait is written into the ``jax.profiler`` trace as ``dyn.parked``,
+        in slices, because an annotation is kept only if it opens and
+        closes inside a trace -- one that starts or stops mid-wait then
+        loses a slice, not the whole wait.  It is no tick phase and enters
+        no tick record."""
+        assert self._wake is not None
+        deadline = None if timeout is None else time.monotonic() + timeout
+        while not self._wake.is_set():
+            left = None if deadline is None else deadline - time.monotonic()
+            if left is not None and left <= 0.0:
+                return
+            parked = None
+            if watched:
+                left = PARKED_SLICE_S if left is None else min(
+                    left, PARKED_SLICE_S
+                )
+                parked = profiling.annotate(profiling.PARKED_ANNOTATION)
+            try:
+                await asyncio.wait_for(self._wake.wait(), left)
+            except asyncio.TimeoutError:
+                pass
+            finally:
+                if parked is not None:
+                    parked.__exit__(None, None, None)
 
     def _revive_paused_lanes(self) -> None:
         """A lane that hit its device-side limit self-deactivated; if growth
@@ -3016,10 +3052,9 @@ class JaxEngine:
         * **Fixed mode** (``DYN_MULTISTEP=<N>``) returns N whenever
           pressure-free -- the bench/ablation pin.
         * **Adaptive mode** ramps K geometrically (1, 2, 4, ... up to
-          ``multistep_max_k``) per consecutive pressure-free tick, and
-          jumps straight to the ceiling when the PR-11 profiler says the
-          host is the bottleneck (recent host occupancy >= 0.5): that is
-          precisely the regime where fusing dispatches buys throughput.
+          ``multistep_max_k``) per consecutive pressure-free tick.  It
+          reads nothing the tick profiler or the span collector holds:
+          the engine serves the same whether it is watched or not.
 
         The ramp (rather than an instant max) bounds the worst-case
         tokens a mid-block cancel/deadline discards right after a busy
@@ -3044,9 +3079,6 @@ class JaxEngine:
             return 1
         if self._multistep_fixed is not None:
             return self._multistep_fixed
-        occ = self.profiler.recent_host_occupancy()
-        if occ is not None and occ >= 0.5:
-            self._ms_ramp = self._multistep_max
         k = min(self._ms_ramp, self._multistep_max)
         self._ms_ramp = min(self._ms_ramp * 2, self._multistep_max)
         return k
@@ -3078,6 +3110,8 @@ class JaxEngine:
     def _fail_seq(self, seq: SeqState, message: str) -> None:
         if seq.finish is None:
             seq.finish = FinishReason.ERROR
+        if tracing.collector.enabled:
+            self._record_request_spans(seq)
         # a failed external request must not resurrect via a late delivery
         self._external.pop(seq.request_id, None)
         self._deliveries.pop(seq.request_id, None)
@@ -3145,6 +3179,8 @@ class JaxEngine:
                 if self.sched.pool is None:
                     self._publish_removed(seq)
                 self.sched.cancel(seq)
+                if tracing.collector.enabled:
+                    self._record_request_spans(seq)
 
     # -- device work (executor thread) --------------------------------------
 
@@ -3539,6 +3575,7 @@ class JaxEngine:
             self._sampling_arrays([seq]),
         )
         seq.prefilled_tokens = start + suffix_len
+        seq.note_prefill(suffix_len)
         self._steps += 1
         self.obs.observe_dispatch("chunk")
         if self._tick is not None:
@@ -3553,8 +3590,6 @@ class JaxEngine:
         self, seq: SeqState, prompt_len: int, cached: int
     ) -> InflightPrefill:
         compile_sentry.set_entry("prefill")
-        from ..runtime import tracing
-
         if cached > 0:
             sampled = self._dispatch_suffix_prefill_batch(
                 [(seq, prompt_len, cached)], 1
@@ -3587,14 +3622,7 @@ class JaxEngine:
         self.obs.observe_dispatch("prefill")
         if self._tick is not None:
             self._tick.note_dispatch("prefill")
-        if tracing.collector.enabled:
-            with tracing.span(
-                "engine.prefill_dispatch", seq.request_id
-            ) as sp:
-                sp.set(
-                    prompt_len=prompt_len, bucket=bucket, cached=cached,
-                    kv_prefetch_hits=seq.prefetch_hits,
-                )
+        seq.note_prefill(prompt_len - cached)
         logger.debug("prefill dispatched id=%s len=%d bucket=%d",
                      seq.request_id, prompt_len, bucket)
         return pf
@@ -3614,8 +3642,6 @@ class JaxEngine:
         ``_dispatch_*_prefill_batch`` builders, the same dispatch sites the
         single-request and disagg-export paths use."""
         compile_sentry.set_entry("prefill")
-        from ..runtime import tracing
-
         for seq, _pl in items:
             self._note_prefetch_admission(seq)
             if seq.pending_onboard:
@@ -3666,14 +3692,7 @@ class JaxEngine:
             ):
                 pf.prompt_lp = self._dispatch_prompt_score(seq)
             self._pending_injects[seq.slot] = pf
-            if tracing.collector.enabled:
-                with tracing.span(
-                    "engine.prefill_dispatch", seq.request_id
-                ) as sp:
-                    sp.set(
-                        prompt_len=pl, cached=caches[i], group=len(items),
-                        kv_prefetch_hits=seq.prefetch_hits,
-                    )
+            seq.note_prefill(pl - caches[i])
             logger.debug(
                 "prefill dispatched id=%s len=%d cached=%d (group of %d)",
                 seq.request_id, pl, caches[i], len(items),
@@ -4165,8 +4184,6 @@ class JaxEngine:
         call, where a chunk-less spec-less dispatch has nothing to pack.
         """
         compile_sentry.set_entry("packed_unified_step")
-        from ..runtime import tracing
-
         sched = self.sched
         spec_lanes = self._gather_spec_lanes() if fold_spec else []
         if not chunks and not spec_lanes and num_steps <= 0:
@@ -4224,6 +4241,8 @@ class JaxEngine:
             n_pf_tokens += ch.length
             # dispatch-ordered host bookkeeping (the _dispatch_chunk rule)
             ch.seq.prefilled_tokens = ch.start + ch.length
+            ch.seq.note_prefill(ch.length)
+            ch.seq.prefill_mixed = True
             if ch.final:
                 ch.seq.prefilling = False
                 final_chunks.append(ch)
@@ -4272,6 +4291,7 @@ class JaxEngine:
             for s in sched.slots
         )
         top_n = self._lp_top(sched.slots)
+        dispatch_meta: Dict[str, Any] = {}  # the dispatch annotation's stats
         if self._packed:
             # fully-packed layout (ISSUE 10): ONE flat token axis sized
             # pow2(real fresh tokens) instead of the [B, S] rectangle --
@@ -4339,6 +4359,22 @@ class JaxEngine:
             tick = self._tick
             if tick is not None:
                 tick.mark("assemble")
+                # what the attention kernels are asked to do, carried by
+                # the dispatch interval's annotation: per live lane its
+                # fresh query rows and the context its last row reads
+                # (host mirrors: a decode lane's lags the device by the
+                # uncommitted generations), the fused steps, the packed
+                # rows.  Lists are "|"-joined: a comma cuts a trace stat.
+                live = np.nonzero(q_host)[0]
+                base = np.where(dec_cap, sched.seq_lens, p_start)
+                dispatch_meta = {
+                    "q": "|".join(str(int(v)) for v in q_host[live]),
+                    "ctx": "|".join(
+                        str(int(v)) for v in (base + q_host)[live]
+                    ),
+                    "k": num_steps,
+                    "np": Np,
+                }
             operands = (
                 self.params,
                 self.model_cfg,
@@ -4459,16 +4495,6 @@ class JaxEngine:
                 pf.prompt_lp = self._dispatch_prompt_score(seq)
             self._pending_injects[b] = pf
             finals.append(pf)
-            if tracing.collector.enabled:
-                with tracing.span(
-                    "engine.prefill_dispatch", seq.request_id
-                ) as sp:
-                    sp.set(
-                        prompt_len=len(seq.prompt),
-                        cached=seq.cached_prompt_tokens,
-                        mixed=True,
-                        kv_prefetch_hits=seq.prefetch_hits,
-                    )
         self._steps += num_steps
         self.obs.observe_dispatch("unified")
         self.obs.observe_mixed(n_decode, n_pf_tokens)
@@ -4478,7 +4504,7 @@ class JaxEngine:
             _start_host_copy(spec_packed)
         if tick is not None:
             tick.note_dispatch("unified")
-            tick.mark("dispatch")
+            tick.mark("dispatch", **dispatch_meta)
         logger.debug(
             "unified dispatch: %d decode lanes + %d prefill tokens "
             "+ %d verify segments (%d chunks, %d final) S=%d K=%d",
@@ -5414,18 +5440,20 @@ class JaxEngine:
             if ev.tokens:
                 self._tokens_generated += len(ev.tokens)
                 self.obs.tokens.inc(len(ev.tokens))
-                if not ev.seq.slo_noted:
-                    # first token: hand the SLO plane this request's
-                    # queue-wait (arrival -> admission) vs service
-                    # (admission -> first commit) decomposition, the
+                ev.seq.token_commits += 1
+                if not ev.seq.first_token_s:
+                    # first token: the service leg (first admission ->
+                    # here) ends; observed once per request, and the SLO
+                    # plane gets the same queue-wait / service split, the
                     # attribution a TTFT miss is classified with
-                    ev.seq.slo_noted = True
+                    seq = ev.seq
+                    seq.first_token_s = now_m = time.monotonic()
+                    adm = seq.admitted_s or now_m
+                    self.obs.first_token_service.observe(now_m - adm)
                     if slo.tracker.enabled:
-                        now_m = time.monotonic()
-                        adm = ev.seq.admitted_s or now_m
                         slo.tracker.note_first_token(
-                            ev.seq.request_id,
-                            queue_s=adm - ev.seq.arrival_s,
+                            seq.request_id,
+                            queue_s=adm - seq.arrival_s,
                             service_s=now_m - adm,
                         )
             if ev.completed_blocks and pool is None:
@@ -5467,8 +5495,6 @@ class JaxEngine:
                         "drafter": st.kind,
                         "auto_disabled": st.auto_disabled,
                     }
-                    from ..runtime import tracing
-
                     if tracing.collector.enabled:
                         with tracing.span(
                             "engine.spec", ev.seq.request_id
@@ -5483,6 +5509,44 @@ class JaxEngine:
                 queue.put_nowait(None)
                 if pool is None:
                     self._publish_removed(ev.seq)
+                if tracing.collector.enabled:
+                    self._record_request_spans(ev.seq)
+
+    def _record_request_spans(self, seq: SeqState) -> None:
+        """Write a finished request's stamps out as spans (tracing on
+        only; once per request): ``engine.request`` from arrival to now
+        under the request id's binding (``http.request`` / ingress), and
+        under it the stages that tile it -- ``engine.queue``,
+        ``engine.prefill``, ``engine.decode``, one ``engine.preempted``
+        per interval.  Nothing is recorded while the request is served:
+        the stamps are floats on its ``SeqState``."""
+        if seq.stages_recorded:
+            return
+        seq.stages_recorded = True
+        end_s = time.monotonic()
+        rid = seq.request_id
+        root = tracing.record_span(
+            "engine.request", rid, seq.arrival_s, end_s,
+            prompt_tokens=len(seq.prompt) - seq.prior_generated,
+            cached_tokens=seq.cached_prompt_tokens,
+            output_tokens=seq.prior_generated + seq.num_generated,
+            dispatches=max(seq.prefill_chunks - 1, 0) + seq.token_commits,
+            preemptions=len(seq.preempted) + bool(seq.preempted_at),
+            finish=seq.finish.value if seq.finish is not None else None,
+        )
+        for stage, lo, hi in seq.stage_segments(end_s):
+            attrs: Dict[str, Any] = {}
+            if stage == "prefill":
+                attrs = {
+                    "chunks": seq.prefill_chunks,
+                    "prompt_tokens_computed": seq.prefill_tokens,
+                    "cached": seq.cached_prompt_tokens,
+                    "kv_prefetch_hits": seq.prefetch_hits,
+                    "mixed": seq.prefill_mixed,
+                }
+            tracing.record_span(
+                "engine." + stage, rid, lo, hi, parent=root, **attrs
+            )
 
     def _emit_kv_event(self, event: Dict[str, Any]) -> None:
         """PagePool event_sink -> the externally-wired kv_event_sink.

@@ -196,15 +196,65 @@ class SeqState:
     # during queue wait (engine._note_prefetch_admission; span attr +
     # dynamo_kv_prefetch_hits)
     prefetch_hits: int = 0
-    # SLO attainment plane (runtime/slo.py): admission stamp closing the
-    # queue-wait leg, and a once-only latch for the first-token
-    # queue/service decomposition note
+    # request stages (time.monotonic(), like arrival_s; 0.0 = not yet):
+    # when the process received the request (the Context's stamp), the
+    # FIRST admission (a re-admission after preemption keeps it), the first
+    # token committed, and the intervals spent preempted (queued again
+    # after losing the lane).  Read once per request: the stage histograms
+    # (runtime/metrics.py), the SLO plane's first-token note, and -- with
+    # tracing on -- the engine.* spans written when the request finishes.
+    created_s: float = 0.0
     admitted_s: float = 0.0
-    slo_noted: bool = False
+    first_token_s: float = 0.0
+    preempted_at: float = 0.0
+    preempted: List[Tuple[float, float]] = field(default_factory=list)
+    # span attributes: prefill dispatches that carried this prompt, the
+    # prompt tokens they computed, commits that brought tokens, and whether
+    # the prompt rode the unified mixed dispatch
+    prefill_chunks: int = 0
+    prefill_tokens: int = 0
+    token_commits: int = 0
+    prefill_mixed: bool = False
+    stages_recorded: bool = False
 
     @property
     def seq_len(self) -> int:
         return len(self.prompt) + self.num_generated
+
+    def note_prefill(self, tokens: int) -> None:
+        """One prefill dispatch carried ``tokens`` of this prompt."""
+        self.prefill_chunks += 1
+        self.prefill_tokens += tokens
+
+    def stage_segments(self, end_s: float) -> List[Tuple[str, float, float]]:
+        """``(stage, start, end)`` pieces that tile arrival -> ``end_s``:
+        ``queue`` to the first admission, ``prefill`` to the first token,
+        ``decode`` after it, each cut around the ``preempted`` intervals
+        (one still open at ``end_s`` closes there).  Stages a request never
+        reached are absent."""
+        adm = self.admitted_s or end_s
+        first = self.first_token_s or end_s
+        stages = [
+            ("queue", self.arrival_s, adm),
+            ("prefill", adm, first),
+            ("decode", first, end_s),
+        ]
+        gaps = list(self.preempted)
+        if self.preempted_at:
+            gaps.append((self.preempted_at, end_s))
+        out: List[Tuple[str, float, float]] = []
+        for name, lo, hi in stages:
+            for g_lo, g_hi in gaps:
+                g_lo, g_hi = max(g_lo, lo), min(g_hi, hi)
+                if g_lo >= g_hi:
+                    continue
+                if g_lo > lo:
+                    out.append((name, lo, g_lo))
+                out.append(("preempted", g_lo, g_hi))
+                lo = g_hi
+            if hi > lo:
+                out.append((name, lo, hi))
+        return out
 
     @classmethod
     def from_request(cls, request_id: str, req: PreprocessedRequest, block_size: int) -> "SeqState":
@@ -485,10 +535,17 @@ class Scheduler:
         seq.owned_pages = onboard + fresh
         seq.pages = cached_pages + fresh
         seq.slot = slot
-        # SLO queue-wait/service decomposition stamp (runtime/slo.py):
-        # admission ends the queue-wait leg; re-admissions after
-        # preemption re-stamp (the first-token note fires only once)
-        seq.admitted_s = time.monotonic()
+        # the first admission ends the queue-wait leg (observed here, once
+        # per request); a re-admission after preemption keeps that stamp
+        # and closes the preempted interval instead
+        now = time.monotonic()
+        if not seq.admitted_s:
+            seq.admitted_s = now
+            if self.metrics is not None:
+                self.metrics.queue_wait.observe(now - seq.arrival_s)
+        elif seq.preempted_at:
+            seq.preempted.append((seq.preempted_at, now))
+            seq.preempted_at = 0.0
         self.slots[slot] = seq
         self._write_slot_arrays(seq)
         self._queue_prompt_registrations(seq)
@@ -848,6 +905,7 @@ class Scheduler:
         seq.prior_generated += seq.num_generated
         seq.num_generated = 0
         seq.slot = -1
+        seq.preempted_at = time.monotonic()
         if swapped:
             # parked exactly like a disagg external lane: holds pages at
             # admission, stays device-inactive until the engine's swap-in

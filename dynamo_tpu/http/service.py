@@ -15,6 +15,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
+import time
 from typing import AsyncIterator, Dict, Optional
 
 from ..protocols.openai import (
@@ -480,6 +481,7 @@ class HttpService:
             )
 
     async def _serve(self, req: Request, chat: bool) -> Response:
+        received_s = time.monotonic()  # handler entry: before any parsing
         endpoint = "chat_completions" if chat else "completions"
         # shed BEFORE parsing: overload rejection must stay O(1)
         if not self.admission.try_acquire():
@@ -509,6 +511,9 @@ class HttpService:
             raise
 
         request = Context.new(parsed)
+        # the request's time in this process counts from the handler's
+        # entry (parse and template included), not from the envelope
+        request.ctx.created_s = received_s
         guard = self.metrics.guard(parsed.model, endpoint, request.id)
         # Deadline budget: armed here at the edge, it rides the codec
         # headers hop by hop; the local watchdog kills the request context

@@ -31,6 +31,7 @@ from ..protocols.openai import (
     new_response_id,
     usage_block,
 )
+from ..runtime import tracing
 from ..runtime.engine import Annotated, AsyncEngine, Context, as_response_stream
 from ..runtime.pipeline import Operator
 from .tokenizer import Tokenizer
@@ -267,6 +268,13 @@ class OpenAIPreprocessor(Operator):
         req = request.data
         is_chat = isinstance(req, ChatCompletionRequest)
         pre = self.preprocess(req)
+        if tracing.collector.enabled:
+            # handler entry (the context's stamp) -> the engine call:
+            # parse, template, tokenize; a child of http.request
+            tracing.record_span(
+                "http.preprocess", request.id, request.created_s,
+                time.monotonic(), prompt_tokens=len(pre.token_ids),
+            )
         stream = await as_response_stream(next, request.replace(pre.to_dict()))
 
         rid = new_response_id("chatcmpl" if is_chat else "cmpl")

@@ -72,6 +72,7 @@ class AsyncEngineContext:
 
     __slots__ = (
         "_id", "_stopped", "_killed", "_complete", "_children", "_deadline",
+        "created_s",
     )
 
     def __init__(self, request_id: Optional[str] = None) -> None:
@@ -81,6 +82,10 @@ class AsyncEngineContext:
         self._complete = asyncio.Event()
         self._children: list["AsyncEngineContext"] = []
         self._deadline: Optional[float] = None  # absolute time.monotonic()
+        # when this process received the request (time.monotonic()): a
+        # context is made by the HTTP handler or by request-plane ingress,
+        # and the engine measures its ingress time from here
+        self.created_s = time.monotonic()
 
     @property
     def id(self) -> str:
@@ -158,6 +163,10 @@ class Context(Generic[T]):
     @property
     def id(self) -> str:
         return self.ctx.id
+
+    @property
+    def created_s(self) -> float:
+        return self.ctx.created_s
 
     def map(self, fn: Callable[[T], U]) -> "Context[U]":
         """Transform the payload while preserving id/context/metadata."""

@@ -44,6 +44,12 @@ STEP_LATENCY_BUCKETS = (
     0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
     1.0, 2.5, 5.0,
 )
+# a request stage spans ~100us (ingress of a tokenized prompt) to tens of
+# seconds (queue wait under overload)
+STAGE_BUCKETS = (
+    1e-4, 5e-4, 1e-3, 2.5e-3, 5e-3, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
+    1.0, 2.5, 5.0, 10.0, 30.0,
+)
 # Disagg KV export/upload legs (multi-MB device->host->wire moves).
 TRANSFER_LATENCY_BUCKETS = (
     0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
@@ -280,6 +286,28 @@ class EngineMetrics:
         self.multistep_k = reg.gauge(
             "dynamo_engine_multistep_k",
             "Decode steps fused into the last packed unified dispatch",
+        )
+        # request stages (ISSUE 26): a request's time to its first token,
+        # split where each leg ends and observed once per request --
+        # ingress (the process received it -> the engine's queue), queue
+        # wait (-> first admission), first-token service (-> first token
+        # committed).  Always on: three observes per request.
+        self.ingress = reg.histogram(
+            "dynamo_engine_ingress_seconds",
+            "Process received the request to the engine's queue (parse, "
+            "template, tokenize, backend, hop)",
+            buckets=STAGE_BUCKETS,
+        )
+        self.queue_wait = reg.histogram(
+            "dynamo_engine_queue_wait_seconds",
+            "Engine queue arrival to first admission into the batch",
+            buckets=STAGE_BUCKETS,
+        )
+        self.first_token_service = reg.histogram(
+            "dynamo_engine_first_token_service_seconds",
+            "First admission to first token committed (prefill chunks and "
+            "the steps they rode in)",
+            buckets=STAGE_BUCKETS,
         )
         if max_slots:
             self.slots.set(max_slots)

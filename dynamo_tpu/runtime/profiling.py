@@ -15,10 +15,25 @@ module is that measurement:
   Completed records land in a bounded ring and feed
   ``dynamo_tick_phase_seconds{phase}`` histograms, a
   ``dynamo_tick_host_occupancy`` gauge (host time / tick wall), and
-  ``dynamo_tick_dispatch_gap_seconds`` -- the host-observed gap between
-  the previous dispatch's results landing and the next dispatch being
-  enqueued, the exact quantity ROADMAP item 2 ("attack the host-side
-  tick loop") optimizes.
+  ``dynamo_tick_dispatch_gap_seconds`` -- the host-observed time from the
+  previous dispatch's results landing to the next dispatch being
+  enqueued, recorded as zero whenever another dispatch is already queued
+  on the device.  It is NOT a bound on device idle: the device can idle
+  while the host believes it is waiting for it (read the idle time by
+  host phase from a device trace instead, below).
+
+* Tick phases on the device trace's clock: while the profiler is enabled,
+  every phase interval a mark closes is also a
+  ``jax.profiler.TraceAnnotation`` named ``dyn.tick`` carrying
+  ``phase=<name>`` (a ``dispatch`` interval of a packed dispatch also
+  carries the lanes' fresh query tokens ``q``, context lengths ``ctx``,
+  the fused step count ``k`` and the packed rows ``np``), and the
+  interval the loop is parked on its wake event is ``dyn.parked``.  A
+  ``jax.profiler`` trace taken meanwhile (``POST /profile/device``) holds
+  them beside the device's operations on one timeline, so each idle gap
+  of the device can be put down to what the host was doing.  Inside a
+  trace an annotation costs under a microsecond; outside one, a static
+  check (~80 ns a mark); with the profiler disabled, nothing.
 
 * :class:`FlightRecorder` -- on-demand snapshots of the last-N tick
   records, recent SLO violations, and registered component state (engine
@@ -47,6 +62,7 @@ timeline (``GET /profile/ticks``, ``python -m dynamo_tpu profile``).
 from __future__ import annotations
 
 import collections
+import logging
 import os
 import threading
 import time
@@ -54,6 +70,8 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
 from . import tracing
+
+logger = logging.getLogger("dynamo.profiling")
 
 # Phase-duration buckets: a tick phase spans ~10us (a no-op plan pass) to
 # ~100ms+ (a huge prefill's device wait).
@@ -88,7 +106,7 @@ class TickRecord:
     dispatches: Dict[str, int] = field(default_factory=dict)
     # host-observed dispatch gap(s) closed this tick: seconds between the
     # previous dispatch's results materializing on host and the next
-    # dispatch being enqueued (upper bound on true device idle)
+    # dispatch being enqueued (zero when another was already queued)
     gap_s: float = 0.0
     n_gaps: int = 0
 
@@ -152,12 +170,43 @@ class TickRecord:
         return out
 
 
+TICK_ANNOTATION = "dyn.tick"
+PARKED_ANNOTATION = "dyn.parked"
+_trace_annotation: Any = None  # jax.profiler.TraceAnnotation, or False
+
+
+def annotate(name: str) -> Any:
+    """An OPEN ``jax.profiler.TraceAnnotation`` (close it with
+    ``__exit__``), or ``None`` while no ``jax.profiler`` trace is being
+    taken (one static check: an annotation opened outside a trace would
+    not be recorded anyway) and where JAX is absent.  Imported on first
+    use and only by a caller that profiles: a process that never enables
+    the profiler never imports JAX through here, and the import starts no
+    backend."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _trace_annotation = TraceAnnotation
+        except Exception as e:
+            logger.debug("no jax.profiler.TraceAnnotation here: %s", e)
+            _trace_annotation = False
+    if not _trace_annotation or not _trace_annotation.is_enabled():
+        return None
+    ann = _trace_annotation(name)
+    ann.__enter__()
+    return ann
+
+
 class _Tick:
     """One in-progress tick: phase marks accumulate elapsed time since the
     previous mark.  Produced by :meth:`TickProfiler.begin_tick`; closed by
     :meth:`TickProfiler.finish_tick` (or dropped via ``discard``)."""
 
-    __slots__ = ("profiler", "record", "_last_ns", "_start_ns", "discarded")
+    __slots__ = (
+        "profiler", "record", "_last_ns", "_start_ns", "discarded", "_ann",
+    )
 
     def __init__(self, profiler: "TickProfiler", idx: int) -> None:
         self.profiler = profiler
@@ -165,14 +214,28 @@ class _Tick:
         self._start_ns = time.perf_counter_ns()
         self._last_ns = self._start_ns
         self.discarded = False
+        # the open interval, as an annotation in the jax.profiler trace
+        self._ann = annotate(TICK_ANNOTATION)
 
-    def mark(self, phase: str) -> None:
+    def mark(self, phase: str, **meta: Any) -> None:
         """Attribute time since the previous mark (or tick start) to
-        ``phase``.  Phases may repeat; durations accumulate."""
+        ``phase``.  Phases may repeat; durations accumulate.  The same
+        interval closes in the device trace as a ``dyn.tick`` annotation
+        with ``phase`` and ``meta`` (a mark names the phase that just
+        ended, so the name is given as the interval closes)."""
+        self._close(phase, **meta)
+        self._ann = annotate(TICK_ANNOTATION)
+
+    def _close(self, phase: str, **meta: Any) -> None:
+        """End the open interval under ``phase``; none opens after it."""
         now = time.perf_counter_ns()
         phases = self.record.phases
         phases[phase] = phases.get(phase, 0.0) + (now - self._last_ns) * 1e-9
         self._last_ns = now
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.set_metadata(phase=phase, **meta)
+            ann.__exit__(None, None, None)
 
     def note_dispatch(self, kind: str) -> None:
         """A device dispatch was just enqueued: count it and close the
@@ -201,6 +264,7 @@ class _Tick:
 
     def discard(self) -> None:
         self.discarded = True
+        self._close("other")
 
 
 class TickProfiler:
@@ -258,7 +322,7 @@ class TickProfiler:
         not flood the ring with no-op records."""
         if tick.discarded:
             return
-        tick.mark("other")
+        tick._close("other")
         rec = tick.record
         rec.wall_s = (time.perf_counter_ns() - tick._start_ns) * 1e-9
         if not rec.dispatches and "device_wait" not in rec.phases:
@@ -304,8 +368,9 @@ class TickProfiler:
 
         rtm.default_registry().histogram(
             "dynamo_tick_dispatch_gap_seconds",
-            "Host-observed gap between a dispatch's results landing and "
-            "the next dispatch being enqueued (upper bound on device idle)",
+            "Host-observed time from a dispatch's results landing to the "
+            "next dispatch being enqueued; zero whenever a dispatch is "
+            "already queued",
             buckets=PHASE_BUCKETS,
         ).observe(max(gap_s, 0.0))
 
@@ -336,18 +401,6 @@ class TickProfiler:
         with self._lock:
             recs = list(self._ring)
         return recs[-last:] if last else recs
-
-    def recent_host_occupancy(self, last: int = 32) -> Optional[float]:
-        """Mean host occupancy over the last ``last`` completed ticks, or
-        ``None`` when nothing has been profiled (disabled profiler, cold
-        ring).  The adaptive multi-step decode controller's signal
-        (engine ``_multistep_plan_k``): a host-bound loop (occupancy near
-        1) is exactly the condition K amortizes, so the controller jumps
-        straight to its ceiling instead of ramping."""
-        recs = self.records(last)
-        if not recs:
-            return None
-        return sum(r.host_occupancy for r in recs) / len(recs)
 
     def summary(self) -> Dict[str, Any]:
         """Aggregate over the ring: per-phase totals + fractions of host
@@ -420,7 +473,10 @@ async def capture_device_trace(
     duration_s: float, log_dir: Optional[str] = None
 ) -> Dict[str, Any]:
     """Bounded-duration ``jax.profiler`` device trace (``POST
-    /profile/device``).  Degrades gracefully: on CPU-only stacks (or with
+    /profile/device``) with the tick profiler on meanwhile: device
+    operations and ``dyn.tick`` phases on one timeline (TensorBoard,
+    Perfetto, ``benchmark/trace_host.py``).  Degrades gracefully: on
+    CPU-only stacks (or with
     jax absent / a capture already running) it returns ``ok=False`` with
     the reason instead of raising -- profiling must never take a serving
     process down."""
@@ -435,12 +491,22 @@ async def capture_device_trace(
     try:
         import jax
 
-        jax.profiler.start_trace(log_dir)
+        # device operations and level-1 host annotations, no Python
+        # tracer: tracing Python slows the host the engine shares
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(log_dir, profiler_options=opts)
     except Exception as e:
         return {"ok": False, "error": f"device trace unavailable: {e}"}
+    # the tick profiler is held on for the capture, so the trace holds the
+    # dyn.tick phases and dyn.parked beside the device's operations
+    was_enabled = profiler.enabled
+    profiler.enable()
     try:
         await asyncio.sleep(duration_s)
     finally:
+        profiler.enabled = was_enabled
         try:
             jax.profiler.stop_trace()
         except Exception as e:

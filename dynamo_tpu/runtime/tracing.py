@@ -301,6 +301,39 @@ class span:
             self._span.attrs.update(attrs)
 
 
+def record_span(
+    name: str,
+    request_id: str,
+    start_s: float,
+    end_s: float,
+    parent: Optional[TraceContext] = None,
+    **attrs: Any,
+) -> Optional[TraceContext]:
+    """Record a span from a start and an end already taken (both
+    ``time.monotonic()``): the stamps a request collects while it is served
+    are written out once, when it finishes, so the hot path never opens a
+    span.  Parent: ``parent`` if given, else the request id's binding (the
+    ``http.request`` / ingress span).  Returns the span's context, for its
+    children; ``None`` (and nothing recorded) when tracing is disabled."""
+    if not collector.enabled:
+        return None
+    if parent is None and request_id:
+        parent = collector.binding(request_id)
+    sp = Span(
+        name=name,
+        request_id=request_id,
+        start_s=start_s,
+        end_s=max(end_s, start_s),
+        trace_id=parent.trace_id if parent is not None else _new_id(),
+        span_id=_new_id(),
+        parent_span_id=parent.span_id if parent is not None else "",
+        component=collector.component,
+        attrs=attrs,
+    )
+    collector.record(sp)
+    return TraceContext(sp.trace_id, sp.span_id)
+
+
 def chrome_trace(span_dicts: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
     """Chrome-trace ("Trace Event Format") JSON object from span dicts
     (``Span.to_dict`` output, possibly merged from several processes).
