@@ -734,6 +734,73 @@ def packed_layout(Np: int, s_max: int, B: int, wide: int | None = None):
     return lens, off, lane, rel
 
 
+def moe_grouped_rows(args, jax) -> None:
+    """The expert MLP both ways at Mixtral widths, by packed rows N: the
+    capacity buffers ``[E, C = N, H]`` against the grouped product over the
+    ``N*K`` routed rows (``ops.grouped_matmul``; half the rows masked as a
+    packed step's padding is, too).  This is the measurement
+    ``model._GROUPED_MIN_ROWS`` is set from: the smallest N from which the
+    grouped path is the faster by more than 2%."""
+    import contextlib
+
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental.pallas import tpu as pltpu
+
+    from dynamo_tpu.engine import ModelConfig
+    from dynamo_tpu.engine import attention as att
+    from dynamo_tpu.engine import model as M
+
+    on_tpu = att._on_tpu
+    if args.rehearse:
+        widths, dtype, rows = dict(hidden_size=128, intermediate_size=256), "float32", (16, 128)
+        att._on_tpu = lambda: True  # the kernel itself, interpreted
+        kernel_mode = pltpu.force_tpu_interpret_mode
+    else:
+        widths, dtype = dict(hidden_size=4096, intermediate_size=14336), "bfloat16"
+        rows, kernel_mode = (32, 64, 128, 256, 512, 1024), contextlib.nullcontext
+    cfg = ModelConfig(
+        vocab_size=256, num_layers=1, num_heads=32, num_kv_heads=8, head_dim=128,
+        dtype=dtype, num_experts=8, num_experts_per_tok=2, moe_capacity_factor=4.0,
+        **widths,
+    )
+    params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
+    lp = jax.tree.map(lambda a: a[0], params.pop("layers"))
+    set_at, table = M._GROUPED_MIN_ROWS, []
+
+    def timed(threshold, x, valid):
+        M._GROUPED_MIN_ROWS = threshold  # a fresh jit traces the path anew
+        f = jax.jit(lambda l, y, v: M._moe_mlp(l, y, cfg, v))
+        with kernel_mode():
+            out = jax.block_until_ready(f(lp, x, valid))
+            t0 = time.perf_counter()
+            for _ in range(1 if args.rehearse else 10):
+                out = f(lp, x, valid)
+            jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / (1 if args.rehearse else 10) * 1e3, out
+
+    try:
+        for n in rows:
+            x = jax.random.normal(jax.random.PRNGKey(n), (1, n, cfg.hidden_size),
+                                  jnp.dtype(dtype))
+            half = (jnp.arange(n) < n // 2)[None]
+            cap_ms, cap = timed(1 << 30, x, None)
+            grp_ms, grp = timed(1, x, None)
+            half_ms, _ = timed(1, x, half)
+            cap, grp = np.asarray(cap, np.float32), np.asarray(grp, np.float32)
+            err = float(np.max(np.abs(cap - grp)) / max(np.max(np.abs(cap)), 1e-9))
+            table.append(dict(N=n, capacity_ms=round(cap_ms, 3), grouped_ms=round(grp_ms, 3),
+                              grouped_half_masked_ms=round(half_ms, 3),
+                              max_rel_diff=round(err, 5)))
+            if not np.isfinite(grp).all() or err > TOLERANCE[dtype]:
+                emit(phase="kernels", failed=table[-1], tolerance=TOLERANCE[dtype])
+                sys.exit(1)
+    finally:
+        M._GROUPED_MIN_ROWS, att._on_tpu = set_at, on_tpu
+    emit(phase="kernels", moe_grouped=table, grouped_min_rows=set_at,
+         compiled=not args.rehearse)
+
+
 def child_kernels(args) -> None:
     jax = child_devices(args.rehearse, 1)
     import math
@@ -883,6 +950,7 @@ def child_kernels(args) -> None:
     emit(phase="kernels", tolerance=tol, compiled=not interp,
          max_abs_err_table=rows)
     del pool, qpool
+    moe_grouped_rows(args, jax)
 
     # the engine's own packed steps, lowered as the engine calls them: on
     # the chip the executable must embed the kernel, not the XLA fallback
@@ -1029,6 +1097,9 @@ def main(argv=None) -> int:
     sys.path.insert(0, ROOT)
     if args.child == "kernels":
         child_kernels(args)
+        return 0
+    if args.child == "moe-grouped":  # that line of the kernels child, alone
+        moe_grouped_rows(args, child_devices(args.rehearse, 1))
         return 0
     if args.child == "shard-evidence":
         child_shard_evidence(args)

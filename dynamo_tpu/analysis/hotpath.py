@@ -122,6 +122,14 @@ HOT_PATH_MANIFEST: Dict[str, List[str]] = {
         "packed_ragged_attention*",
         "_packed_kernel",
     ],
+    # the dropless expert MLP's grouped product, three launches a layer of
+    # every mixed step of a no-drop MoE engine (model._moe_grouped)
+    "dynamo_tpu/ops/grouped_matmul.py": [
+        "grouped_matmul",
+        "_grouped_matmul_pallas",
+        "group_visits",
+        "_kernel",
+    ],
     # offload-plane hot paths: the admission-time tier lookup runs on the
     # event loop and the host-ring put sits behind every eviction -- a
     # host sync or recompile hazard in these stalls admission or the

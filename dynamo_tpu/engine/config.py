@@ -33,8 +33,12 @@ class ModelConfig:
     # MoE (Mixtral-style); num_experts == 0 means dense MLP
     num_experts: int = 0
     num_experts_per_tok: int = 2
-    # per-expert buffer headroom over perfect balance (GShard capacity
-    # factor); assignments past capacity are dropped
+    # headroom of the capacity path's per-expert buffers over perfect
+    # balance (GShard capacity factor): C = ceil(N*K*factor/E) rows an
+    # expert, assignments past capacity are dropped.  At E/K or above C
+    # holds every token, which means "no drop": a single-device engine then
+    # serves its wider steps through the dropless grouped product, which
+    # has no capacity (model._moe_mlp reads the path off the input)
     moe_capacity_factor: float = 2.0
     # Gemma-family switches: RMSNorm multiplies by (1 + w), the MLP uses
     # tanh-approximated GELU, and embeddings scale by sqrt(hidden)

@@ -186,22 +186,127 @@ def _moe_mlp_dense(lp: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     return out.reshape(orig_shape)
 
 
-def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    """Capacity-based sparse MoE dispatch (GShard/Switch pattern).
+# Rows N of a step at and above which a no-drop expert MLP takes the grouped
+# product.  Set from chip_smoke.py's "moe_grouped" line (one v5e, Mixtral
+# widths, the whole expert MLP of one layer, ms; my chip run, PR 27):
+#      N   capacity  grouped
+#     32     3.844    3.932    both stream all eight experts' weights once,
+#    128     3.900    4.013    and the buffers need no sort
+#    256     4.384    4.042    C passes the ridge (about 240 rows): -7.8%
+#    512     8.114    4.262
+#   1024    16.078    5.946    (4.593 with half the rows masked as padding)
+_GROUPED_MIN_ROWS = 256
 
-    Tokens are routed top-k, packed into fixed [E, C, H] per-expert buffers
-    (C = capacity), each expert runs a batched matmul over its buffer, and
-    the combine scatters results back weighted by the router.  Compute is
-    O(N*K*capacity_factor) instead of dense-dispatch O(N*E), shapes are
-    static (jit), and the leading E axis of the buffers/weights shards over
-    the ``ep`` mesh axis -- GSPMD turns the pack/unpack into an all_to_all
-    over ICI (SURVEY.md 2.8: EP is first-party here, engine-internal in the
-    reference).
+_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
-    Assignments that overflow an expert's capacity are dropped (their
-    combine weight contributes nothing), the standard GShard behavior; the
-    default capacity factor leaves headroom so drops need an adversarially
-    skewed batch.
+
+def _moe_capacity(cfg: ModelConfig, N: int) -> int:
+    """Rows of an expert's capacity buffer for a step of N rows: perfect
+    balance is N*K/E; ``moe_capacity_factor`` leaves headroom."""
+    K, E = cfg.num_experts_per_tok, cfg.num_experts
+    C = int(max(1, -(-N * K * cfg.moe_capacity_factor // E)))
+    return min(C, N * K)
+
+
+def _moe_takes_grouped(lp: Params, N: int, C: int) -> bool:
+    """Trace-time choice of the expert MLP's layout, read off the input.
+
+    Grouped product (`ops.grouped_matmul`) when the capacity asked for
+    holds every token (``C >= N``: no assignment can drop, so the buffers
+    would compute the dropless result and the grouped product computes
+    the same one over ``N*K`` rows instead of ``E*N``), the step is at
+    least ``_GROUPED_MIN_ROWS`` rows, and the trace runs on one device.
+    Capacity buffers otherwise: ``C < N`` asks for GShard's drops; on a
+    mesh the buffers' leading E axis is what GSPMD shards over ``ep``
+    (and a Mosaic kernel cannot be partitioned); int8 expert weights
+    dequantize inside the einsum's read, which a kernel operand cannot;
+    and on the chip the kernel wants widths that tile to 128 lanes."""
+    from ..ops.grouped_matmul import kernel_fits
+    from .attention import _context_mesh, _on_tpu
+    from .quant import QuantizedTensor
+
+    w = lp["w_gate"]
+    if C < N or N < _GROUPED_MIN_ROWS or _context_mesh() is not None:
+        return False
+    if isinstance(w, QuantizedTensor):
+        return False
+    return not _on_tpu() or kernel_fits(w.shape[-2], w.shape[-1])
+
+
+def _moe_grouped(
+    lp: Params,
+    xf: jax.Array,  # [N, H]
+    topw: jax.Array,  # [N, K] combine weights
+    topi: jax.Array,  # [N, K] expert of each assignment
+    row_valid: Optional[jax.Array],  # [N] bool, or None: every row counts
+    layer: Optional[jax.Array],  # index, where lp holds the layers' stack
+) -> jax.Array:
+    """The dropless expert MLP over the ``N*K`` routed rows, sorted by
+    expert: three grouped products, each row against its own expert's
+    matrix.  Rows a step marks invalid (padding of a packed dispatch) are
+    sorted behind the last group, where the kernel never goes, and come
+    back zero."""
+    from ..ops.grouped_matmul import _ROW_TILE, grouped_matmul
+    from .attention import _on_tpu
+
+    product = partial(grouped_matmul, layer=layer, kernel=_on_tpu())
+    N, K = topi.shape
+    E = lp["w_gate"].shape[-3]
+    key = topi.reshape(-1)  # [N*K] expert id per assignment
+    if row_valid is not None:
+        key = jnp.where(jnp.repeat(row_valid, K), key, E)
+    # place of each assignment in expert order (stable), from the same
+    # running count the capacity path slots with; class E holds the invalid
+    onehot = jax.nn.one_hot(key, E + 1, dtype=jnp.int32)  # [NK, E+1]
+    counts = jnp.sum(onehot, axis=0)
+    slot = jnp.sum(jnp.cumsum(onehot, axis=0) * onehot, axis=1) - 1
+    dest = (jnp.cumsum(counts) - counts)[key] + slot  # a permutation of NK
+    M = -(-N * K // _ROW_TILE) * _ROW_TILE  # whole row tiles, no pad copy
+    order = jnp.zeros((M,), jnp.int32).at[dest].set(
+        jnp.arange(N * K, dtype=jnp.int32), unique_indices=True
+    )
+    rows = xf[order // K]  # [M, H]; rows past N*K repeat token 0, unread
+    sizes = counts[:E]
+    gate = jax.nn.silu(product(rows, lp["w_gate"], sizes))
+    up = product(rows, lp["w_up"], sizes)
+    down = product(gate * up, lp["w_down"], sizes)  # [M, H]
+    per_assign = down[dest].reshape(N, K, -1)  # un-sort
+    if row_valid is not None:  # behind the groups the kernel stored nothing
+        per_assign = jnp.where(row_valid[:, None, None], per_assign, 0)
+    return jnp.sum(per_assign * topw[:, :, None], axis=1)
+
+
+def _moe_mlp(
+    lp: Params,
+    x: jax.Array,
+    cfg: ModelConfig,
+    row_valid: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
+) -> jax.Array:
+    """Sparse MoE MLP: top-k routing, then one of two layouts of the same
+    expert products, chosen at trace time (:func:`_moe_takes_grouped`).
+    ``lp`` holds one layer's expert weights ``[E, ., .]``; where
+    :func:`scan_layers` found that the step takes the grouped product, the
+    layers' stack ``[L, E, ., .]``, with ``layer`` the index into it.
+
+    **Grouped (dropless).**  Where ``cfg.moe_capacity_factor`` is ``E/K``
+    or more the capacity holds every token, nothing can drop, and a
+    single-device step of enough rows multiplies only the ``N*K`` routed
+    rows, grouped by expert (:func:`_moe_grouped`).  ``row_valid`` ([...]
+    bool, the leading shape of ``x``) marks rows whose result nobody
+    reads; only this layout uses it, to skip them.
+
+    **Capacity buffers (GShard/Switch).**  Tokens are packed into fixed
+    [E, C, H] per-expert buffers (C = capacity), each expert runs a
+    batched matmul over its buffer, and the combine scatters results back
+    weighted by the router.  Compute is O(N*K*capacity_factor), shapes are
+    static (jit), and the leading E axis of the buffers/weights shards
+    over the ``ep`` mesh axis -- GSPMD turns the pack/unpack into an
+    all_to_all over ICI (SURVEY.md 2.8: EP is first-party here,
+    engine-internal in the reference).  Assignments that overflow an
+    expert's capacity are dropped (their combine weight contributes
+    nothing), the standard GShard behavior; the default capacity factor
+    leaves headroom so drops need an adversarially skewed batch.
     """
     orig_shape = x.shape
     H = orig_shape[-1]
@@ -214,9 +319,11 @@ def _moe_mlp(lp: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     topw, topi = jax.lax.top_k(router_logits, K)
     topw = jax.nn.softmax(topw, axis=-1).astype(x.dtype)  # [N, K]
 
-    # capacity per expert: perfect balance is N*K/E; leave headroom
-    C = int(max(1, -(-N * K * cfg.moe_capacity_factor // E)))
-    C = min(C, N * K)
+    C = _moe_capacity(cfg, N)
+    if _moe_takes_grouped(lp, N, C):
+        valid = None if row_valid is None else row_valid.reshape(-1)
+        out = _moe_grouped(lp, xf, topw, topi, valid, layer)
+        return out.reshape(orig_shape)
 
     flat_expert = topi.reshape(-1)  # [N*K] expert id per assignment
     flat_w = topw.reshape(-1)  # [N*K]
@@ -271,10 +378,12 @@ def transformer_layer(
     attn_fn: AttnFn,
     kv_pages: jax.Array,  # [L, 2, num_pages, page, Hkv, D]
     layer: jax.Array,  # scalar i32 layer index into kv_pages
+    row_valid: Optional[jax.Array] = None,  # [B, T] bool: rows anyone reads
 ) -> Tuple[jax.Array, jax.Array]:
     """One decoder layer (norm -> attention -> norm -> MLP, residuals).
     Shared by the single-device layer scan and the pipeline-parallel stage
-    loop so the math cannot diverge."""
+    loop so the math cannot diverge.  ``row_valid`` lets the expert MLP
+    skip a packed dispatch's padding rows (:func:`_moe_mlp`)."""
     B, T, _ = x.shape
     D = cfg.head_dim
     h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps, cfg.rms_norm_offset)
@@ -297,7 +406,7 @@ def transformer_layer(
     x = x + attn.reshape(B, T, cfg.num_heads * D) @ mat(lp["wo"])
     h2 = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps, cfg.rms_norm_offset)
     if cfg.is_moe:
-        x = x + _moe_mlp(lp, h2, cfg)
+        x = x + _moe_mlp(lp, h2, cfg, row_valid, layer)
     else:
         x = x + _dense_mlp(lp, h2, cfg.hidden_act)
     return x, kv_pages
@@ -311,6 +420,7 @@ def scan_layers(
     sin: jax.Array,
     cfg: ModelConfig,
     attn_fn: AttnFn,
+    row_valid: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Scan ``transformer_layer`` over the stacked weights.
 
@@ -320,11 +430,22 @@ def scan_layers(
     trunk and the pipeline-parallel stage loop (which passes its
     stage-local weight/KV stacks)."""
     L = kv_pages.shape[0]
+    # Where the expert MLP takes the grouped kernel, the experts' weights
+    # stay whole and the kernel indexes the stack by layer: a custom call
+    # cannot fuse the scan's slice, which would then be a copy of every
+    # expert's matrices in every layer of every step.
+    whole: Params = {}
+    N = x.shape[0] * x.shape[1]
+    if cfg.is_moe and _moe_takes_grouped(lp_stack, N, _moe_capacity(cfg, N)):
+        whole = {k: lp_stack[k] for k in _EXPERT_WEIGHTS}
+        lp_stack = {k: v for k, v in lp_stack.items() if k not in whole}
 
     def layer(carry, scanned):
         x, kv = carry
         lp, idx = scanned
-        x, kv = transformer_layer(lp, x, cos, sin, cfg, attn_fn, kv, idx)
+        x, kv = transformer_layer(
+            {**lp, **whole}, x, cos, sin, cfg, attn_fn, kv, idx, row_valid
+        )
         return (x, kv), None
 
     (x, kv_pages), _ = jax.lax.scan(
@@ -341,8 +462,13 @@ def transformer(
     kv_pages: jax.Array,  # [L, 2, num_pages, page, Hkv, D]
     attn_fn: AttnFn,
     mm: "Optional[Tuple[jax.Array, jax.Array]]" = None,
+    row_valid: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Run the trunk; returns (hidden [.., H], updated kv_pages).
+
+    ``row_valid`` (bool, shaped like ``tokens``) marks the rows whose
+    hidden state anyone reads; a step that pads its rows passes it so the
+    expert MLP can leave the padding out (:func:`_moe_mlp`).
 
     ``mm = (mm_embeds [B, M, H], mm_len [B])`` injects a llava-style soft
     prompt: lane b's first ``mm_len[b]`` positions take rows from
@@ -370,8 +496,10 @@ def transformer(
         x = jnp.where(take[:, :, None], inj, x)
     cos, sin = rope_cos_sin(positions, D, cfg.rope_theta, cfg.rope_scaling)  # [B, T, D]
 
+    if squeeze and row_valid is not None:
+        row_valid = row_valid[:, None]
     x, new_kv_pages = scan_layers(
-        params["layers"], kv_pages, x, cos, sin, cfg, attn_fn
+        params["layers"], kv_pages, x, cos, sin, cfg, attn_fn, row_valid
     )
 
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, cfg.rms_norm_offset)

@@ -595,7 +595,8 @@ def _packed_unified_step(
         return out[None], new_kv
 
     hidden, kv_pages = transformer(
-        params, cfg, tok_flat[None], positions[None], kv_pages, attn_fn
+        params, cfg, tok_flat[None], positions[None], kv_pages, attn_fn,
+        row_valid=valid[None],
     )
     if s_spec > 0:
         rng, spec_sub = jax.random.split(rng)

@@ -182,6 +182,109 @@ def test_flash_prefill_attention_compiles(chip, widths):
     assert "tpu_custom_call" in suffix.as_text()
 
 
+MIXTRAL_MLP = dict(E=8, H=4096, I=14336)
+
+
+@pytest.mark.parametrize(
+    "rows,way",
+    [(2048, "up"), (2048, "down"), (1024, "up"), (1024, "down"), (128, "up")],
+)
+def test_moe_grouped_matmul_compiles(chip, rows, way):
+    """The grouped expert product at Mixtral widths, both ways round, at
+    the routed rows of the (1024, 512) and (512, 256) packed steps: its
+    weight buffers (two blocks spanning all of K) ask for more VMEM than
+    the default limit, and the copies it starts itself are sliced on the
+    column axis, neither of which interpret mode checks."""
+    from dynamo_tpu.ops.grouped_matmul import KERNEL_NAME, _grouped_matmul_pallas
+
+    w = MIXTRAL_MLP
+    K, N = (w["H"], w["I"]) if way == "up" else (w["I"], w["H"])
+    # the layers' stack and a layer index, as scan_layers hands them over
+    compiled = jax.jit(_grouped_matmul_pallas).lower(
+        chip((rows, K), jnp.bfloat16), chip((4, w["E"], K, N), jnp.bfloat16),
+        chip((w["E"],), jnp.int32), chip((), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and KERNEL_NAME in text
+
+
+def test_moe_mlp_takes_the_kernel_on_the_chip(chip, monkeypatch):
+    """A no-drop expert MLP of a mixed step's 1024 rows, compiled as the
+    chip would: three launches of the grouped kernel and no [E, C, .]
+    product; a decode step's 32 rows keep the buffers."""
+    import re
+
+    from dynamo_tpu.engine import attention as att
+    from dynamo_tpu.engine import model as M
+    from dynamo_tpu.engine.config import ModelConfig
+    from dynamo_tpu.ops.grouped_matmul import KERNEL_NAME
+
+    w = MIXTRAL_MLP
+    cfg = ModelConfig(
+        vocab_size=256, hidden_size=w["H"], intermediate_size=w["I"],
+        num_layers=1, num_heads=32, num_kv_heads=8, head_dim=128,
+        dtype="bfloat16", num_experts=w["E"], num_experts_per_tok=2,
+        moe_capacity_factor=4.0,
+    )
+    lp = dict(
+        router=chip((w["H"], w["E"]), jnp.bfloat16),
+        w_gate=chip((w["E"], w["H"], w["I"]), jnp.bfloat16),
+        w_up=chip((w["E"], w["H"], w["I"]), jnp.bfloat16),
+        w_down=chip((w["E"], w["I"], w["H"]), jnp.bfloat16),
+    )
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+
+    def text(n):
+        x = chip((1, n, w["H"]), jnp.bfloat16)
+        valid = chip((1, n), jnp.bool_)
+        return jax.jit(lambda l, y, v: M._moe_mlp(l, y, cfg, v)).lower(
+            lp, x, valid).compile().as_text()
+
+    mixed, decode = text(1024), text(32)
+    assert len(re.findall(rf"%{KERNEL_NAME}[.\d]* = ", mixed)) == 3
+    assert not re.search(r"bf16\[8,1024,(4096|14336)\]", mixed)
+    assert KERNEL_NAME not in decode
+    assert re.search(r"bf16\[8,32,14336\]", decode)
+
+
+def test_scanned_experts_reach_the_kernel_uncopied(chip, monkeypatch):
+    """Inside the scan over layers the kernel reads the experts' weights
+    out of the layers' stack by index.  Handed the scan's own slice it
+    would be fed a copy of all eight matrices before every launch (2.9 ms
+    beside a 1.3 ms launch on the chip, PR 27): no operation of the
+    compiled trunk may produce one layer's ``[E, ., .]`` weights."""
+    import re
+
+    from dynamo_tpu.engine import attention as att
+    from dynamo_tpu.engine import model as M
+    from dynamo_tpu.engine.config import ModelConfig
+    from dynamo_tpu.ops.grouped_matmul import KERNEL_NAME
+
+    w, L, n = MIXTRAL_MLP, 2, 1024
+    cfg = ModelConfig(
+        vocab_size=256, hidden_size=w["H"], intermediate_size=w["I"],
+        num_layers=L, num_heads=32, num_kv_heads=8, head_dim=128,
+        dtype="bfloat16", num_experts=w["E"], num_experts_per_tok=2,
+        moe_capacity_factor=4.0,
+    )
+    shapes = jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
+    stack = jax.tree.map(lambda a: chip(a.shape, a.dtype), shapes["layers"])
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+
+    def trunk(stack, kv, x, cos, sin, valid):
+        attend = lambda q, k, v, kv, layer: (q, kv)  # the MLP is the subject
+        return M.scan_layers(stack, kv, x, cos, sin, cfg, attend, valid)
+
+    text = jax.jit(trunk).lower(
+        stack, chip((L, 2, 8, 16, 8, 128), jnp.bfloat16),
+        chip((1, n, w["H"]), jnp.bfloat16), chip((1, n, 128), jnp.float32),
+        chip((1, n, 128), jnp.float32), chip((1, n), jnp.bool_),
+    ).compile().as_text()
+    assert KERNEL_NAME in text
+    copies = re.findall(r"= bf16\[(?:1,)?8,(?:4096,14336|14336,4096)\]", text)
+    assert not copies, copies
+
+
 @pytest.mark.parametrize(
     "dispatch,tp,dp",
     [("packed", 4, 1), ("decode", 4, 1), ("flash_prefill", 4, 1),

@@ -750,3 +750,159 @@ def test_moe_drop_semantics_exact():
     assert np.abs(sparse[4:]).max() == 0.0
     # and the dense rows are non-trivial, so the comparison is meaningful
     assert np.abs(dense).max() > 1e-3
+
+
+# -- the dropless grouped product (ops.grouped_matmul) ------------------------
+
+
+def _moe_case(name, cfg, n, thr):
+    """(x [1, n, H], layer params) of one routing case."""
+    from dynamo_tpu.engine.model import init_params
+
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    lp = jax.tree.map(lambda a: a[0], params["layers"])
+    x = jax.random.normal(jax.random.PRNGKey(7), (1, n, cfg.hidden_size),
+                          jnp.float32)
+    if name == "one_pair":  # identical tokens: one pair of experts has it all
+        x = jnp.broadcast_to(x[:, :1], x.shape)
+    if name == "empty_expert":  # positive rows against a negative column
+        x = jnp.abs(x)
+        lp = dict(lp, router=lp["router"].at[:, 3].set(-1.0))
+    return x, lp
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all_rows", "masked"])
+@pytest.mark.parametrize("backend", ["ragged_dot", "kernel"])
+@pytest.mark.parametrize(
+    "name,n",
+    [("balanced", 128), ("one_pair", 128), ("empty_expert", 128),
+     ("ragged_rows", 100),  # N*K = 200: not a multiple of the row tile
+     ("at_threshold", 0), ("below_threshold", -1)],
+)
+def test_moe_grouped_matches_dense(name, n, backend, masked, monkeypatch):
+    """The grouped path computes what ``_moe_mlp_dense`` computes, row for
+    row: through ``ragged_dot`` (the CPU's backend) and through the Pallas
+    kernel itself (interpreted), with the padding mask (masked rows come
+    back zero, the others unchanged) and without.  ``below_threshold`` is
+    the capacity path, held to the same answer."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from dynamo_tpu.engine import attention as att
+    from dynamo_tpu.engine import model as M
+
+    thr = M._GROUPED_MIN_ROWS
+    n = n if n > 0 else thr + n
+    # the kernel's widths tile to 128 lanes; ragged_dot takes any
+    widths = dict(hidden_size=128, intermediate_size=256) if backend == "kernel" else {}
+    cfg = ModelConfig.tiny(num_experts=4, num_experts_per_tok=2,
+                           moe_capacity_factor=2.0, **widths)
+    x, lp = _moe_case(name, cfg, n, thr)
+    if name == "empty_expert":
+        logits = np.asarray(x[0] @ lp["router"])
+        assert 3 not in np.argsort(logits, axis=1)[:, -2:]
+    valid = (jnp.arange(n) % 3 != 1)[None] if masked else None
+    dense = np.asarray(M._moe_mlp_dense(lp, x, cfg))
+    if backend == "kernel":
+        monkeypatch.setattr(att, "_on_tpu", lambda: True)
+        with pltpu.force_tpu_interpret_mode():
+            got = np.asarray(M._moe_mlp(lp, x, cfg, valid))
+    else:
+        got = np.asarray(M._moe_mlp(lp, x, cfg, valid))
+    if masked and n >= thr:
+        keep = np.asarray(valid)[0]
+        assert np.abs(got[0, ~keep]).max() == 0.0
+        got, dense = got[:, keep], dense[:, keep]
+    np.testing.assert_allclose(got, dense, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize(
+    "case,want",
+    [("no_drop_one_device", "grouped"), ("kernel_on_tpu", "kernel"),
+     ("drops_asked_for", "capacity"), ("sharded", "capacity"),
+     ("small_n", "capacity"), ("int8_experts", "capacity"),
+     ("tpu_odd_widths", "capacity")],
+)
+def test_moe_path_is_read_off_the_input(case, want, monkeypatch):
+    """Which layout a step takes, told from the traced program and not
+    from a flag: the grouped product (``ragged_dot`` off the chip, the
+    kernel by its name on it) or the ``[E, C, H]`` buffer."""
+    from dynamo_tpu.engine import attention as att
+    from dynamo_tpu.engine import model as M
+    from dynamo_tpu.engine.quant import quantize_tensor
+    from dynamo_tpu.ops.grouped_matmul import KERNEL_NAME
+    from dynamo_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    E, K = 4, 2
+    n = M._GROUPED_MIN_ROWS + 24  # C = n: told apart from H and I
+    factor = 1.0 if case == "drops_asked_for" else 2.0
+    widths = dict(hidden_size=128, intermediate_size=256)
+    if case in ("no_drop_one_device", "tpu_odd_widths"):
+        widths = {}  # tiny's 64 and 128
+    if case == "small_n":
+        n = M._GROUPED_MIN_ROWS - 8
+    cfg = ModelConfig.tiny(num_experts=E, num_experts_per_tok=K,
+                           moe_capacity_factor=factor, **widths)
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    lp = jax.tree.map(lambda a: a[0], params["layers"])
+    if case == "int8_experts":
+        for k in ("w_gate", "w_up", "w_down"):
+            lp[k] = quantize_tensor(lp[k], jnp.float32)
+    if case in ("kernel_on_tpu", "tpu_odd_widths"):
+        monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    x = jnp.zeros((1, n, cfg.hidden_size), jnp.float32)
+
+    def trace():
+        return str(jax.make_jaxpr(lambda l, y: M._moe_mlp(l, y, cfg))(lp, x))
+
+    if case == "sharded":
+        if len(jax.devices()) < 4:
+            pytest.skip("needs >= 4 (virtual) devices")
+        with jax.set_mesh(build_mesh(MeshConfig(ep=4), jax.devices()[:4])):
+            text = trace()
+    else:
+        text = trace()
+    C = min(int(-(-n * K * factor // E)), n * K)
+    buffer = f"f32[{E},{C},{cfg.hidden_size}]"
+    got = ("kernel" if KERNEL_NAME in text
+           else "grouped" if "ragged_dot" in text
+           else "capacity" if buffer in text else "neither")
+    assert got == want, text[-2000:]
+    assert (buffer in text) == (want == "capacity")
+
+
+def test_moe_engine_serves_the_same_tokens_through_both_paths(run, monkeypatch):
+    """A tiny MoE engine's greedy tokens, prefill chunks and decode steps,
+    are the same whether every step takes the capacity buffers or every
+    step the grouped product.  The two engines' configurations differ in a
+    field no step reads, so that the second does not run the first's
+    compiled steps."""
+    from dynamo_tpu.engine import EngineConfig, JaxEngine
+    from dynamo_tpu.engine import model as M
+
+    from tests.test_jax_engine import collect, req
+
+    prompts = [list(range(1, 41)), [9, 8, 7], [5] * 17]
+
+    async def serve(threshold, max_position):
+        monkeypatch.setattr(M, "_GROUPED_MIN_ROWS", threshold)
+        cfg = ModelConfig.tiny(num_experts=4, num_experts_per_tok=2,
+                               moe_capacity_factor=2.0,
+                               max_position=max_position)
+        engine = JaxEngine.random_init(
+            cfg, EngineConfig(max_batch_size=4, max_seq_len=64, page_size=4,
+                              num_pages=64),
+        )
+        try:
+            import asyncio
+
+            got = await asyncio.gather(
+                *[collect(engine, req(p, max_tokens=6)) for p in prompts]
+            )
+            return [g[0] for g in got]
+        finally:
+            await engine.stop()
+
+    capacity = run(serve(1 << 30, 512))
+    grouped = run(serve(1, 513))
+    assert all(len(t) == 6 for t in capacity)
+    assert grouped == capacity
