@@ -1,5 +1,12 @@
 """What a kernel has to do: operations and bytes from shapes, the table of
-peaks, and the least time the chip could take.  No JAX."""
+peaks, and the least time the chip could take.  No JAX.
+
+``peaks``, ``matmul``, ``roofline_seconds`` and ``expert_matmul`` are the
+arithmetic every family shares (``ctx["costs"]``).  The last three functions
+are the Mistral family's counts (dense or expert MLP under
+``intermediate_size``, one attention kernel a layer): the module a
+configuration names under ``"costs"`` (``ctx["model_costs"]``) has these
+three, and both configurations of that family name this one."""
 
 from __future__ import annotations
 
@@ -44,8 +51,9 @@ def expert_matmul(e: int, c: int, h: int, i: int) -> Tuple[float, float]:
 
 def weight_bytes(cfg: Dict[str, Any], dtype_bytes: int = 2) -> float:
     """Bytes of the weights one forward step has to stream: every layer
-    (all experts: buffers of capacity C = N route tokens to all of them at
-    these batch sizes) and the output head; the embedding is a gather."""
+    (all experts: at these batch sizes every expert has a token routed to
+    it, whether the product goes through capacity buffers or is grouped by
+    expert) and the output head; the embedding is a gather."""
     h = cfg["hidden_size"]
     i = cfg["intermediate_size"]
     hq = cfg["num_attention_heads"]
@@ -65,3 +73,12 @@ def kv_bytes_per_token(cfg: Dict[str, Any], dtype_bytes: int = 2) -> float:
     hkv = cfg.get("num_key_value_heads", hq)
     d = cfg.get("head_dim", h // hq)
     return 2.0 * cfg["num_hidden_layers"] * hkv * d * dtype_bytes
+
+
+def forward_passes(op_counts: Dict[str, int], cfg: Dict[str, Any]) -> float:
+    """Forward passes among a trace's device events (``op_counts``: events
+    by operation label): every layer of a pass runs one attention kernel
+    (the packed ragged kernel, or the paged decode kernel of a fused decode
+    step), so passes = attention events / layers."""
+    kernels = sum(n for label, n in op_counts.items() if "attention" in label)
+    return kernels / cfg["num_hidden_layers"]

@@ -3,9 +3,16 @@ chip could take for them (operations and bytes from ``costs.expert_matmul``)
 over the time they took, as measured and with no cap.
 
 A product is told by what it is, not by how fast it ran: a fusion whose
-result is an expert buffer ``bf16[E, C, H or I]`` and one of whose operands
-is the experts' weights (``bf16[..., E, H, I]`` or ``bf16[..., E, I, H]``).
-An elementwise fusion over a buffer of the same shape reads no weights."""
+result is an expert buffer ``bf16[E, C, H or I]``, one of whose operands is
+the experts' weights (``bf16[..., E, H, I]`` or ``bf16[..., E, I, H]``) and
+another a buffer of the same capacity, ``bf16[E, C, H or I]``.  An
+elementwise fusion over a buffer of the same shape reads no weights, and a
+copy of one layer's expert weights out of the layers' stack (a result
+``bf16[E, I, H]``, which reads as a buffer of capacity I) reads no buffer.
+
+The shapes are the Mistral family's (experts under ``intermediate_size``
+and ``num_local_experts``): a configuration of another family brings a
+reader of its own for its products, and does not list its cells here."""
 import re
 import sys
 
@@ -20,7 +27,12 @@ def products(ctx):
     for label, seconds in trace["ops"].items():
         head, sep, operands = trace.get("op_text", {}).get(label, "").partition(" fusion(")
         m = result.search(head.partition(" = ")[2])
-        if sep and m and weights.search(operands):
+        if not (sep and m and weights.search(operands)):
+            continue
+        # the buffer multiplied: the result's E and C over the other width,
+        # and not the operand that was taken for the weights
+        rest = weights.sub("", operands)
+        if re.search(rf"bf16\[{e},{m.group(1)},(?:{h}|{i})\]", rest):
             out.append((label, int(m.group(1)), trace["op_counts"][label], seconds))
     return out
 

@@ -14,6 +14,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import contextlib
+import importlib
 import importlib.util
 import json
 import os
@@ -362,11 +363,23 @@ def read_layer_metrics(bench, cell_name: str, ctx: Dict[str, Any]) -> Dict[str, 
 
 
 def breakdown(ctx: Dict[str, Any]) -> Dict[str, Any]:
+    """The device operations that took most of the traced slice, and its
+    idle seconds by what the host was doing in them."""
+    from benchmark import trace_host  # not at import: it parses a trace
+
     ops = sorted(ctx["trace"].get("ops", {}).items(), key=lambda kv: -kv[1])[:10]
-    phases = (ctx.get("profiler") or {}).get("phase_totals_s", {})
-    gaps = sorted(phases.items(), key=lambda kv: -kv[1])[:10]
     return {"device_ops": [[k, v] for k, v in ops],
-            "idle_gaps": [[k, v] for k, v in gaps]}
+            "idle_gaps": trace_host.idle_gaps(trace_host.table(ctx))}
+
+
+def layer_context(cfg: Dict[str, Any], spec: Dict[str, Any], cell: Dict[str, Any],
+                  **measured: Any) -> Dict[str, Any]:
+    """What a per-layer reader is handed (README, "A per-layer metric"):
+    the cell's files, the arithmetic every family shares (``costs``), the
+    counts of the configuration's own family (``model_costs``: the module
+    its file names under ``"costs"``), and what the run measured."""
+    return {"cfg": cfg, "traffic": spec, "cell": cell, "costs": costs,
+            "model_costs": importlib.import_module(cfg["costs"]), **measured}
 
 
 # -- main --------------------------------------------------------------------------
@@ -439,11 +452,9 @@ async def drive(args, bench, cell, cfg, spec, ready) -> Dict[str, Any]:
         marks["setup_s"] = start - T_START
         stalls.reset()
         if args.trace:
-            # the tick profiler goes on here and not before: while it is on,
-            # the engine's fused-step controller reads the host's share of a
-            # tick from it and may jump to the longest fused step, so a
-            # warm-up with it on can miss the steps in between, which the
-            # window then compiles (PERF.md section 6)
+            # the tick profiler goes on as the window opens, so that its
+            # totals are the window's; the engine behaves the same with it
+            # on or off (PERF.md section 6, PR 26)
             await client.call_json(host, port, "POST", "/profile/ticks",
                                    {"enabled": True, "clear": True})
         await client.call_json(host, port, "POST", "/bench/log_compiles", {"on": True})
@@ -545,17 +556,16 @@ async def drive(args, bench, cell, cfg, spec, ready) -> Dict[str, Any]:
         trace = await client.call_json(
             host, port, "POST", "/bench/trace_reduce",
             {"dump": args.dump_trace or None}, timeout=300)
-        ctx = {
-            "cfg": cfg, "traffic": spec, "cell": cell,
-            "counters": stats.Counters(marks["metrics0"], metrics1),
-            "profiler": state1.get("profiler"), "trace": trace,
+        ctx = layer_context(
+            cfg, spec, cell,
+            counters=stats.Counters(marks["metrics0"], metrics1),
+            profiler=state1.get("profiler"), trace=trace,
             # the trace runs on until stop_trace returns: where the device
             # was busy at both ends, its own span is the window
-            "trace_window_s": max(marks.get("trace", {}).get("window_s", 0.0),
-                                  trace.get("span_s", 0.0)),
-            "window_s": end - start, "compiles": compiles,
-            "device_kind": device["kind"], "costs": costs, "end_to_end": e2e,
-        }
+            trace_window_s=max(marks.get("trace", {}).get("window_s", 0.0),
+                               trace.get("span_s", 0.0)),
+            window_s=end - start, compiles=compiles,
+            device_kind=device["kind"], end_to_end=e2e)
         if not args.rehearse:
             ctx["peaks"] = costs.peaks(device["kind"])
             device["busy_s"] = trace["busy_s"]

@@ -23,31 +23,25 @@ T_START = time.monotonic()
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
-# rehearsal sizes: ModelConfig.tiny's, with the configuration's own kinds of
-# layer (experts, window) kept
-TINY = {
-    "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
-    "num_attention_heads": 4, "num_key_value_heads": 2, "vocab_size": 256,
-    "torch_dtype": "float32",
-}
-TINY_ENGINE = {
-    "max_batch_size": 4, "max_seq_len": 1024, "page_size": 16,
-    "num_pages": 256, "mixed_token_budget": 64,
-    "packed_shapes": [[4, 1], [32, 16], [128, 64]],
-    "warm_anchor_tokens": [200],
-}
+CONFIGS = os.path.join(HERE, "configs")
+# what a configuration's file names, beside its sizes: the modules that hold
+# its plain reference, its weights and its counts, each found with importlib
+# (README, "A configuration")
+NAMED = ("reference", "weights", "costs")
 
 
 def load_config(name: str, rehearse: bool) -> Dict[str, Any]:
-    with open(os.path.join(HERE, "configs", name + ".json")) as f:
+    with open(os.path.join(CONFIGS, name + ".json")) as f:
         cfg = json.load(f)
+    for key in NAMED:
+        if not cfg.get(key):
+            raise SystemExit(f"configuration {name!r} names no {key!r} module")
+    tiny = cfg.pop("rehearse", None)
     if rehearse:
-        cfg.update(TINY)
-        if cfg.get("num_local_experts"):
-            cfg["num_local_experts"] = 4
-        if cfg.get("sliding_window"):
-            cfg["sliding_window"] = 128
-        cfg["engine"] = dict(TINY_ENGINE)
+        if not tiny:
+            raise SystemExit(
+                f"configuration {name!r} has no 'rehearse' block: it cannot be rehearsed")
+        cfg.update(tiny)  # its tiny sizes, and under "engine" the tiny engine's
     return cfg
 
 
@@ -125,8 +119,6 @@ class Bench:
     def build_params(self, seed: int):
         import jax
 
-        from . import weights
-
         each = None
         if self.args.control == "int8_weights":
             # the control: the program's own int8 form of every matrix it
@@ -142,7 +134,7 @@ class Bench:
                     return _quantize_slice(w, dtype)
                 return w
 
-        params = weights.build_params(self.cfg, seed, each)
+        params = importlib.import_module(self.cfg["weights"]).build_params(self.cfg, seed, each)
         jax.block_until_ready(params)
         return params
 
@@ -229,7 +221,6 @@ class Bench:
     async def reference_route(self, req):
         body = req.json()
         if self.reference is None:
-            # the configuration names the module that holds its reference
             self.reference = importlib.import_module(self.cfg["reference"]).Reference(self.cfg)
         lp = await asyncio.to_thread(
             self.reference.logprobs, body["seed"], body["tokens"],

@@ -148,6 +148,19 @@ def idle_by_host(planes) -> Optional[Dict[str, Any]]:
     return out
 
 
+def idle_gaps(t: Optional[Dict[str, Any]]) -> List[List[Any]]:
+    """The table as the result line's ``breakdown.idle_gaps``: seconds of
+    the traced slice in which the device ran nothing, by what the host was
+    doing (each tick phase, ``dyn.parked``, and ``no_annotation`` for the
+    rest), the ten longest first.  They add up to the slice's idle seconds."""
+    if t is None:
+        return []
+    gaps = dict(t["idle_by_phase_s"])
+    gaps[PARKED] = t["idle_parked_s"]
+    gaps["no_annotation"] = t["idle_no_annotation_s"]
+    return [[k, v] for k, v in sorted(gaps.items(), key=lambda kv: -kv[1])[:10]]
+
+
 def load(path: str) -> Optional[Dict[str, Any]]:
     os.environ["JAX_PLATFORMS"] = "cpu"  # before JAX is imported, see above
     from jax.profiler import ProfileData

@@ -52,7 +52,8 @@ def test_cell_has_its_files(w):
     config = next(c for c in BENCH["configs"] if c["name"] == w["config"])
     with open(os.path.join(ROOT, config["file"])) as f:
         cfg = json.load(f)
-    for key in ("source", "reduced", "assumed", "deployment", "guarantees", "engine", "tolerance"):
+    for key in ("source", "reduced", "assumed", "deployment", "guarantees", "engine", "tolerance",
+                "reference", "weights", "costs", "rehearse"):
         assert key in cfg, key
     assert cfg["source"] == config["source"]
     assert sorted(cfg["reduced"]) == sorted(config["reduced"])

@@ -98,6 +98,24 @@ def test_shares_add_up_to_the_idle_metric():
     assert mod.in_wait(ctx) == pytest.approx(18.75)
 
 
+def test_idle_gaps_are_the_devices_idle_seconds_by_host_phase():
+    """The result line's ``breakdown.idle_gaps``: seconds the device ran
+    nothing, longest first, adding up to the slice's idle seconds; not the
+    tick phases' totals (``device_wait`` spans 2.5 s here, 1.5 s of it idle)."""
+    ops = [_ev("%fusion.1 = bf16[8]{0} fusion()", t, 1.0) for t in (0, 2, 5, 8)]
+    loop = [_tick(0.0, 1.0, "device_wait"), _tick(1.0, 1.0, "plan"),
+            _tick(3.0, 0.5, "commit"), _tick(3.5, 1.5, "device_wait"),
+            _ev("dyn.parked", 6.0, 2.0), _tick(9.75, 0.25, "other")]
+    t = trace_host.idle_by_host(_planes(ops, loop))
+    gaps = trace_host.idle_gaps(t)
+    assert [k for k, _v in gaps] == [
+        "dyn.parked", "device_wait", "plan", "no_annotation", "commit", "other"]
+    assert dict(gaps) == pytest.approx({"dyn.parked": 2.0, "device_wait": 1.5, "plan": 1.0,
+                                        "no_annotation": 0.75, "commit": 0.5, "other": 0.25})
+    assert sum(v for _k, v in gaps) == pytest.approx(t["idle_s"]) == pytest.approx(6.0)
+    assert trace_host.idle_gaps(None) == []  # a program without the annotations
+
+
 def test_a_program_without_the_annotations_reads_nothing():
     ops = [_ev("%fusion.1 = bf16[8]{0} fusion()", 0, 1.0)]
     ctx = {"planes": _planes(ops, [_ev("other", 0, 1.0)]), "trace_window_s": 2.0,
