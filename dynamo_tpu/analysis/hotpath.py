@@ -122,6 +122,15 @@ HOT_PATH_MANIFEST: Dict[str, List[str]] = {
         "packed_ragged_attention*",
         "_packed_kernel",
     ],
+    # the latent pool's kernels (MLA): the attention call of the packed
+    # step and of the fused decode steps over a kv_cache.LatentKV
+    "dynamo_tpu/ops/latent_attention.py": [
+        "latent_packed_attention",
+        "latent_decode_attention",
+        "packed_work_list",
+        "_launch",
+        "_latent_kernel",
+    ],
     # the dropless expert MLP's grouped product, three launches a layer of
     # every mixed step of a no-drop MoE engine (model._moe_grouped)
     "dynamo_tpu/ops/grouped_matmul.py": [

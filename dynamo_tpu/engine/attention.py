@@ -31,6 +31,7 @@ from .kv_cache import (
     gather_layer_kv,
     index_kv_layer,
     kv_data,
+    kv_is_latent,
     kv_is_quantized,
     quantize_kv_rows,
 )
@@ -54,7 +55,10 @@ def _kv_write(kv_pages, kv_idx, layer, ids, k_rows, *, slot=None):
     """Scatter one side's rows into the pool at (layer, ids[, slot]):
     quantizes on write for int8 pools.  ``ids`` (page ids) and ``slot``
     (within-page row) are pre-flattened index arrays; ``k_rows`` is
-    ``[..., Hkv, D]`` aligned with them."""
+    ``[..., Hkv, D]`` aligned with them.  A latent pool has one side: its
+    row is key and value at once, and the value's write is the key's."""
+    if kv_is_latent(kv_pages):
+        return kv_pages if kv_idx else kv_pages.write(layer, ids, k_rows, slot)
     if isinstance(kv_pages, QuantKV):
         q, s = quantize_kv_rows(k_rows)
         if slot is None:
@@ -164,7 +168,10 @@ def decode_attention_dispatch(
     embeds exactly one backend.  Quantized pools take the XLA gather on
     this CLASSIC path only (penalized/multimodal fallback lanes) -- the
     serving hot path under ``--kv-dtype int8`` is the unified ragged
-    dispatch, whose Pallas kernels fuse the dequant."""
+    dispatch, whose Pallas kernels fuse the dequant.  A latent pool has
+    kernels of its own (:func:`_latent_decode`)."""
+    if kv_is_latent(kv_pages):
+        return _latent_decode(q, kv_pages, page_table, kv_lens, layer)
     if (
         not kv_is_quantized(kv_pages)
         # the classic Pallas kernels compute directly on the pool tiles:
@@ -190,6 +197,85 @@ def decode_attention_dispatch(
         )
     layer_kv = index_kv_layer(kv_pages, layer)
     return paged_decode_attention(q, layer_kv, page_table, kv_lens, window)
+
+
+# -- latent pools (MLA) -------------------------------------------------------
+#
+# Every call below gets the model layer's absorbed operands (model.
+# _latent_attention): queries already in the latent space, the fresh rows,
+# and a pool whose one side is key and value at once.  The XLA compositions
+# serve that as multi-query attention unchanged (gather_layer_kv and
+# _kv_write know a latent pool).  On the chip the two paths serving takes --
+# the packed mixed step and the fused decode steps -- have kernels of their
+# own, which read everything from the pool: the dispatch's fresh rows are
+# scattered first (write_packed_kv / write_decode_kv), so a kernel sees one
+# source of keys and the causal mask alone tells fresh from resident.
+
+
+def latent_kernels_enabled(page_size: int) -> bool:
+    """Trace-time choice of a latent pool's attention backend: the Pallas
+    kernels on a TPU (``DYN_PALLAS_RAGGED=1/0`` forces it, the knob of the
+    pair pools' ragged kernels), the XLA composition elsewhere and on a
+    mesh (a latent pool has no head axis to run per shard)."""
+    if _context_mesh() is not None:
+        return False
+    forced = _env_flag("DYN_PALLAS_RAGGED")
+    if forced is not None:
+        return forced
+    return page_size >= 8 and _on_tpu()
+
+
+def latent_packed_path(page_size: int) -> str:
+    """Which latent path a packed dispatch takes, for the tick's
+    ``dispatch`` annotation: both are the absorbed form."""
+    return (
+        "absorbed_kernel" if latent_kernels_enabled(page_size)
+        else "absorbed_xla"
+    )
+
+
+def latent_packed_attention_dispatch(
+    q: jax.Array,  # [Np, Hq, W] absorbed queries
+    rows: jax.Array,  # [Np, 1, W] this dispatch's latent rows
+    kv_pages,  # kv_cache.LatentKV
+    layer, page_table, base, seg_off, q_lens, lane, rel, pos, valid,
+    s_max: int,
+):
+    """The packed mixed step's attention AND row scatter over a latent
+    pool: ``(out [Np, Hq, >= C], pool)``.  On the chip the rows are
+    scattered first and one kernel reads the pool (causal by position); the
+    XLA composition attends to the fresh rows beside the pool, as the pair
+    pools' does, and scatters after."""
+    written = write_packed_kv(
+        kv_pages, rows, rows, page_table, lane, pos, valid, layer
+    )
+    if latent_kernels_enabled(kv_pages.shape[3]):
+        from ..ops.latent_attention import latent_packed_attention
+
+        out = latent_packed_attention(
+            q, written, page_table, base, seg_off, q_lens, s_max, layer
+        )
+    else:
+        from ..ops.ragged_attention import packed_ragged_attention_xla
+
+        out = packed_ragged_attention_xla(
+            q, rows, rows, kv_pages, page_table, base, seg_off, q_lens,
+            lane, rel, s_max, layer, 0,
+        )
+    return out, written
+
+
+def _latent_decode(q, kv_pages, page_table, kv_lens, layer):
+    """Decode attention over a latent pool (the row of the new token is
+    already written): the kernel on the chip, the XLA gather elsewhere."""
+    if latent_kernels_enabled(kv_pages.shape[3]):
+        from ..ops.latent_attention import latent_decode_attention
+
+        return latent_decode_attention(
+            q, kv_pages, page_table, kv_lens, layer
+        )
+    layer_kv = index_kv_layer(kv_pages, layer)
+    return paged_decode_attention(q, layer_kv, page_table, kv_lens, 0)
 
 
 def _pallas_ragged_enabled(page_size: int, Hq: int, Hkv: int, D: int) -> bool:
@@ -240,7 +326,9 @@ def ragged_attention_dispatch(
     Hkv = k.shape[2]
     data = kv_data(kv_pages)
     scales = kv_pages.s if kv_is_quantized(kv_pages) else None
-    if _pallas_ragged_enabled(data.shape[3], Hq, Hkv, D):
+    if not kv_is_latent(kv_pages) and _pallas_ragged_enabled(
+        data.shape[3], Hq, Hkv, D
+    ):
         from ..ops.ragged_attention import ragged_paged_attention
 
         s_ops, s_specs = _scale_args(scales)
@@ -291,7 +379,9 @@ def packed_ragged_attention_dispatch(
     Hkv = k.shape[1]
     data = kv_data(kv_pages)
     scales = kv_pages.s if kv_is_quantized(kv_pages) else None
-    if _pallas_ragged_enabled(data.shape[3], Hq, Hkv, D):
+    if not kv_is_latent(kv_pages) and _pallas_ragged_enabled(
+        data.shape[3], Hq, Hkv, D
+    ):
         from ..ops.ragged_attention import packed_ragged_attention
 
         s_ops, s_specs = _scale_args(scales)
@@ -332,6 +422,8 @@ def _pallas_prefill_enabled(T: int, Hq: int, Hkv: int, D: int) -> bool:
     if forced is not None:
         return forced
     if T < 1024 or Hq % Hkv or D % 8 or not _heads_shard(Hq, Hkv):
+        return False
+    if D > 256:  # a latent cache's rows (MLA): wider than the kernel's tiles
         return False
     return _on_tpu()
 
@@ -375,7 +467,7 @@ def _pallas_prefix_prefill_enabled(
     forced = _env_flag("DYN_PALLAS_PREFILL")
     if forced is not None:
         return forced
-    if Hq % Hkv or D % 8 or not _heads_shard(Hq, Hkv):
+    if Hq % Hkv or D % 8 or D > 256 or not _heads_shard(Hq, Hkv):
         return False
     if T < 1024 and (T < 512 or Kp < 512):
         return False

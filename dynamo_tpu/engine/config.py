@@ -13,7 +13,15 @@ from __future__ import annotations
 import json
 import os
 from dataclasses import dataclass, field
-from typing import Any, Dict, Optional
+import math
+from typing import Any, Dict, Optional, Tuple
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor: ``0.1 * mscale * ln(factor) + 1``."""
+    if factor <= 1.0 or not mscale:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
 
 
 @dataclass(frozen=True)
@@ -53,12 +61,76 @@ class ModelConfig:
     rope_scaling: Optional[tuple] = None
     # sliding-window attention (Mistral/Phi3); None/0 = full attention
     sliding_window: Optional[int] = None
+    # latent attention (MLA; mistral4): kv_lora_rank > 0 switches the layer
+    # to low-rank queries (q_lora_rank) and one cached row per token of
+    # [c_kv (kv_lora_rank) | RoPE(k_r) (qk_rope_head_dim)] shared by every
+    # head -- no K/V pair and no head axis in the cache (kv_geometry)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False  # rotate (2i, 2i+1) pairs, not halves
+    # queries at position p scale by 1 + beta * ln(1 + floor(p / orig_max))
+    # (``llama_4_scaling_beta``, stored as (beta, original_max_position))
+    query_pos_scaling: Optional[tuple] = None
+    # experts held by this process: ``num_experts`` stays the router's
+    # width (routing is over every published expert); 0 = all of them.
+    # Assignments to an absent expert contribute nothing here: their
+    # owner adds them in a deployment's exchange
+    num_local_experts: int = 0
+    local_expert_offset: int = 0
+    # shared experts: dense SwiGLUs of the expert width added to every token
+    num_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
     # activation dtype for compute; params may be stored differently
     dtype: str = "bfloat16"
 
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def experts_held(self) -> int:
+        return self.num_local_experts or self.num_experts
+
+    @property
+    def rope_dim(self) -> int:
+        """Width of the rotated part of a query/key."""
+        return self.qk_rope_head_dim if self.is_mla else self.head_dim
+
+    @property
+    def kv_geometry(self) -> Tuple[int, int, int, int]:
+        """``(slabs, sides, heads, width)``: the pool is ``[slabs, sides,
+        pages, page, heads, width]``.  A K/V pair per KV head per layer; or
+        a latent cache (MLA): one row ``[c_kv | RoPE(k_r)]`` a token a
+        layer, no pair and no head axis, two layers' rows side by side in
+        one slab row (``kv_cache.LatentKV``: 2 x 320 values is a whole
+        number of the chip's 128-lane tiles, 320 is not)."""
+        if self.is_mla:
+            row = self.kv_lora_rank + self.qk_rope_head_dim
+            return -(-self.num_layers // 2), 1, 1, 2 * row
+        return self.num_layers, 2, self.num_kv_heads, self.head_dim
+
+    @property
+    def kv_values_per_token(self) -> int:
+        slabs, sides, heads, width = self.kv_geometry
+        return slabs * sides * heads * width
+
+    @property
+    def attn_softmax_scale(self) -> float:
+        """Scale of the attention scores: head_dim^-0.5, times YaRN's
+        ``mscale_all_dim`` factor squared where the configuration has one."""
+        scale = self.head_dim ** -0.5
+        rs = self.rope_scaling
+        if rs is not None and rs[0] == "yarn":
+            m = _yarn_mscale(rs[1], rs[6])
+            scale *= m * m
+        return scale
 
     def validate_tp(self, tp: int) -> None:
         """Fail fast when a tensor-parallel degree cannot shard this
@@ -71,6 +143,13 @@ class ModelConfig:
         this engine does not carry."""
         if tp <= 1:
             return
+        if self.is_mla:
+            raise ValueError(
+                f"tp={tp}: a latent cache (MLA, kv_lora_rank="
+                f"{self.kv_lora_rank}) has one row a token and no head axis "
+                "to shard; serve it data-parallel (dp) with the experts "
+                "split across chips"
+            )
         if self.num_heads % tp:
             raise ValueError(
                 f"tp={tp} does not divide num_heads={self.num_heads}"
@@ -145,6 +224,7 @@ class ModelConfig:
 
     SUPPORTED_MODEL_TYPES = (
         "llama", "mistral", "qwen2", "mixtral", "gemma", "phi3", "qwen3",
+        "mistral4",
     )
 
     @classmethod
@@ -166,7 +246,9 @@ class ModelConfig:
         # for EVERY model type -- loading a scaled checkpoint with plain
         # RoPE produces garbage at long context with no error
         rope_scaling: Optional[tuple] = None
-        rs = cfg.get("rope_scaling") or None
+        rs = cfg.get("rope_scaling") or cfg.get("rope_parameters") or None
+        if mt == "mistral4":
+            return cls._from_mistral4(cfg, rs or {})
         if rs is not None:
             rs_type = rs.get("rope_type") or rs.get("type")
             if rs_type == "llama3":
@@ -238,6 +320,86 @@ class ModelConfig:
             qk_norm=cfg.get("model_type") == "qwen3",
             rope_scaling=rope_scaling,
             sliding_window=window,
+        )
+
+    @classmethod
+    def _from_mistral4(cls, cfg: Dict[str, Any], rs: Dict[str, Any]) -> "ModelConfig":
+        """``mistral4``: latent attention (MLA), routed experts of width
+        ``moe_intermediate_size`` with shared experts in every layer, YaRN.
+        ``n_routed_experts`` is what this process holds; ``router_experts``
+        (default: the same) the router's published width, with
+        ``expert_offset`` the first held expert's published index."""
+        rs_type = rs.get("rope_type") or rs.get("type")
+        if rs_type == "yarn":
+            rope_scaling: Optional[tuple] = (
+                "yarn",
+                float(rs["factor"]),
+                int(rs["original_max_position_embeddings"]),
+                float(rs.get("beta_fast", 32)),
+                float(rs.get("beta_slow", 1)),
+                float(rs.get("mscale", 1)),
+                float(rs.get("mscale_all_dim", 0)),
+            )
+        elif rs_type in (None, "default"):
+            rope_scaling = None
+        else:
+            raise ValueError(
+                f"rope_scaling type {rs_type!r} is not supported for mistral4"
+                " (implemented: yarn)"
+            )
+        if cfg.get("first_k_dense_replace", 0):
+            raise ValueError(
+                "mistral4 with first_k_dense_replace > 0 (dense layers before"
+                " the expert layers) is not supported"
+            )
+        if cfg.get("n_group", 1) != 1 or cfg.get("topk_group", 1) != 1:
+            raise ValueError("mistral4 grouped routing (n_group > 1) is not supported")
+        if not cfg.get("norm_topk_prob", True):
+            raise ValueError("mistral4 without norm_topk_prob is not supported")
+        if cfg.get("sliding_window"):
+            raise ValueError("mistral4 with a sliding window is not supported")
+        nope, rope = cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"]
+        if cfg.get("qk_head_dim", nope + rope) != nope + rope:
+            raise ValueError("qk_head_dim != qk_nope_head_dim + qk_rope_head_dim")
+        held = cfg["n_routed_experts"]
+        width = cfg.get("router_experts", held)
+        offset = cfg.get("expert_offset", 0)
+        if offset < 0 or offset + held > width:
+            raise ValueError(
+                f"experts {offset}..{offset + held - 1} lie outside the "
+                f"router's {width}"
+            )
+        beta = rs.get("llama_4_scaling_beta")
+        heads = cfg["num_attention_heads"]
+        return cls(
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["moe_intermediate_size"],
+            num_layers=cfg["num_hidden_layers"],
+            num_heads=heads,
+            num_kv_heads=heads,
+            head_dim=nope + rope,
+            rope_theta=float(rs.get("rope_theta", cfg.get("rope_theta", 10000.0))),
+            rms_norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+            max_position=cfg.get("max_position_embeddings", 4096),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            num_experts=width,
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            num_local_experts=held if held != width else 0,
+            local_expert_offset=offset,
+            num_shared_experts=cfg.get("n_shared_experts", 0),
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+            rope_scaling=rope_scaling,
+            q_lora_rank=cfg["q_lora_rank"],
+            kv_lora_rank=cfg["kv_lora_rank"],
+            qk_nope_head_dim=nope,
+            qk_rope_head_dim=rope,
+            v_head_dim=cfg["v_head_dim"],
+            rope_interleave=bool(cfg.get("rope_interleave", False)),
+            query_pos_scaling=(
+                (float(beta), int(rs["original_max_position_embeddings"]))
+                if beta else None
+            ),
         )
 
     @classmethod

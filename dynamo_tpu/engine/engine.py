@@ -705,6 +705,12 @@ class JaxEngine:
         self._dp = int(mesh.shape.get("dp", 1)) if mesh is not None else 1
         self._sp = int(mesh.shape.get("sp", 1)) if mesh is not None else 1
         self._pp = int(mesh.shape.get("pp", 1)) if mesh is not None else 1
+        if model_cfg.is_mla and (self._sp > 1 or self._pp > 1):
+            raise ValueError(
+                "sp/pp meshes are not supported over a latent cache (MLA): "
+                "their prefill routes and stage pools assume K/V pairs "
+                "per head and per layer"
+            )
         if mesh is not None and kv_sharding is None:
             from ..parallel.sharding import kv_pspec
 
@@ -841,6 +847,12 @@ class JaxEngine:
             disk_blocks = env_spec["disk"]
             disk_dir = env_spec["dir"] or disk_dir
             swap_on = env_spec["swap"] and self.cfg.swap_preemption
+        if model_cfg.is_mla and (host_blocks > 0 or disk_blocks > 0):
+            raise ValueError(
+                "host/disk KV offload is not supported over a latent cache "
+                "(MLA): the tiers move [L, 2, pages, page, Hkv, D] blocks, "
+                "and a latent pool holds two layers' rows a slab"
+            )
         if pool is not None and (host_blocks > 0 or disk_blocks > 0):
             from ..offload import KVOffloadEngine
 
@@ -873,6 +885,11 @@ class JaxEngine:
         except ValueError:
             logger.warning(
                 "ignoring malformed kv_remote config %r", self.cfg.kv_remote
+            )
+        if model_cfg.is_mla and self.kv_remote_spec:
+            raise ValueError(
+                "the remote KV tier (G4) is not supported over a latent "
+                "cache (MLA): it ships the offload tiers' K/V blocks"
             )
         if "DYN_KV_REMOTE" in _os.environ:
             # env wins outright, including an explicit "off" disarming a
@@ -1177,6 +1194,10 @@ class JaxEngine:
 
         m = self.model_cfg
         page = self.cfg.page_size
+        if m.is_mla:
+            # the latent kernels move queries and pages by DMA, a tile at a
+            # time: their VMEM does not grow with the packed shape
+            return lambda Np, s_max: True
         with self.mesh_scope():  # the gate reads tp from the context mesh
             kernel = att._pallas_ragged_enabled(
                 page, m.num_heads, m.num_kv_heads, m.head_dim
@@ -1633,7 +1654,17 @@ class JaxEngine:
     ) -> AsyncIterator[Annotated]:
         """Admit a request whose prompt KV a remote prefill worker delivers;
         the lane holds pages but decodes only after deliver_external."""
+        self._refuse_latent("disaggregated serving (a remote prefill's KV)")
         return await self.generate(request, _external=True)
+
+    def _refuse_latent(self, what: str) -> None:
+        """KV transfer paths ship ``[L, 2, pages, page, Hkv, D]`` blobs: over
+        a latent cache (MLA) they would move bytes of the wrong shape."""
+        if self.model_cfg.is_mla:
+            raise ValueError(
+                f"{what} is not supported over a latent cache (MLA): the "
+                "transfer formats carry K/V pairs per head and per layer"
+            )
 
     def awaiting_external(self, request_id: str) -> bool:
         """True while the request is admitted (or queued) and still expects a
@@ -1654,6 +1685,7 @@ class JaxEngine:
         OpenAI arrays one short).  Returns False when the request is no
         longer waiting (cancelled/failed).  Applied by the tick loop at its
         next iteration -- scheduler state is never touched from here."""
+        self._refuse_latent("a remote prefill's KV delivery")
         if request_id not in self._external:
             return False
         arr = np.asarray(first_token).reshape(-1)
@@ -1702,6 +1734,7 @@ class JaxEngine:
         """Stage one layer-group chunk ``[layer_hi-layer_lo, 2, n_pages,
         page, Hkv, D]``; the tick loop scatters it into the lane's pages at
         its next iteration (or as soon as the lane gets a slot)."""
+        self._refuse_latent("a remote prefill's KV delivery")
         rec = self._chunked.get(request_id)
         if rec is None or request_id not in self._external:
             return False
@@ -2002,6 +2035,7 @@ class JaxEngine:
         """Prefill-worker side: run a standalone prefill into scratch pages,
         return (kv_blob [L, 2, n_pages, page, Hkv, D], first_token) and free
         the scratch.  Serialized with the tick loop via the engine executor."""
+        self._refuse_latent("a disaggregated prefill export")
         if not self._running:
             await self.start()
         loop = asyncio.get_running_loop()
@@ -2048,6 +2082,7 @@ class JaxEngine:
         the device->host transfer of the blobs no longer occupies the
         executor, so decode/prefill ticks overlap the transfer instead of
         serializing behind it (round-4 verdict #8)."""
+        self._refuse_latent("a disaggregated prefill export")
         if not self._running:
             await self.start()
         loop = asyncio.get_running_loop()
@@ -2178,6 +2213,7 @@ class JaxEngine:
         request: a :class:`KVExportStream` or the per-request ``Exception``.
         Shares the dispatch site with the aggregated path, preserving
         disagg == aggregated output."""
+        self._refuse_latent("a disaggregated prefill export")
         if not self._running:
             await self.start()
         loop = asyncio.get_running_loop()
@@ -2307,6 +2343,7 @@ class JaxEngine:
         export/import; G4).  Consults G1 (HBM pool, one bundled device
         transfer) then the offload tiers; stops at the first miss, because
         an importer can only use a contiguous prefix."""
+        self._refuse_latent("a KV block export")
         if not self._running:
             await self.start()
         loop = asyncio.get_running_loop()
@@ -4047,15 +4084,20 @@ class JaxEngine:
 
     def _live_page_bucket(self) -> int:
         """Power-of-two page-table width covering the longest slotted
-        lane's allocation (floor 8 bounds the executable count) -- the ONE
-        bucketing rule shared by the decode-block and verify dispatches,
-        so the two paths can never compile against different table
-        widths."""
+        lane's allocation -- the ONE bucketing rule shared by the
+        decode-block and verify dispatches, so the two paths can never
+        compile against different table widths.  The floor of 64 pages
+        bounds the executable count: every width is one more executable
+        for each packed shape and each fused K, compiled the first time a
+        quiet moment leaves only short lanes in the batch (with a floor of
+        8, short chat prompts at 3-7 requests/s compiled inside 5 of 8
+        swept windows of 50 s on the chip, PERF.md PR 30), and what a
+        narrower table saves is the kernels' grid steps over 56 pages."""
         live_pages = [
             len(s.pages) for s in self.sched.slots if s is not None and s.pages
         ]
         return pick_page_bucket(
-            min(max(8, max(live_pages, default=1)), self.sched.max_pages),
+            min(max(64, max(live_pages, default=1)), self.sched.max_pages),
             self.sched.max_pages,
         )
 
@@ -4375,6 +4417,15 @@ class JaxEngine:
                     "k": num_steps,
                     "np": Np,
                 }
+                if self.model_cfg.is_mla:
+                    # which latent path the dispatch takes (read where the
+                    # step's trace reads it)
+                    from . import attention as att
+
+                    with self.mesh_scope():
+                        dispatch_meta["latent"] = att.latent_packed_path(
+                            self.cfg.page_size
+                        )
             operands = (
                 self.params,
                 self.model_cfg,
@@ -5288,7 +5339,9 @@ class JaxEngine:
                 )
                 self.obs.observe_step("decode_block", now - e.dispatched_at)
         alloc = self.kv.allocator
-        self.obs.observe_kv(alloc.used_pages, alloc.num_pages - 1)
+        self.obs.observe_kv(
+            alloc.used_pages, alloc.num_pages - 1, self.kv.bytes_per_token
+        )
         if tick is not None:
             tick.mark("commit")
         return events
@@ -5536,8 +5589,11 @@ class JaxEngine:
         )
         for stage, lo, hi in seq.stage_segments(end_s):
             attrs: Dict[str, Any] = {}
+            if self.model_cfg.is_mla and stage in ("prefill", "decode"):
+                attrs["attn"] = "latent"
             if stage == "prefill":
                 attrs = {
+                    **attrs,
                     "chunks": seq.prefill_chunks,
                     "prompt_tokens_computed": seq.prefill_tokens,
                     "cached": seq.cached_prompt_tokens,

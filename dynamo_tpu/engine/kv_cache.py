@@ -139,18 +139,155 @@ class QuantKV:
         return dequantize_kv_blob(self, compute_dtype)
 
 
+# ---------------------------------------------------------------------------
+# latent pool (MLA)
+#
+# A latent cache holds one row a token a layer, ``[c_kv (C) | RoPE(k_r)
+# (R)]``: key and value of every head at once.  At C + R = 320 values the
+# row is two and a half of the chip's 128-lane tiles, which neither XLA
+# (it would lay the pool out pages-minor, and copy it for every kernel
+# call) nor Mosaic (it refuses to slice such a dimension) will read a page
+# of.  So two layers share a slab row of ``2 (C + R)`` values,
+#
+#     [c_kv of layer 2m | c_kv of layer 2m+1 | k_r of 2m | k_r of 2m+1]
+#
+# whose parts start on tile boundaries (C a multiple of 128, 2R = 128 at the
+# published widths): the pool is ``[ceil(L/2), 1, pages, page, 1, 2(C+R)]``,
+# dense, exactly ``L (C + R)`` values a token for an even L, and nothing of
+# a row is stored twice.  A layer reads its C columns and the R tile (whose
+# other half its queries meet with zeros).  ``LatentKV`` carries C beside
+# the array, so every reader can take a row apart; like ``QuantKV`` it is a
+# pytree, rides the layer scan and jit donation, and mirrors the array's
+# ``shape``/``dtype``.
+# ---------------------------------------------------------------------------
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclass
+class LatentKV:
+    data: Any  # [ceil(L/2), 1, pages, page, 1, 2 * (C + R)]
+    c: int  # width of c_kv (static)
+
+    def tree_flatten(self):
+        return (self.data,), self.c
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(children[0], aux)
+
+    @property
+    def shape(self):
+        return self.data.shape
+
+    @property
+    def dtype(self):
+        return self.data.dtype
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.data.nbytes)
+
+    @property
+    def r(self) -> int:
+        return self.data.shape[-1] // 2 - self.c
+
+    def block_until_ready(self) -> "LatentKV":
+        self.data.block_until_ready()
+        return self
+
+    def lanes_of(self, half):
+        """[2 (C + R)] bool: the slab row's lanes that belong to the layer
+        in ``half`` (0 or 1, may be traced)."""
+        lane = jnp.arange(self.data.shape[-1])
+        c, r = self.c, self.r
+        in_c = (lane >= half * c) & (lane < (half + 1) * c)
+        in_r = (lane >= 2 * c + half * r) & (lane < 2 * c + (half + 1) * r)
+        return in_c | in_r
+
+    def write(self, layer, ids, rows, slot=None) -> "LatentKV":
+        """Scatter ``rows [..., 1, C + R]`` of ``layer`` at ``(ids[,
+        slot])``: whole slab rows are read, the layer's lanes replaced and
+        written back (layers write one after another, so the neighbour's
+        lanes are never in flight)."""
+        c = self.c
+        shape = self.data.shape
+        flat = self.data.reshape(shape[0], shape[2], shape[3], shape[5])
+        row = rows.reshape(*rows.shape[:-2], rows.shape[-1]).astype(flat.dtype)
+        both = jnp.concatenate(
+            [row[..., :c], row[..., :c], row[..., c:], row[..., c:]], axis=-1
+        )
+        pair, mine = layer // 2, self.lanes_of(layer % 2)
+        at = (pair, ids) if slot is None else (pair, ids, slot)
+        flat = flat.at[at].set(jnp.where(mine, both, flat[at]))
+        return LatentKV(flat.reshape(shape), c)
+
+    def layer_view(self, layer) -> "LatentLayer":
+        return LatentLayer(
+            jax.lax.dynamic_index_in_dim(
+                self.data, layer // 2, 0, keepdims=False
+            ),
+            self.c, layer % 2,
+        )
+
+
+@dataclass
+class LatentLayer:
+    """One layer of a latent pool, for the XLA compositions: the slab
+    ``[1, pages, page, 1, 2 (C + R)]`` and which half is the layer's.  Its
+    one side serves as keys and as values."""
+
+    slab: Any
+    c: int
+    half: Any
+
+    @property
+    def shape(self):
+        return (*self.slab.shape[:-1], self.slab.shape[-1] // 2)
+
+    def gather(self, page_table, out_dtype):
+        """``[B, P, page, 1, C + R]`` rows of this layer."""
+        g = self.slab[0][page_table]
+        c, r = self.c, self.slab.shape[-1] // 2 - self.c
+        first = self.half == 0
+        return jnp.concatenate(
+            [
+                jnp.where(first, g[..., :c], g[..., c : 2 * c]),
+                jnp.where(
+                    first, g[..., 2 * c : 2 * c + r], g[..., 2 * c + r :]
+                ),
+            ],
+            axis=-1,
+        ).astype(out_dtype)
+
+
 def kv_data(kv_pages):
-    """The dense data array of either pool form (shape/dtype queries,
-    Pallas operand plumbing)."""
-    return kv_pages.q if isinstance(kv_pages, QuantKV) else kv_pages
+    """The dense data array of any pool form (shape/dtype queries, Pallas
+    operand plumbing)."""
+    if isinstance(kv_pages, QuantKV):
+        return kv_pages.q
+    return kv_pages.data if isinstance(kv_pages, LatentKV) else kv_pages
+
+
+def kv_num_layers(kv_pages) -> int:
+    """Layers a pool holds (a latent pool: two a slab)."""
+    n = kv_data(kv_pages).shape[0]
+    return 2 * n if isinstance(kv_pages, LatentKV) else n
 
 
 def kv_is_quantized(kv_pages) -> bool:
     return isinstance(kv_pages, QuantKV)
 
 
+def kv_is_latent(kv_pages) -> bool:
+    """A latent pool (MLA): one row a token that is key and value at once.
+    Told by its type, at trace time."""
+    return isinstance(kv_pages, (LatentKV, LatentLayer))
+
+
 def index_kv_layer(kv_pages, layer):
-    """``dynamic_index_in_dim(pool, layer, 0)`` for either pool form."""
+    """``dynamic_index_in_dim(pool, layer, 0)`` for any pool form."""
+    if isinstance(kv_pages, LatentKV):
+        return kv_pages.layer_view(layer)
     if isinstance(kv_pages, QuantKV):
         return QuantKV(
             q=jax.lax.dynamic_index_in_dim(
@@ -166,7 +303,10 @@ def index_kv_layer(kv_pages, layer):
 def gather_layer_kv(layer_kv, kv_idx, page_table, out_dtype):
     """Gather one side (k=0 / v=1) of a layer's pages: ``[B, P, page,
     Hkv, D]`` in ``out_dtype``, dequantized when the pool is int8.  The
-    dequant runs on the GATHERED pages (a few MB), never the pool."""
+    dequant runs on the GATHERED pages (a few MB), never the pool.  A
+    latent pool's one side is both."""
+    if isinstance(layer_kv, LatentLayer):
+        return layer_kv.gather(page_table, out_dtype)
     if isinstance(layer_kv, QuantKV):
         pages = layer_kv.q[kv_idx][page_table]  # [B, P, page, Hkv, D] int8
         scales = layer_kv.s[kv_idx][page_table]  # [B, P, page]
@@ -375,14 +515,14 @@ class PagedKVCache:
         # default is the plain free list; the engine passes a PagePool
         # (block_manager) to get the sequence-hash reuse registry
         self.allocator = allocator if allocator is not None else PageAllocator(num_pages)
-        shape = (
-            cfg.num_layers,
-            2,
-            num_pages,
-            page_size,
-            cfg.num_kv_heads,
-            cfg.head_dim,
-        )
+        slabs, sides, heads, width = cfg.kv_geometry
+        shape = (slabs, sides, num_pages, page_size, heads, width)
+        if self.quantized and cfg.is_mla:
+            raise ValueError(
+                "kv_dtype int8 is not supported over a latent cache (MLA): "
+                "one scale a row would span c_kv and the rotated key, whose "
+                "ranges differ"
+            )
         if self.quantized:
             q = jnp.zeros(shape, jnp.int8)
             s = jnp.zeros(shape[:4], jnp.float32)
@@ -404,7 +544,7 @@ class PagedKVCache:
             arr = jnp.zeros(shape, self.dtype)
             if sharding is not None:
                 arr = jax.device_put(arr, sharding)
-            self.pages = arr
+            self.pages = LatentKV(arr, cfg.kv_lora_rank) if cfg.is_mla else arr
 
     @property
     def bytes_per_page(self) -> int:
@@ -412,13 +552,15 @@ class PagedKVCache:
         ``est_hbm_util`` and ``kv_pool_gb`` lines report the actual
         footprint.  Quantized pages count their scale rows too."""
         c = self.cfg
-        data = (
-            c.num_layers * 2 * self.page_size * c.num_kv_heads * c.head_dim
-            * self.dtype.itemsize
-        )
+        data = c.kv_values_per_token * self.page_size * self.dtype.itemsize
         if self.quantized:
             data += c.num_layers * 2 * self.page_size * 4  # f32 row scales
         return data
+
+    @property
+    def bytes_per_token(self) -> float:
+        """Pool bytes over pool tokens (``dynamo_engine_kv_bytes_per_token``)."""
+        return self.bytes_per_page / self.page_size
 
     @property
     def pool_bytes(self) -> int:
@@ -504,9 +646,6 @@ def choose_num_pages(
 ) -> int:
     """Size the G1 pool from available HBM after weights (reference vLLM-style
     gpu_memory_utilization accounting)."""
-    per_page = (
-        cfg.num_layers * 2 * page_size * cfg.num_kv_heads * cfg.head_dim
-        * kv_dtype_size
-    )
+    per_page = cfg.kv_values_per_token * page_size * kv_dtype_size
     budget = int(hbm_bytes * mem_fraction) - param_bytes
     return max(2, budget // per_page)

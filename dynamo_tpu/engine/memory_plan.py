@@ -64,13 +64,24 @@ def _param_shapes(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
     shapes: Dict[str, Tuple[int, ...]] = {
         "embed": (cfg.vocab_size, H),
         "final_norm": (H,),
-        "layers/wq": (L, H, Hq * D),
-        "layers/wk": (L, H, Hkv * D),
-        "layers/wv": (L, H, Hkv * D),
-        "layers/wo": (L, Hq * D, H),
         "layers/input_norm": (L, H),
         "layers/post_norm": (L, H),
     }
+    if cfg.is_mla:
+        R, C = cfg.q_lora_rank, cfg.kv_lora_rank
+        shapes["layers/wq_a"] = (L, H, R)
+        shapes["layers/q_a_norm"] = (L, R)
+        shapes["layers/wq_b"] = (L, R, Hq * D)
+        shapes["layers/wkv_a"] = (L, H, C + cfg.qk_rope_head_dim)
+        shapes["layers/kv_a_norm"] = (L, C)
+        shapes["layers/wkv_b"] = (
+            L, C, Hq * (cfg.qk_nope_head_dim + cfg.v_head_dim))
+        shapes["layers/wo"] = (L, Hq * cfg.v_head_dim, H)
+    else:
+        shapes["layers/wq"] = (L, H, Hq * D)
+        shapes["layers/wk"] = (L, H, Hkv * D)
+        shapes["layers/wv"] = (L, H, Hkv * D)
+        shapes["layers/wo"] = (L, Hq * D, H)
     if cfg.attention_bias:
         shapes["layers/bq"] = (L, Hq * D)
         shapes["layers/bk"] = (L, Hkv * D)
@@ -79,11 +90,16 @@ def _param_shapes(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
         shapes["layers/q_norm"] = (L, D)
         shapes["layers/k_norm"] = (L, D)
     if cfg.is_moe:
-        E = cfg.num_experts
-        shapes["layers/router"] = (L, H, E)
+        E = cfg.experts_held
+        shapes["layers/router"] = (L, H, cfg.num_experts)
         shapes["layers/w_gate"] = (L, E, H, I)
         shapes["layers/w_up"] = (L, E, H, I)
         shapes["layers/w_down"] = (L, E, I, H)
+        if cfg.num_shared_experts:
+            Is = I * cfg.num_shared_experts
+            shapes["layers/ws_gate"] = (L, H, Is)
+            shapes["layers/ws_up"] = (L, H, Is)
+            shapes["layers/ws_down"] = (L, Is, H)
     else:
         shapes["layers/w_gate"] = (L, H, I)
         shapes["layers/w_up"] = (L, H, I)
@@ -95,7 +111,9 @@ def _param_shapes(cfg: ModelConfig) -> Dict[str, Tuple[int, ...]]:
 
 _QUANT_PATHS = frozenset(
     {"layers/wq", "layers/wk", "layers/wv", "layers/wo",
-     "layers/w_gate", "layers/w_up", "layers/w_down", "lm_head"}
+     "layers/w_gate", "layers/w_up", "layers/w_down", "lm_head",
+     "layers/wq_a", "layers/wq_b", "layers/wkv_a", "layers/wkv_b",
+     "layers/ws_gate", "layers/ws_up", "layers/ws_down"}
 )
 
 
@@ -200,11 +218,11 @@ def plan_memory(
 
     # KV pages [L, 2, pages, page, Hkv, D]; kv heads shard over tp only
     # when divisible (kv_pspec + _compatible_spec semantics)
-    kv_heads = cfg.num_kv_heads
+    # (a latent cache has no head axis: cfg.kv_geometry, never divided)
+    slabs, sides, kv_heads, kv_width = cfg.kv_geometry
     kv_div = tp if tp > 1 and kv_heads % tp == 0 else 1
     bytes_per_page = (
-        cfg.num_layers * 2 * page_size * (kv_heads // kv_div)
-        * cfg.head_dim * wbytes
+        slabs * sides * page_size * (kv_heads // kv_div) * kv_width * wbytes
     )
     kv_bytes = bytes_per_page * num_pages
 

@@ -20,6 +20,7 @@ tests/test_engine_model.py).
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -47,20 +48,34 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: Any = None) -> Params:
     Hq, Hkv = cfg.num_heads, cfg.num_kv_heads
     I = cfg.intermediate_size
 
-    keys = iter(jax.random.split(key, 16))
-
     def w(k, shape, scale=None):
         scale = scale if scale is not None else (1.0 / jnp.sqrt(shape[-2] if len(shape) > 1 else shape[-1]))
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
-    layers: Dict[str, Any] = {
-        "wq": w(next(keys), (L, H, Hq * D)),
-        "wk": w(next(keys), (L, H, Hkv * D)),
-        "wv": w(next(keys), (L, H, Hkv * D)),
-        "wo": w(next(keys), (L, Hq * D, H)),
-        "input_norm": jnp.ones((L, H), dtype),
-        "post_norm": jnp.ones((L, H), dtype),
-    }
+    keys = iter(jax.random.split(key, 24))
+    if cfg.is_mla:
+        R, C = cfg.q_lora_rank, cfg.kv_lora_rank
+        layers: Dict[str, Any] = {
+            "wq_a": w(next(keys), (L, H, R)),
+            "q_a_norm": jnp.ones((L, R), dtype),
+            "wq_b": w(next(keys), (L, R, Hq * D)),
+            "wkv_a": w(next(keys), (L, H, C + cfg.qk_rope_head_dim)),
+            "kv_a_norm": jnp.ones((L, C), dtype),
+            "wkv_b": w(
+                next(keys),
+                (L, C, Hq * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            ),
+            "wo": w(next(keys), (L, Hq * cfg.v_head_dim, H)),
+        }
+    else:
+        layers = {
+            "wq": w(next(keys), (L, H, Hq * D)),
+            "wk": w(next(keys), (L, H, Hkv * D)),
+            "wv": w(next(keys), (L, H, Hkv * D)),
+            "wo": w(next(keys), (L, Hq * D, H)),
+        }
+    layers["input_norm"] = jnp.ones((L, H), dtype)
+    layers["post_norm"] = jnp.ones((L, H), dtype)
     if cfg.attention_bias:
         layers["bq"] = jnp.zeros((L, Hq * D), dtype)
         layers["bk"] = jnp.zeros((L, Hkv * D), dtype)
@@ -69,11 +84,16 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: Any = None) -> Params:
         layers["q_norm"] = jnp.ones((L, D), dtype)
         layers["k_norm"] = jnp.ones((L, D), dtype)
     if cfg.is_moe:
-        E = cfg.num_experts
-        layers["router"] = w(next(keys), (L, H, E))
+        E = cfg.experts_held
+        layers["router"] = w(next(keys), (L, H, cfg.num_experts))
         layers["w_gate"] = w(next(keys), (L, E, H, I))
         layers["w_up"] = w(next(keys), (L, E, H, I))
         layers["w_down"] = w(next(keys), (L, E, I, H))
+        if cfg.num_shared_experts:
+            Is = I * cfg.num_shared_experts
+            layers["ws_gate"] = w(next(keys), (L, H, Is))
+            layers["ws_up"] = w(next(keys), (L, H, Is))
+            layers["ws_down"] = w(next(keys), (L, Is, H))
     else:
         layers["w_gate"] = w(next(keys), (L, H, I))
         layers["w_up"] = w(next(keys), (L, H, I))
@@ -131,7 +151,35 @@ def rope_cos_sin(
     inv_freq = 1.0 / (
         theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
     )
-    if scaling is not None:
+    factor_cs = 1.0
+    if scaling is not None and scaling[0] == "yarn":
+        # YaRN: dimensions that turn more than beta_fast times over the
+        # original context keep their frequency, those that turn less than
+        # beta_slow times are interpolated by ``factor``, a linear ramp
+        # between; cos/sin carry mscale/mscale_all_dim (HF
+        # ``_compute_yarn_parameters`` with both mscale keys)
+        from .config import _yarn_mscale
+
+        _, factor, orig_max, b_fast, b_slow, mscale, mscale_all = scaling
+        half = head_dim // 2
+
+        def corr_dim(rot):
+            return head_dim * math.log(orig_max / (rot * 2 * math.pi)) / (
+                2 * math.log(theta)
+            )
+
+        low = max(math.floor(corr_dim(b_fast)), 0)
+        high = min(math.ceil(corr_dim(b_slow)), head_dim - 1)
+        ramp = jnp.clip(
+            (jnp.arange(half, dtype=jnp.float32) - low)
+            / max(high - low, 0.001),
+            0.0, 1.0,
+        )
+        inv_freq = inv_freq / factor * ramp + inv_freq * (1.0 - ramp)
+        factor_cs = _yarn_mscale(factor, mscale) / _yarn_mscale(
+            factor, mscale_all
+        )
+    elif scaling is not None:
         kind, factor, low_f, high_f, orig_max = scaling
         if kind != "llama3":  # config validates; belt and braces
             raise ValueError(f"unknown rope scaling {kind!r}")
@@ -147,7 +195,25 @@ def rope_cos_sin(
         )
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [..., D/2]
     emb = jnp.concatenate([angles, angles], axis=-1)  # [..., D]
-    return jnp.cos(emb), jnp.sin(emb)
+    cos, sin = jnp.cos(emb), jnp.sin(emb)
+    if factor_cs != 1.0:
+        cos, sin = cos * factor_cs, sin * factor_cs
+    return cos, sin
+
+
+def apply_rope_interleaved(
+    x: jax.Array, cos: jax.Array, sin: jax.Array
+) -> jax.Array:
+    """x: [..., heads, D] with the rotated pairs at (2i, 2i+1)
+    (``rope_interleave``); cos/sin: [..., D] as :func:`rope_cos_sin` tiles
+    them (the first half is one angle a pair)."""
+    half = x.shape[-1] // 2
+    c = cos[..., None, :half]
+    s = sin[..., None, :half]
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
+    even, odd = xf[..., 0], xf[..., 1]
+    out = jnp.stack([even * c - odd * s, odd * c + even * s], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -174,16 +240,17 @@ def _moe_mlp_dense(lp: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     orig_shape = x.shape
     H = orig_shape[-1]
     xf = x.reshape(-1, H)  # [N, H]
-    router_logits = (xf @ lp["router"]).astype(jnp.float32)  # [N, E]
-    topw, topi = jax.lax.top_k(router_logits, cfg.num_experts_per_tok)
-    topw = jax.nn.softmax(topw, axis=-1).astype(x.dtype)  # [N, K]
-    one_hot = jax.nn.one_hot(topi, cfg.num_experts, dtype=x.dtype)  # [N, K, E]
+    topw, topi = _route(lp, xf, cfg)
+    # an absent expert's index falls outside one_hot's range: a zero row
+    one_hot = jax.nn.one_hot(
+        topi - cfg.local_expert_offset, cfg.experts_held, dtype=x.dtype
+    )  # [N, K, E held]
     combine = jnp.einsum("nk,nke->ne", topw, one_hot)  # [N, E]
     gate = jax.nn.silu(jnp.einsum("nh,ehi->eni", xf, mat(lp["w_gate"])))
     up = jnp.einsum("nh,ehi->eni", xf, mat(lp["w_up"]))
     down = jnp.einsum("eni,eih->enh", gate * up, mat(lp["w_down"]))  # [E, N, H]
     out = jnp.einsum("enh,ne->nh", down, combine)
-    return out.reshape(orig_shape)
+    return (out + _shared_experts(lp, xf, cfg)).reshape(orig_shape)
 
 
 # Rows N of a step at and above which a no-drop expert MLP takes the grouped
@@ -198,6 +265,35 @@ def _moe_mlp_dense(lp: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
 _GROUPED_MIN_ROWS = 256
 
 _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def _route(lp: Params, xf: jax.Array, cfg: ModelConfig):
+    """Top-k routing over the router's whole width: ``(weights [N, K],
+    published expert index [N, K])``.  The softmax over the chosen logits
+    equals a softmax over all of them renormalised over the chosen
+    (``norm_topk_prob``), so Mixtral and mistral4 share it.  The logits
+    come out of the product in float32: rounded to bfloat16 first, two
+    experts a few thousandths apart change places, and a changed expert is
+    the largest error a token can meet (on the chip at 32k tokens, top-4 of
+    128: the 90th percentile of the log-probability error against the
+    float32 reference read 0.044-0.067 with bfloat16 logits, PERF.md)."""
+    router_logits = jnp.dot(
+        xf, lp["router"], preferred_element_type=jnp.float32
+    )  # [N, E]
+    topw, topi = jax.lax.top_k(router_logits, cfg.num_experts_per_tok)
+    topw = jax.nn.softmax(topw, axis=-1)
+    if cfg.routed_scaling_factor != 1.0:
+        topw = topw * cfg.routed_scaling_factor
+    return topw.astype(xf.dtype), topi
+
+
+def _shared_experts(lp: Params, xf: jax.Array, cfg: ModelConfig):
+    """The shared experts' part: a dense SwiGLU every token takes (0 where
+    the configuration has none)."""
+    if "ws_gate" not in lp:
+        return 0
+    gate = _activate(xf @ mat(lp["ws_gate"]), cfg.hidden_act)
+    return (gate * (xf @ mat(lp["ws_up"]))) @ mat(lp["ws_down"])
 
 
 def _moe_capacity(cfg: ModelConfig, N: int) -> int:
@@ -240,12 +336,14 @@ def _moe_grouped(
     topi: jax.Array,  # [N, K] expert of each assignment
     row_valid: Optional[jax.Array],  # [N] bool, or None: every row counts
     layer: Optional[jax.Array],  # index, where lp holds the layers' stack
+    local: bool = False,  # topi may name experts this process does not hold
 ) -> jax.Array:
     """The dropless expert MLP over the ``N*K`` routed rows, sorted by
     expert: three grouped products, each row against its own expert's
     matrix.  Rows a step marks invalid (padding of a packed dispatch) are
     sorted behind the last group, where the kernel never goes, and come
-    back zero."""
+    back zero.  So are assignments to an expert held elsewhere
+    (``local``: ``topi`` already counts from this process's first expert)."""
     from ..ops.grouped_matmul import _ROW_TILE, grouped_matmul
     from .attention import _on_tpu
 
@@ -253,8 +351,14 @@ def _moe_grouped(
     N, K = topi.shape
     E = lp["w_gate"].shape[-3]
     key = topi.reshape(-1)  # [N*K] expert id per assignment
+    counted = None  # [N*K] bool: assignments some group holds
     if row_valid is not None:
-        key = jnp.where(jnp.repeat(row_valid, K), key, E)
+        counted = jnp.repeat(row_valid, K)
+    if local:
+        here = (key >= 0) & (key < E)
+        counted = here if counted is None else counted & here
+    if counted is not None:
+        key = jnp.where(counted, key, E)
     # place of each assignment in expert order (stable), from the same
     # running count the capacity path slots with; class E holds the invalid
     onehot = jax.nn.one_hot(key, E + 1, dtype=jnp.int32)  # [NK, E+1]
@@ -271,8 +375,10 @@ def _moe_grouped(
     up = product(rows, lp["w_up"], sizes)
     down = product(gate * up, lp["w_down"], sizes)  # [M, H]
     per_assign = down[dest].reshape(N, K, -1)  # un-sort
-    if row_valid is not None:  # behind the groups the kernel stored nothing
-        per_assign = jnp.where(row_valid[:, None, None], per_assign, 0)
+    if counted is not None:  # behind the groups the kernel stored nothing
+        per_assign = jnp.where(
+            counted.reshape(N, K)[:, :, None], per_assign, 0
+        )
     return jnp.sum(per_assign * topw[:, :, None], axis=1)
 
 
@@ -310,22 +416,31 @@ def _moe_mlp(
     """
     orig_shape = x.shape
     H = orig_shape[-1]
-    E = cfg.num_experts
+    E = cfg.experts_held
     K = cfg.num_experts_per_tok
     xf = x.reshape(-1, H)  # [N, H]
     N = xf.shape[0]
 
-    router_logits = (xf @ lp["router"]).astype(jnp.float32)  # [N, E]
-    topw, topi = jax.lax.top_k(router_logits, K)
-    topw = jax.nn.softmax(topw, axis=-1).astype(x.dtype)  # [N, K]
+    topw, topi = _route(lp, xf, cfg)  # [N, K]
+    # experts held elsewhere (a deployment's other chips): routing is over
+    # the router's whole width, only this process's experts compute, and
+    # the sum goes on without the absent ones' part
+    local = cfg.experts_held != cfg.num_experts
+    if local:
+        topi = topi - cfg.local_expert_offset
+    shared = _shared_experts(lp, xf, cfg)
 
     C = _moe_capacity(cfg, N)
     if _moe_takes_grouped(lp, N, C):
         valid = None if row_valid is None else row_valid.reshape(-1)
-        out = _moe_grouped(lp, xf, topw, topi, valid, layer)
-        return out.reshape(orig_shape)
+        out = _moe_grouped(lp, xf, topw, topi, valid, layer, local)
+        return (out + shared).reshape(orig_shape)
 
     flat_expert = topi.reshape(-1)  # [N*K] expert id per assignment
+    held = True
+    if local:
+        held = (flat_expert >= 0) & (flat_expert < E)
+        flat_expert = jnp.where(held, flat_expert, E)  # one_hot: a zero row
     flat_w = topw.reshape(-1)  # [N*K]
     token_of = jnp.arange(N * K, dtype=jnp.int32) // K  # [N*K]
 
@@ -333,7 +448,7 @@ def _moe_mlp(
     onehot = jax.nn.one_hot(flat_expert, E, dtype=jnp.int32)  # [NK, E]
     pos = jnp.cumsum(onehot, axis=0) * onehot  # running count where routed
     slot = jnp.sum(pos, axis=1) - 1  # [N*K]
-    keep = slot < C
+    keep = (slot < C) & held
     dispatch = jnp.where(keep, flat_expert * C + slot, E * C)  # OOB = drop
 
     buf = jnp.zeros((E * C, H), xf.dtype)
@@ -349,7 +464,7 @@ def _moe_mlp(
     )  # [N*K, H]
     per_assign = per_assign * (flat_w * keep.astype(flat_w.dtype))[:, None]
     out = jax.ops.segment_sum(per_assign, token_of, num_segments=N)
-    return out.reshape(orig_shape)
+    return (out + shared).reshape(orig_shape)
 
 
 # ---------------------------------------------------------------------------
@@ -369,6 +484,59 @@ AttnFn = Callable[
 ]
 
 
+def _latent_attention(
+    lp: Params,
+    h: jax.Array,  # [B, T, H] normed input
+    cos: jax.Array,  # [B, T, rope_dim]
+    sin: jax.Array,
+    cfg: ModelConfig,
+    attn_fn: AttnFn,
+    kv_pages: jax.Array,  # [L, 1, num_pages, page, 1, C + R]
+    layer: jax.Array,
+    q_factor: Optional[jax.Array],  # [B, T] or None
+) -> Tuple[jax.Array, jax.Array]:
+    """Latent attention (MLA) in the absorbed form, over a cache that holds
+    one row ``[c_kv | RoPE(k_r)]`` a token and nothing else.
+
+    Head ``i``'s keys are ``[c_kv W^K_i | RoPE(k_r)]`` and its values
+    ``c_kv W^V_i``; since ``q_nope . (c_kv W^K_i) = (q_nope W^K_i^T) .
+    c_kv``, the queries are carried into the latent space instead and every
+    head attends to the SAME cached row: multi-query attention whose one
+    "KV head" is the row, keys the whole row (C + R wide), values its
+    first C columns.  That is the contract every ``attn_fn`` already
+    serves -- it gets ``(q~ [.., Hq, C+R], row [.., 1, C+R], row)``, writes
+    the row once (a latent pool has one side) and returns ``[.., Hq, >=C]``
+    -- so the up-projection ``W^V`` runs after attention, on ``Hq`` rows a
+    token and not on every cached token.  The softmax scale (with YaRN's
+    factor) and the position-dependent query factor are folded into ``q~``
+    against the ``(C+R)^-0.5`` an attention call applies on its own."""
+    B, T, _ = h.shape
+    Hq = cfg.num_heads
+    C, R = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    N, V = cfg.qk_nope_head_dim, cfg.v_head_dim
+    rope = apply_rope_interleaved if cfg.rope_interleave else apply_rope
+    c_q = rms_norm(h @ mat(lp["wq_a"]), lp["q_a_norm"], cfg.rms_norm_eps)
+    q = (c_q @ mat(lp["wq_b"])).reshape(B, T, Hq, N + R)
+    kv_a = h @ mat(lp["wkv_a"])  # [B, T, C + R]
+    c_kv = rms_norm(kv_a[..., :C], lp["kv_a_norm"], cfg.rms_norm_eps)
+    k_r = rope(kv_a[..., None, C:], cos, sin)  # [B, T, 1, R]
+    row = jnp.concatenate([c_kv[..., None, :], k_r], axis=-1)  # [B, T, 1, C+R]
+    w_kvb = mat(lp["wkv_b"]).reshape(C, Hq, N + V)
+    # absorbed and scaled in float32, rounded to the compute type once
+    q_lat = jnp.einsum(
+        "bthn,chn->bthc", q[..., :N], w_kvb[..., :N],
+        preferred_element_type=jnp.float32,
+    )
+    q_rot = rope(q[..., N:].astype(jnp.float32), cos, sin)
+    fold = cfg.attn_softmax_scale * (C + R) ** 0.5
+    if q_factor is not None:
+        fold = fold * q_factor[..., None, None]
+    q_abs = (jnp.concatenate([q_lat, q_rot], axis=-1) * fold).astype(h.dtype)
+    out, kv_pages = attn_fn(q_abs, row, row, kv_pages, layer)
+    attn = jnp.einsum("bthc,chv->bthv", out[..., :C], w_kvb[..., N:])
+    return attn.reshape(B, T, Hq * V), kv_pages
+
+
 def transformer_layer(
     lp: Params,
     x: jax.Array,  # [B, T, H]
@@ -379,6 +547,7 @@ def transformer_layer(
     kv_pages: jax.Array,  # [L, 2, num_pages, page, Hkv, D]
     layer: jax.Array,  # scalar i32 layer index into kv_pages
     row_valid: Optional[jax.Array] = None,  # [B, T] bool: rows anyone reads
+    q_factor: Optional[jax.Array] = None,  # [B, T] per-position query scale
 ) -> Tuple[jax.Array, jax.Array]:
     """One decoder layer (norm -> attention -> norm -> MLP, residuals).
     Shared by the single-device layer scan and the pipeline-parallel stage
@@ -387,6 +556,13 @@ def transformer_layer(
     B, T, _ = x.shape
     D = cfg.head_dim
     h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps, cfg.rms_norm_offset)
+    if cfg.is_mla:
+        attn, kv_pages = _latent_attention(
+            lp, h, cos, sin, cfg, attn_fn, kv_pages, layer, q_factor
+        )
+        x = x + attn @ mat(lp["wo"])
+        h2 = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
+        return x + _moe_mlp(lp, h2, cfg, row_valid, layer), kv_pages
     q = h @ mat(lp["wq"])
     k = h @ mat(lp["wk"])
     v = h @ mat(lp["wv"])
@@ -421,6 +597,7 @@ def scan_layers(
     cfg: ModelConfig,
     attn_fn: AttnFn,
     row_valid: Optional[jax.Array] = None,
+    q_factor: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Scan ``transformer_layer`` over the stacked weights.
 
@@ -429,7 +606,8 @@ def scan_layers(
     cache every call (see AttnFn note above).  Shared by the single-device
     trunk and the pipeline-parallel stage loop (which passes its
     stage-local weight/KV stacks)."""
-    L = kv_pages.shape[0]
+    # layers of the stack in hand (a latent pool holds two layers a slab)
+    L = lp_stack["input_norm"].shape[0]
     # Where the expert MLP takes the grouped kernel, the experts' weights
     # stay whole and the kernel indexes the stack by layer: a custom call
     # cannot fuse the scan's slice, which would then be a copy of every
@@ -444,7 +622,8 @@ def scan_layers(
         x, kv = carry
         lp, idx = scanned
         x, kv = transformer_layer(
-            {**lp, **whole}, x, cos, sin, cfg, attn_fn, kv, idx, row_valid
+            {**lp, **whole}, x, cos, sin, cfg, attn_fn, kv, idx, row_valid,
+            q_factor,
         )
         return (x, kv), None
 
@@ -480,7 +659,6 @@ def transformer(
         tokens = tokens[:, None]
         positions = positions[:, None]
 
-    D = cfg.head_dim
     x = params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
     if cfg.scale_embeddings:  # Gemma: sqrt(hidden) in the embed dtype
         x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
@@ -494,12 +672,21 @@ def transformer(
         pos_t = jnp.arange(T, dtype=jnp.int32)
         take = pos_t[None, :] < jnp.minimum(mm_len, k)[:, None]  # [B, T]
         x = jnp.where(take[:, :, None], inj, x)
-    cos, sin = rope_cos_sin(positions, D, cfg.rope_theta, cfg.rope_scaling)  # [B, T, D]
+    cos, sin = rope_cos_sin(
+        positions, cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
+    )  # [B, T, D]
+    q_factor = None
+    if cfg.query_pos_scaling is not None:
+        beta, orig_max = cfg.query_pos_scaling
+        q_factor = 1.0 + beta * jnp.log1p(
+            (positions // orig_max).astype(jnp.float32)
+        )
 
     if squeeze and row_valid is not None:
         row_valid = row_valid[:, None]
     x, new_kv_pages = scan_layers(
-        params["layers"], kv_pages, x, cos, sin, cfg, attn_fn, row_valid
+        params["layers"], kv_pages, x, cos, sin, cfg, attn_fn, row_valid,
+        q_factor,
     )
 
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, cfg.rms_norm_offset)

@@ -31,7 +31,11 @@ Params = Dict[str, Any]
 # per-layer matmul weights safe to quantize (dense + MoE naming); the
 # contraction axis is -2 ("in") in every one of them, so the scale lives on
 # the output channel
-QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+QUANT_KEYS = (
+    "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+    # latent attention (MLA) and shared experts
+    "wq_a", "wq_b", "wkv_a", "wkv_b", "ws_gate", "ws_up", "ws_down",
+)
 
 
 @jax.tree_util.register_pytree_node_class

@@ -585,6 +585,12 @@ def _packed_unified_step(
     positions = jnp.where(valid, pos, 0)
 
     def attn_fn(q, k, v, kv, layer):
+        if cfg.is_mla:
+            out, new_kv = att.latent_packed_attention_dispatch(
+                q[0], k[0], kv, layer, page_table, base, seg_off, q_lens,
+                t_lane, t_rel, pos, valid, s_max,
+            )
+            return out[None], new_kv
         out = att.packed_ragged_attention_dispatch(
             q[0], k[0], v[0], kv, layer, page_table, base, seg_off,
             q_lens, t_lane, t_rel, s_max, cfg.sliding_window or 0,

@@ -134,7 +134,28 @@ def assemble_params(
             layers[k] = put(f"layers/{k}", bufs.pop(k))
 
     fused_qkv = f"{pre}layers.0.self_attn.qkv_proj.weight" in raw
-    if fused_qkv:
+    if cfg.is_mla:
+        # mistral4: low-rank queries, one latent projection for keys and
+        # values (kv_a_proj_with_mqa's last rows are the shared rotated key)
+        for key, suffix in (
+            ("wq_a", "q_a_proj"), ("wq_b", "q_b_proj"),
+            ("wkv_a", "kv_a_proj_with_mqa"), ("wkv_b", "kv_b_proj"),
+            ("wo", "o_proj"),
+        ):
+            layers[key] = stack(
+                f"layers/{key}",
+                lambda i, s=suffix: linear(
+                    f"{pre}layers.{i}.self_attn.{s}.weight"),
+            )
+        for key, suffix in (
+            ("q_a_norm", "q_a_layernorm"), ("kv_a_norm", "kv_a_layernorm"),
+        ):
+            layers[key] = stack(
+                f"layers/{key}",
+                lambda i, s=suffix: get(
+                    f"{pre}layers.{i}.self_attn.{s}.weight"),
+            )
+    elif fused_qkv:
         # phi3: fused qkv_proj rows are [q | k | v] (torch layout [out, in])
         q_rows = cfg.num_heads * cfg.head_dim
         kv_rows = cfg.num_kv_heads * cfg.head_dim
@@ -186,7 +207,34 @@ def assemble_params(
         lambda i: get(f"{pre}layers.{i}.post_attention_layernorm.weight"),
     )
 
-    if cfg.is_moe:
+    if cfg.is_mla:
+        # mlp.gate is the router over every published expert; this process
+        # loads the experts it holds; the shared experts are one SwiGLU
+        lo = cfg.local_expert_offset
+        layers["router"] = stack(
+            "layers/router",
+            lambda i: linear(f"{pre}layers.{i}.mlp.gate.weight"),
+        )
+        for key, name in (
+            ("w_gate", "gate_proj"), ("w_up", "up_proj"),
+            ("w_down", "down_proj"),
+        ):
+            layers[key] = stack(
+                f"layers/{key}",
+                lambda i, n=name: np.stack(
+                    [
+                        linear(f"{pre}layers.{i}.mlp.experts.{e}.{n}.weight")
+                        for e in range(lo, lo + cfg.experts_held)
+                    ]
+                ),
+            )
+            if cfg.num_shared_experts:
+                layers[key.replace("w_", "ws_")] = stack(
+                    f"layers/{key.replace('w_', 'ws_')}",
+                    lambda i, n=name: linear(
+                        f"{pre}layers.{i}.mlp.shared_experts.{n}.weight"),
+                )
+    elif cfg.is_moe:
         E = cfg.num_experts
         moe = "block_sparse_moe"
         layers["router"] = stack(

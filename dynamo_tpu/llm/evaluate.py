@@ -43,10 +43,9 @@ def window_nll(
     B, W = tokens.shape
     page = 16
     n_pages = W // page + 2  # + trash page 0 + tail slack
-    kv = jnp.zeros(
-        (cfg.num_layers, 2, n_pages, page, cfg.num_kv_heads, cfg.head_dim),
-        jnp.dtype(cfg.dtype),
-    )
+    from ..engine.kv_cache import PagedKVCache
+
+    kv = PagedKVCache(cfg, n_pages, page).pages
     page_table = jnp.arange(1, 1 + (W + page - 1) // page, dtype=jnp.int32)[
         None, :
     ]

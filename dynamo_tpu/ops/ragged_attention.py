@@ -433,11 +433,13 @@ def ragged_paged_attention_xla(
     the CPU tier-1 code path.  Takes either pool form: a ``QuantKV``
     pool's pages dequantize right after the gather (same rule the fused
     kernel applies per VMEM tile)."""
-    from ..engine.kv_cache import gather_layer_kv, index_kv_layer, kv_data
+    from ..engine.kv_cache import (
+        gather_layer_kv, index_kv_layer, kv_data, kv_num_layers,
+    )
 
     B, S, Hq, D = q.shape
     data = kv_data(kv_pages)
-    L = data.shape[0]
+    L = kv_num_layers(kv_pages)
     page_size = data.shape[3]
     P = page_table.shape[1]
     Hkv = k.shape[2]

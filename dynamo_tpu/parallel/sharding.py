@@ -38,13 +38,22 @@ def param_pspecs(cfg: ModelConfig) -> Dict[str, P]:
     specs: Dict[str, P] = {
         "embed": P(None, "tp"),
         "final_norm": P(None),
-        "layers/wq": P(None, None, "tp"),
-        "layers/wk": P(None, None, "tp"),
-        "layers/wv": P(None, None, "tp"),
         "layers/wo": P(None, "tp", None),
         "layers/input_norm": P(None, None),
         "layers/post_norm": P(None, None),
     }
+    if cfg.is_mla:
+        # latent attention serves data-parallel (ModelConfig.validate_tp
+        # refuses tp): its projections replicate, the experts go over ep
+        specs["layers/wo"] = P(None, None, None)
+        for name in ("wq_a", "wq_b", "wkv_a", "wkv_b"):
+            specs[f"layers/{name}"] = P(None, None, None)
+        for name in ("q_a_norm", "kv_a_norm"):
+            specs[f"layers/{name}"] = P(None, None)
+    else:
+        specs["layers/wq"] = P(None, None, "tp")
+        specs["layers/wk"] = P(None, None, "tp")
+        specs["layers/wv"] = P(None, None, "tp")
     if not cfg.tie_word_embeddings:
         specs["lm_head"] = P(None, "tp")
     if cfg.attention_bias:
@@ -60,6 +69,10 @@ def param_pspecs(cfg: ModelConfig) -> Dict[str, P]:
         specs["layers/w_gate"] = P(None, "ep", None, "tp")
         specs["layers/w_up"] = P(None, "ep", None, "tp")
         specs["layers/w_down"] = P(None, "ep", "tp", None)
+        if cfg.num_shared_experts:
+            specs["layers/ws_gate"] = P(None, None, "tp")
+            specs["layers/ws_up"] = P(None, None, "tp")
+            specs["layers/ws_down"] = P(None, "tp", None)
     else:
         specs["layers/w_gate"] = P(None, None, "tp")
         specs["layers/w_up"] = P(None, None, "tp")

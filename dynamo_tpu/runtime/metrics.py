@@ -224,6 +224,10 @@ class EngineMetrics:
             "dynamo_engine_kv_utilization",
             "KV cache page utilization (used/total, 0..1)",
         )
+        self.kv_bytes_per_token = reg.gauge(
+            "dynamo_engine_kv_bytes_per_token",
+            "KV pool bytes over the tokens it holds, all layers",
+        )
         self.prefix_hits = reg.counter(
             "dynamo_engine_prefix_hit_tokens",
             "Prompt tokens whose KV was reused from the prefix cache",
@@ -335,10 +339,14 @@ class EngineMetrics:
         self.mixed_tokens.labels("dispatched").inc(dispatched)
         self.mixed_tokens.labels("rectangle").inc(rectangle)
 
-    def observe_kv(self, used: int, total: int) -> None:
+    def observe_kv(
+        self, used: int, total: int, bytes_per_token: Optional[float] = None
+    ) -> None:
         self.kv_used.set(used)
         self.kv_total.set(total)
         self.kv_util.set(used / total if total else 0.0)
+        if bytes_per_token is not None:
+            self.kv_bytes_per_token.set(bytes_per_token)
 
     def observe_executable_shapes(self, n: int) -> None:
         self.executable_shapes.set(n)

@@ -358,3 +358,114 @@ def test_mesh_kernels_compile_per_shard(topo, dispatch, tp, dp, monkeypatch):
     assert "tpu_custom_call" in text
     gathered = re.findall(r"= \w+\[22,2,768[^\]]*\][^=]*all-gather", text)
     assert not gathered, gathered
+
+
+# -- latent attention (MLA): mistral-small-4-119b's widths ---------------------
+
+LATENT = dict(Hq=32, C=256, R=64, LAYERS=6, PAGES=32768, LANES=16, TABLE=2064)
+# the four packed executables benchmark/configs/mistral-small-4-119b.json fixes
+LATENT_SHAPES = [(16, 1), (256, 128), (1024, 512), (4096, 2048)]
+
+
+def _latent_pool(chip):
+    from dynamo_tpu.engine.kv_cache import LatentKV
+
+    w = LATENT
+    slab = 2 * (w["C"] + w["R"])  # two layers' rows side by side: 640 = 5 tiles
+    return LatentKV(
+        chip((w["LAYERS"] // 2, 1, w["PAGES"], PAGE, 1, slab), jnp.bfloat16),
+        w["C"],
+    )
+
+
+@pytest.mark.parametrize("Np,s_max", LATENT_SHAPES)
+def test_latent_packed_attention_compiles(chip, Np, s_max):
+    """Page DMAs out of a 32768-page pool, a 2064-wide page table in scalar
+    memory, query tiles of 256 rows x 32 heads: Mosaic refuses a page of a
+    320-wide row (not a whole number of 128-lane tiles), which is why two
+    layers share a 640-wide slab row (``kv_cache.LatentKV``)."""
+    from dynamo_tpu.ops.latent_attention import latent_packed_attention
+
+    w = LATENT
+    q = chip((Np, w["Hq"], w["C"] + w["R"]), jnp.bfloat16)
+    table = chip((w["LANES"], w["TABLE"]), jnp.int32)
+    vec = chip((w["LANES"],), jnp.int32)
+
+    def call(q, pool, table, base, off, lens, layer):
+        return latent_packed_attention(q, pool, table, base, off, lens, s_max, layer)
+
+    compiled = jax.jit(call).lower(
+        q, _latent_pool(chip), table, vec, vec, vec, chip((), jnp.int32)
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "latent_packed_attention" in text
+    # the pool reaches the kernel as it lies: no copy of it, no second pool
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+def test_latent_decode_attention_compiles(chip):
+    from dynamo_tpu.ops.latent_attention import latent_decode_attention
+
+    w = LATENT
+    q = chip((w["LANES"], w["Hq"], w["C"] + w["R"]), jnp.bfloat16)
+    table = chip((w["LANES"], w["TABLE"]), jnp.int32)
+    vec = chip((w["LANES"],), jnp.int32)
+    compiled = jax.jit(latent_decode_attention).lower(
+        q, _latent_pool(chip), table, vec, chip((), jnp.int32)
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "latent_decode_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_latent_layers_scatter_and_attend_without_copying_the_pool(chip, monkeypatch):
+    """The trunk over a latent pool, as the packed step runs it: every layer
+    scatters its rows into the slab and attends through the kernel.  XLA
+    would lay a 320-wide pool out pages-minor and copy all of it before
+    every kernel call; with 640-wide slab rows the compiled trunk holds the
+    pool once (its temporaries are activations, far under the pool's 2 GB)
+    and makes no array of the pool's shape but the pool."""
+    import re
+
+    from dynamo_tpu.engine import attention as att
+    from dynamo_tpu.engine import model as M
+    from dynamo_tpu.engine.config import ModelConfig
+
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    cfg = ModelConfig(
+        vocab_size=256, hidden_size=4096, intermediate_size=2048, num_layers=6,
+        num_heads=32, num_kv_heads=32, head_dim=128, dtype="bfloat16",
+        num_experts=128, num_experts_per_tok=4, num_local_experts=4,
+        moe_capacity_factor=32.0, num_shared_experts=1, q_lora_rank=1024,
+        kv_lora_rank=256, qk_nope_head_dim=64, qk_rope_head_dim=64,
+        v_head_dim=128, rope_interleave=True,
+    )
+    shapes = jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
+    stack = jax.tree.map(lambda a: chip(a.shape, a.dtype), shapes["layers"])
+    w, Np, s_max = LATENT, 1024, 512
+    vec = chip((w["LANES"],), jnp.int32)
+    rows = chip((Np,), jnp.int32)
+
+    def trunk(stack, pool, x, cos, sin, table, base, off, lens, lane, rel):
+        valid = lane < w["LANES"]
+        pos = base[jnp.clip(lane, 0, w["LANES"] - 1)] + rel
+
+        def attend(q, k, v, kv, layer):
+            out, kv = att.latent_packed_attention_dispatch(
+                q[0], k[0], kv, layer, table, base, off, lens, lane, rel,
+                pos, valid, s_max)
+            return out[None], kv
+
+        return M.scan_layers(stack, pool, x, cos, sin, cfg, attend, valid[None])
+
+    compiled = jax.jit(trunk, donate_argnums=(1,)).lower(
+        stack, _latent_pool(chip), chip((1, Np, 4096), jnp.bfloat16),
+        chip((1, Np, 64), jnp.float32), chip((1, Np, 64), jnp.float32),
+        chip((w["LANES"], w["TABLE"]), jnp.int32), vec, vec, vec, rows, rows,
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%latent_packed_attention[.\d]* = ", text)) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    made = [l for l in text.splitlines()
+            if re.search(r"= bf16\[3,(1,)?32768,16,(1,)?640\]\S* (copy|transpose)\(", l)]
+    assert not made, made[:3]
