@@ -899,6 +899,33 @@ def child_kernels(args) -> None:
         for wide in (s_max, s_max - 1):
             packed_case(Np, s_max, quant, wide=wide)
 
+    # the work-list kernel a dense pool of 128-lane heads takes (Mixtral's
+    # and Mistral-7B's widths; TinyLlama's 64-wide heads keep the grid
+    # kernel above): lanes own their pages, the dispatch's rows are
+    # scattered first and every key is read from the pool
+    wHq, wHkv, wD = (4, 2, 128) if args.rehearse else (32, 8, 128)
+    wpool = rnd(11, (2, 2, 1 + B * P, page, wHkv, wD))
+    wtable = jnp.asarray(1 + np.arange(B * P).reshape(B, P), jnp.int32)
+    for Np, s_max in shapes:
+        for window in (0, P * page // 2):
+            lens, off, lane, rel = packed_layout(Np, s_max, B)
+            base = rs.randint(0, P * page - s_max, (B,)).astype(np.int32)
+            qp, kp, vp = (rnd(12, (Np, wHq, wD)), rnd(13, (Np, wHkv, wD)),
+                          rnd(14, (Np, wHkv, wD)))
+            lane_c = np.minimum(lane, B - 1)
+            written = att.write_packed_kv(
+                wpool, kp, vp, wtable, jnp.asarray(lane),
+                jnp.asarray(base[lane_c] + rel), jnp.asarray(lane < B), 1)
+            got = packed_ragged_attention(
+                qp, kp, vp, written, wtable, base, off, lens, s_max, 1,
+                window, interpret=interp)
+            ref = packed_ragged_attention_xla(
+                qp, kp, vp, wpool, wtable, base, off, lens,
+                jnp.asarray(lane), jnp.asarray(rel), s_max, 1, window)
+            record("packed_ragged_attention (work list)",
+                   f"Np={Np} s_max={s_max} q={lens[-1]} window={window}",
+                   cfg.dtype, got, ref, valid=lane < B)
+
     for S in rect_S:
         for quant in ((False, True) if S == rect_S[1] else (False,)):
             q, k, v = rnd(4, (B, S, Hq, D)), rnd(5, (B, S, Hkv, D)), rnd(6, (B, S, Hkv, D))

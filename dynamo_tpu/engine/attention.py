@@ -294,6 +294,22 @@ def _pallas_ragged_enabled(page_size: int, Hq: int, Hkv: int, D: int) -> bool:
     return _on_tpu()
 
 
+def packed_walks_work_list(kv_pages, Hq: int, Hkv: int, D: int) -> bool:
+    """Whether this pool's packed launch is a work-list kernel (the latent
+    kernels; a dense pair pool's, ``ragged_attention._takes_work_list``):
+    such a launch has no step for a page group, so the width of the page
+    table it is handed costs it nothing.  Read where the step's trace reads
+    it (under the engine's mesh)."""
+    page = kv_data(kv_pages).shape[3]
+    if kv_is_latent(kv_pages):
+        return latent_kernels_enabled(page)
+    from ..ops.ragged_attention import _takes_work_list
+
+    return _pallas_ragged_enabled(page, Hq, Hkv, D) and _takes_work_list(
+        D, kv_is_quantized(kv_pages)
+    )
+
+
 def _scale_args(scales):
     """The int8 pool's row scales as (operands, specs) of a per-shard
     kernel call: they carry no head axis and ride replicated."""

@@ -967,6 +967,9 @@ class JaxEngine:
         # the kernel cannot serve fails engine construction, not a user's
         # first long prompt) and again on every triple the budget resolves
         self._packed_fits = self._packed_kernel_bound()
+        # a packed step that is one step takes the page table at its full
+        # width where its attention walks a work list (_dispatch_unified)
+        self._packed_full_table = self._packed_walks_work_list()
         # queue-side prefetch: window resolved here, walks issued by the
         # tick loop from queue position (_drive_prefetch), finished or
         # cancelled per request
@@ -1182,6 +1185,19 @@ class JaxEngine:
         if self.mesh is None or self._sp > 1 or self._pp > 1:
             return contextlib.nullcontext()
         return jax.set_mesh(self.mesh)
+
+    def _packed_walks_work_list(self) -> bool:
+        """Whether this engine's packed steps attend through a work-list
+        kernel, which the width of the page table costs nothing."""
+        from . import attention as att
+
+        if not (self._mixed and self._packed):
+            return False
+        m = self.model_cfg
+        with self.mesh_scope():  # the gates read tp from the context mesh
+            return att.packed_walks_work_list(
+                self.kv.pages, m.num_heads, m.num_kv_heads, m.head_dim
+            )
 
     def _packed_kernel_bound(self) -> Callable[[int, int], bool]:
         """``fits(Np, s_max)`` for this engine's packed dispatches.  Always
@@ -4308,7 +4324,15 @@ class JaxEngine:
             s_spec = 1 + (pow2_bucket(max_d) if max_d else 0)
         self._sync_device_state()
         d = self._dev
-        Pb = self._live_page_bucket()
+        # a work-list kernel has no step for a page group, so a dispatch of
+        # one step (every mixed step, K = 1 of the ramp) takes the whole
+        # table: its width is then no axis of those executables.  The fused
+        # steps keep the bucket: the decode kernel walks the table's width
+        Pb = (
+            sched.max_pages
+            if self._packed_full_table and num_steps == 1
+            else self._live_page_bucket()
+        )
         # decode-capable lanes: contribute one fresh row each (packed) /
         # one live column (rectangle); the count feeds the occupancy
         # histograms either way
@@ -4417,6 +4441,13 @@ class JaxEngine:
                     "k": num_steps,
                     "np": Np,
                 }
+                # the launch as the dense pools' kernel walks it: its work
+                # items, and how many of them take the small tile
+                from ..ops.ragged_attention import packed_item_counts
+
+                dispatch_meta["items"], dispatch_meta["small"] = (
+                    packed_item_counts(q_host[live], s_max)
+                )
                 if self.model_cfg.is_mla:
                     # which latent path the dispatch takes (read where the
                     # step's trace reads it)

@@ -591,12 +591,15 @@ def _packed_unified_step(
                 t_lane, t_rel, pos, valid, s_max,
             )
             return out[None], new_kv
-        out = att.packed_ragged_attention_dispatch(
-            q[0], k[0], v[0], kv, layer, page_table, base, seg_off,
-            q_lens, t_lane, t_rel, s_max, cfg.sliding_window or 0,
-        )
+        # rows first: a dense pool's kernel reads every key from the pool;
+        # the other paths read the pool below ``base`` and are none the
+        # wiser
         new_kv = att.write_packed_kv(
             kv, k[0], v[0], page_table, t_lane, pos, valid, layer
+        )
+        out = att.packed_ragged_attention_dispatch(
+            q[0], k[0], v[0], new_kv, layer, page_table, base, seg_off,
+            q_lens, t_lane, t_rel, s_max, cfg.sliding_window or 0,
         )
         return out[None], new_kv
 

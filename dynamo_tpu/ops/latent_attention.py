@@ -23,10 +23,12 @@ One kernel body serves both launches; what differs is the work list.
 * :func:`latent_decode_attention` -- the fused decode steps: one query row a
   lane.
 
-**Work items, not a lane x page grid.**  The pair pools' kernels walk a
-grid of (lane, page group) and pay a grid step for every group of the
-TABLE's width, live or not; at 32k-token contexts that is thousands of dead
-steps a lane.  Here the grid is a list of work items -- (lane, block of up
+**Work items, not a lane x page grid.**  A grid of (lane, page group) pays
+a grid step for every group of the TABLE's width, live or not; at 32k-token
+contexts that is thousands of dead steps a lane.  (The pair pools' packed
+launch walked one until it took this form too:
+``ragged_attention._work_list_kernel``, over :func:`packed_work_list`.)
+Here the grid is a list of work items -- (lane, block of up
 to ``qb`` query rows) -- built on the device from the dispatch's segment
 table, and each item loops over exactly the key blocks its rows can see
 (``fori_loop`` with a dynamic trip count).  Pages are fetched HBM->VMEM by

@@ -40,12 +40,17 @@ Two operand layouts share the math: the original **rectangle**
 the **fully-packed** flat token axis (ISSUE 10,
 :func:`packed_ragged_attention` / :func:`packed_ragged_attention_xla`
 below) whose trunk-side win is the whole point -- see the section
-comment ahead of the packed kernel.
+comment ahead of the packed kernel.  The packed launch has two kernels,
+chosen by the pool at trace time (:func:`_takes_work_list`): a dense pool
+of 128-lane heads walks a work list with its pages by DMA (the section
+ahead of :func:`_work_list_kernel`); an int8 pool and narrower heads keep
+the page-group grid described above.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -77,12 +82,17 @@ def _vmem_limit(need: int) -> int:
     return min(VMEM_CAP_BYTES, max(need + need // 2, 16 << 20))
 
 
+def _sublanes(dtype) -> int:
+    """Rows of one packed (sublane) tile of ``dtype``: 8 of 32 bits."""
+    return 8 * max(4 // jnp.dtype(dtype).itemsize, 1)
+
+
 def _tile_bytes(shape, dtype) -> int:
     """Bytes ``shape`` occupies in VMEM: the last dimension pads to 128
     lanes, the one before it to the dtype's sublane pack (8 rows of 32
     bits)."""
     item = jnp.dtype(dtype).itemsize
-    sub = 8 * max(4 // item, 1)
+    sub = _sublanes(dtype)
     dims = list(shape)
     dims[-1] = -(-dims[-1] // 128) * 128
     if len(dims) > 1:
@@ -673,6 +683,319 @@ def _packed_kernel(
         _for_blocks(n_live, block)
 
 
+# ---------------------------------------------------------------------------
+# the packed launch over a dense pair pool: a work list, pages by DMA
+# ---------------------------------------------------------------------------
+#
+# The grid kernel above pays a grid step for every page group of the TABLE's
+# width on every lane, live or not, multiplies 64 keys a step, and holds the
+# whole packed q/k/v/out in VMEM.  A dense (bf16/f32) pool takes this kernel
+# instead: the grid is a list of work items -- (lane, block of up to
+# ``_WL_Q_BLOCK`` query rows), built on the device by
+# ``latent_attention.packed_work_list`` -- and an item loops over exactly the
+# key blocks its rows can see: from the block that holds position ``pos0 -
+# window + 1`` (block 0 without a window) to the block of its last row's own
+# position.  The dispatch's fresh rows are already in the pool (the step
+# scatters them first), so there is one source of keys and ``kpos <= qpos``
+# alone parts fresh from resident.  A key block is ``_WL_KEY_BLOCK`` keys:
+# its K and V pages come HBM->VMEM by DMA, issued and waited for in rolled
+# loops over the block's LIVE pages (the descriptors in the kernel's jaxpr do
+# not grow with the block), double-buffered against the block's compute.  An
+# item's queries come in and its rows go out by DMA; nothing is resident but
+# a tile, so VMEM does not grow with ``Np``.  An item of a few rows (a decode
+# lane riding a chunk, every lane of a fused dispatch's first step) takes a
+# small tile.
+#
+# Output rows past an item's own (the tail of its tile) are written as zeros
+# and may overlap the next segments; items run in ascending row order and
+# each waits for its output copy, so the owner's write lands last.  Rows no
+# item covers keep the zeros the output buffer is created with.
+
+# query rows (tokens) of one work item, and the rows at or under which an
+# item takes the small tile
+_WL_Q_BLOCK = 256
+_WL_SMALL_ROWS = 8
+# keys of one key block
+_WL_KEY_BLOCK = 512
+# float32 score tiles ``[heads, rows, keys]`` one step of the head loop may
+# hold: at 32 query heads over 8 that is four kv heads a step, which the chip
+# ran a quarter faster than one (their chains overlap) and no slower than
+# eight (PERF.md, PR 32)
+_WL_SCORE_BYTES = 8 << 20
+
+
+def _takes_work_list(D: int, quant: bool) -> bool:
+    """Which packed kernel a pool takes, read off the input at trace time:
+    the work list for a dense pool whose head dimension is whole 128-lane
+    tiles (Mosaic will not slice a page of narrower rows for a DMA); the
+    grid kernel for an int8 pool, whose row scales it dequantizes in the
+    read, and for narrow heads."""
+    return not quant and D % 128 == 0
+
+
+def _work_list_tiles(s_max: int, dtype):
+    """The kernel's tiles as ``(copy rows, tile rows)``, small first: an
+    item moves ``copy`` rows by DMA and computes on a tile padded to whole
+    sublanes.  One tile where a query block is no larger than the small
+    tile (the ``(lanes, 1)`` step)."""
+    qb = min(s_max, _WL_Q_BLOCK)
+    sub = _sublanes(dtype)
+    small = min(_WL_SMALL_ROWS, qb)
+    tiles = [(small, max(small, sub))]
+    if qb > small:
+        tiles.append((qb, max(qb, sub)))
+    return qb, tiles
+
+
+def packed_item_counts(q_lens, s_max: int):
+    """``(items, small)`` of a packed launch over a dense pool, from the
+    lanes' fresh rows on the host: its live work items, and how many of
+    them take the small tile (the tick's ``dispatch`` annotation)."""
+    qb, tiles = _work_list_tiles(s_max, jnp.float32)
+    small = tiles[0][0]
+    items = n_small = 0
+    for n in q_lens:
+        full, rest = divmod(int(n), qb)
+        items += full + (rest > 0)
+        n_small += full * (qb <= small) + (0 < rest <= small)
+    return items, n_small
+
+
+def _work_list_kernel(
+    # scalar prefetch
+    layer_ref,  # [1] layer index
+    pt_ref,  # [B, P] page table
+    w_lane,  # [W] lane of each work item
+    w_row0,  # [W] its first row in the packed axis
+    w_pos0,  # [W] that row's position
+    w_rows,  # [W] its rows (0 = no work)
+    # operands (HBM)
+    q_hbm,  # [Np, Hq, D]
+    kv_hbm,  # [L, 2, num_pages, page, Hkv, D], the fresh rows in it
+    _o_init,  # the zeroed output buffer (aliased to o_hbm)
+    o_hbm,  # [Np, Hq, D]
+    # scratch
+    q_v,  # [rows_t, Hq, D] an item's queries as they lie in HBM
+    q_t,  # [Hkv, n_rep * rows_t, D] heads-major
+    kbuf,  # [2, 2, KB, Hkv, D] two slots of a key block's K and V pages
+    kv_t,  # [2, Hkv, KB, D] the current block heads-major
+    m_scr, l_scr,  # [Hkv, n_rep * rows_t, 1]
+    acc_scr,  # [Hkv, n_rep * rows_t, D]
+    o_v,  # [rows_t, Hq, D]
+    sem_q, sem_kv, sem_o,
+    *,
+    tiles,
+    window: int,
+):
+    w = pl.program_id(0)
+    rows = w_rows[w]
+    _, _, KB, Hkv, D = kbuf.shape
+    Hq = q_v.shape[1]
+    n_rep = Hq // Hkv
+    page = kv_hbm.shape[3]
+    P = pt_ref.shape[1]
+    n_pg = KB // page
+    scale = 1.0 / (D ** 0.5)
+    layer = layer_ref[0]
+    # kv heads a step, while their score tiles stay within budget
+    M_wide = n_rep * tiles[-1][1]
+    hb = math.gcd(Hkv, max(_WL_SCORE_BYTES // (4 * M_wide * KB), 1))
+
+    @pl.when(w == 0)
+    def _clear():
+        # a block's dead pages are never fetched: what the slots hold there
+        # meets a probability of zero, and must be finite
+        kbuf[...] = jnp.zeros(kbuf.shape, kbuf.dtype)
+
+    def page_copy(pid, slot, j):
+        """One page's K and V into place ``j`` of a slot."""
+        return pltpu.make_async_copy(
+            kv_hbm.at[layer, :, pid],
+            kbuf.at[slot, :, pl.ds(j * page, page)],
+            sem_kv.at[slot],
+        )
+
+    def pages_of(kb, pg_lo, pg_hi):
+        """The live pages of key block ``kb``."""
+        return jnp.maximum(kb * n_pg, pg_lo), jnp.minimum((kb + 1) * n_pg, pg_hi)
+
+    def fetch(lane, kb, slot, pg_lo, pg_hi):
+        lo, hi = pages_of(kb, pg_lo, pg_hi)
+
+        def start(pg, carry):
+            page_copy(pt_ref[lane, pg], slot, pg - kb * n_pg).start()
+            return carry
+
+        jax.lax.fori_loop(lo, hi, start, 0)
+
+    def wait(kb, slot, pg_lo, pg_hi):
+        lo, hi = pages_of(kb, pg_lo, pg_hi)
+
+        def done(pg, carry):
+            page_copy(0, slot, 0).wait()
+            return carry
+
+        jax.lax.fori_loop(lo, hi, done, 0)
+
+    def attend(copy, nrow, lane, row0, pos0):
+        """Online softmax of an item's first ``nrow`` tokens (``copy`` of
+        them moved) over the key blocks they can see."""
+        M = n_rep * nrow
+        q_in = pltpu.make_async_copy(
+            q_hbm.at[pl.ds(row0, copy)], q_v.at[pl.ds(0, copy)], sem_q.at[0]
+        )
+        q_in.start()
+        last = pos0 + rows - 1  # the last live row's position
+        first = jnp.maximum(pos0 - window + 1, 0) if window > 0 else 0
+        pg_lo, pg_hi = first // page, jnp.minimum(last // page + 1, P)
+        kb_lo, kb_hi = first // KB, last // KB + 1
+        fetch(lane, kb_lo, 0, pg_lo, pg_hi)
+        q_in.wait()
+        q_t[:, :M] = (
+            q_v[:nrow].transpose(1, 0, 2).reshape(Hkv, M, D)
+        )
+        m_scr[:, :M] = jnp.full((Hkv, M, 1), _NEG_INF, jnp.float32)
+        l_scr[:, :M] = jnp.zeros((Hkv, M, 1), jnp.float32)
+        acc_scr[:, :M] = jnp.zeros((Hkv, M, D), jnp.float32)
+
+        def block(kb, carry):
+            slot = (kb - kb_lo) % 2
+            wait(kb, slot, pg_lo, pg_hi)
+
+            @pl.when(kb + 1 < kb_hi)
+            def _():
+                fetch(lane, kb + 1, 1 - slot, pg_lo, pg_hi)
+
+            for side in range(2):
+                kv_t[side] = (
+                    kbuf[slot, side].transpose(1, 0, 2).astype(kv_t.dtype)
+                )
+            # a row's position: heads of one kv group lie (n_rep, nrow)
+            tok = jax.lax.rem(
+                jax.lax.broadcasted_iota(jnp.int32, (M, KB), 0), nrow
+            )
+            qpos = pos0 + tok
+            kpos = kb * KB + jax.lax.broadcasted_iota(jnp.int32, (M, KB), 1)
+            keep = kpos <= qpos
+            if window > 0:
+                keep = keep & (kpos > qpos - window)
+
+            def heads(i, carry):
+                # ``hb`` kv heads a step: independent chains the scheduler
+                # can overlap (one head's softmax under another's matmul)
+                hs = pl.ds(i * hb, hb)
+                k, v = kv_t[0, hs], kv_t[1, hs]  # [hb, KB, D]
+                s = jax.lax.dot_general(
+                    q_t[hs, :M], k, (((2,), (2,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32,
+                )  # [hb, M, KB]
+                s = jnp.where(keep, s * scale, _NEG_INF)
+                m_prev = m_scr[hs, :M]
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(s, axis=-1, keepdims=True)
+                )
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new)
+                pv = jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32,
+                )  # [hb, M, D]
+                m_scr[hs, :M] = m_new
+                l_scr[hs, :M] = l_scr[hs, :M] * alpha + jnp.sum(
+                    p, axis=-1, keepdims=True
+                )
+                acc_scr[hs, :M] = acc_scr[hs, :M] * alpha + pv
+                return carry
+
+            jax.lax.fori_loop(0, Hkv // hb, heads, 0)
+            return carry
+
+        jax.lax.fori_loop(kb_lo, kb_hi, block, 0)
+        out = (acc_scr[:, :M] / l_scr[:, :M]).astype(o_v.dtype)
+        out = out.reshape(Hq, nrow, D).transpose(1, 0, 2)  # [nrow, Hq, D]
+        mine = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0) < rows
+        o_v[:nrow] = jnp.where(mine, out, jnp.zeros_like(out))
+        o_out = pltpu.make_async_copy(
+            o_v.at[pl.ds(0, copy)], o_hbm.at[pl.ds(row0, copy)], sem_o.at[0]
+        )
+        o_out.start()
+        o_out.wait()
+
+    @pl.when(rows > 0)
+    def _item():
+        args = (w_lane[w], w_row0[w], w_pos0[w])
+        (small, small_t), (wide, wide_t) = tiles[0], tiles[-1]
+        if len(tiles) == 1:
+            attend(small, small_t, *args)
+            return
+
+        @pl.when(rows <= small)
+        def _():
+            attend(small, small_t, *args)
+
+        @pl.when(rows > small)
+        def _():
+            attend(wide, wide_t, *args)
+
+
+def _packed_work_list_attention(
+    q, kv_pages, page_table, base, seg_off, q_lens, *, s_max, layer, window,
+    interpret,
+):
+    """The packed launch over a dense pool that already holds the
+    dispatch's rows (see the section comment): ``[Np, Hq, D]``."""
+    from .latent_attention import packed_work_list
+
+    Np, Hq, D = q.shape
+    L, _, num_pages, page, Hkv, _ = kv_pages.shape
+    qb, tiles = _work_list_tiles(s_max, q.dtype)
+    if s_max % qb:
+        raise ValueError(f"s_max {s_max} is not a multiple of {qb}")
+    n_rep = Hq // Hkv
+    rows_t = tiles[-1][1]
+    KB = max(page, _WL_KEY_BLOCK // page * page)
+    i32 = lambda x: x.astype(jnp.int32)  # noqa: E731
+    lane, row0, pos0, rows = packed_work_list(
+        i32(base), i32(seg_off), i32(q_lens), Np, qb
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(lane.shape[0],),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((rows_t, Hq, D), q.dtype),
+            pltpu.VMEM((Hkv, n_rep * rows_t, D), q.dtype),
+            pltpu.VMEM((2, 2, KB, Hkv, D), kv_pages.dtype),
+            pltpu.VMEM((2, Hkv, KB, D), q.dtype),
+            pltpu.VMEM((Hkv, n_rep * rows_t, 1), jnp.float32),
+            pltpu.VMEM((Hkv, n_rep * rows_t, 1), jnp.float32),
+            pltpu.VMEM((Hkv, n_rep * rows_t, D), jnp.float32),
+            pltpu.VMEM((rows_t, Hq, D), q.dtype),
+            pltpu.SemaphoreType.DMA((1,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_work_list_kernel, tiles=tiles, window=window),
+        out_shape=jax.ShapeDtypeStruct((Np, Hq, D), q.dtype),
+        grid_spec=grid_spec,
+        input_output_aliases={8: 0},  # the zeroed buffer, after 6 scalars
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_CAP_BYTES,
+        ),
+        interpret=interpret,
+        name="packed_ragged_attention",
+    )(
+        jnp.clip(jnp.asarray(layer, jnp.int32), 0, L - 1).reshape(1),
+        jnp.clip(i32(page_table), 0, num_pages - 1),
+        lane, row0, pos0, rows,
+        q, kv_pages, jnp.zeros((Np, Hq, D), q.dtype),
+    )
+
+
 def packed_vmem_bytes(
     Np, s_max, Hq, Hkv, D, page, G, dtype, kv_dtype, quant
 ) -> int:
@@ -707,7 +1030,10 @@ def packed_shape_fits(
     """Whether :func:`packed_ragged_attention` can hold ``(Np, s_max)`` at
     these widths -- the bound the engine checks before it lets a mixed
     dispatch mint a shape (never discovered at a user's first long
-    prompt)."""
+    prompt).  Only the grid kernel has one: the work-list kernel holds a
+    tile, whatever the packed shape."""
+    if _takes_work_list(D, quant):
+        return True
     return (
         packed_vmem_bytes(
             Np, s_max, Hq, Hkv, D, page, group, dtype, kv_dtype, quant
@@ -735,14 +1061,23 @@ def packed_ragged_attention(
     interpret: bool = False,
     kv_scales: jax.Array | None = None,  # [L, 2, num_pages, page] int8 pool
 ) -> jax.Array:
-    """Packed-layout ragged paged attention (see the section comment):
-    one flat ``[Np]`` token axis, per-lane segment offsets, the same
-    page-group-streaming grid as :func:`ragged_paged_attention`.  The
-    packed operands live in VMEM for the whole launch, so ``Np`` (the
-    mixed-dispatch token budget) bounds the resident footprint:
-    :func:`packed_vmem_bytes` is what the call asks the compiler for and
-    :func:`packed_shape_fits` the bound callers check.  ``kv_scales`` arms
-    the fused int8 dequant, exactly as in the rectangle kernel."""
+    """Packed-layout ragged paged attention (see the section comments):
+    one flat ``[Np]`` token axis, per-lane segment offsets.  The pool
+    already holds the dispatch's rows (``step`` scatters them first).  A
+    dense pool of 128-lane heads takes the work-list kernel, which reads
+    every key from the pool and ignores ``k``/``v``/``group``.  An int8
+    pool (``kv_scales``) and narrow heads (:func:`_takes_work_list`) keep
+    the page-group-streaming grid of :func:`ragged_paged_attention` with
+    the fused dequant of the rectangle kernel: it reads the pool below
+    ``base`` and the fresh rows from ``k``/``v``, and holds the packed
+    operands in VMEM for the whole launch, so ``Np`` bounds its footprint
+    (:func:`packed_vmem_bytes`, :func:`packed_shape_fits`).  Chosen by the
+    pool's type at trace time: one kernel an executable."""
+    if _takes_work_list(q.shape[2], kv_scales is not None):
+        return _packed_work_list_attention(
+            q, kv_pages, page_table, base, seg_off, q_lens, s_max=s_max,
+            layer=layer, window=window, interpret=interpret,
+        )
     Np, Hq, D = q.shape
     L, _, num_pages, page, Hkv, _ = kv_pages.shape
     B, P = page_table.shape

@@ -14,6 +14,7 @@ this file, and only the worker that runs it may make the call.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -108,6 +109,93 @@ def test_packed_ragged_attention_compiles(chip, Np, s_max, widths, quant):
         q, kv, kv, pool, table, vec, vec, vec, scales
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the four packed executables the Mistral family's configurations fix
+# (benchmark/configs/mixtral-8x7b.json, mistral-7b.json), at their lanes
+BENCH_PACKED_SHAPES = [(16, 1), (128, 64), (512, 256), (1024, 512)]
+
+
+@pytest.mark.parametrize("window", [0, 4096], ids=["mixtral", "mistral-7b"])
+@pytest.mark.parametrize("Np,s_max", BENCH_PACKED_SHAPES)
+def test_packed_work_list_compiles(chip, Np, s_max, window):
+    """The work-list kernel of a dense pool at the widths Mixtral-8x7B and
+    Mistral-7B share (32 heads over 8, 128 wide), without a window and
+    under Mistral-7B's: queries, pages and rows by DMA, a tile resident."""
+    from dynamo_tpu.ops.ragged_attention import (
+        _takes_work_list, packed_ragged_attention,
+    )
+
+    w = MIXTRAL
+    assert _takes_work_list(w["D"], False)
+    pool, _ = _pool(chip, w, False)
+    table = chip((16, 512), jnp.int32)
+    vec = chip((16,), jnp.int32)
+    q = chip((Np, w["Hq"], w["D"]), jnp.bfloat16)
+    kv = chip((Np, w["Hkv"], w["D"]), jnp.bfloat16)
+
+    def call(q, k, v, pool, table, base, off, lens):
+        return packed_ragged_attention(
+            q, k, v, pool, table, base, off, lens, s_max=s_max, layer=3,
+            window=window,
+        )
+
+    text = jax.jit(call).lower(
+        q, kv, kv, pool, table, vec, vec, vec
+    ).compile().as_text()
+    # one kernel, under the name and with the result the roofline's reader
+    # finds its launches by
+    assert len(re.findall(r"%packed_ragged_attention[.\d]* = ", text)) == 1
+    assert f"bf16[{Np},{w['Hq']},{w['D']}]" in text
+
+
+def test_pair_pool_layers_scatter_and_attend_without_copying_the_pool(chip, monkeypatch):
+    """The trunk over a dense pair pool, as the packed step runs it: every
+    layer scatters its rows into the pool and the work-list kernel reads
+    them from there.  The compiled trunk holds the pool once (1.6 GB here:
+    its temporaries are activations) and traces one packed attention
+    kernel, not the grid kernel beside it."""
+    from dynamo_tpu.engine import attention as att
+    from dynamo_tpu.engine import model as M
+    from dynamo_tpu.engine.config import ModelConfig
+
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    cfg = ModelConfig(
+        vocab_size=256, hidden_size=4096, intermediate_size=14336,
+        num_layers=4, num_heads=32, num_kv_heads=8, head_dim=128,
+        dtype="bfloat16", sliding_window=4096,
+    )
+    shapes = jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
+    stack = jax.tree.map(lambda a: chip(a.shape, a.dtype), shapes["layers"])
+    lanes, pages, Np, s_max = 16, 6144, 1024, 512
+    pool = chip((4, 2, pages, PAGE, 8, 128), jnp.bfloat16)
+    vec = chip((lanes,), jnp.int32)
+    rows = chip((Np,), jnp.int32)
+
+    def trunk(stack, pool, x, cos, sin, table, base, off, lens, lane, rel):
+        valid = lane < lanes
+        pos = base[jnp.clip(lane, 0, lanes - 1)] + rel
+
+        def attend(q, k, v, kv, layer):
+            kv = att.write_packed_kv(kv, k[0], v[0], table, lane, pos, valid, layer)
+            out = att.packed_ragged_attention_dispatch(
+                q[0], k[0], v[0], kv, layer, table, base, off, lens, lane,
+                rel, s_max, cfg.sliding_window)
+            return out[None], kv
+
+        return M.scan_layers(stack, pool, x, cos, sin, cfg, attend, valid[None])
+
+    compiled = jax.jit(trunk, donate_argnums=(1,)).lower(
+        stack, pool, chip((1, Np, 4096), jnp.bfloat16),
+        chip((1, Np, 128), jnp.float32), chip((1, Np, 128), jnp.float32),
+        chip((lanes, 512), jnp.int32), vec, vec, vec, rows, rows,
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%packed_ragged_attention[.\d]* = ", text)) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 29
+    made = [l for l in text.splitlines()
+            if re.search(r"= bf16\[4,2,6144,16,8,128\]\S* (copy|transpose)\(", l)]
+    assert not made, made[:3]
 
 
 def test_packed_bound_refuses_what_the_kernel_cannot_hold():

@@ -362,7 +362,24 @@ def _trace_events(trace_dir):
     return events
 
 
+def _run_queue_waits():
+    """By thread of this process, the seconds it has waited on a run queue
+    for a core (``/proc/<pid>/task/<tid>/schedstat``, second field; empty
+    where the kernel keeps none)."""
+    waits = {}
+    for path in glob.glob("/proc/self/task/*/schedstat"):
+        try:
+            with open(path) as f:
+                waits[path] = int(f.read().split()[1]) * 1e-9
+        except (OSError, IndexError, ValueError):
+            pass
+    return waits
+
+
 async def _traced_burst(trace_dir, profile):
+    """The tick records of a traced burst, and the longest any one thread
+    of this process was kept off a core while it ran: the slack two clocks
+    read a few lines apart need on a loaded machine."""
     import jax
 
     engine = tiny_engine()
@@ -375,6 +392,7 @@ async def _traced_burst(trace_dir, profile):
         opts.python_tracer_level = 0
         opts.host_tracer_level = 1
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        before = _run_queue_waits()
         try:
             await asyncio.sleep(0.2)  # parked: nothing is due
             await asyncio.gather(*[
@@ -383,11 +401,14 @@ async def _traced_burst(trace_dir, profile):
             ])
             await asyncio.sleep(0.2)  # parked again before the trace ends
             recs = profiling.profiler.records()
+            waited = max(
+                (w - before.get(tid, 0.0)
+                 for tid, w in _run_queue_waits().items()), default=0.0)
         finally:
             jax.profiler.stop_trace()
     finally:
         await engine.stop()
-    return recs
+    return recs, waited
 
 
 def test_tick_phases_land_in_the_profiler_trace(run, registry, profiler,
@@ -395,7 +416,7 @@ def test_tick_phases_land_in_the_profiler_trace(run, registry, profiler,
     """With the tick profiler on, a jax.profiler trace holds a dyn.tick
     event per closed phase interval, whose durations add up to the tick
     records', the packed dispatches' shapes, and dyn.parked."""
-    recs = run(_traced_burst(str(tmp_path), True))
+    recs, waited = run(_traced_burst(str(tmp_path), True))
     events = _trace_events(str(tmp_path))
     assert {name for name, _d, _s in events} == {"dyn.tick", "dyn.parked"}
     by_phase = {}
@@ -408,12 +429,18 @@ def test_tick_phases_land_in_the_profiler_trace(run, registry, profiler,
         for k, v in r.phases.items():
             totals[k] = totals.get(k, 0.0) + v
     # phases that only kept ticks hold (a discarded or empty tick has
-    # annotations and no record): the two clocks agree on them
+    # annotations and no record): the two clocks agree on them.  A phase
+    # of the burst sums to a few milliseconds, and the record's clock is
+    # read a few lines before the annotation closes: a thread taken off its
+    # core between the two moves that wait from one phase to the next, so
+    # the clocks are given what the kernel says the worst-off thread waited
+    # (nothing on an idle machine), and not a second try
+    slack = 2e-3 + waited
     for phase in ("assemble", "device_wait", "commit"):
         assert totals[phase] > 0
         assert by_phase[phase] == pytest.approx(totals[phase], rel=0.05,
-                                                abs=2e-3), phase
-    assert sum(by_phase.values()) >= 0.95 * sum(totals.values())
+                                                abs=slack), (phase, waited)
+    assert sum(by_phase.values()) >= 0.95 * sum(totals.values()) - waited
     # parked before and after the burst, less a slice at each edge
     assert sum(d for n, d, _s in events if n == "dyn.parked") > 0.2
     shaped = [s for n, _d, s in events if n == "dyn.tick" and "q" in s]
