@@ -1649,8 +1649,7 @@ async def run_long_context(
     * **cold mix** -- all classes submitted together against a pool that
       holds ~1.5 long requests, budget admission on: TTFT p50 per length
       class, preemption counts by kind, admission skip/block counters,
-      and the padded-token fractions (packed vs what the rectangle
-      layout would have dispatched -- both derived from the same run's
+      and the packed step's padded-token fraction (from the run's
       per-dispatch accounting).
     * **warm prefix, prefetch off vs on** -- the long prompts re-run
       after pool churn demoted their prefix chains to the host/disk
@@ -1728,7 +1727,6 @@ async def run_long_context(
                 mixed_token_budget=chunk,
                 # the fast path under measurement
                 kv_admit_budget="on",
-                packed_ragged=True,
                 # the ring holds ONE long chain with slack; churn volume
                 # (> ring) pushes resident chains to the disk tier, which
                 # is exactly the state the prefetch legs contrast: off =
@@ -1757,7 +1755,6 @@ async def run_long_context(
             # -- cold mix ------------------------------------------------
             used0 = engine.mixed_used_tokens
             disp0 = engine.mixed_dispatched_tokens
-            rect0 = engine.mixed_rect_tokens
             classes = []  # (class_idx, prompt)
             for i, (L, n) in enumerate(zip(lengths, counts)):
                 classes += [(i, mk_prompt(L)) for _ in range(n)]
@@ -1811,12 +1808,8 @@ async def run_long_context(
                 )
             used = engine.mixed_used_tokens - used0
             disp = engine.mixed_dispatched_tokens - disp0
-            rect = engine.mixed_rect_tokens - rect0
             out["lctx_padded_frac_packed"] = (
                 round(1.0 - used / disp, 4) if disp else None
-            )
-            out["lctx_padded_frac_rect"] = (
-                round(1.0 - used / rect, 4) if rect else None
             )
             out["lctx_preempt_swap"] = sched.preempt_swap
             out["lctx_preempt_recompute"] = sched.preempt_recompute

@@ -819,7 +819,6 @@ def child_kernels(args) -> None:
     from dynamo_tpu.ops.paged_attention import paged_decode_attention_v2
     from dynamo_tpu.ops.ragged_attention import (
         packed_ragged_attention, packed_ragged_attention_xla,
-        ragged_paged_attention, ragged_paged_attention_xla,
     )
 
     interp = args.rehearse
@@ -827,7 +826,7 @@ def child_kernels(args) -> None:
     if args.rehearse:
         cfg = ModelConfig.tiny(dtype="float32")
         L, pages, budget = cfg.num_layers, 96, 16
-        flash_T, rect_S = (64,), (1, 8)
+        flash_T = (64,)
     else:
         cfg = ModelConfig(
             vocab_size=32000, hidden_size=2048, intermediate_size=5632,
@@ -835,7 +834,7 @@ def child_kernels(args) -> None:
             max_position=2048, dtype="bfloat16",
         )
         L, pages, budget = cfg.num_layers, 768, ecfg.mixed_token_budget
-        flash_T, rect_S = (1024, 2048), (1, 16, 128)
+        flash_T = (1024, 2048)
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     page, B = ecfg.page_size, ecfg.max_batch_size
     dt = jnp.dtype(cfg.dtype)
@@ -925,21 +924,6 @@ def child_kernels(args) -> None:
             record("packed_ragged_attention (work list)",
                    f"Np={Np} s_max={s_max} q={lens[-1]} window={window}",
                    cfg.dtype, got, ref, valid=lane < B)
-
-    for S in rect_S:
-        for quant in ((False, True) if S == rect_S[1] else (False,)):
-            q, k, v = rnd(4, (B, S, Hq, D)), rnd(5, (B, S, Hkv, D)), rnd(6, (B, S, Hkv, D))
-            base = rs.randint(0, P * page - 1, (B,)).astype(np.int32)
-            lens = rs.randint(1, S + 1, (B,)).astype(np.int32)
-            got = ragged_paged_attention(
-                q, k, v, qpool.q if quant else pool, table, base, lens, layer,
-                interpret=interp, kv_scales=qpool.s if quant else None,
-            )
-            ref = ragged_paged_attention_xla(
-                q, k, v, qpool if quant else pool, table, base, lens, layer)
-            valid = np.arange(S)[None, :] < lens[:, None]
-            record("ragged_paged_attention", f"S={S}",
-                   "int8" if quant else cfg.dtype, got, ref, valid=valid)
 
     q = rnd(7, (B, Hq, D))
     Pd = min(16, P)

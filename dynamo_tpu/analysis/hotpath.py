@@ -47,8 +47,6 @@ HOT_PATH_MANIFEST: Dict[str, List[str]] = {
         "_decode_once",
         "decode_block",
         "_decode_block",
-        "unified_step",
-        "_unified_step",
         "packed_unified_step",
         "_packed_unified_step",
         "packed_unified_multistep",
@@ -112,13 +110,12 @@ HOT_PATH_MANIFEST: Dict[str, List[str]] = {
         "flash_prefill_attention",
         "flash_prefix_prefill_attention",
     ],
-    # the unified mixed prefill+decode ragged kernels -- rectangle and
-    # fully-packed layouts: the ONE attention call of
-    # step.unified_step / step.packed_unified_step, dispatched every
-    # tick under mixed batching (the *_xla references are the same
-    # entry points' CPU paths)
+    # the unified mixed prefill+decode ragged kernels over the packed
+    # token axis: the ONE attention call of step.packed_unified_step,
+    # dispatched every tick under mixed batching (the *_xla references
+    # are the same entry point's CPU path)
     "dynamo_tpu/ops/ragged_attention.py": [
-        "ragged_paged_attention*",
+        "ragged_paged_attention_xla",
         "packed_ragged_attention*",
         "_packed_kernel",
     ],

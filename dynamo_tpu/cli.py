@@ -76,12 +76,6 @@ def _add_engine_flags(p) -> None:
                         "(decode lanes cost one each, the rest packs "
                         "prefill chunks; env DYN_MIXED_TOKEN_BUDGET "
                         "overrides)")
-    p.add_argument("--no-packed-ragged", dest="packed_ragged",
-                   action="store_false", default=True,
-                   help="disable the fully-packed ragged layout for "
-                        "unified dispatches (revert to the lane rectangle "
-                        "padded to the max chunk; env DYN_PACKED_RAGGED "
-                        "overrides)")
     p.add_argument("--no-multistep-decode", dest="multistep_decode",
                    action="store_false", default=True,
                    help="disable multi-step device-resident decode (K "
@@ -478,7 +472,6 @@ async def _make_engine(args):
         disk_offload_dir=args.disk_offload_dir,
         swap_preemption=args.swap_preemption,
         kv_remote=args.kv_remote,
-        packed_ragged=args.packed_ragged,
         kv_admit_budget=args.kv_admit_budget,
         quantize=args.quantize,
         kv_dtype=args.kv_dtype,

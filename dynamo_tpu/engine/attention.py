@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import functools
 import os
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -117,13 +118,18 @@ def _context_mesh():
     return None if mesh.empty or mesh.size == 1 else mesh
 
 
+def _tp() -> int:
+    """Shards of the head axis under the trace's context mesh."""
+    mesh = _context_mesh()
+    return mesh.shape.get("tp", 1) if mesh is not None else 1
+
+
 def _heads_shard(Hq: int, Hkv: int) -> bool:
     """The explicit tp rule of every Pallas gate: under a tp mesh the
     kernels run per shard, which needs both head counts to divide.  Where
     they do not (the pool then sits replicated, sharding._compatible_spec),
     the dispatch takes the XLA composition, which GSPMD can partition."""
-    mesh = _context_mesh()
-    tp = mesh.shape.get("tp", 1) if mesh is not None else 1
+    tp = _tp()
     return Hq % tp == 0 and Hkv % tp == 0
 
 
@@ -225,11 +231,12 @@ def latent_kernels_enabled(page_size: int) -> bool:
     return page_size >= 8 and _on_tpu()
 
 
-def latent_packed_path(page_size: int) -> str:
-    """Which latent path a packed dispatch takes, for the tick's
-    ``dispatch`` annotation: both are the absorbed form."""
+def latent_packed_path(kv_pages) -> str:
+    """Which latent path a packed dispatch over this latent pool takes, for
+    the tick's ``dispatch`` annotation: both are the absorbed form."""
+    row = kv_data(kv_pages).shape[5]
     return (
-        "absorbed_kernel" if latent_kernels_enabled(page_size)
+        "absorbed_kernel" if _packed_backend(kv_pages, 1, 1, row) == "latent"
         else "absorbed_xla"
     )
 
@@ -249,7 +256,7 @@ def latent_packed_attention_dispatch(
     written = write_packed_kv(
         kv_pages, rows, rows, page_table, lane, pos, valid, layer
     )
-    if latent_kernels_enabled(kv_pages.shape[3]):
+    if _packed_backend(kv_pages, q.shape[1], 1, q.shape[2]) == "latent":
         from ..ops.latent_attention import latent_packed_attention
 
         out = latent_packed_attention(
@@ -279,12 +286,12 @@ def _latent_decode(q, kv_pages, page_table, kv_lens, layer):
 
 
 def _pallas_ragged_enabled(page_size: int, Hq: int, Hkv: int, D: int) -> bool:
-    """Trace-time choice of the ragged mixed-batch attention backend.
+    """Trace-time choice of the packed mixed-batch attention backend.
 
     ``DYN_PALLAS_RAGGED=1/0`` forces it; default is auto -- on when the
-    backend is a TPU, the page size meets the kernel's sublane tiling
+    backend is a TPU, the page size meets the kernels' sublane tiling
     (>= 8), and the GQA group divides cleanly.  The XLA composition
-    (ops.ragged_attention.ragged_paged_attention_xla) stays as the
+    (ops.ragged_attention.packed_ragged_attention_xla) stays as the
     universal fallback and the tier-1 (CPU) code path."""
     forced = _env_flag("DYN_PALLAS_RAGGED")
     if forced is not None:
@@ -294,77 +301,67 @@ def _pallas_ragged_enabled(page_size: int, Hq: int, Hkv: int, D: int) -> bool:
     return _on_tpu()
 
 
-def packed_walks_work_list(kv_pages, Hq: int, Hkv: int, D: int) -> bool:
-    """Whether this pool's packed launch is a work-list kernel (the latent
-    kernels; a dense pair pool's, ``ragged_attention._takes_work_list``):
-    such a launch has no step for a page group, so the width of the page
-    table it is handed costs it nothing.  Read where the step's trace reads
-    it (under the engine's mesh)."""
+def _packed_backend(kv_pages, Hq: int, Hkv: int, D: int) -> str:
+    """What attends a packed launch over this pool, decided at trace time
+    under the context mesh -- the ONE statement of the rule, read by both
+    packed dispatches, :func:`latent_packed_path` and :func:`packed_launch`:
+    ``"latent"`` (a latent pool's work-list kernel), ``"work_list"`` or
+    ``"grid"`` (a pair pool's two Pallas kernels,
+    ``ragged_attention._takes_work_list``), or
+    ``"xla"`` (the composition: the CPU, a tiny page, heads that do not
+    shard, a latent pool on a mesh)."""
     page = kv_data(kv_pages).shape[3]
     if kv_is_latent(kv_pages):
-        return latent_kernels_enabled(page)
+        return "latent" if latent_kernels_enabled(page) else "xla"
+    if not _pallas_ragged_enabled(page, Hq, Hkv, D):
+        return "xla"
     from ..ops.ragged_attention import _takes_work_list
 
-    return _pallas_ragged_enabled(page, Hq, Hkv, D) and _takes_work_list(
-        D, kv_is_quantized(kv_pages)
-    )
+    quant = kv_is_quantized(kv_pages)
+    return "work_list" if _takes_work_list(D, quant) else "grid"
+
+
+class PackedLaunch(NamedTuple):
+    """What an engine has to know of its packed launch's attention."""
+
+    # a work-list kernel has no step for a page group: the width of the
+    # page table it is handed costs it nothing
+    walks_work_list: bool
+    # ``fits(Np, s_max)``: whether the launch can hold that packed shape
+    fits: Callable[[int, int], bool]
+
+
+def packed_launch(kv_pages, Hq: int, Hkv: int, D: int, dtype) -> PackedLaunch:
+    """Which kernel serves the packed launches of an engine over this pool,
+    at a model of ``Hq`` query and ``Hkv`` kv heads of ``D`` in ``dtype``.
+    Asked once at construction, under the engine's mesh (the gates read
+    ``tp`` from the context mesh, as the step's trace will).  Only the grid
+    kernel bounds the shape: it holds the packed operands of its shard of
+    heads in VMEM for the whole launch.  The work-list kernels (a tile by
+    DMA) and the XLA composition (no VMEM) hold any."""
+    kind = _packed_backend(kv_pages, Hq, Hkv, D)
+    if kind != "grid":
+        return PackedLaunch(kind in ("latent", "work_list"), lambda Np, s: True)
+    from ..ops.ragged_attention import packed_shape_fits
+
+    # scalars only: the engine keeps ``fits``, and must not keep this pool
+    data = kv_data(kv_pages)
+    page, kv_dtype = data.shape[3], data.dtype
+    tp = _tp()
+    quant = kv_is_quantized(kv_pages)
+
+    def fits(Np: int, s_max: int) -> bool:
+        return packed_shape_fits(
+            Np, s_max, Hq // tp, Hkv // tp, D, page, dtype, kv_dtype, quant
+        )
+
+    return PackedLaunch(False, fits)
 
 
 def _scale_args(scales):
     """The int8 pool's row scales as (operands, specs) of a per-shard
     kernel call: they carry no head axis and ride replicated."""
     return ((), ()) if scales is None else ((scales,), (P(),))
-
-
-@hot_path
-def ragged_attention_dispatch(
-    q: jax.Array,  # [B, S, Hq, D] ragged queries (lane b row i at base[b]+i)
-    k: jax.Array,  # [B, S, Hkv, D] fresh keys for the same columns
-    v: jax.Array,  # [B, S, Hkv, D]
-    kv_pages: jax.Array,  # [L, 2, num_pages, page, Hkv, D]
-    layer: jax.Array,  # scalar i32
-    page_table: jax.Array,  # [B, P] (bucketed)
-    base: jax.Array,  # [B] committed cache length per lane
-    q_lens: jax.Array,  # [B] valid query rows (0 = inactive lane)
-    window: int = 0,
-) -> jax.Array:
-    """Ragged mixed prefill+decode attention over the paged pool: Pallas
-    page-streaming kernel on TPU, XLA gather + einsum elsewhere.  Resolved
-    at trace time (static), so each compiled executable embeds exactly one
-    backend -- the pattern every other dispatch gate here follows.  This
-    is the ONE attention call of ``step.unified_step``: a decode lane is a
-    1-row query, a chunked-prefill lane its chunk's rows, all causal at
-    token granularity against the resident prefix plus the dispatch's own
-    fresh columns.  Quantized pools pass their row scales as extra kernel
-    operands; the dequant fuses into the page-group stream (VMEM multiply
-    per fetched group, never a full-width pool materialization)."""
-    Hq, D = q.shape[2], q.shape[3]
-    Hkv = k.shape[2]
-    data = kv_data(kv_pages)
-    scales = kv_pages.s if kv_is_quantized(kv_pages) else None
-    if not kv_is_latent(kv_pages) and _pallas_ragged_enabled(
-        data.shape[3], Hq, Hkv, D
-    ):
-        from ..ops.ragged_attention import ragged_paged_attention
-
-        s_ops, s_specs = _scale_args(scales)
-        heads = P(None, None, "tp", None)
-        return _per_shard(
-            lambda q, k, v, data, pt, base, lens, layer, *sc: (
-                ragged_paged_attention(
-                    q, k, v, data, pt, base, lens, layer, window,
-                    group=4, kv_scales=sc[0] if sc else None,
-                )
-            ),
-            (q, k, v, data, page_table, base, q_lens, layer, *s_ops),
-            (heads, heads, heads, _POOL_SPEC, P(), P(), P(), P(), *s_specs),
-            heads,
-        )
-    from ..ops.ragged_attention import ragged_paged_attention_xla
-
-    return ragged_paged_attention_xla(
-        q, k, v, kv_pages, page_table, base, q_lens, layer, window
-    )
 
 
 @hot_path
@@ -383,21 +380,22 @@ def packed_ragged_attention_dispatch(
     s_max: int,  # static per-lane window capacity
     window: int = 0,
 ) -> jax.Array:
-    """Fully-packed ragged mixed-batch attention: the flat-token-axis
-    layout of ``step.packed_unified_step`` (ISSUE 10).  Pallas
-    packed-operand kernel on TPU, XLA unpack-rectangle-repack reference
-    elsewhere -- resolved at trace time like every other dispatch gate,
-    and gated by the same ``DYN_PALLAS_RAGGED`` knob as the rectangle
-    kernel (the two are the same algorithm over different operand
-    layouts).  Quantized pools fuse the row-scale dequant exactly like
-    the rectangle dispatch above."""
+    """Fully-packed ragged mixed-batch attention: the ONE attention call
+    of ``step.packed_unified_step`` over a pair pool.  A decode lane is a
+    1-row segment, a chunked-prefill lane its chunk's rows, a speculating
+    lane its verify columns, all causal at token granularity against the
+    resident prefix plus the dispatch's own fresh rows.  A Pallas kernel
+    on TPU (work list or grid, :func:`_packed_backend`), the XLA
+    unpack-reference-repack composition elsewhere -- resolved at trace
+    time like every other dispatch gate (``DYN_PALLAS_RAGGED`` forces
+    it).  A quantized pool passes its row scales as extra kernel operands;
+    the dequant runs on each fetched page group in VMEM, never on a
+    full-width pool."""
     Hq, D = q.shape[1], q.shape[2]
     Hkv = k.shape[1]
     data = kv_data(kv_pages)
     scales = kv_pages.s if kv_is_quantized(kv_pages) else None
-    if not kv_is_latent(kv_pages) and _pallas_ragged_enabled(
-        data.shape[3], Hq, Hkv, D
-    ):
+    if _packed_backend(kv_pages, Hq, Hkv, D) in ("work_list", "grid"):
         from ..ops.ragged_attention import packed_ragged_attention
 
         s_ops, s_specs = _scale_args(scales)

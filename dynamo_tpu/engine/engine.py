@@ -80,7 +80,6 @@ from .step import (
     slice_block_pages,
     packed_unified_multistep,
     packed_unified_step,
-    unified_step,
     verify_and_sample,
 )
 
@@ -227,9 +226,9 @@ class EngineConfig:
     prefill_chunk_tokens: Optional[int] = None
     # mixed prefill+decode batching (Ragged Paged Attention, ROADMAP item
     # 2): admitted prompts pack into the decode tick as ragged chunks
-    # served by ONE unified dispatch (step.unified_step), so prefill never
-    # stalls the decode batch behind a separate launch and TTFT/ITL stop
-    # trading off.  Output is bit-identical to the separate paths for
+    # served by ONE unified dispatch (step.packed_unified_step), so prefill
+    # never stalls the decode batch behind a separate launch and TTFT/ITL
+    # stop trading off.  Output is bit-identical to the separate paths for
     # greedy/seeded lanes.  ``--no-mixed-batching`` restores the classic
     # separate-dispatch behavior exactly; penalized requests always take
     # the classic paths (the unified step carries no penalty histograms).
@@ -242,14 +241,6 @@ class EngineConfig:
     # the remainder packs prefill chunks); DYN_MIXED_TOKEN_BUDGET
     # overrides at engine construction
     mixed_token_budget: int = 512
-    # fully-packed ragged layout (ISSUE 10): unified dispatches run a
-    # flat packed token axis (pow2 of the dispatch's REAL fresh tokens)
-    # instead of the lane rectangle that pads every lane to the max
-    # chunk -- the trunk stops paying for padding exactly where long
-    # prefill chunks make it worst.  Token-identical to the rectangle
-    # and classic paths; ``DYN_PACKED_RAGGED=0/1`` overrides at engine
-    # construction.  Only consulted when mixed batching is on.
-    packed_ragged: bool = True
     # KV-budget admission (ROADMAP item 5 / scheduler.KVAdmitConfig):
     # admit against predicted KV pages -- prompt + max_tokens headroom --
     # with a skip-ahead + aging fairness floor, instead of slot count.
@@ -335,10 +326,9 @@ class EngineConfig:
     # segments -- a speculating mixed tick is ONE device dispatch instead
     # of decode + verify.  Token-identical (greedy and seeded) to the
     # post-commit ``verify_and_sample`` path, which remains the fallback
-    # for classic ticks (penalized lanes), the rectangle layout, and
-    # ``fold_spec_verify=False``.  DYN_SPEC_FOLD=0/1 overrides at engine
-    # construction (the serving-env-knob contract).  Only consulted when
-    # mixed batching + the packed layout are on.
+    # for classic ticks (penalized lanes) and ``fold_spec_verify=False``.
+    # DYN_SPEC_FOLD=0/1 overrides at engine construction (the
+    # serving-env-knob contract).  Only consulted when mixed batching is on.
     fold_spec_verify: bool = True
     # acceptance-aware per-request auto-disable: a speculating lane whose
     # acceptance rate sits below ``spec_min_accept`` after
@@ -641,7 +631,6 @@ from types import SimpleNamespace
 # same raw implementations with explicit in/out shardings.
 _MODULE_STEPS = SimpleNamespace(
     decode_block=decode_block,
-    unified_step=unified_step,
     packed_unified_step=packed_unified_step,
     packed_unified_multistep=packed_unified_multistep,
     verify_and_sample=verify_and_sample,
@@ -932,20 +921,10 @@ class JaxEngine:
                     "ignoring malformed DYN_MIXED_TOKEN_BUDGET=%r", env_budget
                 )
         self._mixed_budget = max(int(budget), 1)
-        # fully-packed ragged layout: DYN_PACKED_RAGGED=0/1 overrides the
-        # config (same contract as every other serving env knob)
-        self._packed = bool(self.cfg.packed_ragged)
-        env_packed = _os.environ.get("DYN_PACKED_RAGGED")
-        if env_packed is not None and env_packed.strip():
-            self._packed = env_packed.strip().lower() not in (
-                "0", "off", "false", "no"
-            )
-        # per-dispatch fresh-token accounting (padded-token fractions the
-        # long-context bench reports): real rows vs rows dispatched vs
-        # rows the rectangle layout would have dispatched
+        # per-dispatch fresh-token accounting (the padded-token fraction
+        # the long-context bench reports): real rows vs rows dispatched
         self.mixed_used_tokens = 0
         self.mixed_dispatched_tokens = 0
-        self.mixed_rect_tokens = 0
         # packed-shape compaction (ISSUE 13 satellite): LRU/merge budget
         # over the packed step's (Np, s_max) executable pairs;
         # DYN_PACKED_SHAPE_BUDGET retunes without a restart flag
@@ -962,14 +941,13 @@ class JaxEngine:
                     env_shapes,
                 )
         self._packed_shapes = PackedShapeBudget(shape_budget)
-        # what the packed Pallas kernel can hold at this model's widths:
-        # checked here for the widest shape the budget can mint (a budget
-        # the kernel cannot serve fails engine construction, not a user's
-        # first long prompt) and again on every triple the budget resolves
-        self._packed_fits = self._packed_kernel_bound()
-        # a packed step that is one step takes the page table at its full
-        # width where its attention walks a work list (_dispatch_unified)
-        self._packed_full_table = self._packed_walks_work_list()
+        # what the packed launch's kernel can hold at this model's widths
+        # (checked again on every triple the budget resolves), and whether
+        # it walks a work list: a packed step that is one step then takes
+        # the page table at its full width (_dispatch_unified)
+        launch = self._packed_launch()
+        self._packed_full_table = launch.walks_work_list
+        self._packed_fits = launch.fits
         # queue-side prefetch: window resolved here, walks issued by the
         # tick loop from queue position (_drive_prefetch), finished or
         # cancelled per request
@@ -1070,26 +1048,21 @@ class JaxEngine:
         self.spec_accepted = 0
         self.spec_verify_steps = 0
         # folded verify (ISSUE 15): speculating lanes' verify columns ride
-        # the packed unified dispatch.  Requires the packed mixed plane;
+        # the packed unified dispatch.  Requires the mixed plane;
         # DYN_SPEC_FOLD overrides config (serving-env-knob contract).
-        self._fold_spec = (
-            bool(self.cfg.fold_spec_verify) and self._mixed and self._packed
-        )
+        self._fold_spec = bool(self.cfg.fold_spec_verify) and self._mixed
         env_fold = _os.environ.get("DYN_SPEC_FOLD")
         if env_fold is not None and env_fold.strip():
             self._fold_spec = (
                 env_fold.strip().lower() not in ("0", "off", "false", "no")
                 and self._mixed
-                and self._packed
             )
-        # multi-step packed decode (ISSUE 16): requires the packed mixed
-        # plane like folded verify.  DYN_MULTISTEP grammar: 0/off =
+        # multi-step packed decode (ISSUE 16): requires the mixed plane
+        # like folded verify.  DYN_MULTISTEP grammar: 0/off =
         # disabled (pins the exact single-step behavior), 1/on/adaptive =
         # the adaptive-K controller, an integer N > 1 = fixed K=N (test /
         # bench pinning).  Malformed values warn and keep config.
-        self._multistep = (
-            bool(self.cfg.multistep_decode) and self._mixed and self._packed
-        )
+        self._multistep = bool(self.cfg.multistep_decode) and self._mixed
         self._multistep_fixed: Optional[int] = None  # None = adaptive
         self._multistep_max = max(int(self.cfg.multistep_max_k), 1)
         env_ms = _os.environ.get("DYN_MULTISTEP")
@@ -1098,12 +1071,12 @@ class JaxEngine:
             if v in ("0", "off", "false", "no"):
                 self._multistep = False
             elif v in ("1", "on", "true", "adaptive"):
-                self._multistep = self._mixed and self._packed
+                self._multistep = self._mixed
                 self._multistep_fixed = None
             else:
                 try:
                     k = int(v)
-                    self._multistep = k > 1 and self._mixed and self._packed
+                    self._multistep = k > 1 and self._mixed
                     self._multistep_fixed = max(k, 1)
                     self._multistep_max = max(self._multistep_max, k)
                 except ValueError:
@@ -1186,57 +1159,26 @@ class JaxEngine:
             return contextlib.nullcontext()
         return jax.set_mesh(self.mesh)
 
-    def _packed_walks_work_list(self) -> bool:
-        """Whether this engine's packed steps attend through a work-list
-        kernel, which the width of the page table costs nothing."""
+    def _packed_launch(self):
+        """Which kernel serves this engine's packed dispatches
+        (``attention.packed_launch``: whether it walks a work list, and
+        ``fits(Np, s_max)``), once the widest shape a mixed engine's budget
+        can mint (one lane's chunk of the whole budget beside other lanes'
+        rows) has passed ``fits``: a budget the kernel cannot serve fails
+        engine construction, not a user's first long prompt."""
         from . import attention as att
 
-        if not (self._mixed and self._packed):
-            return False
         m = self.model_cfg
         with self.mesh_scope():  # the gates read tp from the context mesh
-            return att.packed_walks_work_list(
-                self.kv.pages, m.num_heads, m.num_kv_heads, m.head_dim
+            launch = att.packed_launch(
+                self.kv.pages, m.num_heads, m.num_kv_heads, m.head_dim,
+                m.dtype,
             )
-
-    def _packed_kernel_bound(self) -> Callable[[int, int], bool]:
-        """``fits(Np, s_max)`` for this engine's packed dispatches.  Always
-        true where the dispatch gate takes the XLA composition (no kernel,
-        no VMEM); where it takes the Pallas kernel, the kernel's own
-        footprint rule at this model's widths -- and the widest shape the
-        mixed budget can mint (one lane's chunk of the whole budget beside
-        other lanes' rows) must pass it now."""
-        from . import attention as att
-
-        m = self.model_cfg
         page = self.cfg.page_size
-        if m.is_mla:
-            # the latent kernels move queries and pages by DMA, a tile at a
-            # time: their VMEM does not grow with the packed shape
-            return lambda Np, s_max: True
-        with self.mesh_scope():  # the gate reads tp from the context mesh
-            kernel = att._pallas_ragged_enabled(
-                page, m.num_heads, m.num_kv_heads, m.head_dim
-            )
-        if not (self._mixed and self._packed and kernel):
-            return lambda Np, s_max: True
-        from ..ops.ragged_attention import packed_shape_fits
-
-        pool = self.kv.pages
-        quant = isinstance(pool, QuantKV)
-        tp = int(self.mesh.shape.get("tp", 1)) if self.mesh is not None else 1
-
-        def fits(Np: int, s_max: int) -> bool:
-            return packed_shape_fits(
-                Np, s_max, m.num_heads // tp, m.num_kv_heads // tp,
-                m.head_dim, page, m.dtype,
-                pool.q.dtype if quant else pool.dtype, quant,
-            )
-
         s_top = pow2_bucket(max(self._mixed_budget, page))
-        if not fits(2 * s_top, s_top):
+        if self._mixed and not launch.fits(2 * s_top, s_top):
             ok = s_top
-            while ok > page and not fits(2 * ok, ok):
+            while ok > page and not launch.fits(2 * ok, ok):
                 ok //= 2
             raise ValueError(
                 f"mixed_token_budget {self._mixed_budget} needs a packed "
@@ -1244,7 +1186,7 @@ class JaxEngine:
                 f"does not fit the kernel's VMEM at {m.num_heads} heads x "
                 f"{m.head_dim}; the largest budget that fits is {ok}"
             )
-        return fits
+        return launch
 
     @classmethod
     def random_init(
@@ -2908,9 +2850,9 @@ class JaxEngine:
                 # overlaps this tick's in-flight decode block on device).
                 # With folding active the verify columns already rode the
                 # unified dispatch above -- the standalone path serves
-                # classic ticks (penalized lanes), the rectangle layout,
-                # and --no-fold-spec-verify.  The slot scan gates the
-                # executor hop so spec-free serving pays nothing here.
+                # classic ticks (penalized lanes) and --no-fold-spec-verify.
+                # The slot scan gates the executor hop so spec-free serving
+                # pays nothing here.
                 if not fold_active and any(
                     s is not None and _spec_live(s)
                     for s in self.sched.slots
@@ -4221,17 +4163,17 @@ class JaxEngine:
         like ``_dispatch_chunk``, so next tick's formation never re-packs
         dispatched tokens.
 
-        With ``fold_spec`` (packed layout only) the tick's verify-eligible
-        speculating lanes contribute ``1 + draft`` extra segments -- last
-        committed token + host-proposed drafts -- scored in this SAME
-        dispatch (ISSUE 15): a speculating tick pays ONE device launch,
-        not decode + verify.  Their per-column samples ride the
-        returned record's ``spec_sampled`` handle and commit through the
-        host accept walk at commit time.
+        With ``fold_spec`` the tick's verify-eligible speculating lanes
+        contribute ``1 + draft`` extra segments -- last committed token +
+        host-proposed drafts -- scored in this SAME dispatch (ISSUE 15): a
+        speculating tick pays ONE device launch, not decode + verify.
+        Their per-column samples ride the returned record's
+        ``spec_sampled`` handle and commit through the host accept walk at
+        commit time.
 
-        With ``num_steps >= 1`` (packed layout, chunk-free, spec-free --
-        the tick loop only routes pure-decode multistep ticks here, with
-        K from the adaptive controller) the dispatch runs the decode rows
+        With ``num_steps >= 1`` (chunk-free, spec-free -- the tick loop
+        only routes pure-decode multistep ticks here, with K from the
+        adaptive controller) the dispatch runs the decode rows
         alone; for K > 1 it runs ``packed_unified_multistep``: K decode
         iterations fused into one launch, sampling and appending KV on
         device each step, so the host syncs one ``[B, K]`` token block
@@ -4274,10 +4216,6 @@ class JaxEngine:
                 if seq.cached_prompt_tokens:
                     self.obs.prefix_hits.inc(seq.cached_prompt_tokens)
         B = self.cfg.max_batch_size
-        # ragged query axis buckets to a power of two (the draft-column /
-        # group-batch pad rule), so arrival patterns cannot mint surprise
-        # executables mid-serving
-        S = pow2_bucket(max((ch.length for ch in chunks), default=1))
         p_start = np.zeros((B,), np.int32)
         p_lens = np.zeros((B,), np.int32)
         p_sample = np.zeros((B,), bool)
@@ -4333,9 +4271,8 @@ class JaxEngine:
             if self._packed_full_table and num_steps == 1
             else self._live_page_bucket()
         )
-        # decode-capable lanes: contribute one fresh row each (packed) /
-        # one live column (rectangle); the count feeds the occupancy
-        # histograms either way
+        # decode-capable lanes contribute one fresh row each; the count
+        # feeds the occupancy histograms
         dec_cap = np.zeros((B,), bool)
         for b, s in enumerate(sched.slots):
             dec_cap[b] = (
@@ -4358,207 +4295,161 @@ class JaxEngine:
         )
         top_n = self._lp_top(sched.slots)
         dispatch_meta: Dict[str, Any] = {}  # the dispatch annotation's stats
-        if self._packed:
-            # fully-packed layout (ISSUE 10): ONE flat token axis sized
-            # pow2(real fresh tokens) instead of the [B, S] rectangle --
-            # the trunk stops paying for every lane's padding to the max
-            # chunk.  Segments pack contiguously in slot order; the
-            # packed-axis pad also guarantees every live lane's static
-            # s_max window fits (the Pallas kernel's slice rule).
-            q_host = np.where(
-                dec_cap, 1, np.where(v_host > 0, v_host, p_lens)
-            ).astype(np.int32)
-            total = int(q_host.sum())
-            s_nat = pow2_bucket(int(q_host.max()) if total else 1)
-            seg_off = np.zeros((B,), np.int32)
-            off = 0
-            off_last = 0
-            for b in range(B):
-                ql = int(q_host[b])
-                if ql == 0:
-                    continue
-                seg_off[b] = off
-                off_last = off
-                off += ql
-            # (Np, s_max, s_spec) through the executable-shape budget:
-            # reuse or merge up into an already-minted triple instead of
-            # compiling a fresh executable for every arrival pattern
-            # (ISSUE 13 satellite, verify columns included since ISSUE
-            # 15; the budget keeps off_last + s_max <= Np)
-            Np, s_max, s_spec = self._packed_shapes.fit(
-                s_nat, off_last, total, s_spec
+        # fully-packed layout (ISSUE 10): ONE flat token axis sized
+        # pow2(real fresh tokens), so the trunk never pays for padding
+        # every lane to the longest chunk.  Segments pack contiguously
+        # in slot order; the packed-axis pad also guarantees every live
+        # lane's static s_max window fits (the grid kernel's slice rule).
+        q_host = np.where(
+            dec_cap, 1, np.where(v_host > 0, v_host, p_lens)
+        ).astype(np.int32)
+        total = int(q_host.sum())
+        s_nat = pow2_bucket(int(q_host.max()) if total else 1)
+        seg_off = np.zeros((B,), np.int32)
+        off = 0
+        off_last = 0
+        for b in range(B):
+            ql = int(q_host[b])
+            if ql == 0:
+                continue
+            seg_off[b] = off
+            off_last = off
+            off += ql
+        # (Np, s_max, s_spec) through the executable-shape budget:
+        # reuse or merge up into an already-minted triple instead of
+        # compiling a fresh executable for every arrival pattern
+        # (ISSUE 13 satellite, verify columns included since ISSUE
+        # 15; the budget keeps off_last + s_max <= Np)
+        Np, s_max, s_spec = self._packed_shapes.fit(
+            s_nat, off_last, total, s_spec
+        )
+        if not self._packed_fits(Np, s_max):
+            raise RuntimeError(
+                f"packed dispatch shape (Np={Np}, s_max={s_max}) "
+                "exceeds what the packed attention kernel can hold; "
+                "lower mixed_token_budget"
             )
-            if not self._packed_fits(Np, s_max):
-                raise RuntimeError(
-                    f"packed dispatch shape (Np={Np}, s_max={s_max}) "
-                    "exceeds what the packed attention kernel can hold; "
-                    "lower mixed_token_budget"
-                )
-            self.obs.observe_executable_shapes(len(self._packed_shapes))
-            t_tokens = np.zeros((Np,), np.int32)
-            t_lane = np.full((Np,), B, np.int32)
-            t_rel = np.zeros((Np,), np.int32)
-            t_dec = np.zeros((Np,), bool)
-            spec_by_slot = {b: draft for _s, b, draft in spec_lanes}
-            for b in range(B):
-                ql = int(q_host[b])
-                if ql == 0:
-                    continue
-                o = int(seg_off[b])
-                t_lane[o : o + ql] = b
-                t_rel[o : o + ql] = np.arange(ql, dtype=np.int32)
-                ch = chunk_by_slot.get(b)
-                if ch is not None:
-                    t_tokens[o : o + ql] = ch.seq.prompt[
-                        ch.start : ch.start + ql
-                    ]
-                elif b in spec_by_slot:
-                    # verify segment: committed token + drafts (host
-                    # mirrors authoritative, the verify-dispatch rule)
-                    t_tokens[o] = sched.tokens[b]
-                    dr = spec_by_slot[b]
-                    if dr:
-                        t_tokens[o + 1 : o + 1 + len(dr)] = dr
-                else:
-                    t_dec[o] = True
-            disp_tokens = Np + B * (num_steps - 1)
-            tick = self._tick
-            if tick is not None:
-                tick.mark("assemble")
-                # what the attention kernels are asked to do, carried by
-                # the dispatch interval's annotation: per live lane its
-                # fresh query rows and the context its last row reads
-                # (host mirrors: a decode lane's lags the device by the
-                # uncommitted generations), the fused steps, the packed
-                # rows.  Lists are "|"-joined: a comma cuts a trace stat.
-                live = np.nonzero(q_host)[0]
-                base = np.where(dec_cap, sched.seq_lens, p_start)
-                dispatch_meta = {
-                    "q": "|".join(str(int(v)) for v in q_host[live]),
-                    "ctx": "|".join(
-                        str(int(v)) for v in (base + q_host)[live]
-                    ),
-                    "k": num_steps,
-                    "np": Np,
-                }
-                # the launch as the dense pools' kernel walks it: its work
-                # items, and how many of them take the small tile
-                from ..ops.ragged_attention import packed_item_counts
-
-                dispatch_meta["items"], dispatch_meta["small"] = (
-                    packed_item_counts(q_host[live], s_max)
-                )
-                if self.model_cfg.is_mla:
-                    # which latent path the dispatch takes (read where the
-                    # step's trace reads it)
-                    from . import attention as att
-
-                    with self.mesh_scope():
-                        dispatch_meta["latent"] = att.latent_packed_path(
-                            self.cfg.page_size
-                        )
-            operands = (
-                self.params,
-                self.model_cfg,
-                self.kv.pages,
-                d["tokens"],
-                d["seq_lens"],
-                d["limit_lens"],
-                d["active"],
-                d["stop_ids"],
-                d["page_table"][:, :Pb],
-                jnp.asarray(t_tokens),
-                jnp.asarray(t_lane),
-                jnp.asarray(t_rel),
-                jnp.asarray(t_dec),
-                self._put_batch(p_start),
-                self._put_batch(p_lens),
-                self._put_batch(p_sample),
-                self._put_batch(p_act),
-                self._put_batch(dec_cap),
-                self._put_batch(seg_off),
-                self._put_batch(v_host),
-                self._rng,
-                d["sampling"],
-            )
-            if num_steps > 1:
-                # K decode iterations fused into the launch: packed is
-                # [B, K, 2 + 2*top_n], row k = on-device step k's sample
-                compile_sentry.set_entry("packed_unified_multistep")
-                (
-                    packed,
-                    spec_packed,
-                    d["tokens"],
-                    d["seq_lens"],
-                    d["active"],
-                    self.kv.pages,
-                    self._rng,
-                ) = self._fns.packed_unified_multistep(
-                    *operands, s_max, num_steps, s_spec, top_n, use_filters,
-                )
-            else:
-                (
-                    packed,
-                    spec_packed,
-                    d["tokens"],
-                    d["seq_lens"],
-                    d["active"],
-                    self.kv.pages,
-                    self._rng,
-                ) = self._fns.packed_unified_step(
-                    *operands, s_max, s_spec, top_n, use_filters,
-                )
-        else:
-            # rectangle layout: fold never routes here (fold_spec requires
-            # the packed layout), so no verify segments to place
-            spec_packed = None
-            p_tokens = np.zeros((B, S), np.int32)
-            for ch in chunks:
-                p_tokens[ch.seq.slot, : ch.length] = ch.seq.prompt[
-                    ch.start : ch.start + ch.length
+        self.obs.observe_executable_shapes(len(self._packed_shapes))
+        t_tokens = np.zeros((Np,), np.int32)
+        t_lane = np.full((Np,), B, np.int32)
+        t_rel = np.zeros((Np,), np.int32)
+        t_dec = np.zeros((Np,), bool)
+        spec_by_slot = {b: draft for _s, b, draft in spec_lanes}
+        for b in range(B):
+            ql = int(q_host[b])
+            if ql == 0:
+                continue
+            o = int(seg_off[b])
+            t_lane[o : o + ql] = b
+            t_rel[o : o + ql] = np.arange(ql, dtype=np.int32)
+            ch = chunk_by_slot.get(b)
+            if ch is not None:
+                t_tokens[o : o + ql] = ch.seq.prompt[
+                    ch.start : ch.start + ql
                 ]
-            disp_tokens = B * S
-            tick = self._tick
-            if tick is not None:
-                tick.mark("assemble")
-            compile_sentry.set_entry("unified_step")
+            elif b in spec_by_slot:
+                # verify segment: committed token + drafts (host
+                # mirrors authoritative, the verify-dispatch rule)
+                t_tokens[o] = sched.tokens[b]
+                dr = spec_by_slot[b]
+                if dr:
+                    t_tokens[o + 1 : o + 1 + len(dr)] = dr
+            else:
+                t_dec[o] = True
+        disp_tokens = Np + B * (num_steps - 1)
+        tick = self._tick
+        if tick is not None:
+            tick.mark("assemble")
+            # what the attention kernels are asked to do, carried by
+            # the dispatch interval's annotation: per live lane its
+            # fresh query rows and the context its last row reads
+            # (host mirrors: a decode lane's lags the device by the
+            # uncommitted generations), the fused steps, the packed
+            # rows.  Lists are "|"-joined: a comma cuts a trace stat.
+            live = np.nonzero(q_host)[0]
+            base = np.where(dec_cap, sched.seq_lens, p_start)
+            dispatch_meta = {
+                "q": "|".join(str(int(v)) for v in q_host[live]),
+                "ctx": "|".join(
+                    str(int(v)) for v in (base + q_host)[live]
+                ),
+                "k": num_steps,
+                "np": Np,
+            }
+            # the launch as the dense pools' kernel walks it: its work
+            # items, and how many of them take the small tile
+            from ..ops.ragged_attention import packed_item_counts
+
+            dispatch_meta["items"], dispatch_meta["small"] = (
+                packed_item_counts(q_host[live], s_max)
+            )
+            if self.model_cfg.is_mla:
+                # which latent path the dispatch takes (read where the
+                # step's trace reads it)
+                from . import attention as att
+
+                with self.mesh_scope():
+                    dispatch_meta["latent"] = att.latent_packed_path(
+                        self.kv.pages
+                    )
+        operands = (
+            self.params,
+            self.model_cfg,
+            self.kv.pages,
+            d["tokens"],
+            d["seq_lens"],
+            d["limit_lens"],
+            d["active"],
+            d["stop_ids"],
+            d["page_table"][:, :Pb],
+            jnp.asarray(t_tokens),
+            jnp.asarray(t_lane),
+            jnp.asarray(t_rel),
+            jnp.asarray(t_dec),
+            self._put_batch(p_start),
+            self._put_batch(p_lens),
+            self._put_batch(p_sample),
+            self._put_batch(p_act),
+            self._put_batch(dec_cap),
+            self._put_batch(seg_off),
+            self._put_batch(v_host),
+            self._rng,
+            d["sampling"],
+        )
+        if num_steps > 1:
+            # K decode iterations fused into the launch: packed is
+            # [B, K, 2 + 2*top_n], row k = on-device step k's sample
+            compile_sentry.set_entry("packed_unified_multistep")
             (
                 packed,
+                spec_packed,
                 d["tokens"],
                 d["seq_lens"],
                 d["active"],
                 self.kv.pages,
                 self._rng,
-            ) = self._fns.unified_step(
-                self.params,
-                self.model_cfg,
-                self.kv.pages,
-                d["tokens"],
-                d["seq_lens"],
-                d["limit_lens"],
-                d["active"],
-                d["stop_ids"],
-                d["page_table"][:, :Pb],
-                self._put_batch(p_tokens),
-                self._put_batch(p_start),
-                self._put_batch(p_lens),
-                self._put_batch(p_sample),
-                self._put_batch(p_act),
-                self._rng,
-                d["sampling"],
-                top_n,
-                use_filters,
+            ) = self._fns.packed_unified_multistep(
+                *operands, s_max, num_steps, s_spec, top_n, use_filters,
             )
-        # padded-token accounting, BOTH layouts derived from this one
-        # dispatch: `used` real rows, `dispatched` what actually ran,
-        # `rectangle` what the [B, S] layout would have run -- the bench
-        # reports 1 - used/dispatched vs 1 - used/rectangle.  Multi-step
+        else:
+            (
+                packed,
+                spec_packed,
+                d["tokens"],
+                d["seq_lens"],
+                d["active"],
+                self.kv.pages,
+                self._rng,
+            ) = self._fns.packed_unified_step(
+                *operands, s_max, s_spec, top_n, use_filters,
+            )
+        # padded-token accounting: `used` real rows, `dispatched` what
+        # actually ran -- the bench reports 1 - used/dispatched.  Multi-step
         # scan iterations each run (and use) one row per decode lane.
         used_tokens = n_pf_tokens + n_decode * num_steps + n_spec_tokens
         self.mixed_used_tokens += used_tokens
         self.mixed_dispatched_tokens += disp_tokens
-        self.mixed_rect_tokens += B * S + B * (num_steps - 1)
-        self.obs.observe_mixed_tokens(used_tokens, disp_tokens, B * S)
+        self.obs.observe_mixed_tokens(used_tokens, disp_tokens)
         finals: List[InflightPrefill] = []
         for ch in final_chunks:
             seq = ch.seq
@@ -4589,9 +4480,9 @@ class JaxEngine:
             tick.mark("dispatch", **dispatch_meta)
         logger.debug(
             "unified dispatch: %d decode lanes + %d prefill tokens "
-            "+ %d verify segments (%d chunks, %d final) S=%d K=%d",
+            "+ %d verify segments (%d chunks, %d final) Np=%d s_max=%d K=%d",
             n_decode, n_pf_tokens, len(spec_lanes), len(chunks),
-            len(finals), S, num_steps,
+            len(finals), Np, s_max, num_steps,
         )
         return InflightUnified(
             sampled=packed,
@@ -4683,10 +4574,10 @@ class JaxEngine:
     def _dispatch_verify(self) -> Optional["InflightVerify"]:
         """Enqueue one batched multi-token verify for the speculating lanes
         (executor thread) -- the STANDALONE verify dispatch, serving
-        classic ticks (penalized lanes), the rectangle layout, and
-        fold-off engines.  Folded engines score verify columns inside the
-        packed unified dispatch instead (``_dispatch_unified``); the two
-        share :meth:`_gather_spec_lanes` and the commit-side accept walk.
+        classic ticks (penalized lanes) and fold-off engines.  Folded
+        engines score verify columns inside the packed unified dispatch
+        instead (``_dispatch_unified``); the two share
+        :meth:`_gather_spec_lanes` and the commit-side accept walk.
 
         The scheduler packs each gathered lane's draft as extra columns
         next to its last committed token; one ``verify_and_sample``

@@ -299,91 +299,6 @@ verify_and_sample = partial(
 )(_verify_and_sample)
 
 
-def _unified_step(
-    params: Params,
-    cfg: ModelConfig,
-    kv_pages: jax.Array,
-    tokens: jax.Array,  # [B] device-resident last committed token per lane
-    seq_lens: jax.Array,  # [B] cache length (next decode write position)
-    limit_lens: jax.Array,  # [B] cache length at which a lane must stop
-    active: jax.Array,  # [B] bool: decode lanes the scan would step
-    stop_ids: jax.Array,  # [B, E] device-checked stop tokens (-1 = pad)
-    page_table: jax.Array,  # [B, P] (bucketed)
-    p_tokens: jax.Array,  # [B, S] prefill chunk tokens (0 on decode lanes)
-    p_start: jax.Array,  # [B] chunk start position (prefilled so far)
-    p_lens: jax.Array,  # [B] chunk length; 0 = decode (or idle) lane
-    p_sample: jax.Array,  # [B] bool: final chunk -> sample first token
-    p_activate: jax.Array,  # [B] bool: final chunk also joins the decode
-    # batch (False for speculating lanes, which stay device-inactive and
-    # advance via verify dispatches)
-    rng: jax.Array,
-    sampling: SamplingParams,
-    top_n: int = 0,
-    use_filters: bool = True,
-) -> Tuple[jax.Array, ...]:
-    """ONE ragged mixed prefill+decode dispatch over the whole batch.
-
-    The continuous-batching step (ROADMAP item 2, *Ragged Paged Attention*):
-    decode lanes contribute one query row (their last committed token, read
-    from the device-resident ``tokens`` vector so steps pipeline without a
-    host round trip), chunked-prefill lanes contribute their chunk's rows --
-    all in one ``[B, S]`` ragged block served by a single attention dispatch
-    per layer, so an admitted prompt never stalls the decode batch behind a
-    separate prefill launch.
-
-    Per-lane geometry: row ``j`` of lane ``b`` sits at absolute position
-    ``base[b] + j`` where ``base`` is ``p_start`` for prefill lanes and
-    ``seq_lens`` for decode lanes; KV scatters through ``write_spec_kv``
-    (token-granular, invalid rows to trash page 0) and attention through
-    ``ragged_attention_dispatch`` (resident prefix ``< base`` + causal
-    fresh block).  Sampling keys positions exactly like the paths it
-    replaces -- ``base + q_len`` is ``seq_lens + 1`` for a decode lane
-    (the decode-scan identity) and the prompt length for a final prefill
-    chunk (the prefill-sample identity) -- so greedy and seeded lanes are
-    bit-identical to the separate-dispatch paths.
-
-    Decode lanes replay ``decode_block``'s one-step update on device
-    (stop-token swallow, limit deactivation) so the next pipelined unified
-    dispatch sees consistent state; final-chunk prefill lanes fold their
-    sampled first token into the decode state the way ``inject_token``
-    would.  Intermediate chunks write KV only.  The host replay at commit
-    stays authoritative for all stop rules.
-
-    Returns ``(packed [B, 2 + 2*top_n], tokens, seq_lens, active,
-    kv_pages, rng)``: packed rows carry (raw token | logprob | tops); the
-    token is ``-1`` for lanes that sampled nothing (idle, mid-chunk).
-    """
-    B, S = p_tokens.shape
-    is_pf = p_lens > 0
-    q_lens = jnp.where(is_pf, p_lens, active.astype(jnp.int32))
-    base = jnp.where(is_pf, p_start, seq_lens).astype(jnp.int32)
-    # decode lanes: row 0 carries the device-resident last token
-    col0 = jnp.where(is_pf, p_tokens[:, 0], tokens)
-    toks2d = p_tokens.at[:, 0].set(col0)
-    positions = base[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-
-    def attn_fn(q, k, v, kv, layer):
-        out = att.ragged_attention_dispatch(
-            q, k, v, kv, layer, page_table, base, q_lens,
-            cfg.sliding_window or 0,
-        )
-        new_kv = att.write_spec_kv(kv, k, v, page_table, base, q_lens, layer)
-        return out, new_kv
-
-    hidden, kv_pages = transformer(
-        params, cfg, toks2d, positions, kv_pages, attn_fn
-    )
-    last = jnp.clip(q_lens - 1, 0, S - 1)
-    hidden_last = jnp.take_along_axis(hidden, last[:, None, None], axis=1)[:, 0]
-    logits = lm_logits(params, cfg, hidden_last)  # [B, V]
-    packed, new_tokens, new_seq, new_active, rng = _mixed_sample_epilogue(
-        logits, base, q_lens, is_pf, p_start, p_lens, p_sample, p_activate,
-        tokens, seq_lens, limit_lens, active, stop_ids, rng, sampling,
-        top_n, use_filters,
-    )
-    return packed, new_tokens, new_seq, new_active, kv_pages, rng
-
-
 def _mixed_sample_epilogue(
     logits: jax.Array,  # [B, V] last-row logits per lane
     base: jax.Array,  # [B]
@@ -403,10 +318,8 @@ def _mixed_sample_epilogue(
     top_n: int,
     use_filters: bool,
 ) -> Tuple[jax.Array, ...]:
-    """Sampling + device bookkeeping shared by the rectangle and packed
-    unified steps (the two layouts differ only in how the trunk reaches
-    per-lane last-row logits; everything from sampling down is one code
-    path so they cannot drift).
+    """The packed unified step's epilogue: sampling + device bookkeeping
+    over per-lane last-row logits.
 
     Mirrors ``decode_block``'s live_step for decode lanes and the inject
     path for final-chunk lanes (host replay at commit re-derives the
@@ -434,13 +347,6 @@ def _mixed_sample_epilogue(
     out = jnp.where(live, sampled, -1)
     packed = pack_sampled_logprobs(out, lp, top_ids, top_lps)
     return packed, new_tokens, new_seq, new_active, rng
-
-
-unified_step = partial(
-    jax.jit,
-    static_argnames=("cfg", "top_n", "use_filters"),
-    donate_argnames=("kv_pages", "tokens", "seq_lens", "active"),
-)(_unified_step)
 
 
 def _spec_columns_epilogue(
@@ -508,8 +414,7 @@ def _packed_unified_step(
     t_lane: jax.Array,  # [Np] lane per packed token (B = padding)
     t_rel: jax.Array,  # [Np] row index within the lane's segment
     t_dec: jax.Array,  # [Np] bool: row carries a decode lane's query (its
-    # token is read from the device-resident ``tokens`` vector, so packed
-    # steps pipeline exactly like rectangle ones)
+    # token is read from the device-resident ``tokens`` vector)
     p_start: jax.Array,  # [B] chunk start position (0 on decode lanes;
     # the committed cache length on speculating lanes -- host mirrors are
     # authoritative for them, exactly like the standalone verify step)
@@ -530,25 +435,36 @@ def _packed_unified_step(
     top_n: int = 0,
     use_filters: bool = True,
 ) -> Tuple[jax.Array, ...]:
-    """Fully-packed unified mixed step (ISSUE 10 + folded verify, ISSUE
-    15): the rectangle step's semantics over a flat ``[Np]`` token axis,
-    with speculative verify columns as just more segments.
+    """ONE ragged mixed prefill+decode dispatch over the whole batch, on a
+    flat ``[Np]`` token axis (ISSUE 10 + folded verify, ISSUE 15).
 
-    Where :func:`_unified_step` pads every lane's query axis to the
-    dispatch's max chunk (a ``[B, S]`` trunk for ``used << B*S`` real
-    tokens once one long prefill chunk rides along), this step runs the
-    trunk over exactly the packed rows -- ``Np = pow2(total fresh
-    tokens)`` -- and resolves each row's lane through ``t_lane`` /
-    ``seg_off``.  Segments pack contiguously in slot order; a decode
-    lane contributes one row whose token is read from the
-    device-resident ``tokens`` vector on device (``t_dec``), so host
-    assembly never waits on an uncommitted step.  A decode lane that
-    self-deactivated on device masks its row to the trash page exactly
-    like the rectangle layout masks its column.  Sampling, stop
-    handling, and the decode-state fold are byte-for-byte the shared
-    :func:`_mixed_sample_epilogue`, keyed by the identical positions --
-    greedy and seeded lanes are token-identical to the rectangle and
-    classic paths.
+    The continuous-batching step (ROADMAP item 2, *Ragged Paged
+    Attention*): decode lanes contribute one query row, chunked-prefill
+    lanes their chunk's rows, speculating lanes their verify columns --
+    all served by a single attention dispatch per layer, so an admitted
+    prompt never stalls the decode batch behind a separate prefill
+    launch.  The trunk runs exactly the packed rows -- ``Np = pow2(total
+    fresh tokens)``, never every lane padded to the longest chunk -- and
+    resolves each row's lane through ``t_lane`` / ``seg_off``.  Segments
+    pack contiguously in slot order; a decode lane's token is read from
+    the device-resident ``tokens`` vector on device (``t_dec``), so host
+    assembly never waits on an uncommitted step and steps pipeline
+    without a host round trip.  A decode lane that self-deactivated on
+    device masks its row to the trash page.
+
+    Per-lane geometry: row ``j`` of lane ``b`` sits at absolute position
+    ``base[b] + j`` where ``base`` is ``p_start`` for prefill (and
+    speculating) lanes and ``seq_lens`` for decode lanes.  Sampling keys
+    positions exactly like the classic paths -- ``base + q_len`` is
+    ``seq_lens + 1`` for a decode lane (the decode-scan identity) and the
+    prompt length for a final prefill chunk (the prefill-sample identity)
+    -- so greedy and seeded lanes are token-identical to them.  Decode
+    lanes replay ``decode_block``'s one-step update on device (stop-token
+    swallow, limit deactivation) so the next pipelined dispatch sees
+    consistent state; final-chunk lanes fold their sampled first token
+    into the decode state the way ``inject_token`` would; intermediate
+    chunks write KV only (:func:`_mixed_sample_epilogue`).  The host
+    replay at commit stays authoritative for all stop rules.
 
     A speculating lane (``v_lens > 0``) contributes ``1 + draft`` rows:
     row 0 its last committed token, rows 1.. the host-proposed drafts.
@@ -561,9 +477,10 @@ def _packed_unified_step(
     and ``p_lens`` is 0 on spec lanes, so ``live`` never fires).
 
     Returns ``(packed [B, 2 + 2*top_n], spec_packed [B, s_spec, 2 +
-    2*top_n], tokens, seq_lens, active, kv_pages, rng)`` -- the
-    :func:`_unified_step` contract plus the folded-verify columns
-    (zero-width when ``s_spec == 0``)."""
+    2*top_n], tokens, seq_lens, active, kv_pages, rng)``: packed rows
+    carry (raw token | logprob | tops), the token ``-1`` for lanes that
+    sampled nothing (idle, mid-chunk); ``spec_packed`` is zero-width when
+    ``s_spec == 0``."""
     B = tokens.shape[0]
     Np = t_tokens.shape[0]
     is_pf = p_lens > 0
@@ -1197,7 +1114,7 @@ from .bucketing import (  # noqa: E402,F401
 #
 # - decode_block: page buckets (pow2 over live pages, <= ~6 in practice)
 #   x the use_filters flag.
-# - unified_step / packed_unified_step: PackedShapeBudget caps the live
+# - packed_unified_step: PackedShapeBudget caps the live
 #   (Np, s_max, s_spec) set at 16 (DYN_PACKED_SHAPES); top_n / filter
 #   variants ride the same budget's headroom.
 # - packed_unified_multistep: the packed set x the K ramp {1, 2, 4, 8}
@@ -1214,7 +1131,6 @@ from .bucketing import (  # noqa: E402,F401
 # (tier-1 arms it around the engine tests after compile_sentry.reset()).
 COMPILE_BUDGET = {
     "decode_block": 12,
-    "unified_step": 16,
     "packed_unified_step": 24,
     "packed_unified_multistep": 96,
     "prefill": 32,

@@ -1,50 +1,42 @@
-"""Pallas ragged paged-attention kernel (TPU): one dispatch for a mixed
-prefill+decode batch.
+"""Ragged paged attention for a mixed prefill+decode batch: one launch over
+the packed token axis (TPU Pallas kernels and their XLA reference).
 
 The serving gap this closes (ROADMAP item 2, *Ragged Paged Attention* in
 PAPERS.md): prefill and decode used to run as separate XLA dispatches that
 alternate on the chip, so every admitted prompt stalled the decode batch
-and TTFT traded off against ITL.  This kernel takes **ragged per-sequence
-query lengths** over the existing paged KV layout -- a decode lane
-contributes one query row, a chunked-prefill lane contributes its chunk --
-and serves the whole batch in one launch.
+and TTFT traded off against ITL.  The packed step takes **ragged
+per-sequence query lengths** over the paged KV layout -- a decode lane
+contributes one query row, a chunked-prefill lane its chunk, a speculating
+lane its verify columns -- on ONE flat token axis of ``Np`` rows with
+per-lane segment offsets, and serves the whole batch in one launch.
 
-Geometry: lane ``b``'s query row ``i`` sits at absolute position
-``base[b] + i`` (``base`` = committed cache length, exactly the
-``write_spec_kv`` convention); rows at ``i >= q_lens[b]`` are ragged
-padding whose output is garbage the host never reads (their KV writes
-route to trash page 0, the engine-wide invalid-row convention).  Keys come
-from two places:
+Geometry: lane ``b``'s query row ``i`` sits at packed row ``seg_off[b] +
+i`` and at absolute position ``base[b] + i`` (``base`` = committed cache
+length); rows at ``i >= q_lens[b]`` are padding whose output the host never
+reads (their KV writes route to trash page 0, the engine-wide invalid-row
+convention).  Softmax is the flash-style online max/sum rescale in f32
+VMEM scratch.
 
-* the **resident prefix** -- positions ``< base[b]`` streamed from the
-  paged pool HBM->VMEM page-group by page-group (grid ``(B, P/G + 1)``,
-  the decode-v2 group-fetch pattern: the page table rides as scalar
-  prefetch and each grid step fetches ``G`` pages as independently
-  pipelined block operands);
-* the **fresh block** -- this dispatch's own K/V columns, attended
-  causally among themselves at token granularity (``kpos <= qpos``) in
-  the final grid step.
+What is here:
 
-Softmax is the standard flash-style online max/sum rescale in f32 VMEM
-scratch, shared across both phases, so KV is read from HBM exactly once
-and nothing is written back but the ``[B, S, Hq, D]`` output.
+* :func:`ragged_paged_attention_xla` -- the pure-XLA reference over a lane
+  rectangle ``[B, S]``: gather the table's pages as the prefix, concatenate
+  the fresh columns, one masked softmax.  The parity oracle of every kernel
+  below and, through :func:`packed_ragged_attention_xla` (unpack, call it,
+  repack), the CPU tier-1 code path.
+* :func:`packed_ragged_attention` -- the packed launch, two kernels chosen
+  by the pool at trace time (:func:`_takes_work_list`).  A dense pool of
+  128-lane heads walks a **work list** with its pages by DMA (the section
+  ahead of :func:`_work_list_kernel`).  An int8 pool and heads narrower than
+  128 lanes keep the **page-group grid** (:func:`_packed_kernel`): grid
+  ``(B, P/G + 1)``, the page table rides as scalar prefetch, each step
+  fetches ``G`` pages of the lane's resident prefix (positions ``<
+  base[b]``) as independently pipelined block operands, and the final step
+  attends the dispatch's own fresh K/V causally at token granularity.
 
-``interpret=True`` runs the same kernel through the Pallas interpreter
-(CPU-testable); :func:`ragged_paged_attention_xla` is the pure-XLA
-reference implementation -- tier-1 (``JAX_PLATFORMS=cpu``) exercises the
-XLA composition via ``engine.attention.ragged_attention_dispatch``, which
-resolves the backend at trace time like every other dispatch gate.
-
-Two operand layouts share the math: the original **rectangle**
-(``[B, S]`` queries, every lane padded to the dispatch's max chunk) and
-the **fully-packed** flat token axis (ISSUE 10,
-:func:`packed_ragged_attention` / :func:`packed_ragged_attention_xla`
-below) whose trunk-side win is the whole point -- see the section
-comment ahead of the packed kernel.  The packed launch has two kernels,
-chosen by the pool at trace time (:func:`_takes_work_list`): a dense pool
-of 128-lane heads walks a work list with its pages by DMA (the section
-ahead of :func:`_work_list_kernel`); an int8 pool and narrower heads keep
-the page-group grid described above.
+``interpret=True`` runs a kernel through the Pallas interpreter
+(CPU-testable); ``engine.attention.packed_ragged_attention_dispatch``
+resolves kernel or reference at trace time like every other dispatch gate.
 """
 
 from __future__ import annotations
@@ -165,227 +157,8 @@ def _for_blocks(n, body) -> None:
     jax.lax.fori_loop(0, n, step, 0)
 
 
-def _ragged_kernel(
-    # scalar prefetch
-    layer_ref,  # [1] layer index (SMEM)
-    pt_ref,  # [B, P] page table (SMEM)
-    base_ref,  # [B] committed cache length = first fresh position (SMEM)
-    len_ref,  # [B] fresh query rows per lane (SMEM)
-    *refs,  # G kv blocks [1, 2, 1, page, Hkv, D] (+ G row-scale group
-    # blocks [1, 2, _SCALE_ROWS, page] when the pool is int8), q, fresh k,
-    # fresh v, then o_ref and m/l/acc scratch
-    G: int,
-    quant: bool = False,
-    window: int = 0,
-):
-    """Grid (B, P/G + 1): steps ``p < P/G`` stream the lane's resident
-    prefix page groups, the final step folds in the dispatch's own fresh
-    K/V block with per-token causal masking.  One online-softmax
-    accumulator serves both phases, so the rescale math cannot diverge
-    between the prefix and fresh halves."""
-    kv_refs = refs[:G]
-    s_refs = refs[G : 2 * G] if quant else [None] * G
-    q_ref, fk_ref, fv_ref, o_ref, m_scr, l_scr, acc_scr = refs[
-        2 * G if quant else G :
-    ]
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    npg = pl.num_programs(1) - 1  # page-group steps before the fresh step
-    page = kv_refs[0].shape[3]
-    Hkv = kv_refs[0].shape[4]
-    D = kv_refs[0].shape[5]
-    S = q_ref.shape[1]
-    Hq = q_ref.shape[2]
-    n_rep = Hq // Hkv
-    scale = 1.0 / (D ** 0.5)
-
-    @pl.when(p == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    base = base_ref[b]
-    q_len = len_ref[b]
-
-    # [S, Hq, D] -> [Hkv, n_rep, S, D]: GQA batch layout shared by both
-    # phases (scratch rows flatten the same (Hkv, n_rep, S) order)
-    def q4():
-        return q_ref[0].transpose(1, 0, 2).reshape(Hkv, n_rep, S, D)
-
-    def accumulate(s, v):  # s [Hkv, n_rep, S, K], v [Hkv, K, D]
-        s2 = s.reshape(Hq * S, s.shape[-1])
-        m_prev = m_scr[:]
-        m_cur = jnp.max(s2, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        probs = jnp.exp(s2 - m_new)
-        pv = jax.lax.dot_general(
-            probs.reshape(Hkv, n_rep * S, s.shape[-1]).astype(v.dtype), v,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # [Hkv, n_rep*S, D]
-        m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(probs, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + pv.reshape(Hq * S, D)
-
-    grp_base = p * G * page
-    live = (p < npg) & (grp_base < base)
-    if window > 0:
-        # keys below every query's window can skip (earliest query sits
-        # at position ``base``)
-        live = live & (grp_base + G * page > base - window)
-
-    @pl.when(live)
-    def _prefix():
-        # each page's row inside its scale group (int8 pools only)
-        rows = [
-            pt_ref[b, p * G + g] % _SCALE_ROWS if quant else 0
-            for g in range(G)
-        ]
-        k = _group_kv(kv_refs, s_refs, rows, 0, q_ref.dtype)
-        v = _group_kv(kv_refs, s_refs, rows, 1, q_ref.dtype)  # [Hkv, G*page, D]
-        s = jax.lax.dot_general(
-            q4(), k,
-            dimension_numbers=(((3,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [Hkv, n_rep, S, G*page]
-        kpos = grp_base + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, dimension=3
-        )
-        keep = kpos < base
-        if window > 0:
-            qpos = base + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, dimension=2
-            )
-            keep = keep & (kpos > qpos - window)
-        accumulate(jnp.where(keep, s, _NEG_INF), v)
-
-    @pl.when(p == npg)
-    def _fresh():
-        fk = fk_ref[0].transpose(1, 0, 2)  # [Hkv, S, D]
-        fv = fv_ref[0].transpose(1, 0, 2)
-        s = jax.lax.dot_general(
-            q4(), fk,
-            dimension_numbers=(((3,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [Hkv, n_rep, S, S]
-        qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, dimension=2)
-        kj = jax.lax.broadcasted_iota(jnp.int32, s.shape, dimension=3)
-        keep = (kj <= qi) & (kj < q_len)
-        if window > 0:
-            keep = keep & (qi - kj < window)
-        accumulate(jnp.where(keep, s, _NEG_INF), fv)
-        l = l_scr[:]
-        safe = jnp.where(l > 0.0, l, 1.0)
-        out = (acc_scr[:] / safe).reshape(Hkv, n_rep, S, D)
-        o_ref[0] = out.reshape(Hq, S, D).transpose(1, 0, 2).astype(o_ref.dtype)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("window", "group", "interpret")
-)
-def ragged_paged_attention(
-    q: jax.Array,  # [B, S, Hq, D] ragged queries (row i at base + i)
-    k: jax.Array,  # [B, S, Hkv, D] fresh keys for the same columns
-    v: jax.Array,  # [B, S, Hkv, D]
-    kv_pages: jax.Array,  # [L, 2, num_pages, page, Hkv, D]
-    page_table: jax.Array,  # [B, P] int32 page ids
-    base: jax.Array,  # [B] committed cache length per lane
-    q_lens: jax.Array,  # [B] valid query rows (0 = inactive lane)
-    layer: jax.Array | int = 0,
-    window: int = 0,
-    group: int = 4,  # pages per grid step
-    interpret: bool = False,
-    kv_scales: jax.Array | None = None,  # [L, 2, num_pages, page] int8 pool
-) -> jax.Array:
-    """Ragged mixed-batch attention over the paged KV pool (see module
-    docstring).  When the table width doesn't divide by ``group``, the
-    group degrades to the largest divisor (callers pass power-of-two
-    widths >= 8, so the full group applies).  ``kv_scales`` arms the
-    fused int8 path: each fetched page group carries its row-scale block
-    and dequantizes in VMEM (ISSUE 13)."""
-    B, S, Hq, D = q.shape
-    L, _, num_pages, page, Hkv, _ = kv_pages.shape
-    P = page_table.shape[1]
-    G = min(group, P)
-    while P % G:
-        G -= 1
-    npg = P // G
-    quant = kv_scales is not None
-
-    pt = jnp.clip(page_table.astype(jnp.int32), 0, num_pages - 1)
-    lyr = jnp.clip(jnp.asarray(layer, jnp.int32), 0, L - 1).reshape(1)
-
-    def kv_map(g):
-        def m(b, p, layer_ref, pt_ref, base_ref, len_ref):
-            # the fresh step (p == npg) re-targets the last group: the
-            # fetch is dead weight there but keeps the operand spec static
-            pp = jnp.minimum(p, npg - 1)
-            return (layer_ref[0], 0, pt_ref[b, pp * G + g], 0, 0, 0)
-
-        return m
-
-    def scale_map(g):
-        def m(b, p, layer_ref, pt_ref, base_ref, len_ref):
-            pp = jnp.minimum(p, npg - 1)
-            return (layer_ref[0], 0, pt_ref[b, pp * G + g] // _SCALE_ROWS, 0)
-
-        return m
-
-    def row_map(b, p, *_):
-        return (b, 0, 0, 0)
-
-    scale_specs = (
-        [
-            pl.BlockSpec((1, 2, _SCALE_ROWS, page), scale_map(g))
-            for g in range(G)
-        ]
-        if quant
-        else []
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, npg + 1),
-        in_specs=[
-            pl.BlockSpec((1, 2, 1, page, Hkv, D), kv_map(g)) for g in range(G)
-        ]
-        + scale_specs
-        + [
-            pl.BlockSpec((1, S, Hq, D), row_map),
-            pl.BlockSpec((1, S, Hkv, D), row_map),
-            pl.BlockSpec((1, S, Hkv, D), row_map),
-        ],
-        out_specs=pl.BlockSpec((1, S, Hq, D), row_map),
-        scratch_shapes=[
-            pltpu.VMEM((Hq * S, 1), jnp.float32),
-            pltpu.VMEM((Hq * S, 1), jnp.float32),
-            pltpu.VMEM((Hq * S, D), jnp.float32),
-        ],
-    )
-    scale_ops = [kv_scales] * G if quant else []
-    return pl.pallas_call(
-        functools.partial(_ragged_kernel, G=G, quant=quant, window=window),
-        out_shape=jax.ShapeDtypeStruct((B, S, Hq, D), q.dtype),
-        grid_spec=grid_spec,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(
-                ragged_vmem_bytes(
-                    S, Hq, Hkv, D, page, G, q.dtype, kv_pages.dtype, quant
-                )
-            ),
-        ),
-        interpret=interpret,
-        name="ragged_paged_attention",
-    )(
-        lyr, pt, base.astype(jnp.int32), q_lens.astype(jnp.int32),
-        *([kv_pages] * G), *scale_ops, q, k, v,
-    )
-
-
 def _kv_stream_bytes(page, Hkv, D, G, kv_dtype, quant) -> int:
-    """Double-buffered page-group operands shared by both kernels."""
+    """The grid kernel's double-buffered page-group operands."""
     n = 2 * G * 2 * _tile_bytes((page, Hkv, D), kv_dtype)
     if quant:
         n += 2 * G * 2 * _tile_bytes((_SCALE_ROWS, page), jnp.float32)
@@ -405,25 +178,6 @@ def _body_bytes(rows, keys, Hkv, D, dtype) -> int:
     )
 
 
-def ragged_vmem_bytes(S, Hq, Hkv, D, page, G, dtype, kv_dtype, quant) -> int:
-    """VMEM the rectangle kernel asks for, from its own shapes."""
-    rows = Hq * S
-    blocks = 2 * (
-        2 * _tile_bytes((S, Hq, D), dtype)
-        + 2 * _tile_bytes((S, Hkv, D), dtype)
-    )
-    scratch = 2 * _tile_bytes((rows, 1), jnp.float32) + _tile_bytes(
-        (rows, D), jnp.float32
-    )
-    return (
-        blocks
-        + scratch
-        + _kv_stream_bytes(page, Hkv, D, G, kv_dtype, quant)
-        + _body_bytes(rows, max(G * page, S), Hkv, D, dtype)
-        + (4 << 20)
-    )
-
-
 def ragged_paged_attention_xla(
     q: jax.Array,  # [B, S, Hq, D]
     k: jax.Array,  # [B, S, Hkv, D] fresh keys
@@ -435,11 +189,11 @@ def ragged_paged_attention_xla(
     layer: jax.Array | int = 0,
     window: int = 0,
 ) -> jax.Array:
-    """Pure-XLA reference of the ragged kernel: gather the full table's
+    """Pure-XLA reference of ragged attention: gather the full table's
     pages as the prefix key block (masked at token granularity by
     ``kpos < base``), concatenate the fresh columns, one masked softmax.
     Same math as ``engine.attention.prefill_prefix_attention`` run with
-    the whole page table as the prefix -- the kernel's parity oracle and
+    the whole page table as the prefix -- the kernels' parity oracle and
     the CPU tier-1 code path.  Takes either pool form: a ``QuantKV``
     pool's pages dequantize right after the gather (same rule the fused
     kernel applies per VMEM tile)."""
@@ -502,16 +256,15 @@ def ragged_paged_attention_xla(
 # fully-packed ragged layout (ISSUE 10): flat token axis + per-lane offsets
 # ---------------------------------------------------------------------------
 #
-# The rectangle above pads EVERY lane's query axis to the dispatch's max
-# chunk, so one long prefill chunk makes the whole batch pay its width --
-# with B=8 lanes, a 512-token chunk next to 7 decode lanes runs a
-# [8, 512] trunk (4096 rows) for 519 real tokens.  The packed layout
-# carries the dispatch's fresh tokens on ONE flat axis of length
-# pow2_bucket(total) with per-lane segment offsets: the trunk (embed /
-# QKV / MLP / logits -- the bulk of prefill FLOPs) runs exactly the
-# packed rows, and attention resolves each token's lane through the
-# offset tables.  Segments are packed contiguously in slot order, one
-# segment per lane, decode lanes contributing a single row.
+# A lane rectangle ``[B, S]`` pads EVERY lane's query axis to the
+# dispatch's max chunk, so one long prefill chunk makes the whole batch pay
+# its width -- with B=8 lanes, a 512-token chunk next to 7 decode lanes is
+# 4096 rows for 519 real tokens.  The packed layout carries the dispatch's
+# fresh tokens on ONE flat axis of length pow2_bucket(total) with per-lane
+# segment offsets: the trunk (embed / QKV / MLP / logits -- the bulk of
+# prefill FLOPs) runs exactly the packed rows, and attention resolves each
+# token's lane through the offset tables.  Segments are packed contiguously
+# in slot order, one segment per lane, decode lanes contributing a single row.
 
 
 # query rows one accumulate body handles: a lane's ``s_max`` window is
@@ -535,8 +288,10 @@ def _packed_kernel(
     quant: bool = False,
     window: int = 0,
 ):
-    """Grid ``(B, P/G + 1)``, the page-streaming structure of
-    :func:`_ragged_kernel`, over PACKED operands: the whole packed
+    """Grid ``(B, P/G + 1)``: steps ``p < P/G`` stream the lane's resident
+    prefix page groups, the final step folds in the dispatch's own fresh K/V
+    with per-token causal masking; one online-softmax accumulator serves
+    both phases.  The operands are PACKED: the whole packed
     ``[Np, H, D]`` q / fresh-k / fresh-v arrays ride as single VMEM
     blocks (revisited every step, so they transfer once), and lane ``b``
     reads its ``s_max``-row window at ``off_ref[b]`` with dynamic
@@ -1027,13 +782,12 @@ def packed_vmem_bytes(
 def packed_shape_fits(
     Np, s_max, Hq, Hkv, D, page, dtype, kv_dtype, quant, group: int = 4
 ) -> bool:
-    """Whether :func:`packed_ragged_attention` can hold ``(Np, s_max)`` at
-    these widths -- the bound the engine checks before it lets a mixed
-    dispatch mint a shape (never discovered at a user's first long
-    prompt).  Only the grid kernel has one: the work-list kernel holds a
+    """Whether the grid kernel (:func:`_packed_kernel`) can hold ``(Np,
+    s_max)`` at these widths -- the bound the engine checks before it lets
+    a mixed dispatch mint a shape (never discovered at a user's first long
+    prompt).  Asked only of a launch that takes the grid
+    (``engine.attention.packed_launch``): the work-list kernel holds a
     tile, whatever the packed shape."""
-    if _takes_work_list(D, quant):
-        return True
     return (
         packed_vmem_bytes(
             Np, s_max, Hq, Hkv, D, page, group, dtype, kv_dtype, quant
@@ -1067,8 +821,8 @@ def packed_ragged_attention(
     dense pool of 128-lane heads takes the work-list kernel, which reads
     every key from the pool and ignores ``k``/``v``/``group``.  An int8
     pool (``kv_scales``) and narrow heads (:func:`_takes_work_list`) keep
-    the page-group-streaming grid of :func:`ragged_paged_attention` with
-    the fused dequant of the rectangle kernel: it reads the pool below
+    the page-group-streaming grid (:func:`_packed_kernel`), which
+    dequantizes an int8 page in VMEM as it is read: it reads the pool below
     ``base`` and the fresh rows from ``k``/``v``, and holds the packed
     operands in VMEM for the whole launch, so ``Np`` bounds its footprint
     (:func:`packed_vmem_bytes`, :func:`packed_shape_fits`).  Chosen by the
@@ -1179,12 +933,10 @@ def packed_ragged_attention_xla(
     window: int = 0,
 ) -> jax.Array:
     """Pure-XLA packed reference: unpack the flat axis into the lane
-    rectangle with per-lane dynamic windows, run the EXACT rectangle
-    reference (:func:`ragged_paged_attention_xla` -- same math, same
-    masks), and repack valid rows.  Attention numerics are therefore
-    identical to the rectangle path by construction; the packed layout's
-    compute win on this backend is the trunk (the step runs ``Np`` rows
-    instead of ``B*S``), while the Pallas kernel above also streams
+    rectangle with per-lane dynamic windows, run the rectangle reference
+    (:func:`ragged_paged_attention_xla`), and repack valid rows.  On this
+    backend the packed layout's win is the trunk alone (the step runs
+    ``Np`` rows instead of ``B*S``); the Pallas kernels above also read
     packed operands.  Rows past a lane's ``q_len`` unpack into the next
     lane's tokens -- harmless, the reference masks fresh keys by
     ``q_lens`` and the repack gather never reads an invalid row's

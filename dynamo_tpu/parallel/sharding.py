@@ -257,7 +257,6 @@ class ShardedSteps:
     mesh: Mesh
     kv_sharding: NamedSharding
     decode_block: Any
-    unified_step: Any
     packed_unified_step: Any
     packed_unified_multistep: Any
     verify_and_sample: Any
@@ -328,19 +327,6 @@ def make_sharded_steps(
         # host-bound (device_get at commit) -- forcing it replicated would
         # insert an all-gather on the hot path for nothing
         out_shardings=(None, vec, vec, vec, kv_sh, None, mat),
-    )
-    unified_step = jax.jit(
-        _step._unified_step,
-        static_argnames=("cfg", "top_n", "use_filters"),
-        donate_argnames=("kv_pages", "tokens", "seq_lens", "active"),
-        # (params, kv, tokens, seq_lens, limit_lens, active, stop_ids,
-        #  page_table, p_tokens, p_start, p_lens, p_sample, p_activate,
-        #  rng, sampling)
-        in_shardings=(
-            param_sh, kv_sh, vec, vec, vec, vec, mat, mat,
-            mat, vec, vec, vec, vec, None, samp,
-        ),
-        out_shardings=(None, vec, vec, vec, kv_sh, None),
     )
     packed_unified_step = jax.jit(
         _step._packed_unified_step,
@@ -458,7 +444,6 @@ def make_sharded_steps(
         mesh=mesh,
         kv_sharding=kv_sh,
         decode_block=decode_block,
-        unified_step=unified_step,
         packed_unified_step=packed_unified_step,
         packed_unified_multistep=packed_unified_multistep,
         verify_and_sample=verify_and_sample,

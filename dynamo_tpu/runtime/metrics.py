@@ -268,14 +268,13 @@ class EngineMetrics:
         )
         # fresh-token accounting per unified dispatch (ISSUE 10): `used`
         # counts real rows (decode lanes + packed prefill tokens),
-        # `dispatched` the rows the executable actually ran, `rectangle`
-        # the rows the lane-rectangle layout would have run -- the
-        # padded-token fractions the long-context bench reports are
-        # 1 - used/dispatched and 1 - used/rectangle
+        # `dispatched` the rows the executable actually ran -- the
+        # padded-token fraction the long-context bench reports is
+        # 1 - used/dispatched
         self.mixed_tokens = reg.counter(
             "dynamo_engine_mixed_tokens",
             "Fresh-token rows per unified mixed dispatch by accounting kind",
-            ["kind"],  # used | dispatched | rectangle
+            ["kind"],  # used | dispatched
         )
         # packed-shape budget (ISSUE 13 satellite): active (Np, s_max)
         # executable pairs the packed unified step may dispatch -- bounded
@@ -332,12 +331,9 @@ class EngineMetrics:
         self.mixed_decode_lanes.observe(decode_lanes)
         self.mixed_prefill_tokens.observe(prefill_tokens)
 
-    def observe_mixed_tokens(
-        self, used: int, dispatched: int, rectangle: int
-    ) -> None:
+    def observe_mixed_tokens(self, used: int, dispatched: int) -> None:
         self.mixed_tokens.labels("used").inc(used)
         self.mixed_tokens.labels("dispatched").inc(dispatched)
-        self.mixed_tokens.labels("rectangle").inc(rectangle)
 
     def observe_kv(
         self, used: int, total: int, bytes_per_token: Optional[float] = None

@@ -26,7 +26,7 @@ is armed with ``draft_model``.
 With the packed unified dispatch (ISSUE 15), verify is not even a
 separate dispatch on the serving hot path: speculating lanes' columns
 fold into ``step.packed_unified_step`` as additional flat-axis segments
-(``verify_and_sample`` remains the classic-path / rectangle fallback),
+(``verify_and_sample`` remains the classic-path fallback),
 and acceptance-aware auto-disable reverts low-acceptance lanes to plain
 decode so speculation is safe to run default-on.
 """

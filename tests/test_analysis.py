@@ -798,18 +798,18 @@ def test_dt010_ignores_other_modules(tmp_path):
 
 def test_dt010_manifest_covers_current_step_surface():
     """The real manifest covers every jitted entry point shipping today in
-    step.py and ops/ -- including the unified mixed-batch step and the
-    ragged paged-attention kernel this manifest entry was minted for."""
+    step.py and ops/ -- including the packed unified mixed-batch step and
+    the packed ragged-attention kernels with their XLA reference."""
     from dynamo_tpu.analysis.hotpath import HOT_PATH_MANIFEST
 
     step = HOT_PATH_MANIFEST["dynamo_tpu/engine/step.py"]
-    assert "unified_step" in step and "prefill_step" in step
+    assert "packed_unified_step" in step and "prefill_step" in step
     # the raw implementations behind the assignment-form jit wrappers (the
     # bodies the sharded serving path re-jits) are the scanned surface
-    assert "_decode_block" in step and "_unified_step" in step
-    assert "ragged_paged_attention*" in HOT_PATH_MANIFEST[
-        "dynamo_tpu/ops/ragged_attention.py"
-    ]
+    assert "_decode_block" in step and "_packed_unified_step" in step
+    ragged = HOT_PATH_MANIFEST["dynamo_tpu/ops/ragged_attention.py"]
+    assert "packed_ragged_attention*" in ragged
+    assert "ragged_paged_attention_xla" in ragged
     assert "flash_prefill_attention" in HOT_PATH_MANIFEST[
         "dynamo_tpu/ops/flash_prefill.py"
     ]

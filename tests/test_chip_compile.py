@@ -80,10 +80,21 @@ def _lanes(chip, P=128):
     return chip((LANES, P), jnp.int32), vec
 
 
-@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("widths", [TINYLLAMA, MIXTRAL], ids=["tinyllama", "mixtral"])
+@pytest.mark.parametrize(
+    "widths,quant,window",
+    [
+        (TINYLLAMA, False, 0), (TINYLLAMA, True, 0),
+        (MIXTRAL, False, 0), (MIXTRAL, True, 0),
+        # the grid kernel as mistral-7b (Mixtral's head widths, a 4096
+        # window) would launch it over an int8 pool
+        (MIXTRAL, True, 4096),
+    ],
+    ids=["tinyllama-bf16", "tinyllama-int8", "mixtral-bf16", "mixtral-int8",
+         "mistral-7b-int8"],
+)
 @pytest.mark.parametrize("Np,s_max", PACKED_SHAPES)
-def test_packed_ragged_attention_compiles(chip, Np, s_max, widths, quant):
+def test_packed_ragged_attention_compiles(chip, Np, s_max, widths, quant,
+                                          window):
     from dynamo_tpu.ops.ragged_attention import (
         packed_ragged_attention,
         packed_shape_fits,
@@ -102,7 +113,7 @@ def test_packed_ragged_attention_compiles(chip, Np, s_max, widths, quant):
     def call(q, k, v, pool, table, base, off, lens, scales):
         return packed_ragged_attention(
             q, k, v, pool, table, base, off, lens, s_max=s_max, layer=3,
-            kv_scales=scales,
+            window=window, kv_scales=scales,
         )
 
     compiled = jax.jit(call).lower(
@@ -208,30 +219,6 @@ def test_packed_bound_refuses_what_the_kernel_cannot_hold():
         4096, 2048, w["Hq"], w["Hkv"], w["D"], PAGE, jnp.bfloat16,
         jnp.bfloat16, False,
     )
-
-
-@pytest.mark.parametrize(
-    "S,quant", [(1, False), (16, False), (16, True), (128, False)],
-    ids=["S1", "S16", "S16-int8", "S128"],
-)
-def test_ragged_paged_attention_compiles(chip, S, quant):
-    from dynamo_tpu.ops.ragged_attention import ragged_paged_attention
-
-    w = TINYLLAMA
-    pool, scales = _pool(chip, w, quant)
-    table, vec = _lanes(chip)
-    q = chip((LANES, S, w["Hq"], w["D"]), jnp.bfloat16)
-    kv = chip((LANES, S, w["Hkv"], w["D"]), jnp.bfloat16)
-
-    def call(q, k, v, pool, table, base, lens, scales):
-        return ragged_paged_attention(
-            q, k, v, pool, table, base, lens, layer=3, kv_scales=scales
-        )
-
-    compiled = jax.jit(call).lower(
-        q, kv, kv, pool, table, vec, vec, scales
-    ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("widths", [TINYLLAMA, MIXTRAL], ids=["tinyllama", "mixtral"])

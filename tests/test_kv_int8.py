@@ -2,8 +2,8 @@
 
 Three contract layers:
 
-* **kernel parity** -- the fused-dequant Pallas kernels (rectangle +
-  packed, interpret mode) match the XLA references over a quantized pool;
+* **kernel parity** -- the packed grid kernel's fused dequant (interpret
+  mode) matches the XLA reference over a quantized pool;
 * **accuracy** -- greedy decode over an int8 pool matches the bf16/f32
   engine on the tiny model, and prefill logits over int8-written KV stay
   within a documented tolerance of the full-width pool (per-row scales:
@@ -175,33 +175,14 @@ def test_pool_footprint_accounting():
 
 
 def _kernel_operands(rng):
-    L, P, page, Hkv, D, Hq, B, S = 2, 16, 8, 2, 16, 4, 2, 8
+    L, P, page, Hkv, D, B = 2, 16, 8, 2, 16, 2
     dense = rng.standard_normal((L, 2, P, page, Hkv, D)).astype(np.float32)
     pool = quantize_kv_blob(dense)
     pool = QuantKV(q=jnp.asarray(pool.q), s=jnp.asarray(pool.s))
-    q = jnp.asarray(rng.standard_normal((B, S, Hq, D)).astype(np.float32))
-    k = jnp.asarray(rng.standard_normal((B, S, Hkv, D)).astype(np.float32))
-    v = jnp.asarray(rng.standard_normal((B, S, Hkv, D)).astype(np.float32))
     pt = jnp.asarray(rng.integers(1, P, (B, 8)).astype(np.int32))
     base = jnp.asarray([16, 9], np.int32)
     q_lens = jnp.asarray([8, 1], np.int32)
-    return pool, q, k, v, pt, base, q_lens
-
-
-def test_rect_kernel_int8_parity_interpret():
-    from dynamo_tpu.ops.ragged_attention import (
-        ragged_paged_attention,
-        ragged_paged_attention_xla,
-    )
-
-    rng = np.random.default_rng(5)
-    pool, q, k, v, pt, base, q_lens = _kernel_operands(rng)
-    ref = ragged_paged_attention_xla(q, k, v, pool, pt, base, q_lens, layer=1)
-    out = ragged_paged_attention(
-        q, k, v, pool.q, pt, base, q_lens, layer=1, interpret=True,
-        kv_scales=pool.s,
-    )
-    np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5)
+    return pool, pt, base, q_lens
 
 
 def test_packed_kernel_int8_parity_interpret():
@@ -211,7 +192,7 @@ def test_packed_kernel_int8_parity_interpret():
     )
 
     rng = np.random.default_rng(6)
-    pool, _q, _k, _v, pt, base, q_lens = _kernel_operands(rng)
+    pool, pt, base, q_lens = _kernel_operands(rng)
     Np, s_max, Hq, Hkv, D, B = 16, 8, 4, 2, 16, 2
     qp = jnp.asarray(rng.standard_normal((Np, Hq, D)).astype(np.float32))
     kp = jnp.asarray(rng.standard_normal((Np, Hkv, D)).astype(np.float32))
@@ -243,7 +224,7 @@ def test_packed_kernel_int8_parity_interpret():
 
 def test_greedy_decode_matches_reference(run):
     """Greedy streams over the int8 pool match the full-width engine on
-    the tiny model, across all three dispatch layouts."""
+    the tiny model, on the packed and the classic dispatch paths."""
 
     async def body():
         prompts = [list(range(1 + i, 14 + i)) for i in range(3)]
@@ -261,7 +242,6 @@ def test_greedy_decode_matches_reference(run):
         ref = await runs()
         for kw in (
             dict(kv_dtype="int8"),
-            dict(kv_dtype="int8", packed_ragged=False),
             dict(kv_dtype="int8", mixed_batching=False),
         ):
             assert await runs(**kw) == ref, kw

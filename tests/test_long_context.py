@@ -4,9 +4,8 @@ ragged prefill, prefetch-overlapped onboarding.
 The contracts under test:
 
 * **bit-identity** -- the packed ragged layout produces token-identical
-  streams to the rectangle layout and the classic separate-dispatch
-  paths, for greedy AND seeded lanes, across chunked prefill,
-  preemption, and spec-decode composition;
+  streams to the classic separate-dispatch paths, for greedy AND seeded
+  lanes, across chunked prefill, preemption, and spec-decode composition;
 * **scheduling only** -- KV-budget admission and queue-side prefetch
   change WHICH TICK a request admits on, never its tokens;
 * **starvation freedom both directions** -- a budget-blocked long head
@@ -15,8 +14,8 @@ The contracts under test:
 * **prefetch hygiene** -- staged chains pin the host ring until
   admission consumes them, and a cancel before admission frees the
   pins (the leak fix);
-* the **CPU bench smoke**: packed padded-token fraction strictly below
-  rectangle, and warm-prefix long-prompt TTFT improves with prefetch
+* the **CPU bench smoke**: the packed padded-token fraction is
+  accounted, and warm-prefix long-prompt TTFT improves with prefetch
   on vs off.
 """
 
@@ -332,27 +331,24 @@ def test_budget_admission_token_identity(run):
     run(body())
 
 
-# -- packed == rectangle == classic bit-identity -----------------------------
+# -- packed == classic bit-identity ------------------------------------------
 
 
-def test_packed_matches_rectangle_and_classic(run):
+def test_packed_matches_classic(run):
     prompts = [[1, 2, 3, 4, 5], [9, 8, 7], [5] * 14, [2, 4]]
 
     async def body():
-        packed = await run_batch(prompts, packed_ragged=True)
-        rect = await run_batch(prompts, packed_ragged=False)
+        packed = await run_batch(prompts)
         classic = await run_batch(prompts, mixed_batching=False)
-        assert packed == rect == classic
+        assert packed == classic
         assert all(len(t) == 6 for t, _ in packed)
 
     run(body())
 
 
 def test_packed_chunked_prefill_identity(run):
-    """Long prompts split across packed unified dispatches match the
-    rectangle chunked path (packed == classic is covered by
-    test_mixed_batching, which runs the packed default against the
-    classic chunked paths)."""
+    """Long prompts split across packed unified dispatches (chunk 8,
+    budget 12) match the classic chunked path."""
     prompts = [list(range(1, 33)), [7] * 29, [3, 1, 4, 1, 5, 9, 2, 6] * 3]
     kw = dict(
         prefill_chunk_tokens=8, mixed_token_budget=12,
@@ -360,9 +356,9 @@ def test_packed_chunked_prefill_identity(run):
     )
 
     async def body():
-        packed = await run_batch(prompts, packed_ragged=True, **kw)
-        rect = await run_batch(prompts, packed_ragged=False, **kw)
-        assert packed == rect
+        packed = await run_batch(prompts, **kw)
+        classic = await run_batch(prompts, mixed_batching=False, **kw)
+        assert packed == classic
 
     run(body())
 
@@ -372,20 +368,19 @@ def test_packed_seeded_sampling_identity(run):
     prompts = [[1, 2, 3, 4, 5], [8, 6, 7, 5, 3, 0, 9]]
 
     async def body():
-        packed = await run_batch(
-            prompts, max_tokens=10, sampling=samp, packed_ragged=True
+        packed = await run_batch(prompts, max_tokens=10, sampling=samp)
+        classic = await run_batch(
+            prompts, max_tokens=10, sampling=samp, mixed_batching=False
         )
-        rect = await run_batch(
-            prompts, max_tokens=10, sampling=samp, packed_ragged=False
-        )
-        assert packed == rect
+        assert packed == classic
 
     run(body())
 
 
 def test_packed_preemption_identity(run):
     """Capacity preemption under the packed layout reproduces the exact
-    streams of the rectangle layout and an uncontended pool."""
+    streams of the classic path in the same tight pool and of an
+    uncontended pool."""
     prompts = [[11, 12, 13, 14], [5, 6, 7, 8], [9, 10, 11, 12]]
 
     async def one(num_pages, **kw):
@@ -395,24 +390,25 @@ def test_packed_preemption_identity(run):
         )
 
     async def body():
-        tight_packed = await one(14, packed_ragged=True)
-        tight_rect = await one(14, packed_ragged=False)
-        roomy = await one(64, packed_ragged=True)
-        assert tight_packed == tight_rect == roomy
+        tight_packed = await one(14)
+        tight_classic = await one(14, mixed_batching=False)
+        roomy = await one(64)
+        assert tight_packed == tight_classic == roomy
 
     run(body())
 
 
 def test_packed_spec_compose_identity(run):
-    """Speculating lanes (device-inactive, verify-driven) compose with
-    packed unified dispatches exactly as with rectangle ones."""
+    """Speculating lanes compose with packed unified dispatches (their
+    verify columns folded into the launch) exactly as with the classic
+    path's standalone verify."""
     pat = [3, 1, 4, 1, 5]
     prompts = [(pat * 5)[:20], [7, 7, 8, 8] * 3]
     spec = SpeculationOptions(enabled=True, num_draft_tokens=3)
 
-    async def one(packed):
+    async def one(mixed):
         engine = make_engine(
-            max_seq_len=128, num_pages=128, packed_ragged=packed
+            max_seq_len=128, num_pages=128, mixed_batching=mixed
         )
         try:
             return await asyncio.gather(
@@ -434,9 +430,8 @@ def test_packed_spec_compose_identity(run):
 
 
 def test_packed_padded_accounting(run):
-    """One packed run accounts both layouts: real rows <= packed rows <
-    rectangle rows whenever chunks are ragged, so the bench's two padded
-    fractions come from a single dispatch stream."""
+    """A packed run accounts its padding: real rows <= rows dispatched,
+    what the bench's padded fraction is computed from."""
 
     async def body():
         engine = make_engine(
@@ -452,9 +447,7 @@ def test_packed_padded_accounting(run):
             )
             used = engine.mixed_used_tokens
             disp = engine.mixed_dispatched_tokens
-            rect = engine.mixed_rect_tokens
-            assert used > 0
-            assert used <= disp < rect
+            assert 0 < used <= disp
         finally:
             await engine.stop()
 
@@ -569,8 +562,8 @@ def test_prefetch_identity_and_hits(run, tmp_path):
 
 
 def test_bench_long_context_smoke(run):
-    """The run_long_context scenario at CPU scale: packed padded-token
-    fraction strictly below rectangle, warm-prefix long TTFT improves
+    """The run_long_context scenario at CPU scale: the packed
+    padded-token fraction is a fraction, warm-prefix long TTFT improves
     with prefetch on vs off, overlap ratio sane, preemption/admission
     counters present."""
     import sys
@@ -586,7 +579,7 @@ def test_bench_long_context_smoke(run):
             counts=(3, 2, 2),
             osl=4,
         )
-        assert out["lctx_padded_frac_packed"] < out["lctx_padded_frac_rect"]
+        assert 0.0 <= out["lctx_padded_frac_packed"] < 1.0
         assert (
             out["lctx_warm_long_ttft_ms_prefetch_on"]
             < out["lctx_warm_long_ttft_ms_prefetch_off"]

@@ -446,5 +446,6 @@ def test_latent_kernels_match_the_xla_composition(layer):
 def test_the_dispatch_annotation_names_the_latent_path():
     """Which latent path a packed dispatch takes is read off the backend at
     trace time (no flag): the XLA composition here, the kernel on a chip."""
-    assert att.latent_packed_path(16) == "absorbed_xla"
+    pool = LatentKV(jnp.zeros((1, 1, 3, 16, 1, 384), jnp.bfloat16), 128)
+    assert att.latent_packed_path(pool) == "absorbed_xla"
     assert not att.latent_kernels_enabled(16)

@@ -358,12 +358,19 @@ def test_prefix_prefill_dispatch_uses_xla_on_cpu():
     assert float(jnp.max(jnp.abs(ref - got))) == 0.0
 
 
-# -- ragged paged-attention kernel (mixed prefill+decode) --------------------
+# -- ragged paged attention (mixed prefill+decode) ---------------------------
+#
+# The reference is ``ragged_paged_attention_xla`` over the lane rectangle;
+# the kernel under test is the packed launch's page-group GRID kernel
+# (``packed_ragged_attention`` at D < 128, where ``_takes_work_list`` is
+# false), the same lanes packed onto one flat token axis.
 
 from dynamo_tpu.ops.ragged_attention import (
-    ragged_paged_attention,
+    _takes_work_list,
+    packed_ragged_attention,
     ragged_paged_attention_xla,
 )
+from tests.test_long_context import _mk_packed_case
 
 
 def _mk_ragged_case(B, S, Pp, page, Hq, Hkv, D, bases, qlens, seed=0,
@@ -389,48 +396,69 @@ def _mk_ragged_case(B, S, Pp, page, Hq, Hkv, D, bases, qlens, seed=0,
     )
 
 
+def _packed_lanes(B, Pp, page, Hq, Hkv, D, bases, qlens, seed=0,
+                  dtype=jnp.float32):
+    """The lanes both ways from one draw: ``(packed, rect, rows)`` --
+    the packed kernel's operands up to ``s_max``, the rectangle reference's
+    operands, and ``rows(out_packed, out_rect)`` pairing each live packed
+    row with its rectangle cell."""
+    (qp, kp, vp, qr, kr, vr, kv_pages, pt, base, seg_off, qn, lane, rel,
+     s_max, total) = _mk_packed_case(
+        B, page, Pp, Hq, Hkv, D, bases, qlens, seed=seed)
+    qp, kp, vp, qr, kr, vr, kv_pages = (
+        x.astype(dtype) for x in (qp, kp, vp, qr, kr, vr, kv_pages))
+    lane_np, rel_np = np.asarray(lane)[:total], np.asarray(rel)[:total]
+
+    def rows(out_packed, out_rect):
+        return (
+            np.asarray(out_packed.astype(jnp.float32))[:total],
+            np.asarray(out_rect.astype(jnp.float32))[lane_np, rel_np],
+        )
+
+    return (
+        (qp, kp, vp, kv_pages, pt, base, seg_off, qn, lane, rel, s_max),
+        (qr, kr, vr, kv_pages, pt, base, qn),
+        rows,
+    )
+
+
+def _grid_kernel_err(case, group, window=0):
+    """Max abs error of the grid kernel (interpret mode) against the
+    rectangle reference over the live rows of ``case``."""
+    (qp, kp, vp, kv_pages, pt, base, seg_off, qn, _lane, _rel, s_max), rect, rows = case
+    assert not _takes_work_list(qp.shape[2], False)
+    ref = ragged_paged_attention_xla(*rect, 1, window)
+    got = packed_ragged_attention(
+        qp, kp, vp, kv_pages, pt, base, seg_off, qn, s_max, 1, window,
+        group=group, interpret=True,
+    )
+    got, ref = rows(got, ref)
+    return float(np.abs(got - ref).max())
+
+
 @pytest.mark.parametrize(
-    "B,S,Pp,page,Hq,Hkv,D,bases,qlens,group",
+    "B,Pp,page,Hq,Hkv,D,bases,qlens,group",
     [
         # pure decode batch (every lane one row)
-        (3, 1, 4, 8, 4, 4, 16, [9, 32, 17], [1, 1, 1], 2),
+        (3, 4, 8, 4, 4, 16, [9, 32, 17], [1, 1, 1], 2),
         # mixed: decode lane + chunked-prefill lanes + a dead lane
-        (4, 8, 4, 8, 8, 2, 32, [16, 0, 11, 24], [1, 8, 5, 0], 2),
+        (4, 4, 8, 8, 2, 32, [16, 0, 11, 24], [1, 8, 5, 0], 2),
         # prefill continuation from a non-page-aligned base
-        (2, 16, 8, 4, 4, 2, 16, [7, 0], [16, 13], 4),
+        (2, 8, 4, 4, 2, 16, [7, 0], [16, 13], 4),
         # group doesn't divide the table: degrades to a divisor
-        (2, 4, 6, 8, 4, 4, 16, [48, 3], [4, 1], 4),
+        (2, 6, 8, 4, 4, 16, [48, 3], [4, 1], 4),
     ],
 )
-def test_ragged_kernel_matches_xla(B, S, Pp, page, Hq, Hkv, D, bases,
-                                   qlens, group):
-    q, k, v, kv_pages, pt, base, qn = _mk_ragged_case(
-        B, S, Pp, page, Hq, Hkv, D, bases, qlens
-    )
-    ref = ragged_paged_attention_xla(q, k, v, kv_pages, pt, base, qn, 1)
-    got = ragged_paged_attention(
-        q, k, v, kv_pages, pt, base, qn, 1, group=group, interpret=True
-    )
-    m = _valid_mask(S, qlens)
-    diff = np.abs(np.asarray(ref) - np.asarray(got)) * m
-    assert float(diff.max()) < 1e-5
+def test_ragged_kernel_matches_xla(B, Pp, page, Hq, Hkv, D, bases, qlens,
+                                   group):
+    case = _packed_lanes(B, Pp, page, Hq, Hkv, D, bases, qlens)
+    assert _grid_kernel_err(case, group) < 1e-5
 
 
 @pytest.mark.parametrize("window", [4, 12])
 def test_ragged_kernel_sliding_window(window):
-    B, S, Pp, page, Hq, Hkv, D = 2, 8, 4, 8, 4, 2, 16
-    bases, qlens = [24, 0], [8, 6]
-    q, k, v, kv_pages, pt, base, qn = _mk_ragged_case(
-        B, S, Pp, page, Hq, Hkv, D, bases, qlens, seed=3
-    )
-    ref = ragged_paged_attention_xla(
-        q, k, v, kv_pages, pt, base, qn, 1, window
-    )
-    got = ragged_paged_attention(
-        q, k, v, kv_pages, pt, base, qn, 1, window, group=2, interpret=True
-    )
-    diff = np.abs(np.asarray(ref) - np.asarray(got)) * _valid_mask(S, qlens)
-    assert float(diff.max()) < 1e-5
+    case = _packed_lanes(2, 4, 8, 4, 2, 16, [24, 0], [8, 6], seed=3)
+    assert _grid_kernel_err(case, 2, window) < 1e-5
 
 
 def test_ragged_xla_matches_prefix_prefill():
@@ -449,30 +477,19 @@ def test_ragged_xla_matches_prefix_prefill():
 
 
 def test_ragged_kernel_bf16():
-    B, S, Pp, page, Hq, Hkv, D = 2, 8, 4, 8, 4, 2, 32
-    bases, qlens = [16, 9], [8, 1]
-    q, k, v, kv_pages, pt, base, qn = _mk_ragged_case(
-        B, S, Pp, page, Hq, Hkv, D, bases, qlens, seed=5, dtype=jnp.bfloat16
-    )
-    ref = ragged_paged_attention_xla(
-        q, k, v, kv_pages, pt, base, qn, 1
-    ).astype(jnp.float32)
-    got = ragged_paged_attention(
-        q, k, v, kv_pages, pt, base, qn, 1, group=2, interpret=True
-    ).astype(jnp.float32)
-    diff = np.abs(np.asarray(ref) - np.asarray(got)) * _valid_mask(S, qlens)
-    assert float(diff.max()) < 0.06
+    case = _packed_lanes(
+        2, 4, 8, 4, 2, 32, [16, 9], [8, 1], seed=5, dtype=jnp.bfloat16)
+    assert _grid_kernel_err(case, 2) < 0.06
 
 
 def test_ragged_dispatch_uses_xla_on_cpu():
-    """On the CPU test platform the ragged dispatch must pick the XLA path
-    (the kernel is TPU-only outside interpret mode)."""
-    B, S, Pp, page, Hq, Hkv, D = 2, 4, 4, 8, 4, 2, 16
-    q, k, v, kv_pages, pt, base, qn = _mk_ragged_case(
-        B, S, Pp, page, Hq, Hkv, D, [8, 0], [1, 4]
+    """On the CPU test platform the packed dispatch must pick the XLA
+    composition (the kernels are TPU-only outside interpret mode), which
+    runs the rectangle reference's exact math on the unpacked lanes."""
+    (qp, kp, vp, kv_pages, pt, base, seg_off, qn, lane, rel, s_max), rect, rows = (
+        _packed_lanes(2, 4, 8, 4, 2, 16, [8, 0], [1, 4]))
+    got = att.packed_ragged_attention_dispatch(
+        qp, kp, vp, kv_pages, 1, pt, base, seg_off, qn, lane, rel, s_max
     )
-    got = att.ragged_attention_dispatch(
-        q, k, v, kv_pages, 1, pt, base, qn
-    )
-    ref = ragged_paged_attention_xla(q, k, v, kv_pages, pt, base, qn, 1)
-    assert float(jnp.max(jnp.abs(ref - got))) == 0.0
+    got, ref = rows(got, ragged_paged_attention_xla(*rect, 1))
+    np.testing.assert_array_equal(got, ref)

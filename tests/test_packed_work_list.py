@@ -156,16 +156,19 @@ def _int8_takes_the_grid_kernel():
     np.testing.assert_allclose(got[:total], ref[:total], rtol=2e-5, atol=2e-5)
 
 
-def _count(jaxpr, name):
-    n = 0
+def _eqns(jaxpr):
+    """Every equation under ``jaxpr``, nested jaxprs included."""
     for eqn in jaxpr.eqns:
-        n += eqn.primitive.name == name
+        yield eqn
         for p in eqn.params.values():
             for x in p if isinstance(p, (list, tuple)) else [p]:
                 inner = getattr(x, "jaxpr", x)
                 if hasattr(inner, "eqns"):
-                    n += _count(inner, name)
-    return n
+                    yield from _eqns(inner)
+
+
+def _count(jaxpr, name):
+    return sum(eqn.primitive.name == name for eqn in _eqns(jaxpr))
 
 
 def test_dma_descriptors_do_not_grow_with_the_key_block(monkeypatch):
@@ -217,7 +220,8 @@ def test_one_step_dispatches_take_the_whole_page_table(run, monkeypatch):
 
     def served(work_list):
         monkeypatch.setattr(
-            att, "packed_walks_work_list", lambda *a: work_list)
+            att, "packed_launch",
+            lambda *a: att.PackedLaunch(work_list, lambda Np, s_max: True))
         engine = tiny_engine(max_seq_len=512, num_pages=300, page_size=4)
         assert engine._packed_full_table is work_list
         widths = {}
@@ -246,3 +250,81 @@ def test_one_step_dispatches_take_the_whole_page_table(run, monkeypatch):
     assert widths["packed_unified_step"] == {full}
     fused = "packed_unified_multistep"
     assert widths[fused] == bucketed[fused] and min(widths[fused]) < full
+
+
+def _pallas_calls(jaxpr):
+    """``(name, grid rank)`` of every ``pallas_call`` under ``jaxpr``."""
+    return [
+        (eqn.params["name"], len(eqn.params["grid_mapping"].grid))
+        for eqn in _eqns(jaxpr) if eqn.primitive.name == "pallas_call"
+    ]
+
+
+# what ``attention.packed_launch`` says of a pool, beside the kernel the
+# step's dispatch then traces over it: (walks a work list, (name, grid rank))
+LAUNCHES = {
+    "bf16_d128": (True, ("packed_ragged_attention", 1)),
+    "int8_d128": (False, ("packed_ragged_attention", 2)),
+    "bf16_d64": (False, ("packed_ragged_attention", 2)),
+    "latent": (True, ("latent_packed_attention", 1)),
+    "cpu": (False, None),
+}
+
+
+@pytest.mark.parametrize("name", list(LAUNCHES))
+def test_packed_launch_reports_what_the_dispatch_traces(name, monkeypatch):
+    """The rule "work list or grid, Pallas or XLA, fits or not" is written
+    once (``attention._packed_backend``): what the engine is told at
+    construction is the launch its step traces."""
+    from dynamo_tpu.engine.kv_cache import LatentKV
+
+    work_list, kernel = LAUNCHES[name]
+    monkeypatch.setattr(att, "_on_tpu", lambda: name != "cpu")
+    Np, s_max, Hq, Hkv, B, P = 32, 16, 4, 2, 2, 4
+    d = 64 if name == "bf16_d64" else D
+    pt = jnp.zeros((B, P), jnp.int32)
+    vec = jnp.zeros((B,), jnp.int32)
+    row = jnp.zeros((Np,), jnp.int32)
+    if name == "latent":
+        Hkv, d = 1, 192  # a row [c_kv | k_r] of 128 + 64, two layers a slab
+        pool = LatentKV(jnp.zeros((1, 1, 9, PAGE, 1, 2 * d), jnp.bfloat16), 128)
+    else:
+        pool = jnp.zeros((2, 2, 9, PAGE, Hkv, d), jnp.bfloat16)
+        if name == "int8_d128":
+            pool = QuantKV(q=pool.astype(jnp.int8),
+                           s=jnp.zeros(pool.shape[:4], jnp.float32))
+    q = jnp.zeros((Np, Hq, d), jnp.bfloat16)
+    k = jnp.zeros((Np, Hkv, d), jnp.bfloat16)
+
+    def step_attention(q, k, pool):
+        if name == "latent":
+            return att.latent_packed_attention_dispatch(
+                q, k, pool, 1, pt, vec, vec, vec, row, row, row, row < Np,
+                s_max)[0]
+        return att.packed_ragged_attention_dispatch(
+            q, k, k, pool, 1, pt, vec, vec, vec, row, row, s_max)
+
+    launch = att.packed_launch(pool, Hq, Hkv, d, jnp.bfloat16)
+    traced = _pallas_calls(jax.make_jaxpr(step_attention)(q, k, pool).jaxpr)
+    assert traced == ([kernel] if kernel else [])
+    assert launch.walks_work_list is work_list
+    # only the grid kernel, which holds the packed operands, bounds a shape
+    grid = kernel is not None and kernel[1] == 2
+    assert launch.fits(1024, 512)
+    assert launch.fits(1 << 20, 1 << 19) is not grid
+
+
+def test_budget_the_grid_kernel_cannot_hold_fails_construction(monkeypatch):
+    """The engine's part of the rule: the widest shape its mixed budget can
+    mint must pass the launch's ``fits`` at construction, and the error
+    names the largest budget that does (the tiny model's 16-wide heads
+    take the grid kernel, which holds the packed operands in VMEM)."""
+    from tests.test_request_stages import tiny_engine
+
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="largest budget that fits is") as e:
+        tiny_engine(page_size=8, mixed_token_budget=1 << 16)
+    ok = int(str(e.value).rsplit(" ", 1)[1])
+    assert 8 < ok < 1 << 16
+    engine = tiny_engine(page_size=8, mixed_token_budget=ok)
+    assert engine._packed_fits(2 * ok, ok) and not engine._packed_full_table
