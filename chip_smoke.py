@@ -801,6 +801,120 @@ def moe_grouped_rows(args, jax) -> None:
          compiled=not args.rehearse)
 
 
+def latent_packed_times(args, jax) -> None:
+    """``latent_packed_attention`` alone at ``mistral-small-4-119b``'s widths
+    (32 heads, C 256, R 64, a pool of 32768 pages, both halves of a slab):
+    milliseconds a launch beside the least the chip could take
+    (``benchmark/costs_mla.absorbed_launch``), for a 2048-row chunk at three
+    depths of a 32k document beside 15 decode rows, a 64-row question at
+    16k, and 16 decode rows.  A sample of each launch's rows is compared
+    with float32 attention over the same pool."""
+    import contextlib
+
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental.pallas import tpu as pltpu
+
+    from benchmark import costs, costs_mla
+    from dynamo_tpu.engine.kv_cache import LatentKV
+    from dynamo_tpu.ops.latent_attention import latent_packed_attention
+
+    B = 16
+    if args.rehearse:
+        Hq, C, R, pages, P, dt, reps = 4, 128, 64, 160, 40, jnp.float32, 1
+        kernel_mode = pltpu.force_tpu_interpret_mode
+        decode_ctx = np.linspace(150, 640, B - 1).astype(int)
+        cases = [("chunk@0", 64, 32, 32, 0, B - 1),
+                 ("chunk@600", 64, 32, 32, 600, B - 1),
+                 ("question@300", 16, 8, 5, 300, 0),
+                 ("decodes", 16, 1, 1, 639, B - 1)]
+    else:
+        Hq, C, R, pages, P, dt, reps = 32, 256, 64, 32768, 2064, jnp.bfloat16, 10
+        kernel_mode = contextlib.nullcontext
+        decode_ctx = np.linspace(8192, 32768, B - 1).astype(int)
+        # (name, Np, s_max, the last lane's fresh rows, its base, decode lanes)
+        cases = [("chunk@0", 4096, 2048, 2048, 0, B - 1),
+                 ("chunk@14336", 4096, 2048, 2048, 14336, B - 1),
+                 ("chunk@30720", 4096, 2048, 2048, 30720, B - 1),
+                 ("question@16384", 256, 128, 64, 16384, 0),
+                 ("question@16384+decodes", 256, 128, 64, 16384, B - 1),
+                 ("decodes", 16, 1, 1, 32767, B - 1)]
+    page, slabs = 16, 3
+    cfg = dict(num_attention_heads=Hq, kv_lora_rank=C, qk_rope_head_dim=R)
+    with open(os.path.join(ROOT, "benchmark", "peaks.json")) as f:
+        peaks = json.load(f)
+    peak = peaks["TPU v5e" if args.rehearse else jax.devices()[0].device_kind]
+    key = jax.random.PRNGKey(args.seed)
+    pool = LatentKV(jax.random.normal(
+        key, (slabs, 1, pages, page, 1, 2 * (C + R)), dt), C)
+    rs = np.random.RandomState(args.seed)
+    table = jnp.asarray(rs.randint(1, pages, (B, P)), jnp.int32)
+    flat = np.asarray(
+        pool.data[1].reshape(pages * page, 2 * (C + R)), np.float32)
+    tab = np.asarray(table)
+    table_out = []
+    for name, Np, s_max, rows, base_last, n_dec in cases:
+        lens = np.zeros(B, np.int32)
+        base = np.zeros(B, np.int32)
+        lens[:n_dec], base[:n_dec] = 1, decode_ctx[:n_dec] - 1
+        lens[B - 1], base[B - 1] = rows, base_last
+        off = np.concatenate([[0], np.cumsum(lens)[:-1]]).astype(np.int32)
+        off = np.minimum(off, Np - s_max)
+        ctxs = base + lens
+        # small queries: a diffuse softmax, as trained weights give
+        q = jax.random.normal(jax.random.fold_in(key, Np + base_last),
+                              (Np, Hq, C + R), dt) / 16
+        ms = {}
+        # everything on the device before the clock starts: a launch of a
+        # millisecond is otherwise timed by its arguments' transfers
+        on_device = jax.block_until_ready(
+            [jnp.asarray(a) for a in (base, off, lens)])
+        for layer in (2, 3):  # the two halves of slab 1
+            at = jax.block_until_ready(jnp.asarray(layer, jnp.int32))
+            call = (q, pool, table, *on_device, s_max, at)
+            with kernel_mode():
+                out = jax.block_until_ready(latent_packed_attention(*call))
+                t0 = time.perf_counter()
+                for _ in range(reps):
+                    out = latent_packed_attention(*call)
+                jax.block_until_ready(out)
+            ms[layer] = (time.perf_counter() - t0) / reps * 1e3
+        # float32 attention for a sample of the rows of the last launch
+        err = 0.0
+        for b in range(B):
+            if not lens[b]:
+                continue
+            picks = sorted({0, int(lens[b]) // 2, int(lens[b]) - 1})
+            n_keys = int(base[b] + lens[b])
+            at = (tab[b, np.arange(n_keys) // page] * page
+                  + np.arange(n_keys) % page)
+            rows_b = flat[at]
+            k = np.concatenate(
+                [rows_b[:, C:2 * C], rows_b[:, 2 * C + R:]], axis=1)
+            for i in picks:
+                qi = np.asarray(q[off[b] + i], np.float32)  # [Hq, C + R]
+                s = qi @ k[: base[b] + i + 1].T / np.sqrt(C + R)
+                p = np.exp(s - s.max(-1, keepdims=True))
+                want = (p / p.sum(-1, keepdims=True)) @ k[: base[b] + i + 1, :C]
+                got = np.asarray(out[off[b] + i], np.float32)
+                err = max(err, float(np.max(np.abs(got - want))))
+        flops, nbytes = costs_mla.absorbed_launch(
+            [int(n) for n in lens if n], [int(c) for c, n in zip(ctxs, lens) if n],
+            cfg)
+        least, bound = costs.roofline_seconds(flops, nbytes, peak)
+        row = dict(case=name, Np=Np, s_max=s_max,
+                   ms_even_half=round(ms[2], 3), ms_odd_half=round(ms[3], 3),
+                   least_ms=round(least * 1e3, 3), bound=bound,
+                   roofline_pct=round(least * 1e5 / ms[3], 1),
+                   max_abs_err=round(err, 5))
+        table_out.append(row)
+        tol = TOLERANCE["float32" if args.rehearse else "bfloat16"]
+        if not np.isfinite(np.asarray(out, np.float32)).all() or err > tol:
+            emit(phase="kernels", failed=row, tolerance=tol)
+            sys.exit(1)
+    emit(phase="kernels", latent_packed=table_out, compiled=not args.rehearse)
+
+
 def child_kernels(args) -> None:
     jax = child_devices(args.rehearse, 1)
     import math
@@ -962,6 +1076,7 @@ def child_kernels(args) -> None:
          max_abs_err_table=rows)
     del pool, qpool
     moe_grouped_rows(args, jax)
+    latent_packed_times(args, jax)
 
     # the engine's own packed steps, lowered as the engine calls them: on
     # the chip the executable must embed the kernel, not the XLA fallback
@@ -1111,6 +1226,9 @@ def main(argv=None) -> int:
         return 0
     if args.child == "moe-grouped":  # that line of the kernels child, alone
         moe_grouped_rows(args, child_devices(args.rehearse, 1))
+        return 0
+    if args.child == "latent-packed":  # and that one
+        latent_packed_times(args, child_devices(args.rehearse, 1))
         return 0
     if args.child == "shard-evidence":
         child_shard_evidence(args)

@@ -32,14 +32,27 @@ Here the grid is a list of work items -- (lane, block of up
 to ``qb`` query rows) -- built on the device from the dispatch's segment
 table, and each item loops over exactly the key blocks its rows can see
 (``fori_loop`` with a dynamic trip count).  Pages are fetched HBM->VMEM by
-explicit DMA, ``KB / page`` pages a key block, double-buffered against the
-block's compute; queries and the output move by DMA too, because a packed
-axis of thousands of rows x 32 heads x 320 does not fit VMEM whole.
+explicit DMA in rolled loops over a key block's LIVE pages (a page is two
+copies, whatever the block's size: the kernel's jaxpr does not grow with
+it), double-buffered against the block's compute; queries and the output
+move by DMA too, because a packed axis of thousands of rows x 32 heads x 320
+does not fit VMEM whole.
 
-An item with a handful of rows (a decode lane riding a mixed step) takes a
-small-tile branch, so it does not pay a 256-row prefill tile's arithmetic.
+**The tile.**  A key block is ``_KEY_BLOCK`` = 512 keys for every item.  An
+item's rows are walked inside the block in sub-tiles of ``_SUB_TOKENS``
+tokens x ``Hq`` heads (a float32 score tile of 2 MB at 32 heads), so the
+block's keys are fetched once an item and the float32 accumulator is read
+and rescaled once per 512 keys and sub-tile, not once per 128.  Two
+sub-tiles a loop step are independent chains: one's softmax runs under the
+other's products.  The loop stops at the item's last live sub-tile, starts
+at the first one that can see the block, and builds the causal mask only
+for sub-tiles the block's last key is past (a 2048-row chunk deep in a
+document masks its last blocks alone).  The running maximum and sum are
+kept lane-dense, ``[rows, 128]`` with every lane alike.  An item with a
+handful of rows (a decode lane riding a mixed step, or the decode launch)
+takes one sub-tile of ``_SMALL_ROWS`` tokens.
 
-Output rows past an item's own (the tail of its last block) overlap the
+Output rows past an item's own (the tail of its last loop step) overlap the
 next segment; items run in ascending row order and each waits for its
 output copy, so the owner's write lands last.  Rows no item covers keep the
 zeros the output buffer is created with.
@@ -48,6 +61,7 @@ zeros the output buffer is created with.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -55,16 +69,38 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+_LANES = 128
 
 # query rows (tokens) of one work item of a packed launch, and the rows at
 # or under which an item takes the small tile
 _Q_BLOCK = 256
 _SMALL_ROWS = 8
-# keys a key block holds: short for the wide prefill tile (the score tile
-# is [qb * Hq, KB] in float32), long where the tile is a few rows
-_KB_WIDE_TILE = 128
-_KB_SMALL_TILE = 512
+# a wide item's rows are walked in sub-tiles of this many tokens (x Hq
+# query rows: the float32 score tile is [32 * Hq, KB]), ``_CHAINS`` of them
+# a loop step: independent chains, one's softmax under another's products
+_SUB_TOKENS = 32
+_CHAINS = 2
+# keys a key block holds, whatever the tile
+_KEY_BLOCK = 512
 VMEM_LIMIT_BYTES = 100 << 20
+
+
+def _wide_tile(qb: int):
+    """``(tokens a sub-tile, sub-tiles a loop step)`` for the items of more
+    than ``_SMALL_ROWS`` rows; a step's tokens divide ``qb``, so no step
+    reaches past the item's block of the packed axis.  None where a query
+    block holds no such item (the decode launch)."""
+    if qb <= _SMALL_ROWS:
+        return None
+    st = math.gcd(qb, _SUB_TOKENS)
+    return st, _CHAINS if (qb // st) % _CHAINS == 0 else 1
+
+
+def _lanes(x, n: int):
+    """``x [rows, 128]``, the same value in every lane, as ``[rows, n]``."""
+    if n % _LANES:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return x if n == _LANES else pltpu.repeat(x, n // _LANES, axis=1)
 
 
 def _latent_kernel(
@@ -81,10 +117,16 @@ def _latent_kernel(
     _o_init,  # the zeroed output buffer (aliased to o_hbm)
     o_hbm,  # [Np * Hq, C]
     # scratch
-    q_v, kbuf, m_scr, l_scr, acc_scr, o_v, sem_q, sem_kv, sem_o,
+    q_v,  # [qb * Hq, C + 2R] an item's queries, heads-minor
+    kbuf,  # [2, KB, C + 2R] two slots of a key block's pages
+    rel_scr,  # [sub-tile rows, KB] key column less the row's token
+    m_scr, l_scr,  # [qb * Hq, 128] running max and sum, every lane alike
+    acc_scr,  # [qb * Hq, C]
+    o_v,  # [qb * Hq, C]
+    sem_q, sem_kv, sem_o,
     *,
-    qb: int,
     small: int,
+    wide,
     Hq: int,
 ):
     w = pl.program_id(0)
@@ -99,107 +141,188 @@ def _latent_kernel(
     slab = layer_ref[0]
     mine = pl.ds(pl.multiple_of(layer_ref[1] * C, C), C)  # my c_kv columns
 
-    def parts(src_page, slot, j):
-        """A page's two copies: the layer's c_kv columns, the k_r tile."""
-        at = pl.ds(j * page, page)
+    @pl.when(w == 0)
+    def _once():
+        # a block's dead pages are never fetched: what the slots hold there
+        # meets a probability of zero, and must be finite
+        kbuf[...] = jnp.zeros(kbuf.shape, kbuf.dtype)
+        # rows lie heads-minor: row r of a sub-tile is token r // Hq
+        col = jax.lax.broadcasted_iota(jnp.int32, rel_scr.shape, 1)
+        tok = jax.lax.broadcasted_iota(jnp.int32, rel_scr.shape, 0) // Hq
+        rel_scr[...] = col - tok
+
+    def page_parts(pid, slot, j):
+        """A page's two copies into place ``j`` of a slot: the layer's c_kv
+        columns, the k_r tile."""
+        src = kv_hbm.at[slab, pid]
+        at = pl.ds(pl.multiple_of(j * page, page), page)
         return (
             pltpu.make_async_copy(
-                src_page.at[:, mine], kbuf.at[slot, at, pl.ds(0, C)],
+                src.at[:, mine], kbuf.at[slot, at, pl.ds(0, C)],
                 sem_kv.at[slot],
             ),
             pltpu.make_async_copy(
-                src_page.at[:, pl.ds(2 * C, R2)],
-                kbuf.at[slot, at, pl.ds(C, R2)], sem_kv.at[slot],
+                src.at[:, pl.ds(2 * C, R2)], kbuf.at[slot, at, pl.ds(C, R2)],
+                sem_kv.at[slot],
             ),
         )
 
-    def fetch(lane, kb, slot):
-        for j in range(n_pg):
-            pid = pt_ref[lane, jnp.minimum(kb * n_pg + j, P - 1)]
-            for c in parts(kv_hbm.at[slab, pid], slot, j):
+    def fetch(lane, kb, slot, pg_hi):
+        """Start the copies of key block ``kb``'s live pages."""
+        def start(pg, carry):
+            for c in page_parts(pt_ref[lane, pg], slot, pg - kb * n_pg):
                 c.start()
+            return carry
 
-    def wait(slot):
-        for j in range(n_pg):
-            for c in parts(kv_hbm.at[0, 0], slot, j):
-                c.wait()
+        jax.lax.fori_loop(
+            kb * n_pg, jnp.minimum((kb + 1) * n_pg, pg_hi), start, 0
+        )
 
-    def attend(nrow, pos0, n_kb, lane, row_at):
-        """Online softmax of the tile's first ``nrow`` tokens over key
-        blocks ``0 .. n_kb``."""
-        R_ = nrow * Hq
-        m_scr[:R_] = jnp.full((R_, 1), _NEG_INF, jnp.float32)
-        l_scr[:R_] = jnp.zeros((R_, 1), jnp.float32)
-        acc_scr[:R_] = jnp.zeros((R_, C), jnp.float32)
-        q = q_v[:R_]
-        # a row's position: its token's place in the item, heads-minor
-        tok = jax.lax.broadcasted_iota(jnp.int32, (R_, KB), 0) // Hq
-        qpos = pos0 + tok
+    def wait(kb, slot, pg_hi):
+        """Wait for key block ``kb``'s copies: a DMA semaphore counts bytes,
+        so a block whose pages are all live is one wait for the whole slot;
+        the last block waits page by page."""
+        live = jnp.minimum((kb + 1) * n_pg, pg_hi) - kb * n_pg
+
+        @pl.when(live == n_pg)
+        def _():
+            pltpu.make_async_copy(
+                kbuf.at[1 - slot], kbuf.at[slot], sem_kv.at[slot]
+            ).wait()
+
+        @pl.when(live < n_pg)
+        def _():
+            def done(pg, carry):
+                for c in page_parts(0, slot, 0):
+                    c.wait()
+                return carry
+
+            jax.lax.fori_loop(0, live, done, 0)
+
+    def attend(st, chains, may_skip_mask, lane, row_at, pos0):
+        """Online softmax of an item over the key blocks its rows can see.
+        Its rows are walked in steps of ``chains`` sub-tiles of ``st``
+        tokens, as many steps as hold a live row."""
+        SR = st * Hq  # query rows of a sub-tile
+        T, TR = st * chains, st * chains * Hq  # tokens and rows of a step
+        n_steps = (rows + T - 1) // T
+        last = pos0 + rows - 1  # the last live row's position
+        pg_hi = jnp.minimum(last // page + 1, P)
+        n_kb = last // KB + 1
+
+        def rows_of(i):
+            return pl.ds(pl.multiple_of(i * TR, TR), TR)
+
+        def each_step(body, lo=0, hi=n_steps):
+            def step(i, carry):
+                body(i)
+                return carry
+
+            jax.lax.fori_loop(lo, hi, step, 0)
+
+        def q_in(i):
+            return pltpu.make_async_copy(
+                q_hbm.at[pl.ds(row_at + i * TR, TR)], q_v.at[rows_of(i)],
+                sem_q.at[0],
+            )
+
+        def o_out(i):
+            return pltpu.make_async_copy(
+                o_v.at[rows_of(i)], o_hbm.at[pl.ds(row_at + i * TR, TR)],
+                sem_o.at[0],
+            )
+
+        each_step(lambda i: q_in(i).start())
+        fetch(lane, 0, 0, pg_hi)
+
+        def clear(i):
+            at = rows_of(i)
+            m_scr[at] = jnp.full((TR, _LANES), _NEG_INF, jnp.float32)
+            l_scr[at] = jnp.zeros((TR, _LANES), jnp.float32)
+            acc_scr[at] = jnp.zeros((TR, C), jnp.float32)
+
+        each_step(clear)
+        each_step(lambda i: q_in(0).wait())
 
         def block(kb, carry):
             slot = kb % 2
-            wait(slot)
+            wait(kb, slot, pg_hi)
 
             @pl.when(kb + 1 < n_kb)
             def _():
-                fetch(lane, kb + 1, 1 - slot)
+                fetch(lane, kb + 1, 1 - slot, pg_hi)
 
             k = kbuf[slot]  # [KB, C + 2R]
             kc = k[:, :C]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [R_, KB]
-            kpos = kb * KB + jax.lax.broadcasted_iota(
-                jnp.int32, (R_, KB), 1
-            )
-            s = jnp.where(kpos <= qpos, s * scale, _NEG_INF)
-            m_prev = m_scr[:R_]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            pv = jax.lax.dot_general(
-                p.astype(kc.dtype), kc, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [R_, C]
-            m_scr[:R_] = m_new
-            l_scr[:R_] = l_scr[:R_] * alpha + jnp.sum(
-                p, axis=-1, keepdims=True
-            )
-            acc_scr[:R_] = acc_scr[:R_] * alpha + pv
+
+            def update(i, masked):
+                for ch in range(chains):
+                    at = pl.ds(pl.multiple_of(i * TR + ch * SR, SR), SR)
+                    s = jax.lax.dot_general(
+                        q_v[at], k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    ) * scale  # [SR, KB]
+                    if masked:
+                        # kpos <= qpos, both less the sub-tile's first row's
+                        # position and the block's first key
+                        first = pos0 + (i * chains + ch) * st - kb * KB
+                        s = jnp.where(rel_scr[:SR] <= first, s, _NEG_INF)
+                    m_prev = m_scr[at]
+                    m_new = jnp.maximum(
+                        m_prev, jnp.max(s, axis=-1, keepdims=True)
+                    )  # [SR, 128]
+                    alpha = jnp.exp(m_prev - m_new)
+                    p = jnp.exp(s - _lanes(m_new, KB))
+                    pv = jax.lax.dot_general(
+                        p.astype(kc.dtype), kc, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )  # [SR, C]
+                    m_scr[at] = m_new
+                    l_scr[at] = l_scr[at] * alpha + jnp.sum(
+                        p, axis=-1, keepdims=True
+                    )
+                    acc_scr[at] = acc_scr[at] * _lanes(alpha, C) + pv
+
+            # steps wholly under the block's first key have nothing to add
+            lo = jnp.maximum(kb * KB - pos0, 0) // T
+            # and from the step whose first row sees the block's last key
+            # on, no row needs the mask
+            free = n_steps
+            if may_skip_mask:
+                reach = jnp.maximum((kb + 1) * KB - 1 - pos0, 0)
+                free = jnp.minimum((reach + T - 1) // T, n_steps)
+                each_step(lambda i: update(i, False), free, n_steps)
+            each_step(lambda i: update(i, True), lo, free)
             return carry
 
         jax.lax.fori_loop(0, n_kb, block, 0)
-        o_v[:R_] = (acc_scr[:R_] / l_scr[:R_]).astype(o_v.dtype)
-        # only the tile's own rows go out: what lies past them in o_v is
-        # another item's, or nothing
-        out = pltpu.make_async_copy(
-            o_v.at[pl.ds(0, R_)], o_hbm.at[pl.ds(row_at, R_)], sem_o.at[0]
-        )
-        out.start()
-        out.wait()
+
+        def finish(i):
+            at = rows_of(i)
+            o_v[at] = (
+                acc_scr[at] * _lanes(1.0 / l_scr[at], C)
+            ).astype(o_v.dtype)
+            # whole steps go out: what lies past the item's rows in the
+            # last one is another item's, or nothing
+            o_out(i).start()
+
+        each_step(finish)
+        each_step(lambda i: o_out(0).wait())
 
     @pl.when(rows > 0)
     def _item():
-        lane = w_lane[w]
-        pos0 = w_pos0[w]
-        row_at = pl.multiple_of(w_row0[w] * Hq, Hq)
-        at = pl.ds(row_at, qb * Hq)
-        q_in = pltpu.make_async_copy(q_hbm.at[at], q_v, sem_q.at[0])
-        q_in.start()
-        n_kb = (pos0 + rows + KB - 1) // KB  # key blocks some row can see
-        fetch(lane, 0, 0)
-        q_in.wait()
-        if small < qb:
-            @pl.when(rows <= small)
-            def _():
-                attend(small, pos0, n_kb, lane, row_at)
+        args = (w_lane[w], pl.multiple_of(w_row0[w] * Hq, Hq), w_pos0[w])
+        if wide is None:
+            attend(small, 1, False, *args)
+            return
 
-            @pl.when(rows > small)
-            def _():
-                attend(qb, pos0, n_kb, lane, row_at)
-        else:
-            attend(qb, pos0, n_kb, lane, row_at)
+        @pl.when(rows <= small)
+        def _():
+            attend(small, 1, False, *args)
+
+        @pl.when(rows > small)
+        def _():
+            attend(*wide, True, *args)
 
 
 def _launch(
@@ -227,9 +350,8 @@ def _launch(
          jnp.where(half == 0, zeros, q_r)], axis=-1,
     )
     Wd = C + 2 * R
-    small = min(_SMALL_ROWS, qb)
-    KB = _KB_WIDE_TILE if qb > small else _KB_SMALL_TILE
-    KB = max(page, KB // page * page)
+    small, wide = min(_SMALL_ROWS, qb), _wide_tile(qb)
+    KB = max(page, _KEY_BLOCK // page * page)
     pool = kv_pages.data.reshape(slabs, num_pages, page, width)
     rows_t = qb * Hq
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -240,8 +362,9 @@ def _launch(
         scratch_shapes=[
             pltpu.VMEM((rows_t, Wd), q.dtype),
             pltpu.VMEM((2, KB, Wd), kv_pages.dtype),
-            pltpu.VMEM((rows_t, 1), jnp.float32),
-            pltpu.VMEM((rows_t, 1), jnp.float32),
+            pltpu.VMEM((max(small, wide[0] if wide else 0) * Hq, KB), jnp.int32),
+            pltpu.VMEM((rows_t, _LANES), jnp.float32),
+            pltpu.VMEM((rows_t, _LANES), jnp.float32),
             pltpu.VMEM((rows_t, C), jnp.float32),
             pltpu.VMEM((rows_t, C), q.dtype),
             pltpu.SemaphoreType.DMA((1,)),
@@ -251,7 +374,7 @@ def _launch(
     )
     i32 = lambda x: x.astype(jnp.int32)  # noqa: E731
     out = pl.pallas_call(
-        functools.partial(_latent_kernel, qb=qb, small=small, Hq=Hq),
+        functools.partial(_latent_kernel, small=small, wide=wide, Hq=Hq),
         out_shape=jax.ShapeDtypeStruct((Np * Hq, C), q.dtype),
         grid_spec=grid_spec,
         input_output_aliases={8: 0},  # the zeroed buffer, after 6 scalars

@@ -493,6 +493,54 @@ def test_latent_decode_attention_compiles(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+@pytest.mark.parametrize("Np,s_max", [(16, 0), *LATENT_SHAPES])
+def test_latent_kernel_jaxpr_does_not_grow_with_the_key_block(
+    monkeypatch, Np, s_max
+):
+    """The set-up budget, where a CPU can guard it (the guard PR 31 lacked:
+    ``setup_s`` 111 -> 123 s for a kernel whose page copies were unrolled).
+    Every packed executable traces and lowers this kernel, so its pages are
+    copied in rolled loops over a block's live pages: as many DMA starts and
+    (within a few) equations at 1024 keys a block as at 128, few of either.
+    ``s_max`` 0 is the fused decode launch."""
+    from dynamo_tpu.engine.kv_cache import LatentKV
+    from dynamo_tpu.ops import latent_attention as la
+    from tests.test_packed_work_list import _count, _eqns
+
+    w = LATENT
+    spec = jax.ShapeDtypeStruct
+    q = spec((Np, w["Hq"], w["C"] + w["R"]), jnp.bfloat16)
+    pool = LatentKV(spec((w["LAYERS"] // 2, 1, w["PAGES"], PAGE, 1,
+                          2 * (w["C"] + w["R"])), jnp.bfloat16), w["C"])
+    table = spec((w["LANES"], w["TABLE"]), jnp.int32)
+    vec = spec((w["LANES"],), jnp.int32)
+
+    def size(keys):
+        monkeypatch.setattr(la, "_KEY_BLOCK", keys)
+        if s_max:
+            jaxpr = jax.make_jaxpr(
+                lambda *a: la.latent_packed_attention.__wrapped__(
+                    *a, s_max=s_max, layer=3)
+            )(q, pool, table, vec, vec, vec)
+        else:
+            jaxpr = jax.make_jaxpr(
+                lambda *a: la.latent_decode_attention.__wrapped__(*a, layer=3)
+            )(q, pool, table, vec)
+        return (sum(1 for _ in _eqns(jaxpr.jaxpr)),
+                _count(jaxpr.jaxpr, "dma_start"))
+
+    at_128, at_512, at_1024 = size(128), size(512), size(1024)
+    assert at_128[1] == at_512[1] == at_1024[1]
+    # (a 128-key block's row statistics need no repeat along the lanes)
+    assert at_1024[0] == at_512[0] <= at_128[0] + 8
+    # a tile: the queries' steps, a page's two parts at the item's first
+    # fetch and at the next block's, the rows' steps out; two tiles at most.
+    # The parent's kernel read 497 equations and 51 starts at 128 keys, 631
+    # and 130 at the decode launch's 512
+    eqns, starts = at_512
+    assert starts <= 12 and eqns <= 900, at_512
+
+
 def test_latent_layers_scatter_and_attend_without_copying_the_pool(chip, monkeypatch):
     """The trunk over a latent pool, as the packed step runs it: every layer
     scatters its rows into the slab and attends through the kernel.  XLA

@@ -405,25 +405,52 @@ def test_checkpoint_tensor_names():
         np.testing.assert_array_equal(np.asarray(got[k]), want[k])
 
 
-@pytest.mark.parametrize("layer", [0, 1, 2])
-def test_latent_kernels_match_the_xla_composition(layer):
+# (fresh rows, first position) a lane, s_max, Np, then the decode step's
+# contexts; a key block is 512 keys, a page 16, a wide item's sub-tile 32
+# tokens and a loop step two of them
+_KERNEL_CASES = {
+    # a decode row, two prefill chunks of which one starts at position 0,
+    # an idle lane
+    "mixed": ([(1, 37), (20, 16), (0, 0), (5, 0)], 32, 64, [38, 36, 1, 5]),
+    # rows that are no multiple of a sub-tile or of a step, a lane of no
+    # rows between two live ones, first positions that are no multiple of
+    # the key block, contexts that end inside a page and inside a key block
+    "edges": ([(1, 700), (9, 513), (0, 0), (65, 37), (200, 1000), (256, 300)],
+              256, 1024, [701, 522, 1, 102, 1200, 556]),
+    # a 2048-token chunk of a document beside decode rows, one launch
+    "chunk": ([(1, 1500), (1, 30), (2048, 600), (1, 511)], 2048, 4096,
+              [1501, 31, 2648, 512]),
+}
+
+
+@pytest.mark.parametrize("layer,case", [
+    pytest.param(0, "mixed", id="0"), pytest.param(1, "mixed", id="1"),
+    pytest.param(2, "mixed", id="2"),
+    pytest.param(2, "edges", id="edges-even"),
+    pytest.param(3, "edges", id="edges-odd"),
+    pytest.param(0, "chunk", id="chunk-even"),
+    pytest.param(1, "chunk", id="chunk-odd"),
+])
+def test_latent_kernels_match_the_xla_composition(layer, case):
     """Both Pallas kernels through the interpreter, on either half of a
-    slab row: a mixed dispatch (a decode row, two prefill chunks of which
-    one starts at position 0, an idle lane) and a decode step."""
+    slab row: a mixed dispatch and a decode step (``_KERNEL_CASES``)."""
     from dynamo_tpu.ops.latent_attention import (
         latent_decode_attention, latent_packed_attention)
     from dynamo_tpu.ops.ragged_attention import packed_ragged_attention_xla
 
-    P, page, Wd, C, Hq, B, Pw = 40, 16, 24, 16, 4, 4, 8
+    lanes, s_max, Np, lens = _KERNEL_CASES[case]
+    page, Wd, C, Hq, B = 16, 24, 16, 4, len(lanes)
+    q_lens, base = (np.array(x) for x in zip(*lanes))
+    Pw = -(-max(int((q_lens + base).max()), max(lens)) // page)
+    P = B * Pw + 1
     rng = np.random.RandomState(layer)
     kv = LatentKV(jnp.asarray(rng.randn(2, 1, P, page, 1, 2 * Wd), jnp.float32), C)
     pt = jnp.asarray(rng.permutation(np.arange(1, P))[: B * Pw].reshape(B, Pw))
-    q_lens, base = np.array([1, 20, 0, 5]), np.array([37, 16, 0, 0])
-    seg_off, s_max, Np = np.array([0, 1, 21, 21]), 32, 64
+    seg_off = np.concatenate([[0], np.cumsum(q_lens)[:-1]])
     lane, rel = np.full(Np, B), np.zeros(Np, np.int32)
     for b in range(B):
-        for i in range(q_lens[b]):
-            lane[seg_off[b] + i], rel[seg_off[b] + i] = b, i
+        at = slice(seg_off[b], seg_off[b] + q_lens[b])
+        lane[at], rel[at] = b, np.arange(q_lens[b])
     q = jnp.asarray(rng.randn(Np, Hq, Wd), jnp.float32)
     rows = jnp.asarray(rng.randn(Np, 1, Wd), jnp.float32)
     valid = lane < B
@@ -437,7 +464,7 @@ def test_latent_kernels_match_the_xla_composition(layer):
     got = latent_packed_attention(q, kv, pt, *args, s_max, layer, interpret=True)
     assert float(jnp.max(jnp.abs(got[valid] - want[valid][..., :C]))) < 1e-5
     qd = jnp.asarray(rng.randn(B, Hq, Wd), jnp.float32)
-    lens = jnp.asarray([38, 36, 1, 5])
+    lens = jnp.asarray(lens)
     want = att.paged_decode_attention(qd, kv.layer_view(layer), pt, lens, 0)
     got = latent_decode_attention(qd, kv, pt, lens, layer, interpret=True)
     assert float(jnp.max(jnp.abs(got - want[..., :C]))) < 1e-5
