@@ -45,6 +45,10 @@ def _add_engine_flags(p) -> None:
     p.add_argument("--max-seq-len", type=int, default=2048)
     p.add_argument("--page-size", type=int, default=16)
     p.add_argument("--num-pages", type=int, default=512)
+    p.add_argument("--num-window-pages", type=int, default=0,
+                   help="a trunk of window and full layers (layer_types): "
+                        "pages of the window layers' pool, beside "
+                        "--num-pages for the full layers'")
     p.add_argument("--block-size", type=int, default=None,
                    help="router-visible KV block size (default: page size)")
     p.add_argument("--decode-block-size", type=int, default=16)
@@ -463,6 +467,7 @@ async def _make_engine(args):
         max_seq_len=args.max_seq_len,
         page_size=args.page_size,
         num_pages=args.num_pages,
+        num_window_pages=args.num_window_pages,
         block_size=args.block_size,
         decode_block_size=args.decode_block_size,
         prefill_chunk_tokens=args.prefill_chunk_tokens,

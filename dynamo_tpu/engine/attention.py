@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Callable, NamedTuple
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +28,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..analysis.hotpath import hot_path
 from .kv_cache import (
+    KindKV,
     QuantKV,
     gather_layer_kv,
     index_kv_layer,
@@ -50,6 +51,58 @@ _NEG_INF = -1e30
 # quantize_kv_rows rule below and scatter both arrays.  The branch
 # resolves at trace time (pytree structure is static), so each compiled
 # executable embeds exactly one layout.
+
+
+# -- two kinds of layer (kv_cache.KindKV) -------------------------------------
+
+
+class LayerView(NamedTuple):
+    """What one layer's attention is handed: its pool, its lanes' page
+    table, its index in that pool, its window, how the written pool goes
+    back into the carried cache, and the suffix a window layer's kernels
+    carry in a device trace."""
+
+    kv: Any
+    table: Any
+    layer: Any
+    window: int
+    put: Callable[[Any], Any]
+    suffix: str
+
+
+def layer_view(cfg, kv_pages, page_table, layer, kind) -> LayerView:
+    """The step functions' one question about a layer.  A trunk of one kind
+    (``kind is None``: every ``attn_fn`` call of such a model) is answered
+    with what it was handed, so its program is the one it always was.  A
+    layer of a two-kind trunk gets the pool of its kind out of the
+    ``KindKV``, that kind's page table (``page_table [2, B, P]``: full,
+    window), and its index among its kind's layers: whole periods before
+    it times the kind's layers a period, plus its place in its own."""
+    if kind is None:
+        return LayerView(
+            kv_pages, page_table, layer, cfg.sliding_window or 0,
+            lambda kv: kv, "",
+        )
+    pattern = cfg.layer_pattern
+    rank, seen = [], 0
+    for k in pattern:
+        rank.append(seen)
+        seen += k == kind
+    idx = layer // len(pattern) * seen + jnp.asarray(rank, jnp.int32)[
+        layer % len(pattern)
+    ]
+    table = page_table
+    if page_table is not None and page_table.ndim == 3:
+        table = page_table[0 if kind == "full" else 1]
+    if not isinstance(kv_pages, KindKV):  # a step that writes no cache
+        return LayerView(
+            kv_pages, table, idx, cfg.kind_window(kind), lambda kv: kv, ""
+        )
+    return LayerView(
+        kv_pages.of(kind), table, idx, cfg.kind_window(kind),
+        lambda pool: kv_pages.replace(kind, pool),
+        "" if kind == "full" else "_window",
+    )
 
 
 def _kv_write(kv_pages, kv_idx, layer, ids, k_rows, *, slot=None):
@@ -168,6 +221,7 @@ def decode_attention_dispatch(
     kv_lens: jax.Array,  # [B]
     layer: jax.Array,  # scalar i32
     window: int = 0,  # sliding-window width; 0 = full attention
+    name_suffix: str = "",  # LayerView.suffix: a window layer's launch
 ) -> jax.Array:
     """Decode attention: Pallas page-streaming kernel on TPU, XLA gather
     elsewhere.  Resolved at trace time (static), so each compiled executable
@@ -195,7 +249,8 @@ def decode_attention_dispatch(
         # the group doesn't divide)
         return _per_shard(
             lambda q, kv, pt, lens, layer: paged_decode_attention_v2(
-                q, kv, pt, lens, layer, window, group=8
+                q, kv, pt, lens, layer, window, group=8,
+                name_suffix=name_suffix,
             ),
             (q, kv_pages, page_table, kv_lens, layer),
             (P(None, "tp", None), _POOL_SPEC, P(), P(), P()),
@@ -379,6 +434,7 @@ def packed_ragged_attention_dispatch(
     rel: jax.Array,  # [Np] row index within the lane's segment
     s_max: int,  # static per-lane window capacity
     window: int = 0,
+    name_suffix: str = "",  # LayerView.suffix: a window layer's launch
 ) -> jax.Array:
     """Fully-packed ragged mixed-batch attention: the ONE attention call
     of ``step.packed_unified_step`` over a pair pool.  A decode lane is a
@@ -405,6 +461,7 @@ def packed_ragged_attention_dispatch(
                 packed_ragged_attention(
                     q, k, v, data, pt, base, off, lens, s_max, layer,
                     window, group=4, kv_scales=sc[0] if sc else None,
+                    name_suffix=name_suffix,
                 )
             ),
             (q, k, v, data, page_table, base, seg_off, q_lens, layer, *s_ops),

@@ -83,6 +83,16 @@ class ModelConfig:
     # shared experts: dense SwiGLUs of the expert width added to every token
     num_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
+    # per-layer attention kinds (mellum): the shortest period of the model's
+    # ``layer_types``, each entry "sliding" (attends the last
+    # ``sliding_window`` keys) or "full".  None = every layer is the one
+    # kind ``sliding_window`` says.  A period holding both kinds gives the
+    # cache two pools (kv_cache.KindKV): a window layer's pages behind the
+    # window are let go, a full layer's are kept
+    layer_pattern: Optional[Tuple[str, ...]] = None
+    # RoPE by kind of layer, ``((kind, theta, rope_scaling), ...)``; None =
+    # ``rope_theta`` / ``rope_scaling`` for every layer
+    rope_by_kind: Optional[Tuple[Tuple[str, float, Optional[tuple]], ...]] = None
     # activation dtype for compute; params may be stored differently
     dtype: str = "bfloat16"
 
@@ -97,6 +107,30 @@ class ModelConfig:
     @property
     def experts_held(self) -> int:
         return self.num_local_experts or self.num_experts
+
+    @property
+    def two_kind(self) -> bool:
+        """Window layers and full layers in one trunk: two pools of pages."""
+        return self.layer_pattern is not None and len(set(self.layer_pattern)) > 1
+
+    def kind_layers(self, kind: str) -> int:
+        """Layers of ``kind`` in the whole trunk."""
+        p = self.layer_pattern
+        return self.num_layers // len(p) * p.count(kind)
+
+    def kind_window(self, kind: Optional[str]) -> int:
+        """The window a layer of ``kind`` attends (0 = every key); ``None``
+        is a trunk of one kind."""
+        if kind == "full":
+            return 0
+        return self.sliding_window or 0
+
+    def kind_rope(self, kind: Optional[str]) -> Tuple[float, Optional[tuple]]:
+        """``(theta, rope_scaling)`` of a layer of ``kind``."""
+        for k, theta, scaling in self.rope_by_kind or ():
+            if k == kind:
+                return theta, scaling
+        return self.rope_theta, self.rope_scaling
 
     @property
     def rope_dim(self) -> int:
@@ -118,6 +152,9 @@ class ModelConfig:
 
     @property
     def kv_values_per_token(self) -> int:
+        """Values a cached token takes over all layers (a two-kind cache:
+        while the token lies inside the window; behind it only the full
+        layers' share stays, ``kv_cache.PagedKVCache.bytes_per_token``)."""
         slabs, sides, heads, width = self.kv_geometry
         return slabs * sides * heads * width
 
@@ -224,7 +261,7 @@ class ModelConfig:
 
     SUPPORTED_MODEL_TYPES = (
         "llama", "mistral", "qwen2", "mixtral", "gemma", "phi3", "qwen3",
-        "mistral4",
+        "mistral4", "mellum",
     )
 
     @classmethod
@@ -249,6 +286,8 @@ class ModelConfig:
         rs = cfg.get("rope_scaling") or cfg.get("rope_parameters") or None
         if mt == "mistral4":
             return cls._from_mistral4(cfg, rs or {})
+        if mt == "mellum":
+            return cls._from_mellum(cfg, rs or {})
         if rs is not None:
             rs_type = rs.get("rope_type") or rs.get("type")
             if rs_type == "llama3":
@@ -286,7 +325,9 @@ class ModelConfig:
                     raise ValueError(
                         f"per-layer sliding window (max_window_layers={mwl} <"
                         f" num_hidden_layers={cfg['num_hidden_layers']}) is"
-                        " not supported"
+                        f" not supported for model_type {mt!r}: a trunk of"
+                        " window and full layers is stated through"
+                        " layer_types (model_type 'mellum')"
                     )
         hidden = cfg["hidden_size"]
         heads = cfg["num_attention_heads"]
@@ -400,6 +441,118 @@ class ModelConfig:
                 (float(beta), int(rs["original_max_position_embeddings"]))
                 if beta else None
             ),
+        )
+
+    @staticmethod
+    def _yarn_tuple(rs: Dict[str, Any], where: str) -> Optional[tuple]:
+        """The ``("yarn", ...)`` tuple :func:`model.rope_cos_sin` takes, from
+        a rope section of type ``default`` (None) or ``yarn``; any other
+        type raises by name.  An ``attention_factor`` is carried as the
+        ``mscale`` that yields it (``0.1 mscale ln(factor) + 1``) with
+        ``mscale_all_dim`` 0: it multiplies cos and sin, so the scores take
+        its square, as the source's rope code does."""
+        rs_type = rs.get("rope_type") or rs.get("type")
+        if rs_type in (None, "default"):
+            return None
+        if rs_type != "yarn":
+            raise ValueError(
+                f"rope type {rs_type!r} ({where}) is not supported"
+                " (implemented: default, yarn)"
+            )
+        factor = float(rs["factor"])
+        mscale = float(rs.get("mscale", 1))
+        mscale_all = float(rs.get("mscale_all_dim", 0))
+        af = rs.get("attention_factor")
+        if af is not None and factor > 1.0:
+            mscale, mscale_all = (float(af) - 1.0) / (0.1 * math.log(factor)), 0.0
+        return (
+            "yarn", factor, int(rs["original_max_position_embeddings"]),
+            float(rs.get("beta_fast", 32)), float(rs.get("beta_slow", 1)),
+            mscale, mscale_all,
+        )
+
+    @classmethod
+    def _from_mellum(cls, cfg: Dict[str, Any], rs: Dict[str, Any]) -> "ModelConfig":
+        """``mellum``: ``layer_types`` as whole periods of
+        ``sliding_attention`` / ``full_attention`` layers, RoPE parameters by
+        kind of layer, every MLP sparse (``num_experts`` of width
+        ``moe_intermediate_size``, ``num_experts_per_tok`` a token, no
+        shared expert)."""
+        L = cfg["num_hidden_layers"]
+        names = {"sliding_attention": "sliding", "full_attention": "full"}
+        types = cfg.get("layer_types") or ["full_attention"] * L
+        for t in types:
+            if t not in names:
+                raise ValueError(
+                    f"mellum layer_types entry {t!r} is not supported"
+                    f" (implemented: {', '.join(names)})"
+                )
+        if len(types) != L:
+            raise ValueError(
+                f"mellum layer_types has {len(types)} entries for"
+                f" num_hidden_layers={L}"
+            )
+        kinds = [names[t] for t in types]
+        # the shortest p with kinds[i] == kinds[i % p] throughout; a trunk
+        # cut inside a period has one, and is not whole repetitions of it
+        period = next(
+            p for p in range(1, L + 1)
+            if all(kinds[i] == kinds[i % p] for i in range(L))
+        )
+        if L % period:
+            raise ValueError(
+                f"mellum layer_types is not whole periods: its shortest"
+                f" period has {period} layers, num_hidden_layers={L}"
+            )
+        pattern = tuple(kinds[:period])
+        window = cfg.get("sliding_window") or None
+        if "sliding" in pattern and not window:
+            raise ValueError(
+                "mellum layer_types has sliding_attention layers and no"
+                " sliding_window"
+            )
+        if any(t != "sparse" for t in cfg.get("mlp_layer_types") or []):
+            raise ValueError(
+                "mellum mlp_layer_types with a dense entry is not supported"
+                " (every MLP sparse)"
+            )
+        if not cfg.get("norm_topk_prob", True):
+            raise ValueError("mellum without norm_topk_prob is not supported")
+        # rope_parameters: one section a kind of layer, or one flat section
+        by_kind = []
+        for src, kind in names.items():
+            if kind not in pattern:
+                continue
+            sec = rs.get(src)
+            if not isinstance(sec, dict):
+                sec = rs
+            by_kind.append((
+                kind,
+                float(sec.get("rope_theta", cfg.get("rope_theta", 10000.0))),
+                cls._yarn_tuple(sec, f"rope_parameters of {src}"),
+            ))
+        if len(set(pattern)) == 1:
+            window = window if pattern[0] == "sliding" else None
+        heads = cfg["num_attention_heads"]
+        hidden = cfg["hidden_size"]
+        return cls(
+            vocab_size=cfg["vocab_size"],
+            hidden_size=hidden,
+            intermediate_size=cfg["moe_intermediate_size"],
+            num_layers=L,
+            num_heads=heads,
+            num_kv_heads=cfg.get("num_key_value_heads", heads),
+            head_dim=cfg.get("head_dim", hidden // heads),
+            rope_theta=by_kind[0][1],
+            rms_norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+            max_position=cfg.get("max_position_embeddings", 4096),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            num_experts=cfg["num_experts"],
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            rope_scaling=by_kind[0][2] if len(by_kind) == 1 else None,
+            sliding_window=window,
+            layer_pattern=pattern if len(set(pattern)) > 1 else None,
+            rope_by_kind=tuple(by_kind) if len(by_kind) > 1 else None,
         )
 
     @classmethod

@@ -49,6 +49,7 @@ from .kv_cache import (
     blob_to_host,
     coerce_kv_blob,
     kv_blob_concat,
+    two_kind_refusal,
 )
 from .metrics import EngineMetrics
 from .model import Params, init_params
@@ -212,6 +213,11 @@ class EngineConfig:
     max_seq_len: int = 2048
     page_size: int = 16
     num_pages: int = 512
+    # a trunk of window and full layers (ModelConfig.layer_pattern) keeps
+    # two pools: ``num_pages`` the full layers', this many the window
+    # layers' (pages let go behind the window: a lane never holds more than
+    # its window, the chunk in flight and its decode growth)
+    num_window_pages: int = 0
     block_size: Optional[int] = None  # router-visible KV block size
     # decode steps per device dispatch: decode state stays on device for this
     # many tokens, so host round trips amortize K-fold (ITL burstiness trade)
@@ -700,6 +706,8 @@ class JaxEngine:
                 "their prefill routes and stage pools assume K/V pairs "
                 "per head and per layer"
             )
+        if mesh is not None:
+            self._refuse_two_kind("a serving mesh (tp, dp, sp or pp)")
         if mesh is not None and kv_sharding is None:
             from ..parallel.sharding import kv_pspec
 
@@ -725,6 +733,7 @@ class JaxEngine:
         self.kv_holdings_sink: Optional[Callable[[Dict[str, Any]], None]] = None
         block_size = self.cfg.block_size or self.cfg.page_size
         pool: Optional[PagePool] = None
+        wpool: Optional[PagePool] = None
         if self.cfg.enable_prefix_caching:
             if block_size % self.cfg.page_size == 0:
                 pool = PagePool(
@@ -732,6 +741,15 @@ class JaxEngine:
                     pages_per_block=block_size // self.cfg.page_size,
                     event_sink=self._emit_kv_event,
                 )
+                if model_cfg.two_kind and self.cfg.num_window_pages >= 2:
+                    # the window layers' pool.  KV events to the router
+                    # follow the full pool alone: a block is routable while
+                    # the full layers hold it (one engine here; a router
+                    # over two-kind engines would want the tail's state too)
+                    wpool = PagePool(
+                        self.cfg.num_window_pages,
+                        pages_per_block=block_size // self.cfg.page_size,
+                    )
             else:
                 logger.warning(
                     "prefix caching disabled: block_size %d is not a "
@@ -759,6 +777,8 @@ class JaxEngine:
             dtype=kv_dtype if kv_dtype is not None else self.cfg.dtype,
             sharding=kv_sharding,
             allocator=pool,
+            num_window_pages=self.cfg.num_window_pages,
+            window_allocator=wpool,
         )
         # serving-step dispatch table: module-level jits on one chip; on a
         # dp/tp (/ep) mesh, re-jitted with explicit in/out shardings
@@ -804,6 +824,8 @@ class JaxEngine:
                 kv_admit=parse_kv_admit_spec(admit_spec),
             ),
             self.kv.allocator,
+            self.kv.window_allocator,
+            model_cfg.sliding_window or 0,
         )
         # registry-backed observability (runtime/metrics.py): the scheduler
         # refreshes queue/occupancy gauges at admission, the engine observes
@@ -836,6 +858,8 @@ class JaxEngine:
             disk_blocks = env_spec["disk"]
             disk_dir = env_spec["dir"] or disk_dir
             swap_on = env_spec["swap"] and self.cfg.swap_preemption
+        if host_blocks > 0 or disk_blocks > 0:
+            self._refuse_two_kind("host/disk KV offload and swap preemption")
         if model_cfg.is_mla and (host_blocks > 0 or disk_blocks > 0):
             raise ValueError(
                 "host/disk KV offload is not supported over a latent cache "
@@ -875,6 +899,8 @@ class JaxEngine:
             logger.warning(
                 "ignoring malformed kv_remote config %r", self.cfg.kv_remote
             )
+        if self.kv_remote_spec:
+            self._refuse_two_kind("the remote KV tier (G4)")
         if model_cfg.is_mla and self.kv_remote_spec:
             raise ValueError(
                 "the remote KV tier (G4) is not supported over a latent "
@@ -921,6 +947,30 @@ class JaxEngine:
                     "ignoring malformed DYN_MIXED_TOKEN_BUDGET=%r", env_budget
                 )
         self._mixed_budget = max(int(budget), 1)
+        if model_cfg.two_kind:
+            if not self._mixed:
+                self._refuse_two_kind("serving without mixed batching")
+            # every lane's most at once, so that a chunk or a decode page
+            # can always be had by taking back reusable blocks: the window
+            # behind each lane's next row and its decode growth, and the
+            # one budget of chunk rows the lanes share
+            ps_ = self.cfg.page_size
+            grow = 2 + self.cfg.grow_chunk_pages + -(
+                -3 * max(self.cfg.decode_block_size, self.cfg.multistep_max_k)
+                // ps_
+            )
+            floor = (
+                self.cfg.max_batch_size
+                * (self.sched.window_lane_pages(0) + grow)
+                + -(-self._mixed_budget // ps_)
+            )
+            if self.cfg.num_window_pages - 1 < floor:
+                raise ValueError(
+                    f"num_window_pages {self.cfg.num_window_pages} is under "
+                    f"the {floor + 1} that {self.cfg.max_batch_size} lanes "
+                    f"of window {model_cfg.sliding_window} and a mixed token "
+                    f"budget of {self._mixed_budget} can hold at once"
+                )
         # per-dispatch fresh-token accounting (the padded-token fraction
         # the long-context bench reports): real rows vs rows dispatched
         self.mixed_used_tokens = 0
@@ -1396,6 +1446,17 @@ class JaxEngine:
                     "sampling penalties are unavailable at max_seq_len "
                     f">= 32768 (engine max_seq_len {self.cfg.max_seq_len})"
                 )
+            if self.model_cfg.two_kind and (
+                self._seq_penalized(seq)
+                or seq.mm_embeds is not None
+                or seq.speculation is not None
+            ):
+                # each leaves the packed step for a classic prefill or
+                # verify dispatch, which takes a prompt's pages at once
+                self._refuse_two_kind(
+                    "a request with sampling penalties, a soft prompt or "
+                    "speculation (the classic prefill and verify dispatches)"
+                )
             self._arm_speculation(seq)  # unknown drafter -> error stream
             self.sched.enqueue(seq)
         except ValueError as e:
@@ -1615,9 +1676,17 @@ class JaxEngine:
         self._refuse_latent("disaggregated serving (a remote prefill's KV)")
         return await self.generate(request, _external=True)
 
+    def _refuse_two_kind(self, what: str) -> None:
+        """Everything that moves or reshapes KV beyond one chip's hot path
+        assumes one pool and one page table a lane."""
+        if self.model_cfg.two_kind:
+            raise ValueError(two_kind_refusal(what))
+
     def _refuse_latent(self, what: str) -> None:
         """KV transfer paths ship ``[L, 2, pages, page, Hkv, D]`` blobs: over
-        a latent cache (MLA) they would move bytes of the wrong shape."""
+        a latent cache (MLA) they would move bytes of the wrong shape, and
+        over a two-kind cache the bytes of one pool of two."""
+        self._refuse_two_kind(what)
         if self.model_cfg.is_mla:
             raise ValueError(
                 f"{what} is not supported over a latent cache (MLA): the "
@@ -3764,7 +3833,6 @@ class JaxEngine:
         # window; pad rows carry an out-of-range slot and drop)
         G = self.cfg.max_batch_size
         E = self.cfg.device_stop_width
-        P = sched.page_table.shape[1]
         slots = np.full((G,), self.cfg.max_batch_size, np.int32)  # pad = drop
         rows = {
             "token": np.zeros((G,), np.int32),
@@ -3772,7 +3840,10 @@ class JaxEngine:
             "limit": np.zeros((G,), np.int32),
             "active": np.zeros((G,), bool),
             "stop": np.full((G, E), -1, np.int32),
-            "pages": np.zeros((G, P), np.int32),
+            "pages": np.zeros(
+                (*sched.page_table.shape[:-2], G, sched.page_table.shape[-1]),
+                np.int32,
+            ),
             "temp": np.zeros((G,), np.float32),
             "top_p": np.ones((G,), np.float32),
             "top_k": np.zeros((G,), np.int32),
@@ -3797,7 +3868,7 @@ class JaxEngine:
                 and not _spec_live(seq)
             )
             rows["stop"][i] = self._lane_stop_row(seq)
-            rows["pages"][i] = sched.page_table[b]
+            rows["pages"][..., i, :] = sched.page_table[..., b, :]
             if seq is not None:
                 so = seq.sampling
                 if so.temperature is not None:
@@ -4122,7 +4193,7 @@ class JaxEngine:
             d["limit_lens"],
             d["active"],
             d["stop_ids"],
-            d["page_table"][:, :Pb],
+            d["page_table"][..., :Pb],
             self._rng,
             d["sampling"],
             K,
@@ -4401,7 +4472,7 @@ class JaxEngine:
             d["limit_lens"],
             d["active"],
             d["stop_ids"],
-            d["page_table"][:, :Pb],
+            d["page_table"][..., :Pb],
             jnp.asarray(t_tokens),
             jnp.asarray(t_lane),
             jnp.asarray(t_rel),
@@ -4622,7 +4693,7 @@ class JaxEngine:
             self._put_batch(tokens),
             self._put_batch(base_arr),
             self._put_batch(n_tok),
-            self._put_batch(sched.page_table[:, :Pb].copy()),
+            self._put_batch(sched.page_table[..., :Pb].copy()),
             self._next_rng(),
             self._sampling_arrays(seqs),
             self._lp_top(seqs),
@@ -5264,6 +5335,12 @@ class JaxEngine:
         self.obs.observe_kv(
             alloc.used_pages, alloc.num_pages - 1, self.kv.bytes_per_token
         )
+        if self.sched.two_kind:
+            self.obs.observe_kv_kinds(
+                {"full": alloc, "window": self.kv.window_allocator},
+                self.sched.resident_context_tokens,
+                self.sched.window_released,
+            )
         if tick is not None:
             tick.mark("commit")
         return events
@@ -5513,6 +5590,8 @@ class JaxEngine:
             attrs: Dict[str, Any] = {}
             if self.model_cfg.is_mla and stage in ("prefill", "decode"):
                 attrs["attn"] = "latent"
+            elif self.model_cfg.two_kind and stage in ("prefill", "decode"):
+                attrs["attn"] = "window+full"
             if stage == "prefill":
                 attrs = {
                     **attrs,

@@ -260,12 +260,81 @@ class LatentLayer:
         ).astype(out_dtype)
 
 
+# ---------------------------------------------------------------------------
+# two kinds of layer (mellum): window layers and full layers in one trunk
+#
+# A full layer reads every key of a sequence; a window layer reads the last
+# ``sliding_window`` and nothing behind them.  One page id for all layers
+# would hold a window layer's bytes for as long as a full layer needs the
+# token (three quarters of a token's bytes at three window layers in four).
+# So the cache is two pools with two allocators and a lane has two page
+# tables, both indexed by absolute position: ``full`` holds the full layers
+# ``[Lf, 2, Pf, page, Hkv, D]``, ``window`` the window layers ``[Lw, 2, Pw,
+# page, Hkv, D]``.  The scheduler lets a window page go as soon as it lies
+# behind the window of every row still to be computed; its entry in the
+# lane's window table then points at the trash page, which the window mask
+# hides.  Like ``QuantKV`` and ``LatentKV`` the pair is a pytree: it rides
+# the layer scan's carry and jit donation.  A layer's view of it is taken
+# at trace time (``attention.layer_view``): each pool alone is a plain pair
+# pool, and every reader and writer below sees only that.
+# ---------------------------------------------------------------------------
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclass
+class KindKV:
+    full: Any  # [Lf, 2, Pf, page, Hkv, D]
+    window: Any  # [Lw, 2, Pw, page, Hkv, D]
+
+    def tree_flatten(self):
+        return (self.full, self.window), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        del aux
+        return cls(*children)
+
+    @property
+    def dtype(self):
+        return self.full.dtype
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.full.nbytes) + int(self.window.nbytes)
+
+    def block_until_ready(self) -> "KindKV":
+        self.full.block_until_ready()
+        self.window.block_until_ready()
+        return self
+
+    def of(self, kind: str):
+        return self.full if kind == "full" else self.window
+
+    def replace(self, kind: str, pool) -> "KindKV":
+        if kind == "full":
+            return KindKV(pool, self.window)
+        return KindKV(self.full, pool)
+
+
 def kv_data(kv_pages):
     """The dense data array of any pool form (shape/dtype queries, Pallas
-    operand plumbing)."""
+    operand plumbing).  A two-kind cache answers with its full pool: the
+    two differ in layers and pages only."""
+    if isinstance(kv_pages, KindKV):
+        return kv_pages.full
     if isinstance(kv_pages, QuantKV):
         return kv_pages.q
     return kv_pages.data if isinstance(kv_pages, LatentKV) else kv_pages
+
+
+def two_kind_refusal(what: str) -> str:
+    """The one sentence with which everything that moves or reshapes KV
+    outside one chip's hot path refuses a two-kind cache."""
+    return (
+        f"{what} is not supported over a two-kind cache (window and full "
+        "layers, layer_types): its pages live in two pools with two page "
+        "tables a lane, and the transfer formats and meshes carry one"
+    )
 
 
 def kv_num_layers(kv_pages) -> int:
@@ -497,10 +566,16 @@ class PagedKVCache:
         dtype: Any = None,
         sharding: Optional[jax.sharding.Sharding] = None,
         allocator: Optional[Any] = None,
+        num_window_pages: int = 0,
+        window_allocator: Optional[Any] = None,
     ) -> None:
         self.cfg = cfg
         self.num_pages = num_pages
         self.page_size = page_size
+        # a two-kind cache (KindKV): ``num_pages``/``allocator`` are the full
+        # layers' pool, these the window layers'
+        self.num_window_pages = num_window_pages if cfg.two_kind else 0
+        self.window_allocator = None
         # "int8" selects the quantized layout (see module section comment);
         # anything else is a plain dense pool of that dtype
         self.quantized = dtype is not None and (
@@ -523,7 +598,28 @@ class PagedKVCache:
                 "one scale a row would span c_kv and the rotated key, whose "
                 "ranges differ"
             )
-        if self.quantized:
+        if cfg.two_kind:
+            if self.quantized or sharding is not None:
+                raise ValueError(two_kind_refusal(
+                    "an int8 pool" if self.quantized else "a sharded pool"))
+            if self.num_window_pages < 2:
+                raise ValueError(
+                    "a trunk of window and full layers needs num_window_pages"
+                    " (the window layers' pool) beside num_pages"
+                )
+            self.window_allocator = (
+                window_allocator if window_allocator is not None
+                else PageAllocator(self.num_window_pages)
+            )
+            self.pages = KindKV(
+                jnp.zeros((cfg.kind_layers("full"), *shape[1:]), self.dtype),
+                jnp.zeros(
+                    (cfg.kind_layers("sliding"), sides, self.num_window_pages,
+                     page_size, heads, width),
+                    self.dtype,
+                ),
+            )
+        elif self.quantized:
             q = jnp.zeros(shape, jnp.int8)
             s = jnp.zeros(shape[:4], jnp.float32)
             if sharding is not None:
@@ -559,12 +655,29 @@ class PagedKVCache:
 
     @property
     def bytes_per_token(self) -> float:
-        """Pool bytes over pool tokens (``dynamo_engine_kv_bytes_per_token``)."""
+        """Pool bytes over pool tokens (``dynamo_engine_kv_bytes_per_token``).
+        A two-kind cache: the bytes of both pools over the tokens the full
+        pool holds, which are the tokens of context it can keep."""
+        if self.cfg.two_kind:
+            return self.pool_bytes / (self.num_pages * self.page_size)
         return self.bytes_per_page / self.page_size
+
+    def kind_bytes_per_page(self, kind: str) -> int:
+        """Bytes of one page of the ``full`` or the ``window`` pool."""
+        _, sides, heads, width = self.cfg.kv_geometry
+        return (
+            self.cfg.kind_layers(kind) * sides * heads * width
+            * self.page_size * self.dtype.itemsize
+        )
 
     @property
     def pool_bytes(self) -> int:
         """Total pool footprint (every page, trash page included)."""
+        if self.cfg.two_kind:
+            return (
+                self.kind_bytes_per_page("full") * self.num_pages
+                + self.kind_bytes_per_page("sliding") * self.num_window_pages
+            )
         return self.bytes_per_page * self.num_pages
 
     def pages_for_tokens(self, n_tokens: int) -> int:

@@ -548,11 +548,18 @@ def transformer_layer(
     layer: jax.Array,  # scalar i32 layer index into kv_pages
     row_valid: Optional[jax.Array] = None,  # [B, T] bool: rows anyone reads
     q_factor: Optional[jax.Array] = None,  # [B, T] per-position query scale
+    kind: Optional[str] = None,  # "sliding" | "full" in a two-kind trunk
 ) -> Tuple[jax.Array, jax.Array]:
     """One decoder layer (norm -> attention -> norm -> MLP, residuals).
     Shared by the single-device layer scan and the pipeline-parallel stage
     loop so the math cannot diverge.  ``row_valid`` lets the expert MLP
-    skip a packed dispatch's padding rows (:func:`_moe_mlp`)."""
+    skip a packed dispatch's padding rows (:func:`_moe_mlp`).  In a trunk
+    of window and full layers ``kind`` is this layer's, known at trace
+    time: ``cos``/``sin`` are its kind's table, and ``attn_fn`` is handed
+    the kind to pick pool, page table and window by
+    (``attention.layer_view``)."""
+    if kind is not None:
+        attn_fn = partial(attn_fn, kind=kind)
     B, T, _ = x.shape
     D = cfg.head_dim
     h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps, cfg.rms_norm_offset)
@@ -598,6 +605,7 @@ def scan_layers(
     attn_fn: AttnFn,
     row_valid: Optional[jax.Array] = None,
     q_factor: Optional[jax.Array] = None,
+    rope_by_kind: Optional[Dict[str, Tuple[jax.Array, jax.Array]]] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Scan ``transformer_layer`` over the stacked weights.
 
@@ -605,7 +613,11 @@ def scan_layers(
     place; making it a scanned input/stacked output would copy the whole
     cache every call (see AttnFn note above).  Shared by the single-device
     trunk and the pipeline-parallel stage loop (which passes its
-    stage-local weight/KV stacks)."""
+    stage-local weight/KV stacks).
+
+    A trunk of window and full layers (``cfg.layer_pattern``) scans over
+    its periods: the body runs the period's layers in order, each with its
+    kind known at trace time, its ``(cos, sin)`` from ``rope_by_kind``."""
     # layers of the stack in hand (a latent pool holds two layers a slab)
     L = lp_stack["input_norm"].shape[0]
     # Where the expert MLP takes the grouped kernel, the experts' weights
@@ -627,8 +639,33 @@ def scan_layers(
         )
         return (x, kv), None
 
+    pattern = cfg.layer_pattern
+    if pattern is None:
+        (x, kv_pages), _ = jax.lax.scan(
+            layer, (x, kv_pages), (lp_stack, jnp.arange(L, dtype=jnp.int32))
+        )
+        return x, kv_pages
+
+    def period(carry, first):
+        # a layer's weights are sliced out of the whole stack by its own
+        # index, as the scan above slices its scanned operand
+        x, kv = carry
+        for j, kind in enumerate(pattern):
+            idx = first + j
+            lp = jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, idx, 0, False),
+                lp_stack,
+            )
+            c, s = rope_by_kind[kind]
+            x, kv = transformer_layer(
+                {**lp, **whole}, x, c, s, cfg, attn_fn, kv, idx, row_valid,
+                q_factor, kind,
+            )
+        return (x, kv), None
+
     (x, kv_pages), _ = jax.lax.scan(
-        layer, (x, kv_pages), (lp_stack, jnp.arange(L, dtype=jnp.int32))
+        period, (x, kv_pages),
+        jnp.arange(0, L, len(pattern), dtype=jnp.int32),
     )
     return x, kv_pages
 
@@ -672,9 +709,17 @@ def transformer(
         pos_t = jnp.arange(T, dtype=jnp.int32)
         take = pos_t[None, :] < jnp.minimum(mm_len, k)[:, None]  # [B, T]
         x = jnp.where(take[:, :, None], inj, x)
-    cos, sin = rope_cos_sin(
-        positions, cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
-    )  # [B, T, D]
+    ropes = None
+    if cfg.layer_pattern is None:
+        cos, sin = rope_cos_sin(
+            positions, cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
+        )  # [B, T, D]
+    else:  # one table a kind of layer, built once a step
+        ropes = {
+            kind: rope_cos_sin(positions, cfg.rope_dim, *cfg.kind_rope(kind))
+            for kind in sorted(set(cfg.layer_pattern))
+        }
+        cos = sin = None
     q_factor = None
     if cfg.query_pos_scaling is not None:
         beta, orig_max = cfg.query_pos_scaling
@@ -686,7 +731,7 @@ def transformer(
         row_valid = row_valid[:, None]
     x, new_kv_pages = scan_layers(
         params["layers"], kv_pages, x, cos, sin, cfg, attn_fn, row_valid,
-        q_factor,
+        q_factor, ropes,
     )
 
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, cfg.rms_norm_offset)

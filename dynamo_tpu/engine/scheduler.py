@@ -166,6 +166,13 @@ class SeqState:
     held_blocks: List[int] = field(default_factory=list)
     # pages allocated to (and freed by) this sequence alone
     owned_pages: List[int] = field(default_factory=list)
+    # a two-kind cache (window and full layers): the window pool's page of
+    # each of ``pages``' positions, 0 where none is held (not reached yet,
+    # or let go behind the window); the window pool's registry refs by
+    # block position; the first block not yet let go
+    wpages: List[int] = field(default_factory=list)
+    w_held: Dict[int, int] = field(default_factory=dict)
+    w_lo: int = 0
     # completed blocks whose final token's KV is not yet written (it lands
     # with the next decode step); registered once the cache catches up
     pending_register: List[TokenBlock] = field(default_factory=list)
@@ -333,9 +340,22 @@ class StepEvent:
 
 
 class Scheduler:
-    def __init__(self, cfg: SchedulerConfig, allocator: PageAllocator) -> None:
+    def __init__(
+        self,
+        cfg: SchedulerConfig,
+        allocator: PageAllocator,
+        window_allocator: Optional[PageAllocator] = None,
+        window: int = 0,
+    ) -> None:
         self.cfg = cfg
         self.allocator = allocator
+        # a two-kind cache (kv_cache.KindKV): ``allocator`` hands out the
+        # full layers' pages, which a sequence keeps while it runs, and
+        # ``window_allocator`` the window layers', which it lets go as they
+        # fall behind ``window`` keys of the next row it computes
+        self.window_allocator = window_allocator
+        self.window = int(window)
+        self.window_released = 0  # window pages let go behind the window
         self.block_size = cfg.block_size or cfg.page_size
         # prefix-cache reuse runs when the allocator is a PagePool (has a
         # sequence-hash registry) and router blocks align to whole pages
@@ -346,6 +366,11 @@ class Scheduler:
             else None
         )
         self.pages_per_block = self.block_size // cfg.page_size
+        self.wpool: Optional[PagePool] = (
+            window_allocator
+            if self.pool is not None and isinstance(window_allocator, PagePool)
+            else None
+        )
         # G2/G3 offload lookup: fn(seq_hash) -> (blob, meta) | None, wired
         # by the engine when offload tiers are configured
         self.offload_lookup: Optional[Any] = None
@@ -383,7 +408,12 @@ class Scheduler:
         # numpy mirrors of the device batch arrays
         self.tokens = np.zeros((B,), np.int32)
         self.seq_lens = np.zeros((B,), np.int32)
-        self.page_table = np.zeros((B, self.max_pages), np.int32)
+        # [B, P]; over a two-kind cache [2, B, P]: full, window (kind-major,
+        # so that a lane's row and a width's slice are what they are in one)
+        self.page_table = np.zeros(
+            (2, B, self.max_pages) if self.two_kind else (B, self.max_pages),
+            np.int32,
+        )
         # layout_version: slot membership changed (admission / release /
         # preemption).  growth_version: pages were appended to live lanes --
         # the engine refreshes the device page table and limits, keeping the
@@ -396,6 +426,19 @@ class Scheduler:
         self.dirty_slots: set = set()
 
     # -- queue/observability -------------------------------------------------
+
+    @property
+    def two_kind(self) -> bool:
+        return self.window_allocator is not None
+
+    @property
+    def resident_context_tokens(self) -> int:
+        """Tokens of context the cache keeps: the running sequences' lengths
+        and the reusable (registered, unreferenced) blocks of the pool a
+        prefix match walks (the full pool of a two-kind cache)."""
+        held = sum(s.seq_len for s in self.slots if s is not None)
+        idle = self.pool.num_inactive if self.pool is not None else 0
+        return held + idle * self.block_size
 
     @property
     def num_waiting(self) -> int:
@@ -523,10 +566,18 @@ class Scheduler:
         # write, with one page of headroom per active seq for growth;
         # reused prefix pages are already resident and cost nothing
         need = self.min_total_pages(seq) - len(cached_pages)
-        if self.allocator.free_pages < need + self.num_active:
+        if self.allocator.free_pages < need + self.num_active or (
+            # window pages are taken a chunk at a time (form_mixed_chunks):
+            # admission asks only that a lane's most can be had now
+            self.two_kind
+            and self.window_allocator.free_pages
+            < min(need, self.window_lane_pages(0)) + self.num_active
+        ):
             self._unmatch_prefix(seq)
             return False
         fresh = self.allocator.alloc(n_pages - len(cached_pages))
+        if self.two_kind:
+            seq.wpages += [0] * (n_pages - len(seq.wpages))
         # onboard pages were allocated inside _match_prefix and stay
         # plain-owned until the engine registers them post-scatter
         onboard = [
@@ -556,7 +607,10 @@ class Scheduler:
         return True
 
     def predicted_pages(self, seq: SeqState) -> int:
-        """Predicted peak KV pages for a request under the budget model:
+        """Predicted peak KV pages for a request under the budget model, in
+        the pool ``allocator`` hands out (over a two-kind cache the full
+        layers' pool: a lane's window pages are bounded by
+        :meth:`window_lane_pages` whatever its length):
         current sequence length (the prompt, for a queued request) plus
         decode headroom -- the remaining token budget, optionally capped
         by ``headroom_tokens``.  Never below what the sequence already
@@ -703,6 +757,10 @@ class Scheduler:
                     - seq.prefilled_tokens
                 if take <= 0 and not chunks:
                     take = min(ps, remaining)
+            if take > 0 and self.two_kind and not self._reserve_chunk_window(
+                seq, seq.prefilled_tokens, take
+            ):
+                take = 0  # the window pool is dry: the lane waits a tick
             if take > 0:
                 chunks.append(
                     MixedChunk(
@@ -736,6 +794,8 @@ class Scheduler:
         max_blocks = max(0, (len(seq.prompt) - 1) // self.block_size)
         hashes = seq.blocks.sequence_hashes()[:max_blocks]
         matched = self.pool.match(hashes)
+        if self.wpool is not None:
+            matched = matched[: self._window_tail_boundary(hashes, len(matched))]
         pages: List[int] = []
         for blk in matched:
             got = self.pool.acquire(blk.sequence_hash)
@@ -744,6 +804,16 @@ class Scheduler:
             seq.held_blocks.append(blk.sequence_hash)
             pages.extend(blk.pages)
         n_matched = len(seq.held_blocks)
+        if self.wpool is not None:
+            # the window layers' share of the hit: the blocks that hold the
+            # last ``window - 1`` tokens before the boundary, and no others
+            ppb = self.pages_per_block
+            seq.wpages = [0] * len(pages)
+            seq.w_lo = max(0, n_matched - self._window_blocks)
+            for i in range(seq.w_lo, n_matched):
+                blk = self.wpool.acquire(hashes[i])
+                seq.w_held[i] = hashes[i]
+                seq.wpages[i * ppb : (i + 1) * ppb] = blk.pages
         if self.offload_lookup is not None:
             for h in hashes[n_matched:]:
                 if self.pool.is_registered(h):
@@ -763,10 +833,100 @@ class Scheduler:
         ) * self.block_size
         return pages
 
+    @property
+    def _window_blocks(self) -> int:
+        """Blocks that hold the ``window - 1`` keys before a block boundary
+        (what the row at the boundary reads of a window layer's cache)."""
+        return -(-(self.window - 1) // self.block_size)
+
+    def window_lane_pages(self, chunk_tokens: int) -> int:
+        """The most window-pool pages a lane holds: the window behind the
+        next row, a chunk of ``chunk_tokens`` in flight, and one page for a
+        boundary inside a page."""
+        return -(-(self.window - 1 + chunk_tokens) // self.cfg.page_size) + 1
+
+    def _window_tail_boundary(self, hashes: List[int], n_full: int) -> int:
+        """The longest ``n <= n_full`` blocks of a prompt that the window
+        layers can resume at: the window pool still has the blocks holding
+        the last ``window - 1`` tokens before ``n`` (a hit of ``n`` blocks
+        needs the full layers' pages for all of them, the window layers'
+        for that tail only).  Where the pool has taken a tail back, the
+        match walks back to the longest boundary whose tail is resident."""
+        need, run, best = self._window_blocks, 0, 0
+        for i in range(n_full):
+            run = run + 1 if self.wpool.is_registered(hashes[i]) else 0
+            if run >= min(i + 1, need):
+                best = i + 1
+        return best
+
+    def _free_window_pages(self, seq: SeqState, lo: int, hi: int) -> int:
+        """Let go of the window pages of block positions ``[lo, hi)``: a
+        registered block's reference (it stays reusable until the pool
+        takes it back), an unregistered page to the free list.  Returns the
+        pages let go."""
+        ppb = self.pages_per_block
+        n = 0
+        for b in range(lo, hi):
+            span = seq.wpages[b * ppb : (b + 1) * ppb]
+            h = seq.w_held.pop(b, None)
+            if h is not None:
+                self.wpool.release(h)
+            else:
+                self.window_allocator.free([p for p in span if p])
+            n += sum(1 for p in span if p)
+            seq.wpages[b * ppb : (b + 1) * ppb] = [0] * len(span)
+        if hi > lo and seq.slot >= 0 and self.slots[seq.slot] is seq:
+            self.page_table[1, seq.slot, lo * ppb : hi * ppb] = 0
+        return n
+
+    def release_window_behind(self, seq: SeqState, next_row: int) -> None:
+        """Drop the window pool's blocks that lie wholly behind the window
+        of ``next_row``, the next position the lane computes (a prefill
+        chunk's start, a decode lane's cache length on the host, which the
+        device is never behind).  No row still to be dispatched reads them.
+        A step already dispatched may: that is safe because dispatches run
+        in order on one stream, and whoever is handed the page next writes
+        it in a dispatch that comes later.  The device's copy of the table
+        keeps the stale entry until its next refresh; the window mask hides
+        it either way."""
+        first_key = next_row - (self.window - 1)
+        behind = min(max(first_key, 0) // self.block_size,
+                     len(seq.wpages) // self.pages_per_block)
+        if behind > seq.w_lo:
+            self.window_released += self._free_window_pages(
+                seq, seq.w_lo, behind)
+            seq.w_lo = behind
+
+    def _reserve_chunk_window(self, seq: SeqState, start: int, take: int) -> bool:
+        """Window pages for the chunk ``[start, start + take)`` of a
+        prefilling lane, after registering what earlier chunks completed
+        and letting go what lies behind ``start``'s window."""
+        ps = self.cfg.page_size
+        self._register_ready(seq, cache_len=start)
+        self.release_window_behind(seq, start)
+        idx = [
+            i for i in range(start // ps, -(-(start + take) // ps))
+            if not seq.wpages[i]
+        ]
+        try:
+            got = self.window_allocator.alloc(len(idx))
+        except OutOfPages:
+            return False
+        for i, p in zip(idx, got):
+            seq.wpages[i] = p
+            self.page_table[1, seq.slot, i] = p
+        if idx:
+            self.growth_version += 1
+        return True
+
     def _unmatch_prefix(self, seq: SeqState) -> None:
         for h in seq.held_blocks:
             self.pool.release(h)
         seq.held_blocks = []
+        if self.two_kind:
+            self._free_window_pages(
+                seq, 0, len(seq.wpages) // self.pages_per_block)
+            seq.wpages, seq.w_lo = [], 0
         for _h, pages, _blob, _meta in seq.pending_onboard:
             self.allocator.free(pages)
         seq.pending_onboard = []
@@ -810,8 +970,12 @@ class Scheduler:
 
     def _write_slot_arrays(self, seq: SeqState) -> None:
         b = seq.slot
-        self.page_table[b, :] = 0
-        self.page_table[b, : len(seq.pages)] = seq.pages
+        self.page_table[..., b, :] = 0
+        if self.two_kind:
+            self.page_table[0, b, : len(seq.pages)] = seq.pages
+            self.page_table[1, b, : len(seq.wpages)] = seq.wpages
+        else:
+            self.page_table[b, : len(seq.pages)] = seq.pages
         self.seq_lens[b] = len(seq.prompt)
         self.tokens[b] = seq.prompt[-1] if seq.prompt else 0
         self.layout_version += 1
@@ -841,6 +1005,14 @@ class Scheduler:
             if seq.slot < 0:
                 continue  # became a preemption victim earlier this pass
             cache_len = int(self.seq_lens[seq.slot])
+            if (
+                self.two_kind
+                and not seq.prefilling
+                and seq.prefilled_tokens >= len(seq.prompt)
+            ):
+                # a decoding lane (its whole prompt dispatched): a lane
+                # admitted this tick still owes its chunks their window
+                self.release_window_behind(seq, cache_len)
             budget = max(self.remaining_budget(seq), 1)
             # max cache length the lane can ever use (limit_lens semantics:
             # the final token's KV is never read, and position max_seq_len-1
@@ -854,7 +1026,7 @@ class Scheduler:
                 want = min(want + chunk_pages, -(-useful // ps), self.max_pages)
             while len(seq.pages) < want:
                 try:
-                    page = self.allocator.alloc(1)[0]
+                    page = self._grow_page(seq)
                 except OutOfPages:
                     if len(seq.pages) >= need:
                         break  # best effort met; lane pauses at capacity
@@ -869,9 +1041,28 @@ class Scheduler:
                     continue
                 seq.pages.append(page)
                 seq.owned_pages.append(page)
-                self.page_table[seq.slot, len(seq.pages) - 1] = page
+                if self.two_kind:
+                    self.page_table[:, seq.slot, len(seq.pages) - 1] = (
+                        page, seq.wpages[-1])
+                else:
+                    self.page_table[seq.slot, len(seq.pages) - 1] = page
                 self.growth_version += 1
         return preempted
+
+    def _grow_page(self, seq: SeqState) -> int:
+        """One more page for a decoding lane; over a two-kind cache one in
+        each pool or none (the window pool's first: its page goes on
+        ``seq.wpages``, so that the two lists stay one length)."""
+        if not self.two_kind:
+            return self.allocator.alloc(1)[0]
+        wpage = self.window_allocator.alloc(1)[0]
+        try:
+            page = self.allocator.alloc(1)[0]
+        except OutOfPages:
+            self.window_allocator.free([wpage])
+            raise
+        seq.wpages.append(wpage)
+        return page
 
     def _pick_preemption_victim(self) -> Optional[SeqState]:
         """Preempt the most recently arrived active sequence (reference
@@ -929,11 +1120,15 @@ class Scheduler:
         if seq.slot >= 0:
             b = seq.slot
             self.slots[b] = None
-            self.page_table[b, :] = 0
+            self.page_table[..., b, :] = 0
             self.seq_lens[b] = 0
             self.tokens[b] = 0
             self.layout_version += 1
             self.dirty_slots.add(b)
+        if self.two_kind:
+            self._free_window_pages(
+                seq, 0, -(-len(seq.wpages) // self.pages_per_block))
+            seq.wpages, seq.w_lo = [], 0
         # registered blocks outlive the sequence (refcount drops; the block
         # turns inactive-reusable at zero); only exclusively-owned pages and
         # never-registered completions return to the free list
@@ -1126,16 +1321,24 @@ class Scheduler:
             seq=seq, tokens=[token], finished=finished, completed_blocks=completed
         )
 
-    def _register_ready(self, seq: SeqState) -> None:
+    def _register_ready(
+        self, seq: SeqState, cache_len: Optional[int] = None
+    ) -> None:
         """Register completed blocks whose KV is fully written.
 
         A block ending at token position ``end`` is committable once the
         cache length reaches ``end``: the decode step that consumed the
         block's final token wrote its KV (commit implies the write was
         dispatched, and the device executes dispatches in order, so any
-        later prefill that reuses the block reads it complete).
+        later prefill that reuses the block reads it complete).  Over a
+        two-kind cache a prefilling lane registers what its dispatched
+        chunks completed (``cache_len``: the tokens dispatched), before its
+        window pages fall behind the window, and each block goes into both
+        pools: the window pool's copy is what lets a later hit resume at
+        the block's boundary.
         """
-        cache_len = int(self.seq_lens[seq.slot])
+        if cache_len is None:
+            cache_len = int(self.seq_lens[seq.slot])
         ppb = self.pages_per_block
         while seq.pending_register:
             blk = seq.pending_register[0]
@@ -1158,6 +1361,15 @@ class Scheduler:
                 seq.held_blocks.append(blk.sequence_hash)
                 for p in pages:
                     seq.owned_pages.remove(p)
+            wpages = seq.wpages[start : start + ppb]
+            if (
+                self.wpool is not None
+                and len(wpages) == ppb
+                and all(wpages)
+                and blk.position not in seq.w_held
+                and self.wpool.register(blk.sequence_hash, wpages)
+            ):
+                seq.w_held[blk.position] = blk.sequence_hash
             # register() == False: identical block already registered by a
             # concurrent twin; keep plain ownership of our duplicate pages
 

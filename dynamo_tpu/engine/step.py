@@ -73,12 +73,11 @@ def prefill_step(
     B, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
 
-    def attn_fn(q, k, v, kv, layer):
-        out = att.prefill_attention_dispatch(
-            q, k, v, seq_lens, cfg.sliding_window or 0
-        )
-        new_kv = att.write_prefill_kv(kv, k, v, page_table, layer)
-        return out, new_kv
+    def attn_fn(q, k, v, kv, layer, kind=None):
+        lv = att.layer_view(cfg, kv, page_table, layer, kind)
+        out = att.prefill_attention_dispatch(q, k, v, seq_lens, lv.window)
+        new_kv = att.write_prefill_kv(lv.kv, k, v, lv.table, lv.layer)
+        return out, lv.put(new_kv)
 
     hidden, kv_pages = transformer(params, cfg, tokens, positions, kv_pages, attn_fn)
     last = jnp.clip(seq_lens - 1, 0, T - 1)
@@ -97,15 +96,18 @@ def _decode_once(
     """One unjitted decode step.  Returns (logits [B,V], kv)."""
     positions = seq_lens.astype(jnp.int32)  # new token position (0-indexed)
 
-    def attn_fn(q, k, v, kv, layer):
+    def attn_fn(q, k, v, kv, layer, kind=None):
         # q/k/v arrive [B, 1, H, D]; squeeze the singleton time axis.
         q1, k1, v1 = q[:, 0], k[:, 0], v[:, 0]
-        new_kv = att.write_decode_kv(kv, k1, v1, page_table, positions, layer)
-        out = att.decode_attention_dispatch(
-            q1, new_kv, page_table, positions + 1, layer,
-            cfg.sliding_window or 0,
+        lv = att.layer_view(cfg, kv, page_table, layer, kind)
+        new_kv = att.write_decode_kv(
+            lv.kv, k1, v1, lv.table, positions, lv.layer
         )
-        return out[:, None], new_kv
+        out = att.decode_attention_dispatch(
+            q1, new_kv, lv.table, positions + 1, lv.layer, lv.window,
+            lv.suffix,
+        )
+        return out[:, None], lv.put(new_kv)
 
     hidden, kv_pages = transformer(params, cfg, tokens, positions, kv_pages, attn_fn)
     return lm_logits(params, cfg, hidden), kv_pages
@@ -268,13 +270,15 @@ def _verify_and_sample(
     B, S = tokens.shape
     positions = base[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
 
-    def attn_fn(q, k, v, kv, layer):
+    def attn_fn(q, k, v, kv, layer, kind=None):
+        lv = att.layer_view(cfg, kv, page_table, layer, kind)
         out = att.prefill_prefix_attention_dispatch(
-            q, k, v, kv, layer, page_table, base, n_tokens,
-            cfg.sliding_window or 0,
+            q, k, v, lv.kv, lv.layer, lv.table, base, n_tokens, lv.window,
         )
-        new_kv = att.write_spec_kv(kv, k, v, page_table, base, n_tokens, layer)
-        return out, new_kv
+        new_kv = att.write_spec_kv(
+            lv.kv, k, v, lv.table, base, n_tokens, lv.layer
+        )
+        return out, lv.put(new_kv)
 
     hidden, kv_pages = transformer(
         params, cfg, tokens, positions, kv_pages, attn_fn
@@ -501,7 +505,7 @@ def _packed_unified_step(
     valid = (t_lane < B) & (t_rel < q_lens[lane_c])
     positions = jnp.where(valid, pos, 0)
 
-    def attn_fn(q, k, v, kv, layer):
+    def attn_fn(q, k, v, kv, layer, kind=None):
         if cfg.is_mla:
             out, new_kv = att.latent_packed_attention_dispatch(
                 q[0], k[0], kv, layer, page_table, base, seg_off, q_lens,
@@ -511,14 +515,15 @@ def _packed_unified_step(
         # rows first: a dense pool's kernel reads every key from the pool;
         # the other paths read the pool below ``base`` and are none the
         # wiser
+        lv = att.layer_view(cfg, kv, page_table, layer, kind)
         new_kv = att.write_packed_kv(
-            kv, k[0], v[0], page_table, t_lane, pos, valid, layer
+            lv.kv, k[0], v[0], lv.table, t_lane, pos, valid, lv.layer
         )
         out = att.packed_ragged_attention_dispatch(
-            q[0], k[0], v[0], new_kv, layer, page_table, base, seg_off,
-            q_lens, t_lane, t_rel, s_max, cfg.sliding_window or 0,
+            q[0], k[0], v[0], new_kv, lv.layer, lv.table, base, seg_off,
+            q_lens, t_lane, t_rel, s_max, lv.window, lv.suffix,
         )
-        return out[None], new_kv
+        return out[None], lv.put(new_kv)
 
     hidden, kv_pages = transformer(
         params, cfg, tok_flat[None], positions[None], kv_pages, attn_fn,
@@ -692,11 +697,9 @@ def score_prompt_step(
     B, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
 
-    def attn_fn(q, k, v, kv, layer):
-        out = att.prefill_attention_dispatch(
-            q, k, v, seq_lens, cfg.sliding_window or 0
-        )
-        return out, kv
+    def attn_fn(q, k, v, kv, layer, kind=None):
+        window = att.layer_view(cfg, None, None, layer, kind).window
+        return att.prefill_attention_dispatch(q, k, v, seq_lens, window), kv
 
     hidden, _ = transformer(params, cfg, tokens, positions, kv_pages, attn_fn)
     targets = jnp.roll(tokens, -1, axis=1)  # target[j] = tokens[j + 1]
@@ -806,12 +809,11 @@ def prefill_mm_and_sample(
     B, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
 
-    def attn_fn(q, k, v, kv, layer):
-        out = att.prefill_attention_dispatch(
-            q, k, v, seq_lens, cfg.sliding_window or 0
-        )
-        new_kv = att.write_prefill_kv(kv, k, v, page_table, layer)
-        return out, new_kv
+    def attn_fn(q, k, v, kv, layer, kind=None):
+        lv = att.layer_view(cfg, kv, page_table, layer, kind)
+        out = att.prefill_attention_dispatch(q, k, v, seq_lens, lv.window)
+        new_kv = att.write_prefill_kv(lv.kv, k, v, lv.table, lv.layer)
+        return out, lv.put(new_kv)
 
     hidden, kv_pages = transformer(
         params, cfg, tokens, positions, kv_pages, attn_fn,
@@ -905,11 +907,9 @@ def embed_step(
     B, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
 
-    def attn_fn(q, k, v, kv, layer):
-        out = att.prefill_attention_dispatch(
-            q, k, v, seq_lens, cfg.sliding_window or 0
-        )
-        return out, kv
+    def attn_fn(q, k, v, kv, layer, kind=None):
+        window = att.layer_view(cfg, None, None, layer, kind).window
+        return att.prefill_attention_dispatch(q, k, v, seq_lens, window), kv
 
     hidden, _ = transformer(params, cfg, tokens, positions, kv_pages, attn_fn)
     valid = (
@@ -991,7 +991,12 @@ def _update_lanes(
         limit_lens.at[slots].set(rows["limit"], mode="drop"),
         active.at[slots].set(rows["active"], mode="drop"),
         stop_ids.at[slots].set(rows["stop"], mode="drop"),
-        page_table.at[slots].set(rows["pages"], mode="drop"),
+        # [B, P], or a two-kind cache's [2, B, P]: a lane's row in both
+        (
+            page_table.at[slots].set(rows["pages"], mode="drop")
+            if page_table.ndim == 2
+            else page_table.at[:, slots].set(rows["pages"], mode="drop")
+        ),
         temp.at[slots].set(rows["temp"], mode="drop"),
         top_p.at[slots].set(rows["top_p"], mode="drop"),
         top_k.at[slots].set(rows["top_k"], mode="drop"),

@@ -207,9 +207,13 @@ def assemble_params(
         lambda i: get(f"{pre}layers.{i}.post_attention_layernorm.weight"),
     )
 
-    if cfg.is_mla:
-        # mlp.gate is the router over every published expert; this process
-        # loads the experts it holds; the shared experts are one SwiGLU
+    if cfg.is_mla or (
+        cfg.is_moe and f"{pre}layers.0.mlp.gate.weight" in raw
+    ):
+        # mistral4, mellum: mlp.gate is the router over every published
+        # expert, the experts sit under mlp.experts.N.{gate,up,down}_proj;
+        # this process loads the experts it holds; the shared experts
+        # (where there are any) are one SwiGLU
         lo = cfg.local_expert_offset
         layers["router"] = stack(
             "layers/router",

@@ -120,7 +120,9 @@ def _decode_kernel_v2(
         o_ref[0] = (acc_scr[:] / safe).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "group", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("window", "group", "interpret", "name_suffix")
+)
 def paged_decode_attention_v2(
     q: jax.Array,  # [B, Hq, D]
     kv_pages: jax.Array,  # [L, 2, num_pages, page, Hkv, D]
@@ -130,6 +132,7 @@ def paged_decode_attention_v2(
     window: int = 0,
     group: int = 4,  # pages per grid step
     interpret: bool = False,
+    name_suffix: str = "",  # a two-kind trunk's window layers: "_window"
 ) -> jax.Array:
     """Group-fetch paged decode attention (see _decode_kernel_v2).  When
     the table width doesn't divide by ``group``, the group degrades to the
@@ -171,6 +174,11 @@ def paged_decode_attention_v2(
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        # a one-kind trunk's launch keeps the name it always had
+        **(
+            {"name": "paged_decode_attention" + name_suffix}
+            if name_suffix else {}
+        ),
     )(lyr, pt, lens, *([kv_pages] * G), q)
 
 

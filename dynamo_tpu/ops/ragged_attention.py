@@ -695,7 +695,7 @@ def _work_list_kernel(
 
 def _packed_work_list_attention(
     q, kv_pages, page_table, base, seg_off, q_lens, *, s_max, layer, window,
-    interpret,
+    interpret, name_suffix="",
 ):
     """The packed launch over a dense pool that already holds the
     dispatch's rows (see the section comment): ``[Np, Hq, D]``."""
@@ -742,7 +742,7 @@ def _packed_work_list_attention(
             vmem_limit_bytes=VMEM_CAP_BYTES,
         ),
         interpret=interpret,
-        name="packed_ragged_attention",
+        name="packed_ragged_attention" + name_suffix,
     )(
         jnp.clip(jnp.asarray(layer, jnp.int32), 0, L - 1).reshape(1),
         jnp.clip(i32(page_table), 0, num_pages - 1),
@@ -797,7 +797,8 @@ def packed_shape_fits(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("s_max", "window", "group", "interpret")
+    jax.jit,
+    static_argnames=("s_max", "window", "group", "interpret", "name_suffix"),
 )
 def packed_ragged_attention(
     q: jax.Array,  # [Np, Hq, D] packed queries (lane's row i at base + i)
@@ -814,6 +815,7 @@ def packed_ragged_attention(
     group: int = 4,
     interpret: bool = False,
     kv_scales: jax.Array | None = None,  # [L, 2, num_pages, page] int8 pool
+    name_suffix: str = "",  # a two-kind trunk's window layers: "_window"
 ) -> jax.Array:
     """Packed-layout ragged paged attention (see the section comments):
     one flat ``[Np]`` token axis, per-lane segment offsets.  The pool
@@ -831,6 +833,7 @@ def packed_ragged_attention(
         return _packed_work_list_attention(
             q, kv_pages, page_table, base, seg_off, q_lens, s_max=s_max,
             layer=layer, window=window, interpret=interpret,
+            name_suffix=name_suffix,
         )
     Np, Hq, D = q.shape
     L, _, num_pages, page, Hkv, _ = kv_pages.shape
@@ -910,7 +913,7 @@ def packed_ragged_attention(
             ),
         ),
         interpret=interpret,
-        name="packed_ragged_attention",
+        name="packed_ragged_attention" + name_suffix,
     )(
         lyr, pt, base.astype(jnp.int32), seg_off.astype(jnp.int32),
         q_lens.astype(jnp.int32), *([kv_pages] * G), *scale_ops, q, k, v,

@@ -314,6 +314,10 @@ class EngineMetrics:
         )
         if max_slots:
             self.slots.set(max_slots)
+        # a two-kind cache's families (observe_kv_kinds), minted at the
+        # first observation, and the released pages already counted
+        self._kv_kinds: Optional[Tuple[Gauge, Gauge, Counter]] = None
+        self._window_released = 0
 
     # -- update points (cheap; called per tick / per commit, not per token)
 
@@ -343,6 +347,49 @@ class EngineMetrics:
         self.kv_util.set(used / total if total else 0.0)
         if bytes_per_token is not None:
             self.kv_bytes_per_token.set(bytes_per_token)
+
+    def observe_kv_kinds(
+        self, pools: Dict[str, Any], resident_tokens: int, released: int
+    ) -> None:
+        """A two-kind cache's pools (``{"full": ..., "window": ...}``
+        allocators), the tokens of context it keeps, and the window pages
+        it has let go behind the window.  The families exist only where an
+        engine serves such a cache (minted at the first observation).
+        ``dynamo_engine_kv_pages_used`` itself stays the unlabelled family
+        it was (the pool a prefix match walks: the full pool), since one
+        family cannot carry samples with and without a label."""
+        if self._kv_kinds is None:
+            reg = self.registry
+            self._kv_kinds = (
+                reg.gauge(
+                    "dynamo_engine_kv_kind_pages",
+                    "KV pages of a two-kind cache by pool (kind: full | "
+                    "window) and state (used: referenced by a running "
+                    "sequence; resident: used or reusable)",
+                    ["kind", "state"],
+                ),
+                reg.gauge(
+                    "dynamo_engine_kv_resident_context_tokens",
+                    "Tokens of context the cache keeps: running sequences' "
+                    "lengths plus the reusable blocks of the pool a prefix "
+                    "match walks",
+                ),
+                reg.counter(
+                    "dynamo_engine_kv_window_pages_released",
+                    "Window-pool pages let go because they fell behind the "
+                    "window",
+                ),
+            )
+        pages, tokens, released_total = self._kv_kinds
+        for kind, alloc in pools.items():
+            pages.labels(kind, "used").set(alloc.used_pages)
+            pages.labels(kind, "resident").set(
+                getattr(alloc, "resident_pages", alloc.used_pages)
+            )
+        tokens.set(resident_tokens)
+        if released > self._window_released:
+            released_total.inc(released - self._window_released)
+            self._window_released = released
 
     def observe_executable_shapes(self, n: int) -> None:
         self.executable_shapes.set(n)

@@ -109,9 +109,11 @@ def test_parser_flags():
     p = build_parser()
     a = p.parse_args(
         ["run", "in=http", "out=jax", "--model-path", "/m", "--tp", "4",
-         "--page-size", "32", "--num-pages", "1024"]
+         "--page-size", "32", "--num-pages", "1024",
+         "--num-window-pages", "256"]
     )
     assert a.tp == 4 and a.page_size == 32 and a.num_pages == 1024
+    assert a.num_window_pages == 256
 
 
 def test_llmctl_list_and_remove(run, capsys, model_dir):
