@@ -932,7 +932,8 @@ def child_kernels(args) -> None:
     )
     from dynamo_tpu.ops.paged_attention import paged_decode_attention_v2
     from dynamo_tpu.ops.ragged_attention import (
-        packed_ragged_attention, packed_ragged_attention_xla,
+        decode_work_list_attention, packed_ragged_attention,
+        packed_ragged_attention_xla,
     )
 
     interp = args.rehearse
@@ -1038,6 +1039,16 @@ def child_kernels(args) -> None:
             record("packed_ragged_attention (work list)",
                    f"Np={Np} s_max={s_max} q={lens[-1]} window={window}",
                    cfg.dtype, got, ref, valid=lane < B)
+    # the same kernel as the fused steps' decode launch: one item a lane
+    wq = rnd(15, (B, wHq, wD))
+    wlens = rs.randint(1, P * page, (B,)).astype(np.int32)
+    for window in (0, P * page // 2):
+        got = decode_work_list_attention(
+            wq, wpool, wtable, wlens, 1, window, interpret=interp)
+        ref = att.paged_decode_attention(
+            wq, index_kv_layer(wpool, 1), wtable, wlens, window)
+        record("paged_decode_attention (work list)",
+               f"B={B} P={P} window={window}", cfg.dtype, got, ref)
 
     q = rnd(7, (B, Hq, D))
     Pd = min(16, P)

@@ -113,11 +113,15 @@ HOT_PATH_MANIFEST: Dict[str, List[str]] = {
     # the unified mixed prefill+decode ragged kernels over the packed
     # token axis: the ONE attention call of step.packed_unified_step,
     # dispatched every tick under mixed batching (the *_xla references
-    # are the same entry point's CPU path)
+    # are the same entry point's CPU path), and the work-list kernel's
+    # other entry, the decode launch of the fused steps over a dense pool
     "dynamo_tpu/ops/ragged_attention.py": [
         "ragged_paged_attention_xla",
         "packed_ragged_attention*",
         "_packed_kernel",
+        "decode_work_list_attention",
+        "_work_list_launch",
+        "_work_list_kernel",
     ],
     # the latent pool's kernels (MLA): the attention call of the packed
     # step and of the fused decode steps over a kv_cache.LatentKV

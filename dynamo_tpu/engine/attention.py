@@ -10,10 +10,12 @@ inactive batch slots scatter their writes there, so dead lanes never corrupt
 live state and every step runs with fully static shapes (XLA requirement).
 
 These are the XLA-composed implementations (gather + einsum; XLA fuses the
-mask/softmax chain).  On TPU the decode hot loop routes through the Pallas
-kernel in dynamo_tpu.ops.paged_attention instead (see
-``decode_attention_dispatch``): the XLA gather materializes
-[B, P*page, Hkv, D] per step, the kernel streams pages HBM->VMEM once.
+mask/softmax chain).  On TPU the decode hot loop routes through a Pallas
+kernel instead (``decode_attention_dispatch``, chosen by ``decode_backend``:
+the work list of dynamo_tpu.ops.ragged_attention over a dense pool of
+128-lane heads, the grid of dynamo_tpu.ops.paged_attention over narrower
+ones): the XLA gather materializes [B, P*page, Hkv, D] per step, a kernel
+streams pages HBM->VMEM once.
 """
 
 from __future__ import annotations
@@ -213,6 +215,38 @@ def _pallas_decode_enabled(page_size: int) -> bool:
     return page_size >= 8 and _on_tpu()
 
 
+def decode_backend(kv_pages, Hq: int, D: int, dtype) -> str:
+    """What attends a decode launch (the fused steps after a dispatch's
+    first, ``decode_step``, ``decode_block``) over this pool for ``Hq``
+    query heads of ``D`` in ``dtype``, decided at trace time under the
+    context mesh; the tick's ``dispatch`` annotation reads it here too.
+    ``"latent"`` and ``"work_list"``: the pool's packed launch walks a work
+    list (:func:`_packed_backend`, the one statement of that rule), and the
+    decode launch is that kernel with one item a lane, so the width of the
+    page table costs it nothing.  ``"grid"``: the page-group grid of
+    ``ops.paged_attention`` (narrow heads).  ``"xla"``: the gather (the
+    CPU, an int8 pool, narrow heads over a dense pool whose type is not the
+    query's, heads that do not shard)."""
+    data = kv_data(kv_pages)
+    page, Hkv = data.shape[3], data.shape[4]
+    packed = _packed_backend(kv_pages, Hq, Hkv, D)
+    if packed in ("latent", "work_list"):
+        return packed
+    if (
+        not kv_is_latent(kv_pages)
+        and not kv_is_quantized(kv_pages)
+        # the grid kernel computes directly on the pool tiles: a dense pool
+        # dtype that differs from the query/compute dtype (explicit
+        # --kv-dtype float32 under a bf16 model) takes the XLA gather,
+        # whose dequant/cast normalizes operands
+        and data.dtype == dtype
+        and _heads_shard(Hq, Hkv)
+        and _pallas_decode_enabled(page)
+    ):
+        return "grid"
+    return "xla"
+
+
 @hot_path
 def decode_attention_dispatch(
     q: jax.Array,  # [B, Hq, D]
@@ -223,34 +257,38 @@ def decode_attention_dispatch(
     window: int = 0,  # sliding-window width; 0 = full attention
     name_suffix: str = "",  # LayerView.suffix: a window layer's launch
 ) -> jax.Array:
-    """Decode attention: Pallas page-streaming kernel on TPU, XLA gather
-    elsewhere.  Resolved at trace time (static), so each compiled executable
-    embeds exactly one backend.  Quantized pools take the XLA gather on
-    this CLASSIC path only (penalized/multimodal fallback lanes) -- the
-    serving hot path under ``--kv-dtype int8`` is the unified ragged
-    dispatch, whose Pallas kernels fuse the dequant.  A latent pool has
-    kernels of its own (:func:`_latent_decode`)."""
-    if kv_is_latent(kv_pages):
-        return _latent_decode(q, kv_pages, page_table, kv_lens, layer)
-    if (
-        not kv_is_quantized(kv_pages)
-        # the classic Pallas kernels compute directly on the pool tiles:
-        # a dense pool dtype that differs from the query/compute dtype
-        # (explicit --kv-dtype float32 under a bf16 model) takes the XLA
-        # gather, whose dequant/cast normalizes operands
-        and kv_pages.dtype == q.dtype
-        and _heads_shard(q.shape[1], kv_pages.shape[4])
-        and _pallas_decode_enabled(kv_pages.shape[3])
-    ):
-        from ..ops.paged_attention import paged_decode_attention_v2
+    """Decode attention over a pool that already holds the new token's row:
+    a Pallas kernel on TPU, the XLA gather elsewhere (:func:`decode_backend`).
+    Resolved at trace time (static), so each compiled executable embeds
+    exactly one backend.  Quantized pools take the XLA gather on this path
+    (the fused steps and the classic penalized/multimodal fallback lanes);
+    under ``--kv-dtype int8`` the packed dispatch's Pallas kernels fuse the
+    dequant."""
+    backend = decode_backend(kv_pages, q.shape[1], q.shape[2], q.dtype)
+    if backend == "latent":
+        from ..ops.latent_attention import latent_decode_attention
 
-        # group-of-8 fetches: grid-step overhead dominates per-page v1 at
-        # serving shapes (v2 internally falls back to v1 for table widths
-        # the group doesn't divide)
+        return latent_decode_attention(q, kv_pages, page_table, kv_lens, layer)
+    if backend in ("work_list", "grid"):
+        if backend == "work_list":
+            from ..ops.ragged_attention import (
+                decode_work_list_attention as kernel,
+            )
+
+            extra = {}
+        else:
+            from ..ops.paged_attention import (
+                paged_decode_attention_v2 as kernel,
+            )
+
+            # group-of-8 fetches: grid-step overhead dominates per-page v1
+            # at serving shapes (v2 internally falls back to v1 for table
+            # widths the group doesn't divide)
+            extra = {"group": 8}
         return _per_shard(
-            lambda q, kv, pt, lens, layer: paged_decode_attention_v2(
-                q, kv, pt, lens, layer, window, group=8,
-                name_suffix=name_suffix,
+            lambda q, kv, pt, lens, layer: kernel(
+                q, kv, pt, lens, layer, window, name_suffix=name_suffix,
+                **extra,
             ),
             (q, kv_pages, page_table, kv_lens, layer),
             (P(None, "tp", None), _POOL_SPEC, P(), P(), P()),
@@ -327,19 +365,6 @@ def latent_packed_attention_dispatch(
     return out, written
 
 
-def _latent_decode(q, kv_pages, page_table, kv_lens, layer):
-    """Decode attention over a latent pool (the row of the new token is
-    already written): the kernel on the chip, the XLA gather elsewhere."""
-    if latent_kernels_enabled(kv_pages.shape[3]):
-        from ..ops.latent_attention import latent_decode_attention
-
-        return latent_decode_attention(
-            q, kv_pages, page_table, kv_lens, layer
-        )
-    layer_kv = index_kv_layer(kv_pages, layer)
-    return paged_decode_attention(q, layer_kv, page_table, kv_lens, 0)
-
-
 def _pallas_ragged_enabled(page_size: int, Hq: int, Hkv: int, D: int) -> bool:
     """Trace-time choice of the packed mixed-batch attention backend.
 
@@ -380,7 +405,8 @@ class PackedLaunch(NamedTuple):
     """What an engine has to know of its packed launch's attention."""
 
     # a work-list kernel has no step for a page group: the width of the
-    # page table it is handed costs it nothing
+    # page table it is handed costs it nothing, and the decode launch of the
+    # fused steps over the same pool is the same kernel (decode_backend)
     walks_work_list: bool
     # ``fits(Np, s_max)``: whether the launch can hold that packed shape
     fits: Callable[[int, int], bool]

@@ -993,8 +993,8 @@ class JaxEngine:
         self._packed_shapes = PackedShapeBudget(shape_budget)
         # what the packed launch's kernel can hold at this model's widths
         # (checked again on every triple the budget resolves), and whether
-        # it walks a work list: a packed step that is one step then takes
-        # the page table at its full width (_dispatch_unified)
+        # it walks a work list: every unified dispatch then takes the page
+        # table at its full width (_dispatch_unified)
         launch = self._packed_launch()
         self._packed_full_table = launch.walks_work_list
         self._packed_fits = launch.fits
@@ -4114,8 +4114,12 @@ class JaxEngine:
     def _live_page_bucket(self) -> int:
         """Power-of-two page-table width covering the longest slotted
         lane's allocation -- the ONE bucketing rule shared by the
-        decode-block and verify dispatches, so the two paths can never
-        compile against different table widths.  The floor of 64 pages
+        decode-block and verify dispatches (the classic paths) and by the
+        unified dispatches of a pool whose kernels walk a grid over the
+        table's width (narrow heads, int8: ``_packed_full_table`` false), so
+        no two paths can compile against different table widths.  Where the
+        kernels walk a work list the unified dispatches take the table
+        whole and never ask.  The floor of 64 pages
         bounds the executable count: every width is one more executable
         for each packed shape and each fused K, compiled the first time a
         quiet moment leaves only short lanes in the batch (with a floor of
@@ -4333,13 +4337,15 @@ class JaxEngine:
             s_spec = 1 + (pow2_bucket(max_d) if max_d else 0)
         self._sync_device_state()
         d = self._dev
-        # a work-list kernel has no step for a page group, so a dispatch of
-        # one step (every mixed step, K = 1 of the ramp) takes the whole
-        # table: its width is then no axis of those executables.  The fused
-        # steps keep the bucket: the decode kernel walks the table's width
+        # a work-list kernel has no step for a page group, and where the
+        # packed launch walks one the fused steps' decode launch is the same
+        # kernel (attention.decode_backend): every dispatch takes the whole
+        # table, whose width is then no axis of any unified executable.  A
+        # pool that keeps the grid kernels (narrow heads, int8) keeps the
+        # bucket: they pay a step for every page group of the table's width
         Pb = (
             sched.max_pages
-            if self._packed_full_table and num_steps == 1
+            if self._packed_full_table
             else self._live_page_bucket()
         )
         # decode-capable lanes contribute one fresh row each; the count
@@ -4446,6 +4452,7 @@ class JaxEngine:
                 ),
                 "k": num_steps,
                 "np": Np,
+                "pt": Pb,
             }
             # the launch as the dense pools' kernel walks it: its work
             # items, and how many of them take the small tile
@@ -4454,15 +4461,22 @@ class JaxEngine:
             dispatch_meta["items"], dispatch_meta["small"] = (
                 packed_item_counts(q_host[live], s_max)
             )
-            if self.model_cfg.is_mla:
-                # which latent path the dispatch takes (read where the
-                # step's trace reads it)
-                from . import attention as att
+            # which kernels the dispatch takes (read where the step's trace
+            # reads them): the latent path of its packed launch, and what
+            # attends the fused steps after the first
+            from . import attention as att
 
+            m = self.model_cfg
+            if m.is_mla or num_steps > 1:
                 with self.mesh_scope():
-                    dispatch_meta["latent"] = att.latent_packed_path(
-                        self.kv.pages
-                    )
+                    if m.is_mla:
+                        dispatch_meta["latent"] = att.latent_packed_path(
+                            self.kv.pages
+                        )
+                    if num_steps > 1:
+                        dispatch_meta["decode"] = att.decode_backend(
+                            self.kv.pages, m.num_heads, m.head_dim, m.dtype
+                        )
         operands = (
             self.params,
             self.model_cfg,

@@ -1,4 +1,12 @@
-"""Pallas paged-attention decode kernel (TPU).
+"""Pallas paged-attention decode kernel (TPU): a grid over the page table.
+
+Which pools still reach it (``engine.attention.decode_backend``): a dense
+pool whose heads are narrower than a 128-lane tile (TinyLlama's 64), of the
+query's type.  A dense pool of 128-lane heads takes the work-list kernel
+(``ragged_attention.decode_work_list_attention``: one item a lane over its
+live key blocks, where this grid pays a step for every page group of the
+table's width on every lane), a latent pool its own, an int8 pool the XLA
+gather.
 
 Replaces the XLA gather path (engine/attention.py paged_decode_attention,
 the classic paged-attention "v1" shape) on the decode hot loop.  The XLA

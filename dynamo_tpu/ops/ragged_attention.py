@@ -34,6 +34,10 @@ What is here:
   base[b]``) as independently pipelined block operands, and the final step
   attends the dispatch's own fresh K/V causally at token granularity.
 
+* :func:`decode_work_list_attention` -- the decode launch of the fused
+  steps over a dense pool of 128-lane heads: the work-list kernel at the
+  ``(lanes, 1)`` tile with a fixed list, one item a lane.
+
 ``interpret=True`` runs a kernel through the Pallas interpreter
 (CPU-testable); ``engine.attention.packed_ragged_attention_dispatch``
 resolves kernel or reference at trace time like every other dispatch gate.
@@ -693,26 +697,19 @@ def _work_list_kernel(
             attend(wide, wide_t, *args)
 
 
-def _packed_work_list_attention(
-    q, kv_pages, page_table, base, seg_off, q_lens, *, s_max, layer, window,
-    interpret, name_suffix="",
+def _work_list_launch(
+    q, kv_pages, page_table, layer, lane, row0, pos0, rows, *, s_max, window,
+    interpret, name,
 ):
-    """The packed launch over a dense pool that already holds the
-    dispatch's rows (see the section comment): ``[Np, Hq, D]``."""
-    from .latent_attention import packed_work_list
-
+    """One launch of :func:`_work_list_kernel` over the items ``(lane, row0,
+    pos0, rows)``, each ``[W]`` int32, at the tiles of ``s_max``: ``[Np, Hq,
+    D]``, zeros in the rows no item owns."""
     Np, Hq, D = q.shape
     L, _, num_pages, page, Hkv, _ = kv_pages.shape
-    qb, tiles = _work_list_tiles(s_max, q.dtype)
-    if s_max % qb:
-        raise ValueError(f"s_max {s_max} is not a multiple of {qb}")
+    _, tiles = _work_list_tiles(s_max, q.dtype)
     n_rep = Hq // Hkv
     rows_t = tiles[-1][1]
     KB = max(page, _WL_KEY_BLOCK // page * page)
-    i32 = lambda x: x.astype(jnp.int32)  # noqa: E731
-    lane, row0, pos0, rows = packed_work_list(
-        i32(base), i32(seg_off), i32(q_lens), Np, qb
-    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(lane.shape[0],),
@@ -742,12 +739,64 @@ def _packed_work_list_attention(
             vmem_limit_bytes=VMEM_CAP_BYTES,
         ),
         interpret=interpret,
-        name="packed_ragged_attention" + name_suffix,
+        name=name,
     )(
         jnp.clip(jnp.asarray(layer, jnp.int32), 0, L - 1).reshape(1),
-        jnp.clip(i32(page_table), 0, num_pages - 1),
+        jnp.clip(page_table.astype(jnp.int32), 0, num_pages - 1),
         lane, row0, pos0, rows,
         q, kv_pages, jnp.zeros((Np, Hq, D), q.dtype),
+    )
+
+
+def _packed_work_list_attention(
+    q, kv_pages, page_table, base, seg_off, q_lens, *, s_max, layer, window,
+    interpret, name_suffix="",
+):
+    """The packed launch over a dense pool that already holds the
+    dispatch's rows (see the section comment): ``[Np, Hq, D]``."""
+    from .latent_attention import packed_work_list
+
+    qb, _ = _work_list_tiles(s_max, q.dtype)
+    if s_max % qb:
+        raise ValueError(f"s_max {s_max} is not a multiple of {qb}")
+    i32 = lambda x: x.astype(jnp.int32)  # noqa: E731
+    items = packed_work_list(
+        i32(base), i32(seg_off), i32(q_lens), q.shape[0], qb
+    )
+    return _work_list_launch(
+        q, kv_pages, page_table, layer, *items, s_max=s_max, window=window,
+        interpret=interpret, name="packed_ragged_attention" + name_suffix,
+    )
+
+
+@functools.partial(
+    jax.jit, static_argnames=("window", "interpret", "name_suffix")
+)
+def decode_work_list_attention(
+    q: jax.Array,  # [B, Hq, D] one new query token a lane
+    kv_pages: jax.Array,  # [L, 2, num_pages, page, Hkv, D], that token in it
+    page_table: jax.Array,  # [B, P] int32 page ids
+    kv_lens: jax.Array,  # [B] tokens in the cache, the new one included
+    layer: jax.Array | int = 0,
+    window: int = 0,
+    interpret: bool = False,
+    name_suffix: str = "",  # a two-kind trunk's window layers: "_window"
+) -> jax.Array:
+    """The decode launch of the fused steps over a dense pool of 128-lane
+    heads (:func:`_takes_work_list`): the work-list kernel at the ``(lanes,
+    1)`` tile with a fixed list, one item a lane that holds a token, from
+    its window's first key block to the block of its own position.  No step
+    for a page group of the table's width (``paged_attention``'s grid), so
+    the table may be as wide as the scheduler's; a lane with ``kv_lens`` 0
+    has no item and its row stays zero.  The launch the ``(lanes, 1)`` packed
+    step makes, under the decode kernel's name: a device trace tells the
+    fused steps' launches from the packed ones by it."""
+    lane = jnp.arange(q.shape[0], dtype=jnp.int32)
+    lens = kv_lens.astype(jnp.int32)
+    return _work_list_launch(
+        q, kv_pages, page_table, layer, lane, lane, lens - 1,
+        (lens > 0).astype(jnp.int32), s_max=1, window=window,
+        interpret=interpret, name="paged_decode_attention" + name_suffix,
     )
 
 

@@ -237,6 +237,68 @@ def test_paged_decode_attention_v2_compiles(chip, widths):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def decode_layer_text(chip, monkeypatch, *, lanes, Hq, Hkv, window, table,
+                      suffix="", pages=6144, pool_dtype=jnp.bfloat16):
+    """A fused decode step's layer as ``step._decode_once`` runs it over a
+    dense pool of 128-wide heads (the new token's row scattered, then the
+    dispatch), compiled for the described chip with the pool donated: its
+    HLO text."""
+    from dynamo_tpu.engine import attention as att
+
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    pool = chip((4, 2, pages, PAGE, Hkv, 128), pool_dtype)
+    vec = chip((lanes,), jnp.int32)
+
+    def layer(pool, q, k, v, pt, pos):
+        pool = att.write_decode_kv(pool, k, v, pt, pos, 3)
+        out = att.decode_attention_dispatch(
+            q, pool, pt, pos + 1, 3, window, suffix)
+        return out, pool
+
+    assert att.decode_backend(pool, Hq, 128, jnp.bfloat16) == "work_list"
+    return jax.jit(layer, donate_argnums=(0,)).lower(
+        pool, chip((lanes, Hq, 128), jnp.bfloat16),
+        chip((lanes, Hkv, 128), jnp.bfloat16),
+        chip((lanes, Hkv, 128), jnp.bfloat16),
+        chip((lanes, table), jnp.int32), vec,
+    ).compile().as_text()
+
+
+def assert_one_decode_launch(text, pool_dims, suffix=""):
+    """One decode launch under the name the ledger's ``breakdown`` and the
+    count of forward passes know it by, no packed launch beside it (the
+    packed rooflines' readers find theirs by that name), and the pool
+    neither copied nor transposed around it."""
+    assert len(re.findall(
+        rf"%paged_decode_attention{suffix}[.\d]* = ", text)) == 1
+    assert "%packed_ragged_attention" not in text
+    assert len(re.findall(r"%\w*attention\w*[.\d]* = ", text)) == 1
+    made = [l for l in text.splitlines()
+            if re.search(rf"= \w+\[{pool_dims}\]\S* (copy|transpose)\(", l)]
+    assert not made, made[:3]
+
+
+@pytest.mark.parametrize("table", [512, 2064])
+@pytest.mark.parametrize(
+    "lanes,window,pool_dtype",
+    [(32, 0, jnp.bfloat16), (16, 4096, jnp.bfloat16), (16, 4096, jnp.float32)],
+    ids=["mixtral", "mistral-7b", "mistral-7b-f32-pool"],
+)
+def test_decode_work_list_compiles(chip, monkeypatch, lanes, window,
+                                   pool_dtype, table):
+    """The fused steps' decode launch over a dense pool at the widths
+    Mixtral-8x7B and Mistral-7B share, at their cells' lanes, at a table of
+    512 pages and at the scheduler's whole 2064: the work-list kernel at the
+    ``(lanes, 1)`` tile with one item a lane.  A float32 pool under a bf16
+    model takes it too (the kernel converts a key block in VMEM, as the
+    packed launch over that pool does)."""
+    text = decode_layer_text(
+        chip, monkeypatch, lanes=lanes, Hq=32, Hkv=8, window=window,
+        table=table, pool_dtype=pool_dtype)
+    assert_one_decode_launch(text, "4,2,6144,16,8,128")
+    assert f"bf16[{lanes},32,128]" in text
+
+
 @pytest.mark.parametrize("widths", [TINYLLAMA, MIXTRAL], ids=["tinyllama", "mixtral"])
 def test_flash_prefill_attention_compiles(chip, widths):
     from dynamo_tpu.ops.flash_prefill import (

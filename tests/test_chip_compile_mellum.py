@@ -7,6 +7,7 @@ and that a trunk of one kind traces to the program it traced to before
 import dataclasses
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +20,9 @@ from dynamo_tpu.engine import step as S
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.engine.kv_cache import KindKV
 from dynamo_tpu.engine.sampling import SamplingParams
-from tests.test_chip_compile import chip, topo  # noqa: F401  (fixtures)
+from tests.test_chip_compile import (  # noqa: F401  (fixtures)
+    assert_one_decode_launch, chip, decode_layer_text, topo,
+)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LANES, PAGE, TABLE = 32, 16, 2064
@@ -66,8 +69,7 @@ def test_mellum_steps_lower_at_published_widths(chip, monkeypatch, Np, s_max, st
     monkeypatch.setattr(att, "_on_tpu", lambda: True)
     cfg, eng = published()
     assert cfg.layer_pattern == ("sliding", "sliding", "sliding", "full")
-    table = TABLE if steps == 1 else 256
-    ops = _operands(chip, cfg, eng, Np, table)
+    ops = _operands(chip, cfg, eng, Np, TABLE)
     if steps == 1:
         fn = jax.jit(
             lambda *a: S._packed_unified_step(*a, s_max=s_max),
@@ -80,11 +82,52 @@ def test_mellum_steps_lower_at_published_widths(chip, monkeypatch, Np, s_max, st
     text = compiled.as_text()
     assert "packed_ragged_attention_window" in text
     assert "packed_ragged_attention." in text or "packed_ragged_attention " in text
+    launches = re.findall(r"%(\w*attention\w*?)[.\d]* = ", text)
     if steps > 1:
-        assert "paged_decode_attention_window" in text
+        # the scan's body is a period, the fused steps' launch of each of
+        # its four layers the work list under the decode kernel's name
+        assert sorted(launches) == sorted(
+            ["packed_ragged_attention_window"] * 3 + ["packed_ragged_attention"]
+            + ["paged_decode_attention_window"] * 3 + ["paged_decode_attention"])
     # weights 10.9 GB and pools 2.8 GB are arguments; what the step makes
     # beside them stays far under a layer's experts (0.79 GB)
     assert compiled.memory_analysis().temp_size_in_bytes < 700 << 20
+
+
+@pytest.mark.parametrize("table", [512, TABLE])
+@pytest.mark.parametrize("window,suffix", [(0, ""), (1024, "_window")],
+                         ids=["full", "window"])
+def test_mellum_decode_launch_compiles(chip, monkeypatch, window, suffix, table):
+    """The fused steps' decode launch at Mellum2's heads (32 over 4) and 32
+    lanes, a full layer's and a window layer's, at a table of 512 pages and
+    at the scheduler's whole 2064: one ``paged_decode_attention[_window]``
+    a layer, no packed launch beside it, the pool not copied."""
+    text = decode_layer_text(
+        chip, monkeypatch, lanes=LANES, Hq=32, Hkv=4, window=window,
+        table=table, suffix=suffix, pages=4096)
+    assert_one_decode_launch(text, "4,2,4096,16,4,128", suffix)
+
+
+def test_fused_step_does_not_grow_with_the_page_table(monkeypatch):
+    """The table's width is no axis of the fused step's program: its jaxpr
+    has as many equations at 512 pages as at 2064 (the grid kernel it
+    replaces took a block operand a page of its group and a grid step a
+    group of the width)."""
+    from tests.test_packed_work_list import _eqns
+
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    cfg, eng = published()
+    spec = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype)  # noqa: E731
+
+    def equations(table):
+        ops = _operands(spec, cfg, eng, 32, table)
+        jaxpr = jax.make_jaxpr(
+            lambda *a: S._packed_unified_multistep(
+                a[0], cfg, *a[1:], s_max=1, num_steps=4)
+        )(ops[0], *ops[2:])
+        return sum(1 for _ in _eqns(jaxpr.jaxpr))
+
+    assert equations(512) == equations(TABLE)
 
 
 def test_one_kind_packed_step_keeps_its_jaxpr():

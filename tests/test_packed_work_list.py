@@ -156,6 +156,126 @@ def _int8_takes_the_grid_kernel():
     np.testing.assert_allclose(got[:total], ref[:total], rtol=2e-5, atol=2e-5)
 
 
+# -- the decode launch of the fused steps: the same kernel, one item a lane ----
+
+
+def _decode_case(lens, *, window=0, P=1024, Hq=4, Hkv=2, seed=0,
+                 dtype=np.float32, pool_dtype=None):
+    """Decode lanes holding ``lens`` tokens (the new one's row already in
+    the pool) under a table ``P`` pages wide, far wider than any lane's
+    pages.  Every entry a lane must not read, past its allocation or wholly
+    behind its window (a released page's stale entry), points at one page
+    of NaN: a fetch of it shows in the result."""
+    rs = np.random.RandomState(seed)
+    B = len(lens)
+    need = [-(-n // PAGE) for n in lens]
+    num_pages = 2 + sum(need)
+    pool = rs.randn(2, 2, num_pages, PAGE, Hkv, D).astype(np.float32)
+    pool[:, :, num_pages - 1] = np.nan
+    pt = np.full((B, P), num_pages - 1, np.int32)
+    nxt = 1
+    for b in range(B):
+        behind = max(lens[b] - window, 0) // PAGE if window else 0
+        pt[b, behind: need[b]] = nxt + np.arange(behind, need[b])
+        nxt += need[b]
+    q = rs.randn(B, Hq, D).astype(np.float32)
+    return (jnp.asarray(q, dtype), jnp.asarray(pool, pool_dtype or dtype),
+            jnp.asarray(pt), jnp.asarray(lens, jnp.int32))
+
+
+def _assert_decode_rows(got, q, pool, pt, lens, layer, window, tol):
+    """``got`` against the XLA gather on the layer's slice; a lane without a
+    token has no item and keeps its zeros."""
+    ref = np.asarray(att.paged_decode_attention(
+        q, jnp.nan_to_num(pool[layer]), pt, lens, window).astype(jnp.float32))
+    got = np.asarray(got.astype(jnp.float32))
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(got[live], ref[live], rtol=tol, atol=tol)
+    assert not got[~live].any()
+
+
+KEY_BLOCK = 512  # ra._WL_KEY_BLOCK at this file's page of 8
+DECODE_CASES = {
+    # no token, one token, exactly a page, exactly a key block, one past it
+    "edges": dict(lens=[0, 1, PAGE, KEY_BLOCK, KEY_BLOCK + 1, 600, 0, 77]),
+    # mistral-7b's window: at it, one past it, well past it, far under it
+    "window_4096": dict(lens=[4096, 4097, 5000, 100], window=4096),
+    # mellum2's: the window's first key in the middle of a page and a block
+    "window_1024": dict(lens=[1024, 1025, 3003, 7, 0], window=1024),
+    "gqa8": dict(lens=[300, 1, 1029], window=128, Hq=8, Hkv=1),
+    "mha": dict(lens=[520, 9], Hq=2, Hkv=2),
+    "bf16": dict(lens=[0, 1, 513, 2050], window=1024, dtype=jnp.bfloat16),
+    # an explicit float32 pool under a bf16 model: a key block is converted
+    # in VMEM, as the packed launch over that pool does
+    "f32_pool": dict(lens=[1, 513, 700], dtype=jnp.bfloat16,
+                     pool_dtype=jnp.float32),
+}
+
+
+@pytest.mark.parametrize("name", list(DECODE_CASES))
+def test_decode_work_list_matches_the_gather(name):
+    kw = dict(DECODE_CASES[name])
+    window = kw.get("window", 0)
+    q, pool, pt, lens = _decode_case(**kw)
+    got = ra.decode_work_list_attention(
+        q, pool, pt, lens, LAYER, window, interpret=True)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    tol = 3e-2 if q.dtype == jnp.bfloat16 else 2e-5
+    _assert_decode_rows(got, q, pool, pt, lens, LAYER, window, tol)
+
+
+def test_decode_launch_of_a_two_kind_trunk(monkeypatch):
+    """Through ``attention.layer_view`` and the dispatch's gate, as
+    ``step._decode_once`` runs a layer: a window layer of a two-kind trunk
+    reads its own pool by its own table, whose entries behind the window are
+    stale (the pages were released; here they point at NaN), under the name
+    ``paged_decode_attention_window``; a full layer reads every key under
+    ``paged_decode_attention``.  One launch a layer, and it is not the
+    packed one."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from dynamo_tpu.engine.config import ModelConfig
+    from dynamo_tpu.engine.kv_cache import KindKV
+
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    cfg = ModelConfig.tiny(
+        num_layers=8, head_dim=D, sliding_window=64,
+        layer_pattern=("sliding", "sliding", "sliding", "full"))
+    lens = [300, 65, 1, 0]
+    q, full, pt_full, kv_lens = _decode_case(lens, P=64, seed=1)
+    _, win, pt_win, _ = _decode_case(lens, window=64, P=64, seed=2)
+    win = jnp.concatenate([win] * 3)  # six window layers, two full
+    kv, table = KindKV(full, win), jnp.stack([pt_full, pt_win])
+    k_new = jnp.ones((len(lens), 2, D), q.dtype)
+
+    def layer(kind, index):
+        def run(q, kv, table, kv_lens):
+            lv = att.layer_view(cfg, kv, table, index, kind)
+            pos = jnp.maximum(kv_lens - 1, 0)
+            pool = att.write_decode_kv(
+                lv.kv, k_new, k_new, lv.table, pos, lv.layer)
+            out = att.decode_attention_dispatch(
+                q, pool, lv.table, kv_lens, lv.layer, lv.window, lv.suffix)
+            return out, pool, lv.layer
+
+        return run
+
+    # layer 6 is the second period's last window layer: index 5 of its pool
+    for kind, index, at, window, name in (
+        ("sliding", 6, 5, 64, "paged_decode_attention_window"),
+        ("full", 7, 1, 0, "paged_decode_attention"),
+    ):
+        run = layer(kind, index)
+        assert _pallas_calls(
+            jax.make_jaxpr(run)(q, kv, table, kv_lens).jaxpr) == [(name, 1)]
+        with pltpu.force_tpu_interpret_mode():
+            got, pool, idx = run(q, kv, table, kv_lens)
+        assert int(idx) == at
+        _assert_decode_rows(
+            got, q, pool, table[int(kind == "sliding")], kv_lens, at, window,
+            2e-5)
+
+
 def _eqns(jaxpr):
     """Every equation under ``jaxpr``, nested jaxprs included."""
     for eqn in jaxpr.eqns:
@@ -210,13 +330,27 @@ def test_item_counts_follow_the_work_list():
     assert ((rows > 0) & (rows <= ra._WL_SMALL_ROWS)).sum() == 3
 
 
-def test_one_step_dispatches_take_the_whole_page_table(run, monkeypatch):
-    """Where the packed launch walks a work list, a dispatch of one step is
-    handed the page table at its full width (no executable per bucket of
-    it); the fused steps, whose decode kernel walks the table, keep the
-    bucket.  The tokens are the same either way."""
+def test_unified_dispatches_take_the_whole_page_table(run, monkeypatch):
+    """Where the packed launch walks a work list, so does the fused steps'
+    decode launch, and every unified dispatch, of one step or of ``K``, is
+    handed the page table at its full width: no executable per bucket of it.
+    A pool that keeps the grid kernels keeps the bucket.  The tokens are the
+    same either way, and the ``dispatch`` annotation says which it was:
+    ``pt``, and for ``k > 1`` what attends the steps after the first."""
     from dynamo_tpu.engine import attention as att
+    from dynamo_tpu.runtime import profiling
     from tests.test_request_stages import collect, req, tiny_engine
+
+    marks = []
+    mark = profiling._Tick.mark
+
+    def spy_mark(self, phase, **meta):
+        if phase == "dispatch" and "pt" in meta:
+            marks.append(meta)
+        return mark(self, phase, **meta)
+
+    monkeypatch.setattr(profiling._Tick, "mark", spy_mark)
+    monkeypatch.setattr(profiling.profiler, "enabled", True)
 
     def served(work_list):
         monkeypatch.setattr(
@@ -224,6 +358,7 @@ def test_one_step_dispatches_take_the_whole_page_table(run, monkeypatch):
             lambda *a: att.PackedLaunch(work_list, lambda Np, s_max: True))
         engine = tiny_engine(max_seq_len=512, num_pages=300, page_size=4)
         assert engine._packed_full_table is work_list
+        del marks[:]
         widths = {}
         for name in ("packed_unified_step", "packed_unified_multistep"):
             fn = getattr(engine._fns, name)
@@ -241,15 +376,69 @@ def test_one_step_dispatches_take_the_whole_page_table(run, monkeypatch):
             finally:
                 await engine.stop()
 
-        return run(body()), widths, engine.sched.max_pages
+        return run(body()), widths, engine.sched.max_pages, list(marks)
 
-    want, bucketed, full = served(False)
-    got, widths, _ = served(True)
+    want, bucketed, full, grid_marks = served(False)
+    got, widths, _, marks_full = served(True)
     assert got == want
-    assert min(bucketed["packed_unified_step"]) < full
-    assert widths["packed_unified_step"] == {full}
-    fused = "packed_unified_multistep"
-    assert widths[fused] == bucketed[fused] and min(widths[fused]) < full
+    for name in ("packed_unified_step", "packed_unified_multistep"):
+        assert min(bucketed[name]) < full, name
+        assert widths[name] == {full}, name
+    assert {m["pt"] for m in marks_full} == {full}
+    assert grid_marks and min(m["pt"] for m in grid_marks) < full
+    # the stat is read where the step's trace reads it: on the CPU, the gather
+    fused = [m for m in marks_full if m["k"] > 1]
+    assert fused and all(m["decode"] == "xla" for m in fused)
+    assert all("decode" not in m for m in marks_full if m["k"] == 1)
+
+
+DECODE_BACKENDS = {
+    # pool: (dtype, D, quant) under a bf16 model -> what attends a fused step
+    "bf16_d128": "work_list",
+    "f32_d128": "work_list",
+    "bf16_d64": "grid",
+    "f32_d64": "xla",
+    "int8_d128": "xla",
+    "latent": "latent",
+    "cpu": "xla",
+}
+
+
+@pytest.mark.parametrize("name", list(DECODE_BACKENDS))
+def test_decode_backend_follows_the_packed_launch(name, monkeypatch):
+    """The fused steps' half of the rule: a pool whose packed launch walks a
+    work list (``attention.packed_launch``, what the engine takes the whole
+    table on) gets the decode launch that walks one too, and the dispatch
+    traces the kernel ``decode_backend`` names."""
+    from dynamo_tpu.engine.kv_cache import LatentKV
+
+    monkeypatch.setattr(att, "_on_tpu", lambda: name != "cpu")
+    Hq, Hkv, B, P = 4, 2, 2, 4
+    d = 64 if name.endswith("d64") else D
+    pool = jnp.zeros(
+        (2, 2, 9, PAGE, Hkv, d),
+        jnp.float32 if name.startswith("f32") else jnp.bfloat16)
+    if name == "int8_d128":
+        pool = QuantKV(q=pool.astype(jnp.int8),
+                       s=jnp.zeros(pool.shape[:4], jnp.float32))
+    if name == "latent":
+        Hkv, d = 1, 192
+        pool = LatentKV(jnp.zeros((1, 1, 9, PAGE, 1, 2 * d), jnp.bfloat16), 128)
+    backend = att.decode_backend(pool, Hq, d, jnp.bfloat16)
+    assert backend == DECODE_BACKENDS[name]
+    walks = att.packed_launch(pool, Hq, Hkv, d, jnp.bfloat16).walks_work_list
+    assert walks is (backend in ("work_list", "latent"))
+    traced = _pallas_calls(jax.make_jaxpr(
+        lambda q, pool: att.decode_attention_dispatch(
+            q, pool, jnp.zeros((B, P), jnp.int32), jnp.ones((B,), jnp.int32),
+            1)
+    )(jnp.zeros((B, Hq, d), jnp.bfloat16), pool).jaxpr)
+    assert [n for n, _rank in traced] == {
+        "work_list": ["paged_decode_attention"],
+        "grid": [None],  # a one-kind trunk's grid launch carries no name
+        "latent": ["latent_decode_attention"],
+        "xla": [],
+    }[backend]
 
 
 def _pallas_calls(jaxpr):

@@ -451,6 +451,11 @@ def test_tick_phases_land_in_the_profiler_trace(run, registry, profiler,
         ctx = [int(v) for v in str(s["ctx"]).split("|")]
         assert len(q) == len(ctx) and all(c >= n >= 1 for n, c in zip(q, ctx))
         assert int(s["k"]) >= 1 and int(s["np"]) >= sum(q)
+        # the page table's width handed to the dispatch (the tiny model's
+        # pool keeps the bucket: at most the scheduler's 16 pages), and for
+        # fused steps what attends them after the first: here the gather
+        assert 1 <= int(s["pt"]) <= 16
+        assert s.get("decode") == ("xla" if int(s["k"]) > 1 else None)
 
 
 def test_no_annotation_with_the_profiler_off(run, registry, profiler,
