@@ -691,15 +691,17 @@ def mintable_packed_shapes(budget: int, lanes: int, page: int) -> list:
     """Every ``(Np, s_max)`` the default engine's mixed dispatch can mint:
     for each window bucket, from one lane alone up to the lane with the
     widest segment first and the rest of the token budget behind it
-    (PackedShapeBudget._np_for is the engine's own packed-axis rule)."""
+    (PackedShapeBudget._np_for is the engine's own packed-axis rule: the
+    window rule, which the smoke's narrow heads take)."""
     from dynamo_tpu.engine.bucketing import PackedShapeBudget, pow2_bucket
 
+    np_for = PackedShapeBudget()._np_for
     shapes = []
     s = 1
     while s <= pow2_bucket(max(budget, page)):
-        low = PackedShapeBudget._np_for(s, 0, s // 2 + 1)
+        low = np_for(s, 0, s // 2 + 1)
         total = min(budget, lanes * s)
-        high = PackedShapeBudget._np_for(s, max(total - 1, 0), total)
+        high = np_for(s, max(total - 1, 0), total)
         n = low
         while n <= high:
             shapes.append((n, s))

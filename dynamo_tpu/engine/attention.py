@@ -410,6 +410,14 @@ class PackedLaunch(NamedTuple):
     walks_work_list: bool
     # ``fits(Np, s_max)``: whether the launch can hold that packed shape
     fits: Callable[[int, int], bool]
+    # the rows a launch may touch past an item's first, as the engine's
+    # shape rule needs it (``bucketing.PackedShapeBudget``).  0: a lane's
+    # whole ``s_max`` window from its offset, so ``Np`` must hold ``off_last
+    # + s_max`` (the grid kernel, the XLA composition, the latent kernels).
+    # ``Q > 0``: the launch moves tiles of at most ``Q`` rows and keeps them
+    # inside the packed axis, so ``total <= Np`` alone binds and ``s_max``
+    # means nothing beyond ``Q`` (the pair pools' work-list kernel)
+    item_rows: int = 0
 
 
 def packed_launch(kv_pages, Hq: int, Hkv: int, D: int, dtype) -> PackedLaunch:
@@ -419,10 +427,15 @@ def packed_launch(kv_pages, Hq: int, Hkv: int, D: int, dtype) -> PackedLaunch:
     ``tp`` from the context mesh, as the step's trace will).  Only the grid
     kernel bounds the shape: it holds the packed operands of its shard of
     heads in VMEM for the whole launch.  The work-list kernels (a tile by
-    DMA) and the XLA composition (no VMEM) hold any."""
+    DMA) and the XLA composition (no VMEM) hold any.  Only the pair pools'
+    work-list kernel frees the shape of the lanes' windows."""
     kind = _packed_backend(kv_pages, Hq, Hkv, D)
+    if kind == "work_list":
+        from ..ops.ragged_attention import _WL_Q_BLOCK
+
+        return PackedLaunch(True, lambda Np, s: True, _WL_Q_BLOCK)
     if kind != "grid":
-        return PackedLaunch(kind in ("latent", "work_list"), lambda Np, s: True)
+        return PackedLaunch(kind == "latent", lambda Np, s: True)
     from ..ops.ragged_attention import packed_shape_fits
 
     # scalars only: the engine keeps ``fits``, and must not keep this pool

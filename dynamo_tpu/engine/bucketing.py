@@ -75,22 +75,39 @@ class PackedShapeBudget:
     already minted (or was merged before) reuses it; a new triple mints
     freely under ``budget``; past the budget, the dispatch is merged up
     into the smallest already-minted triple that dominates it (``s_max' >=
-    s_max``, ``s_spec' >= s_spec``, and ``Np'`` covering the recomputed
-    packed extent) -- more padding, identical math, zero new executables.
+    s_max`` as far as the launch can tell them apart, ``s_spec' >=
+    s_spec``, and ``Np'`` covering the recomputed packed extent: the
+    contract below) -- more padding, identical math, zero new executables.
     Padding spec columns up is legal the same way padding the window is:
     columns past a lane's ``v_lens`` are invalid, sample garbage that the
     commit walk never reads (it is bounded by the dispatched draft
     length), and their KV writes route to the trash page.  Only when
     nothing dominates does a mint evict the least-recently-used triple.
 
-    Correctness contract (the kernel's slice rule): a returned triple
-    always satisfies ``off_last + s_max <= Np`` and ``total <= Np``,
-    where ``off_last`` is the last live lane's segment offset -- padding
-    rows carry lane id B and are inert.
+    Correctness contract: which rule holds is the launch's to say
+    (``attention.PackedLaunch.item_rows``, asked once at construction).
+
+    * ``item_rows == 0``, the window rule: the launch reads a whole
+      ``s_max``-row window from every live lane's offset (the grid kernel
+      of narrow heads and int8 pools, the XLA composition, the latent
+      kernels).  A returned triple always satisfies ``off_last + s_max <=
+      Np`` and ``total <= Np``, where ``off_last`` is the last live lane's
+      segment offset, and ``s_max`` covers the longest segment.
+    * ``item_rows == Q > 0``, window-free: the launch walks work items of
+      at most ``Q`` rows and keeps every tile inside the packed axis (the
+      pair pools' work-list kernel).  Only ``total <= Np`` binds.  Beyond
+      ``Q`` a triple's ``s_max`` means nothing to that kernel -- every tile
+      serves every segment length -- so a dispatch merges into the smallest
+      minted ``Np >= total`` whose query block ``min(s_max, Q)`` is no
+      smaller than its own: a segment cut into smaller blocks than its
+      shape would give it re-reads its keys once a block.
+
+    Padding rows carry lane id B and are inert under both.
     """
 
-    def __init__(self, budget: int = 16) -> None:
+    def __init__(self, budget: int = 16, item_rows: int = 0) -> None:
         self.budget = max(int(budget), 1)
+        self.item_rows = int(item_rows)
         # (Np, s_max, s_spec) -> hits, LRU order (oldest first)
         self._pairs: "collections.OrderedDict[Tuple[int, int, int], int]" = (
             collections.OrderedDict()
@@ -110,9 +127,14 @@ class PackedShapeBudget:
         """The minted triples carrying folded-verify columns (s_spec > 0)."""
         return [t for t in self._pairs if t[2] > 0]
 
-    @staticmethod
-    def _np_for(s_max: int, off_last: int, total: int) -> int:
-        return pow2_bucket(max(total, off_last + s_max, 1))
+    def _np_for(self, s_max: int, off_last: int, total: int) -> int:
+        if self.item_rows:
+            return pow2_bucket(total)
+        return pow2_bucket(max(total, off_last + s_max))
+
+    def _block(self, s_max: int) -> int:
+        """What of ``s_max`` the launch can tell apart."""
+        return min(s_max, self.item_rows) if self.item_rows else s_max
 
     def fit(
         self, s_max: int, off_last: int, total: int, s_spec: int = 0
@@ -134,7 +156,9 @@ class PackedShapeBudget:
         # merge up: smallest minted triple that dominates the dispatch
         best: Optional[Tuple[int, int, int]] = None
         for np_m, s_m, sp_m in self._pairs:
-            if s_m < s_max or np_m < self._np_for(s_m, off_last, total):
+            if self._block(s_m) < self._block(s_max):
+                continue
+            if np_m < self._np_for(s_m, off_last, total):
                 continue
             if sp_m < s_spec or (s_spec == 0 and sp_m > 0):
                 continue

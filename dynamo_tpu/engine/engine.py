@@ -990,14 +990,15 @@ class JaxEngine:
                     "ignoring malformed DYN_PACKED_SHAPE_BUDGET=%r",
                     env_shapes,
                 )
-        self._packed_shapes = PackedShapeBudget(shape_budget)
         # what the packed launch's kernel can hold at this model's widths
-        # (checked again on every triple the budget resolves), and whether
-        # it walks a work list: every unified dispatch then takes the page
-        # table at its full width (_dispatch_unified)
+        # (checked again on every triple the budget resolves), whether it
+        # walks a work list: every unified dispatch then takes the page
+        # table at its full width (_dispatch_unified), and which rows it
+        # may touch: the shape budget's rule (window or window-free)
         launch = self._packed_launch()
         self._packed_full_table = launch.walks_work_list
         self._packed_fits = launch.fits
+        self._packed_shapes = PackedShapeBudget(shape_budget, launch.item_rows)
         # queue-side prefetch: window resolved here, walks issued by the
         # tick loop from queue position (_drive_prefetch), finished or
         # cancelled per request
@@ -4375,8 +4376,12 @@ class JaxEngine:
         # fully-packed layout (ISSUE 10): ONE flat token axis sized
         # pow2(real fresh tokens), so the trunk never pays for padding
         # every lane to the longest chunk.  Segments pack contiguously
-        # in slot order; the packed-axis pad also guarantees every live
-        # lane's static s_max window fits (the grid kernel's slice rule).
+        # in slot order.  Where the launch reads a lane's whole static
+        # s_max window from its offset (the grid kernel, the XLA
+        # composition, the latent kernels) the packed-axis pad also covers
+        # the last live lane's window; the pair pools' work-list kernel
+        # keeps its tiles inside the axis, and there the step runs the
+        # rows it has (PackedShapeBudget's contract says which rule holds)
         q_host = np.where(
             dec_cap, 1, np.where(v_host > 0, v_host, p_lens)
         ).astype(np.int32)
@@ -4396,7 +4401,8 @@ class JaxEngine:
         # reuse or merge up into an already-minted triple instead of
         # compiling a fresh executable for every arrival pattern
         # (ISSUE 13 satellite, verify columns included since ISSUE
-        # 15; the budget keeps off_last + s_max <= Np)
+        # 15; the budget keeps total <= Np, and off_last + s_max <= Np
+        # for a launch that reads windows)
         Np, s_max, s_spec = self._packed_shapes.fit(
             s_nat, off_last, total, s_spec
         )

@@ -431,7 +431,9 @@ def _packed_unified_step(
     # lane not speculating this dispatch)
     rng: jax.Array,
     sampling: SamplingParams,
-    s_max: int,  # static per-lane window capacity (pow2 of max segment)
+    s_max: int,  # static per-lane window capacity: pow2 of the longest
+    # segment where the launch reads windows, maybe less where it walks
+    # items (bucketing.PackedShapeBudget's contract); attention alone reads it
     s_spec: int = 0,  # static folded-verify column width (0 = spec-free
     # dispatch: the program is exactly the pre-fold one, no spec sampler
     # and no extra rng split, so spec-free serving compiles and runs the

@@ -465,10 +465,16 @@ def _packed_kernel(
 # lane riding a chunk, every lane of a fused dispatch's first step) takes a
 # small tile.
 #
-# Output rows past an item's own (the tail of its tile) are written as zeros
-# and may overlap the next segments; items run in ascending row order and
-# each waits for its output copy, so the owner's write lands last.  Rows no
-# item covers keep the zeros the output buffer is created with.
+# A tile never leaves the packed axis: one that would overhang ``Np`` (a
+# decode row in the last packed rows, the tail block of a chunk that fills
+# the axis) starts ``shift`` rows early, at ``Np - copy``, and the item's
+# rows lie ``shift`` into it.  So nothing but ``total <= Np`` binds the
+# packed shape (``engine.attention.PackedLaunch.item_rows``).  An item
+# writes its own rows into what its tile's span of the output holds, read
+# first: items run in ascending row order and each waits for its output
+# copy, so the rows before its own are what earlier items wrote, the rows
+# after it the zeros the output buffer is created with, which the rows no
+# item covers keep.
 
 # query rows (tokens) of one work item, and the rows at or under which an
 # item takes the small tile
@@ -549,7 +555,7 @@ def _work_list_kernel(
     w = pl.program_id(0)
     rows = w_rows[w]
     _, _, KB, Hkv, D = kbuf.shape
-    Hq = q_v.shape[1]
+    Np, Hq = q_hbm.shape[0], q_v.shape[1]
     n_rep = Hq // Hkv
     page = kv_hbm.shape[3]
     P = pt_ref.shape[1]
@@ -597,13 +603,28 @@ def _work_list_kernel(
         jax.lax.fori_loop(lo, hi, done, 0)
 
     def attend(copy, nrow, lane, row0, pos0):
-        """Online softmax of an item's first ``nrow`` tokens (``copy`` of
-        them moved) over the key blocks they can see."""
+        """Online softmax of an item's ``rows`` tokens over the key blocks
+        they can see, on a tile of ``nrow`` rows of which ``copy`` move."""
         M = n_rep * nrow
+        # the tile's first row and its position: the item's, or ``shift``
+        # rows earlier where the tile would overhang the axis (a one-row
+        # tile never does, and is traced as it always was)
+        clamps = copy > 1
+        start, pos_t = row0, pos0
+        if clamps:
+            start = jnp.minimum(row0, Np - copy)
+            shift = row0 - start
+            pos_t = pos0 - shift
         q_in = pltpu.make_async_copy(
-            q_hbm.at[pl.ds(row0, copy)], q_v.at[pl.ds(0, copy)], sem_q.at[0]
+            q_hbm.at[pl.ds(start, copy)], q_v.at[pl.ds(0, copy)], sem_q.at[0]
         )
         q_in.start()
+        o_span = o_hbm.at[pl.ds(start, copy)]
+        if clamps:
+            o_in = pltpu.make_async_copy(
+                o_span, o_v.at[pl.ds(0, copy)], sem_o.at[0]
+            )
+            o_in.start()
         last = pos0 + rows - 1  # the last live row's position
         first = jnp.maximum(pos0 - window + 1, 0) if window > 0 else 0
         pg_lo, pg_hi = first // page, jnp.minimum(last // page + 1, P)
@@ -629,11 +650,13 @@ def _work_list_kernel(
                 kv_t[side] = (
                     kbuf[slot, side].transpose(1, 0, 2).astype(kv_t.dtype)
                 )
-            # a row's position: heads of one kv group lie (n_rep, nrow)
+            # a row's position: heads of one kv group lie (n_rep, nrow); the
+            # rows before the item's own (positions under ``pos0``) and
+            # after it are computed like any and never written
             tok = jax.lax.rem(
                 jax.lax.broadcasted_iota(jnp.int32, (M, KB), 0), nrow
             )
-            qpos = pos0 + tok
+            qpos = pos_t + tok
             kpos = kb * KB + jax.lax.broadcasted_iota(jnp.int32, (M, KB), 1)
             keep = kpos <= qpos
             if window > 0:
@@ -672,10 +695,15 @@ def _work_list_kernel(
         jax.lax.fori_loop(kb_lo, kb_hi, block, 0)
         out = (acc_scr[:, :M] / l_scr[:, :M]).astype(o_v.dtype)
         out = out.reshape(Hq, nrow, D).transpose(1, 0, 2)  # [nrow, Hq, D]
-        mine = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0) < rows
-        o_v[:nrow] = jnp.where(mine, out, jnp.zeros_like(out))
+        at = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
+        if clamps:
+            mine = (at >= shift) & (at < shift + rows)
+            o_in.wait()
+            o_v[:nrow] = jnp.where(mine, out, o_v[:nrow])
+        else:
+            o_v[:nrow] = jnp.where(at < rows, out, jnp.zeros_like(out))
         o_out = pltpu.make_async_copy(
-            o_v.at[pl.ds(0, copy)], o_hbm.at[pl.ds(row0, copy)], sem_o.at[0]
+            o_v.at[pl.ds(0, copy)], o_span, sem_o.at[0]
         )
         o_out.start()
         o_out.wait()
@@ -753,12 +781,16 @@ def _packed_work_list_attention(
     interpret, name_suffix="",
 ):
     """The packed launch over a dense pool that already holds the
-    dispatch's rows (see the section comment): ``[Np, Hq, D]``."""
+    dispatch's rows (see the section comment): ``[Np, Hq, D]``.  The
+    segments may lie anywhere in the packed axis and be of any length:
+    ``s_max`` only sets the query block, ``min(s_max, _WL_Q_BLOCK)``."""
     from .latent_attention import packed_work_list
 
     qb, _ = _work_list_tiles(s_max, q.dtype)
     if s_max % qb:
         raise ValueError(f"s_max {s_max} is not a multiple of {qb}")
+    if q.shape[0] < qb:
+        raise ValueError(f"a tile of {qb} rows does not fit {q.shape[0]} packed")
     i32 = lambda x: x.astype(jnp.int32)  # noqa: E731
     items = packed_work_list(
         i32(base), i32(seg_off), i32(q_lens), q.shape[0], qb
@@ -858,7 +890,9 @@ def packed_ragged_attention(
     base: jax.Array,  # [B] committed cache length per lane
     seg_off: jax.Array,  # [B] lane's segment offset into the packed axis
     q_lens: jax.Array,  # [B] fresh rows per lane (0 = no segment)
-    s_max: int,  # static per-lane window capacity (pow2 of max segment)
+    s_max: int,  # static per-lane window capacity (pow2 of max segment);
+    # the work-list kernel takes its query block from it and holds segments
+    # of any length
     layer: jax.Array | int = 0,
     window: int = 0,
     group: int = 4,

@@ -355,11 +355,12 @@ def test_packed_shape_budget_spec_columns():
     assert b.spec_shapes == [(8, 8, 5)]
 
 
-def test_packed_shape_budget_invariant_random():
+@pytest.mark.parametrize("item_rows", [0, 16], ids=["window", "free"])
+def test_packed_shape_budget_invariant_random(item_rows):
     import random
 
     rng = random.Random(0)
-    b = PackedShapeBudget(budget=4)
+    b = PackedShapeBudget(budget=4, item_rows=item_rows)
     for _ in range(200):
         s = pow2_bucket(rng.randint(1, 64))
         off = rng.randint(0, 256)
@@ -367,13 +368,87 @@ def test_packed_shape_budget_invariant_random():
         # ~half the dispatches speculate: the verify pad rule's widths
         sp = rng.choice((0, 0, 2, 3, 5, 9))
         np_got, s_got, sp_got = b.fit(s, off, total, s_spec=sp)
-        assert s_got >= s
         assert sp_got >= sp
         assert sp == 0 or sp_got > 0
         assert not (sp == 0 and sp_got > 0)
-        assert off + s_got <= np_got
         assert total <= np_got
+        if item_rows:
+            assert min(s_got, item_rows) >= min(s, item_rows)
+        else:
+            assert s_got >= s and off + s_got <= np_got
     assert len(b) <= 4
+
+
+# the packed executables the benchmark's configurations fix, and the query
+# block of the pair pools' work-list kernel (ragged_attention._WL_Q_BLOCK)
+MISTRAL_SHAPES = [(16, 1), (128, 64), (512, 256), (1024, 512)]
+MELLUM_SHAPES = [(32, 1), (256, 128), (1024, 512), (2048, 1024)]
+LATENT_SHAPES = [(16, 1), (256, 128), (1024, 512), (4096, 2048)]
+WINDOW, FREE = 0, 256
+
+
+def _minted(shapes, item_rows):
+    """A budget as ``benchmark/server.py`` fills it before any traffic."""
+    b = PackedShapeBudget(len(shapes), item_rows)
+    for np_, s_max in shapes:
+        b.fit(s_max, np_ - s_max, np_ - s_max + 1)
+    return b
+
+
+# (minted shapes, rule, the dispatch's (s_nat, off_last, total)) -> triple
+SHAPE_RULE_CASES = {
+    # a budget-filling chunk beside 15 decode rows: the rows it has
+    "free_mistral_chunk": (MISTRAL_SHAPES, FREE, (512, 15, 511), (512, 256, 0)),
+    "free_mistral_full_axis": (MISTRAL_SHAPES, FREE, (512, 15, 512), (512, 256, 0)),
+    "free_mellum_chunk": (MELLUM_SHAPES, FREE, (1024, 31, 1023), (1024, 512, 0)),
+    # a 96-row question beside four decode rows keeps a tile of its own
+    # size: never 96 one-row items, nor two blocks that each read the keys
+    "free_question": (MISTRAL_SHAPES, FREE, (128, 4, 100), (512, 256, 0)),
+    "free_short_chunk": (MISTRAL_SHAPES, FREE, (64, 8, 48), (128, 64, 0)),
+    "free_decode_only": (MISTRAL_SHAPES, FREE, (1, 15, 16), (16, 1, 0)),
+    # past the budget only the widest shape is left
+    "free_over_budget": (MISTRAL_SHAPES, FREE, (512, 15, 527), (1024, 512, 0)),
+    # a launch that reads windows resolves all of them as it always did
+    "window_mistral_chunk": (MISTRAL_SHAPES, WINDOW, (512, 15, 511), (1024, 512, 0)),
+    "window_mellum_chunk": (MELLUM_SHAPES, WINDOW, (1024, 31, 1023), (2048, 1024, 0)),
+    "window_latent_chunk": (LATENT_SHAPES, WINDOW, (2048, 15, 2047), (4096, 2048, 0)),
+    "window_question": (MISTRAL_SHAPES, WINDOW, (128, 4, 100), (512, 256, 0)),
+    "window_short_chunk": (MISTRAL_SHAPES, WINDOW, (64, 8, 48), (128, 64, 0)),
+    "window_decode_only": (MISTRAL_SHAPES, WINDOW, (1, 15, 16), (16, 1, 0)),
+}
+
+
+@pytest.mark.parametrize("name", list(SHAPE_RULE_CASES))
+def test_packed_shape_rule_follows_the_launch(name):
+    """``PackedLaunch.item_rows`` decides the rule: a launch that keeps its
+    tiles inside the axis takes the smallest minted shape that holds the
+    dispatch's rows, one that reads a lane's window the shape padded for
+    it.  Nothing is minted or evicted either way."""
+    shapes, item_rows, dispatch, want = SHAPE_RULE_CASES[name]
+    b = _minted(shapes, item_rows)
+    assert b.fit(*dispatch) == want
+    assert sorted(b.pairs) == [(n, s, 0) for n, s in shapes]
+    assert not b.evictions
+
+
+@pytest.mark.parametrize("item_rows", [WINDOW, FREE], ids=["window", "free"])
+@pytest.mark.parametrize(
+    "shapes", [MISTRAL_SHAPES, MELLUM_SHAPES, LATENT_SHAPES],
+    ids=["mistral", "mellum2", "mistral4"])
+def test_benchmark_mint_is_the_same_under_both_rules(shapes, item_rows):
+    assert _minted(shapes, item_rows).pairs == [(n, s, 0) for n, s in shapes]
+
+
+@pytest.mark.parametrize("item_rows", [WINDOW, FREE], ids=["window", "free"])
+def test_spec_triples_never_take_spec_free_dispatches(item_rows):
+    b = PackedShapeBudget(2, item_rows)
+    assert b.fit(1, 15, 16) == (16, 1, 0)
+    assert b.fit(256, 0, 300, s_spec=3) == (512, 256, 3)
+    # at the budget: the spec-carrying triple holds the rows and must not
+    # take them; a narrower spec width still pads up into it
+    assert b.fit(256, 10, 250) == (256 if item_rows else 512, 256, 0)
+    assert b.evictions == 1
+    assert b.fit(128, 0, 300, s_spec=2) == (512, 256, 3)
 
 
 def test_engine_executable_shape_gauge(run):

@@ -160,6 +160,40 @@ def test_packed_work_list_compiles(chip, Np, s_max, window):
     assert f"bf16[{Np},{w['Hq']},{w['D']}]" in text
 
 
+@pytest.mark.parametrize("window", [0, 4096], ids=["mixtral", "mistral-7b"])
+@pytest.mark.parametrize(
+    "Np,s_max", BENCH_PACKED_SHAPES + [(2048, 1024)],
+    ids=lambda v: str(v))
+def test_work_list_kernel_jaxpr_stays_within_its_budget(Np, s_max, window):
+    """The set-up budget, where a CPU can guard it: every packed executable
+    traces and lowers this kernel once a kind of layer, and PR 31 was
+    refused for what its unrolled page copies cost there.  PR 32's kernel
+    read 491 equations and 4 DMA starts a tile at the shapes with two tiles
+    (429 without a window), 295 and 4 at ``(lanes, 1)``; keeping a tile
+    inside the packed axis (PR 40: a clamped start, the tile's span of the
+    output read back before the item's rows are written) costs 16 equations
+    and one start a tile, whatever the packed shape."""
+    from dynamo_tpu.ops import ragged_attention as ra
+    from tests.test_packed_work_list import _count, _eqns
+
+    w = MIXTRAL
+    spec = jax.ShapeDtypeStruct
+    q = spec((Np, w["Hq"], w["D"]), jnp.bfloat16)
+    pool = spec((16, 2, PAGES, PAGE, w["Hkv"], w["D"]), jnp.bfloat16)
+    table, vec = spec((16, 528), jnp.int32), spec((16,), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: ra._packed_work_list_attention(
+            *a, s_max=s_max, layer=3, window=window, interpret=False)
+    )(q, pool, table, vec, vec, vec).jaxpr
+    tiles = ra._work_list_tiles(s_max, jnp.bfloat16)[1]
+    eqns = sum(1 for _ in _eqns(jaxpr))
+    starts = _count(jaxpr, "dma_start")
+    if s_max == 1:  # a one-row tile cannot overhang: the parent's kernel
+        assert starts == 4 and eqns <= 295, (eqns, starts)
+    assert starts <= 5 * len(tiles), starts
+    assert eqns <= 520, eqns
+
+
 def test_pair_pool_layers_scatter_and_attend_without_copying_the_pool(chip, monkeypatch):
     """The trunk over a dense pair pool, as the packed step runs it: every
     layer scatters its rows into the pool and the work-list kernel reads

@@ -60,9 +60,12 @@ def _operands(chip, cfg, eng, Np, table):
     )
 
 
-@pytest.mark.parametrize("Np,s_max,steps", [(2048, 1024, 1), (32, 1, 4)])
+@pytest.mark.parametrize(
+    "Np,s_max,steps", [(2048, 1024, 1), (1024, 512, 1), (32, 1, 4)])
 def test_mellum_steps_lower_at_published_widths(chip, monkeypatch, Np, s_max, steps):
-    """One packed executable (a 1024-row chunk beside 31 decode rows) and
+    """The packed executables of a chunk step (minted as ``(2048, 1024)``;
+    since PR 40 a 992-row chunk beside 31 decode rows fills ``(1024,
+    512)``, the shape its rows need) and
     one fused decode step of the 12-layer configuration: the window layers'
     launches carry their suffix, the pools are held once (no copy of either,
     nor of a layer's experts), and the temporaries are activations."""

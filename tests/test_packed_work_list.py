@@ -137,6 +137,63 @@ def test_packed_work_list_matches_xla(name):
     assert not got[total:].any() and not ref[total:].any()
 
 
+# Window-free shapes: the engine hands this kernel the smallest minted shape
+# that holds a dispatch's rows (``bucketing.PackedShapeBudget`` under
+# ``PackedLaunch.item_rows``), so a tile may reach the end of the packed axis
+# and a segment may be longer than the triple's ``s_max``.  ``(lanes' fresh
+# rows, s_max of the launch)``; every case packs into ``Np`` 512.
+FULL_AXIS_CASES = {
+    # total == Np: a 496-row chunk between decode rows, one in the last row
+    "decode_row_in_the_last_row": dict(
+        qlens=[1] * 5 + [496] + [1] * 11, s_max=256),
+    # a chunk whose tail block starts inside the last 256 rows
+    "tail_block_overhangs": dict(qlens=[1] * 5 + [500], s_max=256),
+    # two chunks in one dispatch, the second's tail block clamped
+    "two_chunks": dict(qlens=[200, 1, 300, 1], s_max=256),
+    # a segment longer than the triple's s_max, decode rows behind it whose
+    # small tiles start early over rows the chunk's wide tile wrote
+    "segment_longer_than_s_max": dict(qlens=[506] + [1] * 6, s_max=256),
+    # the same under a window, as a two-kind trunk's window layer launches it
+    "window": dict(qlens=[506] + [1] * 6, s_max=256, window=64,
+                   suffix="_window"),
+    # a short segment at a small query block: several small-tile items
+    "small_blocks": dict(qlens=[1] * 3 + [21] + [1] * 6, s_max=16, Np=32),
+}
+
+
+@pytest.mark.parametrize("name", list(FULL_AXIS_CASES))
+def test_packed_work_list_fills_the_axis(name):
+    """Against the twin on the windowed layout of the same lanes (``Np``
+    padded for every lane's window): the first ``total`` rows of both
+    layouts are the same segments."""
+    kw = dict(FULL_AXIS_CASES[name])
+    qlens, s_max, window = kw["qlens"], kw["s_max"], kw.get("window", 0)
+    bases = [(37 * (b + 1)) % 300 for b in range(len(qlens))]
+    (q, k, v, pool, pt, base, off, lens, lane, rel), s_nat, total = _case(
+        4, 2, bases, qlens, window=window)
+    ref = np.asarray(ra.packed_ragged_attention_xla(
+        q, k, v, pool, pt, base, off, lens, lane, rel, s_nat, LAYER, window))
+    Np = kw.get("Np", 512)
+    assert Np // 2 < total <= Np < q.shape[0]  # the windowed layout is wider
+    written = _scatter(pool, k, v, pt, base, lane, rel)
+    got = np.asarray(ra.packed_ragged_attention(
+        q[:Np], k[:Np], v[:Np], written, pt, base, off, lens, s_max, LAYER,
+        window, interpret=True, name_suffix=kw.get("suffix", ""),
+    ))
+    assert got.shape == (Np,) + q.shape[1:]
+    np.testing.assert_allclose(got[:total], ref[:total], rtol=2e-5, atol=2e-5)
+    assert not got[total:].any()  # rows no item owns stay zero
+
+
+def test_a_tile_wider_than_the_axis_is_refused():
+    (q, k, v, pool, pt, base, off, lens, _l, _r), _, _ = _case(
+        4, 2, [3], [5])
+    with pytest.raises(ValueError, match="does not fit"):
+        ra.packed_ragged_attention(
+            q[:8], k[:8], v[:8], pool, pt, base, off, lens, 16, LAYER,
+            interpret=True)
+
+
 def _int8_takes_the_grid_kernel():
     """An int8 pool keeps the grid kernel, which dequantizes the row scales
     in the read and takes the fresh rows from ``k``/``v``; written first or
@@ -309,9 +366,11 @@ def test_dma_descriptors_do_not_grow_with_the_key_block(monkeypatch):
 
     at_128, at_512 = starts(128), starts(512)
     assert at_128 == at_512
-    # a tile: queries in, a page's K and V in one copy at the first fetch
-    # and at the next block's, rows out
-    assert at_512[0] <= 4 * len(ra._work_list_tiles(s_max, q.dtype)[1])
+    # a tile: queries in, its span of the output in (PR 40: a tile that
+    # would overhang the axis starts early, over rows earlier items wrote),
+    # a page's K and V in one copy at the first fetch and at the next
+    # block's, rows out
+    assert at_512[0] <= 5 * len(ra._work_list_tiles(s_max, q.dtype)[1])
 
 
 def test_item_counts_follow_the_work_list():
@@ -390,6 +449,95 @@ def test_unified_dispatches_take_the_whole_page_table(run, monkeypatch):
     fused = [m for m in marks_full if m["k"] > 1]
     assert fused and all(m["decode"] == "xla" for m in fused)
     assert all("decode" not in m for m in marks_full if m["k"] == 1)
+
+
+@pytest.mark.parametrize("sampling", ["greedy", "seeded"])
+def test_chunk_step_runs_the_rows_it_has(run, monkeypatch, sampling):
+    """The engine's half of the window-free shape rule, over the kernel
+    itself (interpreted): a chunk that fills the token budget beside three
+    decoding lanes is dispatched at the rows it has, 251 of 256, where the
+    window rule pads the axis to 512 for the last lane's ``s_max`` window,
+    and every request's tokens are the same under both.  Which rule an
+    engine takes is ``packed_launch``'s to say (``item_rows``)."""
+    import asyncio
+    import functools
+
+    from dynamo_tpu.engine import EngineConfig, JaxEngine, ModelConfig
+    from dynamo_tpu.protocols.common import (
+        PreprocessedRequest, SamplingOptions, StopConditions,
+    )
+    from tests.test_request_stages import collect
+
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    traced = []
+    for name in ("packed_ragged_attention", "decode_work_list_attention"):
+        kernel = functools.partial(getattr(ra, name), interpret=True)
+
+        def spy(*a, _kernel=kernel, _name=name, **kw):
+            traced.append(_name)
+            return _kernel(*a, **kw)
+
+        monkeypatch.setattr(ra, name, spy)
+    # widths no other test serves: the step's jit cache holds what was
+    # traced for a configuration, kernel or composition
+    model = ModelConfig.tiny(head_dim=D, vocab_size=251)
+    real_launch = att.packed_launch
+
+    def request(tokens, max_tokens, seed):
+        options = (
+            SamplingOptions(temperature=0.0) if sampling == "greedy"
+            else SamplingOptions(temperature=0.9, top_p=0.95, seed=seed)
+        )
+        return PreprocessedRequest(
+            token_ids=list(tokens),
+            stop_conditions=StopConditions(max_tokens=max_tokens),
+            sampling_options=options,
+        )
+
+    def served(window_free):
+        def launch(*a):
+            real = real_launch(*a)
+            assert real.walks_work_list and real.item_rows == ra._WL_Q_BLOCK
+            return real if window_free else real._replace(item_rows=0)
+
+        monkeypatch.setattr(att, "packed_launch", launch)
+        engine = JaxEngine.random_init(model, EngineConfig(
+            max_batch_size=4, max_seq_len=512, page_size=PAGE, num_pages=256,
+            mixed_token_budget=256))
+        assert engine._packed_shapes.item_rows == (256 if window_free else 0)
+        dispatches = []
+        observe = engine.obs.observe_mixed_tokens
+        monkeypatch.setattr(
+            engine.obs, "observe_mixed_tokens",
+            lambda used, disp: (dispatches.append((used, disp)),
+                                observe(used, disp))[1])
+
+        async def body():
+            try:
+                short = [
+                    asyncio.ensure_future(collect(engine, request(
+                        [7 + i] * (9 + i), 64, seed=11 + i)))
+                    for i in range(3)
+                ]
+                while engine._steps < 4:  # the three lanes are decoding
+                    await asyncio.sleep(0.005)
+                document = await collect(engine, request(
+                    [(13 * j) % 250 + 1 for j in range(300)], 4, seed=5))
+                return [await s for s in short] + [document]
+            finally:
+                await engine.stop()
+
+        return run(body()), dispatches, engine._packed_shapes.pairs
+
+    want, windowed, shapes_w = served(False)
+    got, free, shapes_f = served(True)
+    assert "packed_ragged_attention" in traced
+    assert got == want and all(len(t) == 64 for t in got[:3])
+    # the budget-filling chunk: 248 rows (page-aligned) beside 3 decode rows
+    assert max(windowed) == (251, 512) and (512, 256, 0) in shapes_w
+    assert max(free) == (251, 256) and (256, 256, 0) in shapes_f
+    used, dispatched = max(free)
+    assert used / dispatched >= 0.97
 
 
 DECODE_BACKENDS = {
