@@ -22,7 +22,9 @@ import os
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, AsyncIterator, Callable, Dict, List, Optional, Tuple
+from typing import (
+    Any, AsyncIterator, Callable, Dict, List, NamedTuple, Optional, Tuple,
+)
 
 import jax
 import jax.numpy as jnp
@@ -382,6 +384,10 @@ class InflightBlock:
     # (sampling.pack_sampled_logprobs layout; N inferred from the width)
     sampled: Any
     slots: List[Optional[SeqState]]
+    # the dispatch record's counts: decode-runnable lanes at dispatch, and
+    # the block's steps
+    n_decode: int = 0
+    n_steps: int = 1
     # dispatch timestamp: commit observes dispatch->materialize latency
     dispatched_at: float = field(default_factory=time.perf_counter)
 
@@ -399,6 +405,10 @@ class InflightPrefill:
     # echo+logprobs: packed [1, T, 2 + 2N] prompt-scoring handle (step.
     # score_prompt_step), materialized alongside the sampled row at commit
     prompt_lp: Any = None
+    # prompt rows this dispatch computed (a classic chunked prefill's final
+    # dispatch also carries the rows of the chunks before it, which have
+    # no record of their own)
+    rows: int = 0
     dispatched_at: float = field(default_factory=time.perf_counter)
 
 
@@ -435,6 +445,12 @@ class InflightUnified:
     # commit replays the whole block (Scheduler.commit_block), exactly
     # like an InflightBlock.
     n_steps: int = 1
+    # the dispatch record: the executable's packed rows, the real rows of
+    # them (over all fused steps), and the serial the ``dispatch`` and
+    # ``device_wait`` annotations of a profiler trace share
+    np_rows: int = 0
+    used_rows: int = 0
+    serial: int = 0
     dispatched_at: float = field(default_factory=time.perf_counter)
 
 
@@ -449,6 +465,23 @@ class InflightVerify:
     sampled: Any  # packed [B, S, 2 + 2N]
     lanes: List[Tuple[SeqState, int, List[int]]]
     dispatched_at: float = field(default_factory=time.perf_counter)
+
+
+class _DispatchAccount(NamedTuple):
+    """One committed entry as the dispatch record counts it."""
+
+    step: str  # chunk | decode | prefill | decode_block | verify
+    np_rows: int  # the executable's packed rows, 0 where it has none
+    steps: int  # forward passes it ran
+    lane_steps: int  # decoding lanes x forward passes
+    prefill_rows: int
+    used_rows: int  # real rows, over all its steps
+    weight: int  # rows x steps dispatched: its share of a bundled commit
+
+    @property
+    def carried_prefill(self) -> bool:
+        """Whether its service is a first token's ``chunk_steps`` time."""
+        return self.step in ("chunk", "prefill")
 
 
 def _spec_live(seq: SeqState) -> bool:
@@ -834,6 +867,28 @@ class JaxEngine:
             metrics_registry, max_slots=self.cfg.max_batch_size
         )
         self.sched.metrics = self.obs
+        # the dispatch record (ISSUE 41), on time.perf_counter(), the clock
+        # of every Inflight* record's ``dispatched_at`` and of the commit's
+        # one read: service seconds of the committed dispatches that
+        # carried prefill rows and of those that carried none, prefill rows
+        # committed, and the last commit's clock.  One tuple, swapped whole
+        # at each commit (the fanout worker reads it beside the executor
+        # thread).  ``_inflight`` is the tick loop's queue of uncommitted
+        # generations, for the one reading that clips a dispatch in flight
+        # (``_service_mark``); the scheduler copies that reading onto a
+        # request at its first admission and reads nothing of it.
+        self._served: Tuple[float, float, int, float] = (0.0, 0.0, 0, 0.0)
+        self._inflight: Optional[Any] = None
+        self._dispatch_serial = 0
+        self._loose_prefill_rows = 0
+        self.sched.service_mark = self._service_mark
+        # the start of the part of the current parked wait that is not yet
+        # in dynamo_engine_parked_seconds_total (time.monotonic(); None
+        # while the loop is awake): counted when the wait ends, and up to
+        # the moment of a scrape that comes in the middle of one
+        self._parked_since: Optional[float] = None
+        self._parked_lock = threading.Lock()
+        self.obs.registry.before_render(self._count_parked)
         # G2/G3 offload plane (offload.KVOffloadEngine): evictions snapshot
         # (async) onto the dedicated offload thread with disk overflow;
         # admission onboards offloaded prefixes through the chunked scatter
@@ -999,6 +1054,22 @@ class JaxEngine:
         self._packed_full_table = launch.walks_work_list
         self._packed_fits = launch.fits
         self._packed_shapes = PackedShapeBudget(shape_budget, launch.item_rows)
+        # which kernels a packed dispatch takes, for its annotation in a
+        # profiler trace: the latent path of the packed launch, and what
+        # attends the fused steps after the first.  Both depend on the pool
+        # and the head shape alone, so they are read once, here
+        from . import attention as att
+
+        with self.mesh_scope():
+            self._latent_path = (
+                att.latent_packed_path(self.kv.pages)
+                if model_cfg.is_mla
+                else None
+            )
+            self._decode_backend = att.decode_backend(
+                self.kv.pages, model_cfg.num_heads, model_cfg.head_dim,
+                model_cfg.dtype,
+            )
         # queue-side prefetch: window resolved here, walks issued by the
         # tick loop from queue position (_drive_prefetch), finished or
         # cancelled per request
@@ -2568,6 +2639,7 @@ class JaxEngine:
         # each generation is one tick's entry list (the legacy ``pending``
         # is the depth-1 special case)
         inflight: "collections.deque[List[Any]]" = collections.deque()
+        self._inflight = inflight
         prof = self.profiler
         while self._running:
             try:
@@ -2970,26 +3042,47 @@ class JaxEngine:
         in slices, because an annotation is kept only if it opens and
         closes inside a trace -- one that starts or stops mid-wait then
         loses a slice, not the whole wait.  It is no tick phase and enters
-        no tick record."""
+        no tick record.  Watched or not, the wait is counted into
+        ``dynamo_engine_parked_seconds_total`` (``_count_parked``)."""
         assert self._wake is not None
-        deadline = None if timeout is None else time.monotonic() + timeout
-        while not self._wake.is_set():
-            left = None if deadline is None else deadline - time.monotonic()
-            if left is not None and left <= 0.0:
-                return
-            parked = None
-            if watched:
-                left = PARKED_SLICE_S if left is None else min(
-                    left, PARKED_SLICE_S
+        start = self._parked_since = time.monotonic()
+        deadline = None if timeout is None else start + timeout
+        try:
+            while not self._wake.is_set():
+                left = (
+                    None if deadline is None else deadline - time.monotonic()
                 )
-                parked = profiling.annotate(profiling.PARKED_ANNOTATION)
-            try:
-                await asyncio.wait_for(self._wake.wait(), left)
-            except asyncio.TimeoutError:
-                pass
-            finally:
-                if parked is not None:
-                    parked.__exit__(None, None, None)
+                if left is not None and left <= 0.0:
+                    return
+                parked = None
+                if watched:
+                    left = PARKED_SLICE_S if left is None else min(
+                        left, PARKED_SLICE_S
+                    )
+                    parked = profiling.annotate(profiling.PARKED_ANNOTATION)
+                try:
+                    await asyncio.wait_for(self._wake.wait(), left)
+                except asyncio.TimeoutError:
+                    pass
+                finally:
+                    if parked is not None:
+                        parked.__exit__(None, None, None)
+        finally:
+            self._count_parked(ended=True)
+
+    def _count_parked(self, ended: bool = False) -> None:
+        """Move the parked wait's seconds so far into
+        ``dynamo_engine_parked_seconds_total``: where the wait ends
+        (``_park``), and before every exposition of the registry, so that a
+        scrape in the middle of a long wait sees the part that has passed
+        (the lock: a scrape may come from another thread)."""
+        with self._parked_lock:
+            since = self._parked_since
+            if since is None:
+                return
+            now = time.monotonic()
+            self._parked_since = None if ended else now
+            self.obs.parked_seconds.inc(max(now - since, 0.0))
 
     def _revive_paused_lanes(self) -> None:
         """A lane that hit its device-side limit self-deactivated; if growth
@@ -3641,6 +3734,8 @@ class JaxEngine:
         )
         seq.prefilled_tokens = start + suffix_len
         seq.note_prefill(suffix_len)
+        # no record of its own: its rows ride the next classic prefill's
+        self._loose_prefill_rows += suffix_len
         self._steps += 1
         self.obs.observe_dispatch("chunk")
         if self._tick is not None:
@@ -3667,7 +3762,11 @@ class JaxEngine:
         # then inject the device-resident first token into its lane
         self._sync_device_state()
         tok = sampled[:, 0]  # device slice from the packed [1, C] row
-        pf = InflightPrefill(sampled=sampled, tok=tok, seq=seq, slot=seq.slot)
+        pf = InflightPrefill(
+            sampled=sampled, tok=tok, seq=seq, slot=seq.slot,
+            rows=prompt_len - cached + self._loose_prefill_rows,
+        )
+        self._loose_prefill_rows = 0
         if (
             seq.prompt_logprobs is not None
             and not seq.prompt_lp_sent
@@ -3749,6 +3848,7 @@ class JaxEngine:
                 tok=sampled[i : i + 1, 0],  # device slice: inject re-apply
                 seq=seq,
                 slot=seq.slot,
+                rows=pl - caches[i],
             )
             if (
                 seq.prompt_logprobs is not None
@@ -4216,7 +4316,10 @@ class JaxEngine:
         if tick is not None:
             tick.note_dispatch("decode_block")
             tick.mark("dispatch")
-        return InflightBlock(sampled=sampled, slots=list(self.sched.slots))
+        return InflightBlock(
+            sampled=sampled, slots=list(self.sched.slots),
+            n_decode=self.sched.num_decode_runnable, n_steps=K,
+        )
 
     @hot_path
     def _dispatch_unified(
@@ -4440,15 +4543,20 @@ class JaxEngine:
             else:
                 t_dec[o] = True
         disp_tokens = Np + B * (num_steps - 1)
+        self._dispatch_serial = serial = self._dispatch_serial + 1
         tick = self._tick
         if tick is not None:
             tick.mark("assemble")
-            # what the attention kernels are asked to do, carried by
-            # the dispatch interval's annotation: per live lane its
-            # fresh query rows and the context its last row reads
-            # (host mirrors: a decode lane's lags the device by the
-            # uncommitted generations), the fused steps, the packed
-            # rows.  Lists are "|"-joined: a comma cuts a trace stat.
+        if tick is not None and tick.annotating:
+            # a profiler trace is being taken: what the attention kernels
+            # are asked to do, carried by the dispatch interval's
+            # annotation: per live lane its fresh query rows and the
+            # context its last row reads (host mirrors: a decode lane's
+            # lags the device by the uncommitted generations), the fused
+            # steps, the packed rows, the class of the step and the
+            # dispatch's serial (the ``device_wait`` annotation that
+            # fetches it names it again).  Lists are "|"-joined: a comma
+            # cuts a trace stat.
             live = np.nonzero(q_host)[0]
             base = np.where(dec_cap, sched.seq_lens, p_start)
             dispatch_meta = {
@@ -4459,6 +4567,8 @@ class JaxEngine:
                 "k": num_steps,
                 "np": Np,
                 "pt": Pb,
+                "step": "chunk" if n_pf_tokens else "decode",
+                "d": serial,
             }
             # the launch as the dense pools' kernel walks it: its work
             # items, and how many of them take the small tile
@@ -4467,22 +4577,13 @@ class JaxEngine:
             dispatch_meta["items"], dispatch_meta["small"] = (
                 packed_item_counts(q_host[live], s_max)
             )
-            # which kernels the dispatch takes (read where the step's trace
-            # reads them): the latent path of its packed launch, and what
-            # attends the fused steps after the first
-            from . import attention as att
-
-            m = self.model_cfg
-            if m.is_mla or num_steps > 1:
-                with self.mesh_scope():
-                    if m.is_mla:
-                        dispatch_meta["latent"] = att.latent_packed_path(
-                            self.kv.pages
-                        )
-                    if num_steps > 1:
-                        dispatch_meta["decode"] = att.decode_backend(
-                            self.kv.pages, m.num_heads, m.head_dim, m.dtype
-                        )
+            # which kernels the dispatch takes: the latent path of its
+            # packed launch, and what attends the fused steps after the
+            # first (read once, at construction)
+            if self._latent_path is not None:
+                dispatch_meta["latent"] = self._latent_path
+            if num_steps > 1:
+                dispatch_meta["decode"] = self._decode_backend
         operands = (
             self.params,
             self.model_cfg,
@@ -4584,6 +4685,9 @@ class JaxEngine:
             spec_sampled=spec_packed if spec_lanes else None,
             spec_lanes=spec_lanes,
             n_steps=num_steps,
+            np_rows=Np,
+            used_rows=used_tokens,
+            serial=serial,
         )
 
     # -- speculative decoding (spec/: draft on host, verify in one pass) ----
@@ -5150,6 +5254,112 @@ class JaxEngine:
         seq.awaiting_kv = False
         sched.dirty_slots.add(seq.slot)
 
+    def _dispatch_account(self, e: Any) -> "_DispatchAccount":
+        """What the dispatch record keeps of one in-flight entry.  A unified
+        dispatch is a ``chunk`` step if it carried prefill rows and a
+        ``decode`` step if it carried none; the classic entries keep their
+        kind as their class.  Counts the entry carries from its dispatch:
+        nothing here walks the lanes."""
+        if isinstance(e, InflightUnified):
+            return _DispatchAccount(
+                "chunk" if e.n_prefill_tokens > 0 else "decode",
+                e.np_rows, e.n_steps, e.n_decode * e.n_steps,
+                e.n_prefill_tokens, e.used_rows,
+                e.np_rows + self.cfg.max_batch_size * (e.n_steps - 1),
+            )
+        if isinstance(e, InflightBlock):
+            lane_steps = e.n_decode * e.n_steps
+            return _DispatchAccount(
+                "decode_block", 0, e.n_steps, lane_steps, 0, lane_steps,
+                self.cfg.max_batch_size * e.n_steps,
+            )
+        if isinstance(e, InflightVerify):
+            lanes = len(e.lanes)
+            return _DispatchAccount("verify", 0, 1, 0, 0, lanes, lanes or 1)
+        rows = (
+            sum(pf.rows for pf in e.entries)
+            if isinstance(e, InflightPrefillGroup)
+            else e.rows
+        )
+        return _DispatchAccount("prefill", 0, 1, 0, rows, rows, rows or 1)
+
+    def _service_shares(
+        self, entries: List[Any], seconds: float
+    ) -> List[Tuple["_DispatchAccount", float]]:
+        """``seconds`` of device service over the entries of one commit, by
+        rows x steps dispatched: ``(account, share)`` per entry.  A commit
+        holds one entry nearly always."""
+        accounts = [self._dispatch_account(e) for e in entries]
+        weight = sum(a.weight for a in accounts)
+        return [(a, seconds * a.weight / weight) for a in accounts]
+
+    def _record_service(
+        self, entries: List[Any], service: float, now: float
+    ) -> None:
+        """The dispatch record's one update a commit (executor thread):
+        ``service`` seconds the device spent on ``entries``, which ended at
+        this commit's clock ``now``.  Observes
+        ``dynamo_engine_dispatch_service_seconds`` and its two counters per
+        entry, advances the running totals a first token is split by, and
+        (tick profiler on) files the entries under the tick's
+        ``dispatch_records``.
+
+        Service is the HOST's reading of the device's time: ``now`` less
+        the later of the dispatch's enqueue and the previous commit's
+        clock, so the time a dispatch queued behind the one before it (the
+        loop is double-buffered) is the earlier dispatch's, not its own.
+        No two readings overlap and their sum never exceeds the wall time;
+        a host that fetches late makes one reading long and the next short
+        and leaves the sum right.  It is NOT a device-side duration of one
+        dispatch (read that from a device trace), and a classic chunked
+        prefill's non-final chunks, which no commit fetches, fall into
+        whatever commits next."""
+        chunk_s, decode_s, rows, _ = self._served
+        tick = self._tick
+        for a, share in self._service_shares(entries, service):
+            self.obs.observe_service(
+                a.step, a.np_rows, share, a.steps, a.lane_steps
+            )
+            if a.carried_prefill:
+                chunk_s += share
+            else:
+                decode_s += share
+            rows += a.prefill_rows
+            if tick is not None:
+                tick.record.dispatch_records.append({
+                    "step": a.step, "np": a.np_rows, "k": a.steps,
+                    "rows": a.used_rows,
+                    "service_ms": round(share * 1e3, 4),
+                    "bundle": len(entries),
+                })
+        # dynalint: disable=DT014 -- one tuple swapped whole: the fanout
+        # worker's read at a first token sees the record as of one commit
+        # or the next, both of which lie inside that request's interval
+        self._served = (chunk_s, decode_s, rows, now)
+
+    def _service_mark(self) -> Tuple[float, float, int]:
+        """The dispatch record's totals as of now, for a request's first
+        admission (``Scheduler._try_admit`` copies them onto the request;
+        loop thread, between the tick's executor hops, so the queue of
+        uncommitted generations is still): service seconds of chunk steps,
+        of decode-only dispatches, prefill rows committed.  A dispatch the
+        device is serving right now is counted up to this moment (one clock
+        read), so that its commit later credits the request with the part
+        it waited for and no more."""
+        chunk_s, decode_s, rows, floor = self._served
+        head = self._inflight[0] if self._inflight else None
+        if head:
+            # dynalint: disable=DT012 -- once an admission: the record's
+            # clock (perf_counter, like dispatched_at) beside admitted_s
+            run = time.perf_counter() - max(head[0].dispatched_at, floor)
+            if run > 0.0:
+                for a, share in self._service_shares(head, run):
+                    if a.carried_prefill:
+                        chunk_s += share
+                    else:
+                        decode_s += share
+        return chunk_s, decode_s, rows
+
     @hot_path
     def _commit_all(
         self, entries: List[Any], pipeline_busy: bool = False
@@ -5209,8 +5419,28 @@ class JaxEngine:
             # dynalint: disable=DT004 -- the pipeline's ONE designed sync point:
             # block i's results materialize here while block i+1 computes
             mats = jax.device_get(handles)
+        # mats are host-resident np arrays (device_get / allgather output):
+        # no further np.asarray wrapping, which would read as a sync here
+        # dynalint: disable=DT012 -- the commit clock: one read serves every
+        # entry's dispatch->commit latency observe (dynamo_engine_step_latency)
+        # and the dispatch record's service time
+        now = time.perf_counter()
+        # the device's time on what this commit fetched: from the later of
+        # its enqueue and the commit before, which it queued behind
+        service = max(
+            now - max(entries[0].dispatched_at, self._served[3]), 0.0
+        )
         if tick is not None:
-            tick.mark("device_wait")
+            # inside a profiler trace the interval names what it fetched
+            # and how long the device served it
+            fetched = {
+                "d": "|".join(
+                    str(e.serial) for e in entries
+                    if isinstance(e, InflightUnified)
+                ),
+                "svc_us": int(service * 1e6),
+            } if tick.annotating else {}
+            tick.mark("device_wait", **fetched)
             if pipeline_busy:
                 # another generation is already queued on device: results
                 # landing here imply zero device idle -- record the gap
@@ -5253,11 +5483,7 @@ class JaxEngine:
                 seq.prompt_lp_sent = True
             events.append(ev)
 
-        # mats are host-resident np arrays (device_get / allgather output):
-        # no further np.asarray wrapping, which would read as a sync here
-        # dynalint: disable=DT012 -- the commit clock: one read serves every
-        # entry's dispatch->commit latency observe (dynamo_engine_step_latency)
-        now = time.perf_counter()
+        self._record_service(entries, service, now)
         for e, mat in zip(entries, mats):
             if isinstance(e, InflightPrefillGroup):
                 for i, pf in enumerate(e.entries):
@@ -5522,6 +5748,24 @@ class JaxEngine:
                     seq.first_token_s = now_m = time.monotonic()
                     adm = seq.admitted_s or now_m
                     self.obs.first_token_service.observe(now_m - adm)
+                    mark = seq.served_at_admission
+                    if mark is not None:
+                        # what it waited behind: the record's totals now
+                        # less those at its admission.  Committed service
+                        # only: what follows the commit that brought the
+                        # token (its fanout) is no_dispatch's
+                        chunk_s, decode_s, rows, _ = self._served
+                        chunk_s -= mark[0]
+                        decode_s -= mark[1]
+                        rows -= mark[2]
+                        idle_s = max(now_m - adm - chunk_s - decode_s, 0.0)
+                        seq.first_token_wait = (
+                            chunk_s, decode_s, idle_s, rows
+                        )
+                        self.obs.observe_first_token_wait(
+                            chunk_s, decode_s, idle_s,
+                            seq.prefill_tokens, rows,
+                        )
                     if slo.tracker.enabled:
                         slo.tracker.note_first_token(
                             seq.request_id,
@@ -5621,6 +5865,14 @@ class JaxEngine:
                     "kv_prefetch_hits": seq.prefetch_hits,
                     "mixed": seq.prefill_mixed,
                 }
+                if seq.first_token_wait is not None:
+                    in_chunk, in_decode, idle, rows_all = seq.first_token_wait
+                    attrs.update(
+                        in_chunk_steps_ms=round(in_chunk * 1e3, 3),
+                        in_decode_steps_ms=round(in_decode * 1e3, 3),
+                        no_dispatch_ms=round(idle * 1e3, 3),
+                        chunk_rows_all=rows_all,
+                    )
             tracing.record_span(
                 "engine." + stage, rid, lo, hi, parent=root, **attrs
             )

@@ -18,7 +18,7 @@ from __future__ import annotations
 import collections
 import time
 from dataclasses import dataclass, field
-from typing import Any, Deque, Dict, List, Optional, Tuple
+from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -213,6 +213,14 @@ class SeqState:
     created_s: float = 0.0
     admitted_s: float = 0.0
     first_token_s: float = 0.0
+    # the engine's dispatch record at the first admission (service seconds
+    # of chunk steps, of decode-only dispatches, prefill rows committed;
+    # ``JaxEngine._service_mark``), and at the first token what the request
+    # waited behind: seconds in chunk steps, in decode-only dispatches, in
+    # no dispatch, and every request's prefill rows committed meanwhile.
+    # Copied and observed once each; nothing schedules by them.
+    served_at_admission: Optional[Tuple[float, float, int]] = None
+    first_token_wait: Optional[Tuple[float, float, float, int]] = None
     preempted_at: float = 0.0
     preempted: List[Tuple[float, float]] = field(default_factory=list)
     # span attributes: prefill dispatches that carried this prompt, the
@@ -397,6 +405,12 @@ class Scheduler:
         # observability hook (engine/metrics.EngineMetrics): the scheduler
         # stays sans-IO -- it only pokes gauges the engine wired in
         self.metrics: Optional[Any] = None
+        # the engine's dispatch record, read where a request is first
+        # admitted and copied onto the request; the scheduler decides
+        # nothing by it
+        self.service_mark: Optional[
+            Callable[[], Tuple[float, float, int]]
+        ] = None
         B = cfg.max_batch_size
         self.max_pages = cfg.max_seq_len // cfg.page_size
         self.waiting: Deque[SeqState] = collections.deque()
@@ -594,6 +608,8 @@ class Scheduler:
             seq.admitted_s = now
             if self.metrics is not None:
                 self.metrics.queue_wait.observe(now - seq.arrival_s)
+            if self.service_mark is not None:
+                seq.served_at_admission = self.service_mark()
         elif seq.preempted_at:
             seq.preempted.append((seq.preempted_at, now))
             seq.preempted_at = 0.0

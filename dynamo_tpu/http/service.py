@@ -535,12 +535,15 @@ class HttpService:
         # Root span of the request's trace, bound to the request id so the
         # egress hop (and, through the propagated context, every remote
         # component's spans) links under it.  Manually paired: it closes
-        # when the response body completes, covering the full stream.
+        # when the response body completes, covering the full stream.  It
+        # starts at the handler's entry, where its first child
+        # (http.preprocess, from ``created_s``) starts.
         rsp = tracing.span(
             "http.request",
             request.id,
             component="http",
             bind=True,
+            start_s=received_s,
             endpoint=endpoint,
             model=parsed.model,
         )

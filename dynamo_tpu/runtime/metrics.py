@@ -27,7 +27,9 @@ registration error.  The full metric-name catalog lives in README
 from __future__ import annotations
 
 import threading
-from typing import Any, Dict, Optional, Sequence, Tuple
+import time
+import weakref
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from prometheus_client import (
     CollectorRegistry,
@@ -96,6 +98,17 @@ class MetricsRegistry:
         # rollups stop colliding on identical series names.  Empty dict =
         # exact legacy exposition.
         self.default_labels: Dict[str, str] = {}
+        # bound methods called before every exposition (weakly held: an
+        # engine that is gone takes its hook with it)
+        self._before_render: List["weakref.WeakMethod"] = []
+
+    def before_render(self, method: Callable[[], None]) -> None:
+        """Call ``method`` (a bound method) before every :meth:`render`:
+        for a counter whose current interval is still open when a scrape
+        comes (time spent waiting is counted when the wait ends; a scrape
+        in the middle of one has to see the part that has passed)."""
+        with self._lock:
+            self._before_render.append(weakref.WeakMethod(method))
 
     def set_default_labels(self, **labels: Any) -> None:
         """Replace the render-time identity label set (None values drop
@@ -151,6 +164,15 @@ class MetricsRegistry:
         )
 
     def render(self) -> Tuple[bytes, str]:
+        with self._lock:
+            hooks = [ref() for ref in self._before_render]
+            self._before_render = [
+                ref for ref, fn in zip(self._before_render, hooks)
+                if fn is not None
+            ]
+        for fn in hooks:
+            if fn is not None:
+                fn()
         if self.default_labels:
             view = _LabeledView(self.registry, dict(self.default_labels))
             return generate_latest(view), CONTENT_TYPE_LATEST
@@ -312,6 +334,72 @@ class EngineMetrics:
             "the steps they rode in)",
             buckets=STAGE_BUCKETS,
         )
+        # the dispatch record (ISSUE 41): what the tick loop's commit knows
+        # of every dispatch -- what kind of step, at what packed width, how
+        # long the device served it -- over the whole time the engine runs,
+        # and per request what its first token waited behind.  Always on:
+        # one observe and two adds a committed dispatch, four observes a
+        # request.  The mocker mints the families and observes none.
+        self.dispatch_service = reg.histogram(
+            "dynamo_engine_dispatch_service_seconds",
+            "Time the device spent on one committed dispatch as the host "
+            "reads it (the commit's clock less the later of the dispatch's "
+            "enqueue and the commit before): readings never overlap and "
+            "their sum is exact, one reading is not a device-side duration. "
+            "step: chunk | decode | prefill | decode_block | verify; np: "
+            "the executable's packed rows, 0 where it has none",
+            ["step", "np"],
+            buckets=STEP_LATENCY_BUCKETS,
+        )
+        self.dispatch_steps = reg.counter(
+            "dynamo_engine_dispatch_steps",
+            "Forward passes the committed dispatches ran (a fused decode "
+            "dispatch runs k): service seconds over it is one step's time",
+            ["step", "np"],
+        )
+        self.decode_lane_steps = reg.counter(
+            "dynamo_engine_decode_lane_steps",
+            "Decode-lane token steps (decoding lanes x forward passes) by "
+            "the class of the dispatch they rode in",
+            ["step"],
+        )
+        self.parked_seconds = reg.counter(
+            "dynamo_engine_parked_seconds",
+            "Time the tick loop waited on its wake event with nothing "
+            "runnable (JaxEngine._park), a wait still open at the scrape "
+            "counted up to it",
+        )
+        self.first_token_wait = reg.histogram(
+            "dynamo_engine_first_token_wait_seconds",
+            "A request's first admission -> first token, split by what the "
+            "device served meanwhile: chunk_steps (dispatches that carried "
+            "prefill rows, its own or others'), decode_steps (dispatches "
+            "that carried none), no_dispatch (the rest: no dispatch of "
+            "this engine on the device, or the token's fanout).  The three "
+            "tile dynamo_engine_first_token_service_seconds",
+            ["behind"],
+            buckets=STAGE_BUCKETS,
+        )
+        self.first_token_chunk_rows = reg.counter(
+            "dynamo_engine_first_token_chunk_rows",
+            "Prefill rows committed between a request's first admission "
+            "and its first token: own (its own prompt rows computed) and "
+            "all (every request's); own / all is the share of the chunk "
+            "budget the request got while it waited",
+            ["whose"],
+        )
+        # the record's clock, read when /metrics is rendered: its delta
+        # between two scrapes is the time the counters above are deltas
+        # over, which a client's own stopwatch is not (it stops before or
+        # after the second scrape).  Costs nothing between scrapes.
+        self.clock = reg.gauge(
+            "dynamo_engine_clock_seconds",
+            "time.perf_counter() of the engine's process at this scrape "
+            "(the clock of dynamo_engine_dispatch_service_seconds)",
+        )
+        self.clock.set_function(time.perf_counter)
+        # (step, np) -> its children of the three families above
+        self._service_series: Dict[Tuple[str, int], Tuple[Any, ...]] = {}
         if max_slots:
             self.slots.set(max_slots)
         # a two-kind cache's families (observe_kv_kinds), minted at the
@@ -330,6 +418,38 @@ class EngineMetrics:
 
     def observe_dispatch(self, kind: str) -> None:
         self.dispatches.labels(kind).inc()
+
+    def observe_service(
+        self, step: str, np_rows: int, seconds: float, steps: int,
+        lane_steps: int,
+    ) -> None:
+        """One committed dispatch of class ``step`` at ``np_rows`` packed
+        rows: the device's ``seconds`` on it, the ``steps`` forward passes
+        it ran, and the decode-lane token steps that rode in it."""
+        series = self._service_series.get((step, np_rows))
+        if series is None:
+            series = self._service_series[(step, np_rows)] = (
+                self.dispatch_service.labels(step, str(np_rows)),
+                self.dispatch_steps.labels(step, str(np_rows)),
+                self.decode_lane_steps.labels(step),
+            )
+        series[0].observe(max(seconds, 0.0))
+        series[1].inc(steps)
+        if lane_steps:
+            series[2].inc(lane_steps)
+
+    def observe_first_token_wait(
+        self, chunk_s: float, decode_s: float, idle_s: float,
+        own_rows: int, all_rows: int,
+    ) -> None:
+        """Once a request, at its first token: what it waited behind."""
+        wait = self.first_token_wait
+        wait.labels("chunk_steps").observe(chunk_s)
+        wait.labels("decode_steps").observe(decode_s)
+        wait.labels("no_dispatch").observe(idle_s)
+        rows = self.first_token_chunk_rows
+        rows.labels("own").inc(own_rows)
+        rows.labels("all").inc(all_rows)
 
     def observe_mixed(self, decode_lanes: int, prefill_tokens: int) -> None:
         self.mixed_decode_lanes.observe(decode_lanes)

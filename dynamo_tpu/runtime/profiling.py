@@ -27,7 +27,10 @@ module is that measurement:
   ``jax.profiler.TraceAnnotation`` named ``dyn.tick`` carrying
   ``phase=<name>`` (a ``dispatch`` interval of a packed dispatch also
   carries the lanes' fresh query tokens ``q``, context lengths ``ctx``,
-  the fused step count ``k`` and the packed rows ``np``), and the
+  the fused step count ``k``, the packed rows ``np``, the class of the
+  step ``step`` and the dispatch's serial ``d``; the ``device_wait``
+  interval that fetched it carries the serials ``d`` and the device's
+  service time ``svc_us`` as the commit read it), and the
   interval the loop is parked on its wake event is ``dyn.parked``.  A
   ``jax.profiler`` trace taken meanwhile (``POST /profile/device``) holds
   them beside the device's operations on one timeline, so each idle gap
@@ -109,6 +112,10 @@ class TickRecord:
     # dispatch being enqueued (zero when another was already queued)
     gap_s: float = 0.0
     n_gaps: int = 0
+    # the dispatches this tick committed, one dict each: class of step,
+    # packed rows, fused steps, real rows, and the device's service time
+    # as the commit read it (``JaxEngine._commit_all``)
+    dispatch_records: List[Dict[str, Any]] = field(default_factory=list)
 
     @property
     def host_s(self) -> float:
@@ -130,6 +137,7 @@ class TickRecord:
             "dispatches": dict(self.dispatches),
             "gap_ms": round(self.gap_s * 1e3, 4),
             "n_gaps": self.n_gaps,
+            "dispatch_records": [dict(d) for d in self.dispatch_records],
         }
 
     def to_span_dicts(self) -> List[Dict[str, Any]]:
@@ -216,6 +224,13 @@ class _Tick:
         self.discarded = False
         # the open interval, as an annotation in the jax.profiler trace
         self._ann = annotate(TICK_ANNOTATION)
+
+    @property
+    def annotating(self) -> bool:
+        """Whether the open interval is being written into a
+        ``jax.profiler`` trace: only then is an annotation's metadata
+        worth building."""
+        return self._ann is not None
 
     def mark(self, phase: str, **meta: Any) -> None:
         """Attribute time since the previous mark (or tick start) to

@@ -222,8 +222,8 @@ class span:
     it."""
 
     __slots__ = (
-        "name", "request_id", "parent", "component", "bind", "attrs",
-        "_span", "_token",
+        "name", "request_id", "parent", "component", "bind", "start_s",
+        "attrs", "_span", "_token",
     )
 
     def __init__(
@@ -233,6 +233,7 @@ class span:
         parent: Optional[TraceContext] = None,
         component: Optional[str] = None,
         bind: bool = False,
+        start_s: Optional[float] = None,
         **attrs: Any,
     ) -> None:
         self.name = name
@@ -240,6 +241,9 @@ class span:
         self.parent = parent
         self.component = component
         self.bind = bind
+        # a start already taken (time.monotonic()): the span covers what
+        # ran between that stamp and its opening too
+        self.start_s = start_s
         self.attrs = attrs
         self._span: Optional[Span] = None
         self._token: Optional[contextvars.Token] = None
@@ -255,7 +259,9 @@ class span:
         self._span = Span(
             name=self.name,
             request_id=self.request_id,
-            start_s=time.monotonic(),
+            start_s=(
+                time.monotonic() if self.start_s is None else self.start_s
+            ),
             trace_id=trace_id,
             span_id=span_id,
             parent_span_id=parent.span_id if parent is not None else "",

@@ -408,7 +408,18 @@ def test_unified_dispatches_take_the_whole_page_table(run, monkeypatch):
             marks.append(meta)
         return mark(self, phase, **meta)
 
+    class Open:
+        """An open annotation: the stats are built only while a profiler
+        trace is being taken (``_Tick.annotating``)."""
+
+        def set_metadata(self, **meta):
+            pass
+
+        def __exit__(self, *exc):
+            pass
+
     monkeypatch.setattr(profiling._Tick, "mark", spy_mark)
+    monkeypatch.setattr(profiling, "annotate", lambda name: Open())
     monkeypatch.setattr(profiling.profiler, "enabled", True)
 
     def served(work_list):

@@ -290,6 +290,8 @@ def test_trace_endpoint_returns_the_stage_tree(run, registry, traced,
     root = names["http.request"][0]
     request = names["engine.request"][0]
     assert names["http.preprocess"][0]["parent_span_id"] == root["span_id"]
+    # the root starts where its first child does: the handler's entry
+    assert names["http.preprocess"][0]["start_s"] == root["start_s"]
     assert request["parent_span_id"] == root["span_id"]
     stages = [s for s in spans if s.get("parent_span_id") == request["span_id"]]
     assert sorted(s["name"] for s in stages) == [
@@ -302,6 +304,13 @@ def test_trace_endpoint_returns_the_stage_tree(run, registry, traced,
     prefill = names["engine.prefill"][0]["attrs"]
     assert prefill["chunks"] >= 1 and prefill["prompt_tokens_computed"] >= 1
     assert {"cached", "kv_prefetch_hits", "mixed"} <= set(prefill)
+    # what the first token waited behind: the three parts tile the stage
+    waited = (prefill["in_chunk_steps_ms"] + prefill["in_decode_steps_ms"]
+              + prefill["no_dispatch_ms"])
+    assert waited == pytest.approx(
+        names["engine.prefill"][0]["duration_ms"], rel=0.01, abs=0.01)
+    assert prefill["in_chunk_steps_ms"] > 0
+    assert prefill["chunk_rows_all"] == prefill["prompt_tokens_computed"]
 
 
 def test_preempted_request_spans_tile(run, registry, traced):
@@ -388,6 +397,9 @@ async def _traced_burst(trace_dir, profile):
         if profile:
             profiling.profiler.enable()
             profiling.profiler.clear()
+            # a loop that parked before the profiler went on waits unwatched
+            # (one wait, no slices): wake it, so that it parks again watched
+            engine._wake.set()
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 1
@@ -456,6 +468,24 @@ def test_tick_phases_land_in_the_profiler_trace(run, registry, profiler,
         # fused steps what attends them after the first: here the gather
         assert 1 <= int(s["pt"]) <= 16
         assert s.get("decode") == ("xla" if int(s["k"]) > 1 else None)
+        # the class of the step, and the serial its fetch names again
+        prompt_rows = any(n > 1 for n in q)
+        assert s["step"] == ("chunk" if prompt_rows else "decode")
+        assert int(s["d"]) >= 1
+    serials = [int(s["d"]) for s in shaped]
+    assert serials == sorted(set(serials))
+    # the device_wait interval that fetched a dispatch carries its serial
+    # and the device's service time as the commit read it
+    waits = [s for n, _d, s in events
+             if n == "dyn.tick" and s.get("phase") == "device_wait"]
+    assert waits and all("svc_us" in s and "d" in s for s in waits)
+    fetched = [int(v) for s in waits for v in str(s["d"]).split("|")]
+    assert fetched == sorted(set(fetched)) and set(fetched) <= set(serials)
+    assert sum(int(s["svc_us"]) for s in waits) > 0
+    # and the tick ring holds the same record, commit by commit
+    records = [d for r in recs for d in r.to_dict()["dispatch_records"]]
+    assert len(records) >= len(fetched)
+    assert {d["step"] for d in records} <= {"chunk", "decode"}
 
 
 def test_no_annotation_with_the_profiler_off(run, registry, profiler,
