@@ -90,8 +90,10 @@ def _add_engine_flags(p) -> None:
                         "adaptive, or a fixed integer K)")
     p.add_argument("--multistep-max-k", type=int, default=8,
                    metavar="K",
-                   help="ceiling for the adaptive multi-step decode "
-                        "controller (default 8)")
+                   help="widest block the adaptive multi-step decode "
+                        "controller may fuse (default 8); below it the "
+                        "controller stops at as many steps as hide the "
+                        "tick loop's own work")
     p.add_argument("--no-fold-spec-verify", dest="fold_spec_verify",
                    action="store_false", default=True,
                    help="disable folded speculative verify (spec columns "

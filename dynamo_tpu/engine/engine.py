@@ -23,7 +23,8 @@ import threading
 import time
 from dataclasses import dataclass, field
 from typing import (
-    Any, AsyncIterator, Callable, Dict, List, NamedTuple, Optional, Tuple,
+    Any, AsyncIterator, Callable, Dict, Iterable, List, NamedTuple, Optional,
+    Tuple,
 )
 
 import jax
@@ -55,6 +56,7 @@ from .kv_cache import (
 )
 from .metrics import EngineMetrics
 from .model import Params, init_params
+from .multistep import FusedStepCeiling
 from .sampling import SamplingParams
 from .scheduler import (
     Scheduler,
@@ -357,7 +359,13 @@ class EngineConfig:
     # adapts per tick (engine._multistep_plan_k): prefill/mixed queue
     # pressure, speculating lanes, or pending admissions collapse it to 1
     # (admission/preemption granularity never hurts TTFT); an idle queue
-    # ramps it 1, 2, 4, ... toward ``multistep_max_k``.  Token-identical
+    # ramps it 1, 2, 4, ... toward a ceiling the loop works out from its
+    # own record (ISSUE 42, engine/multistep.py): the smallest such K
+    # whose dispatch outlasts the loop's own work per tick by a fixed
+    # margin, so a 2-ms step still fuses 8 and a 12-18-ms step stops at 2
+    # and an arrival waits behind that much less.  ``multistep_max_k`` is
+    # the widest block an executable exists for (what a warm-up mints and
+    # the page look-ahead covers), not a tuning value.  Token-identical
     # (greedy, seeded, and unseeded-temperature) to K=1 -- the commit
     # replays stop rules over the [B, K] block exactly like decode_block.
     # ``--no-multistep-decode`` / DYN_MULTISTEP=0 pin the exact previous
@@ -1206,6 +1214,26 @@ class JaxEngine:
         # adaptive-K ramp state: consecutive pressure-free ticks double
         # the next block's K toward the ceiling; any pressure resets to 1
         self._ms_ramp = 1
+        # the ramp's ceiling (ISSUE 42, engine/multistep.py): the smallest
+        # block that outlasts the loop's own work per tick, from two means
+        # the loop keeps whether it is watched or not.  Readings count
+        # (``_ms_reads``, fixed at a tick's start) once the ramp has run
+        # the widest block (``_ms_topped``): by then every fused
+        # executable is built, so a warm-up still compiles every minted K
+        # and no compile is read as work.  A tick's reading is its wall
+        # time (``_tick_began`` to the next tick's start) less the time it
+        # sat blocked in commits' fetches (``_fetch_wait``), taken of ticks
+        # that enqueued a decode-only dispatch (``_tick_fused``).  Across
+        # processes commits are lockstep and K has to be the same number
+        # everywhere, which means of local clocks are not: there the
+        # ceiling stays ``multistep_max_k``.
+        self._ms_ceiling = FusedStepCeiling(self._multistep_max)
+        self._ms_local = jax.process_count() == 1
+        self._ms_topped = False
+        self._ms_reads = False
+        self._tick_began = 0.0
+        self._fetch_wait = 0.0
+        self._tick_fused = False
         # acceptance-aware auto-disable knobs (+ request-lifetime counters
         # backing the bench's spec_enabled_frac line)
         self._spec_auto_disable = bool(self.cfg.spec_auto_disable)
@@ -2648,6 +2676,7 @@ class JaxEngine:
                 # attribute check here and a None check per site)
                 tick = prof.begin_tick() if prof.enabled else None
                 self._tick = tick
+                self._read_tick_clock()
                 self._process_cancellations()
                 for work in self._process_deliveries():
                     if work[0] == "blob":
@@ -2906,7 +2935,8 @@ class JaxEngine:
                 # adaptive multi-step K (ISSUE 16): chunk/spec/admission
                 # pressure collapses the next packed block to one step
                 # (TTFT granularity); a pressure-free tick ramps K toward
-                # the ceiling and fuses the whole block into one dispatch
+                # the ceiling (ISSUE 42: as many steps as hide the loop's
+                # own work) and fuses the whole block into one dispatch
                 ms_k = (
                     self._multistep_plan_k(chunks, spec_reserve)
                     if self._multistep and mixed_ok
@@ -2940,6 +2970,9 @@ class JaxEngine:
                     )
                     if ub is not None:
                         fresh.append(ub)
+                        self._tick_fused = True
+                        if ub.n_steps >= self._multistep_max:
+                            self._ms_topped = True
                 if ub is None and (
                     # no unified dispatch went out (or the spec candidates
                     # vanished between the loop-thread check and the
@@ -3132,18 +3165,24 @@ class JaxEngine:
                 self._chunking.append(seq)
         self.sched.mix_pending = []
 
+    def _decode_steps_of(self, pending: Iterable[Any]) -> int:
+        """The most decode steps the dispatches in ``pending`` advance any
+        one lane by."""
+        steps = 0
+        for e in pending:
+            if isinstance(e, InflightBlock):
+                steps += self.cfg.decode_block_size
+            elif isinstance(e, InflightUnified):
+                steps += e.n_steps
+        return steps
+
     def _has_steppable_lane(self, pending: List[Any]) -> bool:
         """Whether any decode-runnable lane can still absorb a token once
         the in-flight work lands -- the guard that skips the decode
         dispatch on ticks that could only launch dead rows (e.g. the tail
         tick after every lane's token budget went in-flight: the old loop
         paid one wasted all-dead block per batch completion there)."""
-        inflight = 0
-        for e in pending:
-            if isinstance(e, InflightBlock):
-                inflight += self.cfg.decode_block_size
-            elif isinstance(e, InflightUnified):
-                inflight += e.n_steps
+        inflight = self._decode_steps_of(pending)
         sched = self.sched
         limits = self._compute_limits()
         for b, s in enumerate(sched.slots):
@@ -3209,10 +3248,21 @@ class JaxEngine:
           and would race the chunk machinery's KV writes.
         * **Fixed mode** (``DYN_MULTISTEP=<N>``) returns N whenever
           pressure-free -- the bench/ablation pin.
-        * **Adaptive mode** ramps K geometrically (1, 2, 4, ... up to
-          ``multistep_max_k``) per consecutive pressure-free tick.  It
-          reads nothing the tick profiler or the span collector holds:
-          the engine serves the same whether it is watched or not.
+        * **Adaptive mode** ramps K geometrically (1, 2, 4, ...) per
+          consecutive pressure-free tick, up to a ceiling: the smallest
+          such K, at most ``multistep_max_k``, whose dispatch outlasts the
+          loop's own work per tick by ``multistep.MARGIN`` (ISSUE 42).
+          Fusing more than hides that work buys nothing, and a request
+          admitted beside decoding lanes waits behind every fused step of
+          the running dispatch and of the one queued behind it.  The two
+          means the ceiling is worked out from are kept always on: a
+          decode step's service from the dispatch record
+          (``_record_service``), a decode-only tick's wall time less its
+          blocked fetches from ``_read_tick_clock``.  Nothing here reads
+          what the tick profiler or the span collector holds: the engine
+          serves the same whether it is watched or not.  Until the widest
+          block has run once (``_ms_topped``) the ceiling is
+          ``multistep_max_k``, as it is across processes (``_ms_local``).
 
         The ramp (rather than an instant max) bounds the worst-case
         tokens a mid-block cancel/deadline discards right after a busy
@@ -3237,9 +3287,33 @@ class JaxEngine:
             return 1
         if self._multistep_fixed is not None:
             return self._multistep_fixed
-        k = min(self._ms_ramp, self._multistep_max)
-        self._ms_ramp = min(self._ms_ramp * 2, self._multistep_max)
+        ceiling = self._multistep_max
+        if self._ms_topped and self._ms_local:
+            ceiling = self._ms_ceiling.value()
+        self.obs.observe_multistep_ceiling(
+            ceiling, self._ms_ceiling.step_s, self._ms_ceiling.loop_s
+        )
+        k = min(self._ms_ramp, ceiling)
+        self._ms_ramp = min(self._ms_ramp * 2, ceiling)
         return k
+
+    def _read_tick_clock(self) -> None:
+        """The tick loop's one always-on clock read, at each iteration's
+        start: closes the iteration before (if it enqueued a decode-only
+        dispatch and its readings count, its wall time less its blocked
+        fetches is one reading of the loop's own work a tick, for the
+        fused-step ceiling) and opens this one.  A parked or empty
+        iteration enqueues nothing and is no reading."""
+        # dynalint: disable=DT012 -- once a tick, profiler on or off
+        now = time.perf_counter()
+        if self._tick_fused and self._ms_reads:
+            self._ms_ceiling.observe_loop(
+                now - self._tick_began - self._fetch_wait
+            )
+        self._tick_began = now
+        self._fetch_wait = 0.0
+        self._tick_fused = False
+        self._ms_reads = self._ms_topped
 
     def _handle_stalled_admission(self) -> None:
         """Nothing running, nothing admitted: requests whose prompts can never
@@ -4095,6 +4169,21 @@ class JaxEngine:
             # left as the device carry: paused lanes revive through
             # _revive_paused_lanes marking them dirty.
             limit = self._compute_limits()
+            # a lane that the dispatches still in flight may carry to the
+            # limit they were issued under pauses there on the device.
+            # Raised now, the host's copy would read the new limit before
+            # the mirror reaches the old one, _revive_paused_lanes would
+            # never see the pause and the lane would never run again (short
+            # fused blocks walk a lane up to its limit a step or two at a
+            # time, so the raise meets it there).  Such a lane keeps its
+            # limit, pauses, and its revival folds the raise in with its row.
+            ahead = self._decode_steps_of(
+                e for gen in self._inflight or () for e in gen
+            )
+            heading = sched.seq_lens + ahead >= self._limit_host
+            limit = np.where(
+                heading, np.minimum(limit, self._limit_host), limit
+            )
             # numpy copy for the same aliasing reason as _push_device_state
             self._dev["page_table"] = self._put_batch(sched.page_table.copy())
             self._dev["limit_lens"] = self._put_batch(limit)
@@ -5324,6 +5413,8 @@ class JaxEngine:
                 chunk_s += share
             else:
                 decode_s += share
+            if a.step == "decode" and self._ms_reads:
+                self._ms_ceiling.observe_step(share / a.steps)
             rows += a.prefill_rows
             if tick is not None:
                 tick.record.dispatch_records.append({
@@ -5403,6 +5494,10 @@ class JaxEngine:
                 if pf.prompt_lp is not None:
                     lp_refs.append((pf, len(handles)))
                     handles.append(pf.prompt_lp)
+        # dynalint: disable=DT012 -- with the commit clock below, the time
+        # this tick sat blocked in the fetch (the fused-step ceiling's
+        # reading of the loop's own work leaves it out)
+        fetch_began = time.perf_counter()
         if jax.process_count() > 1:
             # multi-host mesh (v5e pod): a batch-sharded result's shards
             # live partly on other processes, so a plain device_get raises
@@ -5425,6 +5520,7 @@ class JaxEngine:
         # entry's dispatch->commit latency observe (dynamo_engine_step_latency)
         # and the dispatch record's service time
         now = time.perf_counter()
+        self._fetch_wait += now - fetch_began
         # the device's time on what this commit fetched: from the later of
         # its enqueue and the commit before, which it queued behind
         service = max(

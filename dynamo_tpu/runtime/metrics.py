@@ -307,10 +307,21 @@ class EngineMetrics:
         )
         # multi-step decode (ISSUE 16): decode iterations fused into the
         # last packed dispatch -- 1 = single-step (pressure or disabled),
-        # up to multistep_max_k when the adaptive controller opens up
+        # up to the controller's ceiling (below) when it opens up
         self.multistep_k = reg.gauge(
             "dynamo_engine_multistep_k",
             "Decode steps fused into the last packed unified dispatch",
+        )
+        # the ramp's ceiling (ISSUE 42) and the two running means it is
+        # worked out from, as the controller last read them
+        self.multistep_ceiling = reg.gauge(
+            "dynamo_engine_multistep_ceiling",
+            "Most decode steps a pressure-free tick may fuse right now",
+        )
+        self.multistep_reading = reg.gauge(
+            "dynamo_engine_multistep_reading_seconds",
+            "Running means the fused-step ceiling is worked out from",
+            ["of"],
         )
         # request stages (ISSUE 26): a request's time to its first token,
         # split where each leg ends and observed once per request --
@@ -516,6 +527,15 @@ class EngineMetrics:
 
     def observe_multistep_k(self, k: int) -> None:
         self.multistep_k.set(k)
+
+    def observe_multistep_ceiling(
+        self, ceiling: int, step_s: Optional[float], loop_s: Optional[float]
+    ) -> None:
+        self.multistep_ceiling.set(ceiling)
+        if step_s is not None:
+            self.multistep_reading.labels("decode_step").set(step_s)
+        if loop_s is not None:
+            self.multistep_reading.labels("loop_tick").set(loop_s)
 
 
 class OffloadMetrics:

@@ -249,7 +249,8 @@ def test_parked_seconds_advance_only_while_parked(run, registry):
 
 def test_nothing_schedules_by_the_record():
     """The scheduler copies the record onto a request and reads nothing of
-    it; the fused-step controller does not know it exists."""
+    it; the fused-step controller reads the one running mean the record's
+    update hands it (a decode step's service, ISSUE 42), not the record."""
     plan_k = inspect.getsource(JaxEngine._multistep_plan_k)
     for name in ("_served", "_service_mark", "first_token_wait",
                  "served_at_admission", "_inflight"):
