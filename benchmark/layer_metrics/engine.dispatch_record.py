@@ -6,7 +6,7 @@ none), the executable's packed rows and the time the device spent on it as
 the host reads it (this commit's clock less the later of the dispatch's
 enqueue and the commit before: no two readings overlap, and their sum is at
 most the wall time); and once a request, what its first token waited behind.
-One reader file for eight metrics; each metric's json names its function.
+One reader file for nine metrics; each metric's json names its function.
 A program without the families (or a mocker that mints them and observes
 nothing) gives nothing."""
 import json
@@ -41,6 +41,15 @@ def decode_step_mean_ms(ctx):
     c = ctx["counters"]
     return _ratio(c.delta(SERVICE + "_sum", step="decode"),
                   c.delta(STEPS, step="decode"), 1e3)
+
+
+def decode_steps_per_dispatch(ctx):
+    """Forward passes a decode-only dispatch ran, in the mean: how long the
+    fused block is that a request admitted beside decoding lanes waits
+    behind (1 to ``multistep_max_k``; PR 42's ceiling keeps it short)."""
+    c = ctx["counters"]
+    return _ratio(c.delta(STEPS, step="decode"),
+                  c.delta(SERVICE + "_count", step="decode"), 1.0)
 
 
 def _first_token_share(ctx, behind):

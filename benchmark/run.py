@@ -344,10 +344,14 @@ async def check_logits(load: Load, cfg, spec, seed, vocab) -> Dict[str, Any]:
 # -- per-layer readers -------------------------------------------------------------
 
 
-def read_layer_metrics(bench, cell_name: str, ctx: Dict[str, Any]) -> Dict[str, Any]:
+def read_layer_metrics(bench, cell_name: str, ctx: Dict[str, Any],
+                       source: Optional[str] = None) -> Dict[str, Any]:
+    """The cell's per-layer metrics; with ``source``, those of that source."""
     out = {}
     for m in bench["per_layer"]:
         if "workloads" in m and cell_name not in m["workloads"]:
+            continue
+        if source is not None and m["source"] != source:
             continue
         # the metric's own file names its reader, "<file>.py:<function>"
         file, _, func = load_json("benchmark", "layer_metrics", m["name"] + ".json")[
@@ -532,7 +536,7 @@ async def drive(args, bench, cell, cfg, spec, ready) -> Dict[str, Any]:
                        warm_traffic_s=start - t_traffic)
     log("info " + json.dumps({
         "setup_parts": setup_parts, "requests_sent": len(results),
-        "in_window": len(in_window),
+        "in_window": len(in_window), "due_in_window": len(lates),
         "finished_in_window": sum(
             1 for r in results if r["finished"] and start <= r["finished"] < end),
         "compiles_in_window": compiles, "generator_late_p99_s": late_p99,
@@ -545,11 +549,22 @@ async def drive(args, bench, cell, cfg, spec, ready) -> Dict[str, Any]:
     device = dict(state1["device"], memory_peak_bytes=state1["memory_peak_bytes"])
     wanted = [m["name"] for m in bench["end_to_end"]
               if "workloads" not in m or cell["name"] in m["workloads"]]
+    counters = stats.Counters(marks["metrics0"], metrics1)
     if not args.trace:
         metrics = {}
         for m in bench["end_to_end"]:
             if m["name"] in wanted and m["name"] in e2e:
                 metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
+        # what tells two runs of one tree apart (a stall of the machine, the
+        # fused block's ceiling, one request more at the window's edge) is in
+        # the window's counters, which an untraced run has too: printed, not
+        # reported.  ``planes``: no trace of an earlier run is read for it
+        ctx = layer_context(
+            cfg, spec, cell, counters=counters,
+            window_s=end - start, compiles=compiles, planes=[], end_to_end=e2e)
+        log("info " + json.dumps({"window_counters": {
+            k: v["value"] for k, v in read_layer_metrics(
+                bench, cell["name"], ctx, "program_counter").items()}}))
         out = {"correct": correct, "attempted": len(in_window), "failed": len(failed),
                "metrics": metrics, "device": device}
     else:
@@ -558,8 +573,7 @@ async def drive(args, bench, cell, cfg, spec, ready) -> Dict[str, Any]:
             {"dump": args.dump_trace or None}, timeout=300)
         ctx = layer_context(
             cfg, spec, cell,
-            counters=stats.Counters(marks["metrics0"], metrics1),
-            profiler=state1.get("profiler"), trace=trace,
+            counters=counters, profiler=state1.get("profiler"), trace=trace,
             # the trace runs on until stop_trace returns: where the device
             # was busy at both ends, its own span is the window
             trace_window_s=max(marks.get("trace", {}).get("window_s", 0.0),

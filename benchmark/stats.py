@@ -95,6 +95,48 @@ def end_to_end(
     return out
 
 
+HALVES_SLACK = 1.25  # the second half may wait this many times as long as the first
+HALVES_FLOOR_MS = 500.0  # or this much longer: under it the schedule, not a queue
+
+
+def rate_held(line: Dict[str, Any]) -> bool:
+    """Whether the system kept up with one rate of a sweep (one printed
+    ``sweep`` line of ``run.py --sweep``): no request failed or was left
+    without a first token, and the requests due in the second half of the
+    window waited no longer at the median than those of the first (a queue
+    that grows through the window is past capacity).  "No longer" has room
+    for what the schedule puts into either half: ``HALVES_SLACK`` times the
+    first half's median, or ``HALVES_FLOOR_MS`` more than it (lightly loaded,
+    longdoc-open read 69 against 249 ms at 1.5 requests/s and 494 against
+    107 at 2.0; a queue that outgrows capacity by 2% of the arrivals puts
+    half a second between the halves of a 50-s window)."""
+    first, second = line["ttft_p50_first_half_ms"], line["ttft_p50_second_half_ms"]
+    return (not line["failed"] and not line["no_first_token"]
+            and first is not None and second is not None
+            and second <= max(HALVES_SLACK * first, first + HALVES_FLOOR_MS))
+
+
+def knee(lines: Iterable[Dict[str, Any]]) -> Optional[float]:
+    """The knee of a sweep, by the rule PRs 30 and 36 used: the highest rate
+    swept at which ``out_tok_s`` still rises over the rate below and which
+    held (``rate_held``), every lower rate having held too.  ``None`` where
+    the lowest rate swept did not hold: sweep lower."""
+    best = prev = None
+    for line in sorted(lines, key=lambda l: l["rate_per_s"]):
+        if not rate_held(line):
+            break
+        if prev is None or line["out_tok_s"] > prev:
+            best = line["rate_per_s"]
+        prev = line["out_tok_s"]
+    return best
+
+
+def pitch(knee_per_s: float, share: float = 0.75, step: float = 0.05) -> float:
+    """The rate a cell runs at: ``share`` of its knee, rounded down to a
+    multiple of ``step``."""
+    return round(math.floor(knee_per_s * share / step + 1e-9) * step, 6)
+
+
 _SAMPLE = re.compile(r"^([a-zA-Z_:][a-zA-Z0-9_:]*)(\{[^}]*\})?\s+(\S+)")
 _LABEL = re.compile(r'([a-zA-Z_][a-zA-Z0-9_]*)="((?:[^"\\]|\\.)*)"')
 

@@ -12,7 +12,8 @@ phase, under ``dyn.parked``, and under nothing.
 The ``dispatch`` interval of a packed dispatch also carries what the
 attention kernels were asked to do (per lane the fresh query rows ``q`` and
 the context ``ctx`` its last row reads, ``|``-joined; the fused steps ``k``;
-the packed rows ``np``): ``dispatches`` below, for the roofline reader.
+the packed rows ``np``; the class ``step``, ``chunk`` with prefill rows and
+``decode`` without): ``dispatches`` below, for the roofline readers.
 
 A trace from a program without the annotations gives ``None``: the readers
 then report nothing.  JAX is imported inside ``load`` only, with
@@ -108,10 +109,15 @@ def idle_by_host(planes) -> Optional[Dict[str, Any]]:
                     stats = dict(ev.stats)
                     phases.setdefault(str(stats.get("phase")), []).append(_seconds(ev))
                     if "q" in stats:
+                        q = [int(v) for v in str(stats["q"]).split("|")]
                         dispatches.append({
-                            "q": [int(v) for v in str(stats["q"]).split("|")],
+                            "q": q,
                             "ctx": [int(v) for v in str(stats["ctx"]).split("|")],
                             "k": int(stats.get("k", 1)), "np": int(stats.get("np", 0)),
+                            # the class the program states; a trace from
+                            # before PR 41 has none: no lane with two rows
+                            "step": str(stats.get("step") or (
+                                "chunk" if max(q) > 1 else "decode")),
                             "start_s": _seconds(ev)[0]})
     devices = [d for d in devices if d]
     if not devices or not (phases or parked):

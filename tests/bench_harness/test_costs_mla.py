@@ -121,6 +121,54 @@ def test_latent_decode_reader_counts_the_fused_steps():
     assert mod.read(_ctx({"a": (PACKED, 6, 1.0)})) is None
 
 
+def _decode_least():
+    return costs.roofline_seconds(*costs_mla.decode_launch([9001, 16385], CFG), PEAK)[0]
+
+
+@pytest.mark.parametrize("ops, want", [
+    # the packed kernel alone, in the executable of one row a lane: a
+    # decode-only dispatch of one step, as nearly all are since PR 42
+    ({"b": (PACKED_DECODE, 6, 6 * 4.0)}, 25.0),
+    # the fused steps' kernel alone: 7 steps annotated, 42 events
+    ({"d": (DECODE, 42, 42 * 5.0)}, 20.0),
+    # both: (6 + 42) least times over 6 x 4 + 42 x 5
+    ({"b": (PACKED_DECODE, 6, 6 * 4.0), "d": (DECODE, 42, 42 * 5.0)}, 100.0 * 48 / 234),
+    # a chunk's and a question's launches are the other metric's: not this one's
+    ({"b": (PACKED_DECODE, 6, 6 * 4.0), "a": (PACKED, 6, 1.0),
+      "q": (PACKED.replace("131072", "8192"), 6, 1.0)}, 25.0),
+    # faster than the least time reads over 100: no cap
+    ({"b": (PACKED_DECODE, 6, 6 * 0.5)}, 200.0),
+], ids=["packed", "fused", "both", "beside_chunk_and_question", "no_cap"])
+def test_latent_decode_reader_reads_whichever_kernel_served(ops, want):
+    """Seconds are given in least times of the decode launch, the same count
+    for either kernel: one query row a lane against its context."""
+    least = _decode_least()
+    ops = {k: (text, n, sec * least) for k, (text, n, sec) in ops.items()}
+    mod = _reader("kernel.latent_decode_roofline.py")
+    assert mod.read(_ctx(ops)) == pytest.approx(want)
+
+
+def test_latent_decode_reader_counts_one_least_time_for_both_kernels():
+    first, _ = costs.roofline_seconds(
+        *costs_mla.absorbed_launch([1, 1], [9001, 16385], CFG), PEAK)
+    assert first == _decode_least()
+
+
+def test_latent_decode_reader_takes_the_class_the_program_states():
+    """A dispatch the program calls a chunk step (a prefill row of one
+    token in the one-row executable) is not decode attention, and leaves
+    nothing to read where it is the only one."""
+    ctx = _ctx({"b": (PACKED_DECODE, 6, 1.0)})
+    for ev in ctx["planes"][1].lines[0].events:
+        ev.stats.append(("step", "chunk"))
+    mod = _reader("kernel.latent_decode_roofline.py")
+    assert mod.read(ctx) is None
+    ctx = _ctx({"b": (PACKED_DECODE, 6, 6 * 4.0 * _decode_least())})
+    for ev in ctx["planes"][1].lines[0].events:
+        ev.stats.append(("step", "decode" if dict(ev.stats)["k"] == 8 else "chunk"))
+    assert mod.read(ctx) == pytest.approx(25.0)
+
+
 def test_held_experts_reached_by_a_question_and_by_a_chunk():
     rows, experts = costs_mla.held_rows_and_experts(60, CFG)
     assert rows == 60 * 4 * 32 / 128 == 60.0

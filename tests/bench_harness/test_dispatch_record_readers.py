@@ -1,4 +1,4 @@
-"""The eight readers of the tick loop's dispatch record
+"""The nine readers of the tick loop's dispatch record
 (``benchmark/layer_metrics/engine.dispatch_record.py``) on canned
 ``/metrics`` bodies: a number from a program that keeps the record, nothing
 from one that does not (the parent) or that mints the families and observes
@@ -73,6 +73,7 @@ MOCKER = tuple(
 EXPECTED = {
     "chunk_step_mean_ms": 25.0,           # 2.5 s over 100 chunk steps
     "decode_step_mean_ms": 10.0,          # 10 s over 1000 forward passes
+    "decode_steps_per_dispatch": 4.0,     # 1000 forward passes in 250 dispatches
     "first_token_in_chunk_steps": 60.0,   # 6 of 10 s
     "first_token_in_decode_steps": 30.0,
     "first_token_own_rows": 25.0,         # 2000 of 8000 rows
@@ -110,18 +111,22 @@ def test_every_metric_of_the_file_is_in_benchmark_json():
     root = os.path.dirname(os.path.dirname(os.path.dirname(READER)))
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         names = [m["name"] for m in json.load(f)["per_layer"]]
-    funcs = set()
+    funcs, served = set(), set()
     for name in names:
         with open(os.path.join(os.path.dirname(READER), name + ".json")) as f:
             file, _, func = json.load(f)["reader"].partition(":")
         if file == os.path.basename(READER):
             funcs.add(func)
+            served.add(name)
     assert funcs == set(EXPECTED)
-    assert names[-8:] == [
+    # the set the file's readers serve, not the tail of the list: a later PR
+    # appends entries of its own behind them
+    assert served == {
         "step.chunk_step_mean_ms", "step.decode_step_mean_ms",
         "tick.first_token_in_chunk_steps_pct", "tick.first_token_in_decode_steps_pct",
         "sched.first_token_own_rows_pct", "sched.decode_rows_in_chunk_steps_pct",
-        "tick.packed_rows_used_pct", "tick.loop_held_device_pct"]
+        "tick.packed_rows_used_pct", "tick.loop_held_device_pct",
+        "tick.decode_steps_per_dispatch"}
 
 
 def test_without_the_programs_clock_the_harness_window_stands():
@@ -185,6 +190,7 @@ def test_the_engines_own_exposition_has_the_names_the_readers_ask_for():
     r = _reader()
     assert r.chunk_step_mean_ms(ctx) == pytest.approx(25.0)
     assert r.decode_step_mean_ms(ctx) == pytest.approx(10.0)
+    assert r.decode_steps_per_dispatch(ctx) == pytest.approx(8.0)
     assert r.first_token_in_chunk_steps(ctx) == pytest.approx(100 * 0.2 / 0.35)
     assert r.first_token_in_decode_steps(ctx) == pytest.approx(100 * 0.1 / 0.35)
     assert r.first_token_own_rows(ctx) == pytest.approx(25.0)

@@ -44,6 +44,11 @@ def test_a_new_cell_rehearses_to_its_result_line(cell, metrics):
     logits = next(i["logits"] for i in info if "logits" in i)
     assert logits["positions"] >= 96
     assert logits["logprob_err"] < 1e-4, logits
+    # an untraced run prints the window's counters beside its result (what
+    # tells two runs of one tree apart): the cell's own, and numbers only
+    counters = next(i["window_counters"] for i in info if "window_counters" in i)
+    assert "step.compiles_in_window" in counters
+    assert all(isinstance(v, float) for v in counters.values())
     if cell.startswith("mistral-small-4"):
         assert len(logits["prompt_tokens"]) == 3  # the longest again, from the cache
         assert logits["prompt_tokens"][0] == logits["prompt_tokens"][2]

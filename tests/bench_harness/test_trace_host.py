@@ -71,7 +71,8 @@ def test_gaps_go_to_what_the_host_was_doing():
     assert t["idle_no_annotation_s"] == pytest.approx(0.75)
     assert t["busy_while_parked_s"] == 0.0
     assert t["dispatches"] == [
-        {"q": [1, 512], "ctx": [900, 4608], "k": 1, "np": 1024, "start_s": 3.5}]
+        {"q": [1, 512], "ctx": [900, 4608], "k": 1, "np": 1024, "step": "chunk",
+             "start_s": 3.5}]
     parts = (t["idle_host_work_s"] + t["idle_in_wait_s"] + t["idle_parked_s"]
              + t["idle_no_annotation_s"])
     assert parts == pytest.approx(t["idle_s"])
