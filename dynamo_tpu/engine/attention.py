@@ -30,6 +30,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..analysis.hotpath import hot_path
 from .kv_cache import (
+    ConvKV,
     KindKV,
     QuantKV,
     gather_layer_kv,
@@ -85,6 +86,19 @@ def layer_view(cfg, kv_pages, page_table, layer, kind) -> LayerView:
             kv_pages, page_table, layer, cfg.sliding_window or 0,
             lambda kv: kv, "",
         )
+    if cfg.has_conv:
+        # an attention layer beside convolution layers: ``layer`` is its
+        # index among the attention layers already (model.scan_layers
+        # counts it), and the pair pool holds those alone
+        if not isinstance(kv_pages, ConvKV):  # a step that writes no cache
+            return LayerView(kv_pages, page_table, layer, 0, lambda kv: kv, "")
+        return LayerView(
+            kv_pages.attn, page_table, layer, 0,
+            lambda pool: ConvKV(pool, kv_pages.lanes, kv_pages.pages),
+            # heads narrower than the 128 lanes (config.kv_head_pack): the
+            # launches over them say so in a device trace
+            "_narrow" if cfg.head_dim % 128 else "",
+        )
     pattern = cfg.layer_pattern
     rank, seen = [], 0
     for k in pattern:
@@ -104,6 +118,129 @@ def layer_view(cfg, kv_pages, page_table, layer, kind) -> LayerView:
         kv_pages.of(kind), table, idx, cfg.kind_window(kind),
         lambda pool: kv_pages.replace(kind, pool),
         "" if kind == "full" else "_window",
+    )
+
+
+# -- convolution layers (kv_cache.ConvKV) -------------------------------------
+#
+# The two calls below are all a gated short-convolution layer asks of a step:
+# mix every row with its two predecessors, and leave behind what the next
+# step needs (the lane's last two rows) and what a prefix hit needs (the
+# rows at a page's last two positions, under the page's id).
+
+
+def _conv_predecessors(state: ConvKV, layer, page_table, first):
+    """``[B, 2, H]``: the rows at positions ``first - 2`` and ``first - 1``
+    of each lane's sequence.  Zeros where the segment starts the sequence;
+    the snapshot of the page that ends at ``first`` where it starts a page
+    (a prefix hit resumes there, and a lane walking on reads back the rows
+    it wrote); the lane's own rows otherwise."""
+    B, P = page_table.shape
+    page = kv_data(state).shape[3]
+    lanes = jax.lax.dynamic_index_in_dim(state.lanes, layer, 0, False)
+    lanes = lanes.reshape(B, 2, -1)
+    before = jnp.clip(first // page - 1, 0, P - 1)
+    ids = jnp.take_along_axis(page_table, before[:, None], axis=1)[:, 0]
+    both = 2 * ids[:, None] + jnp.arange(2)  # a page's two rows
+    snap = state.pages[layer, both]  # [B, 2, H]
+    prev = jnp.where((first % page == 0)[:, None, None], snap, lanes)
+    return jnp.where((first == 0)[:, None, None], 0, prev), lanes
+
+
+def _conv_taps(taps, z2, z1, z):
+    """``w_0 z_{t-2} + w_1 z_{t-1} + w_2 z_t`` a channel, summed in float32."""
+    w = taps.astype(jnp.float32)
+    f = jnp.float32
+    return (w[0] * z2.astype(f) + w[1] * z1.astype(f) + w[2] * z.astype(f)).astype(
+        z.dtype
+    )
+
+
+def _conv_snapshot(state: ConvKV, layer, lanes, z, page_table, lane, pos, ok):
+    """Put the lanes' new rows back, and of ``z`` the rows at a page's last
+    two positions under that page's id (others to the trash page)."""
+    P = page_table.shape[1]
+    page = kv_data(state).shape[3]
+    page_idx = pos // page
+    ok = ok & (pos % page >= page - 2) & (page_idx < P)
+    ids = page_table[lane, jnp.clip(page_idx, 0, P - 1)]
+    row = jnp.where(ok, 2 * ids + pos % page - (page - 2), 0)
+    return ConvKV(
+        state.attn,
+        state.lanes.at[layer].set(
+            lanes.reshape(state.lanes.shape[1:]).astype(state.lanes.dtype)
+        ),
+        state.pages.at[layer, row].set(z.astype(state.pages.dtype)),
+    )
+
+
+@hot_path
+def packed_conv_mix(
+    z: jax.Array,  # [Np, H] packed rows of B (.) X
+    taps: jax.Array,  # [3, H]
+    state: ConvKV,
+    layer: jax.Array,  # index among the convolution layers
+    page_table: jax.Array,  # [B, P]
+    base: jax.Array,  # [B] position of a lane's first row
+    seg_off: jax.Array,  # [B]
+    q_lens: jax.Array,  # [B] rows per lane (0 = no segment)
+    lane: jax.Array,  # [Np] lane per packed row (B = padding)
+    pos: jax.Array,  # [Np]
+    valid: jax.Array,  # [Np]
+):
+    """The packed step's convolution: a row's predecessors are the rows
+    before it in its segment, else what the lane carries in.  Returns the
+    mixed rows and the state with each live lane's last two rows and the
+    page snapshots written; padding and dead lanes write nothing read."""
+    Np = z.shape[0]
+    B = base.shape[0]
+    prev, lanes = _conv_predecessors(state, layer, page_table, base)
+    live = q_lens > 0
+    drop = jnp.full((B,), Np, jnp.int32)
+    at0 = jnp.where(live, seg_off, drop)
+    at1 = jnp.where(q_lens > 1, seg_off + 1, drop)
+    put = dict(mode="drop", unique_indices=True)
+    z1 = jnp.roll(z, 1, axis=0).at[at0].set(prev[:, 1], **put)
+    z2 = jnp.roll(z, 2, axis=0).at[at0].set(prev[:, 0], **put)
+    z2 = z2.at[at1].set(prev[:, 1], **put)
+    last = jnp.clip(seg_off + q_lens - 1, 0, Np - 1)
+    new = jnp.stack(
+        [
+            jnp.where(
+                (q_lens > 1)[:, None], z[jnp.clip(last - 1, 0, Np - 1)],
+                prev[:, 1],
+            ),
+            z[last],
+        ],
+        axis=1,
+    )
+    lanes = jnp.where(live[:, None, None], new, lanes)
+    lane_c = jnp.clip(lane.astype(jnp.int32), 0, B - 1)
+    ok = valid & (lane.astype(jnp.int32) < B)
+    return _conv_taps(taps, z2, z1, z), _conv_snapshot(
+        state, layer, lanes, z, page_table, lane_c, pos, ok
+    )
+
+
+@hot_path
+def decode_conv_mix(
+    z: jax.Array,  # [B, H] one row a lane
+    taps: jax.Array,  # [3, H]
+    state: ConvKV,
+    layer: jax.Array,
+    page_table: jax.Array,  # [B, P]
+    positions: jax.Array,  # [B] the row's position
+    active: jax.Array,  # [B] bool: lanes the step advances
+):
+    """A decode step's convolution.  Only an ``active`` lane's rows move: a
+    frozen lane runs the step over again on the same token (its K/V write
+    is the same write; a shift of its two rows would not be)."""
+    B = z.shape[0]
+    prev, lanes = _conv_predecessors(state, layer, page_table, positions)
+    new = jnp.stack([prev[:, 1], z], axis=1)
+    lanes = jnp.where(active[:, None, None], new, lanes)
+    return _conv_taps(taps, prev[:, 0], prev[:, 1], z), _conv_snapshot(
+        state, layer, lanes, z, page_table, jnp.arange(B), positions, active
     )
 
 

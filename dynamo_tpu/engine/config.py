@@ -93,6 +93,23 @@ class ModelConfig:
     # RoPE by kind of layer, ``((kind, theta, rope_scaling), ...)``; None =
     # ``rope_theta`` / ``rope_scaling`` for every layer
     rope_by_kind: Optional[Tuple[Tuple[str, float, Optional[tuple]], ...]] = None
+    # gated short-convolution layers (lfm2_moe): a ``layer_pattern`` entry
+    # "conv" is a layer whose operator mixes a token with its two
+    # predecessors by a depthwise 3-tap filter over ``B (.) X`` and gates the
+    # result (model._conv_operator) instead of attending.  Such a layer
+    # touches no page: the cache holds the attention layers only, and beside
+    # them the two rows a sequence carries (kv_cache.ConvKV)
+    #
+    # layers in front of the periods (``num_dense_layers``): their kinds, in
+    # order; each has a dense SwiGLU of ``lead_intermediate_size`` where the
+    # periods' layers have experts.  ``num_layers`` counts them too
+    lead_pattern: Optional[Tuple[str, ...]] = None
+    lead_intermediate_size: int = 0
+    # the router's score: "softmax" over the chosen logits, or "sigmoid" of
+    # every logit, the chosen renormalised by their sum; ``router_bias``: a
+    # per-expert bias added for the choice only, never to a weight
+    router_score: str = "softmax"
+    router_bias: bool = False
     # activation dtype for compute; params may be stored differently
     dtype: str = "bfloat16"
 
@@ -111,12 +128,27 @@ class ModelConfig:
     @property
     def two_kind(self) -> bool:
         """Window layers and full layers in one trunk: two pools of pages."""
-        return self.layer_pattern is not None and len(set(self.layer_pattern)) > 1
+        p = self.layer_pattern or ()
+        return "sliding" in p and "full" in p
+
+    @property
+    def has_conv(self) -> bool:
+        """Convolution layers in the trunk: state beside the pages."""
+        return "conv" in (self.layer_pattern or ()) + (self.lead_pattern or ())
+
+    @property
+    def lead_layers(self) -> int:
+        return len(self.lead_pattern or ())
+
+    def lead_kind_layers(self, kind: str) -> int:
+        """Layers of ``kind`` in front of the periods."""
+        return (self.lead_pattern or ()).count(kind)
 
     def kind_layers(self, kind: str) -> int:
         """Layers of ``kind`` in the whole trunk."""
         p = self.layer_pattern
-        return self.num_layers // len(p) * p.count(kind)
+        periods = (self.num_layers - self.lead_layers) // len(p)
+        return self.lead_kind_layers(kind) + periods * p.count(kind)
 
     def kind_window(self, kind: Optional[str]) -> int:
         """The window a layer of ``kind`` attends (0 = every key); ``None``
@@ -148,7 +180,42 @@ class ModelConfig:
         if self.is_mla:
             row = self.kv_lora_rank + self.qk_rope_head_dim
             return -(-self.num_layers // 2), 1, 1, 2 * row
+        if self.has_conv:  # the attention layers alone hold pages
+            return (self.kind_layers("full"), 2, self.pool_kv_heads,
+                    self.pool_head_dim)
         return self.num_layers, 2, self.num_kv_heads, self.head_dim
+
+    @property
+    def kv_head_pack(self) -> int:
+        """KV heads that share one row of the pool.  A trunk with convolution
+        layers whose heads are narrower than the chip's 128 lanes packs as
+        many as make a row of whole lanes (LFM2: two heads of 64): a pool of
+        64-wide rows is laid out pages-minor by XLA and copied whole around
+        every kernel call (1.6 GB twice a step at LFM2's cut, compiled for a
+        described v5e), and Mosaic will not slice such a page for a DMA.  The
+        attention layer (``model._packed_heads_attention``) puts a query into
+        its KV head's part of the row, zeros elsewhere, so every attention
+        path sees an ordinary pair pool of ``pool_kv_heads`` heads of
+        ``pool_head_dim``.  1 everywhere else, narrow heads or not: a
+        one-kind or two-kind pool is also what tensor parallelism shards by
+        KV head, what the int8 pool scales a head at a time, and what
+        offload, G4 and disaggregation ship as ``[.., Hkv, D]`` blobs, all of
+        which read ``num_kv_heads`` and ``head_dim``; this trunk refuses
+        each of them by name (``kv_cache.conv_state_refusal``), so its pool
+        is free to differ.  Packing another family's pool is theirs to
+        follow first."""
+        d = self.head_dim
+        if not self.has_conv or d >= 128 or 128 % d:
+            return 1
+        return math.gcd(128 // d, self.num_kv_heads)
+
+    @property
+    def pool_kv_heads(self) -> int:
+        return self.num_kv_heads // self.kv_head_pack
+
+    @property
+    def pool_head_dim(self) -> int:
+        return self.head_dim * self.kv_head_pack
 
     @property
     def kv_values_per_token(self) -> int:
@@ -261,7 +328,7 @@ class ModelConfig:
 
     SUPPORTED_MODEL_TYPES = (
         "llama", "mistral", "qwen2", "mixtral", "gemma", "phi3", "qwen3",
-        "mistral4", "mellum",
+        "mistral4", "mellum", "lfm2_moe",
     )
 
     @classmethod
@@ -288,6 +355,8 @@ class ModelConfig:
             return cls._from_mistral4(cfg, rs or {})
         if mt == "mellum":
             return cls._from_mellum(cfg, rs or {})
+        if mt == "lfm2_moe":
+            return cls._from_lfm2_moe(cfg)
         if rs is not None:
             rs_type = rs.get("rope_type") or rs.get("type")
             if rs_type == "llama3":
@@ -471,6 +540,17 @@ class ModelConfig:
             mscale, mscale_all,
         )
 
+    @staticmethod
+    def _shortest_period(kinds) -> int:
+        """The shortest ``p`` with ``kinds[i] == kinds[i % p]`` throughout; a
+        trunk cut inside a period has one, and is not whole repetitions of
+        it."""
+        n = len(kinds)
+        return next(
+            p for p in range(1, n + 1)
+            if all(kinds[i] == kinds[i % p] for i in range(n))
+        )
+
     @classmethod
     def _from_mellum(cls, cfg: Dict[str, Any], rs: Dict[str, Any]) -> "ModelConfig":
         """``mellum``: ``layer_types`` as whole periods of
@@ -493,12 +573,7 @@ class ModelConfig:
                 f" num_hidden_layers={L}"
             )
         kinds = [names[t] for t in types]
-        # the shortest p with kinds[i] == kinds[i % p] throughout; a trunk
-        # cut inside a period has one, and is not whole repetitions of it
-        period = next(
-            p for p in range(1, L + 1)
-            if all(kinds[i] == kinds[i % p] for i in range(L))
-        )
+        period = cls._shortest_period(kinds)
         if L % period:
             raise ValueError(
                 f"mellum layer_types is not whole periods: its shortest"
@@ -553,6 +628,87 @@ class ModelConfig:
             sliding_window=window,
             layer_pattern=pattern if len(set(pattern)) > 1 else None,
             rope_by_kind=tuple(by_kind) if len(by_kind) > 1 else None,
+        )
+
+    @classmethod
+    def _from_lfm2_moe(cls, cfg: Dict[str, Any]) -> "ModelConfig":
+        """``lfm2_moe``: ``layer_types`` of ``conv`` (gated 3-tap short
+        convolution) and ``full_attention`` layers (GQA, a norm over each
+        head of q and k before RoPE); the first ``num_dense_layers`` layers
+        have a dense SwiGLU of ``intermediate_size``, every other an expert
+        MLP of ``moe_intermediate_size`` routed by sigmoid scores, the
+        choice made with a per-expert bias (``use_expert_bias``).  The
+        layers after the dense ones have to be whole periods."""
+        L = cfg["num_hidden_layers"]
+        names = {"conv": "conv", "full_attention": "full"}
+        types = cfg.get("layer_types") or ["full_attention"] * L
+        for t in types:
+            if t not in names:
+                raise ValueError(
+                    f"lfm2_moe layer_types entry {t!r} is not supported"
+                    f" (implemented: {', '.join(names)})"
+                )
+        if len(types) != L:
+            raise ValueError(
+                f"lfm2_moe layer_types has {len(types)} entries for"
+                f" num_hidden_layers={L}"
+            )
+        if cfg.get("conv_bias", False):
+            raise ValueError("lfm2_moe with conv_bias is not supported")
+        if cfg.get("conv_L_cache", 3) != 3:
+            raise ValueError(
+                f"lfm2_moe conv_L_cache={cfg['conv_L_cache']} is not supported"
+                " (implemented: 3, a filter over a token and its two"
+                " predecessors)"
+            )
+        if not cfg.get("norm_topk_prob", True):
+            raise ValueError("lfm2_moe without norm_topk_prob is not supported")
+        if cfg.get("n_group", 1) != 1 or cfg.get("topk_group", 1) != 1:
+            raise ValueError("lfm2_moe grouped routing (n_group > 1) is not supported")
+        lead = int(cfg.get("num_dense_layers", 0))
+        if not 0 <= lead < L:
+            raise ValueError(
+                f"lfm2_moe num_dense_layers={lead} leaves no expert layer of"
+                f" num_hidden_layers={L}"
+            )
+        kinds = [names[t] for t in types]
+        rest = kinds[lead:]
+        period = cls._shortest_period(rest)
+        if len(rest) % period:
+            raise ValueError(
+                f"lfm2_moe layer_types after the {lead} dense layers is not"
+                f" whole periods: its shortest period has {period} layers,"
+                f" {len(rest)} layers follow"
+            )
+        if "full" not in kinds:
+            raise ValueError(
+                "lfm2_moe layer_types without a full_attention layer is not"
+                " supported (the page pool needs a layer)"
+            )
+        plain = not lead and set(rest) == {"full"}
+        heads = cfg["num_attention_heads"]
+        hidden = cfg["hidden_size"]
+        return cls(
+            vocab_size=cfg["vocab_size"],
+            hidden_size=hidden,
+            intermediate_size=cfg["moe_intermediate_size"],
+            num_layers=L,
+            num_heads=heads,
+            num_kv_heads=cfg.get("num_key_value_heads", heads),
+            head_dim=cfg.get("head_dim") or hidden // heads,
+            rope_theta=float(cfg.get("rope_theta", 1000000.0)),
+            rms_norm_eps=float(cfg.get("norm_eps", 1e-5)),
+            max_position=cfg.get("max_position_embeddings", 4096),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", True),
+            num_experts=cfg["num_experts"],
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+            qk_norm=True,
+            layer_pattern=None if plain else tuple(rest[:period]),
+            lead_pattern=tuple(kinds[:lead]) or None,
+            lead_intermediate_size=cfg["intermediate_size"] if lead else 0,
+            router_score="sigmoid",
+            router_bias=bool(cfg.get("use_expert_bias", False)),
         )
 
     @classmethod

@@ -52,6 +52,7 @@ from .kv_cache import (
     blob_to_host,
     coerce_kv_blob,
     kv_blob_concat,
+    conv_state_refusal,
     two_kind_refusal,
 )
 from .metrics import EngineMetrics
@@ -727,6 +728,7 @@ class JaxEngine:
         if mesh is None:
             mesh = self.resolve_mesh(self.cfg, model_cfg)
             if mesh is not None:
+                self._refuse_two_kind("a serving mesh (tp, dp, sp or pp)")
                 from ..parallel.sharding import shard_params
 
                 params = shard_params(params, model_cfg, mesh)
@@ -820,6 +822,7 @@ class JaxEngine:
             allocator=pool,
             num_window_pages=self.cfg.num_window_pages,
             window_allocator=wpool,
+            max_lanes=self.cfg.max_batch_size,
         )
         # serving-step dispatch table: module-level jits on one chip; on a
         # dp/tp (/ep) mesh, re-jitted with explicit in/out shardings
@@ -875,6 +878,9 @@ class JaxEngine:
             metrics_registry, max_slots=self.cfg.max_batch_size
         )
         self.sched.metrics = self.obs
+        if model_cfg.has_conv:
+            self.sched.conv_state = True
+            self.obs.mint_conv_state(self.kv.state_bytes)
         # the dispatch record (ISSUE 41), on time.perf_counter(), the clock
         # of every Inflight* record's ``dispatched_at`` and of the commit's
         # one read: service seconds of the committed dispatches that
@@ -1010,6 +1016,8 @@ class JaxEngine:
                     "ignoring malformed DYN_MIXED_TOKEN_BUDGET=%r", env_budget
                 )
         self._mixed_budget = max(int(budget), 1)
+        if not self._mixed:
+            self._refuse_conv_state("serving without mixed batching")
         if model_cfg.two_kind:
             if not self._mixed:
                 self._refuse_two_kind("serving without mixed batching")
@@ -1074,9 +1082,19 @@ class JaxEngine:
                 if model_cfg.is_mla
                 else None
             )
+            # (the pool's heads: a trunk that packs KV heads into a row asks
+            # as the step's trace will, config.kv_head_pack)
             self._decode_backend = att.decode_backend(
-                self.kv.pages, model_cfg.num_heads, model_cfg.head_dim,
+                self.kv.pages, model_cfg.num_heads, model_cfg.pool_head_dim,
                 model_cfg.dtype,
+            )
+            self._packed_attn = (
+                att._packed_backend(
+                    self.kv.pages, model_cfg.num_heads,
+                    model_cfg.pool_kv_heads, model_cfg.pool_head_dim,
+                )
+                if model_cfg.has_conv
+                else None
             )
         # queue-side prefetch: window resolved here, walks issued by the
         # tick loop from queue position (_drive_prefetch), finished or
@@ -1321,7 +1339,7 @@ class JaxEngine:
         m = self.model_cfg
         with self.mesh_scope():  # the gates read tp from the context mesh
             launch = att.packed_launch(
-                self.kv.pages, m.num_heads, m.num_kv_heads, m.head_dim,
+                self.kv.pages, m.num_heads, m.pool_kv_heads, m.pool_head_dim,
                 m.dtype,
             )
         page = self.cfg.page_size
@@ -1546,7 +1564,7 @@ class JaxEngine:
                     "sampling penalties are unavailable at max_seq_len "
                     f">= 32768 (engine max_seq_len {self.cfg.max_seq_len})"
                 )
-            if self.model_cfg.two_kind and (
+            if (
                 self._seq_penalized(seq)
                 or seq.mm_embeds is not None
                 or seq.speculation is not None
@@ -1556,6 +1574,11 @@ class JaxEngine:
                 self._refuse_two_kind(
                     "a request with sampling penalties, a soft prompt or "
                     "speculation (the classic prefill and verify dispatches)"
+                )
+            if seq.prompt_logprobs is not None:
+                self._refuse_conv_state(
+                    "a request for the prompt's log-probabilities (the "
+                    "scoring step)"
                 )
             self._arm_speculation(seq)  # unknown drafter -> error stream
             self.sched.enqueue(seq)
@@ -1719,6 +1742,7 @@ class JaxEngine:
         """
         if not token_batches:
             return []
+        self._refuse_conv_state("pooled embeddings (the embedding step)")
         for t in token_batches:
             if not t:
                 raise ValueError("embedding input must be non-empty")
@@ -1778,9 +1802,18 @@ class JaxEngine:
 
     def _refuse_two_kind(self, what: str) -> None:
         """Everything that moves or reshapes KV beyond one chip's hot path
-        assumes one pool and one page table a lane."""
+        assumes one pool and one page table a lane, and nothing beside a
+        sequence's pages."""
         if self.model_cfg.two_kind:
             raise ValueError(two_kind_refusal(what))
+        self._refuse_conv_state(what)
+
+    def _refuse_conv_state(self, what: str) -> None:
+        """The convolution layers' state is carried, snapshotted and
+        restored by the packed step and the decode steps, and by them
+        alone."""
+        if self.model_cfg.has_conv:
+            raise ValueError(conv_state_refusal(what))
 
     def _refuse_latent(self, what: str) -> None:
         """KV transfer paths ship ``[L, 2, pages, page, Hkv, D]`` blobs: over
@@ -4673,6 +4706,16 @@ class JaxEngine:
                 dispatch_meta["latent"] = self._latent_path
             if num_steps > 1:
                 dispatch_meta["decode"] = self._decode_backend
+            if self.model_cfg.has_conv:
+                # which kernels the packed launch over the attention layers'
+                # pool takes, and the lanes whose convolution layers resume
+                # from a page's snapshot in this dispatch: a request's
+                # first chunk after a prefix hit
+                dispatch_meta["attn"] = self._packed_attn
+                dispatch_meta["restored"] = sum(
+                    1 for ch in chunks
+                    if ch.start and ch.start == ch.seq.cached_prompt_tokens
+                )
         operands = (
             self.params,
             self.model_cfg,
@@ -5945,6 +5988,13 @@ class JaxEngine:
             dispatches=max(seq.prefill_chunks - 1, 0) + seq.token_commits,
             preemptions=len(seq.preempted) + bool(seq.preempted_at),
             finish=seq.finish.value if seq.finish is not None else None,
+            **(
+                {
+                    "state_restored": seq.state_page is not None,
+                    "state_page": seq.state_page,
+                }
+                if self.model_cfg.has_conv else {}
+            ),
         )
         for stage, lo, hi in seq.stage_segments(end_s):
             attrs: Dict[str, Any] = {}
@@ -5952,6 +6002,8 @@ class JaxEngine:
                 attrs["attn"] = "latent"
             elif self.model_cfg.two_kind and stage in ("prefill", "decode"):
                 attrs["attn"] = "window+full"
+            elif self.model_cfg.has_conv and stage in ("prefill", "decode"):
+                attrs["attn"] = "conv+full"
             if stage == "prefill":
                 attrs = {
                     **attrs,

@@ -316,10 +316,89 @@ class KindKV:
         return KindKV(self.full, pool)
 
 
+# ---------------------------------------------------------------------------
+# convolution layers (lfm2_moe): state beside the pages
+#
+# A gated short-convolution layer mixes a token with its two predecessors:
+# what a sequence carries past position ``t`` is ``(z_{t-1}, z_t)``, two rows
+# of the hidden width a layer, exact to restore.  It touches no page, so the
+# pair pool ``attn`` holds the attention layers alone, and beside it ride
+#
+#     lanes [conv_layers, 2 B, H]          the lane is the slot: the last two
+#                                          rows lane ``b``'s sequence computed,
+#                                          at rows ``2 b`` and ``2 b + 1``
+#     pages [conv_layers, 2 num_pages, H]  a snapshot rides the page: the rows
+#                                          at the last two positions of page
+#                                          ``p`` of the PAIR POOL, at ``2 p``
+#                                          and ``2 p + 1``
+#
+# (The pair of rows is folded into the axis before it: with an axis of 2 in
+# front of ``H`` the chip tiles the array by that 2, the row scatter wants
+# rows of 8, and XLA copies all of ``pages`` around every layer's write:
+# three copies of 1.3 GB beside a step at LFM2's cut, compiled for a
+# described v5e.)
+#
+# Every step writes both beside its K/V (attention.packed_conv_mix /
+# decode_conv_mix).  A page's snapshot lives and dies with the page (one
+# allocator, one refcount, one LRU), so whatever block the registry can hand
+# out has the state at its end.  Where a segment's predecessors come from is
+# read off its first position ``p``: zeros at 0; the snapshot of page ``p /
+# page - 1`` where ``p`` starts a page (a prefix hit ends on a page, and a
+# lane that walks on over a page boundary reads back the very rows it wrote
+# there); the lane's own rows otherwise.  No dispatch carries a flag for it,
+# and a lane a new request takes never reads what the last one left.  Like
+# ``KindKV`` the triple is a pytree on the layer scan's carry.
+# ---------------------------------------------------------------------------
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclass
+class ConvKV:
+    attn: Any  # [La, 2, P, page, Hkv, D]
+    lanes: Any  # [Lc, 2 B, H]
+    pages: Any  # [Lc, 2 P, H]
+
+    def tree_flatten(self):
+        return (self.attn, self.lanes, self.pages), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        del aux
+        return cls(*children)
+
+    @property
+    def dtype(self):
+        return self.attn.dtype
+
+    @property
+    def nbytes(self) -> int:
+        return sum(int(a.nbytes) for a in (self.attn, self.lanes, self.pages))
+
+    def block_until_ready(self) -> "ConvKV":
+        for a in (self.attn, self.lanes, self.pages):
+            a.block_until_ready()
+        return self
+
+
+def conv_state_refusal(what: str) -> str:
+    """The one sentence with which everything that moves, rewinds or
+    reshapes KV outside the packed step refuses a trunk with convolution
+    layers."""
+    return (
+        f"{what} is not supported over a trunk with convolution layers "
+        "(layer_types 'conv'): a sequence carries two rows a layer beside "
+        "its pages, which only the packed step and the fused decode steps "
+        "carry, snapshot and restore"
+    )
+
+
 def kv_data(kv_pages):
     """The dense data array of any pool form (shape/dtype queries, Pallas
     operand plumbing).  A two-kind cache answers with its full pool: the
-    two differ in layers and pages only."""
+    two differ in layers and pages only.  A trunk with convolution layers
+    answers with its attention layers' pool."""
+    if isinstance(kv_pages, ConvKV):
+        return kv_pages.attn
     if isinstance(kv_pages, KindKV):
         return kv_pages.full
     if isinstance(kv_pages, QuantKV):
@@ -568,6 +647,7 @@ class PagedKVCache:
         allocator: Optional[Any] = None,
         num_window_pages: int = 0,
         window_allocator: Optional[Any] = None,
+        max_lanes: int = 0,
     ) -> None:
         self.cfg = cfg
         self.num_pages = num_pages
@@ -598,6 +678,13 @@ class PagedKVCache:
                 "one scale a row would span c_kv and the rotated key, whose "
                 "ranges differ"
             )
+        if cfg.has_conv and (
+            self.quantized or sharding is not None or cfg.two_kind
+        ):
+            raise ValueError(conv_state_refusal(
+                "an int8 pool" if self.quantized
+                else "a sharded pool" if sharding is not None
+                else "a trunk of window and full layers"))
         if cfg.two_kind:
             if self.quantized or sharding is not None:
                 raise ValueError(two_kind_refusal(
@@ -641,6 +728,31 @@ class PagedKVCache:
             if sharding is not None:
                 arr = jax.device_put(arr, sharding)
             self.pages = LatentKV(arr, cfg.kv_lora_rank) if cfg.is_mla else arr
+        if cfg.has_conv:
+            self.pages = ConvKV(self.pages, *self._conv_state(max_lanes))
+
+    def _conv_state(self, max_lanes: int):
+        """The zeroed state of the convolution layers: ``(lanes, pages)``."""
+        if max_lanes < 1:
+            raise ValueError(
+                "a trunk with convolution layers needs max_lanes (the "
+                "engine's max_batch_size): the lane is the state's slot"
+            )
+        Lc, H = self.cfg.kind_layers("conv"), self.cfg.hidden_size
+        return (
+            jnp.zeros((Lc, 2 * max_lanes, H), self.dtype),
+            jnp.zeros((Lc, 2 * self.num_pages, H), self.dtype),
+        )
+
+    @property
+    def state_bytes(self) -> dict:
+        """Bytes of the convolution layers' state by part (empty without)."""
+        if not isinstance(self.pages, ConvKV):
+            return {}
+        return {
+            "lanes": int(self.pages.lanes.nbytes),
+            "pages": int(self.pages.pages.nbytes),
+        }
 
     @property
     def bytes_per_page(self) -> int:

@@ -52,6 +52,10 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: Any = None) -> Params:
         scale = scale if scale is not None else (1.0 / jnp.sqrt(shape[-2] if len(shape) > 1 else shape[-1]))
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
+    if cfg.has_conv:
+        return _init_conv_trunk(
+            cfg, iter(jax.random.split(key, 24 + 6 * cfg.lead_layers)), w, dtype
+        )
     keys = iter(jax.random.split(key, 24))
     if cfg.is_mla:
         R, C = cfg.q_lora_rank, cfg.kv_lora_rank
@@ -102,6 +106,72 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: Any = None) -> Params:
     params: Params = {
         "embed": w(next(keys), (cfg.vocab_size, H), scale=0.02),
         "layers": layers,
+        "final_norm": jnp.ones((H,), dtype),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w(next(keys), (H, cfg.vocab_size))
+    return params
+
+
+def _init_conv_trunk(cfg: ModelConfig, keys, w, dtype) -> Params:
+    """The tree of a trunk with convolution layers (``scan_layers``): the
+    periods' stack with each kind's operator under ``"attn"`` / ``"conv"``,
+    and the layers in front of the periods as a tuple under ``"lead"``."""
+    H, D, I, E = cfg.hidden_size, cfg.head_dim, cfg.intermediate_size, cfg.num_experts
+    Hq, Hkv = cfg.num_heads, cfg.num_kv_heads
+
+    def operator(kind, lead):
+        if kind == "conv":
+            return {
+                "conv_in": w(next(keys), (*lead, H, 3 * H)),
+                "conv_taps": w(next(keys), (*lead, 3, H), scale=0.5),
+                "conv_out": w(next(keys), (*lead, H, H)),
+            }
+        return {
+            "wq": w(next(keys), (*lead, H, Hq * D)),
+            "wk": w(next(keys), (*lead, H, Hkv * D)),
+            "wv": w(next(keys), (*lead, H, Hkv * D)),
+            "wo": w(next(keys), (*lead, Hq * D, H)),
+            "q_norm": jnp.ones((*lead, D), dtype),
+            "k_norm": jnp.ones((*lead, D), dtype),
+        }
+
+    def norms(lead):
+        return {
+            "input_norm": jnp.ones((*lead, H), dtype),
+            "post_norm": jnp.ones((*lead, H), dtype),
+        }
+
+    Il = cfg.lead_intermediate_size
+    lead = tuple(
+        {
+            **norms(()), **operator(kind, ()),
+            "w_gate": w(next(keys), (H, Il)),
+            "w_up": w(next(keys), (H, Il)),
+            "w_down": w(next(keys), (Il, H)),
+        }
+        for kind in cfg.lead_pattern or ()
+    )
+    L = cfg.num_layers - len(lead)
+    layers: Dict[str, Any] = {
+        **norms((L,)),
+        "router": w(next(keys), (L, H, E)),
+        "w_gate": w(next(keys), (L, E, H, I)),
+        "w_up": w(next(keys), (L, E, H, I)),
+        "w_down": w(next(keys), (L, E, I, H)),
+        "attn": operator(
+            "full", (cfg.kind_layers("full") - cfg.lead_kind_layers("full"),)
+        ),
+        "conv": operator(
+            "conv", (cfg.kind_layers("conv") - cfg.lead_kind_layers("conv"),)
+        ),
+    }
+    if cfg.router_bias:
+        layers["router_bias"] = w(next(keys), (L, E), scale=0.1)
+    params: Params = {
+        "embed": w(next(keys), (cfg.vocab_size, H), scale=0.02),
+        "layers": layers,
+        "lead": lead,
         "final_norm": jnp.ones((H,), dtype),
     }
     if not cfg.tie_word_embeddings:
@@ -280,8 +350,20 @@ def _route(lp: Params, xf: jax.Array, cfg: ModelConfig):
     router_logits = jnp.dot(
         xf, lp["router"], preferred_element_type=jnp.float32
     )  # [N, E]
-    topw, topi = jax.lax.top_k(router_logits, cfg.num_experts_per_tok)
-    topw = jax.nn.softmax(topw, axis=-1)
+    if cfg.router_score == "sigmoid":
+        # every expert scored on its own; the bias decides who is chosen
+        # and weighs nothing: the weights are the unbiased scores of the
+        # chosen over their sum (lfm2_moe).  All in float32
+        scores = jax.nn.sigmoid(router_logits)
+        choice = scores
+        if cfg.router_bias:
+            choice = scores + lp["router_bias"].astype(jnp.float32)
+        _, topi = jax.lax.top_k(choice, cfg.num_experts_per_tok)
+        topw = jnp.take_along_axis(scores, topi, axis=-1)
+        topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-6)
+    else:
+        topw, topi = jax.lax.top_k(router_logits, cfg.num_experts_per_tok)
+        topw = jax.nn.softmax(topw, axis=-1)
     if cfg.routed_scaling_factor != 1.0:
         topw = topw * cfg.routed_scaling_factor
     return topw.astype(xf.dtype), topi
@@ -537,6 +619,62 @@ def _latent_attention(
     return attn.reshape(B, T, Hq * V), kv_pages
 
 
+def _packed_heads_attention(q, k, v, cfg, attn_fn, kv_pages, layer):
+    """``attn_fn`` over a pool whose rows hold ``cfg.kv_head_pack`` KV heads
+    (config.kv_head_pack): a query sits in its KV head's part of the row
+    with zeros in the others', so its scores over the row are its own
+    head's; the value rows come back whole and the query takes its part.
+    The scale the attention call applies is the row's, ``(pack D)^-0.5``:
+    the part's ones carry ``sqrt(pack)``, in float32, so the query is
+    rounded once."""
+    B, T, Hq, D = q.shape
+    Hkv, pack = cfg.num_kv_heads, cfg.kv_head_pack
+    part = jax.nn.one_hot(
+        jnp.arange(Hq) // (Hq // Hkv) % pack, pack, dtype=jnp.float32
+    )  # [Hq, pack]
+    q = (q[..., None, :] * (part * pack ** 0.5)[:, :, None]).astype(q.dtype)
+    attn, kv_pages = attn_fn(
+        q.reshape(B, T, Hq, pack * D),
+        k.reshape(B, T, Hkv // pack, pack * D),
+        v.reshape(B, T, Hkv // pack, pack * D),
+        kv_pages, layer,
+    )
+    attn = jnp.sum(
+        attn.reshape(B, T, Hq, pack, D) * part.astype(attn.dtype)[:, :, None],
+        axis=-2,
+    )
+    return attn, kv_pages
+
+
+# A convolution callback receives (z [B, T, H], taps [3, H], kv_pages, layer)
+# -- the rows of ``B (.) X``, one layer's filter, the cache with the
+# convolution layers' state on it (kv_cache.ConvKV) and the layer's index
+# among the convolution layers -- and returns (mixed [B, T, H], kv_pages):
+# each row mixed with its two predecessors, the state left for the next
+# step.  Only the steps that carry that state have one (step.py).
+ConvFn = Callable[
+    [jax.Array, jax.Array, Any, jax.Array], Tuple[jax.Array, Any]
+]
+
+
+def _conv_operator(
+    lp: Params, h: jax.Array, conv_fn: Optional[ConvFn], kv_pages, layer
+) -> Tuple[jax.Array, Any]:
+    """The gated short convolution (lfm2_moe): ``[B | C | X] = W_in h``,
+    ``z = B (.) X``, a causal depthwise 3-tap filter over ``z``
+    (``conv_fn``), ``W_out (C (.) conv)``."""
+    if conv_fn is None:
+        from .kv_cache import conv_state_refusal
+
+        raise ValueError(conv_state_refusal(
+            "a step outside the packed step and the decode steps (classic "
+            "prefill, verify, scoring, embedding)"
+        ))
+    b, c, x = jnp.split(h @ mat(lp["conv_in"]), 3, axis=-1)
+    mixed, kv_pages = conv_fn(b * x, lp["conv_taps"], kv_pages, layer)
+    return (c * mixed) @ mat(lp["conv_out"]), kv_pages
+
+
 def transformer_layer(
     lp: Params,
     x: jax.Array,  # [B, T, H]
@@ -548,7 +686,10 @@ def transformer_layer(
     layer: jax.Array,  # scalar i32 layer index into kv_pages
     row_valid: Optional[jax.Array] = None,  # [B, T] bool: rows anyone reads
     q_factor: Optional[jax.Array] = None,  # [B, T] per-position query scale
-    kind: Optional[str] = None,  # "sliding" | "full" in a two-kind trunk
+    kind: Optional[str] = None,  # "sliding" | "full" | "conv" by layer_pattern
+    conv_fn: Optional[ConvFn] = None,
+    op_layer: Optional[jax.Array] = None,  # index among its kind's layers,
+    # where the cache holds the kinds apart from the stack (has_conv)
 ) -> Tuple[jax.Array, jax.Array]:
     """One decoder layer (norm -> attention -> norm -> MLP, residuals).
     Shared by the single-device layer scan and the pipeline-parallel stage
@@ -563,32 +704,43 @@ def transformer_layer(
     B, T, _ = x.shape
     D = cfg.head_dim
     h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps, cfg.rms_norm_offset)
-    if cfg.is_mla:
+    # where the cache holds the kinds apart, this layer's place among its own
+    layer_in_cache = layer if op_layer is None else op_layer
+    if kind == "conv":  # the kind chooses the operator
+        op, kv_pages = _conv_operator(lp, h, conv_fn, kv_pages, layer_in_cache)
+        x = x + op
+    elif cfg.is_mla:
         attn, kv_pages = _latent_attention(
             lp, h, cos, sin, cfg, attn_fn, kv_pages, layer, q_factor
         )
         x = x + attn @ mat(lp["wo"])
-        h2 = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
-        return x + _moe_mlp(lp, h2, cfg, row_valid, layer), kv_pages
-    q = h @ mat(lp["wq"])
-    k = h @ mat(lp["wk"])
-    v = h @ mat(lp["wv"])
-    if "bq" in lp:
-        q = q + lp["bq"]
-        k = k + lp["bk"]
-        v = v + lp["bv"]
-    q = q.reshape(B, T, cfg.num_heads, D)
-    k = k.reshape(B, T, cfg.num_kv_heads, D)
-    v = v.reshape(B, T, cfg.num_kv_heads, D)
-    if cfg.qk_norm:  # Qwen3: per-head RMSNorm before RoPE
-        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
-        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    attn, kv_pages = attn_fn(q, k, v, kv_pages, layer)
-    x = x + attn.reshape(B, T, cfg.num_heads * D) @ mat(lp["wo"])
+    else:
+        q = h @ mat(lp["wq"])
+        k = h @ mat(lp["wk"])
+        v = h @ mat(lp["wv"])
+        if "bq" in lp:
+            q = q + lp["bq"]
+            k = k + lp["bk"]
+            v = v + lp["bv"]
+        q = q.reshape(B, T, cfg.num_heads, D)
+        k = k.reshape(B, T, cfg.num_kv_heads, D)
+        v = v.reshape(B, T, cfg.num_kv_heads, D)
+        if cfg.qk_norm:  # Qwen3, LFM2: per-head RMSNorm before RoPE
+            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
+        if cfg.kv_head_pack > 1:
+            attn, kv_pages = _packed_heads_attention(
+                q, k, v, cfg, attn_fn, kv_pages, layer_in_cache
+            )
+        else:
+            attn, kv_pages = attn_fn(q, k, v, kv_pages, layer_in_cache)
+        x = x + attn.reshape(B, T, cfg.num_heads * D) @ mat(lp["wo"])
     h2 = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps, cfg.rms_norm_offset)
-    if cfg.is_moe:
+    # a layer in front of the periods may have a dense MLP in a routed
+    # model (lfm2_moe): told by its weights
+    if cfg.is_moe and "router" in lp:
         x = x + _moe_mlp(lp, h2, cfg, row_valid, layer)
     else:
         x = x + _dense_mlp(lp, h2, cfg.hidden_act)
@@ -606,6 +758,7 @@ def scan_layers(
     row_valid: Optional[jax.Array] = None,
     q_factor: Optional[jax.Array] = None,
     rope_by_kind: Optional[Dict[str, Tuple[jax.Array, jax.Array]]] = None,
+    conv_fn: Optional[ConvFn] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Scan ``transformer_layer`` over the stacked weights.
 
@@ -617,7 +770,10 @@ def scan_layers(
 
     A trunk of window and full layers (``cfg.layer_pattern``) scans over
     its periods: the body runs the period's layers in order, each with its
-    kind known at trace time, its ``(cos, sin)`` from ``rope_by_kind``."""
+    kind known at trace time, its ``(cos, sin)`` from ``rope_by_kind``.
+    Where the kinds differ in operator (``cfg.has_conv``) the stack holds
+    what every layer has (norms, experts) and under ``"attn"`` and
+    ``"conv"`` each kind's operator, stacked over that kind's layers."""
     # layers of the stack in hand (a latent pool holds two layers a slab)
     L = lp_stack["input_norm"].shape[0]
     # Where the expert MLP takes the grouped kernel, the experts' weights
@@ -646,20 +802,36 @@ def scan_layers(
         )
         return x, kv_pages
 
+    def at(stack, idx):
+        return jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, idx, 0, False), stack
+        )
+
+    ops: Dict[str, Params] = {}
+    if cfg.has_conv:
+        ops = {"full": lp_stack["attn"], "conv": lp_stack["conv"]}
+        lp_stack = {k: v for k, v in lp_stack.items() if k not in ("attn", "conv")}
+
     def period(carry, first):
         # a layer's weights are sliced out of the whole stack by its own
         # index, as the scan above slices its scanned operand
         x, kv = carry
         for j, kind in enumerate(pattern):
             idx = first + j
-            lp = jax.tree_util.tree_map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, idx, 0, False),
-                lp_stack,
-            )
+            lp = at(lp_stack, idx)
+            op_layer = None
+            if ops:
+                # the layer's place among its kind's: whole periods before
+                # it, its rank in its own, the layers in front of the scan
+                rank = first // len(pattern) * pattern.count(kind) + (
+                    pattern[:j].count(kind)
+                )
+                lp = {**lp, **at(ops[kind], rank)}
+                op_layer = rank + cfg.lead_kind_layers(kind)
             c, s = rope_by_kind[kind]
             x, kv = transformer_layer(
                 {**lp, **whole}, x, c, s, cfg, attn_fn, kv, idx, row_valid,
-                q_factor, kind,
+                q_factor, kind, conv_fn, op_layer,
             )
         return (x, kv), None
 
@@ -679,8 +851,12 @@ def transformer(
     attn_fn: AttnFn,
     mm: "Optional[Tuple[jax.Array, jax.Array]]" = None,
     row_valid: Optional[jax.Array] = None,
+    conv_fn: Optional[ConvFn] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Run the trunk; returns (hidden [.., H], updated kv_pages).
+
+    ``conv_fn`` serves a trunk's convolution layers (:data:`ConvFn`); a
+    step that has none cannot run such a trunk, and says so.
 
     ``row_valid`` (bool, shaped like ``tokens``) marks the rows whose
     hidden state anyone reads; a step that pads its rows passes it so the
@@ -717,8 +893,10 @@ def transformer(
     else:  # one table a kind of layer, built once a step
         ropes = {
             kind: rope_cos_sin(positions, cfg.rope_dim, *cfg.kind_rope(kind))
-            for kind in sorted(set(cfg.layer_pattern))
+            for kind in sorted(set(cfg.layer_pattern + (cfg.lead_pattern or ())))
+            if kind != "conv"
         }
+        ropes["conv"] = (None, None)  # a convolution layer rotates nothing
         cos = sin = None
     q_factor = None
     if cfg.query_pos_scaling is not None:
@@ -729,9 +907,18 @@ def transformer(
 
     if squeeze and row_valid is not None:
         row_valid = row_valid[:, None]
+    # layers in front of the periods, each with its own shapes: unrolled
+    seen: Dict[str, int] = {}
+    for kind, lp in zip(cfg.lead_pattern or (), params.get("lead", ())):
+        c, s = ropes[kind]
+        x, kv_pages = transformer_layer(
+            lp, x, c, s, cfg, attn_fn, kv_pages, None, row_valid, q_factor,
+            kind, conv_fn, jnp.int32(seen.get(kind, 0)),
+        )
+        seen[kind] = seen.get(kind, 0) + 1
     x, new_kv_pages = scan_layers(
         params["layers"], kv_pages, x, cos, sin, cfg, attn_fn, row_valid,
-        q_factor, ropes,
+        q_factor, ropes, conv_fn,
     )
 
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, cfg.rms_norm_offset)

@@ -8,8 +8,9 @@ channel symmetric int8 with the scale applied at the point of use --
 ``x @ (q.astype(bf16) * s)`` -- which XLA fuses into the matmul's operand
 read on TPU, so the bf16 weights are never materialized in HBM.
 
-What quantizes: the per-layer matmul weights (attention projections and
-MLP/expert weights) and the untied ``lm_head``.  What stays bf16: the
+What quantizes: the per-layer matmul weights (attention projections, a
+gated short convolution's two projections and MLP/expert weights) and the
+untied ``lm_head``.  What stays bf16: the
 embedding table (decode gathers B rows per step, not the whole matrix),
 norms/biases (tiny), and a tied lm_head (shared with the embedding).
 
@@ -35,6 +36,9 @@ QUANT_KEYS = (
     "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
     # latent attention (MLA) and shared experts
     "wq_a", "wq_b", "wkv_a", "wkv_b", "ws_gate", "ws_up", "ws_down",
+    # the gated short convolution's two projections (lfm2_moe): the operator
+    # of most of such a trunk's layers, streamed every step like the rest
+    "conv_in", "conv_out",
 )
 
 
@@ -108,15 +112,26 @@ def quantize_tensor(w: jax.Array, dtype: Any) -> QuantizedTensor:
     return _quantize_slice(w, dtype)
 
 
+def _quantize_known(tree: Params, dtype: Any) -> Params:
+    return {
+        k: quantize_tensor(v, dtype) if k in QUANT_KEYS else v
+        for k, v in tree.items()
+    }
+
+
 def quantize_params(params: Params, cfg) -> Params:
     """Quantize the streaming-dominant weights of an assembled params tree
-    (one-time, on device)."""
+    (one-time, on device).  A trunk whose kinds differ in operator keeps
+    each kind's operator stacked under its own key (``layers.attn``,
+    ``layers.conv``) and its leading layers singly under ``lead``."""
     out = dict(params)
-    layers = dict(params["layers"])
-    for k in QUANT_KEYS:
-        if k in layers:
-            layers[k] = quantize_tensor(layers[k], cfg.dtype)
+    layers = _quantize_known(params["layers"], cfg.dtype)
+    for kind in ("attn", "conv"):
+        if kind in layers:
+            layers[kind] = _quantize_known(layers[kind], cfg.dtype)
     out["layers"] = layers
+    if "lead" in params:
+        out["lead"] = tuple(_quantize_known(lp, cfg.dtype) for lp in params["lead"])
     if "lm_head" in params:
         out["lm_head"] = quantize_tensor(params["lm_head"], cfg.dtype)
     return out

@@ -195,6 +195,11 @@ class SeqState:
     # the host mirrors.
     speculation: Optional[Any] = None
     spec: Optional[Any] = None
+    # a trunk with convolution layers: the page whose snapshot the last
+    # admission resumed from (None: it started empty), and whether its hit
+    # was shortened because the registry held the whole prompt
+    state_page: Optional[int] = None
+    state_walked_back: bool = False
     # echo+logprobs: top-N prompt logprobs to compute at first prefill
     prompt_logprobs: Optional[int] = None
     prompt_lp_sent: bool = False
@@ -362,6 +367,8 @@ class Scheduler:
         # ``window_allocator`` the window layers', which it lets go as they
         # fall behind ``window`` keys of the next row it computes
         self.window_allocator = window_allocator
+        # set by an engine whose trunk has convolution layers
+        self.conv_state = False
         self.window = int(window)
         self.window_released = 0  # window pages let go behind the window
         self.block_size = cfg.block_size or cfg.page_size
@@ -616,11 +623,31 @@ class Scheduler:
         self.slots[slot] = seq
         self._write_slot_arrays(seq)
         self._queue_prompt_registrations(seq)
+        if self.conv_state:
+            self._note_state_admission(seq)
         if not seq.awaiting_kv:
             plan.prefills.append((seq, len(seq.prompt)))
         # awaiting_kv lanes hold their pages and stay device-inactive
         # until the remote prefill delivers (engine.deliver_external)
         return True
+
+    def _note_state_admission(self, seq: SeqState) -> None:
+        """Where an admission's convolution layers start: empty at position
+        0, or from the snapshot that rides the last page of its hit (the
+        step reads it off the chunk's first position; nothing is copied).
+        A hit ends on a page because a block is whole pages."""
+        n = seq.cached_prompt_tokens
+        if n % self.cfg.page_size:
+            raise RuntimeError(
+                f"a prefix hit of {n} tokens does not end on a page: the "
+                "convolution layers have no snapshot to resume from"
+            )
+        seq.state_page = seq.pages[n // self.cfg.page_size - 1] if n else None
+        m = self.metrics
+        if m is not None and m.state_restores is not None:
+            (m.state_restores if n else m.state_resets).inc()
+            if seq.state_walked_back:
+                m.state_walkbacks.inc()
 
     def predicted_pages(self, seq: SeqState) -> int:
         """Predicted peak KV pages for a request under the budget model, in
@@ -808,8 +835,18 @@ class Scheduler:
         if self.pool is None or seq.blocks is None:
             return []
         max_blocks = max(0, (len(seq.prompt) - 1) // self.block_size)
-        hashes = seq.blocks.sequence_hashes()[:max_blocks]
+        every = seq.blocks.sequence_hashes()
+        hashes = every[:max_blocks]
         matched = self.pool.match(hashes)
+        if self.conv_state:
+            # the registry holds the whole prompt: the hit walks back a
+            # block (a token is left to compute, and the convolution
+            # layers' state exists at page ends only)
+            seq.state_walked_back = (
+                len(every) > max_blocks
+                and len(matched) == max_blocks
+                and self.pool.is_registered(every[max_blocks])
+            )
         if self.wpool is not None:
             matched = matched[: self._window_tail_boundary(hashes, len(matched))]
         pages: List[int] = []

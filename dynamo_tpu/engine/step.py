@@ -92,9 +92,24 @@ def _decode_once(
     tokens: jax.Array,  # [B] last sampled token per slot
     seq_lens: jax.Array,  # [B] tokens already in cache (new token's position)
     page_table: jax.Array,  # [B, P]
+    active: Optional[jax.Array] = None,  # [B] bool: lanes the step advances;
+    # read by a trunk's convolution layers alone (a frozen lane's K/V write
+    # repeats itself, a shift of its convolution state would not)
 ) -> Tuple[jax.Array, jax.Array]:
     """One unjitted decode step.  Returns (logits [B,V], kv)."""
     positions = seq_lens.astype(jnp.int32)  # new token position (0-indexed)
+
+    def conv_fn(z, taps, kv, layer):
+        if active is None:
+            from .kv_cache import conv_state_refusal
+
+            raise ValueError(conv_state_refusal(
+                "a decode step that is not told which lanes it advances"
+            ))
+        out, kv = att.decode_conv_mix(
+            z[:, 0], taps, kv, layer, page_table, positions, active
+        )
+        return out[:, None], kv
 
     def attn_fn(q, k, v, kv, layer, kind=None):
         # q/k/v arrive [B, 1, H, D]; squeeze the singleton time axis.
@@ -109,7 +124,10 @@ def _decode_once(
         )
         return out[:, None], lv.put(new_kv)
 
-    hidden, kv_pages = transformer(params, cfg, tokens, positions, kv_pages, attn_fn)
+    hidden, kv_pages = transformer(
+        params, cfg, tokens, positions, kv_pages, attn_fn,
+        conv_fn=conv_fn if cfg.has_conv else None,
+    )
     return lm_logits(params, cfg, hidden), kv_pages
 
 
@@ -165,7 +183,9 @@ def _decode_block(
 
     def live_step(carry):
         tokens, seq_lens, active, rng, kv, counts = carry
-        logits, kv = _decode_once(params, cfg, kv, tokens, seq_lens, page_table)
+        logits, kv = _decode_once(
+            params, cfg, kv, tokens, seq_lens, page_table, active
+        )
         rng, sub = jax.random.split(rng)
         if use_penalties:
             # frequency/presence over the lane's generated-token histogram
@@ -527,9 +547,16 @@ def _packed_unified_step(
         )
         return out[None], lv.put(new_kv)
 
+    def conv_fn(z, taps, kv, layer):
+        out, kv = att.packed_conv_mix(
+            z[0], taps, kv, layer, page_table, base, seg_off, q_lens, t_lane,
+            pos, valid,
+        )
+        return out[None], kv
+
     hidden, kv_pages = transformer(
         params, cfg, tok_flat[None], positions[None], kv_pages, attn_fn,
-        row_valid=valid[None],
+        row_valid=valid[None], conv_fn=conv_fn if cfg.has_conv else None,
     )
     if s_spec > 0:
         rng, spec_sub = jax.random.split(rng)
@@ -629,7 +656,9 @@ def _packed_unified_multistep(
 
     def live_step(carry):
         tokens, seq_lens, active, rng, kv = carry
-        logits, kv = _decode_once(params, cfg, kv, tokens, seq_lens, page_table)
+        logits, kv = _decode_once(
+            params, cfg, kv, tokens, seq_lens, page_table, active
+        )
         rng, sub = jax.random.split(rng)
         sampled = sample_tokens(
             logits, sub, sampling, use_filters, positions=seq_lens + 1

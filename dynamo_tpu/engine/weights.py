@@ -78,6 +78,14 @@ def assemble_params(
 ) -> Params:
     """Assemble the stacked pytree from a flat HF name->array mapping
     (a dict, or the lazy ``_ShardIndex``)."""
+    if cfg.has_conv:
+        raise ValueError(
+            "loading a checkpoint of a trunk with convolution layers "
+            "(model_type 'lfm2_moe') is not implemented: its tree keeps each "
+            "kind's operator apart from the layers' stack (model.scan_layers) "
+            "and no tensor names are mapped onto it yet; the benchmark "
+            "serves it with weights drawn from a seed (benchmark/weights_lfm2.py)"
+        )
     L = cfg.num_layers
 
     def get(name: str) -> np.ndarray:

@@ -416,6 +416,10 @@ class EngineMetrics:
         # a two-kind cache's families (observe_kv_kinds), minted at the
         # first observation, and the released pages already counted
         self._kv_kinds: Optional[Tuple[Gauge, Gauge, Counter]] = None
+        # a trunk with convolution layers (mint_conv_state)
+        self.state_restores: Optional[Counter] = None
+        self.state_resets: Optional[Counter] = None
+        self.state_walkbacks: Optional[Counter] = None
         self._window_released = 0
 
     # -- update points (cheap; called per tick / per commit, not per token)
@@ -521,6 +525,34 @@ class EngineMetrics:
         if released > self._window_released:
             released_total.inc(released - self._window_released)
             self._window_released = released
+
+    def mint_conv_state(self, state_bytes: Dict[str, int]) -> None:
+        """The families of a trunk with convolution layers (minted by the
+        engine that serves one, at construction): how each admission found
+        its convolution layers' state, and the bytes the state holds."""
+        reg = self.registry
+        self.state_restores = reg.counter(
+            "dynamo_engine_state_restores",
+            "Admissions whose convolution layers resumed from the snapshot "
+            "of the page their prefix hit ends on",
+        )
+        self.state_resets = reg.counter(
+            "dynamo_engine_state_resets",
+            "Admissions at position 0: the convolution layers start empty",
+        )
+        self.state_walkbacks = reg.counter(
+            "dynamo_engine_state_walkbacks",
+            "Prefix hits shortened by a block because the whole prompt was "
+            "cached (a snapshot exists at page ends only)",
+        )
+        gauge = reg.gauge(
+            "dynamo_engine_state_bytes",
+            "Bytes of the convolution layers' state (part: lanes, the rows "
+            "a lane carries | pages, the snapshots that ride the pages)",
+            ["part"],
+        )
+        for part, n in state_bytes.items():
+            gauge.labels(part).set(n)
 
     def observe_executable_shapes(self, n: int) -> None:
         self.executable_shapes.set(n)

@@ -58,6 +58,30 @@ def pytest_configure(config):
     )
 
 
+# what a process may hold of memory mappings is vm.max_map_count, 65,530 by
+# default; stay well under it
+_MAP_CEILING = 30000
+
+
+@pytest.fixture(autouse=True)
+def _executables_within_the_map_limit():
+    """A compiled program is hundreds of memory mappings that its process
+    keeps for as long as JAX caches it.  A worker of the whole suite compiles
+    thousands; one that crosses the kernel's limit on mappings dies inside
+    its next compile (``Fatal Python error: Aborted`` under
+    ``backend_compile_and_load``), in whichever test comes next.  So a worker
+    that has gathered half the limit gives its programs back: the tests
+    after it compile again what they use."""
+    yield
+    try:
+        with open("/proc/self/maps") as f:
+            held = sum(1 for _ in f)
+    except OSError:  # no /proc: nothing to count, nothing to do
+        return
+    if held > _MAP_CEILING:
+        jax.clear_caches()
+
+
 @pytest.fixture
 def run():
     """Run an async test body on a fresh event loop."""

@@ -531,9 +531,16 @@ def test_chunked_prefill_interleaves_with_decode(run):
         )
         try:
             order = []
+            decoding = asyncio.Event()
 
             async def short():
-                toks, _ = await collect(engine, req([5, 6, 7], max_tokens=8))
+                stream = await engine.generate(
+                    Context.new(req([5, 6, 7], max_tokens=8)))
+                toks = []
+                async for item in stream:
+                    toks.extend((item.data or {}).get("token_ids") or [])
+                    if len(toks) >= 2:
+                        decoding.set()
                 order.append("short-done")
                 return toks
 
@@ -551,7 +558,12 @@ def test_chunked_prefill_interleaves_with_decode(run):
                 return toks
 
             t_short = asyncio.ensure_future(short())
-            await asyncio.sleep(0.05)  # short admitted and decoding
+            # short admitted and decoding, told by its tokens: a sleep says
+            # so only where the first step's compile is already cached, and
+            # since a tick fuses no more decode steps than hide its own
+            # work the short request has no other head start (six tokens
+            # to go against seven chunks of the long prompt)
+            await decoding.wait()
             t_long = asyncio.ensure_future(long_prompt())
             await asyncio.gather(t_short, t_long)
             assert order.index("short-done") < order.index("long-first-token")
