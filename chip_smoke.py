@@ -803,6 +803,14 @@ def moe_grouped_rows(args, jax) -> None:
          compiled=not args.rehearse)
 
 
+def device_peak(args, jax) -> dict:
+    """The chip's published peaks (``benchmark/peaks.json``, by device kind;
+    a rehearsal reads the v5e's)."""
+    with open(os.path.join(ROOT, "benchmark", "peaks.json")) as f:
+        peaks = json.load(f)
+    return peaks["TPU v5e" if args.rehearse else jax.devices()[0].device_kind]
+
+
 def latent_packed_times(args, jax) -> None:
     """``latent_packed_attention`` alone at ``mistral-small-4-119b``'s widths
     (32 heads, C 256, R 64, a pool of 32768 pages, both halves of a slab):
@@ -843,9 +851,7 @@ def latent_packed_times(args, jax) -> None:
                  ("decodes", 16, 1, 1, 32767, B - 1)]
     page, slabs = 16, 3
     cfg = dict(num_attention_heads=Hq, kv_lora_rank=C, qk_rope_head_dim=R)
-    with open(os.path.join(ROOT, "benchmark", "peaks.json")) as f:
-        peaks = json.load(f)
-    peak = peaks["TPU v5e" if args.rehearse else jax.devices()[0].device_kind]
+    peak = device_peak(args, jax)
     key = jax.random.PRNGKey(args.seed)
     pool = LatentKV(jax.random.normal(
         key, (slabs, 1, pages, page, 1, 2 * (C + R)), dt), C)
@@ -915,6 +921,134 @@ def latent_packed_times(args, jax) -> None:
             emit(phase="kernels", failed=row, tolerance=tol)
             sys.exit(1)
     emit(phase="kernels", latent_packed=table_out, compiled=not args.rehearse)
+
+
+def work_list_item_times(args, jax) -> None:
+    """The pair pools' work-list kernel alone, by what a launch costs an
+    item: the decode launch (one item a lane) at four cells' lanes, contexts
+    and heads, then the packed launch with a chunk's wide tiles ahead of the
+    decode rows.  Milliseconds a launch on the device (``reps`` launches
+    chained in one executable, each reading the one before), microseconds an
+    item, and the least an item's K and V bytes could take.  Every launch is
+    compared with the XLA gather over the same pool."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.engine import attention as att
+    from dynamo_tpu.engine.kv_cache import index_kv_layer
+    from dynamo_tpu.ops.ragged_attention import (
+        decode_work_list_attention, packed_ragged_attention,
+    )
+
+    Hq, D, page = (4, 128, 8) if args.rehearse else (32, 128, 16)
+    if args.rehearse:
+        dt, reps, interp, pages = jnp.float32, 2, True, 256
+        # (name, lanes, context, kv heads, window, table width, chunk rows)
+        cases = [("decode 4x300", 4, 300, 2, 0, 64, 0),
+                 ("decode 4x300 window 128", 4, 300, 2, 128, 64, 0),
+                 ("packed 28 + 3 rows", 4, 300, 2, 0, 64, 28)]
+    else:
+        dt, reps, interp, pages = jnp.bfloat16, 100, False, 6144
+        cases = [
+            # batch-closed, docqa-open, mixedlen-open's window layers,
+            # sessions-open (two 64-wide heads a pool row)
+            ("mixtral-8x7b", 32, 930, 8, 0, 256, 0),
+            ("mistral-7b", 16, 3500, 8, 4096, 528, 0),
+            ("mellum2 window", 32, 3000, 4, 1024, 2064, 0),
+            ("lfm2 narrow", 32, 1500, 4, 0, 448, 0),
+            # a chunk's wide tiles with 15 decode rows behind them, as
+            # docqa-open's chunk steps launch them
+            ("mistral-7b 512 rows", 16, 3500, 8, 4096, 528, 496),
+            ("mistral-7b 1024 rows", 16, 3500, 8, 4096, 528, 1008),
+        ]
+    peak = device_peak(args, jax)
+    tol = TOLERANCE["float32" if args.rehearse else "bfloat16"]
+    key = jax.random.PRNGKey(args.seed)
+    table_out = []
+    for name, B, ctx, Hkv, window, P, chunk in cases:
+        # every lane owns its pages; lanes' contexts differ by a few tokens
+        need = -(-(ctx + 1) // page)
+        assert 1 + B * need <= pages and need <= P, name
+        pool = jax.random.normal(
+            jax.random.fold_in(key, Hkv), (2, 2, pages, page, Hkv, D), dt)
+        table = np.zeros((B, P), np.int32)
+        table[:, :need] = 1 + np.arange(B * need).reshape(B, need)
+        table = jnp.asarray(table)
+        lens = np.asarray([ctx - 3 * (b % 5) for b in range(B)], np.int32)
+        # queries are drawn small: a diffuse softmax, as trained weights give
+        if chunk:
+            q_lens = np.ones(B, np.int32)
+            q_lens[0] = chunk  # the chunk first, the decode rows behind it
+            base = lens - q_lens
+            off = np.concatenate([[0], np.cumsum(q_lens)[:-1]]).astype(np.int32)
+            Np = chunk + B  # one row of padding
+            s_max = Np // 2
+            lane = np.append(np.repeat(np.arange(B), q_lens), B).astype(np.int32)
+            rel = np.arange(Np, dtype=np.int32) - off[np.minimum(lane, B - 1)]
+            rel[-1] = 0
+            q, k, v = (
+                jax.random.normal(jax.random.fold_in(key, i), (Np, h, D), dt) / 16
+                for i, h in ((1, Hq), (2, Hkv), (3, Hkv)))
+            vecs = [jnp.asarray(a) for a in (base, off, q_lens)]
+            written = att.write_packed_kv(
+                pool, k, v, table, jnp.asarray(lane),
+                jnp.asarray(base[np.minimum(lane, B - 1)] + rel),
+                jnp.asarray(lane < B), 1)
+
+            def launch(q, pool):
+                return packed_ragged_attention(
+                    q, k, v, pool, table, *vecs, s_max, 1, window,
+                    interpret=interp)
+
+            pool = written
+
+            # a row is a decode row at its own position: the gather over a
+            # sample of the chunk's rows and every decode row
+            valid = np.asarray([0, chunk // 2] + list(range(chunk - 1, Np - 1)))
+            ref = att.paged_decode_attention(
+                q[valid], index_kv_layer(pool, 1), table[lane[valid], :need],
+                jnp.asarray(base[lane[valid]] + rel[valid] + 1), window)
+            items = -(-chunk // min(s_max, 256)) + B - 1
+        else:
+            q = jax.random.normal(key, (B, Hq, D), dt) / 16
+            at = jnp.asarray(lens)
+
+            def launch(q, pool):
+                return decode_work_list_attention(
+                    q, pool, table, at, 1, window, interpret=interp)
+
+            ref = att.paged_decode_attention(
+                q, index_kv_layer(pool, 1), table[:, :need], at, window)
+            valid, items = np.ones(B, bool), B
+
+        @jax.jit
+        def chained(q, pool):  # the pool an argument, not a constant
+            def body(q, _):
+                return q + launch(q, pool) * jnp.asarray(1e-6, dt), None
+
+            return jax.lax.scan(body, q, None, length=reps)[0]
+
+        got = np.asarray(jax.block_until_ready(launch(q, pool)), np.float32)
+        jax.block_until_ready(chained(q, pool))
+        t0 = time.perf_counter()
+        jax.block_until_ready(chained(q, pool))
+        ms = (time.perf_counter() - t0) / reps * 1e3
+        err = float(np.max(np.abs(got[valid] - np.asarray(ref, np.float32))))
+        seen = min(ctx, window) if window else ctx
+        least_us = seen * Hkv * D * 2 * jnp.dtype(dt).itemsize / peak[
+            "hbm_bytes_per_s"] * 1e6
+        row = dict(case=name, lanes=B, context=ctx, kv_heads=Hkv,
+                   window=window, chunk_rows=chunk, items=items,
+                   ms_launch=round(ms, 4), us_item=round(ms * 1e3 / items, 2),
+                   least_us_decode_item=round(least_us, 2),
+                   max_abs_err=round(err, 5))
+        table_out.append(row)
+        print(json.dumps(row), file=sys.stderr, flush=True)  # as they come
+        if not np.isfinite(got).all() or err > tol:
+            emit(phase="kernels", failed=row, tolerance=tol)
+            sys.exit(1)
+        del pool, ref
+    emit(phase="kernels", work_list_items=table_out, compiled=not args.rehearse)
 
 
 def child_kernels(args) -> None:
@@ -1088,6 +1222,7 @@ def child_kernels(args) -> None:
     emit(phase="kernels", tolerance=tol, compiled=not interp,
          max_abs_err_table=rows)
     del pool, qpool
+    work_list_item_times(args, jax)
     moe_grouped_rows(args, jax)
     latent_packed_times(args, jax)
 
@@ -1239,6 +1374,9 @@ def main(argv=None) -> int:
         return 0
     if args.child == "moe-grouped":  # that line of the kernels child, alone
         moe_grouped_rows(args, child_devices(args.rehearse, 1))
+        return 0
+    if args.child == "work-list-items":  # and that one
+        work_list_item_times(args, child_devices(args.rehearse, 1))
         return 0
     if args.child == "latent-packed":  # and that one
         latent_packed_times(args, child_devices(args.rehearse, 1))

@@ -4693,12 +4693,15 @@ class JaxEngine:
                 "d": serial,
             }
             # the launch as the dense pools' kernel walks it: its work
-            # items, and how many of them take the small tile
+            # items, how many of them take the small tile, and how many
+            # start on copies the item before them put in flight
             from ..ops.ragged_attention import packed_item_counts
 
-            dispatch_meta["items"], dispatch_meta["small"] = (
-                packed_item_counts(q_host[live], s_max)
-            )
+            (
+                dispatch_meta["items"],
+                dispatch_meta["small"],
+                dispatch_meta["chained"],
+            ) = packed_item_counts(q_host[live], s_max)
             # which kernels the dispatch takes: the latent path of its
             # packed launch, and what attends the fused steps after the
             # first (read once, at construction)

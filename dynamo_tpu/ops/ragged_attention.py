@@ -36,7 +36,8 @@ What is here:
 
 * :func:`decode_work_list_attention` -- the decode launch of the fused
   steps over a dense pool of 128-lane heads: the work-list kernel at the
-  ``(lanes, 1)`` tile with a fixed list, one item a lane.
+  ``(lanes, 1)`` tile with a fixed list, one item a lane, a query head a
+  row over each key block as it lies in the pool.
 
 ``interpret=True`` runs a kernel through the Pallas interpreter
 (CPU-testable); ``engine.attention.packed_ragged_attention_dispatch``
@@ -465,16 +466,40 @@ def _packed_kernel(
 # lane riding a chunk, every lane of a fused dispatch's first step) takes a
 # small tile.
 #
+# The items of a launch overlap (PR 46).  The list is scalar prefetch, so an
+# item knows the one after it: as it enters its last key block it starts that
+# item's query copy (``q_v`` is free once the queries lie heads-major or in
+# registers) and the page copies of that item's first key block, into the
+# slot its own last block does not use; which slot that is travels in an SMEM
+# scalar.  Its output copy it starts and leaves: the next item waits for it
+# where it next writes ``o_v``, or, where its tile spans rows of the output
+# that earlier items wrote, before it reads that span (after its first
+# block's compute, so the rows have had that long to land).  Only the first
+# of a run of live items fetches for itself, only the last waits for its own
+# output, and every semaphore is back at zero when the grid ends.
+#
+# The one-row tile (every decode row: alone in the ``(lanes, 1)`` launches,
+# the packed step's and the fused steps', or an item beside a chunk's) has
+# its own body.  Measured alone (PERF.md section 5, PR 46), a launch of
+# one-row items spent its time turning each key block heads-major and running
+# 16 padded rows a query head, not waiting for copies.  The kernel is handed
+# the pool a second time as the same bytes seen ``[page * Hkv, D]`` a page; a
+# one-row item's pages land as one ``[KB * Hkv, D]`` matrix a block, its
+# ``Hq`` query heads are the rows of one product against all of it, and a
+# mask keeps, for a head, the columns of its own kv head: no transpose, no
+# head loop, no padding rows, at ``Hkv`` times the multiplications, which the
+# MXU has to spare while the block's bytes arrive.
+#
 # A tile never leaves the packed axis: one that would overhang ``Np`` (a
 # decode row in the last packed rows, the tail block of a chunk that fills
 # the axis) starts ``shift`` rows early, at ``Np - copy``, and the item's
 # rows lie ``shift`` into it.  So nothing but ``total <= Np`` binds the
 # packed shape (``engine.attention.PackedLaunch.item_rows``).  An item
 # writes its own rows into what its tile's span of the output holds, read
-# first: items run in ascending row order and each waits for its output
-# copy, so the rows before its own are what earlier items wrote, the rows
-# after it the zeros the output buffer is created with, which the rows no
-# item covers keep.
+# first: items run in ascending row order and each reads its span only once
+# the item before it has landed, so the rows before its own are what earlier
+# items wrote, the rows after it the zeros the output buffer is created
+# with, which the rows no item covers keep.
 
 # query rows (tokens) of one work item, and the rows at or under which an
 # item takes the small tile
@@ -499,31 +524,33 @@ def _takes_work_list(D: int, quant: bool) -> bool:
 
 
 def _work_list_tiles(s_max: int, dtype):
-    """The kernel's tiles as ``(copy rows, tile rows)``, small first: an
-    item moves ``copy`` rows by DMA and computes on a tile padded to whole
-    sublanes.  One tile where a query block is no larger than the small
-    tile (the ``(lanes, 1)`` step)."""
+    """The kernel's tiles as ``(copy rows, tile rows)``, smallest first: an
+    item takes the smallest that holds its rows, moves ``copy`` rows by DMA
+    and computes on a tile padded to whole sublanes.  The one-row tile has a
+    body of its own (a query head a row); the ``(lanes, 1)`` step has it
+    alone."""
     qb = min(s_max, _WL_Q_BLOCK)
     sub = _sublanes(dtype)
-    small = min(_WL_SMALL_ROWS, qb)
-    tiles = [(small, max(small, sub))]
-    if qb > small:
-        tiles.append((qb, max(qb, sub)))
-    return qb, tiles
+    copies = sorted({1, min(_WL_SMALL_ROWS, qb), qb})
+    return qb, [(copy, max(copy, sub)) for copy in copies]
 
 
 def packed_item_counts(q_lens, s_max: int):
-    """``(items, small)`` of a packed launch over a dense pool, from the
-    lanes' fresh rows on the host: its live work items, and how many of
-    them take the small tile (the tick's ``dispatch`` annotation)."""
-    qb, tiles = _work_list_tiles(s_max, jnp.float32)
-    small = tiles[0][0]
+    """``(items, small, chained)`` of a packed launch over a dense pool, from
+    the lanes' fresh rows on the host: its live work items, how many of them
+    have at most ``_WL_SMALL_ROWS`` rows (the one-row and the small tile),
+    and how many find their queries and first key block already in flight,
+    because the item before them in the list is live (the list packs its
+    live items first, so all but the first: the tick's ``dispatch``
+    annotation)."""
+    qb, _ = _work_list_tiles(s_max, jnp.float32)
+    small = min(_WL_SMALL_ROWS, qb)
     items = n_small = 0
     for n in q_lens:
         full, rest = divmod(int(n), qb)
         items += full + (rest > 0)
         n_small += full * (qb <= small) + (0 < rest <= small)
-    return items, n_small
+    return items, n_small, max(items - 1, 0)
 
 
 def _work_list_kernel(
@@ -537,46 +564,112 @@ def _work_list_kernel(
     # operands (HBM)
     q_hbm,  # [Np, Hq, D]
     kv_hbm,  # [L, 2, num_pages, page, Hkv, D], the fresh rows in it
+    kv_flat,  # the same pool, a page as one matrix [page * Hkv, D]
     _o_init,  # the zeroed output buffer (aliased to o_hbm)
     o_hbm,  # [Np, Hq, D]
-    # scratch
+    # scratch: :func:`_work_list_scratch`
     q_v,  # [rows_t, Hq, D] an item's queries as they lie in HBM
-    q_t,  # [Hkv, n_rep * rows_t, D] heads-major
-    kbuf,  # [2, 2, KB, Hkv, D] two slots of a key block's K and V pages
-    kv_t,  # [2, Hkv, KB, D] the current block heads-major
-    m_scr, l_scr,  # [Hkv, n_rep * rows_t, 1]
-    acc_scr,  # [Hkv, n_rep * rows_t, D]
     o_v,  # [rows_t, Hq, D]
+    kflat,  # [2, 2, KB * Hkv, D] two slots of a key block as it lies
+    slot_ref,  # [1] SMEM: the slot the next item's first key block takes
     sem_q, sem_kv, sem_o,
-    *,
+    *rows_scratch,  # what tiles of several rows need besides
     tiles,
     window: int,
 ):
+    """One work item a grid step, in list order, and the items overlap: an
+    item that a live item follows starts that item's query copy and the page
+    copies of its first key block as it enters its own last block, and
+    leaves its output copy in flight for that item to wait for.  So a live
+    item behind a live one begins with copies that have had a block's
+    compute to land, and only the first of a run fetches for itself.
+
+    Two bodies, by the item's tile.  A tile of several rows (chunks, verify
+    columns) turns a key block heads-major and runs a kv head's group of
+    rows against its keys.  The one-row tile (a decode row, alone in the
+    ``(lanes, 1)`` launches or beside a chunk) has a query head a row: it
+    multiplies its ``Hq`` rows with the block as it lies, every kv head's
+    keys side by side as ``KB * Hkv`` columns, and masks the columns of
+    other heads, so that nothing is transposed and no row is padding."""
+    if rows_scratch:
+        q_t, kbuf, kv_t, m_scr, l_scr, acc_scr = rows_scratch
     w = pl.program_id(0)
-    rows = w_rows[w]
-    _, _, KB, Hkv, D = kbuf.shape
-    Np, Hq = q_hbm.shape[0], q_v.shape[1]
+    W = pl.num_programs(0)
+    Np, Hq, D = q_hbm.shape
+    page, Hkv = kv_hbm.shape[3:5]
     n_rep = Hq // Hkv
-    page = kv_hbm.shape[3]
+    KB = _key_block(page)
     P = pt_ref.shape[1]
     n_pg = KB // page
     scale = 1.0 / (D ** 0.5)
     layer = layer_ref[0]
-    # kv heads a step, while their score tiles stay within budget
-    M_wide = n_rep * tiles[-1][1]
-    hb = math.gcd(Hkv, max(_WL_SCORE_BYTES // (4 * M_wide * KB), 1))
+
+    def item(i):
+        """Item ``i`` of the list: ``(lane, first row, that row's position,
+        rows)`` and the pages ``[pg_lo, pg_hi)`` and key blocks ``[kb_lo,
+        kb_hi)`` its rows can see."""
+        pos0, n = w_pos0[i], w_rows[i]
+        last = pos0 + n - 1  # the last live row's position
+        first = jnp.maximum(pos0 - window + 1, 0) if window > 0 else 0
+        div = jax.lax.div  # of positions, which are never negative
+        return (w_lane[i], w_row0[i], pos0, n), (
+            div(first, page), jnp.minimum(div(last, page) + 1, P),
+            div(first, KB), div(last, KB) + 1,
+        )
+
+    # this item and its neighbours in the list: one with no rows neither
+    # brings nor is brought for
+    (lane, row0, pos0, rows), (pg_lo, pg_hi, kb_lo, kb_hi) = me = item(w)
+    after = item(jnp.minimum(w + 1, W - 1))
+    next_rows = jnp.where(w + 1 < W, after[0][3], 0)
+    prev_rows = jnp.where(w > 0, w_rows[jnp.maximum(w - 1, 0)], 0)
 
     @pl.when(w == 0)
     def _clear():
         # a block's dead pages are never fetched: what the slots hold there
         # meets a probability of zero, and must be finite
-        kbuf[...] = jnp.zeros(kbuf.shape, kbuf.dtype)
+        kflat[...] = jnp.zeros(kflat.shape, kflat.dtype)
+        if rows_scratch:
+            kbuf[...] = jnp.zeros(kbuf.shape, kbuf.dtype)
+        slot_ref[0] = 0
 
-    def page_copy(pid, slot, j):
-        """One page's K and V into place ``j`` of a slot."""
+    def on_tile(n, body) -> None:
+        """``body(copy, nrow)`` at the tile an item of ``n`` rows takes: the
+        smallest that holds them."""
+        if len(tiles) == 1:
+            body(*tiles[0])
+            return
+        under = 0
+        for copy, nrow in tiles:
+            fits = n > under
+            if copy < tiles[-1][0]:
+                fits = fits & (n <= copy)
+            pl.when(fits)(functools.partial(body, copy, nrow))
+            under = copy
+
+    def tile_start(copy, row0):
+        """A tile's first row: the item's, or earlier where the tile would
+        overhang the axis (a one-row tile never does)."""
+        return jnp.minimum(row0, Np - copy) if copy > 1 else row0
+
+    def q_copy(copy, start):
         return pltpu.make_async_copy(
-            kv_hbm.at[layer, :, pid],
-            kbuf.at[slot, :, pl.ds(j * page, page)],
+            q_hbm.at[pl.ds(start, copy)], q_v.at[pl.ds(0, copy)], sem_q.at[0]
+        )
+
+    def o_copy(copy, start):
+        """A tile's rows out, into its span of the output."""
+        return pltpu.make_async_copy(
+            o_v.at[pl.ds(0, copy)], o_hbm.at[pl.ds(start, copy)], sem_o.at[0]
+        )
+
+    def page_copy(flat, pid, slot, j):
+        """One page's K and V into place ``j`` of a slot, as it lies
+        (``flat``: the one-row tile's) or a token a row of heads."""
+        pool, slots = (kv_flat, kflat) if flat else (kv_hbm, kbuf)
+        n = pool.shape[3]
+        return pltpu.make_async_copy(
+            pool.at[layer, :, pid], slots.at[slot, :, pl.ds(j * n, n)],
             sem_kv.at[slot],
         )
 
@@ -584,68 +677,151 @@ def _work_list_kernel(
         """The live pages of key block ``kb``."""
         return jnp.maximum(kb * n_pg, pg_lo), jnp.minimum((kb + 1) * n_pg, pg_hi)
 
-    def fetch(lane, kb, slot, pg_lo, pg_hi):
+    def fetch(flat, lane, kb, slot, pg_lo, pg_hi):
         lo, hi = pages_of(kb, pg_lo, pg_hi)
 
         def start(pg, carry):
-            page_copy(pt_ref[lane, pg], slot, pg - kb * n_pg).start()
+            page_copy(flat, pt_ref[lane, pg], slot, pg - kb * n_pg).start()
             return carry
 
         jax.lax.fori_loop(lo, hi, start, 0)
 
-    def wait(kb, slot, pg_lo, pg_hi):
+    def wait(flat, kb, slot, pg_lo, pg_hi):
         lo, hi = pages_of(kb, pg_lo, pg_hi)
 
         def done(pg, carry):
-            page_copy(0, slot, 0).wait()
+            page_copy(flat, 0, slot, 0).wait()
             return carry
 
         jax.lax.fori_loop(lo, hi, done, 0)
 
-    def attend(copy, nrow, lane, row0, pos0):
-        """Online softmax of an item's ``rows`` tokens over the key blocks
-        they can see, on a tile of ``nrow`` rows of which ``copy`` move."""
+    def bring(it, slot):
+        """Start what an item begins with: its queries, and its first key
+        block's pages into ``slot``, both as its tile takes them."""
+        (lane, row0, _, n), (pg_lo, pg_hi, kb_lo, _) = it
+
+        def start(copy, _nrow):
+            q_copy(copy, tile_start(copy, row0)).start()
+            fetch(copy == 1, lane, kb_lo, slot, pg_lo, pg_hi)
+
+        on_tile(n, start)
+
+    def previous_rows_are_out():
+        """The item before left its output copy in flight: until it lands,
+        ``o_v`` is its source, and its rows of the output are not there for
+        a tile that spans them to read."""
+
+        @pl.when(prev_rows > 0)
+        def _():
+            on_tile(prev_rows, lambda copy, _nrow: o_copy(copy, 0).wait())
+
+    def walk(flat, compute, carry):
+        """The item's key blocks in order from the slot it was handed, each
+        waited for while the next one's pages, or after the last what the
+        next item begins with, are in flight: ``compute(kb, slot, carry)``."""
+        slot0 = slot_ref[0]
+
+        def block(kb, carry):
+            slot = jax.lax.rem(slot0 + kb - kb_lo, 2)
+            wait(flat, kb, slot, pg_lo, pg_hi)
+
+            @pl.when(kb + 1 < kb_hi)
+            def _():
+                fetch(flat, lane, kb + 1, 1 - slot, pg_lo, pg_hi)
+
+            @pl.when((kb + 1 == kb_hi) & (next_rows > 0))
+            def _():
+                bring(after, 1 - slot)
+
+            return compute(kb, slot, carry)
+
+        carry = jax.lax.fori_loop(kb_lo, kb_hi, block, carry)
+        # the next item's first block went into the slot the last block left
+        slot_ref[0] = jax.lax.rem(slot0 + kb_hi - kb_lo, 2)
+        return carry
+
+    def send(copy, start):
+        """The tile's rows out; waited for where ``o_v`` is next written:
+        by the next item, or here when none follows."""
+        o_out = o_copy(copy, start)
+        o_out.start()
+
+        @pl.when(next_rows == 0)
+        def _():
+            o_out.wait()
+
+    def attend_row(copy, _nrow):
+        """The one-row tile: online softmax of the item's token, a query
+        head a row, over the key blocks as they lie."""
+        dt = q_v.dtype
+        C = KB * Hkv  # a block's columns: key ``c // Hkv`` of kv head ``c % Hkv``
+        q_copy(copy, row0).wait()
+        q = q_v[0]  # [Hq, D]; ``q_v`` is free from here on
+        col = jax.lax.broadcasted_iota(jnp.int32, (Hq, C), 1)
+        head = jax.lax.broadcasted_iota(jnp.int32, (Hq, C), 0)
+        group = jax.lax.rem(col, Hkv) * n_rep  # the column's first query head
+        own = (head >= group) & (head < group + n_rep)
+
+        def compute(kb, slot, carry):
+            m_prev, l_prev, acc = carry
+            k = kflat[slot, 0].astype(dt)  # [C, D]
+            v = kflat[slot, 1].astype(dt)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [Hq, C]
+            # key ``c // Hkv`` of the block is at or before the token's
+            # position, and inside its window
+            keep = own & (col < (pos0 - kb * KB + 1) * Hkv)
+            if window > 0:
+                keep = keep & (col >= (pos0 - window + 1 - kb * KB) * Hkv)
+            s = jnp.where(keep, s * scale, _NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            pv = jax.lax.dot_general(
+                p.astype(dt), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [Hq, D]
+            return (m_new, l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                    acc * alpha + pv)
+
+        _, l, acc = walk(True, compute, (
+            jnp.full((Hq, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((Hq, 1), jnp.float32),
+            jnp.zeros((Hq, D), jnp.float32),
+        ))
+        previous_rows_are_out()
+        o_v[0] = (acc / l).astype(o_v.dtype)
+        send(copy, row0)
+
+    def attend_rows(copy, nrow):
+        """A tile of ``nrow`` rows of which ``copy`` move: online softmax of
+        the item's ``rows`` tokens, a kv head's group of rows at a time,
+        over the key blocks turned heads-major."""
         M = n_rep * nrow
-        # the tile's first row and its position: the item's, or ``shift``
-        # rows earlier where the tile would overhang the axis (a one-row
-        # tile never does, and is traced as it always was)
-        clamps = copy > 1
-        start, pos_t = row0, pos0
-        if clamps:
-            start = jnp.minimum(row0, Np - copy)
-            shift = row0 - start
-            pos_t = pos0 - shift
-        q_in = pltpu.make_async_copy(
-            q_hbm.at[pl.ds(start, copy)], q_v.at[pl.ds(0, copy)], sem_q.at[0]
-        )
-        q_in.start()
-        o_span = o_hbm.at[pl.ds(start, copy)]
-        if clamps:
-            o_in = pltpu.make_async_copy(
-                o_span, o_v.at[pl.ds(0, copy)], sem_o.at[0]
-            )
-            o_in.start()
-        last = pos0 + rows - 1  # the last live row's position
-        first = jnp.maximum(pos0 - window + 1, 0) if window > 0 else 0
-        pg_lo, pg_hi = first // page, jnp.minimum(last // page + 1, P)
-        kb_lo, kb_hi = first // KB, last // KB + 1
-        fetch(lane, kb_lo, 0, pg_lo, pg_hi)
-        q_in.wait()
+        # kv heads a step, while their score tiles stay within budget
+        M_wide = n_rep * tiles[-1][1]
+        hb = math.gcd(Hkv, max(_WL_SCORE_BYTES // (4 * M_wide * KB), 1))
+        # the item's rows lie ``shift`` into a tile that starts early
+        start = tile_start(copy, row0)
+        shift = row0 - start
+        pos_t = pos0 - shift
+        q_copy(copy, start).wait()
+        # ``q_v`` is free from here on: the next item's queries land in it
         q_t[:, :M] = (
             q_v[:nrow].transpose(1, 0, 2).reshape(Hkv, M, D)
         )
         m_scr[:, :M] = jnp.full((Hkv, M, 1), _NEG_INF, jnp.float32)
         l_scr[:, :M] = jnp.zeros((Hkv, M, 1), jnp.float32)
         acc_scr[:, :M] = jnp.zeros((Hkv, M, D), jnp.float32)
+        # the tile's span of the output, read before the item's rows are
+        # written into it
+        o_in = pltpu.make_async_copy(
+            o_hbm.at[pl.ds(start, copy)], o_v.at[pl.ds(0, copy)], sem_o.at[0]
+        )
 
-        def block(kb, carry):
-            slot = (kb - kb_lo) % 2
-            wait(kb, slot, pg_lo, pg_hi)
-
-            @pl.when(kb + 1 < kb_hi)
-            def _():
-                fetch(lane, kb + 1, 1 - slot, pg_lo, pg_hi)
-
+        def compute(kb, slot, carry):
             for side in range(2):
                 kv_t[side] = (
                     kbuf[slot, side].transpose(1, 0, 2).astype(kv_t.dtype)
@@ -690,39 +866,67 @@ def _work_list_kernel(
                 return carry
 
             jax.lax.fori_loop(0, Hkv // hb, heads, 0)
+
+            # needed only at the merge, and not before the rows of the item
+            # before, which the span may hold, have landed: they have had
+            # this block's compute to
+            @pl.when(kb == kb_lo)
+            def _():
+                previous_rows_are_out()
+                o_in.start()
+
             return carry
 
-        jax.lax.fori_loop(kb_lo, kb_hi, block, 0)
+        walk(False, compute, 0)
         out = (acc_scr[:, :M] / l_scr[:, :M]).astype(o_v.dtype)
         out = out.reshape(Hq, nrow, D).transpose(1, 0, 2)  # [nrow, Hq, D]
         at = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
-        if clamps:
-            mine = (at >= shift) & (at < shift + rows)
-            o_in.wait()
-            o_v[:nrow] = jnp.where(mine, out, o_v[:nrow])
-        else:
-            o_v[:nrow] = jnp.where(at < rows, out, jnp.zeros_like(out))
-        o_out = pltpu.make_async_copy(
-            o_v.at[pl.ds(0, copy)], o_span, sem_o.at[0]
-        )
-        o_out.start()
-        o_out.wait()
+        mine = (at >= shift) & (at < shift + rows)
+        o_in.wait()
+        o_v[:nrow] = jnp.where(mine, out, o_v[:nrow])
+        send(copy, start)
 
     @pl.when(rows > 0)
     def _item():
-        args = (w_lane[w], w_row0[w], w_pos0[w])
-        (small, small_t), (wide, wide_t) = tiles[0], tiles[-1]
-        if len(tiles) == 1:
-            attend(small, small_t, *args)
-            return
-
-        @pl.when(rows <= small)
+        # the first of a run of live items fetches for itself
+        @pl.when(prev_rows == 0)
         def _():
-            attend(small, small_t, *args)
+            bring(me, slot_ref[0])
 
-        @pl.when(rows > small)
-        def _():
-            attend(wide, wide_t, *args)
+        on_tile(rows, lambda copy, nrow: (
+            attend_row if copy == 1 else attend_rows)(copy, nrow))
+
+
+def _key_block(page: int) -> int:
+    """Keys of a key block: whole pages, ``_WL_KEY_BLOCK`` or a page."""
+    return max(page, _WL_KEY_BLOCK // page * page)
+
+
+def _work_list_scratch(tiles, Hq, Hkv, D, page, dtype, kv_dtype):
+    """The kernel's scratch at the launch's tiles: the queries' and the
+    output's tile, two slots of a key block as it lies in the pool, the
+    scalar that hands a slot from item to item and the copies' semaphores;
+    where there are tiles of several rows also their queries heads-major,
+    two slots of a key block a token a row of heads, the current block
+    heads-major and the softmax's running state."""
+    rows_t, KB = tiles[-1][1], _key_block(page)
+    tile = pltpu.VMEM((rows_t, Hq, D), dtype)
+    scratch = [
+        tile, tile, pltpu.VMEM((2, 2, KB * Hkv, D), kv_dtype),
+        pltpu.SMEM((1,), jnp.int32), pltpu.SemaphoreType.DMA((1,)),
+        pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA((1,)),
+    ]
+    if len(tiles) > 1:
+        M = Hq // Hkv * rows_t
+        scratch += [
+            pltpu.VMEM((Hkv, M, D), dtype),
+            pltpu.VMEM((2, 2, KB, Hkv, D), kv_dtype),
+            pltpu.VMEM((2, Hkv, KB, D), dtype),
+            pltpu.VMEM((Hkv, M, 1), jnp.float32),
+            pltpu.VMEM((Hkv, M, 1), jnp.float32),
+            pltpu.VMEM((Hkv, M, D), jnp.float32),
+        ]
+    return scratch
 
 
 def _work_list_launch(
@@ -735,33 +939,19 @@ def _work_list_launch(
     Np, Hq, D = q.shape
     L, _, num_pages, page, Hkv, _ = kv_pages.shape
     _, tiles = _work_list_tiles(s_max, q.dtype)
-    n_rep = Hq // Hkv
-    rows_t = tiles[-1][1]
-    KB = max(page, _WL_KEY_BLOCK // page * page)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(lane.shape[0],),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 4,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((rows_t, Hq, D), q.dtype),
-            pltpu.VMEM((Hkv, n_rep * rows_t, D), q.dtype),
-            pltpu.VMEM((2, 2, KB, Hkv, D), kv_pages.dtype),
-            pltpu.VMEM((2, Hkv, KB, D), q.dtype),
-            pltpu.VMEM((Hkv, n_rep * rows_t, 1), jnp.float32),
-            pltpu.VMEM((Hkv, n_rep * rows_t, 1), jnp.float32),
-            pltpu.VMEM((Hkv, n_rep * rows_t, D), jnp.float32),
-            pltpu.VMEM((rows_t, Hq, D), q.dtype),
-            pltpu.SemaphoreType.DMA((1,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((1,)),
-        ],
+        scratch_shapes=_work_list_scratch(
+            tiles, Hq, Hkv, D, page, q.dtype, kv_pages.dtype),
     )
     return pl.pallas_call(
         functools.partial(_work_list_kernel, tiles=tiles, window=window),
         out_shape=jax.ShapeDtypeStruct((Np, Hq, D), q.dtype),
         grid_spec=grid_spec,
-        input_output_aliases={8: 0},  # the zeroed buffer, after 6 scalars
+        input_output_aliases={9: 0},  # the zeroed buffer, after 6 scalars
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_CAP_BYTES,
@@ -772,7 +962,10 @@ def _work_list_launch(
         jnp.clip(jnp.asarray(layer, jnp.int32), 0, L - 1).reshape(1),
         jnp.clip(page_table.astype(jnp.int32), 0, num_pages - 1),
         lane, row0, pos0, rows,
-        q, kv_pages, jnp.zeros((Np, Hq, D), q.dtype),
+        # the pool twice: as it is, and a page as one matrix, its tokens' kv
+        # heads row after row (the same bytes: no copy is made)
+        q, kv_pages, kv_pages.reshape(L, 2, num_pages, page * Hkv, D),
+        jnp.zeros((Np, Hq, D), q.dtype),
     )
 
 
@@ -816,8 +1009,9 @@ def decode_work_list_attention(
 ) -> jax.Array:
     """The decode launch of the fused steps over a dense pool of 128-lane
     heads (:func:`_takes_work_list`): the work-list kernel at the ``(lanes,
-    1)`` tile with a fixed list, one item a lane that holds a token, from
-    its window's first key block to the block of its own position.  No step
+    1)`` tile (a query head a row, the key blocks as they lie) with a fixed
+    list, one item a lane that holds a token, from its window's first key
+    block to the block of its own position.  No step
     for a page group of the table's width (``paged_attention``'s grid), so
     the table may be as wide as the scheduler's; a lane with ``kv_lens`` 0
     has no item and its row stays zero.  The launch the ``(lanes, 1)`` packed

@@ -158,6 +158,11 @@ FULL_AXIS_CASES = {
                    suffix="_window"),
     # a short segment at a small query block: several small-tile items
     "small_blocks": dict(qlens=[1] * 3 + [21] + [1] * 6, s_max=16, Np=32),
+    # PR 40's case, extended (PR 46): two wide tiles, then eight one-row
+    # items whose 8-row tiles all start at row 504, over the second wide
+    # tile's span and over each other; each reads its span of the output
+    # only after the item before it has landed there
+    "wide_tile_then_eight_rows": dict(qlens=[504] + [1] * 8, s_max=256),
 }
 
 
@@ -269,9 +274,31 @@ DECODE_CASES = {
 }
 
 
-@pytest.mark.parametrize("name", list(DECODE_CASES))
+# lists that take every hand-over between the items of a launch (PR 46, the
+# section further down)
+HANDOVER_DECODE = {
+    # nothing before it, nothing behind it
+    "one_live_item": dict(lens=[0, 0, 700, 0]),
+    # an idle lane parts two runs: the item behind it fetches for itself
+    "live_idle_live": dict(lens=[300, 0, 300, 0, 0, 41]),
+    # the one block of the first item is its last: it brings at once, for an
+    # item that then fetches six more blocks; and the reverse
+    "one_block_then_seven": dict(lens=[100, 3500]),
+    "seven_blocks_then_one": dict(lens=[3500, 100]),
+    # 2, 3, 1, 3, 2, 1, 2 blocks: items start on either slot, and end on the
+    # slot they started on (odd) and on the other (even)
+    "ends_on_either_slot": dict(lens=[600, 1100, 100, 1030, 520, 9, 513]),
+    # a window layer: the first block brought is block 3, 0, 2 and 7
+    "window_first_block_is_not_block_0": dict(
+        lens=[3003, 1025, 2050, 5000], window=1024),
+    # no token in the first, a middle and the last lane
+    "no_token_first_middle_last": dict(lens=[0, 300, 0, 513, 40, 0]),
+}
+
+
+@pytest.mark.parametrize("name", list(DECODE_CASES) + list(HANDOVER_DECODE))
 def test_decode_work_list_matches_the_gather(name):
-    kw = dict(DECODE_CASES[name])
+    kw = dict(DECODE_CASES.get(name) or HANDOVER_DECODE[name])
     window = kw.get("window", 0)
     q, pool, pt, lens = _decode_case(**kw)
     got = ra.decode_work_list_attention(
@@ -333,6 +360,112 @@ def test_decode_launch_of_a_two_kind_trunk(monkeypatch):
             2e-5)
 
 
+# -- the items of a launch overlap (PR 46) -------------------------------------
+#
+# An item that a live item follows starts that item's queries and first key
+# block as it enters its own last block, and leaves its output copy in flight
+# for that item to wait for.  At tiles of several rows the arithmetic is PR
+# 45's: those launches are held bit for bit to the kernel with PR 45's serial
+# order of copies (``tests/work_list_serial.py``).  The one-row tile (every
+# decode launch) multiplies a query head a row with the key block as it lies,
+# which sums a softmax in another order: its launches are held to the gather,
+# in the plain interpreter and in the TPU's, over lists that take every
+# hand-over.
+
+
+
+def _packed_call(name):
+    """A packed case of ``CASES`` or ``FULL_AXIS_CASES`` as the arguments of
+    ``packed_ragged_attention``."""
+    if name in CASES:
+        kw = dict(CASES[name])
+        window = kw.get("window", 0)
+        (q, k, v, pool, pt, base, off, lens, lane, rel), s_max, _ = _case(**kw)
+        Np, suffix = q.shape[0], ""
+    else:
+        kw = dict(FULL_AXIS_CASES[name])
+        qlens, s_max, window = kw["qlens"], kw["s_max"], kw.get("window", 0)
+        bases = [(37 * (b + 1)) % 300 for b in range(len(qlens))]
+        (q, k, v, pool, pt, base, off, lens, lane, rel), _, _ = _case(
+            4, 2, bases, qlens, window=window)
+        Np, suffix = kw.get("Np", 512), kw.get("suffix", "")
+    written = _scatter(pool, k, v, pt, base, lane, rel)
+    return (q[:Np], k[:Np], v[:Np], written, pt, base, off, lens, s_max,
+            LAYER, window), dict(name_suffix=suffix)
+
+
+def _serial_order(monkeypatch):
+    """Launches traced from here on take PR 45's kernel: call the entry
+    points unjitted (``__wrapped__``), a jitted one keeps what it traced."""
+    from tests.work_list_serial import serial_work_list_kernel
+
+    monkeypatch.setattr(ra, "_work_list_kernel", serial_work_list_kernel)
+
+
+@pytest.mark.parametrize(
+    "name", [n for n in list(CASES) + list(FULL_AXIS_CASES) if n != "decode_step"])
+def test_overlapped_packed_items_match_the_serial_order_bit_for_bit(
+        name, monkeypatch):
+    """Items of several rows: what moved is when a copy starts and who waits
+    for it, and not one bit of the result.  A one-row item beside them takes
+    the one-row tile, whose arithmetic is this PR's (a softmax summed in
+    another order): the same to rounding.  (``decode_step`` launches that
+    tile alone and the serial kernel has none: the cases above.)"""
+    args, kw = _packed_call(name)
+    lens, off = np.asarray(args[7]), np.asarray(args[6])
+    got = np.asarray(ra.packed_ragged_attention(*args, interpret=True, **kw))
+    _serial_order(monkeypatch)
+    want = np.asarray(
+        ra.packed_ragged_attention.__wrapped__(*args, interpret=True, **kw))
+    # a segment's last row is an item of its own where one row is left over
+    qb = ra._work_list_tiles(args[8], got.dtype)[0]
+    one_row = np.zeros(got.shape[0], bool)
+    one_row[(off + lens - 1)[lens % qb == 1]] = True
+    np.testing.assert_array_equal(got[~one_row], want[~one_row])
+    np.testing.assert_allclose(got[one_row], want[one_row], rtol=2e-5, atol=2e-5)
+    assert (~one_row).sum() > one_row.sum()
+
+
+@pytest.fixture
+def tpu_interpreter():
+    """The TPU interpreter with its race detector on: a copy moves when it
+    is waited for and not before, so a wait that comes late, or for the
+    wrong copy, shows in the result; a vector access that a copy in flight
+    could meet is a race; and a semaphore left off zero when the grid ends
+    fails the launch."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as ipc
+    from jax.experimental.pallas import tpu as pltpu
+
+    yield pltpu.InterpretParams(detect_races=True), lambda: ipc.races.races_found
+    pltpu.reset_tpu_interpret_mode_state()
+
+
+@pytest.mark.parametrize("name", list(HANDOVER_DECODE))
+def test_overlapped_decode_items_wait_for_what_they_read(name, tpu_interpreter):
+    params, raced = tpu_interpreter
+    kw = dict(HANDOVER_DECODE[name])
+    window = kw.get("window", 0)
+    q, pool, pt, lens = _decode_case(**kw)
+    got = ra.decode_work_list_attention(
+        q, pool, pt, lens, LAYER, window, interpret=params)
+    _assert_decode_rows(got, q, pool, pt, lens, LAYER, window, 2e-5)
+    assert not raced()
+
+
+@pytest.mark.parametrize(
+    "name", ["blocks_beside_decode", "idle_lanes_and_padding", "verify_columns",
+             "two_chunks", "window", "small_blocks", "wide_tile_then_eight_rows"])
+def test_overlapped_packed_items_wait_for_what_they_read(name, tpu_interpreter):
+    """A tile that spans rows of the items before it reads them only once
+    they have landed, and the last live item leaves nothing in flight."""
+    params, raced = tpu_interpreter
+    args, kw = _packed_call(name)
+    want = ra.packed_ragged_attention(*args, interpret=True, **kw)
+    got = ra.packed_ragged_attention(*args, interpret=params, **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not raced()
+
+
 def _eqns(jaxpr):
     """Every equation under ``jaxpr``, nested jaxprs included."""
     for eqn in jaxpr.eqns:
@@ -366,11 +499,17 @@ def test_dma_descriptors_do_not_grow_with_the_key_block(monkeypatch):
 
     at_128, at_512 = starts(128), starts(512)
     assert at_128 == at_512
-    # a tile: queries in, its span of the output in (PR 40: a tile that
-    # would overhang the axis starts early, over rows earlier items wrote),
-    # a page's K and V in one copy at the first fetch and at the next
-    # block's, rows out
-    assert at_512[0] <= 5 * len(ra._work_list_tiles(s_max, q.dtype)[1])
+    # PR 40's kernel started 5 copies a tile of its two (queries in, the
+    # tile's span of the output in, a page's K and V at the first fetch and
+    # at the next block's, rows out).  Since PR 46 there are three tiles, and
+    # an item starts what the item after it begins with at any tile's size
+    # (three query copies, three first fetches; one of each runs): 6, beside
+    # its own next block's pages and its rows out: 8 a tile, one more where
+    # a tile of several rows reads its span of the output back; the first of
+    # a run of live items starts the same 6 for itself, once: 6 + 3 * 8 + 2
+    tiles = ra._work_list_tiles(s_max, q.dtype)[1]
+    assert [copy for copy, _ in tiles] == [1, 8, 256]
+    assert at_512 == (32, 20)
 
 
 def test_item_counts_follow_the_work_list():
@@ -379,14 +518,18 @@ def test_item_counts_follow_the_work_list():
     from dynamo_tpu.ops.latent_attention import packed_work_list
 
     lens = [300, 1, 0, 256, 8, 9, 513]
-    assert ra.packed_item_counts(lens, 512) == (2 + 1 + 1 + 1 + 1 + 3, 3)
-    assert ra.packed_item_counts([1, 0, 1, 1], 1) == (3, 3)
+    assert ra.packed_item_counts(lens, 512) == (2 + 1 + 1 + 1 + 1 + 3, 3, 8)
+    assert ra.packed_item_counts([1, 0, 1, 1], 1) == (3, 3, 2)
+    assert ra.packed_item_counts([0, 0], 64) == (0, 0, 0)
+    assert ra.packed_item_counts([5], 64) == (1, 1, 0)
     qb = ra._work_list_tiles(512, jnp.bfloat16)[0]
     rows = np.asarray(packed_work_list(
         jnp.zeros(7, jnp.int32), jnp.zeros(7, jnp.int32),
         jnp.asarray(lens, jnp.int32), 2048, qb)[3])
     assert (rows > 0).sum() == 9
     assert ((rows > 0) & (rows <= ra._WL_SMALL_ROWS)).sum() == 3
+    # chained: a live item behind a live one (the list packs them first)
+    assert ((rows[1:] > 0) & (rows[:-1] > 0)).sum() == 8
 
 
 def test_unified_dispatches_take_the_whole_page_table(run, monkeypatch):
@@ -455,6 +598,11 @@ def test_unified_dispatches_take_the_whole_page_table(run, monkeypatch):
         assert min(bucketed[name]) < full, name
         assert widths[name] == {full}, name
     assert {m["pt"] for m in marks_full} == {full}
+    # one request: a chunk's items, then one decode row; every item but the
+    # launch's first starts on copies the item before it put in flight
+    for m in marks_full:
+        assert m["items"] >= 1 and 0 <= m["small"] <= m["items"]
+        assert m["chained"] == m["items"] - 1
     assert grid_marks and min(m["pt"] for m in grid_marks) < full
     # the stat is read where the step's trace reads it: on the CPU, the gather
     fused = [m for m in marks_full if m["k"] > 1]
