@@ -737,12 +737,18 @@ def packed_layout(Np: int, s_max: int, B: int, wide: int | None = None):
 
 
 def moe_grouped_rows(args, jax) -> None:
-    """The expert MLP both ways at Mixtral widths, by packed rows N: the
-    capacity buffers ``[E, C = N, H]`` against the grouped product over the
-    ``N*K`` routed rows (``ops.grouped_matmul``; half the rows masked as a
-    packed step's padding is, too).  This is the measurement
-    ``model._GROUPED_MIN_ROWS`` is set from: the smallest N from which the
-    grouped path is the faster by more than 2%."""
+    """The expert MLP both ways, by packed rows N: the capacity buffers
+    ``[E, C = N, H]`` against the grouped product over the ``N*K`` routed
+    rows (``ops.grouped_matmul``; half the rows masked as a packed step's
+    padding or a fused step's idle lanes are, too).  Two tables, the
+    measurements the two clauses of ``model._moe_takes_grouped`` are set
+    from.  At Mixtral widths (8 experts, top-2): the smallest N from which
+    the grouped path is the faster by more than 2% is
+    ``model._GROUPED_MIN_ROWS``.  At the widths of a router wider than the
+    step's assignments (top-4 of 128, 32 of them held here): the grouped
+    path reads only the experts a row reaches (``model._moe_reaches_few``;
+    N = 32 routes 128 and is the first step the rule leaves to the
+    buffers)."""
     import contextlib
 
     import jax.numpy as jnp
@@ -753,54 +759,69 @@ def moe_grouped_rows(args, jax) -> None:
     from dynamo_tpu.engine import attention as att
     from dynamo_tpu.engine import model as M
 
-    on_tpu = att._on_tpu
+    on_tpu, choice = att._on_tpu, M._moe_takes_grouped
+    kernel_mode, dtype = contextlib.nullcontext, "bfloat16"
+    dense = dict(hidden_size=4096, intermediate_size=14336, num_experts=8,
+                 num_experts_per_tok=2, moe_capacity_factor=4.0)
+    held = dict(hidden_size=4096, intermediate_size=2048, num_experts=128,
+                num_local_experts=32, num_experts_per_tok=4,
+                moe_capacity_factor=32.0)
+    rows, held_rows = (32, 64, 128, 256, 512, 1024), (8, 16, 32)
     if args.rehearse:
-        widths, dtype, rows = dict(hidden_size=128, intermediate_size=256), "float32", (16, 128)
+        small = dict(hidden_size=128, intermediate_size=256)
+        dense, held = {**dense, **small}, {**held, **small}
+        dtype, rows, held_rows = "float32", (16, 128), (8, 32)
         att._on_tpu = lambda: True  # the kernel itself, interpreted
         kernel_mode = pltpu.force_tpu_interpret_mode
-    else:
-        widths, dtype = dict(hidden_size=4096, intermediate_size=14336), "bfloat16"
-        rows, kernel_mode = (32, 64, 128, 256, 512, 1024), contextlib.nullcontext
-    cfg = ModelConfig(
-        vocab_size=256, num_layers=1, num_heads=32, num_kv_heads=8, head_dim=128,
-        dtype=dtype, num_experts=8, num_experts_per_tok=2, moe_capacity_factor=4.0,
-        **widths,
-    )
-    params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
-    lp = jax.tree.map(lambda a: a[0], params.pop("layers"))
-    set_at, table = M._GROUPED_MIN_ROWS, []
+    reps = 1 if args.rehearse else 10
 
-    def timed(threshold, x, valid):
-        M._GROUPED_MIN_ROWS = threshold  # a fresh jit traces the path anew
+    def timed(cfg, lp, grouped, x, valid):
+        M._moe_takes_grouped = lambda *a: grouped  # a fresh jit traces anew
         f = jax.jit(lambda l, y, v: M._moe_mlp(l, y, cfg, v))
         with kernel_mode():
             out = jax.block_until_ready(f(lp, x, valid))
             t0 = time.perf_counter()
-            for _ in range(1 if args.rehearse else 10):
+            for _ in range(reps):
                 out = f(lp, x, valid)
             jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / (1 if args.rehearse else 10) * 1e3, out
+        return (time.perf_counter() - t0) / reps * 1e3, out
 
-    try:
+    def table(widths, rows):
+        cfg = ModelConfig(
+            vocab_size=256, num_layers=1, num_heads=32, num_kv_heads=8,
+            head_dim=128, dtype=dtype, **widths,
+        )
+        params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
+        lp = jax.tree.map(lambda a: a[0], params.pop("layers"))
+        lines = []
         for n in rows:
             x = jax.random.normal(jax.random.PRNGKey(n), (1, n, cfg.hidden_size),
                                   jnp.dtype(dtype))
             half = (jnp.arange(n) < n // 2)[None]
-            cap_ms, cap = timed(1 << 30, x, None)
-            grp_ms, grp = timed(1, x, None)
-            half_ms, _ = timed(1, x, half)
+            cap_ms, cap = timed(cfg, lp, False, x, None)
+            grp_ms, grp = timed(cfg, lp, True, x, None)
+            half_ms, _ = timed(cfg, lp, True, x, half)
+            # no row at all: the kernel's visit axis is empty
+            _, none = timed(cfg, lp, True, x, jnp.zeros_like(half))
             cap, grp = np.asarray(cap, np.float32), np.asarray(grp, np.float32)
             err = float(np.max(np.abs(cap - grp)) / max(np.max(np.abs(cap)), 1e-9))
-            table.append(dict(N=n, capacity_ms=round(cap_ms, 3), grouped_ms=round(grp_ms, 3),
+            lines.append(dict(N=n, capacity_ms=round(cap_ms, 3), grouped_ms=round(grp_ms, 3),
                               grouped_half_masked_ms=round(half_ms, 3),
                               max_rel_diff=round(err, 5)))
-            if not np.isfinite(grp).all() or err > TOLERANCE[dtype]:
-                emit(phase="kernels", failed=table[-1], tolerance=TOLERANCE[dtype])
+            shared = np.asarray(M._shared_experts(lp, x[0], cfg), np.float32)
+            empty = np.max(np.abs(np.asarray(none, np.float32)[0] - shared))
+            if not np.isfinite(grp).all() or err > TOLERANCE[dtype] or empty > 0:
+                emit(phase="kernels", failed=lines[-1], tolerance=TOLERANCE[dtype],
+                     no_row_max_abs=float(empty))
                 sys.exit(1)
+        return lines
+
+    try:
+        by_rows, by_reach = table(dense, rows), table(held, held_rows)
     finally:
-        M._GROUPED_MIN_ROWS, att._on_tpu = set_at, on_tpu
-    emit(phase="kernels", moe_grouped=table, grouped_min_rows=set_at,
-         compiled=not args.rehearse)
+        M._moe_takes_grouped, att._on_tpu = choice, on_tpu
+    emit(phase="kernels", moe_grouped=by_rows, moe_grouped_held=by_reach,
+         grouped_min_rows=M._GROUPED_MIN_ROWS, compiled=not args.rehearse)
 
 
 def device_peak(args, jax) -> dict:

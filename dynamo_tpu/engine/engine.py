@@ -460,6 +460,10 @@ class InflightUnified:
     np_rows: int = 0
     used_rows: int = 0
     serial: int = 0
+    # [2] int32 on the device, of a decode-only dispatch whose expert MLPs
+    # read only the experts a row reaches (step._packed_unified_step): the
+    # experts read and the experts held, over its layers and steps
+    moe_reach: Any = None
     dispatched_at: float = field(default_factory=time.perf_counter)
 
 
@@ -4709,6 +4713,14 @@ class JaxEngine:
                 dispatch_meta["latent"] = self._latent_path
             if num_steps > 1:
                 dispatch_meta["decode"] = self._decode_backend
+            if self.model_cfg.is_moe:
+                # the layout the packed rows' expert MLPs take (a fused
+                # block's later steps ask the same of the lanes)
+                from .model import moe_layout
+
+                dispatch_meta["moe"] = moe_layout(
+                    self.params, self.model_cfg, Np
+                )
             if self.model_cfg.has_conv:
                 # which kernels the packed launch over the attention layers'
                 # pool takes, and the lanes whose convolution layers resume
@@ -4755,6 +4767,7 @@ class JaxEngine:
                 d["active"],
                 self.kv.pages,
                 self._rng,
+                *reach,
             ) = self._fns.packed_unified_multistep(
                 *operands, s_max, num_steps, s_spec, top_n, use_filters,
             )
@@ -4767,9 +4780,14 @@ class JaxEngine:
                 d["active"],
                 self.kv.pages,
                 self._rng,
+                *reach,
             ) = self._fns.packed_unified_step(
                 *operands, s_max, s_spec, top_n, use_filters,
             )
+        # what the decode steps' expert MLPs read is counted over decode-only
+        # dispatches: rows of a chunk or of a draft reach experts of their own
+        decode_only = not (n_pf_tokens or spec_lanes)
+        moe_reach = reach[0] if reach and decode_only else None
         # padded-token accounting: `used` real rows, `dispatched` what
         # actually ran -- the bench reports 1 - used/dispatched.  Multi-step
         # scan iterations each run (and use) one row per decode lane.
@@ -4802,6 +4820,8 @@ class JaxEngine:
         _start_host_copy(packed)
         if spec_lanes:
             _start_host_copy(spec_packed)
+        if moe_reach is not None:
+            _start_host_copy(moe_reach)
         if tick is not None:
             tick.note_dispatch("unified")
             tick.mark("dispatch", **dispatch_meta)
@@ -4823,6 +4843,7 @@ class JaxEngine:
             np_rows=Np,
             used_rows=used_tokens,
             serial=serial,
+            moe_reach=moe_reach,
         )
 
     # -- speculative decoding (spec/: draft on host, verify in one pass) ----
@@ -5525,10 +5546,14 @@ class JaxEngine:
         # the same bundled transfer
         lp_refs: List[Tuple[Any, int]] = []
         spec_refs: List[Tuple[Any, int]] = []
+        reach_at: List[int] = []  # the counts of experts read and held
         for e in entries:
             if isinstance(e, InflightUnified) and e.spec_sampled is not None:
                 spec_refs.append((e, len(handles)))
                 handles.append(e.spec_sampled)
+            if isinstance(e, InflightUnified) and e.moe_reach is not None:
+                reach_at.append(len(handles))
+                handles.append(e.moe_reach)
             pfs = (
                 e.entries
                 if isinstance(e, InflightPrefillGroup)
@@ -5626,6 +5651,8 @@ class JaxEngine:
             events.append(ev)
 
         self._record_service(entries, service, now)
+        for i in reach_at:
+            self.obs.observe_moe_reach(int(mats[i][0]), int(mats[i][1]))
         for e, mat in zip(entries, mats):
             if isinstance(e, InflightPrefillGroup):
                 for i, pf in enumerate(e.entries):

@@ -334,6 +334,26 @@ def _moe_mlp_dense(lp: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
 #   1024    16.078    5.946    (4.593 with half the rows masked as padding)
 _GROUPED_MIN_ROWS = 256
 
+
+# A step under that takes the grouped product all the same where it routes
+# fewer assignments than the router has experts, ``N*K < num_experts``: under
+# one row an expert on average, so most matrices have no row, the grouped
+# product does not read a group with no row, and the buffers' einsum reads
+# every held expert whatever was routed.  chip_smoke.py's "moe_grouped_held"
+# line (one v5e; top-4 of 128 experts, 32 held, H 4096, I 2048; the whole
+# routed expert MLP of one layer, ms; my chip run, PR 51):
+#      N   N*K   capacity  grouped  (half the rows idle lanes)
+#      8    32     2.228    0.789    0.471    the buffers stream 32 x 50 MB,
+#     16    64     2.234    0.659    0.466    the groups 50 MB an expert
+#     32   128     2.255    1.740    1.005    reached (about 7, 13, 20 of 32)
+# The rule leaves N = 32 the buffers although the groups still win there: a
+# step with rows enough to reach every expert is the case _GROUPED_MIN_ROWS
+# was measured on (8 of 8 reached from 32 rows on), and no served shape
+# lies between.
+def _moe_reaches_few(cfg: ModelConfig, N: int) -> bool:
+    return N * cfg.num_experts_per_tok < cfg.num_experts
+
+
 _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
@@ -386,14 +406,17 @@ def _moe_capacity(cfg: ModelConfig, N: int) -> int:
     return min(C, N * K)
 
 
-def _moe_takes_grouped(lp: Params, N: int, C: int) -> bool:
-    """Trace-time choice of the expert MLP's layout, read off the input.
+def _moe_takes_grouped(lp: Params, cfg: ModelConfig, N: int) -> bool:
+    """Trace-time choice of the expert MLP's layout for a step of ``N``
+    rows, read off the input (``lp``: a layer's weights or the layers'
+    stack).
 
     Grouped product (`ops.grouped_matmul`) when the capacity asked for
     holds every token (``C >= N``: no assignment can drop, so the buffers
     would compute the dropless result and the grouped product computes
-    the same one over ``N*K`` rows instead of ``E*N``), the step is at
-    least ``_GROUPED_MIN_ROWS`` rows, and the trace runs on one device.
+    the same one over ``N*K`` rows instead of ``E*N``), the trace runs on
+    one device, and the step is at least ``_GROUPED_MIN_ROWS`` rows or
+    reaches few of its experts (:func:`_moe_reaches_few`).
     Capacity buffers otherwise: ``C < N`` asks for GShard's drops; on a
     mesh the buffers' leading E axis is what GSPMD shards over ``ep``
     (and a Mosaic kernel cannot be partitioned); int8 expert weights
@@ -404,11 +427,32 @@ def _moe_takes_grouped(lp: Params, N: int, C: int) -> bool:
     from .quant import QuantizedTensor
 
     w = lp["w_gate"]
-    if C < N or N < _GROUPED_MIN_ROWS or _context_mesh() is not None:
+    if _moe_capacity(cfg, N) < N or _context_mesh() is not None:
+        return False
+    if N < _GROUPED_MIN_ROWS and not _moe_reaches_few(cfg, N):
         return False
     if isinstance(w, QuantizedTensor):
         return False
     return not _on_tpu() or kernel_fits(w.shape[-2], w.shape[-1])
+
+
+def moe_layout(params: Params, cfg: ModelConfig, N: int) -> Optional[str]:
+    """``"grouped"`` or ``"capacity"``: the layout the trunk's expert MLP
+    takes in a step of ``N`` rows (None: no routed experts).  The one
+    trace-time question, for a step that hands the grouped layout its
+    idle lanes and for the engine's ``dispatch`` annotation."""
+    if not cfg.is_moe:
+        return None
+    grouped = _moe_takes_grouped(params["layers"], cfg, N)
+    return "grouped" if grouped else "capacity"
+
+
+def moe_counts_reached(params: Params, cfg: ModelConfig, N: int) -> bool:
+    """Whether a step of ``N`` rows returns the count of the experts it
+    read: where it reaches few of them and takes the grouped layout for
+    that, the count is what the layout saved.  A step of enough rows to
+    reach every expert returns what it always did."""
+    return _moe_reaches_few(cfg, N) and moe_layout(params, cfg, N) == "grouped"
 
 
 def _moe_grouped(
@@ -419,13 +463,15 @@ def _moe_grouped(
     row_valid: Optional[jax.Array],  # [N] bool, or None: every row counts
     layer: Optional[jax.Array],  # index, where lp holds the layers' stack
     local: bool = False,  # topi may name experts this process does not hold
-) -> jax.Array:
+) -> Tuple[jax.Array, jax.Array]:
     """The dropless expert MLP over the ``N*K`` routed rows, sorted by
     expert: three grouped products, each row against its own expert's
-    matrix.  Rows a step marks invalid (padding of a packed dispatch) are
-    sorted behind the last group, where the kernel never goes, and come
-    back zero.  So are assignments to an expert held elsewhere
-    (``local``: ``topi`` already counts from this process's first expert)."""
+    matrix.  Rows a step marks invalid (padding of a packed dispatch, a
+    lane that has stopped) are sorted behind the last group, where the
+    kernel never goes, and come back zero.  So are assignments to an expert
+    held elsewhere (``local``: ``topi`` already counts from this process's
+    first expert).  Returns the result and the groups' sizes ``[E]``: a
+    group with a row is an expert whose matrices the products read."""
     from ..ops.grouped_matmul import _ROW_TILE, grouped_matmul
     from .attention import _on_tpu
 
@@ -461,7 +507,7 @@ def _moe_grouped(
         per_assign = jnp.where(
             counted.reshape(N, K)[:, :, None], per_assign, 0
         )
-    return jnp.sum(per_assign * topw[:, :, None], axis=1)
+    return jnp.sum(per_assign * topw[:, :, None], axis=1), sizes
 
 
 def _moe_mlp(
@@ -471,11 +517,25 @@ def _moe_mlp(
     row_valid: Optional[jax.Array] = None,
     layer: Optional[jax.Array] = None,
 ) -> jax.Array:
+    """The sparse MoE MLP's result alone (:func:`_moe_mlp_counted`)."""
+    return _moe_mlp_counted(lp, x, cfg, row_valid, layer)[0]
+
+
+def _moe_mlp_counted(
+    lp: Params,
+    x: jax.Array,
+    cfg: ModelConfig,
+    row_valid: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Sparse MoE MLP: top-k routing, then one of two layouts of the same
     expert products, chosen at trace time (:func:`_moe_takes_grouped`).
     ``lp`` holds one layer's expert weights ``[E, ., .]``; where
     :func:`scan_layers` found that the step takes the grouped product, the
     layers' stack ``[L, E, ., .]``, with ``layer`` the index into it.
+    Returns the result and, from the grouped layout, its groups' sizes
+    ``[E]`` (an expert with no row is not read; the buffers read every one:
+    None).
 
     **Grouped (dropless).**  Where ``cfg.moe_capacity_factor`` is ``E/K``
     or more the capacity holds every token, nothing can drop, and a
@@ -512,12 +572,12 @@ def _moe_mlp(
         topi = topi - cfg.local_expert_offset
     shared = _shared_experts(lp, xf, cfg)
 
-    C = _moe_capacity(cfg, N)
-    if _moe_takes_grouped(lp, N, C):
+    if _moe_takes_grouped(lp, cfg, N):
         valid = None if row_valid is None else row_valid.reshape(-1)
-        out = _moe_grouped(lp, xf, topw, topi, valid, layer, local)
-        return (out + shared).reshape(orig_shape)
+        out, sizes = _moe_grouped(lp, xf, topw, topi, valid, layer, local)
+        return (out + shared).reshape(orig_shape), sizes
 
+    C = _moe_capacity(cfg, N)
     flat_expert = topi.reshape(-1)  # [N*K] expert id per assignment
     held = True
     if local:
@@ -546,7 +606,7 @@ def _moe_mlp(
     )  # [N*K, H]
     per_assign = per_assign * (flat_w * keep.astype(flat_w.dtype))[:, None]
     out = jax.ops.segment_sum(per_assign, token_of, num_segments=N)
-    return (out + shared).reshape(orig_shape)
+    return (out + shared).reshape(orig_shape), None
 
 
 # ---------------------------------------------------------------------------
@@ -690,11 +750,15 @@ def transformer_layer(
     conv_fn: Optional[ConvFn] = None,
     op_layer: Optional[jax.Array] = None,  # index among its kind's layers,
     # where the cache holds the kinds apart from the stack (has_conv)
-) -> Tuple[jax.Array, jax.Array]:
+    reach: Optional[jax.Array] = None,  # [2] i32 running count, or None
+) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
     """One decoder layer (norm -> attention -> norm -> MLP, residuals).
     Shared by the single-device layer scan and the pipeline-parallel stage
     loop so the math cannot diverge.  ``row_valid`` lets the expert MLP
-    skip a packed dispatch's padding rows (:func:`_moe_mlp`).  In a trunk
+    skip the rows nobody reads (:func:`_moe_mlp_counted`); a step that
+    counts what its grouped expert MLPs read hands ``reach`` (experts
+    whose matrices were read, experts held) and gets it back with this
+    layer's added, as the third result.  In a trunk
     of window and full layers ``kind`` is this layer's, known at trace
     time: ``cos``/``sin`` are its kind's table, and ``attn_fn`` is handed
     the kind to pick pool, page table and window by
@@ -741,10 +805,13 @@ def transformer_layer(
     # a layer in front of the periods may have a dense MLP in a routed
     # model (lfm2_moe): told by its weights
     if cfg.is_moe and "router" in lp:
-        x = x + _moe_mlp(lp, h2, cfg, row_valid, layer)
+        out, sizes = _moe_mlp_counted(lp, h2, cfg, row_valid, layer)
+        x = x + out
+        if reach is not None and sizes is not None:
+            reach = reach + jnp.stack([jnp.sum(sizes > 0), sizes.size])
     else:
         x = x + _dense_mlp(lp, h2, cfg.hidden_act)
-    return x, kv_pages
+    return x, kv_pages, reach
 
 
 def scan_layers(
@@ -759,8 +826,10 @@ def scan_layers(
     q_factor: Optional[jax.Array] = None,
     rope_by_kind: Optional[Dict[str, Tuple[jax.Array, jax.Array]]] = None,
     conv_fn: Optional[ConvFn] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """Scan ``transformer_layer`` over the stacked weights.
+    reach: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
+    """Scan ``transformer_layer`` over the stacked weights; ``reach`` rides
+    the carry beside the cache (None: a carry with nothing in it).
 
     kv_pages rides the scan CARRY and each layer scatters into its slice in
     place; making it a scanned input/stacked output would copy the whole
@@ -782,25 +851,25 @@ def scan_layers(
     # expert's matrices in every layer of every step.
     whole: Params = {}
     N = x.shape[0] * x.shape[1]
-    if cfg.is_moe and _moe_takes_grouped(lp_stack, N, _moe_capacity(cfg, N)):
+    if cfg.is_moe and _moe_takes_grouped(lp_stack, cfg, N):
         whole = {k: lp_stack[k] for k in _EXPERT_WEIGHTS}
         lp_stack = {k: v for k, v in lp_stack.items() if k not in whole}
 
     def layer(carry, scanned):
-        x, kv = carry
+        x, kv, reach = carry
         lp, idx = scanned
-        x, kv = transformer_layer(
+        return transformer_layer(
             {**lp, **whole}, x, cos, sin, cfg, attn_fn, kv, idx, row_valid,
-            q_factor,
-        )
-        return (x, kv), None
+            q_factor, reach=reach,
+        ), None
 
     pattern = cfg.layer_pattern
     if pattern is None:
-        (x, kv_pages), _ = jax.lax.scan(
-            layer, (x, kv_pages), (lp_stack, jnp.arange(L, dtype=jnp.int32))
+        carry, _ = jax.lax.scan(
+            layer, (x, kv_pages, reach),
+            (lp_stack, jnp.arange(L, dtype=jnp.int32)),
         )
-        return x, kv_pages
+        return carry
 
     def at(stack, idx):
         return jax.tree_util.tree_map(
@@ -815,7 +884,7 @@ def scan_layers(
     def period(carry, first):
         # a layer's weights are sliced out of the whole stack by its own
         # index, as the scan above slices its scanned operand
-        x, kv = carry
+        x, kv, reach = carry
         for j, kind in enumerate(pattern):
             idx = first + j
             lp = at(lp_stack, idx)
@@ -829,17 +898,17 @@ def scan_layers(
                 lp = {**lp, **at(ops[kind], rank)}
                 op_layer = rank + cfg.lead_kind_layers(kind)
             c, s = rope_by_kind[kind]
-            x, kv = transformer_layer(
+            x, kv, reach = transformer_layer(
                 {**lp, **whole}, x, c, s, cfg, attn_fn, kv, idx, row_valid,
-                q_factor, kind, conv_fn, op_layer,
+                q_factor, kind, conv_fn, op_layer, reach,
             )
-        return (x, kv), None
+        return (x, kv, reach), None
 
-    (x, kv_pages), _ = jax.lax.scan(
-        period, (x, kv_pages),
+    carry, _ = jax.lax.scan(
+        period, (x, kv_pages, reach),
         jnp.arange(0, L, len(pattern), dtype=jnp.int32),
     )
-    return x, kv_pages
+    return carry
 
 
 def transformer(
@@ -852,8 +921,14 @@ def transformer(
     mm: "Optional[Tuple[jax.Array, jax.Array]]" = None,
     row_valid: Optional[jax.Array] = None,
     conv_fn: Optional[ConvFn] = None,
-) -> Tuple[jax.Array, jax.Array]:
+    count_reached: bool = False,
+) -> Tuple[jax.Array, ...]:
     """Run the trunk; returns (hidden [.., H], updated kv_pages).
+
+    ``count_reached`` asks for a third result, ``[2] int32``: over the
+    layers whose expert MLP took the grouped layout, the experts whose
+    matrices were read and the experts held (:func:`moe_counts_reached`
+    says of which steps that is worth asking).
 
     ``conv_fn`` serves a trunk's convolution layers (:data:`ConvFn`); a
     step that has none cannot run such a trunk, and says so.
@@ -907,23 +982,26 @@ def transformer(
 
     if squeeze and row_valid is not None:
         row_valid = row_valid[:, None]
+    reach = jnp.zeros((2,), jnp.int32) if count_reached else None
     # layers in front of the periods, each with its own shapes: unrolled
     seen: Dict[str, int] = {}
     for kind, lp in zip(cfg.lead_pattern or (), params.get("lead", ())):
         c, s = ropes[kind]
-        x, kv_pages = transformer_layer(
+        x, kv_pages, reach = transformer_layer(
             lp, x, c, s, cfg, attn_fn, kv_pages, None, row_valid, q_factor,
-            kind, conv_fn, jnp.int32(seen.get(kind, 0)),
+            kind, conv_fn, jnp.int32(seen.get(kind, 0)), reach,
         )
         seen[kind] = seen.get(kind, 0) + 1
-    x, new_kv_pages = scan_layers(
+    x, new_kv_pages, reach = scan_layers(
         params["layers"], kv_pages, x, cos, sin, cfg, attn_fn, row_valid,
-        q_factor, ropes, conv_fn,
+        q_factor, ropes, conv_fn, reach,
     )
 
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, cfg.rms_norm_offset)
     if squeeze:
         x = x[:, 0]
+    if count_reached:
+        return x, new_kv_pages, reach
     return x, new_kv_pages
 
 

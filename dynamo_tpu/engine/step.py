@@ -23,7 +23,13 @@ import jax.numpy as jnp
 
 from . import attention as att
 from .config import ModelConfig
-from .model import Params, lm_logits, transformer
+from .model import (
+    Params,
+    lm_logits,
+    moe_counts_reached,
+    moe_layout,
+    transformer,
+)
 from .sampling import (
     PROMPT_FLAG,
     SamplingParams,
@@ -93,11 +99,18 @@ def _decode_once(
     seq_lens: jax.Array,  # [B] tokens already in cache (new token's position)
     page_table: jax.Array,  # [B, P]
     active: Optional[jax.Array] = None,  # [B] bool: lanes the step advances;
-    # read by a trunk's convolution layers alone (a frozen lane's K/V write
-    # repeats itself, a shift of its convolution state would not)
-) -> Tuple[jax.Array, jax.Array]:
-    """One unjitted decode step.  Returns (logits [B,V], kv)."""
+    # read by a trunk's convolution layers (a frozen lane's K/V write
+    # repeats itself, a shift of its convolution state would not) and by
+    # the expert MLP's grouped layout, which reads no expert for a lane
+    # that has stopped (nobody reads such a lane's result)
+    count_reached: bool = False,
+) -> Tuple[jax.Array, ...]:
+    """One unjitted decode step.  Returns (logits [B,V], kv), and with
+    ``count_reached`` the experts its grouped expert MLPs read and held
+    (``model.transformer``)."""
     positions = seq_lens.astype(jnp.int32)  # new token position (0-indexed)
+    # the buffers take no mask: the other layouts' steps stay as they were
+    grouped = moe_layout(params, cfg, tokens.shape[0]) == "grouped"
 
     def conv_fn(z, taps, kv, layer):
         if active is None:
@@ -124,11 +137,13 @@ def _decode_once(
         )
         return out[:, None], lv.put(new_kv)
 
-    hidden, kv_pages = transformer(
+    hidden, kv_pages, *reach = transformer(
         params, cfg, tokens, positions, kv_pages, attn_fn,
+        row_valid=active if grouped else None,
         conv_fn=conv_fn if cfg.has_conv else None,
+        count_reached=count_reached,
     )
-    return lm_logits(params, cfg, hidden), kv_pages
+    return (lm_logits(params, cfg, hidden), kv_pages, *reach)
 
 
 decode_step = partial(jax.jit, static_argnames=("cfg",), donate_argnames=("kv_pages",))(
@@ -506,7 +521,10 @@ def _packed_unified_step(
     2*top_n], tokens, seq_lens, active, kv_pages, rng)``: packed rows
     carry (raw token | logprob | tops), the token ``-1`` for lanes that
     sampled nothing (idle, mid-chunk); ``spec_packed`` is zero-width when
-    ``s_spec == 0``."""
+    ``s_spec == 0``.  A step of so few rows that its expert MLPs read only
+    the experts a row reaches (``model.moe_counts_reached``, asked at trace
+    time) returns an eighth: ``[2] int32``, the experts read and the
+    experts held, summed over its layers."""
     B = tokens.shape[0]
     Np = t_tokens.shape[0]
     is_pf = p_lens > 0
@@ -554,9 +572,10 @@ def _packed_unified_step(
         )
         return out[None], kv
 
-    hidden, kv_pages = transformer(
+    hidden, kv_pages, *reach = transformer(
         params, cfg, tok_flat[None], positions[None], kv_pages, attn_fn,
         row_valid=valid[None], conv_fn=conv_fn if cfg.has_conv else None,
+        count_reached=moe_counts_reached(params, cfg, Np),
     )
     if s_spec > 0:
         rng, spec_sub = jax.random.split(rng)
@@ -574,7 +593,10 @@ def _packed_unified_step(
         tokens, seq_lens, limit_lens, active, stop_ids, rng, sampling,
         top_n, use_filters,
     )
-    return packed, spec_packed, new_tokens, new_seq, new_active, kv_pages, rng
+    return (
+        packed, spec_packed, new_tokens, new_seq, new_active, kv_pages, rng,
+        *reach,
+    )
 
 
 packed_unified_step = partial(
@@ -644,8 +666,10 @@ def _packed_unified_multistep(
 
     Returns the :func:`_packed_unified_step` contract with ``packed``
     widened to ``[B, num_steps, 2 + 2*top_n]`` (row 0 = step 0; ``-1``
-    tokens mark steps a lane was already dead for)."""
-    packed0, spec_packed, tokens, seq_lens, active, kv_pages, rng = (
+    tokens mark steps a lane was already dead for); the count of experts
+    read and held, where either part returns one, is the sum over the
+    steps that ran."""
+    packed0, spec_packed, tokens, seq_lens, active, kv_pages, rng, *reach = (
         _packed_unified_step(
             params, cfg, kv_pages, tokens, seq_lens, limit_lens, active,
             stop_ids, page_table, t_tokens, t_lane, t_rel, t_dec, p_start,
@@ -653,12 +677,17 @@ def _packed_unified_multistep(
             sampling, s_max, s_spec, top_n, use_filters,
         )
     )
+    count_tail = moe_counts_reached(params, cfg, tokens.shape[0])
+    if count_tail and not reach:
+        reach = [jnp.zeros((2,), jnp.int32)]
 
     def live_step(carry):
-        tokens, seq_lens, active, rng, kv = carry
-        logits, kv = _decode_once(
-            params, cfg, kv, tokens, seq_lens, page_table, active
+        tokens, seq_lens, active, rng, kv, *reach = carry
+        logits, kv, *step_reach = _decode_once(
+            params, cfg, kv, tokens, seq_lens, page_table, active, count_tail
         )
+        if count_tail:
+            reach = [reach[0] + step_reach[0]]
         rng, sub = jax.random.split(rng)
         sampled = sample_tokens(
             logits, sub, sampling, use_filters, positions=seq_lens + 1
@@ -671,7 +700,7 @@ def _packed_unified_multistep(
         new_tokens = jnp.where(emit, sampled, tokens)
         out = jnp.where(active, sampled, -1)
         packed = pack_sampled_logprobs(out, lp, top_ids, top_lps)
-        return (new_tokens, new_seq, new_active, rng, kv), packed
+        return (new_tokens, new_seq, new_active, rng, kv, *reach), packed
 
     def dead_step(carry):
         B = carry[0].shape[0]
@@ -681,14 +710,16 @@ def _packed_unified_multistep(
     def body(carry, _):
         return jax.lax.cond(jnp.any(carry[2]), live_step, dead_step, carry)
 
-    (tokens, seq_lens, active, rng, kv_pages), tail = jax.lax.scan(
-        body, (tokens, seq_lens, active, rng, kv_pages), None,
+    (tokens, seq_lens, active, rng, kv_pages, *reach), tail = jax.lax.scan(
+        body, (tokens, seq_lens, active, rng, kv_pages, *reach), None,
         length=num_steps - 1,
     )
     packed = jnp.concatenate(
         [packed0[:, None], tail.transpose(1, 0, 2)], axis=1
     )
-    return packed, spec_packed, tokens, seq_lens, active, kv_pages, rng
+    return (
+        packed, spec_packed, tokens, seq_lens, active, kv_pages, rng, *reach
+    )
 
 
 packed_unified_multistep = partial(

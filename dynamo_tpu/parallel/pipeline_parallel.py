@@ -104,7 +104,9 @@ def pp_prefill_step(
                 )
                 return o, att.write_prefill_kv(kv_buf, k, v, pt_t, layer)
 
-            x_out, kv = scan_layers(lp_local, kv, x_in, cos_t, sin_t, cfg, attn_fn)
+            x_out, kv, _ = scan_layers(
+                lp_local, kv, x_in, cos_t, sin_t, cfg, attn_fn
+            )
             oi = t - (num_stages - 1)
             if oi >= 0:
                 emit = jnp.where(s == num_stages - 1, x_out, 0)

@@ -298,6 +298,21 @@ class EngineMetrics:
             "Fresh-token rows per unified mixed dispatch by accounting kind",
             ["kind"],  # used | dispatched
         )
+        # what the expert MLPs of decode-only dispatches read, where a step
+        # of few rows takes the grouped layout (model.moe_counts_reached):
+        # experts with a row, whose matrices were read, and experts held,
+        # both summed over layers and steps.  Their ratio is the share of the
+        # experts' bytes a decode step streams; other steps count nothing
+        self.moe_experts_reached = reg.counter(
+            "dynamo_engine_moe_experts_reached",
+            "Experts a decode step's rows reached (matrices read), summed "
+            "over layers and steps that took the grouped layout",
+        )
+        self.moe_experts_held = reg.counter(
+            "dynamo_engine_moe_experts_held",
+            "Experts held (matrices the capacity buffers would read), "
+            "summed over the same layers and steps",
+        )
         # packed-shape budget (ISSUE 13 satellite): active (Np, s_max)
         # executable pairs the packed unified step may dispatch -- bounded
         # by engine/bucketing.PackedShapeBudget's LRU/merge pass
@@ -473,6 +488,10 @@ class EngineMetrics:
     def observe_mixed_tokens(self, used: int, dispatched: int) -> None:
         self.mixed_tokens.labels("used").inc(used)
         self.mixed_tokens.labels("dispatched").inc(dispatched)
+
+    def observe_moe_reach(self, reached: int, held: int) -> None:
+        self.moe_experts_reached.inc(reached)
+        self.moe_experts_held.inc(held)
 
     def observe_kv(
         self, used: int, total: int, bytes_per_token: Optional[float] = None

@@ -702,3 +702,106 @@ def test_latent_layers_scatter_and_attend_without_copying_the_pool(chip, monkeyp
     made = [l for l in text.splitlines()
             if re.search(r"= bf16\[3,(1,)?32768,16,(1,)?640\]\S* (copy|transpose)\(", l)]
     assert not made, made[:3]
+
+
+# -- the fused decode executables: which layout their expert MLPs take ---------
+
+
+def published(name):
+    """(ModelConfig, engine block) of ``benchmark/configs/<name>.json`` as the
+    benchmark serves it: bfloat16, no assignment dropped."""
+    import dataclasses
+    import json
+
+    from dynamo_tpu.engine.config import ModelConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", name + ".json")) as f:
+        cfg = json.load(f)
+    mc = ModelConfig.from_hf_config(
+        {k: v for k, v in cfg.items() if k not in ("engine", "rehearse")})
+    return dataclasses.replace(
+        mc, dtype="bfloat16",
+        moe_capacity_factor=mc.num_experts / mc.num_experts_per_tok), cfg["engine"]
+
+
+def _decode_block_operands(shape, cfg, pool, lanes, table):
+    """Operands of ``step._packed_unified_multistep`` for a decode-only
+    dispatch of ``lanes`` rows, ``cfg`` left out (``shape(dims, dtype)``
+    makes each: the ``chip`` fixture's, or ``jax.ShapeDtypeStruct``)."""
+    from dynamo_tpu.engine import model as M
+    from dynamo_tpu.engine.sampling import SamplingParams
+
+    shapes = jax.eval_shape(
+        lambda: M.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))
+    params = jax.tree.map(lambda a: shape(a.shape, a.dtype), shapes)
+    i32 = lambda *d: shape(d, jnp.int32)  # noqa: E731
+    b1 = lambda *d: shape(d, jnp.bool_)  # noqa: E731
+    f32 = lambda *d: shape(d, jnp.float32)  # noqa: E731
+    B = lanes
+    sampling = SamplingParams(
+        f32(B), f32(B), i32(B), shape((B,), jnp.uint32), f32(B), f32(B), f32(B))
+    return (
+        params, pool, i32(B), i32(B), i32(B), b1(B), i32(B, 4), i32(B, table),
+        i32(B), i32(B), i32(B), b1(B), i32(B), i32(B), b1(B), b1(B), b1(B),
+        i32(B), i32(B), shape((2,), jnp.uint32), sampling,
+    )
+
+
+def decode_block_equations(cfg, operands, steps=4):
+    """Equations of the fused block of ``steps`` decode steps' jaxpr, nested
+    ones counted, traced as on the chip (the caller patches ``_on_tpu``)."""
+    from dynamo_tpu.engine import step as S
+    from tests.test_packed_work_list import _eqns
+
+    jaxpr = jax.make_jaxpr(
+        lambda *a: S._packed_unified_multistep(
+            a[0], cfg, *a[1:], s_max=1, num_steps=steps)
+    )(*operands)
+    return sum(1 for _ in _eqns(jaxpr.jaxpr)), len(jaxpr.out_avals)
+
+
+def test_mixtral_decode_block_keeps_its_jaxpr(monkeypatch):
+    """Mixtral's 32 lanes route 64 assignments over a router of 8: the rule
+    that hands a step of few rows to the grouped product does not hold, and
+    the fused block of four decode steps traces to the program it traced to
+    on the parent (counted there with this function): the buffers
+    ``[8, 32, .]``, seven results, no count of experts read."""
+    from dynamo_tpu.engine import attention as att
+
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    cfg, eng = published("mixtral-8x7b")
+    spec = jax.ShapeDtypeStruct
+    pool = spec((cfg.num_layers, 2, eng["num_pages"], PAGE, cfg.num_kv_heads,
+                 cfg.head_dim), jnp.bfloat16)
+    ops = _decode_block_operands(spec, cfg, pool, 32, 512)
+    assert decode_block_equations(cfg, ops) == (1407, 7)
+
+
+def test_mistral4_decode_block_reads_the_experts_its_rows_reach(chip, monkeypatch):
+    """``mistral-small-4-119b``'s 16 lanes route 64 assignments over a router
+    of 128: the ``(16, 1)`` executable and the fused steps behind it take
+    the grouped kernel out of the experts' whole stack (three launches a
+    layer in each of the two scan bodies), make no ``[32, 16, .]`` buffer
+    and no copy of a layer's 32 experts, and return the count of experts
+    read beside the seven results."""
+    from dynamo_tpu.engine import attention as att
+    from dynamo_tpu.engine import step as S
+    from dynamo_tpu.ops.grouped_matmul import KERNEL_NAME
+
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    cfg, eng = published("mistral-small-4-119b")
+    assert (cfg.num_experts, cfg.experts_held, cfg.num_experts_per_tok) == (128, 32, 4)
+    lanes = eng["max_batch_size"]
+    ops = _decode_block_operands(chip, cfg, _latent_pool(chip), lanes, LATENT["TABLE"])
+    fn = jax.jit(
+        lambda *a: S._packed_unified_multistep(
+            a[0], cfg, *a[1:], s_max=1, num_steps=2),
+        donate_argnums=(1,))
+    lowered = fn.lower(*ops)
+    assert len(lowered.out_info) == 8 and lowered.out_info[-1].shape == (2,)
+    text = lowered.compile().as_text()
+    assert len(re.findall(rf"%{KERNEL_NAME}[.\d]* = ", text)) == 6
+    assert not re.search(r"bf16\[32,16,(4096|2048)\]", text)
+    copies = re.findall(r"= bf16\[(?:1,)?32,(?:4096,2048|2048,4096)\]", text)
+    assert not copies, copies

@@ -5,9 +5,6 @@ attention layers' pool with two 64-wide KV heads a 128-lane row ``[3, 2, P, 16,
 4, 128]`` and the state beside it; what the step holds beside its arguments;
 and which kernels such a pool takes."""
 
-import dataclasses
-import json
-import os
 import re
 
 import jax
@@ -17,23 +14,16 @@ import pytest
 from dynamo_tpu.engine import attention as att
 from dynamo_tpu.engine import model as M
 from dynamo_tpu.engine import step as S
-from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.engine.kv_cache import ConvKV
 from dynamo_tpu.engine.sampling import SamplingParams
+from tests import test_chip_compile as base
 from tests.test_chip_compile import chip, topo  # noqa: F401  (fixtures)
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LANES, PAGE = 32, 16
 
 
 def published():
-    with open(os.path.join(ROOT, "benchmark", "configs", "lfm2-8b-a1b.json")) as f:
-        cfg = json.load(f)
-    mc = ModelConfig.from_hf_config(
-        {k: v for k, v in cfg.items() if k not in ("engine", "rehearse")})
-    return dataclasses.replace(
-        mc, dtype="bfloat16",
-        moe_capacity_factor=mc.num_experts / mc.num_experts_per_tok), cfg["engine"]
+    return base.published("lfm2-8b-a1b")
 
 
 def _operands(chip, cfg, eng, Np, table):
@@ -94,6 +84,19 @@ def test_lfm2_steps_lower_at_published_widths(chip, monkeypatch, Np, s_max, step
     # the step makes beside them is far under any of the three
     assert compiled.memory_analysis().temp_size_in_bytes < 200 << 20
     assert not re.search(r"bf16\[3,2,16384,16,4,128\]\S* copy\(", text)
+
+
+def test_lfm2_decode_block_keeps_its_jaxpr(monkeypatch):
+    """32 lanes route 128 assignments over a router of 32: the rule that
+    hands a step of few rows to the grouped product does not hold, and the
+    fused block of four decode steps traces to the program it traced to on
+    the parent of PR 51 (counted there with this function): the buffers,
+    no count of experts read among its results (tokens, columns, three of
+    state, the cache's three parts, the key)."""
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    cfg, eng = published()
+    ops = _operands(jax.ShapeDtypeStruct, cfg, eng, 32, 448)
+    assert base.decode_block_equations(cfg, (ops[0], *ops[2:])) == (4701, 9)
 
 
 def test_a_packed_pool_takes_the_work_list_and_a_64_wide_one_the_grid(monkeypatch):

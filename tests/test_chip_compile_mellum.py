@@ -4,9 +4,6 @@ full layers at Mellum2-12B-A2.5B's published widths, 12 layers, two pools;
 and that a trunk of one kind traces to the program it traced to before
 ``layer_types`` existed."""
 
-import dataclasses
-import json
-import os
 import re
 
 import jax
@@ -20,22 +17,16 @@ from dynamo_tpu.engine import step as S
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.engine.kv_cache import KindKV
 from dynamo_tpu.engine.sampling import SamplingParams
+from tests import test_chip_compile as base
 from tests.test_chip_compile import (  # noqa: F401  (fixtures)
     assert_one_decode_launch, chip, decode_layer_text, topo,
 )
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LANES, PAGE, TABLE = 32, 16, 2064
 
 
 def published():
-    with open(os.path.join(ROOT, "benchmark", "configs", "mellum2-12b-a2.5b.json")) as f:
-        cfg = json.load(f)
-    mc = ModelConfig.from_hf_config(
-        {k: v for k, v in cfg.items() if k not in ("engine", "rehearse")})
-    return dataclasses.replace(
-        mc, dtype="bfloat16",
-        moe_capacity_factor=mc.num_experts / mc.num_experts_per_tok), cfg["engine"]
+    return base.published("mellum2-12b-a2.5b")
 
 
 def _operands(chip, cfg, eng, Np, table):
@@ -130,7 +121,11 @@ def test_fused_step_does_not_grow_with_the_page_table(monkeypatch):
         )(ops[0], *ops[2:])
         return sum(1 for _ in _eqns(jaxpr.jaxpr))
 
-    assert equations(512) == equations(TABLE)
+    # ... and as many as on the parent of PR 51 (counted there with this
+    # function): 32 lanes route 256 assignments over a router of 64, so the
+    # rule that hands a step of few rows to the grouped product does not
+    # hold and the block keeps the buffers
+    assert equations(512) == equations(TABLE) == 5063
 
 
 def test_one_kind_packed_step_keeps_its_jaxpr():

@@ -771,20 +771,30 @@ def _moe_case(name, cfg, n, thr):
     return x, lp
 
 
+# a step that routes fewer assignments than its router has experts, most of
+# them held elsewhere: 16 rows, top-2 of 64, experts 16-31 held here
+HELD_ELSEWHERE = dict(num_experts=64, num_local_experts=16,
+                      local_expert_offset=16, moe_capacity_factor=32.0)
+
+
 @pytest.mark.parametrize("masked", [False, True], ids=["all_rows", "masked"])
 @pytest.mark.parametrize("backend", ["ragged_dot", "kernel"])
 @pytest.mark.parametrize(
     "name,n",
     [("balanced", 128), ("one_pair", 128), ("empty_expert", 128),
      ("ragged_rows", 100),  # N*K = 200: not a multiple of the row tile
-     ("at_threshold", 0), ("below_threshold", -1)],
+     ("at_threshold", 0), ("below_threshold", -1), ("held_elsewhere", 16)],
 )
 def test_moe_grouped_matches_dense(name, n, backend, masked, monkeypatch):
     """The grouped path computes what ``_moe_mlp_dense`` computes, row for
     row: through ``ragged_dot`` (the CPU's backend) and through the Pallas
     kernel itself (interpreted), with the padding mask (masked rows come
     back zero, the others unchanged) and without.  ``below_threshold`` is
-    the capacity path, held to the same answer."""
+    the capacity path, held to the same answer; ``held_elsewhere`` a decode
+    step's 16 rows (half of them idle lanes under the mask) that take the
+    grouped path because they reach few experts, most of them absent: one
+    row tile, the absent experts' assignments and the idle lanes behind
+    the groups."""
     from jax.experimental.pallas import tpu as pltpu
 
     from dynamo_tpu.engine import attention as att
@@ -794,13 +804,23 @@ def test_moe_grouped_matches_dense(name, n, backend, masked, monkeypatch):
     n = n if n > 0 else thr + n
     # the kernel's widths tile to 128 lanes; ragged_dot takes any
     widths = dict(hidden_size=128, intermediate_size=256) if backend == "kernel" else {}
-    cfg = ModelConfig.tiny(num_experts=4, num_experts_per_tok=2,
-                           moe_capacity_factor=2.0, **widths)
+    geometry = dict(num_experts=4, moe_capacity_factor=2.0)
+    if name == "held_elsewhere":
+        geometry = HELD_ELSEWHERE
+    cfg = ModelConfig.tiny(num_experts_per_tok=2, **geometry, **widths)
     x, lp = _moe_case(name, cfg, n, thr)
     if name == "empty_expert":
         logits = np.asarray(x[0] @ lp["router"])
         assert 3 not in np.argsort(logits, axis=1)[:, -2:]
     valid = (jnp.arange(n) % 3 != 1)[None] if masked else None
+    grouped = n >= thr
+    if name == "held_elsewhere":
+        valid = (jnp.arange(n) % 2 == 0)[None] if masked else None
+        grouped = M._moe_takes_grouped(lp, cfg, n)
+        assert grouped and M._moe_capacity(cfg, n) == n
+        # some assignments are held here and most are not
+        held = np.asarray(M._route(lp, x[0], cfg)[1]) // 16 == 1
+        assert 0 < held.sum() < held.size // 2
     dense = np.asarray(M._moe_mlp_dense(lp, x, cfg))
     if backend == "kernel":
         monkeypatch.setattr(att, "_on_tpu", lambda: True)
@@ -808,11 +828,25 @@ def test_moe_grouped_matches_dense(name, n, backend, masked, monkeypatch):
             got = np.asarray(M._moe_mlp(lp, x, cfg, valid))
     else:
         got = np.asarray(M._moe_mlp(lp, x, cfg, valid))
-    if masked and n >= thr:
+    if masked and grouped:
         keep = np.asarray(valid)[0]
         assert np.abs(got[0, ~keep]).max() == 0.0
         got, dense = got[:, keep], dense[:, keep]
+    assert np.abs(dense).max() > 1e-3
     np.testing.assert_allclose(got, dense, rtol=2e-5, atol=2e-5)
+
+
+# (router's width, experts held, K, rows) of the cases told by geometry: no
+# case names a model.  The first routes 64 assignments over 128 experts; the
+# others route at least as many as their router is wide
+GEOMETRY = {
+    "router128_held32_top4_n16": (128, 32, 4, 16),
+    "router128_held32_top4_n16_on_tpu": (128, 32, 4, 16),
+    "router8_top2_n32": (8, 8, 2, 32),
+    "router32_top4_n32": (32, 32, 4, 32),
+    "router64_top8_n32": (64, 64, 8, 32),
+    "router128_held32_top4_n32": (128, 32, 4, 32),
+}
 
 
 @pytest.mark.parametrize(
@@ -820,54 +854,83 @@ def test_moe_grouped_matches_dense(name, n, backend, masked, monkeypatch):
     [("no_drop_one_device", "grouped"), ("kernel_on_tpu", "kernel"),
      ("drops_asked_for", "capacity"), ("sharded", "capacity"),
      ("small_n", "capacity"), ("int8_experts", "capacity"),
-     ("tpu_odd_widths", "capacity")],
+     ("tpu_odd_widths", "capacity"),
+     ("router128_held32_top4_n16", "grouped"),
+     ("router128_held32_top4_n16_on_tpu", "kernel"),
+     ("router8_top2_n32", "capacity"), ("router32_top4_n32", "capacity"),
+     ("router64_top8_n32", "capacity"),
+     ("router128_held32_top4_n32", "capacity")],
 )
 def test_moe_path_is_read_off_the_input(case, want, monkeypatch):
     """Which layout a step takes, told from the traced program and not
     from a flag: the grouped product (``ragged_dot`` off the chip, the
-    kernel by its name on it) or the ``[E, C, H]`` buffer."""
+    kernel by its name on it) or the ``[E, C, H]`` buffer.  A step under
+    ``_GROUPED_MIN_ROWS`` takes the grouped product only where it routes
+    fewer assignments than its router has experts (``GEOMETRY``).  And
+    ``scan_layers`` asks the same question: the experts' stack stays whole
+    (an operand the scan does not slice) exactly where the layer takes the
+    grouped layout."""
     from dynamo_tpu.engine import attention as att
     from dynamo_tpu.engine import model as M
     from dynamo_tpu.engine.quant import quantize_tensor
     from dynamo_tpu.ops.grouped_matmul import KERNEL_NAME
     from dynamo_tpu.parallel.mesh import MeshConfig, build_mesh
 
-    E, K = 4, 2
-    n = M._GROUPED_MIN_ROWS + 24  # C = n: told apart from H and I
-    factor = 1.0 if case == "drops_asked_for" else 2.0
+    E, held, K, n = GEOMETRY.get(case, (4, 4, 2, M._GROUPED_MIN_ROWS + 24))
+    # C = n: told apart from H and I
+    factor = 1.0 if case == "drops_asked_for" else E / K
     widths = dict(hidden_size=128, intermediate_size=256)
     if case in ("no_drop_one_device", "tpu_odd_widths"):
         widths = {}  # tiny's 64 and 128
     if case == "small_n":
         n = M._GROUPED_MIN_ROWS - 8
-    cfg = ModelConfig.tiny(num_experts=E, num_experts_per_tok=K,
-                           moe_capacity_factor=factor, **widths)
+    cfg = ModelConfig.tiny(num_experts=E, num_local_experts=held % E,
+                           num_experts_per_tok=K, moe_capacity_factor=factor,
+                           **widths)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     lp = jax.tree.map(lambda a: a[0], params["layers"])
+    stack = params["layers"]
     if case == "int8_experts":
         for k in ("w_gate", "w_up", "w_down"):
             lp[k] = quantize_tensor(lp[k], jnp.float32)
-    if case in ("kernel_on_tpu", "tpu_odd_widths"):
+            stack[k] = quantize_tensor(stack[k], jnp.float32)
+    if case in ("kernel_on_tpu", "tpu_odd_widths") or case.endswith("on_tpu"):
         monkeypatch.setattr(att, "_on_tpu", lambda: True)
     x = jnp.zeros((1, n, cfg.hidden_size), jnp.float32)
 
+    def trunk(stack, y):
+        rope = jnp.zeros((1, n, cfg.head_dim), jnp.float32)
+        attend = lambda q, k, v, kv, layer: (q, kv)  # noqa: E731
+        return M.scan_layers(stack, jnp.zeros(()), y, rope, rope, cfg, attend)[0]
+
     def trace():
-        return str(jax.make_jaxpr(lambda l, y: M._moe_mlp(l, y, cfg))(lp, x))
+        scan = next(
+            e for e in jax.make_jaxpr(trunk)(stack, x).eqns
+            if e.primitive.name == "scan"
+        )
+        unsliced = scan.invars[: scan.params["num_consts"]]
+        whole = any(
+            v.aval.shape == (cfg.num_layers, held, cfg.hidden_size,
+                             cfg.intermediate_size) for v in unsliced
+        )
+        text = str(jax.make_jaxpr(lambda l, y: M._moe_mlp(l, y, cfg))(lp, x))
+        return text, whole
 
     if case == "sharded":
         if len(jax.devices()) < 4:
             pytest.skip("needs >= 4 (virtual) devices")
         with jax.set_mesh(build_mesh(MeshConfig(ep=4), jax.devices()[:4])):
-            text = trace()
+            text, whole = trace()
     else:
-        text = trace()
+        text, whole = trace()
     C = min(int(-(-n * K * factor // E)), n * K)
-    buffer = f"f32[{E},{C},{cfg.hidden_size}]"
+    buffer = f"f32[{held},{C},{cfg.hidden_size}]"
     got = ("kernel" if KERNEL_NAME in text
            else "grouped" if "ragged_dot" in text
            else "capacity" if buffer in text else "neither")
     assert got == want, text[-2000:]
     assert (buffer in text) == (want == "capacity")
+    assert whole == (want != "capacity")
 
 
 def test_moe_engine_serves_the_same_tokens_through_both_paths(run, monkeypatch):
@@ -906,3 +969,108 @@ def test_moe_engine_serves_the_same_tokens_through_both_paths(run, monkeypatch):
     grouped = run(serve(1, 513))
     assert all(len(t) == 6 for t in capacity)
     assert grouped == capacity
+
+
+def _moe_experts_counted(engine, since=(0.0, 0.0)):
+    """(experts read, experts held) on the engine's registry, which the
+    engines of a process share, less an earlier reading."""
+    sample = engine.obs.registry.sample
+    now = (sample("dynamo_engine_moe_experts_reached") or 0.0,
+           sample("dynamo_engine_moe_experts_held") or 0.0)
+    return now[0] - since[0], now[1] - since[1]
+
+
+@pytest.mark.parametrize("k", [1, 2, 4])
+def test_moe_engine_reads_only_the_experts_its_lanes_reach(run, monkeypatch, k):
+    """A tiny engine whose router is wider than ``max_batch_size * K``
+    (4 lanes x top-2 under 16 experts, 8 of them held here): its decode
+    steps take the grouped product, idle lanes behind the groups, and serve
+    the greedy tokens of an engine forced onto the buffers, through fused
+    blocks of ``k`` steps with lanes that stop inside a block (3, 6 and 9
+    tokens).  The counters read what the decode steps' expert MLPs read:
+    fewer experts than are held, and nothing on the buffers."""
+    from dynamo_tpu.engine import EngineConfig, JaxEngine
+    from dynamo_tpu.engine import model as M
+
+    from tests.test_jax_engine import collect, req
+
+    monkeypatch.setenv("DYN_MULTISTEP", str(k))
+    prompts = {3: list(range(1, 41)), 6: [9, 8, 7], 9: [5] * 17}
+    choice = M._moe_takes_grouped
+
+    async def serve(forced_onto_buffers, max_position):
+        if forced_onto_buffers:
+            monkeypatch.setattr(M, "_moe_takes_grouped", lambda *a: False)
+        else:
+            monkeypatch.setattr(M, "_moe_takes_grouped", choice)
+        cfg = ModelConfig.tiny(num_experts=16, num_local_experts=8,
+                               local_expert_offset=4, num_experts_per_tok=2,
+                               moe_capacity_factor=8.0,
+                               max_position=max_position)
+        engine = JaxEngine.random_init(
+            cfg, EngineConfig(max_batch_size=4, max_seq_len=64, page_size=4,
+                              num_pages=64, multistep_max_k=max(k, 1)),
+        )
+        assert (M.moe_layout(engine.params, cfg, 4) == "capacity") == (
+            forced_onto_buffers)
+        before = _moe_experts_counted(engine)
+        try:
+            import asyncio
+
+            got = await asyncio.gather(
+                *[collect(engine, req(p, max_tokens=n))
+                  for n, p in prompts.items()]
+            )
+            return [g[0] for g in got], _moe_experts_counted(engine, before)
+        finally:
+            await engine.stop()
+
+    # (max_position differs in a field no step reads: the second engine
+    # does not run the first one's compiled steps)
+    buffers, counted = run(serve(True, 520 + k))
+    assert [len(t) for t in buffers] == [3, 6, 9]
+    assert not any(counted)
+    grouped, (reached, held) = run(serve(False, 530 + k))
+    assert grouped == buffers
+    assert 0 < reached < held and held % (2 * 8) == 0
+
+
+def test_moe_counters_agree_where_every_expert_is_reached(run, monkeypatch):
+    """Experts read equals experts held where every live lane's rows reach
+    every held expert: a sigmoid router whose bias always chooses the two
+    experts held here, of eight.  A step whose lanes have all stopped runs
+    nothing and counts nothing."""
+    from dynamo_tpu.engine import EngineConfig, JaxEngine
+
+    from tests.test_jax_engine import collect, req
+
+    monkeypatch.setenv("DYN_MULTISTEP", "4")
+    cfg = ModelConfig.tiny(num_experts=8, num_local_experts=2,
+                           num_experts_per_tok=2, moe_capacity_factor=4.0,
+                           router_score="sigmoid", router_bias=True,
+                           max_position=540)
+
+    async def serve():
+        engine = JaxEngine.random_init(
+            cfg, EngineConfig(max_batch_size=2, max_seq_len=64, page_size=4,
+                              num_pages=64, multistep_max_k=4),
+        )
+        # (a trunk without convolution layers draws no bias of its own)
+        engine.params["layers"]["router_bias"] = (
+            jnp.zeros((cfg.num_layers, 8), jnp.float32).at[:, :2].set(10.0)
+        )
+        before = _moe_experts_counted(engine)
+        try:
+            import asyncio
+
+            got = await asyncio.gather(
+                collect(engine, req([3, 1, 4], max_tokens=5)),
+                collect(engine, req([1, 5, 9, 2, 6], max_tokens=7)),
+            )
+            return [len(g[0]) for g in got], _moe_experts_counted(engine, before)
+        finally:
+            await engine.stop()
+
+    lengths, (reached, held) = run(serve())
+    assert lengths == [5, 7]
+    assert reached == held > 0
