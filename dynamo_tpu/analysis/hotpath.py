@@ -75,10 +75,19 @@ HOT_PATH_MANIFEST: Dict[str, List[str]] = {
         "_bump_counts",
         "seed_count_rows",
         "_seed_count_rows",
+    ],
+    # the jitted page movers of the KV blob: eviction snapshots, tier and
+    # swap restores, and the layer-page gather/scatter of the chunked KV
+    # delivery on the tick loop
+    "dynamo_tpu/engine/kv_cache.py": [
         "scatter_block_pages",
         "_scatter_block_pages",
         "slice_block_pages",
         "_slice_block_pages",
+        "gather_layer_pages",
+        "_gather_layer_pages",
+        "scatter_layer_pages",
+        "_scatter_layer_pages",
     ],
     # multichip serving entry points: the sharded re-jit factory (its jit
     # wrappers pin in/out shardings over the raw step bodies above --
@@ -96,14 +105,9 @@ HOT_PATH_MANIFEST: Dict[str, List[str]] = {
         "ring_prefill_step",
         "make_ring_attention",
     ],
-    # paged-attention kernels + the layer-page gather/scatter used by the
-    # chunked KV delivery scatter on the tick loop
+    # paged-attention kernels
     "dynamo_tpu/ops/paged_attention.py": [
         "paged_decode_attention*",
-        "gather_layer_pages",
-        "_gather_layer_pages",
-        "scatter_layer_pages",
-        "_scatter_layer_pages",
     ],
     # flash prefill kernels (full-prompt and prefix-suffix)
     "dynamo_tpu/ops/flash_prefill.py": [

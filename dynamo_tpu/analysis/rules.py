@@ -966,7 +966,8 @@ class HotPathManifestDrift(Rule):
     severity = "error"
     description = (
         "A jitted entry point in a step/kernel/parallel module "
-        "(engine/step.py, ops/*.py, parallel/*.py) is covered by neither "
+        "(engine/step.py, engine/kv_cache.py, ops/*.py, parallel/*.py) is "
+        "covered by neither "
         "an @hot_path decorator nor a HOT_PATH_MANIFEST pattern.  "
         "DT004/DT005 scan exactly the marked surface, so an unlisted "
         "jax.jit entry point silently loses host-sync and "
@@ -985,7 +986,7 @@ class HotPathManifestDrift(Rule):
 
     @classmethod
     def _applies(cls, relpath: str) -> bool:
-        if relpath.endswith("engine/step.py"):
+        if relpath.endswith(("engine/step.py", "engine/kv_cache.py")):
             return True
         head, _, fname = relpath.rpartition("/")
         return fname.endswith(".py") and (

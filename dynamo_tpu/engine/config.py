@@ -201,7 +201,7 @@ class ModelConfig:
         KV head, what the int8 pool scales a head at a time, and what
         offload, G4 and disaggregation ship as ``[.., Hkv, D]`` blobs, all of
         which read ``num_kv_heads`` and ``head_dim``; this trunk refuses
-        each of them by name (``kv_cache.conv_state_refusal``), so its pool
+        each of them by name (``kv_cache.KV_REFUSALS``), so its pool
         is free to differ.  Packing another family's pool is theirs to
         follow first."""
         d = self.head_dim

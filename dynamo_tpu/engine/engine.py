@@ -47,13 +47,25 @@ from ..tokens.sequence import TokenBlock
 from .config import ModelConfig
 from .kv_cache import (
     PagedKVCache,
-    QuantKV,
     as_device_blob,
+    assemble_blob,
+    blob_layers,
+    blob_nbytes,
+    blob_num_layers,
+    blob_num_pages,
+    blob_pages,
+    blob_shape,
     blob_to_host,
     coerce_kv_blob,
-    kv_blob_concat,
-    conv_state_refusal,
-    two_kind_refusal,
+    concat_blob_layers,
+    concat_blob_pages,
+    gather_layer_pages,
+    layer_chunk_spans,
+    pad_page_axis,
+    refuse,
+    scatter_block_pages,
+    scatter_layer_pages,
+    slice_block_pages,
 )
 from .metrics import EngineMetrics
 from .model import Params, init_params
@@ -80,10 +92,6 @@ from .step import (
     prefill_and_sample,
     prefill_buckets,
     prefill_suffix_and_sample,
-    gather_layer_pages,
-    scatter_block_pages,
-    scatter_layer_pages,
-    slice_block_pages,
     packed_unified_multistep,
     packed_unified_step,
     verify_and_sample,
@@ -163,12 +171,8 @@ def _start_host_copy(arr) -> None:
     device_get is a wait, not a transfer.  Values without
     ``copy_to_host_async`` (mocks, host arrays) skip it and the commit's
     fetch blocks as it always did; on a jax array the call exists on
-    every backend, so a failure there is a bug and raises.  Pytree values
-    (quantized KV pairs) start one copy per leaf."""
-    if isinstance(arr, QuantKV):
-        _start_host_copy(arr.q)
-        _start_host_copy(arr.s)
-        return
+    every backend, so a failure there is a bug and raises.  A blob of
+    several leaves answers for them all (kv_cache._PoolTree)."""
     start = getattr(arr, "copy_to_host_async", None)
     if start is not None:
         start()
@@ -181,8 +185,6 @@ def _handles_ready(arr) -> bool:
     (mocks) report ready -- the commit then simply blocks as it always
     did; a probe that exists and fails raises.  THE readiness primitive
     of the async-commit pipeline."""
-    if isinstance(arr, QuantKV):
-        return _handles_ready(arr.q) and _handles_ready(arr.s)
     probe = getattr(arr, "is_ready", None)
     return True if probe is None else bool(probe())
 
@@ -318,8 +320,8 @@ class EngineConfig:
     # step (engine/quant.py).  None = bf16/f32 as loaded.
     quantize: Optional[str] = None
     # paged KV pool dtype (ISSUE 13): "int8" switches the pool to the
-    # quantized per-row layout (kv_cache.QuantKV -- ~half the pool's HBM,
-    # so the freed bytes become resident batch/context), dequant fused
+    # quantized per-row layout (int8 and one scale a row -- ~half the pool's
+    # HBM, so the freed bytes become resident batch/context), dequant fused
     # into the ragged kernels and quantize applied on every write.  bf16
     # (the model dtype) stays the exact default; DYN_KV_DTYPE env wins at
     # engine construction (the serving-env-knob contract).  None = model
@@ -526,15 +528,8 @@ class _GroupSpanExport:
     def _materialize(self, idx: int) -> np.ndarray:
         # per-shard assembly: a tp-sharded pool's span comes to host one
         # kv-head slice per chip and reassembles here (the wire format is
-        # always full-width); unsharded spans take the plain device_get.
-        # Quantized spans assemble (data, scales) together.
-        from ..parallel.sharding import assemble_shards
-
-        dev = self._devs[idx]
-        if isinstance(dev, QuantKV):
-            arr = QuantKV(q=assemble_shards(dev.q), s=assemble_shards(dev.s))
-        else:
-            arr = assemble_shards(dev)
+        # always full-width); unsharded spans take the plain device_get
+        arr = assemble_blob(self._devs[idx])
         # dynalint: disable=DT014 -- per-span slots are disjoint: host_span
         # dedupes to ONE to_thread task per idx on the loop, so concurrent
         # workers never touch the same index
@@ -566,7 +561,7 @@ class KVExportStream:
     ``first_ready_at``/``last_ready_at`` record the pipeline's
     export-before-first-byte and total-materialize times."""
 
-    shape: Tuple[int, ...]  # [L, 2, n_pages, page, Hkv, D]
+    shape: Tuple[int, ...]  # of the request's blob (kv_cache.blob_shape)
     dtype: str
     row: np.ndarray  # packed [2 + 2N] (token | logprob | tops)
     spans: List[Tuple[int, int]]  # per-chunk [layer_lo, layer_hi)
@@ -587,7 +582,7 @@ class KVExportStream:
             shape=tuple(blob.shape),
             dtype=str(blob.dtype),
             row=np.asarray(row),
-            spans=[(0, blob.shape[0])],
+            spans=[(0, blob_num_layers(blob.shape))],
             _blob=blob_to_host(blob),
         )
 
@@ -597,37 +592,29 @@ class KVExportStream:
 
     @property
     def nbytes(self) -> int:
-        """Wire bytes of the full blob.  Quantized exports count the f32
-        row scales packed after each layer's int8 data (the
-        kv_cache.pack_quant_blob_bytes layout), so byte framing on both
-        ends derives identical extents from (shape, dtype)."""
-        if self.quantized:
-            from .kv_cache import quant_blob_nbytes
-
-            return quant_blob_nbytes(self.shape)
-        return int(
-            np.prod(self.shape) * jnp.dtype(self.dtype).itemsize
-        )
+        """Wire bytes of the full blob (kv_cache.blob_nbytes: byte framing
+        on both ends derives identical extents from (shape, dtype))."""
+        return blob_nbytes(self.shape, self.dtype)
 
     @property
     def chunk_bounds(self) -> List[Tuple[int, int]]:
         """Byte range of each chunk in the C-order blob (layer slabs are
         contiguous, so chunk i covers its layers' bytes exactly)."""
-        bpl = self.nbytes // self.shape[0]
+        bpl = self.nbytes // blob_num_layers(self.shape)
         return [(lo * bpl, hi * bpl) for lo, hi in self.spans]
 
     async def chunks(self):
         """Yield ``(idx, layer_lo, layer_hi, array)`` in span order as each
         group materializes; the array is a view, C-contiguity not
         guaranteed."""
-        k = self.shape[2]
+        k = blob_num_pages(self.shape)
         for idx, (lo, hi) in enumerate(self.spans):
             if self._blob is not None:
-                part = self._blob[lo:hi]
+                part = blob_layers(self._blob, lo, hi)
             else:
                 assert self._group is not None
                 span = await self._group.host_span(idx)
-                part = span[:, :, self._page_off : self._page_off + k]
+                part = blob_pages(span, self._page_off, self._page_off + k)
             # dynalint: disable=DT012 -- export-stream readiness stamps feed
             # the bench's export-before-first-byte stats, not ad-hoc timing
             now = time.perf_counter()
@@ -639,11 +626,7 @@ class KVExportStream:
     async def assemble(self) -> np.ndarray:
         """Materialize the full blob (same-process handoff / tests)."""
         parts = [part async for _, _, _, part in self.chunks()]
-        if len(parts) == 1:
-            if isinstance(parts[0], QuantKV):
-                return blob_to_host(parts[0])
-            return np.ascontiguousarray(parts[0])
-        return kv_blob_concat(parts, axis=0)
+        return concat_blob_layers(parts)
 
 
 @dataclass
@@ -732,7 +715,7 @@ class JaxEngine:
         if mesh is None:
             mesh = self.resolve_mesh(self.cfg, model_cfg)
             if mesh is not None:
-                self._refuse_two_kind("a serving mesh (tp, dp, sp or pp)")
+                self._refuse("mesh")
                 from ..parallel.sharding import shard_params
 
                 params = shard_params(params, model_cfg, mesh)
@@ -747,14 +730,10 @@ class JaxEngine:
         self._dp = int(mesh.shape.get("dp", 1)) if mesh is not None else 1
         self._sp = int(mesh.shape.get("sp", 1)) if mesh is not None else 1
         self._pp = int(mesh.shape.get("pp", 1)) if mesh is not None else 1
-        if model_cfg.is_mla and (self._sp > 1 or self._pp > 1):
-            raise ValueError(
-                "sp/pp meshes are not supported over a latent cache (MLA): "
-                "their prefill routes and stage pools assume K/V pairs "
-                "per head and per layer"
-            )
+        if self._sp > 1 or self._pp > 1:
+            self._refuse("sp_pp")
         if mesh is not None:
-            self._refuse_two_kind("a serving mesh (tp, dp, sp or pp)")
+            self._refuse("mesh")
         if mesh is not None and kv_sharding is None:
             from ..parallel.sharding import kv_pspec
 
@@ -932,13 +911,7 @@ class JaxEngine:
             disk_dir = env_spec["dir"] or disk_dir
             swap_on = env_spec["swap"] and self.cfg.swap_preemption
         if host_blocks > 0 or disk_blocks > 0:
-            self._refuse_two_kind("host/disk KV offload and swap preemption")
-        if model_cfg.is_mla and (host_blocks > 0 or disk_blocks > 0):
-            raise ValueError(
-                "host/disk KV offload is not supported over a latent cache "
-                "(MLA): the tiers move [L, 2, pages, page, Hkv, D] blocks, "
-                "and a latent pool holds two layers' rows a slab"
-            )
+            self._refuse("offload")
         if pool is not None and (host_blocks > 0 or disk_blocks > 0):
             from ..offload import KVOffloadEngine
 
@@ -973,12 +946,7 @@ class JaxEngine:
                 "ignoring malformed kv_remote config %r", self.cfg.kv_remote
             )
         if self.kv_remote_spec:
-            self._refuse_two_kind("the remote KV tier (G4)")
-        if model_cfg.is_mla and self.kv_remote_spec:
-            raise ValueError(
-                "the remote KV tier (G4) is not supported over a latent "
-                "cache (MLA): it ships the offload tiers' K/V blocks"
-            )
+            self._refuse("remote_tier")
         if "DYN_KV_REMOTE" in _os.environ:
             # env wins outright, including an explicit "off" disarming a
             # config-armed tier
@@ -1021,10 +989,8 @@ class JaxEngine:
                 )
         self._mixed_budget = max(int(budget), 1)
         if not self._mixed:
-            self._refuse_conv_state("serving without mixed batching")
+            self._refuse("unmixed")
         if model_cfg.two_kind:
-            if not self._mixed:
-                self._refuse_two_kind("serving without mixed batching")
             # every lane's most at once, so that a chunk or a decode page
             # can always be had by taking back reusable blocks: the window
             # behind each lane's next row and its decode growth, and the
@@ -1575,15 +1541,9 @@ class JaxEngine:
             ):
                 # each leaves the packed step for a classic prefill or
                 # verify dispatch, which takes a prompt's pages at once
-                self._refuse_two_kind(
-                    "a request with sampling penalties, a soft prompt or "
-                    "speculation (the classic prefill and verify dispatches)"
-                )
+                self._refuse("classic_dispatch")
             if seq.prompt_logprobs is not None:
-                self._refuse_conv_state(
-                    "a request for the prompt's log-probabilities (the "
-                    "scoring step)"
-                )
+                self._refuse("scoring")
             self._arm_speculation(seq)  # unknown drafter -> error stream
             self.sched.enqueue(seq)
         except ValueError as e:
@@ -1746,7 +1706,7 @@ class JaxEngine:
         """
         if not token_batches:
             return []
-        self._refuse_conv_state("pooled embeddings (the embedding step)")
+        self._refuse("embedding")
         for t in token_batches:
             if not t:
                 raise ValueError("embedding input must be non-empty")
@@ -1801,34 +1761,13 @@ class JaxEngine:
     ) -> AsyncIterator[Annotated]:
         """Admit a request whose prompt KV a remote prefill worker delivers;
         the lane holds pages but decodes only after deliver_external."""
-        self._refuse_latent("disaggregated serving (a remote prefill's KV)")
+        self._refuse("disagg_serving")
         return await self.generate(request, _external=True)
 
-    def _refuse_two_kind(self, what: str) -> None:
-        """Everything that moves or reshapes KV beyond one chip's hot path
-        assumes one pool and one page table a lane, and nothing beside a
-        sequence's pages."""
-        if self.model_cfg.two_kind:
-            raise ValueError(two_kind_refusal(what))
-        self._refuse_conv_state(what)
-
-    def _refuse_conv_state(self, what: str) -> None:
-        """The convolution layers' state is carried, snapshotted and
-        restored by the packed step and the decode steps, and by them
-        alone."""
-        if self.model_cfg.has_conv:
-            raise ValueError(conv_state_refusal(what))
-
-    def _refuse_latent(self, what: str) -> None:
-        """KV transfer paths ship ``[L, 2, pages, page, Hkv, D]`` blobs: over
-        a latent cache (MLA) they would move bytes of the wrong shape, and
-        over a two-kind cache the bytes of one pool of two."""
-        self._refuse_two_kind(what)
-        if self.model_cfg.is_mla:
-            raise ValueError(
-                f"{what} is not supported over a latent cache (MLA): the "
-                "transfer formats carry K/V pairs per head and per layer"
-            )
+    def _refuse(self, capability: str) -> None:
+        """Raise the sentence with which this model's kind of cache refuses
+        ``capability``, if it does (the table is kv_cache.KV_REFUSALS)."""
+        refuse(self.model_cfg, capability)
 
     def awaiting_external(self, request_id: str) -> bool:
         """True while the request is admitted (or queued) and still expects a
@@ -1842,14 +1781,14 @@ class JaxEngine:
         first_token: int,
         lp_row: Optional[np.ndarray] = None,
     ) -> bool:
-        """Hand over a remote prefill's KV (``[L, 2, n_pages, page, Hkv, D]``)
+        """Hand over a remote prefill's KV (the blob of the prompt's pages)
         plus its sampled first token (and, optionally, the packed logprob
         row the prefill worker sampled it from -- without it a logprobs
         request's first token would ship without its logprob, leaving the
         OpenAI arrays one short).  Returns False when the request is no
         longer waiting (cancelled/failed).  Applied by the tick loop at its
         next iteration -- scheduler state is never touched from here."""
-        self._refuse_latent("a remote prefill's KV delivery")
+        self._refuse("kv_delivery")
         if request_id not in self._external:
             return False
         arr = np.asarray(first_token).reshape(-1)
@@ -1895,10 +1834,10 @@ class JaxEngine:
         layer_hi: int,
         arr: np.ndarray,
     ) -> bool:
-        """Stage one layer-group chunk ``[layer_hi-layer_lo, 2, n_pages,
-        page, Hkv, D]``; the tick loop scatters it into the lane's pages at
+        """Stage one layer-group chunk (layers ``[layer_lo, layer_hi)`` of the
+        prompt's blob); the tick loop scatters it into the lane's pages at
         its next iteration (or as soon as the lane gets a slot)."""
-        self._refuse_latent("a remote prefill's KV delivery")
+        self._refuse("kv_delivery")
         rec = self._chunked.get(request_id)
         if rec is None or request_id not in self._external:
             return False
@@ -1942,22 +1881,6 @@ class JaxEngine:
             self._wake.set()
         return True
 
-    @staticmethod
-    def _assemble_kv(arr) -> np.ndarray:
-        """Materialize a KV slice on host: per-shard head-slice gathers
-        reassembled for sharded pools (parallel.sharding.assemble_shards),
-        plain device_get otherwise.  Every export path routes through here
-        so the wire/offload blob format stays full-width regardless of the
-        serving mesh.  Quantized slices assemble data and scales together
-        (scales are replicated -- a plain device_get)."""
-        from ..parallel.sharding import assemble_shards
-
-        if isinstance(arr, QuantKV):
-            return QuantKV(
-                q=assemble_shards(arr.q), s=assemble_shards(arr.s)
-            )
-        return assemble_shards(arr)
-
     def _coerce_blob(self, blob):
         """Bring a delivered/onboarded blob into this pool's dtype domain
         (kv_cache.coerce_kv_blob): same-domain blobs pass through
@@ -1968,9 +1891,8 @@ class JaxEngine:
         return coerce_kv_blob(blob, self.kv.quantized, self.kv.dtype)
 
     def _expected_blob_shape(self, seq: SeqState) -> Tuple[int, ...]:
-        kp = self.kv.pages.shape  # [L, 2, num_pages, page, Hkv, D]
         n_pages = -(-len(seq.prompt) // self.cfg.page_size)
-        return (kp[0], kp[1], n_pages) + tuple(kp[3:])
+        return blob_shape(self.kv.pages.shape, n_pages)
 
     def _drop_external(self, rid: str, message: str) -> None:
         """Fail one parked external request without touching the rest of the
@@ -2007,7 +1929,10 @@ class JaxEngine:
                 self._deliveries[rid] = (blob, first, lp_row)
                 continue
             expect = self._expected_blob_shape(seq)
-            if tuple(blob.shape) != expect or expect[2] > len(seq.pages):
+            if (
+                tuple(blob.shape) != expect
+                or blob_num_pages(expect) > len(seq.pages)
+            ):
                 # a mis-configured prefill worker (page_size/model mismatch)
                 # must not take down the whole decode batch
                 self._external_deadline.pop(rid, None)
@@ -2049,7 +1974,10 @@ class JaxEngine:
                 continue  # not admitted yet: parts stay staged
             if not rec.validated:
                 expect = self._expected_blob_shape(seq)
-                if rec.shape != expect or expect[2] > len(seq.pages):
+                if (
+                    rec.shape != expect
+                    or blob_num_pages(expect) > len(seq.pages)
+                ):
                     del self._chunked[rid]
                     self._external.pop(rid, None)
                     self._external_deadline.pop(rid, None)
@@ -2061,13 +1989,15 @@ class JaxEngine:
                     self.sched.cancel(seq)
                     continue
                 rec.validated = True
-            L = rec.shape[0]
+            L = blob_num_layers(rec.shape)
             bad = next(
                 (
                     (lo, hi, arr)
                     for lo, hi, arr in rec.parts
                     if not (0 <= lo < hi <= L)
-                    or tuple(arr.shape) != (hi - lo,) + rec.shape[1:]
+                    or tuple(arr.shape) != blob_shape(
+                        rec.shape, num_layers=hi - lo
+                    )
                 ),
                 None,
             )
@@ -2122,8 +2052,6 @@ class JaxEngine:
         lane's pages (the incremental half of a chunked delivery; the
         first-token commit waits for the barrier)."""
         compile_sentry.set_entry("kv_pages")
-        from .kv_cache import pad_page_axis
-
         _n_pages, bucket, ids = self._lane_scatter_ids(seq)
         ids_dev = jnp.asarray(ids)
         for lo, hi, arr in parts:
@@ -2156,8 +2084,6 @@ class JaxEngine:
         # delivery.  Destination ids are page-bucketed by the shared
         # helper (blob shape was validated against the prompt's page count
         # in _process_deliveries).
-        from .kv_cache import pad_page_axis
-
         _n_pages, bucket, ids = self._lane_scatter_ids(seq)
         padded = pad_page_axis(self._coerce_blob(blob), bucket)
         self.kv.pages = self._fns.scatter_block_pages(
@@ -2197,9 +2123,9 @@ class JaxEngine:
         self, req: PreprocessedRequest
     ) -> Tuple[np.ndarray, int]:
         """Prefill-worker side: run a standalone prefill into scratch pages,
-        return (kv_blob [L, 2, n_pages, page, Hkv, D], first_token) and free
+        return (the blob of the prompt's pages, first_token) and free
         the scratch.  Serialized with the tick loop via the engine executor."""
-        self._refuse_latent("a disaggregated prefill export")
+        self._refuse("prefill_export")
         if not self._running:
             await self.start()
         loop = asyncio.get_running_loop()
@@ -2216,7 +2142,7 @@ class JaxEngine:
             seq = SeqState.from_request("export", req, self.sched.block_size)
             sampled = self._dispatch_full_prefill(seq, prompt, pages)
             ids = np.asarray(pages, np.int32)
-            blob = self._assemble_kv(self.kv.pages[:, :, ids])
+            blob = assemble_blob(self.kv.read_pages(ids))
             # the full packed row (token | logprob | tops): delivery carries
             # it so a logprobs request's first token keeps its logprob
             row = np.asarray(jax.device_get(sampled))[0]
@@ -2246,7 +2172,7 @@ class JaxEngine:
         the device->host transfer of the blobs no longer occupies the
         executor, so decode/prefill ticks overlap the transfer instead of
         serializing behind it (round-4 verdict #8)."""
-        self._refuse_latent("a disaggregated prefill export")
+        self._refuse("prefill_export")
         if not self._running:
             await self.start()
         loop = asyncio.get_running_loop()
@@ -2261,7 +2187,7 @@ class JaxEngine:
             if self.kv.shard_geometry is not None:
                 # sharded pool: each blob assembles from its per-shard
                 # head slices (one D2H per shard, no device all-gather)
-                blobs = [self._assemble_kv(results[i][0]) for i in idx]
+                blobs = [assemble_blob(results[i][0]) for i in idx]
             else:
                 # ONE bundled device_get for every blob (a per-item get
                 # would pay one device round trip each on a high-RTT link)
@@ -2345,15 +2271,15 @@ class JaxEngine:
                 # device-resident export: the gather materializes a copy of
                 # the group's pages on device (freeing the scratch pages
                 # below is safe), and only the first tokens come to host
-                blob_all = self.kv.pages[:, :, jnp.asarray(all_ids)]
+                blob_all = self.kv.read_pages(jnp.asarray(all_ids))
             else:
                 # one transfer per shard for the whole group's pages
-                blob_all = self._assemble_kv(self.kv.pages[:, :, all_ids])
+                blob_all = assemble_blob(self.kv.read_pages(all_ids))
             firsts = np.asarray(jax.device_get(sampled))  # [Bp, 2 + 2N]
             off = 0
             for row, (i, pages) in enumerate(zip(group, allocated)):
                 k = len(pages)
-                results[i] = (blob_all[:, :, off : off + k], firsts[row])
+                results[i] = (blob_pages(blob_all, off, off + k), firsts[row])
                 off += k
         finally:
             for pages in allocated:
@@ -2377,7 +2303,7 @@ class JaxEngine:
         request: a :class:`KVExportStream` or the per-request ``Exception``.
         Shares the dispatch site with the aggregated path, preserving
         disagg == aggregated output."""
-        self._refuse_latent("a disaggregated prefill export")
+        self._refuse("prefill_export")
         if not self._running:
             await self.start()
         loop = asyncio.get_running_loop()
@@ -2436,8 +2362,6 @@ class JaxEngine:
         program order) and nothing blocks on the bulk transfer here --
         only the tiny sampled rows come to host."""
         compile_sentry.set_entry("kv_export")
-        from .kv_cache import layer_chunk_spans
-
         ps = self.cfg.page_size
         allocated: List[List[int]] = []
         try:
@@ -2480,12 +2404,11 @@ class JaxEngine:
                 span_devs.append(sl)
             firsts = np.asarray(jax.device_get(sampled))  # [Bp, 2 + 2N]
             shared = _GroupSpanExport(span_devs)
-            tail = tuple(self.kv.pages.shape[3:])
             off = 0
             for row, (i, pages) in enumerate(zip(group, allocated)):
                 k = len(pages)
                 results[i] = KVExportStream(
-                    shape=(L, 2, k) + tail,
+                    shape=blob_shape(self.kv.pages.shape, k),
                     dtype=str(self.kv.pages.dtype),
                     row=firsts[row],
                     spans=spans,
@@ -2507,7 +2430,7 @@ class JaxEngine:
         export/import; G4).  Consults G1 (HBM pool, one bundled device
         transfer) then the offload tiers; stops at the first miss, because
         an importer can only use a contiguous prefix."""
-        self._refuse_latent("a KV block export")
+        self._refuse("block_export")
         if not self._running:
             await self.start()
         loop = asyncio.get_running_loop()
@@ -2529,14 +2452,14 @@ class JaxEngine:
                     all_ids = np.concatenate(
                         [np.asarray(b.pages, np.int32) for b in acquired]
                     )
-                    blob_all = self._assemble_kv(self.kv.pages[:, :, all_ids])
+                    blob_all = assemble_blob(self.kv.read_pages(all_ids))
                     off = 0
                     for blk in acquired:
                         k = len(blk.pages)
                         out.append(
                             (
                                 blk.sequence_hash,
-                                blob_all[:, :, off : off + k],
+                                blob_pages(blob_all, off, off + k),
                                 {
                                     "block_hash": blk.block_hash,
                                     "parent_sequence_hash": blk.parent_sequence_hash,
@@ -5156,8 +5079,6 @@ class JaxEngine:
         compile-cache entries stay O(page buckets x layer groups)."""
         compile_sentry.set_entry("kv_pages")
         from ..runtime import faults
-        from .kv_cache import layer_chunk_spans, pad_page_axis
-
         sched = self.sched
         if not seq.pending_onboard:
             return
@@ -5170,16 +5091,15 @@ class JaxEngine:
         ids = np.concatenate(
             [np.asarray(pages, np.int32) for _h, pages, _b, _m in pending]
         )
-        blob = kv_blob_concat(
-            [self._coerce_blob(blob_to_host(b)) for _h, _p, b, _m in pending],
-            axis=2,
+        blob = concat_blob_pages(
+            [self._coerce_blob(blob_to_host(b)) for _h, _p, b, _m in pending]
         )
         bucket = pick_page_bucket(len(ids), self.sched.max_pages)
         ids_p = np.zeros((bucket,), np.int32)  # pad -> trash page 0
         ids_p[: len(ids)] = ids
         ids_dev = jnp.asarray(ids_p)
         padded = pad_page_axis(blob, bucket)
-        L = int(blob.shape[0])
+        L = blob_num_layers(blob.shape)
         # dynalint: disable=DT012 -- routes into dynamo_kv_onboard_seconds
         t0 = time.perf_counter()
         for lo, hi in layer_chunk_spans(L, None, DEFAULT_EXPORT_CHUNKS):
@@ -5187,7 +5107,7 @@ class JaxEngine:
                 self.kv.pages,
                 jnp.asarray(np.arange(lo, hi, dtype=np.int32)),
                 ids_dev,
-                as_device_blob(padded[lo:hi]),
+                as_device_blob(blob_layers(padded, lo, hi)),
             )
         self.offload_engine.record_onboard(
             # dynalint: disable=DT012 -- routes into dynamo_kv_onboard_seconds
@@ -5335,8 +5255,6 @@ class JaxEngine:
         wait happens on the executor (never the event loop), yielding the
         true H2D throughput for the ``kv_onboard_gbps`` accounting."""
         compile_sentry.set_entry("kv_pages")
-        from .kv_cache import layer_chunk_spans, pad_page_axis
-
         rid = seq.request_id
         sched = self.sched
         try:
@@ -5368,7 +5286,7 @@ class JaxEngine:
                 seq.slot < 0
                 or sched.slots[seq.slot] is not seq
                 or n_pages > len(seq.pages)
-                or tuple(blob.shape[2:3]) != (n_pages,)
+                or blob_num_pages(blob.shape) != n_pages
             ):
                 self._swapped[rid] = seq  # re-examine next tick
                 return
@@ -5382,7 +5300,7 @@ class JaxEngine:
             if blob is not dev:
                 blob = self._coerce_blob(blob)
             padded = pad_page_axis(blob, bucket)
-            L = int(blob.shape[0])
+            L = blob_num_layers(blob.shape)
             # dynalint: disable=DT012 -- routes into dynamo_kv_onboard_seconds
             t0 = time.perf_counter()
             for lo, hi in layer_chunk_spans(L, None, DEFAULT_EXPORT_CHUNKS):
@@ -5390,7 +5308,7 @@ class JaxEngine:
                     self.kv.pages,
                     jnp.asarray(np.arange(lo, hi, dtype=np.int32)),
                     ids_dev,
-                    as_device_blob(padded[lo:hi]),
+                    as_device_blob(blob_layers(padded, lo, hi)),
                 )
             self.kv.pages.block_until_ready()
             self.offload_engine.record_onboard(

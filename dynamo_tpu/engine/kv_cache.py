@@ -1,26 +1,45 @@
-"""Paged KV cache: device pages + host-side page allocator.
+"""Paged KV cache: the device pool, its host-side page allocator, and the
+one home of the cache's data format.
 
 The G1 (HBM) tier of the multi-tier design (reference block_manager
-CacheLevel G1, lib/llm/src/block_manager.rs:66-80).  One device array per
-model:
+CacheLevel G1, lib/llm/src/block_manager.rs:66-80).  Page 0 is the reserved
+trash page (inactive batch lanes write there), so the usable pool is pages
+``1..num_pages``.  Allocation is a host-side free list: page ids are just
+ints; the device arrays are only touched by the jitted step functions
+(functional update, buffer donated so XLA updates in place).
 
-    kv_pages: [num_layers, 2, num_pages, page_size, num_kv_heads, head_dim]
+Four kinds of pool, each a pytree that rides the layer scan and jit
+donation (``PagedKVCache`` builds the one a ``ModelConfig`` asks for):
 
-Page 0 is the reserved trash page (inactive batch lanes write there), so the
-usable pool is pages ``1..num_pages``.  Allocation is a host-side free list:
-page ids are just ints; the device array is only touched by the jitted step
-functions (functional update, buffer donated so XLA updates in place).
+    pair      one array ``[layers, 2, pages, page, Hkv, D]``; with
+              ``--kv-dtype int8`` a ``QuantKV``: the int8 array and one f32
+              scale a row ``[layers, 2, pages, page]``
+    LatentKV  (MLA) ``[ceil(L/2), 1, pages, page, 1, 2 (C + R)]``: one row a
+              token that is key and value, two layers a slab
+    KindKV    (window and full layers) two pair pools, two allocators, two
+              page tables a lane
+    ConvKV    (convolution layers) a pair pool of the attention layers, and
+              the convolution state by lane and by page beside it
 
-G2 (host RAM) / G3 (disk) offload tiers and the sequence-hash reuse registry
-live in dynamo_tpu.block_manager; this module is the minimal engine-facing
-pool.
+A *blob* is a block of a pair pool outside it (an evicted block, a swap
+snapshot, a remote prefill's export, a donor's prefix block): a pytree of
+arrays that share their first four axes ``[layers, 2, pages, page]``.
+Every operation on one -- to host, to device, pad, concatenate, slice, the
+jitted page movers, the stored arrays and the wire bytes -- is in the
+section "the blob" below, written once over the leaves; no other module
+names an axis of it or looks inside.  What a kind of pool cannot do is one
+table, ``KV_REFUSALS``, read through ``kv_refusal``.
+
+G2 (host RAM) / G3 (disk) offload tiers and the sequence-hash reuse
+registry live in dynamo_tpu.offload and dynamo_tpu.block_manager.
 """
 
 from __future__ import annotations
 
 import threading
 from dataclasses import dataclass
-from typing import Any, List, Optional, Tuple
+from functools import partial
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -65,9 +84,37 @@ PACKED_DISPATCH_SITES = (
 # ---------------------------------------------------------------------------
 
 
+_map = jax.tree_util.tree_map
+_leaves = jax.tree_util.tree_leaves
+
+
+class _PoolTree:
+    """What the pool pytrees below answer like the array they stand for,
+    written once over their leaves."""
+
+    @property
+    def nbytes(self) -> int:
+        return sum(int(a.nbytes) for a in _leaves(self))
+
+    def block_until_ready(self):
+        for a in _leaves(self):
+            a.block_until_ready()
+        return self
+
+    # the commit's handles (engine._start_host_copy, _handles_ready): a leaf
+    # without the call, a host array, skips it as it does there
+    def copy_to_host_async(self) -> None:
+        for a in _leaves(self):
+            if hasattr(a, "copy_to_host_async"):
+                a.copy_to_host_async()
+
+    def is_ready(self) -> bool:
+        return all(a.is_ready() for a in _leaves(self) if hasattr(a, "is_ready"))
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclass
-class QuantKV:
+class QuantKV(_PoolTree):
     """An int8 KV payload + its per-row scales.
 
     Used both for the live device pool (``PagedKVCache.pages`` when
@@ -78,9 +125,9 @@ class QuantKV:
     tree_map-based sharding harvests unchanged.
 
     Mirrors enough of the ndarray surface (``shape``/``dtype``/``ndim``/
-    ``nbytes`` of the data, leading-axis ``__getitem__``) that geometry
-    code -- shape validation, layer-span slicing, page-axis arithmetic --
-    treats it like the bf16 array it replaces.  ``q`` is int8
+    ``nbytes`` of the data, leading-axis ``__getitem__``, the host-copy
+    handles) that geometry code -- shape validation, the commit's readiness
+    probe -- treats it like the bf16 array it replaces.  ``q`` is int8
     ``[L, 2, n, page, Hkv, D]``; ``s`` is f32 ``[L, 2, n, page]``.
     """
 
@@ -107,17 +154,12 @@ class QuantKV:
     def ndim(self):
         return self.q.ndim
 
-    @property
-    def nbytes(self) -> int:
-        return int(self.q.nbytes) + int(self.s.nbytes)
-
     def __getitem__(self, key):
         """Apply a leading-axes key to data AND scales.
 
-        Valid keys index at most the shared ``[L, 2, pages, page]`` axes
-        (layer-span slices, page-id gathers) -- exactly what the egress
-        and geometry code does.  Keys reaching into (Hkv, D) would
-        desynchronize the pair and raise."""
+        Valid keys index at most the shared ``[L, 2, pages, page]`` axes.
+        Keys reaching into (Hkv, D) would desynchronize the pair and
+        raise."""
         klen = len(key) if isinstance(key, tuple) else 1
         if klen > self.s.ndim:
             raise IndexError(
@@ -125,18 +167,9 @@ class QuantKV:
             )
         return QuantKV(q=self.q[key], s=self.s[key])
 
-    def block_until_ready(self) -> "QuantKV":
-        self.q.block_until_ready()
-        self.s.block_until_ready()
-        return self
-
     def copy(self) -> "QuantKV":
         """Host-side deep copy (tier ring get/demote semantics)."""
         return QuantKV(q=np.array(self.q), s=np.array(self.s))
-
-    def astype_like(self, compute_dtype) -> Any:
-        """Dequantized dense array (tests / cross-dtype delivery)."""
-        return dequantize_kv_blob(self, compute_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -164,7 +197,7 @@ class QuantKV:
 
 @jax.tree_util.register_pytree_node_class
 @dataclass
-class LatentKV:
+class LatentKV(_PoolTree):
     data: Any  # [ceil(L/2), 1, pages, page, 1, 2 * (C + R)]
     c: int  # width of c_kv (static)
 
@@ -184,16 +217,8 @@ class LatentKV:
         return self.data.dtype
 
     @property
-    def nbytes(self) -> int:
-        return int(self.data.nbytes)
-
-    @property
     def r(self) -> int:
         return self.data.shape[-1] // 2 - self.c
-
-    def block_until_ready(self) -> "LatentKV":
-        self.data.block_until_ready()
-        return self
 
     def lanes_of(self, half):
         """[2 (C + R)] bool: the slab row's lanes that belong to the layer
@@ -282,7 +307,7 @@ class LatentLayer:
 
 @jax.tree_util.register_pytree_node_class
 @dataclass
-class KindKV:
+class KindKV(_PoolTree):
     full: Any  # [Lf, 2, Pf, page, Hkv, D]
     window: Any  # [Lw, 2, Pw, page, Hkv, D]
 
@@ -297,15 +322,6 @@ class KindKV:
     @property
     def dtype(self):
         return self.full.dtype
-
-    @property
-    def nbytes(self) -> int:
-        return int(self.full.nbytes) + int(self.window.nbytes)
-
-    def block_until_ready(self) -> "KindKV":
-        self.full.block_until_ready()
-        self.window.block_until_ready()
-        return self
 
     def of(self, kind: str):
         return self.full if kind == "full" else self.window
@@ -353,7 +369,7 @@ class KindKV:
 
 @jax.tree_util.register_pytree_node_class
 @dataclass
-class ConvKV:
+class ConvKV(_PoolTree):
     attn: Any  # [La, 2, P, page, Hkv, D]
     lanes: Any  # [Lc, 2 B, H]
     pages: Any  # [Lc, 2 P, H]
@@ -370,26 +386,98 @@ class ConvKV:
     def dtype(self):
         return self.attn.dtype
 
-    @property
-    def nbytes(self) -> int:
-        return sum(int(a.nbytes) for a in (self.attn, self.lanes, self.pages))
 
-    def block_until_ready(self) -> "ConvKV":
-        for a in (self.attn, self.lanes, self.pages):
-            a.block_until_ready()
-        return self
+# ---------------------------------------------------------------------------
+# what a kind of cache cannot do
+#
+# Everything that moves, rewinds or reshapes KV outside one chip's packed
+# step was written for one pair pool with one page table a lane and nothing
+# beside a sequence's pages.  A kind of cache that is not that refuses such
+# a capability with one sentence: ``{what} is not supported over {the
+# kind}: {why}``, or the capability's own where it has one to give.  The
+# engine asks at configuration and at a request (``JaxEngine._refuse``), the
+# pool when it is built, the step functions when they are traced.
+# ---------------------------------------------------------------------------
+
+# (kind, the ModelConfig property that tells it, what a sentence calls it,
+# why), in the order a trunk of several kinds answers
+_KINDS = (
+    ("two_kind", "two_kind",
+     "a two-kind cache (window and full layers, layer_types)",
+     "its pages live in two pools with two page tables a lane, and the "
+     "transfer formats and meshes carry one"),
+    ("conv", "has_conv",
+     "a trunk with convolution layers (layer_types 'conv')",
+     "a sequence carries two rows a layer beside its pages, which only the "
+     "packed step and the fused decode steps carry, snapshot and restore"),
+    ("latent", "is_mla",
+     "a latent cache (MLA)",
+     "the transfer formats carry K/V pairs per head and per layer"),
+)
+_PAIR_ONLY = {"two_kind": None, "conv": None}
+_ONE_POOL = {**_PAIR_ONLY, "latent": None}
+_STATELESS = {"conv": None}
+
+# capability -> (what the sentence calls it, {kind that refuses it: None
+# for the kind's own reason, or the whole sentence})
+KV_REFUSALS: Dict[str, Tuple[str, Dict[str, Optional[str]]]] = {
+    "mesh": ("a serving mesh (tp, dp, sp or pp)", _PAIR_ONLY),
+    "sp_pp": ("sp/pp meshes", {
+        "latent": "sp/pp meshes are not supported over a latent cache "
+        "(MLA): their prefill routes and stage pools assume K/V pairs per "
+        "head and per layer"}),
+    "offload": ("host/disk KV offload and swap preemption", {
+        **_PAIR_ONLY,
+        "latent": "host/disk KV offload is not supported over a latent "
+        "cache (MLA): the tiers move [L, 2, pages, page, Hkv, D] blocks, "
+        "and a latent pool holds two layers' rows a slab"}),
+    "remote_tier": ("the remote KV tier (G4)", {
+        **_PAIR_ONLY,
+        "latent": "the remote KV tier (G4) is not supported over a latent "
+        "cache (MLA): it ships the offload tiers' K/V blocks"}),
+    "int8_pool": ("an int8 pool", {
+        **_PAIR_ONLY,
+        "latent": "kv_dtype int8 is not supported over a latent cache "
+        "(MLA): one scale a row would span c_kv and the rotated key, whose "
+        "ranges differ"}),
+    "sharded_pool": ("a sharded pool", _PAIR_ONLY),
+    "window_layers": ("a trunk of window and full layers", _STATELESS),
+    "disagg_serving": (
+        "disaggregated serving (a remote prefill's KV)", _ONE_POOL),
+    "kv_delivery": ("a remote prefill's KV delivery", _ONE_POOL),
+    "prefill_export": ("a disaggregated prefill export", _ONE_POOL),
+    "block_export": ("a KV block export", _ONE_POOL),
+    "unmixed": ("serving without mixed batching", _PAIR_ONLY),
+    "classic_dispatch": (
+        "a request with sampling penalties, a soft prompt or speculation "
+        "(the classic prefill and verify dispatches)", _PAIR_ONLY),
+    "scoring": (
+        "a request for the prompt's log-probabilities (the scoring step)",
+        _STATELESS),
+    "embedding": ("pooled embeddings (the embedding step)", _STATELESS),
+    "unmasked_decode_step": (
+        "a decode step that is not told which lanes it advances",
+        _STATELESS),
+    "classic_step": (
+        "a step outside the packed step and the decode steps (classic "
+        "prefill, verify, scoring, embedding)", _STATELESS),
+}
 
 
-def conv_state_refusal(what: str) -> str:
-    """The one sentence with which everything that moves, rewinds or
-    reshapes KV outside the packed step refuses a trunk with convolution
-    layers."""
-    return (
-        f"{what} is not supported over a trunk with convolution layers "
-        "(layer_types 'conv'): a sequence carries two rows a layer beside "
-        "its pages, which only the packed step and the fused decode steps "
-        "carry, snapshot and restore"
-    )
+def kv_refusal(cfg: ModelConfig, capability: str) -> Optional[str]:
+    """The sentence with which ``cfg``'s kind of cache refuses
+    ``capability`` (a key of ``KV_REFUSALS``), or None where it serves it."""
+    what, kinds = KV_REFUSALS[capability]
+    for kind, told_by, over, why in _KINDS:
+        if kind in kinds and getattr(cfg, told_by):
+            return kinds[kind] or f"{what} is not supported over {over}: {why}"
+    return None
+
+
+def refuse(cfg: ModelConfig, capability: str) -> None:
+    sentence = kv_refusal(cfg, capability)
+    if sentence is not None:
+        raise ValueError(sentence)
 
 
 def kv_data(kv_pages):
@@ -404,16 +492,6 @@ def kv_data(kv_pages):
     if isinstance(kv_pages, QuantKV):
         return kv_pages.q
     return kv_pages.data if isinstance(kv_pages, LatentKV) else kv_pages
-
-
-def two_kind_refusal(what: str) -> str:
-    """The one sentence with which everything that moves or reshapes KV
-    outside one chip's hot path refuses a two-kind cache."""
-    return (
-        f"{what} is not supported over a two-kind cache (window and full "
-        "layers, layer_types): its pages live in two pools with two page "
-        "tables a lane, and the transfer formats and meshes carry one"
-    )
 
 
 def kv_num_layers(kv_pages) -> int:
@@ -527,29 +605,104 @@ def dequantize_kv_blob(blob: QuantKV, dtype: Any = np.float32) -> Any:
     ).astype(dtype)
 
 
-def kv_blob_concat(blobs: List[Any], axis: int = 2) -> Any:
-    """Concatenate KV blobs along a shared leading axis (the onboard path
-    stacks an admission's tier hits on the pages axis) -- pair-aware."""
-    if blobs and isinstance(blobs[0], QuantKV):
-        return QuantKV(
-            q=np.concatenate([np.asarray(b.q) for b in blobs], axis=axis),
-            s=np.concatenate([np.asarray(b.s) for b in blobs], axis=axis),
-        )
-    return np.concatenate([np.asarray(b) for b in blobs], axis=axis)
+# ---------------------------------------------------------------------------
+# the blob: a block of a pair pool outside it
+#
+# A pytree of arrays that share their first four axes ``[layers, 2, pages,
+# page]``: a dense pool's blob is its one array ``[.., Hkv, D]``, an int8
+# pool's the ``QuantKV`` of that array in int8 and its row scales.  Every
+# function here is written once over the leaves.  The stored and the wire
+# forms, which a block written by any commit must keep loading from:
+#
+#   arrays  ``{"blob": data[, "blob_scales": scales]}``: the disk tier's
+#           ``.npz`` keys and the host tier's rings
+#   bytes   each leaf's C-order bytes in that order (int8 data, then f32
+#           scales).  A frame carries the data's ``(shape, dtype)``; dtype
+#           ``int8`` means the pair, and both extents follow from the shape.
+#           The G4 frame says it once more as kind ``quant`` | ``dense``
+# ---------------------------------------------------------------------------
+
+LAYER_AXIS = 0
+PAGE_AXIS = 2
+_SHARED_AXES = 4  # [layers, 2, pages, page]
+_ARRAY_NAMES = ("blob", "blob_scales")  # in leaf order
+
+
+def _at_pages(key) -> tuple:
+    return (slice(None),) * PAGE_AXIS + (key,)
 
 
 def as_device_blob(blob: Any) -> Any:
-    """``jnp.asarray`` for either blob form (scatter-site upload)."""
-    if isinstance(blob, QuantKV):
-        return QuantKV(q=jnp.asarray(blob.q), s=jnp.asarray(blob.s))
-    return jnp.asarray(blob)
+    """``jnp.asarray`` of every leaf (scatter-site upload)."""
+    return _map(jnp.asarray, blob)
 
 
 def blob_to_host(blob: Any) -> Any:
-    """``np.asarray`` for either blob form (tier materialize)."""
-    if isinstance(blob, QuantKV):
-        return QuantKV(q=np.asarray(blob.q), s=np.asarray(blob.s))
-    return np.asarray(blob)
+    """``np.asarray`` of every leaf (tier materialize)."""
+    return _map(np.asarray, blob)
+
+
+def assemble_blob(blob: Any) -> Any:
+    """Materialize a slice of the pool on host, each leaf reassembled from
+    its per-shard head slices where the pool is sharded
+    (``parallel.sharding.assemble_shards``; a plain ``device_get``
+    otherwise): the wire and offload forms are full-width whatever the
+    serving mesh."""
+    from ..parallel.sharding import assemble_shards
+
+    return _map(assemble_shards, blob)
+
+
+def _concat_blobs(blobs: List[Any], axis: int) -> Any:
+    return _map(
+        lambda *xs: np.concatenate([np.asarray(x) for x in xs], axis), *blobs
+    )
+
+
+def concat_blob_pages(blobs: List[Any]) -> Any:
+    """Host blobs end to end on the page axis (an admission's tier hits)."""
+    return _concat_blobs(blobs, PAGE_AXIS)
+
+
+def concat_blob_layers(blobs: List[Any]) -> Any:
+    """Host blobs of consecutive layer spans, stacked to the whole."""
+    return _concat_blobs(blobs, LAYER_AXIS)
+
+
+def blob_layers(blob: Any, lo: int, hi: int) -> Any:
+    """Layers ``[lo, hi)`` of a blob (a view)."""
+    return _map(lambda a: a[lo:hi], blob)
+
+
+def blob_pages(blob: Any, lo: int, hi: int) -> Any:
+    """Pages ``[lo, hi)`` of a blob (a view): one request's of a group's."""
+    return _map(lambda a: a[_at_pages(slice(lo, hi))], blob)
+
+
+def blob_num_pages(shape) -> int:
+    return int(shape[PAGE_AXIS])
+
+
+def blob_num_layers(shape) -> int:
+    return int(shape[LAYER_AXIS])
+
+
+def blob_tokens(shape) -> int:
+    """Token rows a blob of ``shape`` holds (pages x page)."""
+    return int(shape[PAGE_AXIS]) * int(shape[PAGE_AXIS + 1])
+
+
+def blob_shape(
+    shape, num_pages: Optional[int] = None, num_layers: Optional[int] = None
+) -> Tuple[int, ...]:
+    """``shape`` (a pool's, a blob's) at ``num_pages`` pages and
+    ``num_layers`` layers, each as it is by default."""
+    shape = list(int(x) for x in shape)
+    if num_pages is not None:
+        shape[PAGE_AXIS] = int(num_pages)
+    if num_layers is not None:
+        shape[LAYER_AXIS] = int(num_layers)
+    return tuple(shape)
 
 
 def coerce_kv_blob(blob: Any, pool_quantized: bool, compute_dtype) -> Any:
@@ -568,33 +721,180 @@ def coerce_kv_blob(blob: Any, pool_quantized: bool, compute_dtype) -> Any:
     return blob
 
 
-def pack_quant_blob_bytes(blob: QuantKV) -> bytes:
-    """Wire form of a quantized blob (disagg/prefix-onboard frames): the
-    data bytes followed by the scale bytes, both C-order.  The receiver
-    re-derives both extents from the shape + ``kv_dtype`` metadata."""
-    q = np.ascontiguousarray(np.asarray(blob.q))
-    s = np.ascontiguousarray(np.asarray(blob.s, np.float32))
-    return q.tobytes() + s.tobytes()
+def pad_page_axis(blob, bucket: int):
+    """Pad a blob with zero pages up to ``bucket`` pages -- the shared
+    shape-normalization for every bucketed page scatter (external KV
+    delivery, chunked delivery, tier onboard, swap-in restore).  Pad
+    entries target trash page 0 with zero content (a zero scale row decodes
+    to zero), so one executable per page bucket serves every blob size.
+    Device-resident leaves pad on device (``np.pad`` would silently pull
+    them to host and re-upload)."""
+
+    def pad_leaf(a):
+        n = a.shape[PAGE_AXIS]
+        if bucket <= n:
+            return a
+        pad = [(0, 0)] * a.ndim
+        pad[PAGE_AXIS] = (0, bucket - n)
+        return jnp.pad(a, pad) if isinstance(a, jax.Array) else np.pad(a, pad)
+
+    return _map(pad_leaf, blob)
 
 
-def unpack_quant_blob_bytes(buf, shape: Tuple[int, ...]) -> QuantKV:
-    """Inverse of :func:`pack_quant_blob_bytes` for a ``shape``-d blob.
+# -- the stored form ---------------------------------------------------------
+
+
+def blob_to_arrays(blob: Any) -> Dict[str, Any]:
+    """The blob's leaves under their stored names."""
+    return dict(zip(_ARRAY_NAMES, _leaves(blob)))
+
+
+def blob_from_arrays(arrays) -> Any:
+    """Inverse of :func:`blob_to_arrays` over any mapping that holds the
+    names (other keys, a block's meta beside them, are not read)."""
+    leaves = [arrays[name] for name in _ARRAY_NAMES if name in arrays]
+    return QuantKV(*leaves) if len(leaves) > 1 else leaves[0]
+
+
+# -- the wire form -----------------------------------------------------------
+
+
+def _wire_leaves(shape, dtype) -> List[Tuple[Tuple[int, ...], Any]]:
+    """``(shape, dtype)`` of each leaf of the blob a frame describes."""
+    shape = tuple(int(x) for x in shape)
+    if blob_kind(dtype) == "quant":
+        return [
+            (shape, jnp.dtype(jnp.int8)),
+            (shape[:_SHARED_AXES], jnp.dtype(jnp.float32)),
+        ]
+    return [(shape, jnp.dtype(dtype))]
+
+
+def blob_kind(dtype) -> str:
+    """What a G4 frame calls a blob of this dtype."""
+    return "quant" if jnp.dtype(dtype) == jnp.int8 else "dense"
+
+
+def blob_nbytes(shape, dtype) -> int:
+    """Wire size of the blob a frame describes."""
+    return sum(
+        int(np.prod(s)) * d.itemsize for s, d in _wire_leaves(shape, dtype)
+    )
+
+
+def blob_byte_views(blob: Any) -> List[np.ndarray]:
+    """The wire bytes as flat uint8 views, one a leaf: a sender chunks them
+    in order and no buffer of the whole ever materializes.  A leaf that is
+    not C-contiguous (a request's pages of a group's transfer) is copied
+    once."""
+    return [
+        np.ascontiguousarray(a).reshape(-1).view(np.uint8)
+        for a in _leaves(blob)
+    ]
+
+
+def blob_to_bytes(blob: Any) -> bytes:
+    return b"".join(blob_byte_views(blob))
+
+
+def blob_from_bytes(buf, shape, dtype) -> Any:
+    """Inverse of :func:`blob_to_bytes` for the blob a frame describes.
 
     ``buf`` is anything exposing the buffer protocol (bytes, a uint8
-    ndarray, a memoryview) -- the returned pair ALIASES it, so a
+    ndarray, a memoryview) -- the returned leaves ALIAS it, so a
     staging-buffer caller gets a zero-copy unpack (the refcount keeps the
     backing buffer alive)."""
-    shape = tuple(int(x) for x in shape)
-    q_n = int(np.prod(shape))
-    q = np.frombuffer(buf, np.int8, count=q_n).reshape(shape)
-    s = np.frombuffer(buf, np.float32, offset=q_n).reshape(shape[:4])
-    return QuantKV(q=q, s=s)
+    leaves, off = [], 0
+    for s, d in _wire_leaves(shape, dtype):
+        n = int(np.prod(s))
+        leaves.append(np.frombuffer(buf, d, count=n, offset=off).reshape(s))
+        off += n * d.itemsize
+    return QuantKV(*leaves) if len(leaves) > 1 else leaves[0]
 
 
-def quant_blob_nbytes(shape: Tuple[int, ...]) -> int:
-    """Wire size of a quantized blob: int8 data + f32 per-row scales."""
-    shape = tuple(int(x) for x in shape)
-    return int(np.prod(shape)) + int(np.prod(shape[:4])) * 4
+# -- the jitted page movers --------------------------------------------------
+#
+# ``ids`` are page ids; pad entries of a bucketed scatter target trash page
+# 0.  The layer-range pair take the layer ids as an ARRAY (one executable per
+# (group size, page count), not one per layer range) and three adjacent
+# advanced indices, which keep a chunk in blob layout.  The serving mesh
+# re-jits the raw bodies with the pool's shardings pinned
+# (parallel/sharding.make_sharded_steps).
+
+
+def _slice_block_pages(kv_pages, ids: jax.Array):
+    """Read a block's pages (pre-eviction snapshot for G1 -> G2 demotion).
+    Dispatched before the free-list reuses the pages, so device program
+    order guarantees it reads the pre-reuse contents."""
+    return _map(lambda a: a[_at_pages(ids)], kv_pages)
+
+
+slice_block_pages = jax.jit(_slice_block_pages)
+
+
+def _scatter_block_pages(kv_pages, ids: jax.Array, blob):
+    """Write an offloaded block's contents back into fresh pages (G2/G3 ->
+    G1 onboarding).  Donated so the cache updates in place."""
+    return _map(
+        lambda a, b: a.at[_at_pages(ids)].set(b.astype(a.dtype)),
+        kv_pages, blob,
+    )
+
+
+scatter_block_pages = partial(jax.jit, donate_argnames=("kv_pages",))(
+    _scatter_block_pages
+)
+
+
+def _layer_page_index(layer_ids: jax.Array, page_ids: jax.Array) -> tuple:
+    return (
+        layer_ids[:, None, None],
+        jnp.arange(2)[None, :, None],
+        page_ids[None, None, :],
+    )
+
+
+def _gather_layer_pages(kv_pages, layer_ids: jax.Array, page_ids: jax.Array):
+    """Slice one layer-group chunk out of the pool: a device-resident copy,
+    so the scratch pages can be freed as soon as the gather is dispatched
+    (device program order, as in ``_slice_block_pages``)."""
+    at = _layer_page_index(layer_ids, page_ids)
+    return _map(lambda a: a[at], kv_pages)
+
+
+gather_layer_pages = jax.jit(_gather_layer_pages)
+
+
+def _scatter_layer_pages(
+    kv_pages, layer_ids: jax.Array, page_ids: jax.Array, blob
+):
+    """Write one layer-group chunk into its reserved pages (the incremental
+    decode-side onboard; donated so the pool updates in place)."""
+    at = _layer_page_index(layer_ids, page_ids)
+    return _map(lambda a, b: a.at[at].set(b.astype(a.dtype)), kv_pages, blob)
+
+
+scatter_layer_pages = partial(jax.jit, donate_argnames=("kv_pages",))(
+    _scatter_layer_pages
+)
+
+
+def place_pool(pages: Any, sharding) -> Any:
+    """``device_put`` a pool: the leaves with a head axis take ``sharding``
+    (kv heads over tp); an int8 pool's row scales have none and replicate --
+    they are 4/(Hkv*D) of the data, so replication costs ~nothing."""
+    mesh = getattr(sharding, "mesh", None)
+
+    def place(a):
+        if a.ndim > _SHARED_AXES:
+            return jax.device_put(a, sharding)
+        if mesh is None:
+            return a
+        return jax.device_put(
+            a, jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+        )
+
+    return _map(place, pages)
 
 
 class PageAllocator:
@@ -672,23 +972,14 @@ class PagedKVCache:
         self.allocator = allocator if allocator is not None else PageAllocator(num_pages)
         slabs, sides, heads, width = cfg.kv_geometry
         shape = (slabs, sides, num_pages, page_size, heads, width)
-        if self.quantized and cfg.is_mla:
-            raise ValueError(
-                "kv_dtype int8 is not supported over a latent cache (MLA): "
-                "one scale a row would span c_kv and the rotated key, whose "
-                "ranges differ"
-            )
-        if cfg.has_conv and (
-            self.quantized or sharding is not None or cfg.two_kind
+        for asked, capability in (
+            (self.quantized, "int8_pool"),
+            (sharding is not None, "sharded_pool"),
+            (cfg.two_kind, "window_layers"),
         ):
-            raise ValueError(conv_state_refusal(
-                "an int8 pool" if self.quantized
-                else "a sharded pool" if sharding is not None
-                else "a trunk of window and full layers"))
+            if asked:
+                refuse(cfg, capability)
         if cfg.two_kind:
-            if self.quantized or sharding is not None:
-                raise ValueError(two_kind_refusal(
-                    "an int8 pool" if self.quantized else "a sharded pool"))
             if self.num_window_pages < 2:
                 raise ValueError(
                     "a trunk of window and full layers needs num_window_pages"
@@ -707,27 +998,15 @@ class PagedKVCache:
                 ),
             )
         elif self.quantized:
-            q = jnp.zeros(shape, jnp.int8)
-            s = jnp.zeros(shape[:4], jnp.float32)
-            if sharding is not None:
-                # data shards like the dense pool (kv heads over tp); the
-                # row scales have no head axis and replicate -- they are
-                # 4/(Hkv*D) of the data, so replication costs ~nothing
-                q = jax.device_put(q, sharding)
-                mesh = getattr(sharding, "mesh", None)
-                if mesh is not None:
-                    s = jax.device_put(
-                        s,
-                        jax.sharding.NamedSharding(
-                            mesh, jax.sharding.PartitionSpec()
-                        ),
-                    )
-            self.pages: Any = QuantKV(q=q, s=s)
+            self.pages: Any = QuantKV(
+                q=jnp.zeros(shape, jnp.int8),
+                s=jnp.zeros(shape[:_SHARED_AXES], jnp.float32),
+            )
         else:
             arr = jnp.zeros(shape, self.dtype)
-            if sharding is not None:
-                arr = jax.device_put(arr, sharding)
             self.pages = LatentKV(arr, cfg.kv_lora_rank) if cfg.is_mla else arr
+        if sharding is not None:
+            self.pages = place_pool(self.pages, sharding)
         if cfg.has_conv:
             self.pages = ConvKV(self.pages, *self._conv_state(max_lanes))
 
@@ -808,8 +1087,12 @@ class PagedKVCache:
         restore sites can assert pool compatibility."""
         from ..parallel.sharding import kv_shard_geometry
 
-        arr = self.pages.q if isinstance(self.pages, QuantKV) else self.pages
-        return kv_shard_geometry(arr)
+        return kv_shard_geometry(kv_data(self.pages))
+
+    def read_pages(self, ids) -> Any:
+        """The blob of pages ``ids``, read outside jit (the export paths:
+        a copy on the device, placed like the pool)."""
+        return _slice_block_pages(self.pages, ids)
 
 
 def layer_chunk_spans(
@@ -836,29 +1119,6 @@ def layer_chunk_spans(
     return [
         (lo, min(lo + g, num_layers)) for lo in range(0, num_layers, g)
     ]
-
-
-def pad_page_axis(blob, bucket: int):
-    """Pad a KV blob ``[..., P, page, Hkv, D]`` (pages on axis 2) with
-    zeros up to ``bucket`` pages -- the shared shape-normalization for
-    every bucketed page scatter (external KV delivery, chunked delivery,
-    tier onboard, swap-in restore).  Pad entries target trash page 0 with
-    zero content, so one executable per page bucket serves every blob
-    size.  Device-resident blobs pad on device (``np.pad`` would silently
-    pull them to host and re-upload).  Quantized blobs pad data and
-    scales together (zero scale rows decode to zero -- inert)."""
-    if isinstance(blob, QuantKV):
-        return QuantKV(
-            q=pad_page_axis(blob.q, bucket), s=pad_page_axis(blob.s, bucket)
-        )
-    n = blob.shape[2]
-    if bucket <= n:
-        return blob
-    pad = [(0, 0)] * blob.ndim
-    pad[2] = (0, bucket - n)
-    if isinstance(blob, jax.Array):
-        return jnp.pad(blob, pad)
-    return np.pad(blob, pad)
 
 
 def choose_num_pages(

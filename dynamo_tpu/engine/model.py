@@ -718,18 +718,16 @@ ConvFn = Callable[
 
 
 def _conv_operator(
-    lp: Params, h: jax.Array, conv_fn: Optional[ConvFn], kv_pages, layer
+    lp: Params, h: jax.Array, cfg: ModelConfig, conv_fn: Optional[ConvFn],
+    kv_pages, layer,
 ) -> Tuple[jax.Array, Any]:
     """The gated short convolution (lfm2_moe): ``[B | C | X] = W_in h``,
     ``z = B (.) X``, a causal depthwise 3-tap filter over ``z``
     (``conv_fn``), ``W_out (C (.) conv)``."""
     if conv_fn is None:
-        from .kv_cache import conv_state_refusal
+        from .kv_cache import refuse
 
-        raise ValueError(conv_state_refusal(
-            "a step outside the packed step and the decode steps (classic "
-            "prefill, verify, scoring, embedding)"
-        ))
+        refuse(cfg, "classic_step")
     b, c, x = jnp.split(h @ mat(lp["conv_in"]), 3, axis=-1)
     mixed, kv_pages = conv_fn(b * x, lp["conv_taps"], kv_pages, layer)
     return (c * mixed) @ mat(lp["conv_out"]), kv_pages
@@ -771,7 +769,9 @@ def transformer_layer(
     # where the cache holds the kinds apart, this layer's place among its own
     layer_in_cache = layer if op_layer is None else op_layer
     if kind == "conv":  # the kind chooses the operator
-        op, kv_pages = _conv_operator(lp, h, conv_fn, kv_pages, layer_in_cache)
+        op, kv_pages = _conv_operator(
+            lp, h, cfg, conv_fn, kv_pages, layer_in_cache
+        )
         x = x + op
     elif cfg.is_mla:
         attn, kv_pages = _latent_attention(

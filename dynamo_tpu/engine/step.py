@@ -114,11 +114,9 @@ def _decode_once(
 
     def conv_fn(z, taps, kv, layer):
         if active is None:
-            from .kv_cache import conv_state_refusal
+            from .kv_cache import refuse
 
-            raise ValueError(conv_state_refusal(
-                "a decode step that is not told which lanes it advances"
-            ))
+            refuse(cfg, "unmasked_decode_step")
         out, kv = att.decode_conv_mix(
             z[:, 0], taps, kv, layer, page_table, positions, active
         )
@@ -1114,53 +1112,6 @@ seed_count_rows = partial(jax.jit, donate_argnames=("counts",))(
     _seed_count_rows
 )
 
-
-def _scatter_block_pages(
-    kv_pages: jax.Array,  # [L, 2, num_pages, page, Hkv, D] | QuantKV
-    ids: jax.Array,  # [pages_per_block] page ids
-    blob: jax.Array,  # [L, 2, pages_per_block, page, Hkv, D] | QuantKV
-) -> jax.Array:
-    """Write an offloaded block's contents back into fresh pages (G2/G3 ->
-    G1 onboarding).  Donated so the cache updates in place.  Quantized
-    pools restore (data, scales) byte-for-byte."""
-    from .kv_cache import QuantKV
-
-    if isinstance(kv_pages, QuantKV):
-        return QuantKV(
-            q=kv_pages.q.at[:, :, ids].set(blob.q.astype(jnp.int8)),
-            s=kv_pages.s.at[:, :, ids].set(blob.s.astype(kv_pages.s.dtype)),
-        )
-    return kv_pages.at[:, :, ids].set(blob.astype(kv_pages.dtype))
-
-
-scatter_block_pages = partial(jax.jit, donate_argnames=("kv_pages",))(
-    _scatter_block_pages
-)
-
-
-def _slice_block_pages(kv_pages: jax.Array, ids: jax.Array) -> jax.Array:
-    """Read a block's pages (pre-eviction snapshot for G1 -> G2 demotion).
-    Dispatched before the free-list reuses the pages, so device program
-    order guarantees it reads the pre-reuse contents.  A quantized pool's
-    snapshot is the (data, scales) pair."""
-    from .kv_cache import QuantKV
-
-    if isinstance(kv_pages, QuantKV):
-        return QuantKV(q=kv_pages.q[:, :, ids], s=kv_pages.s[:, :, ids])
-    return kv_pages[:, :, ids]
-
-
-slice_block_pages = jax.jit(_slice_block_pages)
-
-
-# Layer-range variants of slice/scatter_block_pages -- the chunked KV
-# export/onboard primitives.  They live with the Pallas page kernels
-# (ops/paged_attention.py) but are re-exported here so engine code imports
-# every jitted page operation from one module.
-from ..ops.paged_attention import (  # noqa: E402,F401
-    gather_layer_pages,
-    scatter_layer_pages,
-)
 
 # Shape bucketing lives in engine/bucketing.py (the ONE home of every
 # pow2/pad rule); re-exported here for the existing import sites.

@@ -31,6 +31,7 @@ from __future__ import annotations
 import asyncio
 import collections
 import contextlib
+import itertools
 import json
 import logging
 import os
@@ -302,22 +303,9 @@ def _queue_deadline_expired(msg: Dict[str, Any]) -> bool:
         return False
 
 
-def _blob_chunks(blob: np.ndarray) -> Iterator[bytes]:
-    """Yield the blob's bytes in KV_CHUNK_BYTES slices.
-
-    One ``tobytes`` copy total -- it emits C-order bytes even from a
-    non-contiguous view (the batch-export results are slices into the group
-    transfer), and bfloat16 arrays don't expose a buffer protocol that
-    ``memoryview`` could cast copy-free anyway.  The per-chunk slices are
-    zero-copy memoryviews over it.
-    """
-    yield from _byte_chunks(blob.tobytes())
-
-
 def _byte_chunks(raw: bytes) -> Iterator[bytes]:
-    """KV_CHUNK_BYTES slices over pre-packed bytes (the one chunking
-    loop; :func:`_blob_chunks` and the quantized-blob wire form --
-    data followed by row scales -- both route through it)."""
+    """KV_CHUNK_BYTES slices over bytes in wire form (the one chunking
+    loop): zero-copy memoryviews of ``raw``."""
     view = memoryview(raw)
     for off in range(0, len(view), KV_CHUNK_BYTES):
         yield view[off : off + KV_CHUNK_BYTES]
@@ -594,20 +582,13 @@ class DisaggDecodeEngine:
         elif meta.get("chunked"):
             ok = await self._kv_deliver_chunked(rid, meta, chunks)
         else:
+            from ..engine.kv_cache import blob_from_bytes, blob_nbytes
+
             dtype = jnp.dtype(meta["dtype"])  # resolves bfloat16 via ml_dtypes
             shape = tuple(int(s) for s in meta["shape"])
-            quant = dtype == jnp.dtype(jnp.int8)
-            if quant:
-                # quantized wire form: data bytes then f32 row scales
-                # (kv_cache.pack_quant_blob_bytes); extents derive from
-                # (shape, dtype) on both ends
-                from ..engine.kv_cache import quant_blob_nbytes
-
-                flat = np.empty((quant_blob_nbytes(shape),), np.uint8)
-                buf = None
-            else:
-                buf = np.empty(shape, dtype)
-                flat = buf.view(np.uint8).reshape(-1)
+            # the bytes land in wire form (kv_cache.blob_to_bytes); extents
+            # derive from (shape, dtype) on both ends
+            flat = np.empty((blob_nbytes(shape, dtype),), np.uint8)
             size = flat.size
             off = 0
             truncated = False
@@ -631,15 +612,12 @@ class DisaggDecodeEngine:
                     f"KV delivery truncated: got {off} of {size} bytes",
                 )
             else:
-                if quant:
-                    from ..engine.kv_cache import unpack_quant_blob_bytes
-
-                    # zero-copy: the delivered pair aliases the landing
-                    # buffer (multi-GB blobs must not double on receive)
-                    buf = unpack_quant_blob_bytes(flat, shape)
                 lp_row = meta.get("lp_row")
                 ok = self.engine.deliver_external(
-                    rid, buf, int(meta["first_token"]),
+                    # zero-copy: the delivered blob aliases the landing
+                    # buffer (multi-GB blobs must not double on receive)
+                    rid, blob_from_bytes(flat, shape, dtype),
+                    int(meta["first_token"]),
                     np.asarray(lp_row, np.int32) if lp_row else None,
                 )
 
@@ -657,6 +635,7 @@ class DisaggDecodeEngine:
         waits for every layer plus the final commit."""
         import jax.numpy as jnp
 
+        from ..engine.kv_cache import blob_num_layers
         from ..offload import KVStagingBuffer
 
         cm = meta["chunked"]
@@ -667,6 +646,7 @@ class DisaggDecodeEngine:
         try:
             dtype = jnp.dtype(meta["dtype"])  # resolves bfloat16
             shape = tuple(int(s) for s in meta["shape"])
+            num_layers = blob_num_layers(shape)
             spans = [(int(a), int(b)) for a, b in cm["layers"]]
             # spans must tile [0, L) disjointly in order: duplicate or
             # gapped spans could sum to L layers while leaving some layer
@@ -676,12 +656,12 @@ class DisaggDecodeEngine:
             for lo, hi in spans:
                 if lo != expect_lo or hi <= lo:
                     raise ValueError(
-                        f"layer spans {spans} do not tile [0, {shape[0]})"
+                        f"layer spans {spans} do not tile [0, {num_layers})"
                     )
                 expect_lo = hi
-            if expect_lo != shape[0]:
+            if expect_lo != num_layers:
                 raise ValueError(
-                    f"layer spans {spans} do not tile [0, {shape[0]})"
+                    f"layer spans {spans} do not tile [0, {num_layers})"
                 )
             staging = KVStagingBuffer.for_layer_spans(shape, dtype, spans)
             if int(cm.get("total_bytes", staging.flat.size)) != staging.flat.size:
@@ -1018,25 +998,24 @@ class PrefillWorker:
         first = int(np.asarray(row).reshape(-1)[0])
         lp_row = [int(x) for x in np.asarray(row).reshape(-1)]
         local = self._local_engine(msg)
-        # lazy: QuantKV lives with the (jax-importing) engine package, and
-        # chip-free stacks import this module without jax
-        from ..engine.kv_cache import QuantKV, blob_to_host
+        # lazy: the blob's format lives with the (jax-importing) engine
+        # package, and chip-free stacks import this module without jax
+        from ..engine.kv_cache import (
+            blob_byte_views,
+            blob_to_host,
+            blob_tokens,
+        )
 
-        quant = isinstance(blob, QuantKV)
         t0 = time.perf_counter()
         if local is not None and not isinstance(blob, np.ndarray):
-            # same-process handoff: the device-resident blob (or quantized
-            # pair) goes straight into the decode engine's delivery queue;
+            # same-process handoff: the device-resident blob goes straight
+            # into the decode engine's delivery queue;
             # the scatter is a device-to-device copy at its next tick
             self.local_deliveries += 1
             local.deliver_external(
                 rid, blob, first, np.asarray(lp_row, np.int32)
             )
-            nbytes = (
-                blob.nbytes
-                if quant
-                else int(np.prod(blob.shape)) * blob.dtype.itemsize
-            )
+            nbytes = blob.nbytes
             path = "device"
         else:
             meta = {
@@ -1049,29 +1028,15 @@ class PrefillWorker:
             shards = self._kv_shard_geometry()
             if shards is not None:
                 meta["kv_shards"] = shards
-            if quant:
-                # int8 export: the wire carries data bytes then the f32
-                # row scales (the pack_quant_blob_bytes layout, streamed
-                # as two buffer-protocol views so no (q+s)-sized concat
-                # buffer ever materializes); the receiver re-derives both
-                # extents from (shape, dtype)
-                import itertools
-
-                blob = blob_to_host(blob)
-                q_arr = np.ascontiguousarray(blob.q)
-                s_arr = np.ascontiguousarray(blob.s, np.float32)
-                chunks_iter = itertools.chain(
-                    _byte_chunks(q_arr.reshape(-1).view(np.uint8)),
-                    _byte_chunks(s_arr.reshape(-1).view(np.uint8)),
-                )
-                nbytes = q_arr.nbytes + s_arr.nbytes
-            else:
-                if not isinstance(blob, np.ndarray):
-                    # mixed batch: a device export targeting a remote
-                    # decode worker still ships over the wire
-                    blob = np.asarray(blob)
-                chunks_iter = _blob_chunks(blob)
-                nbytes = blob.nbytes
+            # the blob's wire form (kv_cache.blob_to_bytes), streamed leaf
+            # by leaf as buffer-protocol views so no buffer of the whole
+            # ever materializes; a device export targeting a remote decode
+            # worker (mixed batch) comes to host here
+            views = blob_byte_views(blob_to_host(blob))
+            chunks_iter = itertools.chain.from_iterable(
+                _byte_chunks(v) for v in views
+            )
+            nbytes = sum(v.nbytes for v in views)
             try:
                 if faults.injector.enabled:
                     await faults.injector.maybe_delay("disagg.slow_export", rid)
@@ -1094,7 +1059,7 @@ class PrefillWorker:
         logger.info(
             "prefilled %d tokens for %s -> %s/%d",
             # the true prompt length, not the page-padded blob capacity
-            prompt_tokens or blob.shape[2] * blob.shape[3], rid,
+            prompt_tokens or blob_tokens(blob.shape), rid,
             msg["decode_component"], int(msg["decode_instance"]),
         )
 
@@ -1125,18 +1090,15 @@ class PrefillWorker:
             meta["kv_shards"] = stream.shards
 
         async def frames() -> AsyncIterator[bytes]:
-            from ..engine.kv_cache import QuantKV, pack_quant_blob_bytes
+            from ..engine.kv_cache import blob_to_bytes
 
             truncated = False
             async for idx, _lo, _hi, part in stream.chunks():
                 if truncated:
                     continue  # drain the export without sending (fault)
-                if isinstance(part, QuantKV):
-                    # quantized slab: int8 data then f32 row scales --
-                    # matches the receiver's quant staging-buffer bounds
-                    raw = pack_quant_blob_bytes(part)
-                else:
-                    raw = part.tobytes()  # C-order bytes of the layer slab
+                # the layer slab in wire form: what the receiver's staging
+                # buffer derived its bounds from
+                raw = blob_to_bytes(part)
                 for frame in iter_chunk_frames(
                     idx, bounds[idx][0], raw, KV_CHUNK_BYTES
                 ):

@@ -32,7 +32,6 @@ import json
 import logging
 from typing import Any, AsyncIterator, Dict, List, Optional
 
-import numpy as np
 
 from ..offload import BlockMeta, KVStagingBuffer
 from ..runtime.component import Namespace, PushRouter
@@ -66,18 +65,13 @@ def kv_export_handler(engine):
             pass  # no request body expected
 
         async def gen() -> AsyncIterator[bytes]:
-            from ..engine.kv_cache import QuantKV, pack_quant_blob_bytes
+            from ..engine.kv_cache import blob_to_bytes
 
             hashes = [int(h) for h in (hdr.get("meta") or {}).get("hashes", [])]
             found = await engine.export_blocks(hashes)
             for seq_hash, blob, meta in found:
-                if isinstance(blob, QuantKV):
-                    # quantized donor block: int8 data then f32 row scales
-                    # -- the importer re-derives both extents from
-                    # (shape, dtype), and the scales travel with the bytes
-                    raw = pack_quant_blob_bytes(blob)
-                else:
-                    raw = np.asarray(blob).tobytes()  # C-order bytes
+                # the importer re-derives the extents from (shape, dtype)
+                raw = blob_to_bytes(blob)
                 yield json.dumps(
                     {
                         "seq_hash": int(seq_hash),
@@ -209,9 +203,7 @@ class PrefixOnboardEngine:
         def _store() -> None:
             nonlocal fetched, pending_meta, staging, asm
             # the host-ring copy (and any disk demotion it cascades into)
-            # runs on the offload engine's thread, never this event loop;
-            # payload() unpacks quantized wire bytes into the (data,
-            # scales) pair the tiers store
+            # runs on the offload engine's thread, never this event loop
             offload.submit_put(
                 int(pending_meta["seq_hash"]),
                 staging.payload(),
@@ -236,19 +228,13 @@ class PrefixOnboardEngine:
                 if asm.complete:  # zero-byte blob: no chunk frames follow
                     _store()
             elif asm is None:
-                if jnp.dtype(pending_meta["dtype"]) == jnp.dtype(jnp.int8):
-                    from ..engine.kv_cache import unpack_quant_blob_bytes
+                from ..engine.kv_cache import blob_from_bytes
 
-                    blob = unpack_quant_blob_bytes(
-                        frame, pending_meta["shape"]
-                    )
-                else:
-                    blob = np.frombuffer(
-                        frame, jnp.dtype(pending_meta["dtype"])
-                    ).reshape(pending_meta["shape"])
                 offload.submit_put(
                     int(pending_meta["seq_hash"]),
-                    blob,
+                    blob_from_bytes(
+                        frame, pending_meta["shape"], pending_meta["dtype"]
+                    ),
                     BlockMeta.from_dict(pending_meta["meta"]),
                 )
                 fetched += 1

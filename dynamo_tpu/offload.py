@@ -13,8 +13,9 @@ the blocking materialize + every tier put/get runs on the offload
 engine's dedicated thread -- never the event loop, never the engine
 executor that drives device ticks.
 
-A block is stored as ``(blob, meta)``: blob is the raw page content
-``[L, 2, pages_per_block, page, Hkv, D]``, meta carries the router-facing
+A block is stored as ``(blob, meta)``: blob is the raw page content of the
+block's pages (a blob of ``engine/kv_cache.py``, which owns its format:
+this module asks it for named arrays or bytes), meta carries the router-facing
 identity (block_hash, parent_sequence_hash, position) so an onboarded
 block re-registers and re-publishes exactly as it first did.
 
@@ -74,15 +75,12 @@ def to_host(arr: Any) -> np.ndarray:
     Runs only on the offload engine's thread: by the time it is called the
     async DMA (``copy_to_host_async``, started at dispatch) has usually
     landed, so this is a wait, not a transfer -- and if it is a transfer,
-    it blocks a thread nobody's tick latency depends on.  Quantized pool
-    snapshots (kv_cache.QuantKV) materialize data and scales together --
-    the pair is the blob."""
+    it blocks a thread nobody's tick latency depends on.  A blob of several
+    leaves (an int8 pool's) materializes them together."""
     thread_sentry.assert_role("kv-offload", what="offload.to_host")
-    from .engine.kv_cache import QuantKV
+    from .engine.kv_cache import blob_to_host
 
-    if isinstance(arr, QuantKV):
-        return QuantKV(q=np.asarray(arr.q), s=np.asarray(arr.s))
-    return np.asarray(arr)
+    return blob_to_host(arr)
 
 
 @dataclass
@@ -96,8 +94,8 @@ class BlockMeta:
     # reassemble on export), so this is provenance for restore-site
     # validation, not a layout switch.
     shards: Optional[Dict[str, int]] = None
-    # dtype of the pool the blob was sliced from ("int8" = quantized
-    # kv_cache.QuantKV pair -- its per-row scales travel inside the blob).
+    # dtype of the pool the blob was sliced from ("int8" = the quantized
+    # pair -- its per-row scales travel inside the blob).
     # Restore sites use this to route cross-geometry deliveries through
     # the shared conversion rule; None = pre-ISSUE-13 full-width blob.
     kv_dtype: Optional[str] = None
@@ -135,24 +133,18 @@ class KVStagingBuffer:
     the geometry arithmetic -- the preallocated ndarray, its flat byte
     view, and each chunk's [start, end) byte range -- so sender and
     receiver derive identical bounds from the same metadata.  Layer spans
-    map to byte ranges because layer slabs are contiguous in the C-order
-    blob ``[L, 2, pages, page, Hkv, D]``."""
+    map to byte ranges because layers lead the blob's axes, so their slabs
+    are contiguous in its C-order bytes."""
 
-    def __init__(self, shape, dtype, bounds, quant: bool = False) -> None:
-        shape = tuple(int(s) for s in shape)
-        self.quant = quant
-        self.shape = shape
-        if quant:
-            # quantized wire layout (kv_cache.pack_quant_blob_bytes): each
-            # layer slab is its int8 data followed by its f32 row scales,
-            # so the landing zone is a flat byte buffer and layer_slice
-            # unpacks the (data, scales) pair per span
-            from .engine.kv_cache import quant_blob_nbytes
+    def __init__(self, shape, dtype, bounds) -> None:
+        # lazy: kv_cache lives with the (jax-importing) engine package
+        from .engine.kv_cache import blob_nbytes
 
-            self.array = np.empty((quant_blob_nbytes(shape),), np.uint8)
-        else:
-            self.array = np.empty(shape, dtype)
-        self.flat = self.array.view(np.uint8).reshape(-1)
+        self.shape = tuple(int(s) for s in shape)
+        self.dtype = dtype
+        # one flat byte buffer in the blob's wire form
+        # (kv_cache.blob_to_bytes); payload / layer_slice unpack views of it
+        self.flat = np.empty((blob_nbytes(self.shape, dtype),), np.uint8)
         self.bounds = [(int(s), int(e)) for s, e in bounds]
         if self.bounds and self.bounds[-1][1] != self.flat.size:
             raise ValueError(
@@ -162,77 +154,56 @@ class KVStagingBuffer:
 
     @classmethod
     def for_layer_spans(cls, shape, dtype, spans) -> "KVStagingBuffer":
-        """One chunk per layer-group span [lo, hi) over axis 0.  An int8
-        ``dtype`` selects the quantized wire layout (data + row scales per
-        layer slab)."""
-        shape = tuple(int(s) for s in shape)
-        if np.dtype("int8") == np.dtype(str(dtype)):
-            from .engine.kv_cache import quant_blob_nbytes
+        """One chunk per layer-group span [lo, hi) over the layer axis,
+        each span in the wire form of its own blob."""
+        from .engine.kv_cache import blob_nbytes, blob_num_layers
 
-            bpl = quant_blob_nbytes(shape) // max(shape[0], 1)
-            return cls(
-                shape, dtype, [(lo * bpl, hi * bpl) for lo, hi in spans],
-                quant=True,
-            )
-        total = int(np.prod(shape)) * np.dtype(dtype).itemsize
-        bpl = total // max(shape[0], 1)
+        bpl = blob_nbytes(shape, dtype) // max(blob_num_layers(shape), 1)
         return cls(shape, dtype, [(lo * bpl, hi * bpl) for lo, hi in spans])
 
     @classmethod
     def for_byte_chunks(cls, shape, dtype, chunk_bytes: int) -> "KVStagingBuffer":
-        """Fixed-size byte chunks (the block-blob transfer framing).  An
-        int8 ``dtype`` selects the quantized wire layout."""
-        shape = tuple(int(s) for s in shape)
-        quant = np.dtype("int8") == np.dtype(str(dtype))
-        if quant:
-            from .engine.kv_cache import quant_blob_nbytes
+        """Fixed-size byte chunks (the block-blob transfer framing)."""
+        from .engine.kv_cache import blob_nbytes
 
-            total = quant_blob_nbytes(shape)
-        else:
-            total = int(np.prod(shape)) * np.dtype(dtype).itemsize
+        total = blob_nbytes(shape, dtype)
         if total == 0:
-            return cls(shape, dtype, [(0, 0)], quant=quant)
+            return cls(shape, dtype, [(0, 0)])
         bounds = [
             (off, min(off + chunk_bytes, total))
             for off in range(0, total, chunk_bytes)
         ]
-        return cls(shape, dtype, bounds, quant=quant)
+        return cls(shape, dtype, bounds)
 
     def payload(self):
-        """The assembled blob in its engine-facing form: the ndarray for
-        dense pools, the unpacked (data, scales) pair for quantized wire
-        bytes.  Valid for whole-blob staging (``for_byte_chunks``) only --
-        the layer-span layout packs (data | scales) PER SPAN, so those
-        consumers unpack via :meth:`layer_slice` instead."""
-        if self.quant:
-            from .engine.kv_cache import unpack_quant_blob_bytes
+        """The assembled blob in its engine-facing form, ALIASING the
+        staging buffer (zero-copy).  Valid for whole-blob staging
+        (``for_byte_chunks``) only -- the layer-span layout is in wire form
+        PER SPAN, so those consumers unpack via :meth:`layer_slice`."""
+        from .engine.kv_cache import blob_from_bytes
 
-            # zero-copy: the pair aliases the staging buffer's bytes
-            return unpack_quant_blob_bytes(self.flat, self.shape)
-        return self.array
+        return blob_from_bytes(self.flat, self.shape, self.dtype)
 
     @property
     def memoryview(self) -> memoryview:
         return memoryview(self.flat)
 
     def layer_slice(self, lo: int, hi: int) -> np.ndarray:
-        """View of layers [lo, hi) -- stable once their bytes landed.  For
-        the quantized layout this unpacks the span's (data, scales) pair;
-        like the dense path it ALIASES the staging buffer (zero-copy), so
-        it is valid only while the buffer's bytes stay untouched."""
-        if self.quant:
-            from .engine.kv_cache import (
-                quant_blob_nbytes,
-                unpack_quant_blob_bytes,
-            )
+        """View of layers [lo, hi) -- stable once their bytes landed.  It
+        ALIASES the staging buffer (zero-copy), so it is valid only while
+        the buffer's bytes stay untouched."""
+        from .engine.kv_cache import (
+            blob_from_bytes,
+            blob_num_layers,
+            blob_shape,
+        )
 
-            bpl = quant_blob_nbytes(self.shape) // max(self.shape[0], 1)
-            span_shape = (hi - lo,) + self.shape[1:]
-            # zero-copy: the pair aliases the staging buffer's bytes
-            return unpack_quant_blob_bytes(
-                self.flat[lo * bpl : hi * bpl], span_shape
-            )
-        return self.array[lo:hi]
+        bpl = self.flat.size // max(blob_num_layers(self.shape), 1)
+        return blob_from_bytes(
+            self.flat[lo * bpl : hi * bpl],
+            blob_shape(self.shape, num_layers=hi - lo),
+            self.dtype,
+        )
 
 
 class DiskTier:
@@ -278,7 +249,7 @@ class DiskTier:
         LRU eviction -- so the publisher never advertises a tier the
         worker already dropped."""
         thread_sentry.assert_role("kv-offload", what="DiskTier.put")
-        from .engine.kv_cache import QuantKV
+        from .engine.kv_cache import blob_to_arrays
 
         if self.capacity <= 0:
             return [(seq_hash, None, 0)]
@@ -288,11 +259,7 @@ class DiskTier:
             meta_d = {
                 k: v for k, v in meta.to_dict().items() if k != "shards"
             }
-            if isinstance(blob, QuantKV):
-                # quantized pair: scales are part of the block's bytes
-                np.savez(tmp, blob=blob.q, blob_scales=blob.s, **meta_d)
-            else:
-                np.savez(tmp, blob=blob, **meta_d)
+            np.savez(tmp, **blob_to_arrays(blob), **meta_d)
             os.replace(tmp, path)
         except OSError:
             logger.exception("disk tier write failed for %x", seq_hash)
@@ -322,13 +289,11 @@ class DiskTier:
             if seq_hash not in self._lru:
                 self.misses += 1
                 return None
-        from .engine.kv_cache import QuantKV
+        from .engine.kv_cache import blob_from_arrays
 
         try:
             with np.load(self._path(seq_hash)) as z:
-                blob = z["blob"]
-                if "blob_scales" in z.files:
-                    blob = QuantKV(q=blob, s=z["blob_scales"])
+                blob = blob_from_arrays(z)
                 meta = BlockMeta(
                     int(z["block_hash"]),
                     int(z["parent_sequence_hash"]),
@@ -382,10 +347,10 @@ class HostTier:
         )
         self._misc: Dict[int, Tuple[np.ndarray, BlockMeta]] = {}
         self._meta: Dict[int, BlockMeta] = {}
-        self._ring: Optional[np.ndarray] = None
-        # scale ring of a quantized pool's blocks (kv_cache.QuantKV): the
-        # pair occupies one LRU slot -- scales are part of the block
-        self._ring_s: Optional[np.ndarray] = None
+        # one ring an array of the block's stored form
+        # (kv_cache.blob_to_arrays): an int8 pool's block is data and row
+        # scales, and the pair occupies one LRU slot
+        self._ring: Optional[Dict[str, np.ndarray]] = None
         self._ring_failed = False
         self._free_slots: List[int] = []
         # prefetch pins: hash -> refcount.  A pinned block is skipped by
@@ -408,28 +373,16 @@ class HostTier:
 
     @property
     def ring_nbytes(self) -> int:
-        n = self._ring.nbytes if self._ring is not None else 0
-        if self._ring_s is not None:
-            n += self._ring_s.nbytes
-        return n
+        return sum(r.nbytes for r in (self._ring or {}).values())
 
-    def _ensure_ring_locked(self, blob: Any) -> None:
-        from .engine.kv_cache import QuantKV
-
+    def _ensure_ring_locked(self, arrays: Dict[str, np.ndarray]) -> None:
         if self._ring is not None or self._ring_failed or self.capacity <= 0:
             return
         try:
-            if isinstance(blob, QuantKV):
-                self._ring = np.empty(
-                    (self.capacity,) + tuple(blob.q.shape), blob.q.dtype
-                )
-                self._ring_s = np.empty(
-                    (self.capacity,) + tuple(blob.s.shape), blob.s.dtype
-                )
-            else:
-                self._ring = np.empty(
-                    (self.capacity,) + tuple(blob.shape), blob.dtype
-                )
+            self._ring = {
+                name: np.empty((self.capacity,) + tuple(a.shape), a.dtype)
+                for name, a in arrays.items()
+            }
         except MemoryError:
             # remember the failure: retrying a multi-GB allocation on
             # every eviction would hammer the allocator on the one thread
@@ -438,38 +391,28 @@ class HostTier:
                 "host tier ring allocation failed (%d blocks); falling "
                 "back to per-entry storage", self.capacity,
             )
-            self._ring = None
-            self._ring_s = None
             self._ring_failed = True
             return
         self._free_slots = list(range(self.capacity - 1, -1, -1))
 
-    def _ring_fits_locked(self, blob: Any) -> bool:
-        from .engine.kv_cache import QuantKV
-
-        if self._ring is None:
-            return False
-        if isinstance(blob, QuantKV):
-            return (
-                self._ring_s is not None
-                and tuple(blob.q.shape) == self._ring.shape[1:]
-                and blob.q.dtype == self._ring.dtype
-                and tuple(blob.s.shape) == self._ring_s.shape[1:]
-            )
+    def _ring_fits_locked(self, arrays: Dict[str, np.ndarray]) -> bool:
+        ring = self._ring
         return (
-            self._ring_s is None
-            and tuple(blob.shape) == self._ring.shape[1:]
-            and blob.dtype == self._ring.dtype
+            ring is not None
+            and ring.keys() == arrays.keys()
+            and all(
+                tuple(a.shape) == ring[name].shape[1:]
+                and a.dtype == ring[name].dtype
+                for name, a in arrays.items()
+            )
         )
 
     def _ring_read_locked(self, slot: int):
-        from .engine.kv_cache import QuantKV
+        from .engine.kv_cache import blob_from_arrays
 
-        if self._ring_s is not None:
-            return QuantKV(
-                q=self._ring[slot].copy(), s=self._ring_s[slot].copy()
-            )
-        return self._ring[slot].copy()
+        return blob_from_arrays(
+            {name: r[slot].copy() for name, r in self._ring.items()}
+        )
 
     def put(self, seq_hash: int, blob: np.ndarray, meta: BlockMeta) -> None:
         delta: List[Tuple[int, Optional[str], int]] = []
@@ -480,23 +423,21 @@ class HostTier:
                 delta = [(seq_hash, None, 0)]
             self._emit_holdings(delta)
             return
-        from .engine.kv_cache import QuantKV
+        from .engine.kv_cache import blob_to_arrays
 
+        arrays = blob_to_arrays(blob)
         demote: List[Tuple[int, np.ndarray, BlockMeta]] = []
         with self._lock:
             self._evict_locked(seq_hash)  # overwrite: recycle the old slot
-            self._ensure_ring_locked(blob)
+            self._ensure_ring_locked(arrays)
             slot: Optional[int] = None
-            if self._ring_fits_locked(blob):
+            if self._ring_fits_locked(arrays):
                 if not self._free_slots:
                     self._demote_lru_locked(demote)
                 if self._free_slots:
                     slot = self._free_slots.pop()
-                    if isinstance(blob, QuantKV):
-                        np.copyto(self._ring[slot], blob.q)
-                        np.copyto(self._ring_s[slot], blob.s)
-                    else:
-                        np.copyto(self._ring[slot], blob)
+                    for name, a in arrays.items():
+                        np.copyto(self._ring[name][slot], a)
             if slot is None:
                 # geometry mismatch (or ring unavailable): side table
                 self._misc[seq_hash] = (blob.copy(), meta)
@@ -585,10 +526,7 @@ class HostTier:
     def block_nbytes(self) -> int:
         """Bytes of one resident block blob (0 until the first put)."""
         if self._ring is not None:
-            n = int(self._ring[0].nbytes)
-            if self._ring_s is not None:
-                n += int(self._ring_s[0].nbytes)
-            return n
+            return sum(int(r[0].nbytes) for r in self._ring.values())
         with self._lock:
             for blob, _meta in self._misc.values():
                 return int(blob.nbytes)
@@ -663,29 +601,21 @@ class HostTier:
 def pack_kv_blob_frame(blob: Any, meta: BlockMeta) -> bytes:
     """Self-describing G4 wire frame for one block blob.
 
-    ``u32-LE header length | JSON header | payload``: quantized blobs
-    (kv_cache.QuantKV) pack through the shared
-    ``pack_quant_blob_bytes`` rule -- int8 pools ship half the bytes --
-    and dense blobs ship C-order raw.  A COPY_HELPERS member: this is the
+    ``u32-LE header length | JSON header | payload``, the payload in the
+    blob's wire form (kv_cache.blob_to_bytes: an int8 pool's pair ships
+    half a dense blob's bytes).  A COPY_HELPERS member: this is the
     remote tier's one sync materialize point and runs only on the
     kv-remote thread."""
-    from .engine.kv_cache import QuantKV, pack_quant_blob_bytes
+    from .engine.kv_cache import blob_kind, blob_to_bytes
 
-    if isinstance(blob, QuantKV):
-        payload = pack_quant_blob_bytes(blob)
-        kind, dtype = "quant", "int8"
-        shape = tuple(int(s) for s in blob.q.shape)
-    else:
-        arr = np.ascontiguousarray(blob)
-        payload = arr.tobytes()
-        kind, dtype = "dense", str(arr.dtype)
-        shape = tuple(int(s) for s in arr.shape)
+    payload = blob_to_bytes(blob)
+    dtype = str(blob.dtype)
     hdr = json.dumps(
         {
             "v": 1,
-            "kind": kind,
+            "kind": blob_kind(dtype),
             "dtype": dtype,
-            "shape": list(shape),
+            "shape": [int(s) for s in blob.shape],
             "meta": meta.to_dict(),
             "payload_nbytes": len(payload),
         }
@@ -701,7 +631,7 @@ def unpack_kv_blob_frame(buf: Any) -> Tuple[Any, BlockMeta]:
 
     The returned blob ALIASES ``buf`` (zero-copy unpack); the host-tier
     put that follows copies into the ring."""
-    from .engine.kv_cache import quant_blob_nbytes, unpack_quant_blob_bytes
+    from .engine.kv_cache import blob_from_bytes, blob_nbytes
 
     view = memoryview(buf)
     if len(view) < 4:
@@ -717,11 +647,10 @@ def unpack_kv_blob_frame(buf: Any) -> Tuple[Any, BlockMeta]:
         raise ValueError("G4 frame header missing blob geometry")
     shape = tuple(int(s) for s in hdr["shape"])
     payload = view[4 + hlen :]
+    # the kind decides; a dense frame names its dtype
+    dtype = "int8" if hdr.get("kind") == "quant" else str(hdr.get("dtype"))
     try:
-        if hdr.get("kind") == "quant":
-            expect = quant_blob_nbytes(shape)
-        else:
-            expect = int(np.prod(shape)) * np.dtype(str(hdr.get("dtype"))).itemsize
+        expect = blob_nbytes(shape, dtype)
     except TypeError as e:
         raise ValueError("G4 frame header names an unknown dtype") from e
     if len(payload) != expect or expect != int(hdr.get("payload_nbytes", -1)):
@@ -730,9 +659,7 @@ def unpack_kv_blob_frame(buf: Any) -> Tuple[Any, BlockMeta]:
             f"expects {expect}"
         )
     meta = BlockMeta.from_dict(hdr.get("meta") or {})
-    if hdr.get("kind") == "quant":
-        return unpack_quant_blob_bytes(payload, shape), meta
-    return np.frombuffer(payload, str(hdr["dtype"])).reshape(shape), meta
+    return blob_from_bytes(payload, shape, dtype), meta
 
 
 class InMemoryBlobStore:

@@ -210,73 +210,3 @@ def paged_decode_attention(
         q, kv_pages, page_table, kv_lens, layer, window,
         group=1, interpret=interpret,
     )
-
-
-# ---------------------------------------------------------------------------
-# Layer-range page-slice helpers (the chunked KV export/onboard primitives)
-#
-# The disagg export path pipelines the prefill cache device->host->wire in
-# per-layer-group chunks; the decode side scatters each group into its
-# reserved pages as it arrives.  Both sides index the stacked KV buffer on
-# (layer, page) simultaneously, so the gather/scatter take the layer ids as
-# an ARRAY (one executable per (group size, page count), not one per layer
-# range) and use three adjacent advanced indices to keep the result in
-# [Lg, 2, P, page, Hkv, D] layout -- the wire layout of one chunk.
-# ---------------------------------------------------------------------------
-
-
-def _gather_layer_pages(
-    kv_pages: jax.Array,  # [L, 2, num_pages, page, Hkv, D] | QuantKV
-    layer_ids: jax.Array,  # [Lg] layer indices of the chunk
-    page_ids: jax.Array,  # [P] page ids to export
-) -> jax.Array:
-    """Slice one layer-group chunk out of the KV pool: a device-resident
-    copy, so the scratch pages can be freed as soon as the gather is
-    dispatched (device program order guarantees it reads pre-reuse
-    contents, same argument as engine.step.slice_block_pages).  A
-    quantized pool's chunk is the (data, scales) pair -- the scales are
-    part of the bytes and travel with them on every egress path."""
-    from ..engine.kv_cache import QuantKV
-
-    li = layer_ids[:, None, None]
-    ki = jnp.arange(2)[None, :, None]
-    pi = page_ids[None, None, :]
-    if isinstance(kv_pages, QuantKV):
-        return QuantKV(
-            q=kv_pages.q[li, ki, pi], s=kv_pages.s[li, ki, pi]
-        )
-    return kv_pages[li, ki, pi]
-
-
-gather_layer_pages = jax.jit(_gather_layer_pages)
-
-
-def _scatter_layer_pages(
-    kv_pages: jax.Array,  # [L, 2, num_pages, page, Hkv, D] | QuantKV
-    layer_ids: jax.Array,  # [Lg] layer indices of the chunk
-    page_ids: jax.Array,  # [P] destination page ids (pad entries -> page 0)
-    blob: jax.Array,  # [Lg, 2, P, page, Hkv, D] chunk contents | QuantKV
-) -> jax.Array:
-    """Write one layer-group chunk into its reserved pages (the incremental
-    decode-side onboard; donated so the pool updates in place).  Pad page
-    slots target trash page 0, matching engine.step.scatter_block_pages.
-    A quantized pool restores the (data, scales) pair byte-for-byte --
-    the same ints and the same scales the export sliced out."""
-    from ..engine.kv_cache import QuantKV
-
-    li = layer_ids[:, None, None]
-    ki = jnp.arange(2)[None, :, None]
-    pi = page_ids[None, None, :]
-    if isinstance(kv_pages, QuantKV):
-        return QuantKV(
-            q=kv_pages.q.at[li, ki, pi].set(blob.q.astype(jnp.int8)),
-            s=kv_pages.s.at[li, ki, pi].set(
-                blob.s.astype(kv_pages.s.dtype)
-            ),
-        )
-    return kv_pages.at[li, ki, pi].set(blob.astype(kv_pages.dtype))
-
-
-scatter_layer_pages = functools.partial(
-    jax.jit, donate_argnames=("kv_pages",)
-)(_scatter_layer_pages)

@@ -132,18 +132,10 @@ def shard_params(params: Params, cfg: ModelConfig, mesh: Mesh) -> Params:
 
 
 def shard_kv(kv: jax.Array, cfg: ModelConfig, mesh: Mesh) -> jax.Array:
-    from ..engine.kv_cache import QuantKV
+    from ..engine.kv_cache import kv_data, place_pool
 
-    if isinstance(kv, QuantKV):
-        # int8 pool: data shards like the dense pool (kv heads over tp);
-        # the per-row scales carry no head axis and replicate
-        spec = _compatible_spec(kv_pspec(cfg), kv.q.shape, mesh)
-        return QuantKV(
-            q=jax.device_put(kv.q, NamedSharding(mesh, spec)),
-            s=jax.device_put(kv.s, NamedSharding(mesh, P())),
-        )
-    spec = _compatible_spec(kv_pspec(cfg), kv.shape, mesh)
-    return jax.device_put(kv, NamedSharding(mesh, spec))
+    spec = _compatible_spec(kv_pspec(cfg), kv_data(kv).shape, mesh)
+    return place_pool(kv, NamedSharding(mesh, spec))
 
 
 def _compatible_spec(spec: P, shape, mesh: Mesh) -> P:
@@ -294,7 +286,7 @@ def make_sharded_steps(
     from ..engine import step as _step
 
     param_sh = jax.tree_util.tree_map(lambda x: x.sharding, params)
-    # the KV pool may be a QuantKV pytree (int8 data + replicated row
+    # the KV pool may be a pytree (an int8 pool: data + replicated row
     # scales): harvest per-leaf, so the pinned in/out shardings follow
     # whatever layout the pool was actually placed with
     kv_sh = jax.tree_util.tree_map(lambda x: x.sharding, kv_pages)
@@ -414,28 +406,28 @@ def make_sharded_steps(
         in_shardings=(mat, None, None, None),
         out_shardings=mat,
     )
-    from ..ops import paged_attention as _pa
+    from ..engine import kv_cache as _kv
 
     # (kv, ids, blob): host-built blobs/ids stay unconstrained; the pool
     # result is pinned so delivery/restore can't drift its placement
     scatter_block_pages = jax.jit(
-        _step._scatter_block_pages,
+        _kv._scatter_block_pages,
         donate_argnames=("kv_pages",),
         in_shardings=(kv_sh, None, None),
         out_shardings=kv_sh,
     )
     slice_block_pages = jax.jit(
-        _step._slice_block_pages,
+        _kv._slice_block_pages,
         in_shardings=(kv_sh, None),
         out_shardings=None,  # snapshot: head-sliced like the pool
     )
     gather_layer_pages = jax.jit(
-        _pa._gather_layer_pages,
+        _kv._gather_layer_pages,
         in_shardings=(kv_sh, None, None),
         out_shardings=None,
     )
     scatter_layer_pages = jax.jit(
-        _pa._scatter_layer_pages,
+        _kv._scatter_layer_pages,
         donate_argnames=("kv_pages",),
         in_shardings=(kv_sh, None, None, None),
         out_shardings=kv_sh,

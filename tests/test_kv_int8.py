@@ -27,14 +27,14 @@ from dynamo_tpu.engine.kv_cache import (
     QuantKV,
     coerce_kv_blob,
     dequantize_kv_blob,
-    kv_blob_concat,
-    pack_quant_blob_bytes,
+    blob_from_bytes,
+    blob_nbytes,
+    blob_to_bytes,
+    concat_blob_pages,
     pad_page_axis,
     parse_kv_dtype,
-    quant_blob_nbytes,
     quantize_kv_blob,
     quantize_kv_rows,
-    unpack_quant_blob_bytes,
 )
 from dynamo_tpu.offload import BlockMeta, DiskTier, HostTier
 from dynamo_tpu.protocols.common import (
@@ -118,9 +118,9 @@ def test_quantize_error_bound_and_roundtrip_stability():
 def test_pack_unpack_bytes_bit_exact():
     rng = np.random.default_rng(2)
     q = quantize_kv_blob(_rand_blob(rng))
-    buf = pack_quant_blob_bytes(q)
-    assert len(buf) == quant_blob_nbytes(q.shape)
-    back = unpack_quant_blob_bytes(buf, q.shape)
+    buf = blob_to_bytes(q)
+    assert len(buf) == blob_nbytes(q.shape, "int8")
+    back = blob_from_bytes(buf, q.shape, "int8")
     np.testing.assert_array_equal(back.q, q.q)
     np.testing.assert_array_equal(back.s, q.s)
 
@@ -130,7 +130,7 @@ def test_blob_concat_pad_getitem():
     a, b = quantize_kv_blob(_rand_blob(rng, n=2)), quantize_kv_blob(
         _rand_blob(rng, n=3)
     )
-    cat = kv_blob_concat([a, b], axis=2)
+    cat = concat_blob_pages([a, b])
     assert cat.shape[2] == 5 and cat.s.shape[2] == 5
     padded = pad_page_axis(cat, 8)
     assert padded.shape[2] == 8 and padded.s.shape[2] == 8
@@ -337,7 +337,7 @@ def test_slice_scatter_pool_roundtrip_bit_exact():
     """Device egress primitives: slice pages out of a quantized pool,
     round-trip through host, scatter back -- identical pool bytes (the
     swap-snapshot/offload-eviction path in miniature)."""
-    from dynamo_tpu.engine.step import scatter_block_pages, slice_block_pages
+    from dynamo_tpu.engine.kv_cache import scatter_block_pages, slice_block_pages
     from dynamo_tpu.offload import to_host
 
     rng = np.random.default_rng(10)
@@ -360,7 +360,7 @@ def test_slice_scatter_pool_roundtrip_bit_exact():
 
 
 def test_gather_scatter_layer_pages_roundtrip_bit_exact():
-    from dynamo_tpu.engine.step import gather_layer_pages, scatter_layer_pages
+    from dynamo_tpu.engine.kv_cache import gather_layer_pages, scatter_layer_pages
 
     rng = np.random.default_rng(11)
     cfg = ModelConfig.tiny()
@@ -438,7 +438,7 @@ def test_external_delivery_int8_bit_exact_and_identity(run):
     and decode continues token-identically to a local prefill."""
 
     async def body():
-        from dynamo_tpu.engine.step import slice_block_pages
+        from dynamo_tpu.engine.kv_cache import slice_block_pages
         from dynamo_tpu.engine.sampling import unpack_sampled_logprobs
 
         prompt = list(range(1, 13))
@@ -546,7 +546,7 @@ def test_export_stream_chunks_and_nbytes(run):
             st = streams[0]
             assert not isinstance(st, Exception), st
             assert st.quantized
-            assert st.nbytes == quant_blob_nbytes(st.shape)
+            assert st.nbytes == blob_nbytes(st.shape, "int8")
             blob = await st.assemble()
             assert isinstance(blob, QuantKV)
             mono, _row = await engine.prefill_export(req(prompt, max_tokens=4))
@@ -574,13 +574,13 @@ def test_wire_staging_roundtrip_bit_exact():
     blob = quantize_kv_blob(_rand_blob(rng, L=4))
     spans = layer_chunk_spans(4, 2)
     staging = KVStagingBuffer.for_layer_spans(blob.shape, "int8", spans)
-    assert staging.quant
-    bpl = quant_blob_nbytes(blob.shape) // 4
+    assert isinstance(staging.payload(), QuantKV)
+    bpl = blob_nbytes(blob.shape, "int8") // 4
     assert staging.bounds == [(lo * bpl, hi * bpl) for lo, hi in spans]
     asm = ChunkAssembler(staging.memoryview, staging.bounds)
     done = []
     for idx, (lo, hi) in enumerate(spans):
-        raw = pack_quant_blob_bytes(blob[lo:hi])
+        raw = blob_to_bytes(blob[lo:hi])
         for frame in iter_chunk_frames(idx, staging.bounds[idx][0], raw, 64):
             done.extend(asm.add(frame))
     assert sorted(done) == list(range(len(spans)))
@@ -591,7 +591,7 @@ def test_wire_staging_roundtrip_bit_exact():
         np.testing.assert_array_equal(part.s, blob.s[lo:hi])
     # whole-blob framing (the prefix-onboard donor path): payload()
     # unpacks the assembled pair bit-for-bit
-    whole_raw = pack_quant_blob_bytes(blob)
+    whole_raw = blob_to_bytes(blob)
     st2 = KVStagingBuffer.for_byte_chunks(blob.shape, "int8", 96)
     asm2 = ChunkAssembler(st2.memoryview, st2.bounds)
     for idx, (lo_b, _hi_b) in enumerate(st2.bounds):
@@ -628,3 +628,140 @@ def test_async_dispatch_composes_with_int8(run):
         )
 
     run(body())
+
+
+# ---------------------------------------------------------------------------
+# one module knows the format (ISSUE 52)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("module", [
+    "offload", "llm.disagg", "llm.prefix_onboard", "parallel.sharding",
+    "engine.engine", "engine.step",
+])
+def test_the_kv_movers_do_not_name_the_pair(module):
+    """What moves a blob asks ``engine/kv_cache.py`` for arrays or bytes and
+    never looks inside: the module's AST holds no ``QuantKV``."""
+    import ast
+    import importlib
+
+    path = importlib.import_module(f"dynamo_tpu.{module}").__file__
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    named = [
+        node.lineno for node in ast.walk(tree)
+        if "QuantKV" in (
+            getattr(node, "id", None), getattr(node, "attr", None),
+            getattr(node, "name", None),
+        )
+    ]
+    assert not named, f"{path} names QuantKV at lines {named}"
+
+
+# the sentences as the tree raised them before they were one table: a kind's
+# own reason behind ``{what} is not supported over``, or the capability's own
+_OVER = {
+    "two_kind": "{what} is not supported over a two-kind cache (window and "
+    "full layers, layer_types): its pages live in two pools with two page "
+    "tables a lane, and the transfer formats and meshes carry one",
+    "conv": "{what} is not supported over a trunk with convolution layers "
+    "(layer_types 'conv'): a sequence carries two rows a layer beside its "
+    "pages, which only the packed step and the fused decode steps carry, "
+    "snapshot and restore",
+    "latent": "{what} is not supported over a latent cache (MLA): the "
+    "transfer formats carry K/V pairs per head and per layer",
+}
+_MOVES = ("two_kind", "conv")
+_SHIPS = ("two_kind", "conv", "latent")
+_REFUSED = {  # capability -> (what, the kinds that refuse it)
+    "mesh": ("a serving mesh (tp, dp, sp or pp)", _MOVES),
+    "sp_pp": ("", ()),
+    "offload": ("host/disk KV offload and swap preemption", _MOVES),
+    "remote_tier": ("the remote KV tier (G4)", _MOVES),
+    "int8_pool": ("an int8 pool", _MOVES),
+    "sharded_pool": ("a sharded pool", _MOVES),
+    "window_layers": ("a trunk of window and full layers", ("conv",)),
+    "disagg_serving": ("disaggregated serving (a remote prefill's KV)", _SHIPS),
+    "kv_delivery": ("a remote prefill's KV delivery", _SHIPS),
+    "prefill_export": ("a disaggregated prefill export", _SHIPS),
+    "block_export": ("a KV block export", _SHIPS),
+    "unmixed": ("serving without mixed batching", _MOVES),
+    "classic_dispatch": (
+        "a request with sampling penalties, a soft prompt or speculation "
+        "(the classic prefill and verify dispatches)", _MOVES),
+    "scoring": (
+        "a request for the prompt's log-probabilities (the scoring step)",
+        ("conv",)),
+    "embedding": ("pooled embeddings (the embedding step)", ("conv",)),
+    "unmasked_decode_step": (
+        "a decode step that is not told which lanes it advances", ("conv",)),
+    "classic_step": (
+        "a step outside the packed step and the decode steps (classic "
+        "prefill, verify, scoring, embedding)", ("conv",)),
+}
+_LATENT_OWN = {
+    "sp_pp": "sp/pp meshes are not supported over a latent cache (MLA): "
+    "their prefill routes and stage pools assume K/V pairs per head and per "
+    "layer",
+    "offload": "host/disk KV offload is not supported over a latent cache "
+    "(MLA): the tiers move [L, 2, pages, page, Hkv, D] blocks, and a latent "
+    "pool holds two layers' rows a slab",
+    "remote_tier": "the remote KV tier (G4) is not supported over a latent "
+    "cache (MLA): it ships the offload tiers' K/V blocks",
+    "int8_pool": "kv_dtype int8 is not supported over a latent cache (MLA): "
+    "one scale a row would span c_kv and the rotated key, whose ranges "
+    "differ",
+}
+_KIND_ENGINES = {}
+
+
+def _engine_of(kind):
+    """One served toy engine a kind, from that kind's own test module."""
+    if kind not in _KIND_ENGINES:
+        if kind == "pair":
+            engine = make_engine()
+        else:
+            import importlib
+
+            t = importlib.import_module({
+                "two_kind": "tests.test_mellum", "conv": "tests.test_lfm2",
+                "latent": "tests.test_mla",
+            }[kind])
+            cfg = t.tiny()
+            engine_config = getattr(
+                t, "engine_config",
+                lambda: EngineConfig(max_batch_size=2, max_seq_len=64,
+                                     page_size=16, num_pages=16))
+            engine = JaxEngine(t.model_config(cfg),
+                               t.W.build_params(cfg, t.SEED), engine_config())
+        _KIND_ENGINES[kind] = engine
+    return _KIND_ENGINES[kind]
+
+
+def test_the_table_holds_the_capabilities_the_engine_asks_for():
+    from dynamo_tpu.engine.kv_cache import KV_REFUSALS
+
+    assert set(KV_REFUSALS) == set(_REFUSED)
+
+
+@pytest.mark.parametrize("kind", ["pair", "two_kind", "conv", "latent"])
+@pytest.mark.parametrize("capability", sorted(_REFUSED))
+def test_a_kind_refuses_a_capability_with_the_table_s_sentence(
+    capability, kind
+):
+    from dynamo_tpu.engine.kv_cache import kv_refusal
+
+    what, kinds = _REFUSED[capability]
+    want = None
+    if kind == "latent" and capability in _LATENT_OWN:
+        want = _LATENT_OWN[capability]
+    elif kind in kinds:
+        want = _OVER[kind].format(what=what)
+    engine = _engine_of(kind)
+    assert kv_refusal(engine.model_cfg, capability) == want
+    if want is None:
+        engine._refuse(capability)
+    else:
+        with pytest.raises(ValueError) as raised:
+            engine._refuse(capability)
+        assert str(raised.value) == want

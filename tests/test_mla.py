@@ -345,7 +345,7 @@ def test_tp_and_disaggregation_refuse_a_latent_cache():
     for call in (
         lambda: engine.deliver_external("r", np.zeros(1), 0),
         lambda: engine.deliver_external_chunk("r", 0, 1, np.zeros(1)),
-        lambda: engine._refuse_latent("x"),
+        lambda: engine._refuse("kv_delivery"),
     ):
         with pytest.raises(ValueError, match="latent cache"):
             call()

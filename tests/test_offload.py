@@ -520,11 +520,11 @@ def test_host_ring_is_single_allocation():
     for i in range(16):
         t.put(i, _blob(i), BlockMeta(position=i))
     assert len(t) == 4
-    ring = t._ring
-    assert ring is not None and ring.shape[0] == 4
+    ring = t._ring["blob"]
+    assert ring.shape[0] == 4
     for i in range(16, 32):
         t.put(i, _blob(i), BlockMeta(position=i))
-    assert t._ring is ring  # never reallocated
+    assert t._ring["blob"] is ring  # never reallocated
     blob, meta = t.get(31)
     assert np.array_equal(blob, _blob(31)) and meta.position == 31
     # returned blobs are decoupled from slot recycling
@@ -553,3 +553,117 @@ def test_kv_offload_engine_lookup_is_ram_only(tmp_path):
         assert 0.0 < eng.tier_hit_rate <= 1.0
     finally:
         eng.close()
+
+
+# ---------------------------------------------------------------------------
+# the stored and the wire form, byte for byte (ISSUE 52): the bytes below
+# are spelled here, in the format the tree wrote before kv_cache owned it
+# ---------------------------------------------------------------------------
+
+_GOLDEN_SHAPE = (1, 2, 1, 2, 1, 2)  # [layers, 2, pages, page, Hkv, D]
+_GOLDEN = {
+    "dense": dict(
+        dtype="float32",
+        blob=np.arange(8, dtype=np.float32).reshape(_GOLDEN_SHAPE),
+        # eight little-endian float32: 0.0 .. 7.0
+        payload=b"".join(
+            bytes.fromhex(h) for h in (
+                "00000000", "0000803f", "00000040", "00004040",
+                "00008040", "0000a040", "0000c040", "0000e040",
+            )
+        ),
+    ),
+    "quant": dict(
+        dtype="int8",
+        blob=np.array([1, -2, 3, -4, 5, -6, 7, -127], np.int8).reshape(
+            _GOLDEN_SHAPE),
+        blob_scales=np.array([0.5, 1.0, 2.0, 0.25], np.float32).reshape(
+            _GOLDEN_SHAPE[:4]),
+        # the int8 data, then the float32 row scales
+        payload=bytes.fromhex("01fe03fc05fa0781")
+        + bytes.fromhex("0000003f" "0000803f" "00000040" "0000803e"),
+    ),
+}
+
+
+def _golden_blob(form):
+    from dynamo_tpu.engine.kv_cache import QuantKV
+
+    g = _GOLDEN[form]
+    if form == "quant":
+        return QuantKV(q=g["blob"], s=g["blob_scales"])
+    return g["blob"]
+
+
+def _assert_golden(form, got):
+    g = _GOLDEN[form]
+    if form == "quant":
+        assert type(got).__name__ == "QuantKV"
+        data, scales = got.q, got.s
+        assert scales.dtype == np.float32
+        np.testing.assert_array_equal(scales, g["blob_scales"])
+    else:
+        assert isinstance(got, np.ndarray)
+        data = got
+    assert data.dtype == np.dtype(g["dtype"]) and data.shape == _GOLDEN_SHAPE
+    np.testing.assert_array_equal(data, g["blob"])
+
+
+@pytest.mark.parametrize("form", ["dense", "quant"])
+def test_a_disk_block_keeps_its_stored_form(form, tmp_path):
+    g = _GOLDEN[form]
+    tier = DiskTier(str(tmp_path), capacity_blocks=4)
+    # a block as the parent wrote it: one .npz, the data under ``blob``, an
+    # int8 pool's row scales under ``blob_scales``, the meta beside them
+    arrays = {k: g[k] for k in ("blob", "blob_scales") if k in g}
+    np.savez(tier._path(7), **arrays, block_hash=3, parent_sequence_hash=2,
+             position=1, kv_dtype=g["dtype"])
+    tier._lru[7] = None
+    got, meta = tier.get(7)
+    _assert_golden(form, got)
+    assert (meta.block_hash, meta.parent_sequence_hash, meta.position,
+            meta.kv_dtype) == (3, 2, 1, g["dtype"])
+    # and what the change writes is that file
+    tier.put(8, _golden_blob(form), meta)
+    with np.load(tier._path(8)) as z:
+        assert sorted(z.files) == sorted(
+            [*arrays, "block_hash", "parent_sequence_hash", "position",
+             "kv_dtype"])
+        for k, a in arrays.items():
+            assert z[k].dtype == a.dtype
+            np.testing.assert_array_equal(z[k], a)
+        assert (int(z["block_hash"]), int(z["position"]),
+                str(z["kv_dtype"])) == (3, 1, g["dtype"])
+
+
+@pytest.mark.parametrize("form", ["dense", "quant"])
+def test_a_wire_frame_keeps_its_bytes(form):
+    import struct
+
+    from dynamo_tpu.engine.kv_cache import (
+        blob_from_bytes,
+        blob_nbytes,
+        blob_to_bytes,
+    )
+    from dynamo_tpu.offload import pack_kv_blob_frame, unpack_kv_blob_frame
+
+    g = _GOLDEN[form]
+    # the payload of every transfer (disagg delivery, prefix onboard, G4)
+    assert blob_to_bytes(_golden_blob(form)) == g["payload"]
+    assert blob_nbytes(_GOLDEN_SHAPE, g["dtype"]) == len(g["payload"])
+    _assert_golden(
+        form, blob_from_bytes(g["payload"], _GOLDEN_SHAPE, g["dtype"]))
+    # the G4 frame around it: u32-LE header length | JSON header | payload
+    hdr = (
+        '{"v": 1, "kind": "%s", "dtype": "%s", "shape": [1, 2, 1, 2, 1, 2], '
+        '"meta": {"block_hash": 5, "parent_sequence_hash": 4, "position": 3, '
+        '"kv_dtype": "%s"}, "payload_nbytes": %d}'
+        % (form, g["dtype"], g["dtype"], len(g["payload"]))
+    ).encode()
+    frame = struct.pack("<I", len(hdr)) + hdr + g["payload"]
+    meta = BlockMeta(block_hash=5, parent_sequence_hash=4, position=3,
+                     kv_dtype=g["dtype"])
+    assert pack_kv_blob_frame(_golden_blob(form), meta) == frame
+    got, got_meta = unpack_kv_blob_frame(frame)
+    _assert_golden(form, got)
+    assert got_meta == meta
