@@ -1072,6 +1072,151 @@ def work_list_item_times(args, jax) -> None:
     emit(phase="kernels", work_list_items=table_out, compiled=not args.rehearse)
 
 
+def gdn_chunk_times(args, jax) -> None:
+    """The gated delta rule's packed layer alone (``attention.
+    packed_delta_mix``, the XLA composition under ``gdn_chunk``) at the
+    shapes of the cell that serves it: 2048 packed rows at Qwen3-Next's
+    widths (32 value heads, state 128 x 128, convolution over 8192 channels),
+    as one segment, as a chunk beside 15 decode rows with a snapshot taken
+    mid-segment, and as 16 decode rows; and the fused steps' one-token update
+    (``decode_delta_mix``).  Milliseconds a layer on the device (``reps``
+    layers chained in one executable, each reading the state the one before
+    left) and the largest difference from the token-by-token recurrence over
+    the same rows."""
+    import time as _time
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.engine import attention as att
+    from dynamo_tpu.engine.config import ModelConfig
+    from dynamo_tpu.engine.kv_cache import DeltaKV
+
+    if args.rehearse:
+        Hk, Hv, dk, dv, Np, B, reps = 2, 4, 8, 8, 160, 4, 2
+    else:
+        Hk, Hv, dk, dv, Np, B, reps = 16, 32, 128, 128, 2048, 16, 9
+    cfg = ModelConfig(
+        linear_num_key_heads=Hk, linear_num_value_heads=Hv,
+        linear_key_head_dim=dk, linear_value_head_dim=dv,
+        layer_pattern=("linear", "full"), num_layers=2)
+    C, S = cfg.linear_conv_width, 4
+    rng = np.random.RandomState(args.seed)
+    dt = jnp.float32 if args.rehearse else jnp.bfloat16
+    u = jnp.asarray(rng.standard_normal((Np, C)), dt)
+    taps = jnp.asarray(rng.standard_normal((4, C)) / 2, dt)
+    g = -jnp.asarray(rng.uniform(1e-3, 0.1, (Np, Hv)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.1, 0.9, (Np, Hv)), jnp.float32)
+
+    def state(plan):
+        return DeltaKV(
+            jnp.zeros((1,), dt),
+            jnp.asarray(rng.standard_normal((1, B, Hv, dk, dv)) * 0.1, jnp.float32),
+            jnp.asarray(rng.standard_normal((1, 3 * B, C)), dt),
+            jnp.zeros((1, S, Hv, dk, dv), jnp.float32),
+            jnp.zeros((1, 3 * S, C), dt),
+            jnp.asarray(plan, jnp.int32),
+        )
+
+    def recurrence(q_lens, seg_off, base, st):
+        """Token by token over every live lane's rows, from the lane's
+        state and history (zeros at position 0)."""
+        out = np.zeros((Np, Hv, dv), np.float32)
+        hist = np.asarray(st.conv[0].astype(jnp.float32)).reshape(B, 3, C)
+        w = np.asarray(taps.astype(jnp.float32))
+        uu = np.asarray(u.astype(jnp.float32))
+
+        @jax.jit
+        def lane(S0, x, gg, bb):
+            q, k, v = att._gdn_heads(cfg, x)
+
+            def step(S, t):
+                qt, kt, vt, gt, bt = t
+                S = jnp.exp(gt)[:, None, None] * S
+                d = bt[:, None] * (vt - jnp.einsum("hk,hkv->hv", kt, S, precision="highest"))
+                S = S + kt[:, :, None] * d[:, None, :]
+                return S, jnp.einsum("hk,hkv->hv", qt, S, precision="highest")
+
+            return jax.lax.scan(step, S0, (q, k, v, gg, bb))[1]
+
+        for b in range(B):
+            n, o = int(q_lens[b]), int(seg_off[b])
+            if not n:
+                continue
+            first = int(base[b]) == 0
+            rows = np.concatenate(
+                [np.zeros((3, C), np.float32) if first else hist[b], uu[o:o + n]])
+            x = sum(w[i] * rows[i:i + n] for i in range(4))
+            x = jnp.asarray(x / (1 + np.exp(-x)), jnp.float32)
+            S0 = jnp.zeros((Hv, dk, dv)) if first else st.lanes[0, b]
+            out[o:o + n] = np.asarray(lane(S0, x, g[o:o + n], beta[o:o + n]))
+        return out
+
+    none = np.full((3, B), -1, np.int32)
+    chunk = Np - (B - 1)
+    mid = none.copy()
+    mid[1, 0], mid[2, 0] = 1, 1000 + chunk // 2 // 16 * 16
+    cases = [
+        ("one segment of %d" % Np, [Np] + [0] * (B - 1), [0] * B, none),
+        ("chunk of %d + %d decode rows, snapshot mid-chunk" % (chunk, B - 1),
+         [chunk] + [1] * (B - 1), [1000] + [500] * (B - 1), mid),
+        ("%d decode rows" % B, [1] * B, [700] * B, none),
+    ]
+    for name, q_lens, base, plan in cases:
+        q_lens = np.asarray(q_lens, np.int32)
+        seg_off = np.concatenate([[0], np.cumsum(q_lens)[:-1]]).astype(np.int32)
+        lane = np.full((Np,), B, np.int32)
+        rel = np.zeros((Np,), np.int32)
+        for b in range(B):
+            lane[seg_off[b]:seg_off[b] + q_lens[b]] = b
+            rel[seg_off[b]:seg_off[b] + q_lens[b]] = np.arange(q_lens[b])
+        ops = tuple(jnp.asarray(a, jnp.int32) for a in (base, seg_off, q_lens, lane, rel))
+
+        @jax.jit
+        def layers(st):
+            o = None
+            for _ in range(reps):
+                o, st = att.packed_delta_mix(
+                    cfg, u, taps, g, beta, st, jnp.int32(0), *ops)
+            return o, st
+
+        @jax.jit
+        def once(st):
+            return att.packed_delta_mix(cfg, u, taps, g, beta, st, jnp.int32(0), *ops)
+
+        st = state(plan)
+        got = np.asarray(once(st)[0])
+        want = recurrence(q_lens, seg_off, base, st)
+        live = lane < B
+        gap = float(np.abs(got - want)[live].max())
+        jax.block_until_ready(layers(st))
+        t0 = _time.perf_counter()
+        jax.block_until_ready(layers(st))
+        ms = (_time.perf_counter() - t0) * 1e3 / reps
+        emit(gdn_chunk=name, ms_a_layer=round(ms, 3),
+             max_gap_vs_recurrence=gap, scale=float(np.abs(want[live]).max()))
+        if not gap < (1e-4 if args.rehearse else 2e-2) * max(1.0, float(np.abs(want).max())):
+            fail(f"gdn_chunk {name}: differs from the recurrence by {gap}")
+
+    ub = u[:B]
+
+    @jax.jit
+    def decode(st):
+        o = None
+        for _ in range(reps):
+            o, st = att.decode_delta_mix(
+                cfg, ub, taps, g[:B], beta[:B], st, jnp.int32(0),
+                jnp.ones((B,), bool))
+        return o, st
+
+    st = state(none)
+    jax.block_until_ready(decode(st))
+    t0 = _time.perf_counter()
+    jax.block_until_ready(decode(st))
+    emit(gdn_decode="%d lanes" % B,
+         ms_a_layer=round((_time.perf_counter() - t0) * 1e3 / reps, 3))
+
+
 def child_kernels(args) -> None:
     jax = child_devices(args.rehearse, 1)
     import math
@@ -1401,6 +1546,9 @@ def main(argv=None) -> int:
         return 0
     if args.child == "latent-packed":  # and that one
         latent_packed_times(args, child_devices(args.rehearse, 1))
+        return 0
+    if args.child == "gdn-chunk":  # the delta rule's layer alone
+        gdn_chunk_times(args, child_devices(args.rehearse, 1))
         return 0
     if args.child == "shard-evidence":
         child_shard_evidence(args)

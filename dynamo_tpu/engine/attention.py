@@ -31,6 +31,7 @@ from jax.sharding import PartitionSpec as P
 from ..analysis.hotpath import hot_path
 from .kv_cache import (
     ConvKV,
+    DeltaKV,
     KindKV,
     QuantKV,
     gather_layer_kv,
@@ -85,6 +86,15 @@ def layer_view(cfg, kv_pages, page_table, layer, kind) -> LayerView:
         return LayerView(
             kv_pages, page_table, layer, cfg.sliding_window or 0,
             lambda kv: kv, "",
+        )
+    if cfg.has_linear:
+        # an attention layer beside delta-rule layers, as below
+        if not isinstance(kv_pages, DeltaKV):  # a step that writes no cache
+            return LayerView(kv_pages, page_table, layer, 0, lambda kv: kv, "")
+        return LayerView(
+            kv_pages.attn, page_table, layer, 0, kv_pages.with_attn,
+            # heads wider than the 128 lanes: the launches over them say so
+            "_wide" if cfg.head_dim > 128 else "",
         )
     if cfg.has_conv:
         # an attention layer beside convolution layers: ``layer`` is its
@@ -242,6 +252,298 @@ def decode_conv_mix(
     return _conv_taps(taps, prev[:, 0], prev[:, 1], z), _conv_snapshot(
         state, layer, lanes, z, page_table, jnp.arange(B), positions, active
     )
+
+
+# -- gated delta-rule layers (kv_cache.DeltaKV) -------------------------------
+#
+# The two calls below are all a linear layer asks of a step: run the
+# convolution over ``[q | k | v]`` and the recurrence
+#
+#     S <- exp(g_t) S;  d = beta_t (v_t - S^T k_t);  S <- S + k_t d^T;
+#     o_t = S^T q_t
+#
+# over every row, from the state its lane carries in, and leave behind what
+# the next step needs (the lane's state and last three rows) and what a
+# prefix hit needs (a snapshot in the slot the dispatch names).
+
+GDN_CHUNK = 64
+# float32 products in float32: the chip's default rounds both sides to
+# bfloat16, and the state is what every later token reads
+_GDN_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _gdn_dot(spec, a, b):
+    return jnp.einsum(
+        spec, a, b, precision=_GDN_PRECISION,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _gdn_conv(taps, rows):
+    """``silu(sum_i w_i u_{t-3+i})`` a channel, summed in float32:
+    ``rows`` the four rows oldest first."""
+    w = taps.astype(jnp.float32)
+    acc = sum(w[i] * rows[i].astype(jnp.float32) for i in range(4))
+    return jax.nn.silu(acc)
+
+
+def _gdn_heads(cfg, x):
+    """A convolved row ``[.., C]`` in float32 as the recurrence reads it:
+    ``(q, k [.., Hv, dk], v [.., Hv, dv])``, q and k normalised over ``dk``
+    (L2, eps 1e-6), q scaled by ``dk^-1/2``, each key head repeated for the
+    value heads it serves."""
+    Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    lead = x.shape[:-1]
+    q, k, v = jnp.split(x, [Hk * dk, 2 * Hk * dk], axis=-1)
+
+    def unit(a):
+        a = a.reshape(*lead, Hk, dk)
+        a = a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+        return jnp.repeat(a, Hv // Hk, axis=-2)
+
+    return unit(q) * dk ** -0.5, unit(k), v.reshape(*lead, Hv, dv)
+
+
+def gdn_chunk_terms(q, k, v, g, beta):
+    """What a chunk of the delta rule computes before it meets the state
+    entering it, for any leading axes: ``q, k [.., C, dk]``, ``v [.., C,
+    dv]``, ``g, beta [.., C]`` (float32; a row that is padding has ``beta =
+    g = 0`` and zero ``k``).  With ``G`` the running sum of ``g``, ``A_ij =
+    -beta_i (k_i . k_j) exp(G_i - G_j)`` for ``j < i`` and ``T = (I - A)^-1``
+    by the doubling product (``A`` is nilpotent): returns ``(V' = T (beta
+    V), K' = T (beta exp(G) K), Qg = exp(G) Q, W = tril(Q K^T exp(G_i -
+    G_j)), Kd = exp(G_C - G) K, exp(G_C))``.  The exponent is masked above
+    the diagonal, not the product: ``exp`` of a positive sum overflows."""
+    C = q.shape[-2]
+    G = jnp.cumsum(g, axis=-1)
+    diff = G[..., :, None] - G[..., None, :]
+    i, j = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
+    below = jnp.exp(jnp.where(i > j, diff, -jnp.inf))
+    upto = jnp.exp(jnp.where(i >= j, diff, -jnp.inf))
+    A = -beta[..., :, None] * _gdn_dot("...ik,...jk->...ij", k, k) * below
+    T = jnp.eye(C, dtype=jnp.float32) + A
+    P = A
+    for _ in range(max(C - 1, 1).bit_length() - 1):
+        P = _gdn_dot("...ij,...jk->...ik", P, P)
+        T = T + _gdn_dot("...ij,...jk->...ik", T, P)
+    eG = jnp.exp(G)
+    Vp = _gdn_dot("...ij,...jv->...iv", T, beta[..., None] * v)
+    Kp = _gdn_dot("...ij,...jk->...ik", T, (beta * eG)[..., None] * k)
+    W = _gdn_dot("...ik,...jk->...ij", q, k) * upto
+    Kd = jnp.exp(G[..., -1:] - G)[..., None] * k
+    return Vp, Kp, eG[..., None] * q, W, Kd, eG[..., -1]
+
+
+def gdn_chunk_apply(S, Vp, Kp, Qg, W, Kd, gC):
+    """A chunk's rows from the state entering it, ``S [.., dk, dv]``:
+    ``V'' = V' - K' S``, ``O = Qg S + W V''``, ``S <- exp(G_C) S + Kd^T
+    V''``.  Returns ``(O [.., C, dv], S)``."""
+    Vpp = Vp - _gdn_dot("...ik,...kv->...iv", Kp, S)
+    O = _gdn_dot("...ik,...kv->...iv", Qg, S) + _gdn_dot(
+        "...ij,...jv->...iv", W, Vpp)
+    S = gC[..., None, None] * S + _gdn_dot("...ik,...iv->...kv", Kd, Vpp)
+    return O, S
+
+
+def _gdn_history(state: DeltaKV, layer, B):
+    """``[B, 3, C]``: the lanes' last three rows, oldest first."""
+    rows = jax.lax.dynamic_index_in_dim(state.conv, layer, 0, False)
+    return rows.reshape(B, 3, -1)
+
+
+@hot_path
+def packed_delta_mix(
+    cfg,
+    u: jax.Array,  # [Np, C] packed rows of [q | k | v] before the convolution
+    taps: jax.Array,  # [4, C]
+    g: jax.Array,  # [Np, Hv] f32 log-decay
+    beta: jax.Array,  # [Np, Hv] f32
+    state: DeltaKV,
+    layer: jax.Array,  # index among the linear layers
+    base: jax.Array,  # [B] position of a lane's first row
+    seg_off: jax.Array,  # [B]
+    q_lens: jax.Array,  # [B] rows per lane (0 = no segment)
+    lane: jax.Array,  # [Np] lane per packed row (B = padding)
+    rel: jax.Array,  # [Np] row index within the lane's segment
+):
+    """The packed step's delta rule.  A lane's segment of one row (a decode
+    row) takes the recurrence's one step; a longer one runs in chunks of
+    ``GDN_CHUNK`` of its rows, cut where the dispatch takes its snapshot so
+    that a chunk never straddles it: the chunks in packed order, each from
+    its lane's state (a loop of as many turns as the dispatch has chunks).
+    Returns ``o [Np, Hv, dv]`` float32 and the state with each live lane's
+    state and last three rows written, and the snapshots the dispatch's
+    plan names."""
+    Np, C = u.shape
+    B = base.shape[0]
+    Hv, dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
+    restore, snap_slot, snap_pos = state.plan
+    live = q_lens > 0
+    S_slots = state.slots.shape[1]
+    K = GDN_CHUNK
+    with jax.named_scope("gdn_chunk"):
+        # -- where each lane starts from
+        old_hist = _gdn_history(state, layer, B)  # [B, 3, C]
+        lanes = jax.lax.dynamic_index_in_dim(state.lanes, layer, 0, False)
+        resumes = live & (restore >= 0) & (base > 0)
+        fresh = live & (base == 0)
+        at = jnp.clip(restore, 0, S_slots - 1)
+
+        def from_slots(_):  # few dispatches have a lane that restores
+            conv = state.slot_conv.reshape(-1, S_slots, 3, C)[layer, at]
+            return (
+                jnp.where(
+                    resumes[:, None, None, None], state.slots[layer, at], lanes),
+                jnp.where(resumes[:, None, None], conv, old_hist),
+            )
+
+        S0, hist = jax.lax.cond(
+            jnp.any(resumes), from_slots, lambda _: (lanes, old_hist), None)
+        hist = jnp.where(fresh[:, None, None], 0, hist)
+        S0 = jnp.where(fresh[:, None, None, None], 0.0, S0)
+        # -- the convolution: a row's predecessors are the rows before it in
+        # its segment, else the lane's history
+        lane_c = jnp.clip(lane.astype(jnp.int32), 0, B - 1)
+        past = [u]
+        for n in (1, 2, 3):
+            h = hist[lane_c, jnp.clip(3 - n + rel, 0, 2)]
+            past.append(
+                jnp.where((rel >= n)[:, None], jnp.roll(u, n, axis=0), h))
+        q, k, v = _gdn_heads(cfg, _gdn_conv(taps, past[::-1]))  # [Np, Hv, d]
+        # -- segments of one row: the recurrence's one step
+        single = q_lens == 1
+        row1 = jnp.clip(seg_off, 0, Np - 1)
+        S1 = jnp.exp(g[row1])[..., None, None] * S0
+        d1 = beta[row1][..., None] * (v[row1] - _gdn_dot("bhk,bhkv->bhv", k[row1], S1))
+        S1 = S1 + k[row1][..., :, None] * d1[..., None, :]
+        o1 = _gdn_dot("bhk,bhkv->bhv", q[row1], S1)
+        # -- longer segments in chunks: a lane's segment is two runs, before
+        # and from the snapshot's position
+        multi = q_lens > 1
+        snaps = multi & (snap_slot >= 0)
+        cut = jnp.where(snaps, jnp.clip(snap_pos - base, 0, q_lens), q_lens)
+        cut = jnp.where(multi, cut, 0)
+        run_len = jnp.stack(
+            [cut, jnp.where(multi, q_lens - cut, 0)], axis=1).reshape(-1)  # [2B]
+        run_off = jnp.stack([seg_off, seg_off + cut], axis=1).reshape(-1)
+        n_chunks = -(-run_len // K)
+        ends = jnp.cumsum(n_chunks)
+        r = jnp.arange(K, dtype=jnp.int32)
+        tail = lambda a: jnp.concatenate(  # noqa: E731  (a chunk reads K rows)
+            [a, jnp.zeros((K, *a.shape[1:]), a.dtype)], axis=0)
+        qp, kp, vp, gp, bp = tail(q), tail(k), tail(v), tail(g), tail(beta)
+
+        def chunk(c, carry):
+            Sw, snap, out = carry
+            run = jnp.searchsorted(ends, c, side="right").astype(jnp.int32)
+            b = run // 2
+            j = c - (ends[run] - n_chunks[run])
+            row0 = run_off[run] + K * j
+            ok = r < run_len[run] - K * j  # rows past the run are not its own
+
+            def rows(a):  # [K, Hv, ..] of this chunk, heads first
+                a = jax.lax.dynamic_slice_in_dim(a, row0, K, 0)
+                a = jnp.where(ok.reshape(K, *(1,) * (a.ndim - 1)), a, 0)
+                return jnp.moveaxis(a, 0, 1)
+
+            S = jax.lax.dynamic_index_in_dim(Sw, b, 0, False)
+            O, S = gdn_chunk_apply(
+                S, *gdn_chunk_terms(rows(qp), rows(kp), rows(vp), rows(gp), rows(bp)))
+            Sw = jax.lax.dynamic_update_index_in_dim(Sw, S, b, 0)
+            # a chunk that ends the run before a snapshot hands its state on
+            takes = (run % 2 == 0) & (j == n_chunks[run] - 1) & snaps[b]
+            snap = jax.lax.cond(
+                takes,
+                lambda: jax.lax.dynamic_update_index_in_dim(snap, S, b, 0),
+                lambda: snap,
+            )
+            # rows past the run are written by their own chunk, later
+            out = jax.lax.dynamic_update_slice_in_dim(
+                out, jnp.moveaxis(O, 0, 1), row0, 0)
+            return Sw, snap, out
+
+        Sw, snap, out = jax.lax.fori_loop(
+            0, ends[-1], chunk,
+            (S0, jnp.zeros_like(S0), jnp.zeros((Np + K, Hv, dv), jnp.float32)),
+        )
+        o = out.at[jnp.where(single, seg_off, Np + K)].set(o1, mode="drop")[:Np]
+        Sw = jnp.where(single[:, None, None, None], S1, Sw)
+
+        # -- what the step leaves: the lanes' state and last three rows
+        def row_at(p):  # [B, C]: a lane's row at segment index p (< 0: history)
+            inside = u[jnp.clip(seg_off + p, 0, Np - 1)]
+            return jnp.where(
+                (p >= 0)[:, None], inside,
+                hist[jnp.arange(B), jnp.clip(3 + p, 0, 2)])
+
+        new_hist = jnp.stack([row_at(q_lens - 3 + n) for n in range(3)], axis=1)
+        new_hist = jnp.where(live[:, None, None], new_hist, old_hist)
+        snap_hist = jnp.stack([row_at(cut - 3 + n) for n in range(3)], axis=1)
+        # -- the snapshots the plan names (others to no slot)
+        to = jnp.where(snaps, snap_slot, S_slots)
+        rows3 = (3 * to[:, None] + jnp.arange(3)[None, :]).reshape(-1)
+
+        def write(pool):  # and few take a snapshot
+            slots, slot_conv = pool
+            return (
+                slots.at[layer, to].set(snap, mode="drop"),
+                slot_conv.at[layer, rows3].set(
+                    snap_hist.reshape(3 * B, C).astype(slot_conv.dtype),
+                    mode="drop",
+                ),
+            )
+
+        slots, slot_conv = jax.lax.cond(
+            jnp.any(snaps), write, lambda pool: pool,
+            (state.slots, state.slot_conv))
+        new = DeltaKV(
+            state.attn,
+            state.lanes.at[layer].set(Sw),
+            state.conv.at[layer].set(
+                new_hist.reshape(3 * B, C).astype(state.conv.dtype)),
+            slots, slot_conv, state.plan,
+        )
+    return o, new
+
+
+@hot_path
+def decode_delta_mix(
+    cfg,
+    u: jax.Array,  # [B, C] one row a lane
+    taps: jax.Array,  # [4, C]
+    g: jax.Array,  # [B, Hv] f32
+    beta: jax.Array,  # [B, Hv] f32
+    state: DeltaKV,
+    layer: jax.Array,
+    active: jax.Array,  # [B] bool: lanes the step advances
+):
+    """A decode step's delta rule: one token a lane against the lane's
+    state.  Only an ``active`` lane's state moves: a frozen lane runs the
+    step over again on the same token, and a second update of its state
+    would not be the first.  No snapshot is taken or read."""
+    B, C = u.shape
+    with jax.named_scope("gdn_decode"):
+        hist = _gdn_history(state, layer, B)
+        x = _gdn_conv(taps, [hist[:, 0], hist[:, 1], hist[:, 2], u])
+        q, k, v = _gdn_heads(cfg, x)  # [B, Hv, d]
+        old = jax.lax.dynamic_index_in_dim(state.lanes, layer, 0, False)
+        S = jnp.exp(g)[..., None, None] * old
+        d = beta[..., None] * (v - _gdn_dot("bhk,bhkv->bhv", k, S))
+        S = S + k[..., :, None] * d[..., None, :]
+        o = _gdn_dot("bhk,bhkv->bhv", q, S)
+        keep = active[:, None, None]
+        new_hist = jnp.where(
+            keep, jnp.concatenate([hist[:, 1:], u[:, None]], axis=1), hist)
+        new = DeltaKV(
+            state.attn,
+            state.lanes.at[layer].set(jnp.where(keep[..., None], S, old)),
+            state.conv.at[layer].set(
+                new_hist.reshape(3 * B, C).astype(state.conv.dtype)),
+            state.slots, state.slot_conv, state.plan,
+        )
+    return o, new
+
 
 
 def _kv_write(kv_pages, kv_idx, layer, ids, k_rows, *, slot=None):

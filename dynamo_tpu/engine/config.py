@@ -110,6 +110,25 @@ class ModelConfig:
     # per-expert bias added for the choice only, never to a weight
     router_score: str = "softmax"
     router_bias: bool = False
+    # gated delta-rule layers (qwen3_next): a ``layer_pattern`` entry
+    # "linear" is a layer whose operator keeps a matrix a value head,
+    # ``S [linear_key_head_dim, linear_value_head_dim]`` in float32, that
+    # every token decays and updates (model._gated_delta_operator), behind a
+    # causal depthwise convolution of 4 taps over ``[q | k | v]``.  Such a
+    # layer touches no page: the cache holds the attention layers only, the
+    # lane holds the state, and a pool of slots holds the few snapshots a
+    # prefix hit can resume from (kv_cache.DeltaKV)
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    # the share of a head's width RoPE rotates (its first columns)
+    partial_rotary_factor: float = 1.0
+    # the query projection carries a gate as wide as the head beside every
+    # head's query; the attention's result is multiplied by its sigmoid
+    attn_output_gate: bool = False
+    # the shared expert's result is multiplied by sigmoid(w_sg . x)
+    shared_expert_gate: bool = False
     # activation dtype for compute; params may be stored differently
     dtype: str = "bfloat16"
 
@@ -135,6 +154,28 @@ class ModelConfig:
     def has_conv(self) -> bool:
         """Convolution layers in the trunk: state beside the pages."""
         return "conv" in (self.layer_pattern or ()) + (self.lead_pattern or ())
+
+    @property
+    def has_linear(self) -> bool:
+        """Gated delta-rule layers in the trunk: a matrix state in the lane,
+        snapshots in a pool of slots."""
+        return "linear" in (self.layer_pattern or ())
+
+    @property
+    def state_kind(self) -> Optional[str]:
+        """The kind of the trunk's layers that hold state and no page
+        ("conv", "linear"), or None: the stack holds such a kind's operator
+        and the attention layers' apart (model.scan_layers), and the pair
+        pool holds the attention layers alone."""
+        return "conv" if self.has_conv else "linear" if self.has_linear else None
+
+    @property
+    def linear_conv_width(self) -> int:
+        """Channels of a linear layer's convolution: ``[q | k | v]``."""
+        return (
+            2 * self.linear_num_key_heads * self.linear_key_head_dim
+            + self.linear_num_value_heads * self.linear_value_head_dim
+        )
 
     @property
     def lead_layers(self) -> int:
@@ -167,7 +208,9 @@ class ModelConfig:
     @property
     def rope_dim(self) -> int:
         """Width of the rotated part of a query/key."""
-        return self.qk_rope_head_dim if self.is_mla else self.head_dim
+        if self.is_mla:
+            return self.qk_rope_head_dim
+        return int(self.head_dim * self.partial_rotary_factor)
 
     @property
     def kv_geometry(self) -> Tuple[int, int, int, int]:
@@ -180,7 +223,7 @@ class ModelConfig:
         if self.is_mla:
             row = self.kv_lora_rank + self.qk_rope_head_dim
             return -(-self.num_layers // 2), 1, 1, 2 * row
-        if self.has_conv:  # the attention layers alone hold pages
+        if self.state_kind:  # the attention layers alone hold pages
             return (self.kind_layers("full"), 2, self.pool_kv_heads,
                     self.pool_head_dim)
         return self.num_layers, 2, self.num_kv_heads, self.head_dim
@@ -328,7 +371,7 @@ class ModelConfig:
 
     SUPPORTED_MODEL_TYPES = (
         "llama", "mistral", "qwen2", "mixtral", "gemma", "phi3", "qwen3",
-        "mistral4", "mellum", "lfm2_moe",
+        "mistral4", "mellum", "lfm2_moe", "qwen3_next",
     )
 
     @classmethod
@@ -357,6 +400,8 @@ class ModelConfig:
             return cls._from_mellum(cfg, rs or {})
         if mt == "lfm2_moe":
             return cls._from_lfm2_moe(cfg)
+        if mt == "qwen3_next":
+            return cls._from_qwen3_next(cfg)
         if rs is not None:
             rs_type = rs.get("rope_type") or rs.get("type")
             if rs_type == "llama3":
@@ -709,6 +754,113 @@ class ModelConfig:
             lead_intermediate_size=cfg["intermediate_size"] if lead else 0,
             router_score="sigmoid",
             router_bias=bool(cfg.get("use_expert_bias", False)),
+        )
+
+    @classmethod
+    def _from_qwen3_next(cls, cfg: Dict[str, Any]) -> "ModelConfig":
+        """``qwen3_next``: periods of ``full_attention_interval`` layers, the
+        last of each gated softmax attention (a norm over each head of q and
+        k, RoPE over ``partial_rotary_factor`` of the head, the result times
+        the sigmoid of a gate the query projection carries), the others
+        gated delta-rule layers (``linear_*``); every MLP ``num_experts``
+        routed experts of ``moe_intermediate_size`` (softmax over all, the
+        ``num_experts_per_tok`` largest renormalised) beside a shared expert
+        with a sigmoid gate; RMSNorm weights centred at zero.
+        ``num_experts`` is what this process holds; ``router_experts``
+        (default: the same) the router's published width, ``expert_offset``
+        the first held expert's published index (the ``mistral4`` keys)."""
+        L = cfg["num_hidden_layers"]
+        interval = int(cfg.get("full_attention_interval", 4))
+        if cfg.get("mlp_only_layers"):
+            raise ValueError(
+                f"qwen3_next mlp_only_layers={cfg['mlp_only_layers']} is not"
+                " supported (every MLP routed)"
+            )
+        if cfg.get("decoder_sparse_step", 1) != 1:
+            raise ValueError(
+                f"qwen3_next decoder_sparse_step={cfg['decoder_sparse_step']}"
+                " is not supported (implemented: 1, every MLP routed)"
+            )
+        if cfg.get("linear_conv_kernel_dim", 4) != 4:
+            raise ValueError(
+                f"qwen3_next linear_conv_kernel_dim="
+                f"{cfg['linear_conv_kernel_dim']} is not supported"
+                " (implemented: 4, a filter over a token and its three"
+                " predecessors)"
+            )
+        if interval < 1 or L % interval:
+            raise ValueError(
+                f"qwen3_next num_hidden_layers={L} is not whole periods of"
+                f" full_attention_interval={interval}"
+            )
+        types = cfg.get("layer_types")
+        want = [
+            "full_attention" if (i + 1) % interval == 0 else "linear_attention"
+            for i in range(L)
+        ]
+        if types is not None and list(types) != want:
+            raise ValueError(
+                "qwen3_next layer_types differs from what"
+                f" full_attention_interval={interval} lays out"
+            )
+        if not cfg.get("norm_topk_prob", True):
+            raise ValueError("qwen3_next without norm_topk_prob is not supported")
+        if cfg.get("use_sliding_window") or cfg.get("rope_scaling"):
+            raise ValueError(
+                "qwen3_next with a sliding window or rope_scaling is not"
+                " supported"
+            )
+        I = cfg["moe_intermediate_size"]
+        shared = int(cfg.get("shared_expert_intermediate_size", 0))
+        if shared % I:
+            raise ValueError(
+                f"qwen3_next shared_expert_intermediate_size={shared} is not a"
+                f" multiple of moe_intermediate_size={I}"
+            )
+        hk, hv = cfg["linear_num_key_heads"], cfg["linear_num_value_heads"]
+        if hv % hk:
+            raise ValueError(
+                f"qwen3_next linear_num_value_heads={hv} is not a multiple of"
+                f" linear_num_key_heads={hk}"
+            )
+        held = cfg["num_experts"]
+        width = cfg.get("router_experts", held)
+        offset = cfg.get("expert_offset", 0)
+        if offset < 0 or offset + held > width:
+            raise ValueError(
+                f"experts {offset}..{offset + held - 1} lie outside the "
+                f"router's {width}"
+            )
+        heads = cfg["num_attention_heads"]
+        hidden = cfg["hidden_size"]
+        pattern = ("linear",) * (interval - 1) + ("full",)
+        return cls(
+            vocab_size=cfg["vocab_size"],
+            hidden_size=hidden,
+            intermediate_size=I,
+            num_layers=L,
+            num_heads=heads,
+            num_kv_heads=cfg.get("num_key_value_heads", heads),
+            head_dim=cfg.get("head_dim") or hidden // heads,
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rms_norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+            max_position=cfg.get("max_position_embeddings", 4096),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            num_experts=width,
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            num_local_experts=held if held != width else 0,
+            local_expert_offset=offset,
+            num_shared_experts=shared // I,
+            shared_expert_gate=shared > 0,
+            rms_norm_offset=True,
+            qk_norm=True,
+            layer_pattern=pattern if interval > 1 else None,
+            linear_num_key_heads=hk,
+            linear_num_value_heads=hv,
+            linear_key_head_dim=cfg["linear_key_head_dim"],
+            linear_value_head_dim=cfg["linear_value_head_dim"],
+            partial_rotary_factor=float(cfg.get("partial_rotary_factor", 1.0)),
+            attn_output_gate=True,
         )
 
     @classmethod

@@ -225,6 +225,10 @@ class EngineConfig:
     # layers' (pages let go behind the window: a lane never holds more than
     # its window, the chunk in flight and its decode growth)
     num_window_pages: int = 0
+    # a trunk with gated delta-rule layers (ModelConfig.has_linear) keeps a
+    # pool of this many snapshots of the lanes' state, which a prefix hit
+    # resumes from (kv_cache.DeltaKV): one is as large as a lane's state
+    state_snapshot_slots: int = 0
     block_size: Optional[int] = None  # router-visible KV block size
     # decode steps per device dispatch: decode state stays on device for this
     # many tokens, so host round trips amortize K-fold (ITL burstiness trade)
@@ -806,6 +810,7 @@ class JaxEngine:
             num_window_pages=self.cfg.num_window_pages,
             window_allocator=wpool,
             max_lanes=self.cfg.max_batch_size,
+            state_slots=self.cfg.state_snapshot_slots,
         )
         # serving-step dispatch table: module-level jits on one chip; on a
         # dp/tp (/ep) mesh, re-jitted with explicit in/out shardings
@@ -864,6 +869,14 @@ class JaxEngine:
         if model_cfg.has_conv:
             self.sched.conv_state = True
             self.obs.mint_conv_state(self.kv.state_bytes)
+        if model_cfg.has_linear:
+            from .kv_cache import StateSlots
+
+            self.sched.state_slots = StateSlots(self.cfg.state_snapshot_slots)
+            self.obs.mint_delta_state(self.kv.state_bytes)
+            if pool is not None:  # a slot dies with its block
+                pool.on_evict = lambda blk: self.sched.state_slots.drop(
+                    blk.sequence_hash)
         # the dispatch record (ISSUE 41), on time.perf_counter(), the clock
         # of every Inflight* record's ``dispatched_at`` and of the commit's
         # one read: service seconds of the committed dispatches that
@@ -1063,7 +1076,7 @@ class JaxEngine:
                     self.kv.pages, model_cfg.num_heads,
                     model_cfg.pool_kv_heads, model_cfg.pool_head_dim,
                 )
-                if model_cfg.has_conv
+                if model_cfg.state_kind
                 else None
             )
         # queue-side prefetch: window resolved here, walks issued by the
@@ -4654,6 +4667,23 @@ class JaxEngine:
                     1 for ch in chunks
                     if ch.start and ch.start == ch.seq.cached_prompt_tokens
                 )
+        if self.model_cfg.has_linear:
+            # what the dispatch does with the snapshot pool rides the cache
+            # into the step (kv_cache.DeltaKV.plan): every packed dispatch
+            # writes it, so none reads the last one's
+            plan = sched.state_plan(chunks)
+            self.kv.pages = self.kv.pages.with_plan(jnp.asarray(plan))
+            self.obs.observe_snapshot_slots(
+                len(sched.state_slots), sched.state_slots.evictions)
+            if tick is not None and tick.annotating:
+                dispatch_meta["attn"] = self._packed_attn
+                dispatch_meta["restored"] = int((plan[0] >= 0).sum())
+                dispatch_meta["snapshots"] = int((plan[1] >= 0).sum())
+                dispatch_meta["state_restored_tokens"] = sum(
+                    ch.start for ch in chunks if plan[0, ch.seq.slot] >= 0)
+                dispatch_meta["recompute_tokens"] = sum(
+                    ch.seq.state_recompute for ch in chunks
+                    if plan[0, ch.seq.slot] >= 0)
         operands = (
             self.params,
             self.model_cfg,
@@ -5943,6 +5973,13 @@ class JaxEngine:
                 }
                 if self.model_cfg.has_conv else {}
             ),
+            **(
+                {
+                    "state_restored_tokens": seq.cached_prompt_tokens,
+                    "recompute_tokens": seq.state_recompute,
+                }
+                if self.model_cfg.has_linear else {}
+            ),
         )
         for stage, lo, hi in seq.stage_segments(end_s):
             attrs: Dict[str, Any] = {}
@@ -5952,6 +5989,8 @@ class JaxEngine:
                 attrs["attn"] = "window+full"
             elif self.model_cfg.has_conv and stage in ("prefill", "decode"):
                 attrs["attn"] = "conv+full"
+            elif self.model_cfg.has_linear and stage in ("prefill", "decode"):
+                attrs["attn"] = "linear+full"
             if stage == "prefill":
                 attrs = {
                     **attrs,

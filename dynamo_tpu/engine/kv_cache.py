@@ -8,7 +8,7 @@ trash page (inactive batch lanes write there), so the usable pool is pages
 ints; the device arrays are only touched by the jitted step functions
 (functional update, buffer donated so XLA updates in place).
 
-Four kinds of pool, each a pytree that rides the layer scan and jit
+Five kinds of pool, each a pytree that rides the layer scan and jit
 donation (``PagedKVCache`` builds the one a ``ModelConfig`` asks for):
 
     pair      one array ``[layers, 2, pages, page, Hkv, D]``; with
@@ -20,6 +20,8 @@ donation (``PagedKVCache`` builds the one a ``ModelConfig`` asks for):
               page tables a lane
     ConvKV    (convolution layers) a pair pool of the attention layers, and
               the convolution state by lane and by page beside it
+    DeltaKV   (gated delta-rule layers) a pair pool of the attention layers,
+              the lanes' matrix state and a pool of snapshot slots beside it
 
 A *blob* is a block of a pair pool outside it (an evicted block, a swap
 snapshot, a remote prefill's export, a donor's prefix block): a pytree of
@@ -37,6 +39,7 @@ registry live in dynamo_tpu.offload and dynamo_tpu.block_manager.
 from __future__ import annotations
 
 import threading
+from collections import OrderedDict
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
@@ -388,6 +391,132 @@ class ConvKV(_PoolTree):
 
 
 # ---------------------------------------------------------------------------
+# gated delta-rule layers (qwen3_next): an accumulated state beside the pages
+#
+# A linear layer keeps, a value head, a matrix ``S [dk, dv]`` in float32 that
+# every token decays and updates, behind a convolution of 4 taps whose three
+# predecessor rows a sequence carries too.  What a sequence carries past a
+# position is as large as the state itself (2 MB a layer at Qwen3-Next's
+# widths where a page of keys and values is 32 KB), so a snapshot cannot ride
+# every page as ``ConvKV.pages`` does.  The pair pool ``attn`` holds the
+# attention layers alone, and beside it ride
+#
+#     lanes     [Ll, B, Hv, dk, dv] f32   the lane is the slot: the state
+#                                         after the last row lane ``b``'s
+#                                         sequence computed
+#     conv      [Ll, 3 B, C]              the last three rows of ``[q|k|v]``
+#                                         before their convolution (rows
+#                                         ``3 b .. 3 b + 2``, oldest first)
+#     slots     [Ll, S, Hv, dk, dv] f32   a pool of ``S`` snapshots, far fewer
+#     slot_conv [Ll, 3 S, C]              than pages, handed out by the
+#                                         scheduler (``StateSlots``)
+#     plan      [3, B] i32                what the next packed step does with
+#                                         the slots, a lane: the slot it
+#                                         restores from, the slot it writes a
+#                                         snapshot to (-1: none), and the
+#                                         position the snapshot is taken at
+#                                         (the state after the row before it)
+#
+# Where a segment's state comes from: zeros at position 0; the slot ``plan``
+# names where the admission resumed from a snapshot; the lane's own
+# otherwise.  The engine writes ``plan`` before a packed dispatch
+# (``with_plan``); the decode steps read none of it and take no snapshot.
+# ---------------------------------------------------------------------------
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclass
+class DeltaKV(_PoolTree):
+    attn: Any  # [La, 2, P, page, Hkv, D]
+    lanes: Any  # [Ll, B, Hv, dk, dv] f32
+    conv: Any  # [Ll, 3 B, C]
+    slots: Any  # [Ll, S, Hv, dk, dv] f32
+    slot_conv: Any  # [Ll, 3 S, C]
+    plan: Any  # [3, B] i32
+
+    def tree_flatten(self):
+        return (
+            self.attn, self.lanes, self.conv, self.slots, self.slot_conv,
+            self.plan,
+        ), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        del aux
+        return cls(*children)
+
+    @property
+    def dtype(self):
+        return self.attn.dtype
+
+    def with_attn(self, pool) -> "DeltaKV":
+        return DeltaKV(pool, *self.tree_flatten()[0][1:])
+
+    def with_plan(self, plan) -> "DeltaKV":
+        return DeltaKV(*self.tree_flatten()[0][:-1], plan)
+
+
+class StateSlots:
+    """The host's table of the snapshot pool: block hash -> slot, least
+    recently used out first (a restore is a use), a slot held while a lane
+    waits to restore from it.  One slot past the pool is the step's trash."""
+
+    def __init__(self, num_slots: int) -> None:
+        self.num_slots = num_slots
+        self._free = list(range(num_slots - 1, -1, -1))
+        self._slot_of: "OrderedDict[int, int]" = OrderedDict()  # LRU first
+        self._held: Dict[int, int] = {}  # hash -> lanes waiting to restore
+        self.evictions = 0
+
+    def __len__(self) -> int:
+        return len(self._slot_of)
+
+    def __contains__(self, block_hash: int) -> bool:
+        return block_hash in self._slot_of
+
+    def hold(self, block_hash: int) -> int:
+        """The slot of a snapshot a lane will restore from: used now, and
+        not handed out again before ``release``."""
+        self._slot_of.move_to_end(block_hash)
+        self._held[block_hash] = self._held.get(block_hash, 0) + 1
+        return self._slot_of[block_hash]
+
+    def release(self, block_hash: int) -> None:
+        n = self._held.get(block_hash, 0) - 1
+        if n > 0:
+            self._held[block_hash] = n
+        else:
+            self._held.pop(block_hash, None)
+
+    def slot_of(self, block_hash: int) -> int:
+        return self._slot_of[block_hash]
+
+    def take(self, block_hash: int) -> Optional[int]:
+        """A slot for a new snapshot of ``block_hash``: a free one, else the
+        least recently used that no lane waits on.  None where the hash has
+        a snapshot already (it is used now) or every slot is waited on."""
+        if block_hash in self._slot_of:
+            self._slot_of.move_to_end(block_hash)
+            return None
+        if self._free:
+            slot = self._free.pop()
+        else:
+            victim = next(
+                (h for h in self._slot_of if h not in self._held), None)
+            if victim is None:
+                return None
+            slot = self._slot_of.pop(victim)
+            self.evictions += 1
+        self._slot_of[block_hash] = slot
+        return slot
+
+    def drop(self, block_hash: int) -> None:
+        """A slot dies with its block (the registry's ``on_evict``)."""
+        if block_hash in self._slot_of and block_hash not in self._held:
+            self._free.append(self._slot_of.pop(block_hash))
+
+
+# ---------------------------------------------------------------------------
 # what a kind of cache cannot do
 #
 # Everything that moves, rewinds or reshapes KV outside one chip's packed
@@ -410,13 +539,18 @@ _KINDS = (
      "a trunk with convolution layers (layer_types 'conv')",
      "a sequence carries two rows a layer beside its pages, which only the "
      "packed step and the fused decode steps carry, snapshot and restore"),
+    ("linear", "has_linear",
+     "a trunk with gated delta-rule layers (layer kind 'linear')",
+     "a sequence carries a matrix a value head and three rows a layer "
+     "beside its pages, which only the packed step and the fused decode "
+     "steps carry, snapshot and restore"),
     ("latent", "is_mla",
      "a latent cache (MLA)",
      "the transfer formats carry K/V pairs per head and per layer"),
 )
-_PAIR_ONLY = {"two_kind": None, "conv": None}
+_PAIR_ONLY = {"two_kind": None, "conv": None, "linear": None}
 _ONE_POOL = {**_PAIR_ONLY, "latent": None}
-_STATELESS = {"conv": None}
+_STATELESS = {"conv": None, "linear": None}
 
 # capability -> (what the sentence calls it, {kind that refuses it: None
 # for the kind's own reason, or the whole sentence})
@@ -485,7 +619,7 @@ def kv_data(kv_pages):
     operand plumbing).  A two-kind cache answers with its full pool: the
     two differ in layers and pages only.  A trunk with convolution layers
     answers with its attention layers' pool."""
-    if isinstance(kv_pages, ConvKV):
+    if isinstance(kv_pages, (ConvKV, DeltaKV)):
         return kv_pages.attn
     if isinstance(kv_pages, KindKV):
         return kv_pages.full
@@ -948,6 +1082,7 @@ class PagedKVCache:
         num_window_pages: int = 0,
         window_allocator: Optional[Any] = None,
         max_lanes: int = 0,
+        state_slots: int = 0,
     ) -> None:
         self.cfg = cfg
         self.num_pages = num_pages
@@ -1009,6 +1144,29 @@ class PagedKVCache:
             self.pages = place_pool(self.pages, sharding)
         if cfg.has_conv:
             self.pages = ConvKV(self.pages, *self._conv_state(max_lanes))
+        if cfg.has_linear:
+            self.pages = DeltaKV(
+                self.pages, *self._delta_state(max_lanes, state_slots))
+
+    def _delta_state(self, max_lanes: int, slots: int):
+        """The zeroed state of the delta-rule layers: lanes and slots."""
+        if max_lanes < 1 or slots < 1:
+            raise ValueError(
+                "a trunk with gated delta-rule layers needs max_lanes (the "
+                "engine's max_batch_size: the lane is the state's slot) and "
+                "state_slots (the snapshots a prefix hit can resume from)"
+            )
+        c = self.cfg
+        Ll, C = c.kind_layers("linear"), c.linear_conv_width
+        mat = (c.linear_num_value_heads, c.linear_key_head_dim,
+               c.linear_value_head_dim)
+        return (
+            jnp.zeros((Ll, max_lanes, *mat), jnp.float32),
+            jnp.zeros((Ll, 3 * max_lanes, C), self.dtype),
+            jnp.zeros((Ll, slots, *mat), jnp.float32),
+            jnp.zeros((Ll, 3 * slots, C), self.dtype),
+            jnp.full((3, max_lanes), -1, jnp.int32),
+        )
 
     def _conv_state(self, max_lanes: int):
         """The zeroed state of the convolution layers: ``(lanes, pages)``."""
@@ -1025,7 +1183,13 @@ class PagedKVCache:
 
     @property
     def state_bytes(self) -> dict:
-        """Bytes of the convolution layers' state by part (empty without)."""
+        """Bytes of the state layers' state by part (empty without)."""
+        if isinstance(self.pages, DeltaKV):
+            p = self.pages
+            return {
+                "lanes": int(p.lanes.nbytes) + int(p.conv.nbytes),
+                "slots": int(p.slots.nbytes) + int(p.slot_conv.nbytes),
+            }
         if not isinstance(self.pages, ConvKV):
             return {}
         return {

@@ -52,7 +52,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: Any = None) -> Params:
         scale = scale if scale is not None else (1.0 / jnp.sqrt(shape[-2] if len(shape) > 1 else shape[-1]))
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
-    if cfg.has_conv:
+    if cfg.state_kind:
         return _init_conv_trunk(
             cfg, iter(jax.random.split(key, 24 + 6 * cfg.lead_layers)), w, dtype
         )
@@ -114,9 +114,10 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: Any = None) -> Params:
 
 
 def _init_conv_trunk(cfg: ModelConfig, keys, w, dtype) -> Params:
-    """The tree of a trunk with convolution layers (``scan_layers``): the
-    periods' stack with each kind's operator under ``"attn"`` / ``"conv"``,
-    and the layers in front of the periods as a tuple under ``"lead"``."""
+    """The tree of a trunk with layers that hold state (``scan_layers``):
+    the periods' stack with each kind's operator under ``"attn"`` and
+    ``"conv"`` / ``"linear"`` (``cfg.state_kind``), and the layers in front
+    of the periods as a tuple under ``"lead"``."""
     H, D, I, E = cfg.hidden_size, cfg.head_dim, cfg.intermediate_size, cfg.num_experts
     Hq, Hkv = cfg.num_heads, cfg.num_kv_heads
 
@@ -127,19 +128,39 @@ def _init_conv_trunk(cfg: ModelConfig, keys, w, dtype) -> Params:
                 "conv_taps": w(next(keys), (*lead, 3, H), scale=0.5),
                 "conv_out": w(next(keys), (*lead, H, H)),
             }
+        if kind == "linear":
+            Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+            dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+            return {
+                "gdn_in": w(next(keys), (*lead, H, 2 * Hk * dk + 2 * Hv * dv)),
+                "gdn_ba": w(next(keys), (*lead, H, 2 * Hv)),
+                "gdn_taps": w(
+                    next(keys), (*lead, 4, cfg.linear_conv_width), scale=0.5),
+                # memories of about 8 to 500 tokens, a head
+                "gdn_a_log": jnp.broadcast_to(
+                    jnp.linspace(-6.0, -2.0, Hv, dtype=jnp.float32),
+                    (*lead, Hv)).astype(dtype),
+                "gdn_dt_bias": jnp.ones((*lead, Hv), dtype),
+                "gdn_norm": jnp.ones((*lead, dv), dtype),
+                "gdn_out": w(next(keys), (*lead, Hv * dv, H)),
+            }
+        gate = 2 if cfg.attn_output_gate else 1
         return {
-            "wq": w(next(keys), (*lead, H, Hq * D)),
+            "wq": w(next(keys), (*lead, H, gate * Hq * D)),
             "wk": w(next(keys), (*lead, H, Hkv * D)),
             "wv": w(next(keys), (*lead, H, Hkv * D)),
             "wo": w(next(keys), (*lead, Hq * D, H)),
-            "q_norm": jnp.ones((*lead, D), dtype),
-            "k_norm": jnp.ones((*lead, D), dtype),
+            "q_norm": unit((*lead, D)),
+            "k_norm": unit((*lead, D)),
         }
+
+    def unit(shape):  # a norm weight that multiplies by one
+        return (jnp.zeros if cfg.rms_norm_offset else jnp.ones)(shape, dtype)
 
     def norms(lead):
         return {
-            "input_norm": jnp.ones((*lead, H), dtype),
-            "post_norm": jnp.ones((*lead, H), dtype),
+            "input_norm": unit((*lead, H)),
+            "post_norm": unit((*lead, H)),
         }
 
     Il = cfg.lead_intermediate_size
@@ -153,26 +174,34 @@ def _init_conv_trunk(cfg: ModelConfig, keys, w, dtype) -> Params:
         for kind in cfg.lead_pattern or ()
     )
     L = cfg.num_layers - len(lead)
+    Eh, kind = cfg.experts_held, cfg.state_kind
     layers: Dict[str, Any] = {
         **norms((L,)),
         "router": w(next(keys), (L, H, E)),
-        "w_gate": w(next(keys), (L, E, H, I)),
-        "w_up": w(next(keys), (L, E, H, I)),
-        "w_down": w(next(keys), (L, E, I, H)),
+        "w_gate": w(next(keys), (L, Eh, H, I)),
+        "w_up": w(next(keys), (L, Eh, H, I)),
+        "w_down": w(next(keys), (L, Eh, I, H)),
         "attn": operator(
             "full", (cfg.kind_layers("full") - cfg.lead_kind_layers("full"),)
         ),
-        "conv": operator(
-            "conv", (cfg.kind_layers("conv") - cfg.lead_kind_layers("conv"),)
+        kind: operator(
+            kind, (cfg.kind_layers(kind) - cfg.lead_kind_layers(kind),)
         ),
     }
     if cfg.router_bias:
         layers["router_bias"] = w(next(keys), (L, E), scale=0.1)
+    if cfg.num_shared_experts:
+        Is = I * cfg.num_shared_experts
+        layers["ws_gate"] = w(next(keys), (L, H, Is))
+        layers["ws_up"] = w(next(keys), (L, H, Is))
+        layers["ws_down"] = w(next(keys), (L, Is, H))
+        if cfg.shared_expert_gate:
+            layers["ws_router"] = w(next(keys), (L, H, 1))
     params: Params = {
         "embed": w(next(keys), (cfg.vocab_size, H), scale=0.02),
         "layers": layers,
         "lead": lead,
-        "final_norm": jnp.ones((H,), dtype),
+        "final_norm": unit((H,)),
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w(next(keys), (H, cfg.vocab_size))
@@ -395,7 +424,12 @@ def _shared_experts(lp: Params, xf: jax.Array, cfg: ModelConfig):
     if "ws_gate" not in lp:
         return 0
     gate = _activate(xf @ mat(lp["ws_gate"]), cfg.hidden_act)
-    return (gate * (xf @ mat(lp["ws_up"]))) @ mat(lp["ws_down"])
+    out = (gate * (xf @ mat(lp["ws_up"]))) @ mat(lp["ws_down"])
+    if "ws_router" in lp:  # qwen3_next: the shared expert has a gate
+        score = jnp.dot(
+            xf, lp["ws_router"], preferred_element_type=jnp.float32)
+        out = out * jax.nn.sigmoid(score).astype(out.dtype)
+    return out
 
 
 def _moe_capacity(cfg: ModelConfig, N: int) -> int:
@@ -733,6 +767,74 @@ def _conv_operator(
     return (c * mixed) @ mat(lp["conv_out"]), kv_pages
 
 
+# A delta callback is the convolution callback of a trunk with gated
+# delta-rule layers.  It receives (u [B, T, C], taps [4, C], g [B, T, Hv],
+# beta [B, T, Hv], kv_pages, layer): the rows of ``[q | k | v]`` before
+# their convolution, one layer's filter, the log-decay and the step size a
+# value head in float32, the cache with the lanes' state on it
+# (kv_cache.DeltaKV) and the layer's index among the linear layers.  It
+# returns (o [B, T, Hv, dv] float32, kv_pages): every row's read of its
+# head's state after the row's own update, the state left for the next
+# step.  Only the steps that carry that state have one (step.py).
+DeltaFn = Callable[..., Tuple[jax.Array, Any]]
+
+
+def delta_columns(heads: int, widths: Tuple[int, ...]):
+    """The columns of a published projection that reads a head at a time
+    ``[part_0 | part_1 | ..]`` (``widths`` of the parts within one head;
+    ``W_qkvz``: ``(dk, dk, r dv, r dv)`` a key head, ``W_ba``: ``(r, r)``, the
+    query projection's ``[query | gate]``: ``(D, D)`` a query head), in the
+    order the tree keeps them: every head's ``part_0``, then every head's
+    ``part_1``, ...  ``w[:, delta_columns(..)]`` is a loader's one step."""
+    import numpy as np
+
+    per = sum(widths)
+    starts = np.cumsum((0, *widths[:-1]))
+    return np.concatenate([
+        (np.arange(heads)[:, None] * per + s0 + np.arange(w)[None, :]).reshape(-1)
+        for s0, w in zip(starts, widths)
+    ])
+
+
+def _gated_delta_operator(
+    lp: Params, h: jax.Array, cfg: ModelConfig, delta_fn: Optional[DeltaFn],
+    kv_pages, layer,
+) -> Tuple[jax.Array, Any]:
+    """The gated delta rule (qwen3_next): ``[q | k | v | z] = W_qkvz h``,
+    ``[b | a] = W_ba h``; ``beta = sigmoid(b)``, ``g = -exp(A_log)
+    softplus(a + dt_bias)`` in float32; ``delta_fn`` runs the convolution
+    over ``[q | k | v]`` and the recurrence; ``W_o (w rms(o) silu(z))``
+    with the norm over a head's values.  The tree holds both projections'
+    columns in that order, every key head's ``q`` first (value head ``i r +
+    j`` is key head ``i``'s ``j``-th): the published tensors interleave
+    them a key head at a time, which a loader undoes once
+    (``delta_columns``), where a split of every step's rows a head at a
+    time has XLA copy the layers' weights into a layout of its own (432 MB
+    beside a step at Qwen3-Next's cut, compiled for a described v5e)."""
+    if delta_fn is None:
+        from .kv_cache import refuse
+
+        refuse(cfg, "classic_step")
+    B, T, _ = h.shape
+    Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    C = 2 * Hk * dk + Hv * dv
+    qkvz = h @ mat(lp["gdn_in"])
+    u, z = qkvz[..., :C], qkvz[..., C:]
+    ba = (h @ mat(lp["gdn_ba"])).astype(jnp.float32)
+    b, a = ba[..., :Hv], ba[..., Hv:]
+    beta = jax.nn.sigmoid(b)
+    g = -jnp.exp(lp["gdn_a_log"].astype(jnp.float32)) * jax.nn.softplus(
+        a + lp["gdn_dt_bias"].astype(jnp.float32)
+    )
+    o, kv_pages = delta_fn(u, lp["gdn_taps"], g, beta, kv_pages, layer)
+    o = rms_norm(o, lp["gdn_norm"], cfg.rms_norm_eps).astype(h.dtype)
+    o = o.astype(jnp.float32) * jax.nn.silu(
+        z.reshape(B, T, Hv, dv).astype(jnp.float32)
+    )
+    return o.astype(h.dtype).reshape(B, T, Hv * dv) @ mat(lp["gdn_out"]), kv_pages
+
+
 def transformer_layer(
     lp: Params,
     x: jax.Array,  # [B, T, H]
@@ -773,6 +875,11 @@ def transformer_layer(
             lp, h, cfg, conv_fn, kv_pages, layer_in_cache
         )
         x = x + op
+    elif kind == "linear":
+        op, kv_pages = _gated_delta_operator(
+            lp, h, cfg, conv_fn, kv_pages, layer_in_cache
+        )
+        x = x + op
     elif cfg.is_mla:
         attn, kv_pages = _latent_attention(
             lp, h, cos, sin, cfg, attn_fn, kv_pages, layer, q_factor
@@ -786,20 +893,35 @@ def transformer_layer(
             q = q + lp["bq"]
             k = k + lp["bk"]
             v = v + lp["bv"]
+        gate = None
+        if cfg.attn_output_gate:  # [every head's query | every head's gate]
+            q, gate = jnp.split(q, 2, axis=-1)
+            gate = gate.reshape(B, T, cfg.num_heads, D)
         q = q.reshape(B, T, cfg.num_heads, D)
         k = k.reshape(B, T, cfg.num_kv_heads, D)
         v = v.reshape(B, T, cfg.num_kv_heads, D)
         if cfg.qk_norm:  # Qwen3, LFM2: per-head RMSNorm before RoPE
-            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
-            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps, cfg.rms_norm_offset)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps, cfg.rms_norm_offset)
+        R = cfg.rope_dim
+        if R < D:  # partial_rotary_factor: the head's first columns turn
+            q = jnp.concatenate(
+                [apply_rope(q[..., :R], cos, sin), q[..., R:]], axis=-1)
+            k = jnp.concatenate(
+                [apply_rope(k[..., :R], cos, sin), k[..., R:]], axis=-1)
+        else:
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
         if cfg.kv_head_pack > 1:
             attn, kv_pages = _packed_heads_attention(
                 q, k, v, cfg, attn_fn, kv_pages, layer_in_cache
             )
         else:
             attn, kv_pages = attn_fn(q, k, v, kv_pages, layer_in_cache)
+        if gate is not None:
+            attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+                attn.dtype
+            )
         x = x + attn.reshape(B, T, cfg.num_heads * D) @ mat(lp["wo"])
     h2 = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps, cfg.rms_norm_offset)
     # a layer in front of the periods may have a dense MLP in a routed
@@ -877,9 +999,11 @@ def scan_layers(
         )
 
     ops: Dict[str, Params] = {}
-    if cfg.has_conv:
-        ops = {"full": lp_stack["attn"], "conv": lp_stack["conv"]}
-        lp_stack = {k: v for k, v in lp_stack.items() if k not in ("attn", "conv")}
+    if cfg.state_kind:
+        ops = {"full": lp_stack["attn"], cfg.state_kind: lp_stack[cfg.state_kind]}
+        lp_stack = {
+            k: v for k, v in lp_stack.items() if k not in ("attn", cfg.state_kind)
+        }
 
     def period(carry, first):
         # a layer's weights are sliced out of the whole stack by its own
@@ -969,9 +1093,10 @@ def transformer(
         ropes = {
             kind: rope_cos_sin(positions, cfg.rope_dim, *cfg.kind_rope(kind))
             for kind in sorted(set(cfg.layer_pattern + (cfg.lead_pattern or ())))
-            if kind != "conv"
+            if kind not in ("conv", "linear")
         }
-        ropes["conv"] = (None, None)  # a convolution layer rotates nothing
+        # a convolution layer, a delta-rule layer rotate nothing
+        ropes["conv"] = ropes["linear"] = (None, None)
         cos = sin = None
     q_factor = None
     if cfg.query_pos_scaling is not None:

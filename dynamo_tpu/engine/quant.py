@@ -39,6 +39,8 @@ QUANT_KEYS = (
     # the gated short convolution's two projections (lfm2_moe): the operator
     # of most of such a trunk's layers, streamed every step like the rest
     "conv_in", "conv_out",
+    # the gated delta rule's two wide projections (qwen3_next), likewise
+    "gdn_in", "gdn_out",
 )
 
 
@@ -123,10 +125,11 @@ def quantize_params(params: Params, cfg) -> Params:
     """Quantize the streaming-dominant weights of an assembled params tree
     (one-time, on device).  A trunk whose kinds differ in operator keeps
     each kind's operator stacked under its own key (``layers.attn``,
-    ``layers.conv``) and its leading layers singly under ``lead``."""
+    ``layers.conv`` / ``layers.linear``) and its leading layers singly under
+    ``lead``."""
     out = dict(params)
     layers = _quantize_known(params["layers"], cfg.dtype)
-    for kind in ("attn", "conv"):
+    for kind in ("attn", "conv", "linear"):
         if kind in layers:
             layers[kind] = _quantize_known(layers[kind], cfg.dtype)
     out["layers"] = layers

@@ -200,6 +200,12 @@ class SeqState:
     # was shortened because the registry held the whole prompt
     state_page: Optional[int] = None
     state_walked_back: bool = False
+    # a trunk with gated delta-rule layers: the block whose snapshot the
+    # admission resumes from, held in the slot table until the lane's first
+    # chunk is dispatched (None: none, or restored already); the tokens of
+    # the hit behind that block, computed again
+    state_block: Optional[int] = None
+    state_recompute: int = 0
     # echo+logprobs: top-N prompt logprobs to compute at first prefill
     prompt_logprobs: Optional[int] = None
     prompt_lp_sent: bool = False
@@ -369,6 +375,9 @@ class Scheduler:
         self.window_allocator = window_allocator
         # set by an engine whose trunk has convolution layers
         self.conv_state = False
+        # set by an engine whose trunk has gated delta-rule layers: the
+        # table of the snapshot pool (kv_cache.StateSlots)
+        self.state_slots: Optional[Any] = None
         self.window = int(window)
         self.window_released = 0  # window pages let go behind the window
         self.block_size = cfg.block_size or cfg.page_size
@@ -625,6 +634,8 @@ class Scheduler:
         self._queue_prompt_registrations(seq)
         if self.conv_state:
             self._note_state_admission(seq)
+        if self.state_slots is not None:
+            self._note_snapshot_admission(seq)
         if not seq.awaiting_kv:
             plan.prefills.append((seq, len(seq.prompt)))
         # awaiting_kv lanes hold their pages and stay device-inactive
@@ -648,6 +659,53 @@ class Scheduler:
             (m.state_restores if n else m.state_resets).inc()
             if seq.state_walked_back:
                 m.state_walkbacks.inc()
+
+    def _note_snapshot_admission(self, seq: SeqState) -> None:
+        """Where an admission's delta-rule layers start: empty at position
+        0, or from the snapshot ``_match_prefix`` walked the hit back to."""
+        m = self.metrics
+        if m is None or m.state_restores is None:
+            return
+        (m.state_restores if seq.state_block is not None else m.state_resets).inc()
+        if seq.state_recompute:
+            m.state_walkbacks.inc()
+            m.snapshot_recompute_tokens.inc(seq.state_recompute)
+
+    def state_plan(self, chunks: List[MixedChunk]) -> np.ndarray:
+        """What a packed dispatch of ``chunks`` does with the snapshot pool
+        (``kv_cache.DeltaKV.plan``, ``[3, B]``: the slot a lane restores
+        from, the slot it writes a snapshot to, the position it is taken
+        at).  A lane restores in the first chunk after its hit; a
+        prefilling lane takes a snapshot where its chunk ends on a block,
+        and at the last whole block of its prompt (the deepest a later hit
+        can reach: a token is always left to compute).  A snapshot a block
+        already has is used, not taken again."""
+        slots, bs = self.state_slots, self.block_size
+        plan = np.full((3, self.cfg.max_batch_size), -1, np.int32)
+        for ch in chunks:
+            seq, b = ch.seq, ch.seq.slot
+            if seq.state_block is not None:
+                plan[0, b] = slots.slot_of(seq.state_block)
+                slots.release(seq.state_block)
+                seq.state_block = None
+            elif ch.start and ch.start == seq.cached_prompt_tokens:
+                raise RuntimeError(
+                    f"lane {b} resumes at {ch.start} and no snapshot is held "
+                    "for it: the delta-rule layers have no state to start from"
+                )
+            if seq.blocks is None:
+                continue
+            at = ch.start + ch.length
+            if ch.final:
+                at = (len(seq.prompt) - 1) // bs * bs
+            if at <= ch.start or at % bs:
+                continue
+            slot = slots.take(seq.blocks.sequence_hashes()[at // bs - 1])
+            if slot is not None:
+                plan[1, b], plan[2, b] = slot, at
+                if self.metrics is not None and self.metrics.state_snapshots:
+                    self.metrics.state_snapshots.inc()
+        return plan
 
     def predicted_pages(self, seq: SeqState) -> int:
         """Predicted peak KV pages for a request under the budget model, in
@@ -849,6 +907,16 @@ class Scheduler:
             )
         if self.wpool is not None:
             matched = matched[: self._window_tail_boundary(hashes, len(matched))]
+        if self.state_slots is not None:
+            # the hit walks back to the deepest block that has a live
+            # snapshot, as if the prompt had matched no further: the rows
+            # behind it pass through every layer again, on pages of the
+            # lane's own
+            keep = next(
+                (i for i in range(len(matched), 0, -1)
+                 if matched[i - 1].sequence_hash in self.state_slots), 0)
+            seq.state_recompute = (len(matched) - keep) * self.block_size
+            matched = matched[:keep]
         pages: List[int] = []
         for blk in matched:
             got = self.pool.acquire(blk.sequence_hash)
@@ -857,6 +925,9 @@ class Scheduler:
             seq.held_blocks.append(blk.sequence_hash)
             pages.extend(blk.pages)
         n_matched = len(seq.held_blocks)
+        if self.state_slots is not None and n_matched:
+            seq.state_block = seq.held_blocks[-1]
+            self.state_slots.hold(seq.state_block)
         if self.wpool is not None:
             # the window layers' share of the hit: the blocks that hold the
             # last ``window - 1`` tokens before the boundary, and no others
@@ -972,7 +1043,13 @@ class Scheduler:
             self.growth_version += 1
         return True
 
+    def _drop_state_hold(self, seq: SeqState) -> None:
+        if seq.state_block is not None:
+            self.state_slots.release(seq.state_block)
+            seq.state_block = None
+
     def _unmatch_prefix(self, seq: SeqState) -> None:
+        self._drop_state_hold(seq)
         for h in seq.held_blocks:
             self.pool.release(h)
         seq.held_blocks = []
@@ -1170,6 +1247,7 @@ class Scheduler:
     def _release_slot(self, seq: SeqState) -> None:
         seq.prefilling = False
         seq.prefilled_tokens = 0
+        self._drop_state_hold(seq)
         if seq.slot >= 0:
             b = seq.slot
             self.slots[b] = None

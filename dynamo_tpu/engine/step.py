@@ -91,6 +91,11 @@ def prefill_step(
     return lm_logits(params, cfg, hidden_last), kv_pages
 
 
+def _state_fn(cfg: ModelConfig, conv_fn, delta_fn):
+    """The callback of a trunk's layers that hold state, by their kind."""
+    return {"conv": conv_fn, "linear": delta_fn}.get(cfg.state_kind)
+
+
 def _decode_once(
     params: Params,
     cfg: ModelConfig,
@@ -122,6 +127,16 @@ def _decode_once(
         )
         return out[:, None], kv
 
+    def delta_fn(u, taps, g, beta, kv, layer):
+        if active is None:
+            from .kv_cache import refuse
+
+            refuse(cfg, "unmasked_decode_step")
+        out, kv = att.decode_delta_mix(
+            cfg, u[:, 0], taps, g[:, 0], beta[:, 0], kv, layer, active
+        )
+        return out[:, None], kv
+
     def attn_fn(q, k, v, kv, layer, kind=None):
         # q/k/v arrive [B, 1, H, D]; squeeze the singleton time axis.
         q1, k1, v1 = q[:, 0], k[:, 0], v[:, 0]
@@ -138,7 +153,7 @@ def _decode_once(
     hidden, kv_pages, *reach = transformer(
         params, cfg, tokens, positions, kv_pages, attn_fn,
         row_valid=active if grouped else None,
-        conv_fn=conv_fn if cfg.has_conv else None,
+        conv_fn=_state_fn(cfg, conv_fn, delta_fn),
         count_reached=count_reached,
     )
     return (lm_logits(params, cfg, hidden), kv_pages, *reach)
@@ -570,9 +585,16 @@ def _packed_unified_step(
         )
         return out[None], kv
 
+    def delta_fn(u, taps, g, beta, kv, layer):
+        out, kv = att.packed_delta_mix(
+            cfg, u[0], taps, g[0], beta[0], kv, layer, base, seg_off, q_lens,
+            t_lane, t_rel,
+        )
+        return out[None], kv
+
     hidden, kv_pages, *reach = transformer(
         params, cfg, tok_flat[None], positions[None], kv_pages, attn_fn,
-        row_valid=valid[None], conv_fn=conv_fn if cfg.has_conv else None,
+        row_valid=valid[None], conv_fn=_state_fn(cfg, conv_fn, delta_fn),
         count_reached=moe_counts_reached(params, cfg, Np),
     )
     if s_spec > 0:

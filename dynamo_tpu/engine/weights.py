@@ -86,6 +86,16 @@ def assemble_params(
             "and no tensor names are mapped onto it yet; the benchmark "
             "serves it with weights drawn from a seed (benchmark/weights_lfm2.py)"
         )
+    if cfg.has_linear:
+        raise ValueError(
+            "loading a checkpoint of a trunk with gated delta-rule layers "
+            "(model_type 'qwen3_next') is not implemented: its tree keeps each "
+            "kind's operator apart from the layers' stack (model.scan_layers), "
+            "the projections that interleave their parts a head at a time "
+            "part by part (model.delta_columns), and no tensor names are "
+            "mapped onto it yet; the benchmark serves it with weights drawn "
+            "from a seed (benchmark/weights_qwen3next.py)"
+        )
     L = cfg.num_layers
 
     def get(name: str) -> np.ndarray:

@@ -668,8 +668,10 @@ def _work_list_kernel(
         (``flat``: the one-row tile's) or a token a row of heads."""
         pool, slots = (kv_flat, kflat) if flat else (kv_hbm, kbuf)
         n = pool.shape[3]
+        # (``kv_flat`` may hold this layer alone: ``_pages_as_matrices``)
+        at = layer if pool.shape[0] == kv_hbm.shape[0] else 0
         return pltpu.make_async_copy(
-            pool.at[layer, :, pid], slots.at[slot, :, pl.ds(j * n, n)],
+            pool.at[at, :, pid], slots.at[slot, :, pl.ds(j * n, n)],
             sem_kv.at[slot],
         )
 
@@ -964,9 +966,26 @@ def _work_list_launch(
         lane, row0, pos0, rows,
         # the pool twice: as it is, and a page as one matrix, its tokens' kv
         # heads row after row (the same bytes: no copy is made)
-        q, kv_pages, kv_pages.reshape(L, 2, num_pages, page * Hkv, D),
+        q, kv_pages, _pages_as_matrices(kv_pages, layer),
         jnp.zeros((Np, Hq, D), q.dtype),
     )
+
+
+def _pages_as_matrices(kv_pages, layer):
+    """The pool with a page as one matrix ``[page * Hkv, D]``.  Where a row
+    of heads is one tile of lanes wide (``D`` 128) or whole sublane tiles
+    high, those are the pool's own bytes and XLA makes no copy.  A pool of a
+    few heads wider than the lanes (2 heads of 256) is laid out a head's two
+    lane tiles side by side, the matrix wants them a row apart, and the
+    reshape is a copy: then of this layer's pages alone, which the kernel
+    finds at layer 0 (a third of the pool a launch at Qwen3-Next's cut, and
+    still 3.9 ms: PERF.md section 7)."""
+    L, _, num_pages, page, Hkv, D = kv_pages.shape
+    if D <= 128 or Hkv % 8 == 0 or L == 1:
+        return kv_pages.reshape(L, 2, num_pages, page * Hkv, D)
+    one = jax.lax.dynamic_index_in_dim(
+        kv_pages, jnp.clip(jnp.asarray(layer, jnp.int32), 0, L - 1), 0, True)
+    return one.reshape(1, 2, num_pages, page * Hkv, D)
 
 
 def _packed_work_list_attention(

@@ -435,6 +435,11 @@ class EngineMetrics:
         self.state_restores: Optional[Counter] = None
         self.state_resets: Optional[Counter] = None
         self.state_walkbacks: Optional[Counter] = None
+        # a trunk with gated delta-rule layers (mint_delta_state)
+        self.state_snapshots: Optional[Counter] = None
+        self.snapshot_recompute_tokens: Optional[Counter] = None
+        self._snapshot_slots: Optional[Tuple[Counter, Gauge]] = None
+        self._snapshot_evictions = 0
         self._window_released = 0
 
     # -- update points (cheap; called per tick / per commit, not per token)
@@ -545,33 +550,85 @@ class EngineMetrics:
             released_total.inc(released - self._window_released)
             self._window_released = released
 
+    def _mint_state_admissions(self, layers: str, restores: str, walkbacks: str):
+        """How each admission found the state of its ``layers``: the three
+        counters both kinds of trunk with state beside pages have."""
+        reg = self.registry
+        self.state_restores = reg.counter("dynamo_engine_state_restores", restores)
+        self.state_resets = reg.counter(
+            "dynamo_engine_state_resets",
+            f"Admissions at position 0: the {layers} start empty",
+        )
+        self.state_walkbacks = reg.counter(
+            "dynamo_engine_state_walkbacks", walkbacks)
+
+    def _mint_state_bytes(self, state_bytes: Dict[str, int], parts: str) -> None:
+        gauge = self.registry.gauge("dynamo_engine_state_bytes", parts, ["part"])
+        for part, n in state_bytes.items():
+            gauge.labels(part).set(n)
+
     def mint_conv_state(self, state_bytes: Dict[str, int]) -> None:
         """The families of a trunk with convolution layers (minted by the
         engine that serves one, at construction): how each admission found
         its convolution layers' state, and the bytes the state holds."""
-        reg = self.registry
-        self.state_restores = reg.counter(
-            "dynamo_engine_state_restores",
+        self._mint_state_admissions(
+            "convolution layers",
             "Admissions whose convolution layers resumed from the snapshot "
             "of the page their prefix hit ends on",
-        )
-        self.state_resets = reg.counter(
-            "dynamo_engine_state_resets",
-            "Admissions at position 0: the convolution layers start empty",
-        )
-        self.state_walkbacks = reg.counter(
-            "dynamo_engine_state_walkbacks",
             "Prefix hits shortened by a block because the whole prompt was "
             "cached (a snapshot exists at page ends only)",
         )
-        gauge = reg.gauge(
-            "dynamo_engine_state_bytes",
+        self._mint_state_bytes(
+            state_bytes,
             "Bytes of the convolution layers' state (part: lanes, the rows "
             "a lane carries | pages, the snapshots that ride the pages)",
-            ["part"],
         )
-        for part, n in state_bytes.items():
-            gauge.labels(part).set(n)
+
+    def mint_delta_state(self, state_bytes: Dict[str, int]) -> None:
+        """The families of a trunk with gated delta-rule layers: how each
+        admission found its state (the three counters a trunk with
+        convolution layers has), the snapshot pool's traffic, and the bytes
+        the state holds."""
+        reg = self.registry
+        self._mint_state_admissions(
+            "delta-rule layers",
+            "Admissions whose delta-rule layers resumed from a snapshot slot",
+            "Prefix hits walked back to a shallower block because the "
+            "deepest matched block had no live snapshot",
+        )
+        self.state_snapshots = reg.counter(
+            "dynamo_engine_state_snapshots",
+            "Snapshots a packed dispatch wrote into a slot",
+        )
+        self.snapshot_recompute_tokens = reg.counter(
+            "dynamo_engine_state_snapshot_recompute_tokens",
+            "Tokens of prefix hits behind the snapshot they resumed from, "
+            "computed again",
+        )
+        self._snapshot_slots = (
+            reg.counter(
+                "dynamo_engine_state_snapshot_evictions",
+                "Snapshots that gave their slot to a newer one",
+            ),
+            reg.gauge(
+                "dynamo_engine_state_snapshot_slots_in_use",
+                "Slots of the snapshot pool that hold a snapshot",
+            ),
+        )
+        self._mint_state_bytes(
+            state_bytes,
+            "Bytes of the delta-rule layers' state (part: lanes, what a "
+            "lane carries | slots, the snapshot pool)",
+        )
+
+    def observe_snapshot_slots(self, in_use: int, evictions: int) -> None:
+        if self._snapshot_slots is None:
+            return
+        evicted, gauge = self._snapshot_slots
+        gauge.set(in_use)
+        if evictions > self._snapshot_evictions:
+            evicted.inc(evictions - self._snapshot_evictions)
+            self._snapshot_evictions = evictions
 
     def observe_executable_shapes(self, n: int) -> None:
         self.executable_shapes.set(n)

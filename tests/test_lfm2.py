@@ -623,6 +623,9 @@ def _packed_operands(mc, B=4, Np=32, P=8, pages=16):
 PARENT_EQUATIONS = {
     "mistral-7b": 913, "mixtral-8x7b": 1043,
     "mistral-small-4-119b": 1427, "mellum2-12b-a2.5b": 3667,
+    # this family's own, counted on the parent of PR 53 (003b1f2), which
+    # brought a second kind of layer that holds state
+    "lfm2-8b-a1b": 4213,
 }
 
 
