@@ -946,12 +946,17 @@ def latent_packed_times(args, jax) -> None:
 
 def work_list_item_times(args, jax) -> None:
     """The pair pools' work-list kernel alone, by what a launch costs an
-    item: the decode launch (one item a lane) at four cells' lanes, contexts
+    item: the decode launch (one item a lane) at five cells' lanes, contexts
     and heads, then the packed launch with a chunk's wide tiles ahead of the
-    decode rows.  Milliseconds a launch on the device (``reps`` launches
-    chained in one executable, each reading the one before), microseconds an
-    item, and the least an item's K and V bytes could take.  Every launch is
-    compared with the XLA gather over the same pool."""
+    decode rows.  A case may name its own query heads and head width (the
+    last two cases: Qwen3-Next's 16 heads of 256 over 2, whose one-row tile
+    reads the pool a token a row of heads).  Milliseconds a launch on the
+    device (``reps`` launches chained in one executable, each reading the one
+    before; what does not depend on the one before, as the parent's relayout
+    of a layer's pages, XLA hoists out of the chain and is not in the
+    reading), microseconds an item, and the least an item's K and V bytes
+    could take.  Every launch is compared with the XLA gather over the same
+    pool."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -961,13 +966,16 @@ def work_list_item_times(args, jax) -> None:
         decode_work_list_attention, packed_ragged_attention,
     )
 
-    Hq, D, page = (4, 128, 8) if args.rehearse else (32, 128, 16)
+    *cell_heads, page = (4, 128, 8) if args.rehearse else (32, 128, 16)
     if args.rehearse:
         dt, reps, interp, pages = jnp.float32, 2, True, 256
-        # (name, lanes, context, kv heads, window, table width, chunk rows)
+        # (name, lanes, context, kv heads, window, table width, chunk rows[,
+        # query heads, head width])
         cases = [("decode 4x300", 4, 300, 2, 0, 64, 0),
                  ("decode 4x300 window 128", 4, 300, 2, 128, 64, 0),
-                 ("packed 28 + 3 rows", 4, 300, 2, 0, 64, 28)]
+                 ("packed 28 + 3 rows", 4, 300, 2, 0, 64, 28),
+                 ("decode 4x300 wide heads", 4, 300, 2, 0, 64, 0, 8, 256),
+                 ("packed 29 + 3 rows wide heads", 4, 300, 2, 0, 64, 29, 8, 256)]
     else:
         dt, reps, interp, pages = jnp.bfloat16, 100, False, 6144
         cases = [
@@ -981,17 +989,22 @@ def work_list_item_times(args, jax) -> None:
             # docqa-open's chunk steps launch them
             ("mistral-7b 512 rows", 16, 3500, 8, 4096, 528, 496),
             ("mistral-7b 1024 rows", 16, 3500, 8, 4096, 528, 1008),
+            # longsessions-open: a decode step's launch, and a chunk step's
+            ("qwen3-next", 16, 20000, 2, 0, 2112, 0, 16, 256),
+            ("qwen3-next 2048 rows", 16, 20000, 2, 0, 2112, 2033, 16, 256),
         ]
     peak = device_peak(args, jax)
     tol = TOLERANCE["float32" if args.rehearse else "bfloat16"]
     key = jax.random.PRNGKey(args.seed)
     table_out = []
-    for name, B, ctx, Hkv, window, P, chunk in cases:
+    for name, B, ctx, Hkv, window, P, chunk, *heads in cases:
+        Hq, D = heads or cell_heads
         # every lane owns its pages; lanes' contexts differ by a few tokens
         need = -(-(ctx + 1) // page)
-        assert 1 + B * need <= pages and need <= P, name
+        assert need <= P, name
         pool = jax.random.normal(
-            jax.random.fold_in(key, Hkv), (2, 2, pages, page, Hkv, D), dt)
+            jax.random.fold_in(key, Hkv),
+            (2, 2, max(pages, 1 + B * need), page, Hkv, D), dt)
         table = np.zeros((B, P), np.int32)
         table[:, :need] = 1 + np.arange(B * need).reshape(B, need)
         table = jnp.asarray(table)
@@ -1002,11 +1015,14 @@ def work_list_item_times(args, jax) -> None:
             q_lens[0] = chunk  # the chunk first, the decode rows behind it
             base = lens - q_lens
             off = np.concatenate([[0], np.cumsum(q_lens)[:-1]]).astype(np.int32)
-            Np = chunk + B  # one row of padding
+            # the axis is the power of two that holds the rows: a row of
+            # padding, or none (2033 + 15)
+            Np = 1 << (chunk + B - 2).bit_length()
             s_max = Np // 2
-            lane = np.append(np.repeat(np.arange(B), q_lens), B).astype(np.int32)
+            lane = np.repeat(np.arange(B + 1), list(q_lens) + [Np - q_lens.sum()])
+            lane = lane.astype(np.int32)
             rel = np.arange(Np, dtype=np.int32) - off[np.minimum(lane, B - 1)]
-            rel[-1] = 0
+            rel[lane == B] = 0
             q, k, v = (
                 jax.random.normal(jax.random.fold_in(key, i), (Np, h, D), dt) / 16
                 for i, h in ((1, Hq), (2, Hkv), (3, Hkv)))
@@ -1025,7 +1041,8 @@ def work_list_item_times(args, jax) -> None:
 
             # a row is a decode row at its own position: the gather over a
             # sample of the chunk's rows and every decode row
-            valid = np.asarray([0, chunk // 2] + list(range(chunk - 1, Np - 1)))
+            valid = np.asarray(
+                [0, chunk // 2] + list(range(chunk - 1, chunk + B - 1)))
             ref = att.paged_decode_attention(
                 q[valid], index_kv_layer(pool, 1), table[lane[valid], :need],
                 jnp.asarray(base[lane[valid]] + rel[valid] + 1), window)

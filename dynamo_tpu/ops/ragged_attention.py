@@ -37,7 +37,8 @@ What is here:
 * :func:`decode_work_list_attention` -- the decode launch of the fused
   steps over a dense pool of 128-lane heads: the work-list kernel at the
   ``(lanes, 1)`` tile with a fixed list, one item a lane, a query head a
-  row over each key block as it lies in the pool.
+  row over each key block as it lies in the pool (a kv head's rows over
+  that head's keys where the pool has a few heads wider than the lanes).
 
 ``interpret=True`` runs a kernel through the Pallas interpreter
 (CPU-testable); ``engine.attention.packed_ragged_attention_dispatch``
@@ -490,6 +491,18 @@ def _packed_kernel(
 # head loop, no padding rows, at ``Hkv`` times the multiplications, which the
 # MXU has to spare while the block's bytes arrive.
 #
+# That second form is handed over only where it is a view of the pool
+# (:func:`_pages_are_matrices`: heads of 128 lanes, or whole sublane tiles of
+# heads).  A pool of a few heads wider than the lanes (Qwen3-Next's 2 of 256)
+# lies a head's lane tiles side by side, two heads' bf16 in one word; a page
+# as one matrix is then a relayout of the pool in HBM, which XLA made in front
+# of every launch (3.9 ms for a layer's 0.54 GB, three a step: PERF.md, PR 54).
+# Over such a pool the one-row tile reads its key blocks from the pool's own
+# form, a token a row of heads, into slots of the form the several-row body
+# uses, turns a block heads-major as that body does, and multiplies a kv head's
+# own ``n_rep`` query rows with that head's keys, head by head over the few
+# heads: no second form of the pool, no column of another head.
+#
 # A tile never leaves the packed axis: one that would overhang ``Np`` (a
 # decode row in the last packed rows, the tail block of a chunk that fills
 # the axis) starts ``shift`` rows early, at ``Np - copy``, and the item's
@@ -521,6 +534,18 @@ def _takes_work_list(D: int, quant: bool) -> bool:
     grid kernel for an int8 pool, whose row scales it dequantizes in the
     read, and for narrow heads."""
     return not quant and D % 128 == 0
+
+
+def _pages_are_matrices(Hkv: int, D: int) -> bool:
+    """Whether a page seen as one matrix ``[page * Hkv, D]`` is a view of the
+    pool: its own bytes, so that the reshape costs nothing and a page can be
+    copied as it lies into a slot of that form.  True where a token's row of
+    heads is one tile of lanes wide (``D`` 128) or whole sublane tiles high.
+    A few heads wider than the lanes (Qwen3-Next's 2 of 256) lie a head's
+    lane tiles side by side where the matrix wants them a row apart: there
+    the reshape is a relayout of the pool in HBM, and the one-row tile reads
+    the pool's own form instead (:func:`_work_list_kernel`)."""
+    return D <= 128 or Hkv % 8 == 0
 
 
 def _work_list_tiles(s_max: int, dtype):
@@ -564,16 +589,20 @@ def _work_list_kernel(
     # operands (HBM)
     q_hbm,  # [Np, Hq, D]
     kv_hbm,  # [L, 2, num_pages, page, Hkv, D], the fresh rows in it
-    kv_flat,  # the same pool, a page as one matrix [page * Hkv, D]
+    kv_flat,  # the same pool, a page as one matrix [page * Hkv, D], where
+    # that is a view (:func:`_pages_are_matrices`); else None
     _o_init,  # the zeroed output buffer (aliased to o_hbm)
     o_hbm,  # [Np, Hq, D]
-    # scratch: :func:`_work_list_scratch`
+    # scratch: :func:`_work_list_scratch` (None: not held at this launch)
     q_v,  # [rows_t, Hq, D] an item's queries as they lie in HBM
     o_v,  # [rows_t, Hq, D]
     kflat,  # [2, 2, KB * Hkv, D] two slots of a key block as it lies
     slot_ref,  # [1] SMEM: the slot the next item's first key block takes
     sem_q, sem_kv, sem_o,
-    *rows_scratch,  # what tiles of several rows need besides
+    q_t=None,  # [Hkv, M, D] a tile of several rows' queries heads-major
+    kbuf=None,  # [2, 2, KB, Hkv, D] two slots, a token a row of heads
+    kv_t=None, m_scr=None, l_scr=None, acc_scr=None,  # tiles of several rows
+    *,
     tiles,
     window: int,
 ):
@@ -587,12 +616,17 @@ def _work_list_kernel(
     Two bodies, by the item's tile.  A tile of several rows (chunks, verify
     columns) turns a key block heads-major and runs a kv head's group of
     rows against its keys.  The one-row tile (a decode row, alone in the
-    ``(lanes, 1)`` launches or beside a chunk) has a query head a row: it
-    multiplies its ``Hq`` rows with the block as it lies, every kv head's
-    keys side by side as ``KB * Hkv`` columns, and masks the columns of
-    other heads, so that nothing is transposed and no row is padding."""
-    if rows_scratch:
-        q_t, kbuf, kv_t, m_scr, l_scr, acc_scr = rows_scratch
+    ``(lanes, 1)`` launches or beside a chunk) has a query head a row, and
+    the pool's shape says how it reads its keys (:func:`_pages_are_matrices`,
+    at trace time).  Where a page as one matrix is a view of the pool (heads
+    of 128: every pair pool but Qwen3-Next's) it multiplies its ``Hq`` rows
+    with the block as it lies, every kv head's keys side by side as ``KB *
+    Hkv`` columns, and masks the columns of other heads, so that nothing is
+    transposed and no row is padding (``attend_row``).  Where it is not (a
+    few heads wider than the lanes: 2 of 256) there is no such operand: the
+    block comes a token a row of heads, is turned heads-major, and a kv
+    head's own ``n_rep`` rows meet that head's keys, head by head
+    (``attend_row_by_head``)."""
     w = pl.program_id(0)
     W = pl.num_programs(0)
     Np, Hq, D = q_hbm.shape
@@ -603,6 +637,7 @@ def _work_list_kernel(
     n_pg = KB // page
     scale = 1.0 / (D ** 0.5)
     layer = layer_ref[0]
+    as_it_lies = _pages_are_matrices(Hkv, D)  # the one-row tile's key blocks
 
     def item(i):
         """Item ``i`` of the list: ``(lane, first row, that row's position,
@@ -628,9 +663,9 @@ def _work_list_kernel(
     def _clear():
         # a block's dead pages are never fetched: what the slots hold there
         # meets a probability of zero, and must be finite
-        kflat[...] = jnp.zeros(kflat.shape, kflat.dtype)
-        if rows_scratch:
-            kbuf[...] = jnp.zeros(kbuf.shape, kbuf.dtype)
+        for slots in (kflat, kbuf):
+            if slots is not None:
+                slots[...] = jnp.zeros(slots.shape, slots.dtype)
         slot_ref[0] = 0
 
     def on_tile(n, body) -> None:
@@ -668,10 +703,8 @@ def _work_list_kernel(
         (``flat``: the one-row tile's) or a token a row of heads."""
         pool, slots = (kv_flat, kflat) if flat else (kv_hbm, kbuf)
         n = pool.shape[3]
-        # (``kv_flat`` may hold this layer alone: ``_pages_as_matrices``)
-        at = layer if pool.shape[0] == kv_hbm.shape[0] else 0
         return pltpu.make_async_copy(
-            pool.at[at, :, pid], slots.at[slot, :, pl.ds(j * n, n)],
+            pool.at[layer, :, pid], slots.at[slot, :, pl.ds(j * n, n)],
             sem_kv.at[slot],
         )
 
@@ -704,7 +737,7 @@ def _work_list_kernel(
 
         def start(copy, _nrow):
             q_copy(copy, tile_start(copy, row0)).start()
-            fetch(copy == 1, lane, kb_lo, slot, pg_lo, pg_hi)
+            fetch(copy == 1 and as_it_lies, lane, kb_lo, slot, pg_lo, pg_hi)
 
         on_tile(n, start)
 
@@ -795,6 +828,57 @@ def _work_list_kernel(
         ))
         previous_rows_are_out()
         o_v[0] = (acc / l).astype(o_v.dtype)
+        send(copy, row0)
+
+    def attend_row_by_head(copy, _nrow):
+        """The one-row tile where a page is no matrix: the key blocks come a
+        token a row of heads, and a kv head's own ``n_rep`` query rows meet
+        that head's keys alone, head by head over the few heads there are."""
+        dt = q_v.dtype
+        q_copy(copy, row0).wait()
+        # (sliced as float32, whose rows are whole sublanes where n_rep is)
+        q = q_v[0].astype(jnp.float32)  # ``q_v`` is free from here on
+        q_of = [q[h * n_rep:(h + 1) * n_rep].astype(dt) for h in range(Hkv)]
+        at = jax.lax.broadcasted_iota(jnp.int32, (n_rep, KB), 1)
+
+        def compute(kb, slot, carry):
+            kpos = kb * KB + at
+            keep = kpos <= pos0
+            if window > 0:
+                keep = keep & (kpos > pos0 - window)
+            # the block heads-major, as ``attend_rows`` turns it (a head's
+            # rows read one by one out of the slot took six times as long on
+            # the chip; the pool packs two heads' bf16 into one word, so no
+            # copy can write a head's rows apart): [Hkv, KB, D]
+            k, v = (kbuf[slot, side].transpose(1, 0, 2).astype(dt)
+                    for side in range(2))
+            out = []
+            for h, (m_prev, l_prev, acc) in enumerate(carry):
+                s = jax.lax.dot_general(
+                    q_of[h], k[h], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # [n_rep, KB]
+                s = jnp.where(keep, s * scale, _NEG_INF)
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new)
+                pv = jax.lax.dot_general(
+                    p.astype(dt), v[h], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # [n_rep, D]
+                out.append((
+                    m_new, l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                    acc * alpha + pv))
+            return tuple(out)
+
+        heads = walk(False, compute, ((
+            jnp.full((n_rep, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((n_rep, 1), jnp.float32),
+            jnp.zeros((n_rep, D), jnp.float32),
+        ),) * Hkv)
+        previous_rows_are_out()
+        o_v[0] = jnp.concatenate(
+            [acc / l for _, l, acc in heads]).astype(o_v.dtype)
         send(copy, row0)
 
     def attend_rows(copy, nrow):
@@ -895,8 +979,9 @@ def _work_list_kernel(
         def _():
             bring(me, slot_ref[0])
 
+        row = attend_row if as_it_lies else attend_row_by_head
         on_tile(rows, lambda copy, nrow: (
-            attend_row if copy == 1 else attend_rows)(copy, nrow))
+            row if copy == 1 else attend_rows)(copy, nrow))
 
 
 def _key_block(page: int) -> int:
@@ -905,29 +990,38 @@ def _key_block(page: int) -> int:
 
 
 def _work_list_scratch(tiles, Hq, Hkv, D, page, dtype, kv_dtype):
-    """The kernel's scratch at the launch's tiles: the queries' and the
-    output's tile, two slots of a key block as it lies in the pool, the
-    scalar that hands a slot from item to item and the copies' semaphores;
-    where there are tiles of several rows also their queries heads-major,
-    two slots of a key block a token a row of heads, the current block
-    heads-major and the softmax's running state."""
+    """The kernel's scratch at the launch's tiles, in the kernel's order and
+    None where the launch holds none: the queries' and the output's tile;
+    two slots of a key block as it lies in the pool, where a page as one
+    matrix is a view of it (:func:`_pages_are_matrices`: the one-row tile's,
+    else that tile reads the slots below); the scalar that hands a slot from
+    item to item and the copies' semaphores.  Where there are tiles of
+    several rows also their queries heads-major, two slots of a key block a
+    token a row of heads, the current block heads-major and the softmax's
+    running state; of these the ``(lanes, 1)`` launch over a pool that has
+    no such view holds the slots alone."""
     rows_t, KB = tiles[-1][1], _key_block(page)
+    as_it_lies, several = _pages_are_matrices(Hkv, D), len(tiles) > 1
     tile = pltpu.VMEM((rows_t, Hq, D), dtype)
     scratch = [
-        tile, tile, pltpu.VMEM((2, 2, KB * Hkv, D), kv_dtype),
+        tile, tile,
+        pltpu.VMEM((2, 2, KB * Hkv, D), kv_dtype) if as_it_lies else None,
         pltpu.SMEM((1,), jnp.int32), pltpu.SemaphoreType.DMA((1,)),
         pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA((1,)),
     ]
-    if len(tiles) > 1:
+    by_token = pltpu.VMEM((2, 2, KB, Hkv, D), kv_dtype)
+    if several:
         M = Hq // Hkv * rows_t
         scratch += [
             pltpu.VMEM((Hkv, M, D), dtype),
-            pltpu.VMEM((2, 2, KB, Hkv, D), kv_dtype),
+            by_token,
             pltpu.VMEM((2, Hkv, KB, D), dtype),
             pltpu.VMEM((Hkv, M, 1), jnp.float32),
             pltpu.VMEM((Hkv, M, 1), jnp.float32),
             pltpu.VMEM((Hkv, M, D), jnp.float32),
         ]
+    elif not as_it_lies:
+        scratch += [None, by_token]
     return scratch
 
 
@@ -937,14 +1031,19 @@ def _work_list_launch(
 ):
     """One launch of :func:`_work_list_kernel` over the items ``(lane, row0,
     pos0, rows)``, each ``[W]`` int32, at the tiles of ``s_max``: ``[Np, Hq,
-    D]``, zeros in the rows no item owns."""
+    D]``, zeros in the rows no item owns.  The kernel is handed the pool as
+    it is and, where :func:`_pages_are_matrices`, a second time with a page as
+    one matrix for the one-row tile: a reshape that is a view.  Any other
+    pool is handed once, and nothing is made of it in front of the launch."""
     Np, Hq, D = q.shape
     L, _, num_pages, page, Hkv, _ = kv_pages.shape
     _, tiles = _work_list_tiles(s_max, q.dtype)
+    as_it_lies = _pages_are_matrices(Hkv, D)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(lane.shape[0],),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 4,
+        in_specs=[hbm, hbm, hbm if as_it_lies else None, hbm],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=_work_list_scratch(
             tiles, Hq, Hkv, D, page, q.dtype, kv_pages.dtype),
@@ -953,7 +1052,8 @@ def _work_list_launch(
         functools.partial(_work_list_kernel, tiles=tiles, window=window),
         out_shape=jax.ShapeDtypeStruct((Np, Hq, D), q.dtype),
         grid_spec=grid_spec,
-        input_output_aliases={9: 0},  # the zeroed buffer, after 6 scalars
+        # the zeroed buffer, the last operand after 6 scalars
+        input_output_aliases={8 + as_it_lies: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_CAP_BYTES,
@@ -964,28 +1064,13 @@ def _work_list_launch(
         jnp.clip(jnp.asarray(layer, jnp.int32), 0, L - 1).reshape(1),
         jnp.clip(page_table.astype(jnp.int32), 0, num_pages - 1),
         lane, row0, pos0, rows,
-        # the pool twice: as it is, and a page as one matrix, its tokens' kv
-        # heads row after row (the same bytes: no copy is made)
-        q, kv_pages, _pages_as_matrices(kv_pages, layer),
+        # the pool as it is and, where that is a view of it, a second time
+        # with a page as one matrix, its tokens' kv heads row after row (the
+        # same bytes: no copy is made)
+        q, kv_pages,
+        kv_pages.reshape(L, 2, num_pages, page * Hkv, D) if as_it_lies else None,
         jnp.zeros((Np, Hq, D), q.dtype),
     )
-
-
-def _pages_as_matrices(kv_pages, layer):
-    """The pool with a page as one matrix ``[page * Hkv, D]``.  Where a row
-    of heads is one tile of lanes wide (``D`` 128) or whole sublane tiles
-    high, those are the pool's own bytes and XLA makes no copy.  A pool of a
-    few heads wider than the lanes (2 heads of 256) is laid out a head's two
-    lane tiles side by side, the matrix wants them a row apart, and the
-    reshape is a copy: then of this layer's pages alone, which the kernel
-    finds at layer 0 (a third of the pool a launch at Qwen3-Next's cut, and
-    still 3.9 ms: PERF.md section 7)."""
-    L, _, num_pages, page, Hkv, D = kv_pages.shape
-    if D <= 128 or Hkv % 8 == 0 or L == 1:
-        return kv_pages.reshape(L, 2, num_pages, page * Hkv, D)
-    one = jax.lax.dynamic_index_in_dim(
-        kv_pages, jnp.clip(jnp.asarray(layer, jnp.int32), 0, L - 1), 0, True)
-    return one.reshape(1, 2, num_pages, page * Hkv, D)
 
 
 def _packed_work_list_attention(
@@ -1028,14 +1113,15 @@ def decode_work_list_attention(
 ) -> jax.Array:
     """The decode launch of the fused steps over a dense pool of 128-lane
     heads (:func:`_takes_work_list`): the work-list kernel at the ``(lanes,
-    1)`` tile (a query head a row, the key blocks as they lie) with a fixed
-    list, one item a lane that holds a token, from its window's first key
-    block to the block of its own position.  No step
-    for a page group of the table's width (``paged_attention``'s grid), so
-    the table may be as wide as the scheduler's; a lane with ``kv_lens`` 0
-    has no item and its row stays zero.  The launch the ``(lanes, 1)`` packed
-    step makes, under the decode kernel's name: a device trace tells the
-    fused steps' launches from the packed ones by it."""
+    1)`` tile (a query head a row; the key blocks as they lie, or a token a
+    row of heads where a page is no matrix) with a fixed list, one item a
+    lane that holds a token, from its window's first key block to the block
+    of its own position.  No step for a page group of the table's width
+    (``paged_attention``'s grid), so the table may be as wide as the
+    scheduler's; a lane with ``kv_lens`` 0 has no item and its row stays
+    zero.  The launch the ``(lanes, 1)`` packed step makes, under the decode
+    kernel's name: a device trace tells the fused steps' launches from the
+    packed ones by it."""
     lane = jnp.arange(q.shape[0], dtype=jnp.int32)
     lens = kv_lens.astype(jnp.int32)
     return _work_list_launch(

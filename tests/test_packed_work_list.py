@@ -21,7 +21,7 @@ PAGE, D, LAYER = 8, 128, 1
 
 
 def _case(Hq, Hkv, bases, qlens, *, window=0, seed=0, dtype=np.float32,
-          poison=False, s_max=None):
+          poison=False, s_max=None, D=D):
     """A packed dispatch of lanes at ``bases`` bringing ``qlens`` fresh rows:
     every lane owns pages for ``base + q`` positions.  With ``poison`` every
     page wholly behind a lane's window is one shared page of NaN."""
@@ -109,14 +109,42 @@ CASES = {
     # eight query heads a kv head
     "gqa8": dict(Hq=8, Hkv=1, bases=[20, 300], qlens=[40, 1], window=128),
 }
+# Two KV heads of 256, eight query heads a head (Qwen3-Next's): a page as one
+# matrix is no view of such a pool (``ra._pages_are_matrices``), and a one-row
+# item reads its key blocks a token a row of heads, a kv head's own query
+# rows against that head's keys (PR 54).  Contexts cross several key blocks.
+WIDE = dict(Hq=16, Hkv=2, D=256)
+CASES.update({
+    # the (lanes, 1) launch: one-row items alone, an idle lane among them
+    "wide_one_row_items": dict(
+        WIDE, bases=[600, 1500, 0, 0, 3, 511, 512], qlens=[1, 1, 0, 1, 1, 1, 1]),
+    # one-row items behind a 256-row tile (and a 44-row one)
+    "wide_rows_behind_a_256_row_tile": dict(
+        WIDE, bases=[24, 700, 1100, 3], qlens=[300, 1, 1, 1]),
+    # a window: the pages behind it are poisoned
+    "wide_window": dict(
+        WIDE, bases=[1100, 1500, 0, 37], qlens=[130, 1, 1, 1], window=100,
+        poison=True),
+    # lanes with no rows between live ones; the axis ends in padding
+    "wide_idle_lanes": dict(
+        WIDE, bases=[516, 0, 11, 0, 1024], qlens=[1, 0, 5, 0, 1]),
+    # four heads of 256, two query heads a head: the same body
+    "wide_four_heads": dict(
+        Hq=8, Hkv=4, D=256, bases=[20, 600], qlens=[9, 1]),
+})
+# launches of the one-row tile alone: the serial kernel has no such tile
+ONE_ROW_LAUNCHES = ("decode_step", "wide_one_row_items")
 
 
-@pytest.mark.parametrize("name", list(CASES) + ["bf16", "int8_grid"])
+@pytest.mark.parametrize(
+    "name", list(CASES) + ["bf16", "wide_bf16", "int8_grid"])
 def test_packed_work_list_matches_xla(name):
     if name == "int8_grid":
         return _int8_takes_the_grid_kernel()
-    kw = dict(CASES.get(name) or CASES["blocks_beside_decode"])
-    dtype = jnp.bfloat16 if name == "bf16" else np.float32
+    kw = dict(CASES.get(name) or CASES[
+        "wide_rows_behind_a_256_row_tile" if name == "wide_bf16"
+        else "blocks_beside_decode"])
+    dtype = jnp.bfloat16 if name.endswith("bf16") else np.float32
     window = kw.get("window", 0)
     (q, k, v, pool, pt, base, off, lens, lane, rel), s_max, total = _case(
         dtype=dtype, **kw)
@@ -125,13 +153,14 @@ def test_packed_work_list_matches_xla(name):
     ref = np.asarray(ra.packed_ragged_attention_xla(
         q, k, v, clean, pt, base, off, lens, lane, rel, s_max, LAYER, window,
     ).astype(jnp.float32))
-    assert ra._takes_work_list(D, False)
+    assert ra._takes_work_list(q.shape[2], False)
+    assert ra._pages_are_matrices(*k.shape[1:]) is not name.startswith("wide")
     got = np.asarray(ra.packed_ragged_attention(
         q, k, v, _scatter(pool, k, v, pt, base, lane, rel), pt, base, off,
         lens, s_max, LAYER, window, interpret=True,
     ).astype(jnp.float32))
     assert got.shape == q.shape  # [Np, Hq, D]: what the roofline reader keys on
-    tol = 3e-2 if name == "bf16" else 2e-5
+    tol = 3e-2 if name.endswith("bf16") else 2e-5
     np.testing.assert_allclose(got[:total], ref[:total], rtol=tol, atol=tol)
     # rows no lane owns come out as zeros, like the twin's
     assert not got[total:].any() and not ref[total:].any()
@@ -222,7 +251,7 @@ def _int8_takes_the_grid_kernel():
 
 
 def _decode_case(lens, *, window=0, P=1024, Hq=4, Hkv=2, seed=0,
-                 dtype=np.float32, pool_dtype=None):
+                 dtype=np.float32, pool_dtype=None, D=D):
     """Decode lanes holding ``lens`` tokens (the new one's row already in
     the pool) under a table ``P`` pages wide, far wider than any lane's
     pages.  Every entry a lane must not read, past its allocation or wholly
@@ -271,6 +300,14 @@ DECODE_CASES = {
     # in VMEM, as the packed launch over that pool does
     "f32_pool": dict(lens=[1, 513, 700], dtype=jnp.bfloat16,
                      pool_dtype=jnp.float32),
+    # two KV heads of 256, eight query heads a head: the one-row tile over
+    # the pool's own form (PR 54)
+    "wide_edges": dict(
+        WIDE, lens=[0, 1, PAGE, KEY_BLOCK, KEY_BLOCK + 1, 1600, 0, 77]),
+    "wide_window_1024": dict(WIDE, lens=[1024, 1025, 3003, 7, 0], window=1024),
+    "wide_bf16": dict(
+        WIDE, lens=[0, 1, 513, 2050], window=1024, dtype=jnp.bfloat16),
+    "wide_four_heads": dict(Hq=8, Hkv=4, D=256, lens=[520, 9, 0, 1100]),
 }
 
 
@@ -293,6 +330,10 @@ HANDOVER_DECODE = {
         lens=[3003, 1025, 2050, 5000], window=1024),
     # no token in the first, a middle and the last lane
     "no_token_first_middle_last": dict(lens=[0, 300, 0, 513, 40, 0]),
+    # the slots a token a row of heads: items of 2, 3, 1 and 3 blocks, an idle
+    # lane between them, under a window whose first block is not block 0
+    "wide_ends_on_either_slot": dict(WIDE, lens=[600, 1100, 0, 100, 1030]),
+    "wide_window": dict(WIDE, lens=[1500, 0, 1025, 40], window=512),
 }
 
 
@@ -353,7 +394,7 @@ def test_decode_launch_of_a_two_kind_trunk(monkeypatch):
         assert _pallas_calls(
             jax.make_jaxpr(run)(q, kv, table, kv_lens).jaxpr) == [(name, 1)]
         with pltpu.force_tpu_interpret_mode():
-            got, pool, idx = run(q, kv, table, kv_lens)
+            got, pool, idx = jax.block_until_ready(run(q, kv, table, kv_lens))
         assert int(idx) == at
         _assert_decode_rows(
             got, q, pool, table[int(kind == "sliding")], kv_lens, at, window,
@@ -403,7 +444,8 @@ def _serial_order(monkeypatch):
 
 
 @pytest.mark.parametrize(
-    "name", [n for n in list(CASES) + list(FULL_AXIS_CASES) if n != "decode_step"])
+    "name", [n for n in list(CASES) + list(FULL_AXIS_CASES)
+             if n not in ONE_ROW_LAUNCHES])
 def test_overlapped_packed_items_match_the_serial_order_bit_for_bit(
         name, monkeypatch):
     """Items of several rows: what moved is when a copy starts and who waits
@@ -446,24 +488,56 @@ def test_overlapped_decode_items_wait_for_what_they_read(name, tpu_interpreter):
     kw = dict(HANDOVER_DECODE[name])
     window = kw.get("window", 0)
     q, pool, pt, lens = _decode_case(**kw)
-    got = ra.decode_work_list_attention(
-        q, pool, pt, lens, LAYER, window, interpret=params)
+    # (waited for before the gather is traced: the interpreter's callbacks
+    # run JAX operations of their own, and a launch still in flight under a
+    # main thread that compiles has hung a whole run of the tests, PR 54)
+    got = jax.block_until_ready(ra.decode_work_list_attention(
+        q, pool, pt, lens, LAYER, window, interpret=params))
     _assert_decode_rows(got, q, pool, pt, lens, LAYER, window, 2e-5)
     assert not raced()
 
 
 @pytest.mark.parametrize(
     "name", ["blocks_beside_decode", "idle_lanes_and_padding", "verify_columns",
-             "two_chunks", "window", "small_blocks", "wide_tile_then_eight_rows"])
+             "two_chunks", "window", "small_blocks", "wide_tile_then_eight_rows",
+             "wide_rows_behind_a_256_row_tile", "wide_idle_lanes"])
 def test_overlapped_packed_items_wait_for_what_they_read(name, tpu_interpreter):
     """A tile that spans rows of the items before it reads them only once
     they have landed, and the last live item leaves nothing in flight."""
     params, raced = tpu_interpreter
     args, kw = _packed_call(name)
-    want = ra.packed_ragged_attention(*args, interpret=True, **kw)
-    got = ra.packed_ragged_attention(*args, interpret=params, **kw)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # one launch at a time, each waited for (see the decode launches above)
+    want = np.asarray(ra.packed_ragged_attention(*args, interpret=True, **kw))
+    got = np.asarray(ra.packed_ragged_attention(*args, interpret=params, **kw))
+    np.testing.assert_array_equal(got, want)
     assert not raced()
+
+
+@pytest.mark.parametrize("Hkv,D,as_it_lies", [
+    (8, 128, True), (4, 128, True), (1, 128, True), (8, 256, True),
+    (2, 256, False), (4, 256, False)])
+def test_the_pool_picks_the_one_row_body(Hkv, D, as_it_lies):
+    """Read off the pool's shape alone: where a page as one matrix is the
+    pool's own bytes the launch is handed that view and holds the slots of
+    that form; where it is not (a few heads wider than the lanes) it is
+    handed the pool once, makes no second form of it, and holds slots a token
+    a row of heads, at the ``(lanes, 1)`` launch too."""
+    assert ra._pages_are_matrices(Hkv, D) is as_it_lies
+    q, pool, pt, lens = _decode_case([9, 40], P=8, Hq=2 * Hkv, Hkv=Hkv, D=D)
+    jaxpr = jax.make_jaxpr(lambda *a: ra.decode_work_list_attention(
+        *a, LAYER, interpret=False))(q, pool, pt, lens)
+    (call,) = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"]
+    shapes = [v.aval.shape for v in call.invars]
+    flat_page = (2, 2) + (pool.shape[2], PAGE * Hkv, D)
+    assert (flat_page in shapes) is as_it_lies
+    assert shapes.count(pool.shape) == 1
+    KB = ra._key_block(PAGE)
+    scratch = [
+        s.shape for s in ra._work_list_scratch(
+            [(1, 8)], 2 * Hkv, Hkv, D, PAGE, q.dtype, pool.dtype)
+        if hasattr(s, "shape")]
+    assert ((2, 2, KB * Hkv, D) in scratch) is as_it_lies
+    assert ((2, 2, KB, Hkv, D) in scratch) is not as_it_lies
 
 
 def _eqns(jaxpr):
