@@ -1091,15 +1091,18 @@ def work_list_item_times(args, jax) -> None:
 
 def gdn_chunk_times(args, jax) -> None:
     """The gated delta rule's packed layer alone (``attention.
-    packed_delta_mix``, the XLA composition under ``gdn_chunk``) at the
-    shapes of the cell that serves it: 2048 packed rows at Qwen3-Next's
-    widths (32 value heads, state 128 x 128, convolution over 8192 channels),
-    as one segment, as a chunk beside 15 decode rows with a snapshot taken
-    mid-segment, and as 16 decode rows; and the fused steps' one-token update
-    (``decode_delta_mix``).  Milliseconds a layer on the device (``reps``
-    layers chained in one executable, each reading the state the one before
-    left) and the largest difference from the token-by-token recurrence over
-    the same rows."""
+    packed_delta_mix``) at the shapes of the cell that serves it: 2048 packed
+    rows at Qwen3-Next's widths (32 value heads, state 128 x 128, convolution
+    over 8192 channels), as one segment, as a chunk beside 15 decode rows
+    with a snapshot taken mid-segment, and as 16 decode rows; and the fused
+    steps' one-token update (``decode_delta_mix``).  Each packed case twice:
+    the chunks through the launch ``gated_delta_chunks`` (what the chip
+    serves; rehearsed through the interpreter) and through the XLA
+    composition (what a CPU serves, and the launch's reference).
+    Milliseconds a layer on the device (``reps`` layers chained in one
+    executable, each reading the state the one before left) and the largest
+    difference from the token-by-token recurrence over the same rows."""
+    import functools
     import time as _time
 
     import jax.numpy as jnp
@@ -1120,7 +1123,11 @@ def gdn_chunk_times(args, jax) -> None:
     C, S = cfg.linear_conv_width, 4
     rng = np.random.RandomState(args.seed)
     dt = jnp.float32 if args.rehearse else jnp.bfloat16
-    u = jnp.asarray(rng.standard_normal((Np, C)), dt)
+    # rows of their own for each chained layer: the bulk of the convolution
+    # reads nothing of the state, and one executable would compute it once
+    # for all of them
+    us = jnp.asarray(rng.standard_normal((reps, Np, C)), dt)
+    u = us[0]
     taps = jnp.asarray(rng.standard_normal((4, C)) / 2, dt)
     g = -jnp.asarray(rng.uniform(1e-3, 0.1, (Np, Hv)), jnp.float32)
     beta = jnp.asarray(rng.uniform(0.1, 0.9, (Np, Hv)), jnp.float32)
@@ -1137,36 +1144,35 @@ def gdn_chunk_times(args, jax) -> None:
 
     def recurrence(q_lens, seg_off, base, st):
         """Token by token over every live lane's rows, from the lane's
-        state and history (zeros at position 0)."""
-        out = np.zeros((Np, Hv, dv), np.float32)
-        hist = np.asarray(st.conv[0].astype(jnp.float32)).reshape(B, 3, C)
-        w = np.asarray(taps.astype(jnp.float32))
-        uu = np.asarray(u.astype(jnp.float32))
-
-        @jax.jit
-        def lane(S0, x, gg, bb):
-            q, k, v = att._gdn_heads(cfg, x)
-
-            def step(S, t):
-                qt, kt, vt, gt, bt = t
-                S = jnp.exp(gt)[:, None, None] * S
-                d = bt[:, None] * (vt - jnp.einsum("hk,hkv->hv", kt, S, precision="highest"))
-                S = S + kt[:, :, None] * d[:, None, :]
-                return S, jnp.einsum("hk,hkv->hv", qt, S, precision="highest")
-
-            return jax.lax.scan(step, S0, (q, k, v, gg, bb))[1]
-
+        state and history (zeros at position 0), in float64 on the host:
+        what both forms of the chunks are rounded against."""
+        f64 = lambda a: np.asarray(a.astype(jnp.float32), np.float64)  # noqa: E731
+        out = np.zeros((Np, Hv, dv), np.float64)
+        hist = f64(st.conv[0]).reshape(B, 3, C)
+        w, uu, gg, bb = f64(taps), f64(u), f64(g), f64(beta)
         for b in range(B):
             n, o = int(q_lens[b]), int(seg_off[b])
             if not n:
                 continue
             first = int(base[b]) == 0
             rows = np.concatenate(
-                [np.zeros((3, C), np.float32) if first else hist[b], uu[o:o + n]])
+                [np.zeros((3, C)) if first else hist[b], uu[o:o + n]])
             x = sum(w[i] * rows[i:i + n] for i in range(4))
-            x = jnp.asarray(x / (1 + np.exp(-x)), jnp.float32)
-            S0 = jnp.zeros((Hv, dk, dv)) if first else st.lanes[0, b]
-            out[o:o + n] = np.asarray(lane(S0, x, g[o:o + n], beta[o:o + n]))
+            x = x / (1 + np.exp(-x))
+            q, k, v = np.split(x, [Hk * dk, 2 * Hk * dk], axis=-1)
+
+            def unit(a):
+                a = a.reshape(n, Hk, dk)
+                a = a / np.sqrt(np.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+                return np.repeat(a, Hv // Hk, axis=1)
+
+            q, k, v = unit(q) * dk ** -0.5, unit(k), v.reshape(n, Hv, dv)
+            S = np.zeros((Hv, dk, dv)) if first else f64(st.lanes[0, b])
+            for t in range(n):
+                S *= np.exp(gg[o + t])[:, None, None]
+                kS = np.matmul(k[t][:, None, :], S)[:, 0]
+                S += k[t][:, :, None] * (bb[o + t][:, None] * (v[t] - kS))[:, None, :]
+                out[o + t] = np.matmul(q[t][:, None, :], S)[:, 0]
         return out
 
     none = np.full((3, B), -1, np.int32)
@@ -1183,37 +1189,45 @@ def gdn_chunk_times(args, jax) -> None:
         q_lens = np.asarray(q_lens, np.int32)
         seg_off = np.concatenate([[0], np.cumsum(q_lens)[:-1]]).astype(np.int32)
         lane = np.full((Np,), B, np.int32)
-        rel = np.zeros((Np,), np.int32)
         for b in range(B):
             lane[seg_off[b]:seg_off[b] + q_lens[b]] = b
-            rel[seg_off[b]:seg_off[b] + q_lens[b]] = np.arange(q_lens[b])
-        ops = tuple(jnp.asarray(a, jnp.int32) for a in (base, seg_off, q_lens, lane, rel))
-
-        @jax.jit
-        def layers(st):
-            o = None
-            for _ in range(reps):
-                o, st = att.packed_delta_mix(
-                    cfg, u, taps, g, beta, st, jnp.int32(0), *ops)
-            return o, st
-
-        @jax.jit
-        def once(st):
-            return att.packed_delta_mix(cfg, u, taps, g, beta, st, jnp.int32(0), *ops)
+        ops = tuple(jnp.asarray(a, jnp.int32) for a in (base, seg_off, q_lens))
 
         st = state(plan)
-        got = np.asarray(once(st)[0])
         want = recurrence(q_lens, seg_off, base, st)
         live = lane < B
-        gap = float(np.abs(got - want)[live].max())
-        jax.block_until_ready(layers(st))
-        t0 = _time.perf_counter()
-        jax.block_until_ready(layers(st))
-        ms = (_time.perf_counter() - t0) * 1e3 / reps
-        emit(gdn_chunk=name, ms_a_layer=round(ms, 3),
-             max_gap_vs_recurrence=gap, scale=float(np.abs(want[live]).max()))
-        if not gap < (1e-4 if args.rehearse else 2e-2) * max(1.0, float(np.abs(want).max())):
-            fail(f"gdn_chunk {name}: differs from the recurrence by {gap}")
+        for backend in ("kernel", "xla"):
+            # the backend is packed_delta_mix's own choice at trace time (a
+            # child process: nothing else of it reads the choice)
+            att.delta_backend = lambda backend=backend: backend
+            mix = functools.partial(
+                att.packed_delta_mix,
+                interpret=args.rehearse and backend == "kernel")
+
+            @jax.jit
+            def layers(st):
+                o = None
+                for r in range(reps):
+                    o, st = mix(cfg, us[r], taps, g, beta, st, jnp.int32(0), *ops)
+                return o, st
+
+            @jax.jit
+            def once(st):
+                return mix(cfg, u, taps, g, beta, st, jnp.int32(0), *ops)
+
+            got = np.asarray(jax.block_until_ready(once(st))[0])
+            gap = float(np.abs(got - want)[live].max())
+            jax.block_until_ready(layers(st))
+            t0 = _time.perf_counter()
+            jax.block_until_ready(layers(st))
+            ms = (_time.perf_counter() - t0) * 1e3 / reps
+            emit(gdn_chunk=name, chunks=backend, ms_a_layer=round(ms, 3),
+                 max_gap_vs_recurrence=gap,
+                 scale=float(np.abs(want[live]).max()))
+            if not gap < (1e-4 if args.rehearse else 2e-2) * max(
+                    1.0, float(np.abs(want).max())):
+                fail(f"gdn_chunk {name} ({backend}): differs from the "
+                     f"recurrence by {gap}")
 
     ub = u[:B]
 

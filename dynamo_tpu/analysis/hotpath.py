@@ -144,6 +144,12 @@ HOT_PATH_MANIFEST: Dict[str, List[str]] = {
         "group_visits",
         "_kernel",
     ],
+    # the gated delta rule's chunks, one launch a linear layer of every
+    # packed step of a trunk that has such layers (attention.packed_delta_mix)
+    "dynamo_tpu/ops/gated_delta.py": [
+        "gated_delta_chunks",
+        "_kernel",
+    ],
     # offload-plane hot paths: the admission-time tier lookup runs on the
     # event loop and the host-ring put sits behind every eviction -- a
     # host sync or recompile hazard in these stalls admission or the

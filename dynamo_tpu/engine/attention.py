@@ -352,6 +352,114 @@ def _gdn_history(state: DeltaKV, layer, B):
     return rows.reshape(B, 3, -1)
 
 
+def delta_backend() -> str:
+    """What runs a packed step's chunks of the delta rule, at trace time:
+    the launch ``ops.gated_delta.gated_delta_chunks`` on a TPU, the XLA
+    composition (``gdn_chunk_terms``, ``gdn_chunk_apply`` in a loop)
+    elsewhere."""
+    return "kernel" if _on_tpu() else "xla"
+
+
+def delta_runs(plan, base, seg_off, q_lens):
+    """A dispatch's segments that run in chunks, as runs, a lane's two side
+    by side: before, and from, the position its snapshot is taken at, so
+    that a chunk never straddles it.  Every segment of more than one row
+    runs so, and the rare one of one row that resumes from a slot or ends
+    on a snapshot (a prompt's chunk of one row: the chunks read and write a
+    slot where it lies; the one-row step knows the lanes alone).  Returns
+    ``(chunked [B], snaps [B], cut [B], run_len [2 B], run_off [2 B])``."""
+    restore, snap_slot, snap_pos = plan
+    chunked = (q_lens > 1) | (
+        (q_lens == 1) & (((restore >= 0) & (base > 0)) | (snap_slot >= 0)))
+    snaps = chunked & (snap_slot >= 0)
+    cut = jnp.where(snaps, jnp.clip(snap_pos - base, 0, q_lens), q_lens)
+    cut = jnp.where(chunked, cut, 0)
+    run_len = jnp.stack(
+        [cut, jnp.where(chunked, q_lens - cut, 0)], axis=1).reshape(-1)
+    run_off = jnp.stack([seg_off, seg_off + cut], axis=1).reshape(-1)
+    return chunked, snaps, cut, run_len, run_off
+
+
+def _gdn_packed_conv(taps, u, hist, seg_off, q_lens):
+    """``silu(conv)`` of the packed rows ``u [Np, C]`` in float32.  A row's
+    three predecessors are the rows before it in the packed axis, but for a
+    segment's first three, which reach into the lane's history ``hist [B,
+    3, C]``: those few rows are computed apart and written over the rest
+    (a gather of every row's predecessors out of the history took three
+    passes as long as the convolution itself)."""
+    Np, C = u.shape
+    B = seg_off.shape[0]
+    x = _gdn_conv(taps, [jnp.roll(u, n, axis=0) for n in (3, 2, 1)] + [u])
+    p = jnp.arange(3)[None, :]
+    rows = seg_off[:, None] + p  # [B, 3]
+    ext = jnp.concatenate([hist, u[jnp.clip(rows, 0, Np - 1)]], axis=1)
+    first = _gdn_conv(taps, [ext[:, i:i + 3] for i in range(4)])  # [B, 3, C]
+    to = jnp.where(p < q_lens[:, None], rows, Np).reshape(-1)
+    return x.at[to].set(
+        first.reshape(3 * B, C), mode="drop", unique_indices=True)
+
+
+def _gdn_one_step(S0, q, k, v, g, beta):
+    """The recurrence's one step a lane: ``S0 [B, Hv, dk, dv]`` and a row
+    each of ``q, k [B, Hv, dk]``, ``v [B, Hv, dv]``, ``g, beta [B, Hv]``.
+    Returns ``(o [B, Hv, dv], S)``."""
+    S = jnp.exp(g)[..., None, None] * S0
+    d = beta[..., None] * (v - _gdn_dot("bhk,bhkv->bhv", k, S))
+    S = S + k[..., :, None] * d[..., None, :]
+    return _gdn_dot("bhk,bhkv->bhv", q, S), S
+
+
+def _gdn_chunk_loop(S0, q, k, v, g, beta, snaps, run_len, run_off):
+    """The runs' chunks in packed order as an XLA loop of as many turns as
+    the dispatch has chunks, each from its lane's state in ``S0 [B, ..]``
+    (``q, k, v [Np, Hv, d]`` as the recurrence reads them).  Returns ``(out
+    [Np, Hv, dv]``, zero in the rows of no run, the lanes' states, and the
+    states at the cut of the lanes in ``snaps``)."""
+    Np, Hv, dv = v.shape
+    K = GDN_CHUNK
+    n_chunks = -(-run_len // K)
+    ends = jnp.cumsum(n_chunks)
+    r = jnp.arange(K, dtype=jnp.int32)
+    tail = lambda a: jnp.concatenate(  # noqa: E731  (a chunk reads K rows)
+        [a, jnp.zeros((K, *a.shape[1:]), a.dtype)], axis=0)
+    qp, kp, vp, gp, bp = tail(q), tail(k), tail(v), tail(g), tail(beta)
+
+    def chunk(c, carry):
+        Sw, snap, out = carry
+        run = jnp.searchsorted(ends, c, side="right").astype(jnp.int32)
+        b = run // 2
+        j = c - (ends[run] - n_chunks[run])
+        row0 = run_off[run] + K * j
+        ok = r < run_len[run] - K * j  # rows past the run are not its own
+
+        def rows(a):  # [K, Hv, ..] of this chunk, heads first
+            a = jax.lax.dynamic_slice_in_dim(a, row0, K, 0)
+            a = jnp.where(ok.reshape(K, *(1,) * (a.ndim - 1)), a, 0)
+            return jnp.moveaxis(a, 0, 1)
+
+        S = jax.lax.dynamic_index_in_dim(Sw, b, 0, False)
+        O, S = gdn_chunk_apply(
+            S, *gdn_chunk_terms(rows(qp), rows(kp), rows(vp), rows(gp), rows(bp)))
+        Sw = jax.lax.dynamic_update_index_in_dim(Sw, S, b, 0)
+        # a chunk that ends the run before a snapshot hands its state on
+        takes = (run % 2 == 0) & (j == n_chunks[run] - 1) & snaps[b]
+        snap = jax.lax.cond(
+            takes,
+            lambda: jax.lax.dynamic_update_index_in_dim(snap, S, b, 0),
+            lambda: snap,
+        )
+        # rows past the run are written by their own chunk, later
+        out = jax.lax.dynamic_update_slice_in_dim(
+            out, jnp.moveaxis(O, 0, 1), row0, 0)
+        return Sw, snap, out
+
+    Sw, snap, out = jax.lax.fori_loop(
+        0, ends[-1], chunk,
+        (S0, jnp.zeros_like(S0), jnp.zeros((Np + K, Hv, dv), jnp.float32)),
+    )
+    return out[:Np], Sw, snap
+
+
 @hot_path
 def packed_delta_mix(
     cfg,
@@ -364,110 +472,81 @@ def packed_delta_mix(
     base: jax.Array,  # [B] position of a lane's first row
     seg_off: jax.Array,  # [B]
     q_lens: jax.Array,  # [B] rows per lane (0 = no segment)
-    lane: jax.Array,  # [Np] lane per packed row (B = padding)
-    rel: jax.Array,  # [Np] row index within the lane's segment
+    interpret: bool = False,  # the tests': the launch through the interpreter
 ):
     """The packed step's delta rule.  A lane's segment of one row (a decode
     row) takes the recurrence's one step; a longer one runs in chunks of
     ``GDN_CHUNK`` of its rows, cut where the dispatch takes its snapshot so
-    that a chunk never straddles it: the chunks in packed order, each from
-    its lane's state (a loop of as many turns as the dispatch has chunks).
+    that a chunk never straddles it (:func:`delta_runs`).  On a TPU the
+    chunks are one launch that holds a run's state in VMEM and reads and
+    writes only the lanes that have one (``ops/gated_delta.py``); elsewhere
+    they are an XLA loop over the lanes' states (:func:`_gdn_chunk_loop`).
     Returns ``o [Np, Hv, dv]`` float32 and the state with each live lane's
     state and last three rows written, and the snapshots the dispatch's
     plan names."""
     Np, C = u.shape
     B = base.shape[0]
-    Hv, dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
-    restore, snap_slot, snap_pos = state.plan
+    restore = state.plan[0]
     live = q_lens > 0
     S_slots = state.slots.shape[1]
-    K = GDN_CHUNK
+    kernel = interpret or delta_backend() == "kernel"
     with jax.named_scope("gdn_chunk"):
         # -- where each lane starts from
         old_hist = _gdn_history(state, layer, B)  # [B, 3, C]
-        lanes = jax.lax.dynamic_index_in_dim(state.lanes, layer, 0, False)
         resumes = live & (restore >= 0) & (base > 0)
         fresh = live & (base == 0)
         at = jnp.clip(restore, 0, S_slots - 1)
-
-        def from_slots(_):  # few dispatches have a lane that restores
-            conv = state.slot_conv.reshape(-1, S_slots, 3, C)[layer, at]
-            return (
-                jnp.where(
-                    resumes[:, None, None, None], state.slots[layer, at], lanes),
-                jnp.where(resumes[:, None, None], conv, old_hist),
-            )
-
-        S0, hist = jax.lax.cond(
-            jnp.any(resumes), from_slots, lambda _: (lanes, old_hist), None)
-        hist = jnp.where(fresh[:, None, None], 0, hist)
-        S0 = jnp.where(fresh[:, None, None, None], 0.0, S0)
-        # -- the convolution: a row's predecessors are the rows before it in
-        # its segment, else the lane's history
-        lane_c = jnp.clip(lane.astype(jnp.int32), 0, B - 1)
-        past = [u]
-        for n in (1, 2, 3):
-            h = hist[lane_c, jnp.clip(3 - n + rel, 0, 2)]
-            past.append(
-                jnp.where((rel >= n)[:, None], jnp.roll(u, n, axis=0), h))
-        q, k, v = _gdn_heads(cfg, _gdn_conv(taps, past[::-1]))  # [Np, Hv, d]
-        # -- segments of one row: the recurrence's one step
-        single = q_lens == 1
-        row1 = jnp.clip(seg_off, 0, Np - 1)
-        S1 = jnp.exp(g[row1])[..., None, None] * S0
-        d1 = beta[row1][..., None] * (v[row1] - _gdn_dot("bhk,bhkv->bhv", k[row1], S1))
-        S1 = S1 + k[row1][..., :, None] * d1[..., None, :]
-        o1 = _gdn_dot("bhk,bhkv->bhv", q[row1], S1)
-        # -- longer segments in chunks: a lane's segment is two runs, before
-        # and from the snapshot's position
-        multi = q_lens > 1
-        snaps = multi & (snap_slot >= 0)
-        cut = jnp.where(snaps, jnp.clip(snap_pos - base, 0, q_lens), q_lens)
-        cut = jnp.where(multi, cut, 0)
-        run_len = jnp.stack(
-            [cut, jnp.where(multi, q_lens - cut, 0)], axis=1).reshape(-1)  # [2B]
-        run_off = jnp.stack([seg_off, seg_off + cut], axis=1).reshape(-1)
-        n_chunks = -(-run_len // K)
-        ends = jnp.cumsum(n_chunks)
-        r = jnp.arange(K, dtype=jnp.int32)
-        tail = lambda a: jnp.concatenate(  # noqa: E731  (a chunk reads K rows)
-            [a, jnp.zeros((K, *a.shape[1:]), a.dtype)], axis=0)
-        qp, kp, vp, gp, bp = tail(q), tail(k), tail(v), tail(g), tail(beta)
-
-        def chunk(c, carry):
-            Sw, snap, out = carry
-            run = jnp.searchsorted(ends, c, side="right").astype(jnp.int32)
-            b = run // 2
-            j = c - (ends[run] - n_chunks[run])
-            row0 = run_off[run] + K * j
-            ok = r < run_len[run] - K * j  # rows past the run are not its own
-
-            def rows(a):  # [K, Hv, ..] of this chunk, heads first
-                a = jax.lax.dynamic_slice_in_dim(a, row0, K, 0)
-                a = jnp.where(ok.reshape(K, *(1,) * (a.ndim - 1)), a, 0)
-                return jnp.moveaxis(a, 0, 1)
-
-            S = jax.lax.dynamic_index_in_dim(Sw, b, 0, False)
-            O, S = gdn_chunk_apply(
-                S, *gdn_chunk_terms(rows(qp), rows(kp), rows(vp), rows(gp), rows(bp)))
-            Sw = jax.lax.dynamic_update_index_in_dim(Sw, S, b, 0)
-            # a chunk that ends the run before a snapshot hands its state on
-            takes = (run % 2 == 0) & (j == n_chunks[run] - 1) & snaps[b]
-            snap = jax.lax.cond(
-                takes,
-                lambda: jax.lax.dynamic_update_index_in_dim(snap, S, b, 0),
-                lambda: snap,
-            )
-            # rows past the run are written by their own chunk, later
-            out = jax.lax.dynamic_update_slice_in_dim(
-                out, jnp.moveaxis(O, 0, 1), row0, 0)
-            return Sw, snap, out
-
-        Sw, snap, out = jax.lax.fori_loop(
-            0, ends[-1], chunk,
-            (S0, jnp.zeros_like(S0), jnp.zeros((Np + K, Hv, dv), jnp.float32)),
+        hist = jax.lax.cond(
+            jnp.any(resumes),
+            lambda: jnp.where(
+                resumes[:, None, None],
+                state.slot_conv.reshape(-1, S_slots, 3, C)[layer, at], old_hist),
+            lambda: old_hist,
         )
-        o = out.at[jnp.where(single, seg_off, Np + K)].set(o1, mode="drop")[:Np]
+        hist = jnp.where(fresh[:, None, None], 0, hist)
+        x = _gdn_packed_conv(taps, u, hist, seg_off, q_lens)  # [Np, C] f32
+        chunked, snaps, cut, run_len, run_off = delta_runs(
+            state.plan, base, seg_off, q_lens)
+        single = live & ~chunked  # segments of one row: the one step
+        row1 = jnp.clip(seg_off, 0, Np - 1)
+        one = lambda S0: _gdn_one_step(  # noqa: E731
+            S0, *_gdn_heads(cfg, x[row1]), g[row1], beta[row1])
+        slots = state.slots
+        if kernel:
+            # the chunks first, over the lanes that have a run; then the
+            # one-row lanes, whose states the launch left as they were
+            from ..ops.gated_delta import (
+                SRC_LANE, SRC_SLOT, SRC_ZERO, gated_delta_chunks)
+
+            out, lanes_all, slots = gated_delta_chunks(
+                x, g, beta, state.lanes, slots, layer, run_len, run_off,
+                jnp.where(fresh, SRC_ZERO, jnp.where(resumes, SRC_SLOT, SRC_LANE)),
+                restore, jnp.where(snaps, state.plan[1], -1),
+                Hk=cfg.linear_num_key_heads, Hv=cfg.linear_num_value_heads,
+                interpret=interpret,
+            )
+            Sw = jax.lax.dynamic_index_in_dim(lanes_all, layer, 0, False)
+            o1, S1 = one(jnp.where(fresh[:, None, None, None], 0.0, Sw))
+        else:
+            lanes_all = state.lanes
+            lanes = jax.lax.dynamic_index_in_dim(lanes_all, layer, 0, False)
+            S0 = jax.lax.cond(  # few dispatches have a lane that resumes
+                jnp.any(resumes),
+                lambda: jnp.where(
+                    resumes[:, None, None, None], slots[layer, at], lanes),
+                lambda: lanes,
+            )
+            S0 = jnp.where(fresh[:, None, None, None], 0.0, S0)
+            o1, S1 = one(S0)
+            out, Sw, snap = _gdn_chunk_loop(
+                S0, *_gdn_heads(cfg, x), g, beta, snaps, run_len, run_off)
+        o = out.at[jnp.where(single, seg_off, Np)].set(o1, mode="drop")
+        if kernel:
+            # the launch writes its runs' rows and nothing else: zeros in
+            # the rows of no lane, where whatever reads ``o`` reads it
+            r = jnp.arange(Np)[:, None]
+            mine = (r >= seg_off[None, :]) & (r < (seg_off + q_lens)[None, :])
+            o = jnp.where(jnp.any(mine, axis=1)[:, None, None], o, 0.0)
         Sw = jnp.where(single[:, None, None, None], S1, Sw)
 
         # -- what the step leaves: the lanes' state and last three rows
@@ -480,14 +559,17 @@ def packed_delta_mix(
         new_hist = jnp.stack([row_at(q_lens - 3 + n) for n in range(3)], axis=1)
         new_hist = jnp.where(live[:, None, None], new_hist, old_hist)
         snap_hist = jnp.stack([row_at(cut - 3 + n) for n in range(3)], axis=1)
-        # -- the snapshots the plan names (others to no slot)
-        to = jnp.where(snaps, snap_slot, S_slots)
+        # -- the snapshots the plan names (others to no slot): the launch
+        # has written the states, their rows of history go here
+        to = jnp.where(snaps, state.plan[1], S_slots)
         rows3 = (3 * to[:, None] + jnp.arange(3)[None, :]).reshape(-1)
 
         def write(pool):  # and few take a snapshot
             slots, slot_conv = pool
+            if not kernel:
+                slots = slots.at[layer, to].set(snap, mode="drop")
             return (
-                slots.at[layer, to].set(snap, mode="drop"),
+                slots,
                 slot_conv.at[layer, rows3].set(
                     snap_hist.reshape(3 * B, C).astype(slot_conv.dtype),
                     mode="drop",
@@ -495,11 +577,10 @@ def packed_delta_mix(
             )
 
         slots, slot_conv = jax.lax.cond(
-            jnp.any(snaps), write, lambda pool: pool,
-            (state.slots, state.slot_conv))
+            jnp.any(snaps), write, lambda pool: pool, (slots, state.slot_conv))
         new = DeltaKV(
             state.attn,
-            state.lanes.at[layer].set(Sw),
+            lanes_all.at[layer].set(Sw),
             state.conv.at[layer].set(
                 new_hist.reshape(3 * B, C).astype(state.conv.dtype)),
             slots, slot_conv, state.plan,

@@ -874,6 +874,10 @@ class JaxEngine:
 
             self.sched.state_slots = StateSlots(self.cfg.state_snapshot_slots)
             self.obs.mint_delta_state(self.kv.state_bytes)
+            # what runs a packed step's chunks of the delta rule (read once)
+            from . import attention as att
+
+            self._delta_backend = att.delta_backend()
             if pool is not None:  # a slot dies with its block
                 pool.on_evict = lambda blk: self.sched.state_slots.drop(
                     blk.sequence_hash)
@@ -4675,8 +4679,17 @@ class JaxEngine:
             self.kv.pages = self.kv.pages.with_plan(jnp.asarray(plan))
             self.obs.observe_snapshot_slots(
                 len(sched.state_slots), sched.state_slots.evictions)
+            if self._delta_backend == "kernel":
+                # chunks the step's launches run, a launch a linear layer
+                from ..ops.gated_delta import chunks_of
+
+                self.obs.gdn_chunks.inc(
+                    self.model_cfg.kind_layers("linear") * chunks_of(
+                        q_host, np.where(dec_cap, sched.seq_lens, p_start),
+                        plan))
             if tick is not None and tick.annotating:
                 dispatch_meta["attn"] = self._packed_attn
+                dispatch_meta["gdn"] = self._delta_backend
                 dispatch_meta["restored"] = int((plan[0] >= 0).sum())
                 dispatch_meta["snapshots"] = int((plan[1] >= 0).sum())
                 dispatch_meta["state_restored_tokens"] = sum(

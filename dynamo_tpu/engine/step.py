@@ -588,7 +588,6 @@ def _packed_unified_step(
     def delta_fn(u, taps, g, beta, kv, layer):
         out, kv = att.packed_delta_mix(
             cfg, u[0], taps, g[0], beta[0], kv, layer, base, seg_off, q_lens,
-            t_lane, t_rel,
         )
         return out[None], kv
 

@@ -438,6 +438,7 @@ class EngineMetrics:
         # a trunk with gated delta-rule layers (mint_delta_state)
         self.state_snapshots: Optional[Counter] = None
         self.snapshot_recompute_tokens: Optional[Counter] = None
+        self.gdn_chunks: Optional[Counter] = None
         self._snapshot_slots: Optional[Tuple[Counter, Gauge]] = None
         self._snapshot_evictions = 0
         self._window_released = 0
@@ -604,6 +605,12 @@ class EngineMetrics:
             "dynamo_engine_state_snapshot_recompute_tokens",
             "Tokens of prefix hits behind the snapshot they resumed from, "
             "computed again",
+        )
+        self.gdn_chunks = reg.counter(
+            "dynamo_engine_gdn_chunks",
+            "Chunks of the gated delta rule that packed steps ran through "
+            "the launch gated_delta_chunks, summed over the linear layers "
+            "(0 where the XLA composition runs them)",
         )
         self._snapshot_slots = (
             reg.counter(

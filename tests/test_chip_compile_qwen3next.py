@@ -20,6 +20,12 @@ from tests import test_chip_compile as base
 from tests.test_chip_compile import chip, topo  # noqa: F401  (fixtures)
 
 LANES, PAGE, TABLE = 16, 16, 2112
+# pinned anew by PR 55, for this configuration alone.  A ceiling on the
+# compiler's count of the steps' temporaries, MiB by fused steps (the chunk
+# step of 2048 rows, the fused block of 4 decode steps), and the equations of
+# the chunk step's jaxpr, nested ones counted
+TEMP_MIB = {1: 304, 4: 194}
+PACKED_STEP_EQUATIONS = 10456
 
 
 def published():
@@ -91,6 +97,10 @@ def test_qwen3next_steps_lower_at_published_widths(chip, monkeypatch, Np, s_max,
     if steps > 1:
         want.append("paged_decode_attention_wide")
     assert sorted(set(launches)) == sorted(want), launches
+    # the linear layers' chunks are one launch a layer, found by its name
+    # (PR 55), and no loop carries the lanes' states
+    assert re.search(r"%gated_delta_chunks[.\d]* = ", text)
+    assert not re.search(r"f32\[16,32,128,128\]\S*, f32\[16,32,128,128\]\S*[^\n]* while\(", text)
     assert not re.search(r"f32\[9,64,32,128,128\]\S* copy\(", text)
     assert not re.search(r"f32\[9,16,32,128,128\]\S* copy\(", text)
     assert not re.search(r"bf16\[3,2,16384,16,2,256\]\S* copy\(", text)
@@ -106,11 +116,33 @@ def test_qwen3next_steps_lower_at_published_widths(chip, monkeypatch, Np, s_max,
           mem.argument_size_in_bytes / 2**30, "GiB")
     # 10.8 GB of weights, 1.6 GB of pool, 0.3 GB of lanes and 1.2 GB of
     # slots are arguments; what the step makes beside them has to fit in
-    # what is left of 15.75 GiB.  The relayout's temporary is gone (PR 54: a
-    # layer's pages sliced, 512 MiB, and turned, 512 more in the fused
-    # block): the parent's steps read 1056 and 1208 MiB here, these 525 and
-    # 182, and the bound fell by the slice
-    assert mem.temp_size_in_bytes < ((1300 - 512) << 20)
+    # what is left of 15.75 GiB.  PR 54 took away the attention relayout's
+    # temporary (1056 and 1208 MiB -> 525 and 182); with the chunks in a
+    # launch (PR 55) the loop's carries, the five padded copies of the rows
+    # and the three gathered predecessors are gone too (276.1 and 175.9 MiB
+    # read here): TEMP_MIB is a tenth over that
+    assert mem.temp_size_in_bytes < TEMP_MIB[steps] << 20
+
+
+def test_qwen3next_packed_step_traces_to_its_pinned_jaxpr(monkeypatch):
+    """The chunk step of 2048 rows as the chip traces it: its equations,
+    nested ones counted (pinned for this configuration alone; the other
+    families' pins are theirs), one launch ``gated_delta_chunks`` a linear
+    layer of the scan's period and no XLA loop of chunks."""
+    from tests.test_packed_work_list import _eqns
+
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    cfg, eng = published()
+    spec = jax.ShapeDtypeStruct
+    params, _, *rest = _operands(spec, cfg, _delta_pool(spec, cfg, eng), 2048)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: S._packed_unified_step(a[0], cfg, *a[1:], s_max=1024)
+    )(params, *rest)
+    eqns = list(_eqns(jaxpr.jaxpr))
+    names = [
+        e.params["name"] for e in eqns if e.primitive.name == "pallas_call"]
+    assert names.count("gated_delta_chunks") == 3  # the scan body is a period
+    assert len(eqns) == PACKED_STEP_EQUATIONS
 
 
 def packed_step_equations(name, Np, s_max, lanes, table):

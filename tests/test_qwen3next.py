@@ -303,14 +303,9 @@ def _delta_case(q_lens, base, plan=None, B=4, Np=256, seed=0, state=None):
         state = state.with_plan(jnp.asarray(plan, jnp.int32))
     q_lens = np.asarray(q_lens, np.int32)
     seg_off = np.concatenate([[0], np.cumsum(q_lens)[:-1]]).astype(np.int32)
-    lane = np.full((Np,), B, np.int32)
-    rel = np.zeros((Np,), np.int32)
-    for b in range(B):
-        lane[seg_off[b]:seg_off[b] + q_lens[b]] = b
-        rel[seg_off[b]:seg_off[b] + q_lens[b]] = np.arange(q_lens[b])
     o, new = jax.jit(att.packed_delta_mix, static_argnums=0)(
         mc, u, taps, g, beta, state, jnp.int32(0),
-        *(jnp.asarray(a, jnp.int32) for a in (base, seg_off, q_lens, lane, rel)))
+        *(jnp.asarray(a, jnp.int32) for a in (base, seg_off, q_lens)))
 
     def recurrence(b, S, hist):
         n, off = int(q_lens[b]), int(seg_off[b])
